@@ -64,6 +64,16 @@ def init(
     loop — same wire protocol, no subprocess cost; with False they are real
     subprocesses like the reference's `ray start` topology
     (ref: _private/node.py:1479 start_ray_processes).
+
+    Chips: without ``num_tpus`` the node advertises the chips it detects
+    (device files, never a jax backend — ``init`` initialises none). A chip
+    belongs to one process, so a driver that already holds a TPU backend
+    cannot also start a node that leases those chips to workers: ``init``
+    raises instead of letting the first chip worker fail on libtpu's lock.
+    Pass ``num_tpus=0`` to keep the chips in the driver. ``num_tpus`` in a
+    task's or actor's options is a whole number of chips: the raylet refuses
+    to lease a fraction (``validate_resource_request_quantity``), so the
+    task fails with ``SchedulingError`` and the actor dies with that cause.
     """
     global _core, _io, _owned_cluster
     if _core is not None:
@@ -103,6 +113,17 @@ def init(
             for k, v in TPUAcceleratorManager.get_current_node_tpu_resources().items():
                 res.setdefault(k, v)
             labels.update(TPUAcceleratorManager.get_current_node_tpu_labels())
+        if res.get("TPU"):
+            from ray_tpu.utils.device import holds_tpu_backend
+
+            if holds_tpu_backend():
+                raise RuntimeError(
+                    f"this process already holds a TPU backend, so the "
+                    f"{res['TPU']:g} chip(s) the local node would lease to "
+                    f"workers cannot be opened by them (a chip belongs to "
+                    f"one process). Call ray_tpu.init() before touching jax "
+                    f"and keep the driver off the chip (JAX_PLATFORMS=cpu), "
+                    f"or pass num_tpus=0 to keep the chips in the driver.")
         if _in_process:
             from ray_tpu.core.cluster import Cluster
 

@@ -706,6 +706,10 @@ class GcsServer:
         if info is None or not info.alive:
             return
         info.alive = False
+        # a disconnect is how every orderly shutdown looks; a timeout is
+        # the GCS's own judgement and must not pass in silence
+        log.log(logging.WARNING if "timeout" in cause else logging.INFO,
+                "node %s marked dead: %s", node_id.hex()[:12], cause)
         self._drop_node_conn(node_id)
         await self.publish("nodes", {"event": "removed", "node_id": node_id, "cause": cause})
         # dedicated low-traffic channel for location-cache invalidation:
@@ -768,6 +772,13 @@ class GcsServer:
         with a flat 0.05s sleep and a fresh deadline every time —
         raylint RT013's synchronized-herd shape, and an actor could
         retry forever)."""
+
+        async def give_up(cause: str) -> None:
+            info.state = DEAD
+            info.death_cause = cause
+            await self.publish("actors", info.view())
+            await self.publish(f"actor:{info.actor_id.hex()}", info.view())
+
         try:
             resources = info.spec.get("resources", {"CPU": 1.0})
             pg_id = info.spec.get("placement_group")
@@ -780,17 +791,12 @@ class GcsServer:
                                        strategy)
                 if node is None:
                     if time.monotonic() > deadline:
-                        info.state = DEAD
-                        info.death_cause = (
+                        return await give_up(
                             f"no node can host actor resources {resources}"
                             + (f" under strategy {strategy}" if strategy
                                else "")
                             + (" (placement group not CREATED)"
                                if pg_id is not None else ""))
-                        await self.publish("actors", info.view())
-                        await self.publish(
-                            f"actor:{info.actor_id.hex()}", info.view())
-                        return
                     await asyncio.sleep(0.1)  # poll: placement may repair
                     continue
                 # leases ride the batched lease_workers path (2.0):
@@ -815,16 +821,16 @@ class GcsServer:
                               node.node_id.hex()[:12], exc_info=True)
                 if lease and lease.get("granted"):
                     break
+                if lease and lease.get("infeasible"):
+                    # refused, not busy: no retry can change the answer
+                    # (e.g. a fractional TPU demand)
+                    return await give_up(
+                        f"actor lease refused: {lease.get('error')}")
                 if time.monotonic() > deadline:
-                    info.state = DEAD
-                    info.death_cause = (
+                    return await give_up(
                         f"actor lease not granted within "
                         f"worker_start_timeout_s="
                         f"{self.cfg.worker_start_timeout_s}")
-                    await self.publish("actors", info.view())
-                    await self.publish(
-                        f"actor:{info.actor_id.hex()}", info.view())
-                    return
                 retries += 1
                 base = min(0.05 * (2 ** min(retries, 5)), 1.0)
                 await asyncio.sleep(base * (0.5 + random.random() / 2))
@@ -845,10 +851,7 @@ class GcsServer:
             await self.publish("actors", info.view())
             await self.publish(f"actor:{info.actor_id.hex()}", info.view())
         except Exception as e:  # scheduling failed terminally
-            info.state = DEAD
-            info.death_cause = f"actor creation failed: {e!r}"
-            await self.publish("actors", info.view())
-            await self.publish(f"actor:{info.actor_id.hex()}", info.view())
+            await give_up(f"actor creation failed: {e!r}")
 
     async def _lease_via_batch(self, node: "NodeInfo", payload: dict,
                                timeout: float):
@@ -1472,11 +1475,32 @@ class GcsServer:
         if node_id is not None:
             self._bg.spawn(self._mark_node_dead(node_id, "raylet disconnected"))
 
+    def _forgive_own_stall(self, late_s: float, now: float) -> None:
+        """This process itself did not run for ``late_s`` seconds — a
+        blocked loop, a paused VM or sandbox — so it could not have taken
+        a heartbeat in that time either: push every live node's last
+        heartbeat forward by the pause instead of reading it as the death
+        of all of them, but never past ``now`` — a heartbeat that was
+        handled after all must not buy a node that dies next extra time.
+        First seen on a four-chip host (PERF.md PR 21): every process
+        stalled 7 s while four TPU backends started, and the in-process
+        GCS declared its own node dead."""
+        if late_s <= self.cfg.health_check_period_s:
+            return
+        log.warning("health loop ran %.1fs late: forgiving the pause", late_s)
+        for info in self.nodes.values():
+            if info.alive:
+                info.last_heartbeat = min(info.last_heartbeat + late_s, now)
+
     async def _health_loop(self):
         cfg = self.cfg
+        last_tick = time.monotonic()
         while not self._stopping:
             await asyncio.sleep(cfg.health_check_period_s)
             now = time.monotonic()
+            self._forgive_own_stall(
+                now - last_tick - cfg.health_check_period_s, now)
+            last_tick = now
             deadline = cfg.health_check_period_s * cfg.health_check_failure_threshold
             for info in list(self.nodes.values()):
                 if info.alive and now - info.last_heartbeat > deadline:
@@ -1801,6 +1825,9 @@ def _fits_all(bundles: list[dict], avail: dict) -> bool:
 def main():
     import argparse
 
+    from ray_tpu.utils.device import pin_cpu
+
+    pin_cpu()  # a long-lived daemon must never take a chip (utils/device.py)
     chaos.maybe_arm()  # fault schedule rides the serialized config
 
     parser = argparse.ArgumentParser()

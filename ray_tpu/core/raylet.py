@@ -325,12 +325,11 @@ class Raylet:
         self.host = host
         self.labels = labels or {}
         # per-chip TPU instance tracking (ref: the reference's per-slot
-        # resource_instance_set; chips are handed to leases by id so workers
-        # can isolate via TPU_VISIBLE_CHIPS)
+        # resource_instance_set): chips are handed to leases by id, and a
+        # chip worker is born with its TPU_VISIBLE_CHIPS (_spawn_worker)
+        self._n_tpu_chips = int((resources or {}).get("TPU", 0))
         self._tpu_chips_free: list[str] = [
-            str(i) for i in range(int((resources or {}).get("TPU", 0)))
-        ]
-        self._worker_chips: dict = {}  # worker_id -> list[str]
+            str(i) for i in range(self._n_tpu_chips)]
         self.session = session or f"s{os.getpid()}"
 
         if resources is None:
@@ -348,7 +347,7 @@ class Raylet:
         self.ledger = ResourceLedger(resources)
 
         self.log_dir = os.path.join(
-            "/tmp", "ray_tpu", f"session_{self.session}", "logs"
+            self.cfg.temp_dir, f"session_{self.session}", "logs"
         )
         self.store_name = f"/rt_{self.session}_{self.node_id.hex()[:8]}"
         self.store = SharedObjectStore(
@@ -690,10 +689,23 @@ class Raylet:
             log.debug("worker death report failed", exc_info=True)
 
     # ---------------------------------------------------------- worker pool
-    def _spawn_worker(self, language: str = "python") -> WorkerHandle:
+    def _spawn_worker(self, language: str = "python",
+                      chips: list[str] | None = None) -> WorkerHandle:
         worker_id = WorkerID.generate()
         env = dict(os.environ)
         env.update(self.cfg.to_env())
+        # The lease's chips are part of the worker's birth environment:
+        # the process decides its jax backend from it before anything can
+        # touch jax (utils/device.py). A worker with no chips must not
+        # inherit a chip subset the node's own environment names.
+        from ray_tpu.accelerators.tpu import TPUAcceleratorManager
+
+        for k, v in TPUAcceleratorManager.visible_chips_env(
+                chips or [], self._n_tpu_chips).items():
+            if v is None:
+                env.pop(k, None)
+            else:
+                env[k] = v
         pkg_parent = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
         env["PYTHONPATH"] = pkg_parent + os.pathsep + env.get("PYTHONPATH", "")
         env.update(
@@ -817,14 +829,6 @@ class Raylet:
         except OSError:
             return None
 
-    async def rpc_get_lease_env(self, conn, p):
-        """Worker-side query for its accelerator assignment (applied as
-        TPU_VISIBLE_CHIPS before the first user code runs)."""
-        from ray_tpu.utils.ids import WorkerID as _WID
-
-        chips = self._worker_chips.get(_WID.from_hex(p["worker_id"]))
-        return {"tpu_chips": chips}
-
     async def rpc_kill_worker(self, conn, p):
         """Force-kill a worker (task cancellation with force=True; ref:
         CancelTask force_kill path)."""
@@ -847,16 +851,20 @@ class Raylet:
         w.ready.set()
         return {"ok": True}
 
-    async def _pop_worker(self, language: str = "python") -> WorkerHandle:
-        # language-segregated pop (ref: worker_pool.h:231 per-language pools)
-        for i in range(len(self.idle_workers) - 1, -1, -1):
+    async def _pop_worker(self, language: str = "python",
+                          chips: list[str] | None = None) -> WorkerHandle:
+        # language-segregated pop (ref: worker_pool.h:231 per-language pools).
+        # A chip lease never takes an idle worker: those were born pinned
+        # to the CPU and may already hold a backend; it gets a fresh
+        # process whose environment carries its chips.
+        for i in range(0 if chips else len(self.idle_workers))[::-1]:
             if self.idle_workers[i].language != language:
                 continue
             w = self.idle_workers.pop(i)
             if w.proc.poll() is None:
                 return w
             await self._on_worker_death(w)
-        w = self._spawn_worker(language)
+        w = self._spawn_worker(language, chips)
         try:
             await asyncio.wait_for(w.ready.wait(), timeout=self.cfg.worker_start_timeout_s)
         except asyncio.TimeoutError:
@@ -876,6 +884,9 @@ class Raylet:
         with a spillback address; otherwise queue (infeasible-now).
         """
         resources = dict(p.get("resources") or {"CPU": 1.0})
+        refusal = self._refuse_tpu_demand(resources)
+        if refusal is not None:
+            return refusal
         if chaos.ENABLED:
             # "raylet.lease_grant" fault point: `error` raises out of the
             # handler (the requester's lease RPC fails — its retry/
@@ -894,35 +905,50 @@ class Raylet:
             redirect = self._apply_strategy(strategy, resources, p)
             if redirect is not None:
                 return redirect
-        granted = self._try_allocate(resources, pg_key)
-        if not granted:
+        chips = self._try_allocate(resources, pg_key)
+        if chips is None:
             spill = self._pick_spillback(resources, p)
             if spill is not None:
                 return {"granted": False, "spill_to": spill}
             fut = asyncio.get_running_loop().create_future()
             self._lease_waiters.append((resources, fut, pg_key, conn))
             try:
-                await fut  # resolved by _grant_waiters when resources free up
+                # resolved by _grant_waiters when resources free up
+                chips = await fut
             except asyncio.CancelledError:
                 # requester disconnected while queued (see _on_disconnect)
                 if fut.done() and not fut.cancelled():
-                    self._free_resources(resources, pg_key)
+                    self._free_resources(resources, pg_key, fut.result())
                 raise
-        return await self._grant_lease(conn, p, resources, pg_key)
+        return await self._grant_lease(conn, p, resources, pg_key, chips)
 
-    async def _grant_lease(self, conn, p, resources, pg_key) -> dict:
-        """Shared grant tail (resources already allocated): pop/spawn a
-        worker, stamp the lease, build the reply. On failure the
-        allocation is returned."""
+    def _refuse_tpu_demand(self, resources) -> dict | None:
+        """Refusal reply for a TPU demand no lease can carry (fractional,
+        or a chip count hosts cannot isolate) — see
+        ``TPUAcceleratorManager.validate_resource_request_quantity``."""
+        n_tpu = resources.get("TPU", 0)
+        if not n_tpu:
+            return None
+        from ray_tpu.accelerators.tpu import TPUAcceleratorManager
+
+        ok, why = TPUAcceleratorManager.validate_resource_request_quantity(n_tpu)
+        if ok:
+            return None
+        return {"granted": False, "infeasible": True, "error": why}
+
+    async def _grant_lease(self, conn, p, resources, pg_key, chips) -> dict:
+        """Shared grant tail (resources and ``chips`` already allocated):
+        pop/spawn a worker, stamp the lease, build the reply. On failure
+        the allocation is returned."""
         if conn._closed:
             # requester died between grant and reply: give the slot back
-            self._free_resources(resources, pg_key)
+            self._free_resources(resources, pg_key, chips)
             self._grant_waiters()
             raise rpc.RpcError("lease requester disconnected")
         try:
-            w = await self._pop_worker(p.get("language") or "python")
+            w = await self._pop_worker(p.get("language") or "python", chips)
         except Exception:
-            self._free_resources(resources, pg_key)
+            self._free_resources(resources, pg_key, chips)
             raise
         lease_id = next(self._lease_ids)
         w.lease_id = lease_id
@@ -930,11 +956,7 @@ class Raylet:
         # cap so a recycled worker can't inherit the previous lease's limit
         mem = resources.get("memory")
         self.cgroups.set_limit(w.worker_id.hex(), int(mem) if mem else None)
-        tpu_chips = None
-        n_tpu = int(resources.get("TPU", 0))
-        if n_tpu > 0 and self._tpu_chips_free:
-            tpu_chips = [self._tpu_chips_free.pop(0) for _ in range(min(n_tpu, len(self._tpu_chips_free)))]
-            self._worker_chips[w.worker_id] = tpu_chips
+        tpu_chips = chips or None
         if p.get("for_actor") is not None:
             w.actor_id = p["for_actor"]
         # A lease dies with its owner's connection only when the owner says
@@ -967,6 +989,9 @@ class Raylet:
         # one ledger pass: allocation order is batch order
         for i, req in enumerate(requests):
             resources = dict(req.get("resources") or {"CPU": 1.0})
+            out[i] = self._refuse_tpu_demand(resources)
+            if out[i] is not None:
+                continue
             if chaos.ENABLED:
                 # per-request verdict, absorbed per slot: an injected
                 # `error` must fail THIS request only — raising out of
@@ -987,17 +1012,19 @@ class Raylet:
             pg_key = None
             if req.get("pg_id") is not None:
                 pg_key = (req["pg_id"], req.get("bundle_index", 0))
-            if self._try_allocate(resources, pg_key):
-                granted.append((i, resources, pg_key, req))
+            chips = self._try_allocate(resources, pg_key)
+            if chips is not None:
+                granted.append((i, resources, pg_key, req, chips))
             else:
                 spill = self._pick_spillback(resources, req)
                 out[i] = ({"granted": False, "spill_to": spill}
                           if spill is not None
                           else {"granted": False, "busy": True})
 
-        async def grant(i, resources, pg_key, req):
+        async def grant(i, resources, pg_key, req, chips):
             try:
-                out[i] = await self._grant_lease(conn, req, resources, pg_key)
+                out[i] = await self._grant_lease(
+                    conn, req, resources, pg_key, chips)
             except Exception as e:
                 out[i] = {"granted": False, "busy": True, "error": repr(e)}
 
@@ -1076,21 +1103,41 @@ class Raylet:
                     "error": f"no alive node matches labels {hard}"}
         return None
 
-    def _try_allocate(self, resources, pg_key) -> bool:
-        if pg_key is not None:
-            return self.ledger.bundle_allocate(pg_key, resources)
-        return self.ledger.allocate(resources)
+    def _try_allocate(self, resources, pg_key) -> list[str] | None:
+        """Ledger allocation and the lease's chip ids in one step: None
+        when it does not fit now, else the chips (empty without a TPU
+        demand). A lease whose ``TPU`` demand is granted always carries
+        that many chip ids: while an exiting worker still holds chips
+        (``_release_chips``) the lease waits, even where the ledger — a
+        removed placement group frees its bundle at once — already shows
+        the resource. The demand is whole (``_refuse_tpu_demand``)."""
+        n_tpu = int(resources.get("TPU", 0))
+        if n_tpu > len(self._tpu_chips_free):
+            return None
+        fits = (self.ledger.bundle_allocate(pg_key, resources)
+                if pg_key is not None else self.ledger.allocate(resources))
+        if not fits:
+            return None
+        # lowest ids first: chips come back in the order their holders
+        # exit, and a two-chip lease wants neighbours (0,1 or 2,3 on a 2x2
+        # host), not whichever two returned last
+        self._tpu_chips_free.sort(key=int)
+        chips = self._tpu_chips_free[:n_tpu]
+        del self._tpu_chips_free[:n_tpu]
+        return chips
 
-    def _free_resources(self, resources, pg_key):
+    def _free_resources(self, resources, pg_key, chips=()):
+        """Undo ``_try_allocate`` for an allocation no worker ran under
+        (its chips were never opened, so they return at once)."""
         if pg_key is not None:
             self.ledger.bundle_free(pg_key, resources)
         else:
             self.ledger.free(resources)
+        self._tpu_chips_free.extend(chips)
 
     def _free_lease_resources(self, lease: Lease):
         self._free_resources(lease.resources, lease.pg_key)
         if lease.tpu_chips:
-            self._worker_chips.pop(lease.worker.worker_id, None)
             self._release_chips(lease.worker, list(lease.tpu_chips))
 
     def _release_chips(self, w: WorkerHandle, chips: list):
@@ -1120,8 +1167,9 @@ class Raylet:
         for resources, fut, pg_key, conn in self._lease_waiters:
             if fut.done() or conn._closed:
                 continue  # requester gone: drop without allocating
-            if self._try_allocate(resources, pg_key):
-                fut.set_result(True)
+            chips = self._try_allocate(resources, pg_key)
+            if chips is not None:
+                fut.set_result(chips)
             else:
                 still.append((resources, fut, pg_key, conn))
         self._lease_waiters = still
@@ -2377,6 +2425,9 @@ class Raylet:
 def main():
     import argparse
 
+    from ray_tpu.utils.device import pin_cpu
+
+    pin_cpu()  # a long-lived daemon must never take a chip (utils/device.py)
     chaos.maybe_arm()  # fault schedule rides the serialized config
 
     parser = argparse.ArgumentParser()

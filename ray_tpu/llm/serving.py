@@ -206,6 +206,13 @@ class LLMEngineServer:
                 "waiting": len(self.engine.waiting),
                 "free_pages": len(self.engine.free_pages)}
 
+    def device_report(self) -> dict:
+        """The device this replica really serves from and its memory high
+        water mark."""
+        from ray_tpu.utils.device import device_report
+
+        return device_report()
+
 
 def build_llm_engine_deployment(model_config, *, params=None, params_fn=None,
                                 num_replicas: int = 1, num_tpus: float = 0.0,

@@ -233,8 +233,7 @@ def _block_tp(layer, x, cos, sin, cfg: LlamaConfig, tp_axis: str):
 
     B, T, D = x.shape
     hd = cfg.head_dim
-    tp = (lax.axis_size(tp_axis) if hasattr(lax, "axis_size")
-          else lax.psum(1, tp_axis))  # jax 0.4.x spelling
+    tp = lax.axis_size(tp_axis)
     h = rms_norm(x, layer["attn_norm"]["scale"])
     q = (h @ layer["wq"]["kernel"]).reshape(B, T, cfg.n_heads // tp, hd)
     k = (h @ layer["wk"]["kernel"]).reshape(B, T, cfg.n_kv_heads // tp, hd)

@@ -8,6 +8,7 @@
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from ray_tpu.parallel.ring_attention import reference_attention
@@ -48,9 +49,8 @@ def attention(q, k, v, *, causal: bool = True, sm_scale=None, mesh=None,
 
 
 def _default_local_impl(q) -> str:
-    from ray_tpu.utils.device import is_tpu
-
     B, T, H, D = q.shape
-    if is_tpu() and T >= 1024 and T % 512 == 0 and D in (64, 128, 256):
+    if (jax.default_backend() == "tpu" and T >= 1024 and T % 512 == 0
+            and D in (64, 128, 256)):
         return "flash"
     return "plain"

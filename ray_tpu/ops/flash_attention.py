@@ -26,21 +26,18 @@ both sides — no autodiff-through-pallas (which the TPU lowering rejects).
 from __future__ import annotations
 
 import functools
+import logging
 import math
 import os
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU backend bits are absent on CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
+log = logging.getLogger(__name__)
 
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
-
+_VMEM = pltpu.VMEM
 _NEG_BIG = -1e30
 _LANES = 128
 
@@ -339,6 +336,12 @@ _BLOCK_Q = int(os.environ.get("RT_FLASH_BLOCK_Q", "1024"))
 _BLOCK_K = int(os.environ.get("RT_FLASH_BLOCK_K", "1024"))
 
 
+@functools.cache
+def _log_interpreting_once(backend: str) -> None:
+    log.warning("flash_attention: backend is %r, not 'tpu' — the Pallas "
+                "kernels run in interpret mode", backend)
+
+
 def flash_attention(q, k, v, *, causal: bool = True, sm_scale: float | None = None,
                     block_q: int | None = None, block_k: int | None = None,
                     interpret: bool | None = None):
@@ -347,14 +350,14 @@ def flash_attention(q, k, v, *, causal: bool = True, sm_scale: float | None = No
     Differentiable: backward runs the dedicated Pallas kernels above through
     jax.custom_vjp (autodiff through pallas_call is rejected by the TPU
     lowering, and a recompute-free bwd kernel is faster anyway)."""
-    if _VMEM is None:
-        raise RuntimeError("pallas TPU backend unavailable; use attn impl 'plain'")
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     if interpret is None:
-        from ray_tpu.utils.device import is_tpu
-
-        interpret = not is_tpu()
+        # decided from the backend this process really has: the kernels
+        # compile for the TPU and are interpreted anywhere else
+        interpret = jax.default_backend() != "tpu"
+        if interpret:
+            _log_interpreting_once(jax.default_backend())
     B, T, H, D = q.shape
     Tk = k.shape[1]
     # DEFAULTED blocks clamp then halve until they divide the sequence

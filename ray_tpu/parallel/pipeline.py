@@ -16,10 +16,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
-
-from ray_tpu.parallel._compat import shard_map
 
 
 def pipeline_spmd_local(stage_fn, stage_params, x_micro, *, axis_name: str = "pp"):

@@ -71,11 +71,7 @@ def ring_attention_local(q, k, v, *, axis_name: str, causal: bool = True,
     axes = tuple(vary_axes) + (axis_name,) if axis_name not in vary_axes else tuple(vary_axes)
 
     def _vary(x):
-        if hasattr(lax, "pcast"):
-            return lax.pcast(x, axes, to="varying")
-        if hasattr(lax, "pvary"):
-            return lax.pvary(x, axes)
-        return x  # jax 0.4.x: no vma typing, nothing to mark
+        return lax.pcast(x, axes, to="varying")
 
     m0 = _vary(jnp.full((B, H, t), _NEG_BIG, dtype=jnp.float32))
     l0 = _vary(jnp.zeros((B, H, t), dtype=jnp.float32))
@@ -93,7 +89,7 @@ def ring_attention(q, k, v, mesh, *, axis_name: str = "sp", causal: bool = True,
     data parallelism inside one jitted step."""
     from jax.sharding import PartitionSpec as P
 
-    from ray_tpu.parallel._compat import shard_map
+    from jax import shard_map
 
     batch_axes = tuple(a for a in ("dp", "fsdp") if a in mesh.shape)
     spec = P(batch_axes or None, axis_name, None, None)

@@ -12,10 +12,9 @@ from __future__ import annotations
 
 import functools
 
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.parallel._compat import shard_map
 from ray_tpu.parallel.ring_attention import reference_attention
 
 

@@ -63,6 +63,11 @@ class TrainWorker:
         from ray_tpu.utils.device import configure_jax
 
         configure_jax()
+        # a train worker runs jax (checkpoints alone need it): pay for the
+        # import here, while the group is still in step, not inside the
+        # loop's first report, where seconds of skew let one rank run ahead
+        import jax  # noqa: F401
+
         ckpt = Checkpoint.from_directory(checkpoint_path) if checkpoint_path else None
         context = TrainContext(
             world_rank=self.rank,
@@ -165,7 +170,11 @@ class JaxTrainer:
             [scaling.worker_resources() for _ in range(n)],
             strategy=scaling.placement_strategy,
         )
-        pg.ready(timeout=60)
+        if not pg.ready(timeout=60):
+            ray_tpu.remove_placement_group(pg)
+            raise TrainingFailedError(
+                f"no placement for {n} worker(s) of {scaling.worker_resources()} "
+                f"within 60s; the cluster has {ray_tpu.available_resources()} free")
         WorkerCls = ray_tpu.remote(TrainWorker)
         workers = [
             # per-worker bundle_index: options differ every iteration

@@ -240,7 +240,6 @@ CATALOG: dict[str, dict[str, dict]] = {
         "worker_ready": {"since": (1, 0), "fields": {
             "worker_id": "hex", "address": "(host, port)", "pid": "int",
             "language": "str (since 1.1)"}},
-        "get_lease_env": {"since": (1, 0), "fields": {"worker_id": "hex"}},
         "kill_worker": {"since": (1, 0), "fields": {"worker_id": "hex"}},
         "prepare_bundle": {"since": (1, 0), "fields": {
             "pg_id": "PGID", "bundle_index": "int", "resources": "dict"}},

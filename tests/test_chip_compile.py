@@ -1,0 +1,145 @@
+"""The main path's programs, compiled by the TPU's own compiler for a chip
+that is described and not attached (a v5e 2x2 host), at the widths
+``chip_smoke.py`` runs them: what interpret mode and the CPU backend cannot
+refuse — tiling, fast-memory limits, device memory — is refused here, at no
+chip time. Nothing runs, so these say nothing about results or speed.
+
+All in this one file, compiled in the test's own process: only one process
+may load the TPU's library, and it keeps it until it exits. The topology is
+described inside a fixture, after a test of this file has started — never
+while a module is imported."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from ray_tpu.models.llama import LlamaConfig, llama_init, make_train_step
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # whatever the compiler raises where it cannot
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """Shapes placed on the first described chip. The persistent cache is
+    off around these compiles: an entry written without a chip cannot be
+    read back without one, and the next run would warn and recompile."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    sharding = SingleDeviceSharding(topo.devices[0])
+
+    def placed(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+            tree)
+
+    yield placed
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _shape(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+# chip_smoke's train phase: Llama-2-7B heads, sequence 2048
+_QKV = _shape((1, 2048, 32, 128), jnp.bfloat16)
+
+
+def test_flash_forward_compiles(one_chip):
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    q = one_chip(_QKV)
+    compiled = jax.jit(
+        lambda q, k, v: flash_attention(q, k, v, interpret=False)
+    ).lower(q, q, q).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flash_backward_compiles(one_chip):
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, interpret=False)
+        return out.astype(jnp.float32).sum()
+
+    q = one_chip(_QKV)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, q).compile()
+    # forward, dq and dkv kernels
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def _engine_args(one_chip, n_layers: int):
+    """chip_smoke's serve phase: Llama-3-8B widths, batch 16, a 32k-token
+    bf16 pool in 16-token pages, 512-token sequences — depth cut to two
+    layers, which is what keeps the compile to seconds."""
+    cfg = dataclasses.replace(LlamaConfig.llama3_8b(), n_layers=n_layers)
+    params = jax.eval_shape(lambda: llama_init(jax.random.PRNGKey(0), cfg))
+    pool = _shape((n_layers, 2048, 16, cfg.n_kv_heads, cfg.head_dim),
+                  jnp.bfloat16)
+    return cfg, one_chip(params), one_chip(pool), one_chip(_shape((2,), jnp.uint32))
+
+
+def test_paged_decode_multi_compiles(one_chip):
+    from ray_tpu.llm.engine import paged_decode_multi
+
+    cfg, params, pool, key = _engine_args(one_chip, 2)
+    B, max_pages = 16, 512 // 16
+    i32 = one_chip(_shape((B,), jnp.int32))
+    compiled = paged_decode_multi.lower(
+        params, None, i32, i32, i32,
+        one_chip(_shape((B, max_pages), jnp.int32)), pool, pool,
+        one_chip(_shape((B,), jnp.bool_)), one_chip(_shape((B,), jnp.float32)),
+        key, cfg=cfg, n_steps=8).compile()
+    mem = compiled.memory_analysis()
+    # embedding + head 2.1 GB, two layers 0.87 GB, two pools 0.27 GB each
+    assert 3.0e9 < mem.argument_size_in_bytes < 4.0e9
+    # the hoisted qkv / gate-up concatenations: about 0.3 GB a layer
+    assert mem.temp_size_in_bytes < 2.0e9
+
+
+def test_paged_prefill_batch_compiles(one_chip):
+    from ray_tpu.llm.engine import paged_prefill_batch
+
+    cfg, params, pool, key = _engine_args(one_chip, 2)
+    N, Tp = 4, 256  # a wave of four 256-token prompts
+    compiled = paged_prefill_batch.lower(
+        params, None, one_chip(_shape((N,), jnp.int32)),
+        one_chip(_shape((N, Tp), jnp.int32)),
+        one_chip(_shape((N, Tp // 16), jnp.int32)), pool, pool,
+        one_chip(_shape((N,), jnp.int32)), one_chip(_shape((N,), jnp.float32)),
+        key, cfg=cfg).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.0e9
+
+
+def test_train_step_takes_the_flash_kernels(one_chip, monkeypatch):
+    """``attn_impl="auto"`` at sequence 2048 puts the Pallas forward and
+    backward kernels into the compiled step when the backend is a TPU. The
+    dispatch asks ``jax.default_backend()``, which here is the CPU: the test
+    answers for it, the program gains no option."""
+    import optax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(LlamaConfig.llama2_7b(), n_layers=1)
+    optimizer = optax.adamw(1e-3)
+    params = jax.eval_shape(lambda: llama_init(jax.random.PRNGKey(0), cfg))
+    opt_state = jax.eval_shape(optimizer.init, params)
+    step = make_train_step(cfg, optimizer, attn_impl="auto")
+    compiled = step.lower(
+        one_chip(params), one_chip(opt_state),
+        {"tokens": one_chip(_shape((1, 2048 + 1), jnp.int32))}).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3

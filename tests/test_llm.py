@@ -203,6 +203,57 @@ def test_engine_streaming_and_page_reclaim(tiny):
     assert free0 == free1, f"page leak: {free0} -> {free1}"
 
 
+@pytest.mark.parametrize("eos_id,n_pages", [(None, 41), (255, 43)])
+def test_engine_compiles_off_the_event_loop(tiny, eos_id, n_pages):
+    """A program's first use at new shapes compiles — tens of seconds at
+    real widths — and the loop that drives the engine is the one a replica
+    answers health probes on. So neither driver (planned: no EOS; reactive)
+    may compile an engine program on the loop thread, and the tokens are
+    those of the static-batch path all the same. The pool shape is this
+    test's own, so the programs are new to the process."""
+    import asyncio
+    import threading
+
+    import jax.monitoring
+
+    from ray_tpu.llm import ContinuousBatchingEngine, generate
+
+    cfg, params = tiny
+    compiled_on: dict[str, set] = {}
+
+    def on_duration(event, secs, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiled_on.setdefault(kw.get("fun_name"), set()).add(
+                threading.get_ident())
+
+    prompts = [[1, 2, 3, 4, 5], [7, 8, 9]]
+
+    async def go():
+        eng = ContinuousBatchingEngine(params, cfg, max_batch=3, page_size=8,
+                                       n_pages=n_pages, max_seq_len=72,
+                                       eos_id=eos_id)
+        await eng.start()
+        outs = await asyncio.gather(
+            *[eng.generate(p, max_tokens=20) for p in prompts])
+        await eng.stop()
+        return outs, threading.get_ident()
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        outs, loop_thread = _run(go())
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+    for program in ("jit(paged_prefill_batch)", "jit(paged_decode_multi)"):
+        assert compiled_on.get(program), f"{program} was not new: {compiled_on}"
+        assert loop_thread not in compiled_on[program], (
+            f"{program} compiled on the event loop")
+    ref = generate(params, cfg, prompts, max_new_tokens=20, temperature=0.0)
+    if eos_id is None:
+        assert outs == ref
+    else:  # stops at EOS, if the model happens to emit it
+        assert all(o == r[:len(o)] and len(o) >= 1 for o, r in zip(outs, ref))
+
+
 def test_engine_lora_multiplex(tiny):
     """Two adapters in ONE decode batch must produce their own outputs
     (and differ from base when the adapter is non-trivial)."""

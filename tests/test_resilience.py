@@ -362,3 +362,56 @@ def test_chaos_interval_killer_workload_completes():
             pass  # links already torn by the last kill
         cluster.shutdown()
         io.stop()
+
+
+# ------------------------------------------------------------- health loop
+@pytest.mark.parametrize("stall_s,alive", [
+    # the process that hosts the GCS did not run for 8 s (first seen: a whole
+    # four-chip host paused while four TPU backends started). It could not
+    # have taken a heartbeat meanwhile: its own pause is not a node's death
+    (8.0, True),
+    # the GCS ran all along and the node sent nothing for 8 s: dead
+    (0.0, False),
+])
+def test_health_loop_forgives_its_own_stall(stall_s, alive):
+    import asyncio
+
+    from ray_tpu.core.gcs import GcsServer, NodeInfo
+    from ray_tpu.utils.ids import NodeID
+
+    async def run():
+        gcs = GcsServer()
+        nid = NodeID.generate()
+        silent_for = 8.0  # > health_check_period_s * failure_threshold
+        gcs.nodes[nid] = NodeInfo(
+            node_id=nid, address=("127.0.0.1", 1), store_name="s",
+            resources_total={"CPU": 1.0}, resources_available={"CPU": 1.0},
+            last_heartbeat=time.monotonic() - silent_for)
+        gcs._forgive_own_stall(stall_s, time.monotonic())
+        task = asyncio.get_running_loop().create_task(gcs._health_loop())
+        await asyncio.sleep(gcs.cfg.health_check_period_s + 0.3)  # one sweep
+        gcs._stopping = True
+        task.cancel()
+        return gcs.nodes[nid].alive
+
+    assert asyncio.run(run()) is alive
+
+
+def test_stall_forgiveness_never_dates_a_heartbeat_ahead():
+    """The loop was late but this node's heartbeat had been handled: its
+    timestamp stays at or before now, so its death right afterwards is
+    detected no later than any other."""
+    from ray_tpu.core.gcs import GcsServer, NodeInfo
+    from ray_tpu.utils.ids import NodeID
+
+    gcs = GcsServer()
+    now = time.monotonic()
+    fresh, stale = NodeID.generate(), NodeID.generate()
+    for nid, age in ((fresh, 0.5), (stale, 9.0)):
+        gcs.nodes[nid] = NodeInfo(
+            node_id=nid, address=("127.0.0.1", 1), store_name="s",
+            resources_total={"CPU": 1.0}, resources_available={"CPU": 1.0},
+            last_heartbeat=now - age)
+    gcs._forgive_own_stall(8.0, now)
+    assert gcs.nodes[fresh].last_heartbeat == now
+    assert gcs.nodes[stale].last_heartbeat == pytest.approx(now - 1.0)
