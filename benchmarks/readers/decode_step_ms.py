@@ -1,0 +1,8 @@
+"""Device time of one decode step: the ``paged_decode_multi`` programs'
+time in the trace over the steps they ran."""
+from benchmarks.readers.decode_steps import steps_and_seconds
+
+
+def read(run: dict, program: str):
+    got = steps_and_seconds(run, program)
+    return None if got is None else 1e3 * got[1] / got[0]
