@@ -2050,7 +2050,6 @@ class CoreClient:
                 rec_r.record_sample(raw[2], raw[1], ring_ns, deser_ns,
                                     exec_ns, reply_ns, total)
         self._rec_published = stats.n
-        metrics.recorder_samples.set(stats.n)
         # histogram feed is bounded per flush (newest samples win): under
         # full load this is deliberate sampling, not a per-task tax
         fresh = stats.new_since_flush()

@@ -45,6 +45,20 @@ log = logging.getLogger(__name__)
 _current_worker = None  # set by Worker.start(): runtime_context introspection
 _profiler = None  # RT_WORKER_PROFILE_DIR cProfile, dumped on exit_worker
 
+_LEG_RING, _LEG_LOOP = {"leg": "ring"}, {"leg": "loop"}
+
+
+def _observe_lane(t_sub: int, t_pop: int, t_x0: int) -> None:
+    """The two waits of a lane call that runs on the event loop, once a
+    call: ``ring`` from the caller's pack to the keeper thread's pop,
+    ``loop`` from the pop until the loop got round to starting the call
+    — what a replica whose loop is held (the LLM engine's blocking
+    read) makes its callers wait."""
+    metrics.serve_lane_seconds.observe(max(0, t_pop - t_sub) * 1e-9,
+                                       _LEG_RING)
+    metrics.serve_lane_seconds.observe(max(0, t_x0 - t_pop) * 1e-9,
+                                       _LEG_LOOP)
+
 
 class Worker:
     def __init__(self):
@@ -713,6 +727,8 @@ class Worker:
         span = (self._fast_exec_span(trc, tid, mname, transport)
                 if self._trace_on else None)
         t_x0 = time.perf_counter_ns()
+        if t_sub:
+            _observe_lane(t_sub, t_pop, t_x0)
         try:
             if chaos.ENABLED:
                 chaos.point("worker.exec", name=mname, fast=1)
@@ -861,6 +877,8 @@ class Worker:
                 if self._trace_on else None)
         loop = asyncio.get_running_loop()
         t_x0 = time.perf_counter_ns()
+        if t_sub:
+            _observe_lane(t_sub, t_pop, t_x0)
         nchunks = 0
         agen = it = None
         pending = None  # in-flight agen.__anext__ carried between bursts

@@ -164,7 +164,7 @@ def _span_sink():
 
 def publish_decode_signals(engine) -> None:
     """Drain one engine's per-block speculative log into the stage
-    windows and refresh the decode-plane gauges — called by the decode
+    windows and refresh the tokens-in-flight gauge — called by the decode
     worker after each request and from ``headroom()`` probes, so the
     scheduler's admission signal, Prometheus, the dashboard LLM panel
     and the bench all read the SAME numbers."""
@@ -181,12 +181,6 @@ def publish_decode_signals(engine) -> None:
         count(spec_proposed=proposed, spec_accepted=accepted,
               spec_steps=n_steps, spec_tokens=emitted)
     metrics.llm_decode_tokens_in_flight.set(engine.tokens_in_flight())
-    if st["spec_proposed"]:
-        metrics.llm_spec_accept_rate.set(st["spec_accept_rate"])
-    win = stage_window(TOKENS_PER_STEP)
-    if win:
-        metrics.llm_tokens_per_step.set(
-            sum(win[-64:]) / len(win[-64:]) / 1000.0)
 
 
 def count(**deltas: int) -> None:

@@ -37,6 +37,7 @@ import numpy as np
 from ray_tpu.devtools import chaos
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.ops.basic import rms_norm, rope, rope_freqs
+from ray_tpu.utils import metrics, tracing
 
 
 def _lora_delta(h, loras, name, aid):
@@ -590,6 +591,15 @@ class _Request:
     # its slice of the engine's token-history mirror (the drafter's
     # context), which _reserve_slot/_emit_spec_block maintain
     spec: bool = False
+    # perf_counter_ns stamps: submit, prefill dispatched (or pages
+    # adopted), first token on the host. Observed once a request into
+    # rt_llm_queue_wait / prefill_wait / decode_seconds
+    t_submit: int = 0
+    t_admit: int = 0
+    t_first: int = 0
+    # (trace_id, span_id) of the submitter's sampled span, captured once
+    # in submit; None = no retro spans for this request
+    trace: tuple | None = None
 
 
 class EngineFull(Exception):
@@ -725,6 +735,12 @@ class ContinuousBatchingEngine:
         req = _Request(next(self._req_ids), list(prompt_tokens),
                        int(max_tokens), float(temperature), aid,
                        spec=self.spec_enable if spec is None else bool(spec))
+        return self._enqueue(req)
+
+    def _enqueue(self, req: _Request) -> int:
+        req.t_submit = time.perf_counter_ns()
+        if tracing.enabled():
+            req.trace = tracing.current()
         self._reqs[req.req_id] = req
         self.waiting.append(req)
         self._wake.set()
@@ -766,10 +782,7 @@ class ContinuousBatchingEngine:
                        int(max_tokens), float(temperature), aid,
                        spec=self.spec_enable if spec is None else bool(spec))
         req.prefilled = (k_stack, v_stack, int(first_token))
-        self._reqs[req.req_id] = req
-        self.waiting.append(req)
-        self._wake.set()
-        return req.req_id
+        return self._enqueue(req)
 
     def export_pages(self, req_id: int):
         """Page-export hook: seal a LIVE request's prompt KV pages into
@@ -956,22 +969,29 @@ class ContinuousBatchingEngine:
             self.hist[slot, :Tp] = req.prompt
         return slot
 
-    async def _call(self, fn, *args):
-        """Run one of the jitted programs above. Its first use at new
-        shapes compiles — tens of seconds at real widths — and the event
-        loop is where the replica also answers health probes and streams
-        other requests' tokens. So the first use lowers and compiles in a
-        thread, and the call that follows finds the executable in jit's
-        own cache. Lowering donates nothing: the pools stay readable
-        (``export_pages``) meanwhile. Steady state costs one set lookup."""
+    async def _call(self, ph, fn, *args):
+        """Run one of the jitted programs above, inside the caller's open
+        phase ``ph``. Its first use at new shapes compiles — tens of
+        seconds at real widths — and the event loop is where the replica
+        also answers health probes and streams other requests' tokens. So
+        the first use lowers and compiles in a thread, and the call that
+        follows finds the executable in jit's own cache. Lowering donates
+        nothing: the pools stay readable (``export_pages``) meanwhile.
+        Steady state costs one set lookup and never suspends; the compile
+        does, so it closes ``ph`` (a phase belongs to its thread), waits
+        under ``engine.compile`` and opens ``ph`` again for the call."""
         # what can differ between two calls under one engine: an array's
         # shape (pad and wave buckets) and the static step counts
         key = (fn, *(a if isinstance(a, int) else getattr(a, "shape", None)
                      for a in args[2:]))
         if key not in self._compiled:
-            await asyncio.get_running_loop().run_in_executor(
-                None, lambda: fn.lower(*args).compile())
+            ph.__exit__(None, None, None)
+            with tracing.phase("engine.compile", program=fn.__name__,
+                               shape=str(key[1:])):
+                await asyncio.get_running_loop().run_in_executor(
+                    None, lambda: fn.lower(*args).compile())
             self._compiled.add(key)
+            ph.__enter__()
         return fn(*args)
 
     _WAVE_BUCKETS = (1, 2, 4, 8, 16)
@@ -982,7 +1002,8 @@ class ContinuousBatchingEngine:
         not per request). Returns True if anything was admitted."""
         groups = await self._admit_dispatch()
         for reqs, first in groups:
-            first = np.asarray(first)  # ONE sync per group
+            with tracing.phase("engine.prefill_sync", prompts=len(reqs)):
+                first = np.asarray(first)  # ONE sync per group
             for j, req in enumerate(reqs):
                 self.next_tok[req.slot] = int(first[j])
                 if self.spec_enable:
@@ -997,20 +1018,22 @@ class ContinuousBatchingEngine:
         first-token device array)] per pad-bucket group."""
         groups: dict[int, list[_Request]] = {}
         adopted: list[_Request] = []
-        while self.waiting:
-            nxt = self.waiting[0]
-            if nxt.cancelled:
+        with tracing.phase("engine.admit", pad=0, wave=0) as ph:
+            while self.waiting:  # slot and page reservation, no device work
+                nxt = self.waiting[0]
+                if nxt.cancelled:
+                    self.waiting.pop(0)
+                    self._finish_stream(nxt)
+                    continue
+                if self._reserve_slot(nxt) is None:
+                    break
                 self.waiting.pop(0)
-                self._finish_stream(nxt)
-                continue
-            if self._reserve_slot(nxt) is None:
-                break
-            self.waiting.pop(0)
-            if nxt.prefilled is not None:
-                adopted.append(nxt)
-                continue
-            Tp_pad = -(-len(nxt.prompt) // self.PS) * self.PS
-            groups.setdefault(Tp_pad, []).append(nxt)
+                if nxt.prefilled is not None:
+                    adopted.append(nxt)
+                    continue
+                Tp_pad = -(-len(nxt.prompt) // self.PS) * self.PS
+                groups.setdefault(Tp_pad, []).append(nxt)
+            ph.set(prompts=len(adopted) + sum(map(len, groups.values())))
         out = []
         for req in adopted:
             # slot adoption (llm/disagg): the prompt KV was produced by a
@@ -1024,28 +1047,39 @@ class ContinuousBatchingEngine:
             rows = self.page_tables[req.slot, :n_cover].copy()
             self.kpool = scatter_pages(self.kpool, rows, k_stack)
             self.vpool = scatter_pages(self.vpool, rows, v_stack)
+            req.t_admit = time.perf_counter_ns()
             out.append(([req], np.asarray([first], np.int32)))
         for Tp_pad, reqs in groups.items():
             npages = Tp_pad // self.PS
             nb = next(b for b in self._WAVE_BUCKETS if b >= len(reqs)) \
                 if len(reqs) <= self._WAVE_BUCKETS[-1] else len(reqs)
-            toks = np.zeros((nb, Tp_pad), np.int32)
-            pages = np.zeros((nb, npages), np.int32)  # dummy rows: junk page
-            aids = np.zeros(nb, np.int32)
-            true_lens = np.ones(nb, np.int32)
-            temps = np.zeros(nb, np.float32)
-            for j, req in enumerate(reqs):
-                toks[j, :len(req.prompt)] = req.prompt
-                pages[j] = self.page_tables[req.slot, :npages]
-                aids[j] = req.adapter
-                true_lens[j] = len(req.prompt)
-                temps[j] = req.temperature
-            self._rng, sub = jax.random.split(self._rng)
-            first, self.kpool, self.vpool = await self._call(
-                paged_prefill_batch, self.params, self.loras,
-                jnp.asarray(aids), jnp.asarray(toks), jnp.asarray(pages),
-                self.kpool, self.vpool, jnp.asarray(true_lens),
-                jnp.asarray(temps), sub, self.cfg)
+            with tracing.phase("engine.admit", pad=Tp_pad, wave=nb,
+                               prompts=len(reqs)) as ph:
+                toks = np.zeros((nb, Tp_pad), np.int32)
+                pages = np.zeros((nb, npages), np.int32)  # dummy rows: junk
+                aids = np.zeros(nb, np.int32)
+                true_lens = np.ones(nb, np.int32)
+                temps = np.zeros(nb, np.float32)
+                for j, req in enumerate(reqs):
+                    toks[j, :len(req.prompt)] = req.prompt
+                    pages[j] = self.page_tables[req.slot, :npages]
+                    aids[j] = req.adapter
+                    true_lens[j] = len(req.prompt)
+                    temps[j] = req.temperature
+                self._rng, sub = jax.random.split(self._rng)
+                first, self.kpool, self.vpool = await self._call(
+                    ph, paged_prefill_batch, self.params, self.loras,
+                    jnp.asarray(aids), jnp.asarray(toks), jnp.asarray(pages),
+                    self.kpool, self.vpool, jnp.asarray(true_lens),
+                    jnp.asarray(temps), sub, self.cfg)
+            now = time.perf_counter_ns()
+            for req in reqs:
+                req.t_admit = now
+            metrics.llm_prefill_waves_total.inc()
+            metrics.llm_prefill_prompts_total.inc(len(reqs))
+            metrics.llm_prefill_true_tokens_total.inc(
+                sum(len(r.prompt) for r in reqs))
+            metrics.llm_prefill_padded_tokens_total.inc(nb * Tp_pad)
             out.append((reqs, first))
         return out
 
@@ -1053,13 +1087,41 @@ class ContinuousBatchingEngine:
         req.emitted += 1
         self.tokens_out += 1
         req.out.put_nowait(tok)
+        if req.emitted == 1:
+            self._stage(req, "engine::queue", req.t_submit, req.t_admit,
+                        metrics.llm_queue_wait_seconds, "queue")
+            req.t_first = time.perf_counter_ns()
+            self._stage(req, "engine::prefill", req.t_admit, req.t_first,
+                        metrics.llm_prefill_wait_seconds, "exec")
         if req.emitted >= req.max_tokens or (
                 self.eos_id is not None and tok == self.eos_id):
             req.finished = True
             req.cancelled = True  # finished: reclaim on the next sweep
+            self._stage(req, "engine::decode", req.t_first,
+                        time.perf_counter_ns(), metrics.llm_decode_seconds,
+                        "exec")
+            metrics.llm_decode_tokens_total.inc(req.emitted - 1)
             if req.slot < 0:
                 # planned mode already retired the slot; close the stream
                 self._finish_stream(req)
+
+    @staticmethod
+    def _stage(req: _Request, name: str, t0: int, t1: int, hist,
+               stage: str) -> None:
+        """One of a request's three stages, once it is over: into its
+        histogram and, if the submitter was inside a sampled span, as a
+        retro span under that span (the replica's ``::run``), so the
+        request's waterfall reaches down to the engine."""
+        hist.observe((t1 - t0) * 1e-9)
+        if req.trace is not None:
+            from ray_tpu.llm.disagg.telemetry import _span_sink
+
+            sink = _span_sink()
+            if sink is not None:
+                tracing.emit_retro(
+                    name, {"trace_id": req.trace[0],
+                           "parent_span_id": req.trace[1]},
+                    sink, (t1 - t0) * 1e-9, end_ns=t1, stage=stage)
 
     async def _loop(self):
         """Engine driver. Any exception here is fatal for the engine:
@@ -1124,26 +1186,92 @@ class ContinuousBatchingEngine:
                 return b
         return self.block_buckets[-1]
 
+    async def _dispatch_block(self, carry, planned: bool = False):
+        """Pick the block size and dispatch one fused decode block from
+        the device-resident ``carry`` (token, length), or from host state
+        when it is None. No host sync. Returns (K, tokens [K, B] on the
+        device, the next carry)."""
+        with tracing.phase("engine.decode_dispatch") as ph:
+            K = self._pick_block(planned)
+            active = np.array([r is not None for r in self.slot_req])
+            ph.set(steps=K, live=int(active.sum()))
+            self._rng, sub = jax.random.split(self._rng)
+            # .copy() on every host array that the loops later mutate
+            # (page_tables/seq_lens/next_tok/aids/temps): PJRT CPU
+            # zero-copies aligned numpy buffers into device arrays, so a
+            # retire/emission mutation while the async dispatch is still
+            # in flight would corrupt the program's view of them (race
+            # observed as garbage decode tokens under load).
+            if carry is None:
+                carry = (jnp.asarray(self.next_tok.copy()),
+                         jnp.asarray(self.seq_lens.copy()))
+            tok_d, lens_d = carry
+            toks, tok_d, lens_d, self.kpool, self.vpool = await self._call(
+                ph, paged_decode_multi, self.params, self.loras,
+                jnp.asarray(self.aids.copy()), tok_d, lens_d,
+                jnp.asarray(self.page_tables.copy()), self.kpool, self.vpool,
+                jnp.asarray(active), jnp.asarray(self.temps.copy()), sub,
+                self.cfg, K)
+        return K, toks, (tok_d, lens_d)
+
     def _emit_block(self, entry) -> None:
-        """Host-side emission of one synced decode block."""
+        """Host-side emission of one synced decode block. The read below
+        (``engine.block_sync``) blocks the calling thread — the replica's
+        event loop — until the device has finished the block: up to 64
+        steps, about 2.7 s at 42 ms a step. Nothing else of the replica
+        runs meanwhile: no new call starts, no delta leaves."""
         K, toks, slot_snapshot = entry
-        toks = np.asarray(toks)  # [K, B]; blocks until the device is done
+        with tracing.phase("engine.block_sync", steps=K):
+            toks = np.asarray(toks)  # [K, B]; blocks until the device is done
         self.steps += K
-        for i, req in enumerate(slot_snapshot):
-            if req is None:
-                continue
-            if self.slot_req[i] is req:
-                # planned mode may have retired + re-admitted this slot
-                # while the block was in flight; host per-slot state then
-                # belongs to the newcomer
-                self.seq_lens[i] += K
-            for k in range(K):
-                if req.cancelled:
-                    break  # finished/cancelled mid-block: discard rest
-                tok = int(toks[k, i])
+        with tracing.phase("engine.emit") as ph:
+            before = self.tokens_out
+            for i, req in enumerate(slot_snapshot):
+                if req is None:
+                    continue
                 if self.slot_req[i] is req:
-                    self.next_tok[i] = tok
-                self._emit(req, tok)
+                    # planned mode may have retired + re-admitted this
+                    # slot while the block was in flight; host per-slot
+                    # state then belongs to the newcomer
+                    self.seq_lens[i] += K
+                for k in range(K):
+                    if req.cancelled:
+                        break  # finished/cancelled mid-block: discard rest
+                    tok = int(toks[k, i])
+                    if self.slot_req[i] is req:
+                        self.next_tok[i] = tok
+                    self._emit(req, tok)
+            ph.set(tokens=self.tokens_out - before)
+
+    def _sweep(self) -> None:
+        """Give back the slot and pages of every finished or cancelled
+        request (only with no block in flight: its writes would land on
+        pages handed to someone else)."""
+        with tracing.phase("engine.free") as ph:
+            freed = 0
+            for i, req in enumerate(self.slot_req):
+                if req is not None and req.cancelled:
+                    self._free_slot(i)
+                    freed += 1
+            ph.set(freed=freed)
+
+    async def _yield(self) -> None:
+        """One turn of the event loop for everything else the replica
+        does — stream consumers pushing their deltas, new calls starting,
+        stats and health probes. Like ``engine.idle`` a phase that spans
+        a suspending await: nothing of this engine opens one meanwhile."""
+        with tracing.phase("engine.yield"):
+            await asyncio.sleep(0)
+
+    async def _idle(self, timeout: float = 1.0) -> None:
+        """No slot is live: sleep until a submit, a cancel or stop()
+        wakes the loop."""
+        with tracing.phase("engine.idle"):
+            self._wake.clear()
+            try:
+                await asyncio.wait_for(self._wake.wait(), timeout=timeout)
+            except asyncio.TimeoutError:
+                pass
 
     async def _loop_inner(self):
         if self.spec_enable:
@@ -1170,7 +1298,8 @@ class ContinuousBatchingEngine:
             kind, *rest = pending.pop(0)
             if kind == "prefill":
                 reqs, first = rest
-                first = np.asarray(first)
+                with tracing.phase("engine.prefill_sync", prompts=len(reqs)):
+                    first = np.asarray(first)
                 for j, req in enumerate(reqs):
                     if not req.cancelled:  # user-cancelled: stream closed
                         self._emit(req, int(first[j]))
@@ -1181,19 +1310,23 @@ class ContinuousBatchingEngine:
             # retire slots whose scheduled tokens are all dispatched; their
             # in-flight junk writes land on pages ordered BEFORE any new
             # prefill, so immediate reuse is safe (see paged_decode_multi)
-            for i, req in enumerate(self.slot_req):
-                if req is not None and (req.planned >= req.max_tokens
-                                        or req.cancelled):
-                    req.slot = -1  # emission closes the stream at finish
-                    self.slot_req[i] = None
-                    self.free_pages.extend(
-                        int(p) for p in self.page_tables[i] if p != 0)
-                    self.page_tables[i, :] = 0
-                    self.seq_lens[i] = 0
-                    if req.cancelled and not req.finished:
-                        # user-cancelled: no finish emission will ever
-                        # close this stream — close it here
-                        self._finish_stream(req)
+            with tracing.phase("engine.free") as ph:
+                freed = 0
+                for i, req in enumerate(self.slot_req):
+                    if req is not None and (req.planned >= req.max_tokens
+                                            or req.cancelled):
+                        req.slot = -1  # emission closes the stream at finish
+                        self.slot_req[i] = None
+                        self.free_pages.extend(
+                            int(p) for p in self.page_tables[i] if p != 0)
+                        self.page_tables[i, :] = 0
+                        self.seq_lens[i] = 0
+                        freed += 1
+                        if req.cancelled and not req.finished:
+                            # user-cancelled: no finish emission will ever
+                            # close this stream — close it here
+                            self._finish_stream(req)
+                ph.set(freed=freed)
             if self.waiting and any(r is None for r in self.slot_req):
                 groups = await self._admit_dispatch()
                 if groups:
@@ -1219,13 +1352,9 @@ class ContinuousBatchingEngine:
                     sync_oldest()
                     # yield between blocks: consumers must observe tokens
                     # in emission order, not one burst after the drain
-                    await asyncio.sleep(0)
+                    await self._yield()
                 carry = None
-                self._wake.clear()
-                try:
-                    await asyncio.wait_for(self._wake.wait(), timeout=1.0)
-                except asyncio.TimeoutError:
-                    pass
+                await self._idle()
                 continue
             # pace dispatch to emission + 2 entries: enough run-ahead to
             # hide the dispatch round trip under device compute, little
@@ -1236,31 +1365,12 @@ class ContinuousBatchingEngine:
             # use may compile) occupies the loop thread.
             while len(pending) >= 2:
                 sync_oldest()
-                await asyncio.sleep(0)
-            K = self._pick_block(planned=True)
-            self._rng, sub = jax.random.split(self._rng)
-            # .copy() on every host array that this loop later mutates
-            # (page_tables/seq_lens/next_tok/aids/temps): PJRT CPU
-            # zero-copies aligned numpy buffers into device arrays, so a
-            # retire/emission mutation while the async dispatch is still
-            # in flight would corrupt the program's view of them (race
-            # observed as garbage decode tokens under load).
-            if carry is None:
-                carry = (jnp.asarray(self.next_tok.copy()),
-                         jnp.asarray(self.seq_lens.copy()))
-            tok_d, lens_d = carry
-            active = np.array([r is not None for r in self.slot_req])
-            toks, tok_d, lens_d, self.kpool, self.vpool = await self._call(
-                paged_decode_multi, self.params, self.loras,
-                jnp.asarray(self.aids.copy()), tok_d, lens_d,
-                jnp.asarray(self.page_tables.copy()), self.kpool, self.vpool,
-                jnp.asarray(active), jnp.asarray(self.temps.copy()), sub,
-                self.cfg, K)
-            carry = (tok_d, lens_d)
+                await self._yield()
+            K, toks, carry = await self._dispatch_block(carry, planned=True)
             for r in live:
                 r.planned = min(r.max_tokens, r.planned + K)
             pending.append(("block", K, toks, list(self.slot_req)))
-            await asyncio.sleep(0)
+            await self._yield()
 
     async def _loop_reactive(self):
         # pipeline of dispatched-but-unsynced decode blocks. Depth 2:
@@ -1270,6 +1380,11 @@ class ContinuousBatchingEngine:
         # from host state only after the pipeline drains at admission
         # points (a new slot changes page_tables/active for the next
         # dispatch).
+        # The loop holds the event loop's thread nearly all the time:
+        # every _emit_block sleeps in np.asarray (phase engine.block_sync)
+        # until the device has finished a block of up to 64 steps (about
+        # 2.7 s at 42 ms a step), and only the _yield() at the bottom and
+        # the one after a wave let the replica's other coroutines run.
         pending: list = []
         carry = None  # (tok_dev, lens_dev) device-resident between blocks
 
@@ -1278,49 +1393,26 @@ class ContinuousBatchingEngine:
                 self._emit_block(pending.pop(0))
 
         while self._running:
-            for i, req in enumerate(self.slot_req):
-                if req is not None and req.cancelled and req.slot >= 0:
-                    if pending:
-                        break  # free only with no block in flight
-                    self._free_slot(i)
+            if not pending:  # free only with no block in flight
+                self._sweep()
             if self.waiting and any(r is None for r in self.slot_req):
                 drain()  # admission changes device-visible state
-                for i, req in enumerate(self.slot_req):
-                    if req is not None and req.cancelled:
-                        self._free_slot(i)
+                self._sweep()
                 if await self._admit_wave():
                     carry = None
                     # the wave just emitted each admitted request's
                     # prefill token: let consumers flush it (TTFC) before
                     # the next decode dispatch occupies the loop thread
-                    await asyncio.sleep(0)
-            active = np.array([r is not None for r in self.slot_req])
-            if not active.any():
+                    await self._yield()
+            if all(r is None for r in self.slot_req):
                 drain()
                 # idle, OR the head-of-queue request can't be admitted yet
                 # (pages still held elsewhere): either way we must yield —
                 # a bare continue would spin the loop without ever
                 # letting consumers/stop() run
-                self._wake.clear()
-                try:
-                    await asyncio.wait_for(self._wake.wait(), timeout=1.0)
-                except asyncio.TimeoutError:
-                    pass
+                await self._idle()
                 continue
-            K = self._pick_block()
-            self._rng, sub = jax.random.split(self._rng)
-            if carry is None:
-                tok_d = jnp.asarray(self.next_tok.copy())
-                lens_d = jnp.asarray(self.seq_lens.copy())
-            else:
-                tok_d, lens_d = carry
-            toks, tok_d, lens_d, self.kpool, self.vpool = await self._call(
-                paged_decode_multi, self.params, self.loras,
-                jnp.asarray(self.aids.copy()), tok_d, lens_d,
-                jnp.asarray(self.page_tables.copy()), self.kpool, self.vpool,
-                jnp.asarray(active), jnp.asarray(self.temps.copy()), sub,
-                self.cfg, K)
-            carry = (tok_d, lens_d)
+            K, toks, carry = await self._dispatch_block(carry)
             pending.append((K, toks, list(self.slot_req)))
             if len(pending) >= 2:
                 self._emit_block(pending.pop(0))
@@ -1330,7 +1422,7 @@ class ContinuousBatchingEngine:
                 drain()
                 carry = None
             # hand the loop to consumers/admitters every block
-            await asyncio.sleep(0)
+            await self._yield()
 
     # ------------------------------------------------------- speculative loop
     _SPEC_BUCKETS = (1, 2, 4)
@@ -1386,37 +1478,40 @@ class ContinuousBatchingEngine:
         positions hold is overwritten when they are legitimately
         decoded)."""
         S, toks, n_emit, n_prop, snapshot, spec_snap = entry
-        toks = np.asarray(toks)      # [S, B, k+1]; ONE sync per block
-        n_emit = np.asarray(n_emit)  # [S, B]
-        n_prop = np.asarray(n_prop)
+        with tracing.phase("engine.block_sync", steps=S):
+            toks = np.asarray(toks)      # [S, B, k+1]; ONE sync per block
+            n_emit = np.asarray(n_emit)  # [S, B]
+            n_prop = np.asarray(n_prop)
         self.steps += S
         self.spec_steps += S
         emitted = proposed = accepted = 0
         H = self.hist.shape[1]
-        for s in range(S):
-            for i, req in enumerate(snapshot):
-                if req is None:
-                    continue
-                ne = int(n_emit[s, i])
-                if ne <= 0:
-                    continue
-                live = self.slot_req[i] is req
-                if live:
-                    base = int(self.seq_lens[i])
-                    self.seq_lens[i] += ne
-                if spec_snap[i] and not req.cancelled:
-                    proposed += int(n_prop[s, i])
-                    accepted += ne - 1
-                for j in range(ne):
-                    if req.cancelled:
-                        break  # finished/cancelled mid-block: discard
-                    tok = int(toks[s, i, j])
+        with tracing.phase("engine.emit") as ph:
+            for s in range(S):
+                for i, req in enumerate(snapshot):
+                    if req is None:
+                        continue
+                    ne = int(n_emit[s, i])
+                    if ne <= 0:
+                        continue
+                    live = self.slot_req[i] is req
                     if live:
-                        self.next_tok[i] = tok
-                        if base + j + 1 < H:
-                            self.hist[i, base + j + 1] = tok
-                    emitted += 1
-                    self._emit(req, tok)
+                        base = int(self.seq_lens[i])
+                        self.seq_lens[i] += ne
+                    if spec_snap[i] and not req.cancelled:
+                        proposed += int(n_prop[s, i])
+                        accepted += ne - 1
+                    for j in range(ne):
+                        if req.cancelled:
+                            break  # finished/cancelled mid-block: discard
+                        tok = int(toks[s, i, j])
+                        if live:
+                            self.next_tok[i] = tok
+                            if base + j + 1 < H:
+                                self.hist[i, base + j + 1] = tok
+                        emitted += 1
+                        self._emit(req, tok)
+            ph.set(tokens=emitted)
         self.spec_proposed += proposed
         self.spec_accepted += accepted
         self._block_log.append((S, emitted, proposed, accepted))
@@ -1447,30 +1542,21 @@ class ContinuousBatchingEngine:
                 self._emit_spec_block(pending.pop(0))
 
         while self._running:
-            for i, req in enumerate(self.slot_req):
-                if req is not None and req.cancelled and req.slot >= 0:
-                    if pending:
-                        break  # free only with no block in flight
-                    self._free_slot(i)
+            if not pending:  # free only with no block in flight
+                self._sweep()
             if self.waiting and any(r is None for r in self.slot_req):
                 drain()  # admission changes device-visible state
-                for i, req in enumerate(self.slot_req):
-                    if req is not None and req.cancelled:
-                        self._free_slot(i)
+                self._sweep()
                 if await self._admit_wave():
                     carry = None
                     # flush the just-emitted prefill tokens (TTFC) before
                     # the next spec dispatch occupies the loop thread
-                    await asyncio.sleep(0)
+                    await self._yield()
             active = np.array([r is not None for r in self.slot_req])
             if not active.any():
                 drain()
                 carry = None
-                self._wake.clear()
-                try:
-                    await asyncio.wait_for(self._wake.wait(), timeout=1.0)
-                except asyncio.TimeoutError:
-                    pass
+                await self._idle()
                 continue
             # optimistic dispatch gate: a spec step emits 1..k+1 tokens,
             # so in-flight blocks COULD have satisfied a request long
@@ -1489,63 +1575,64 @@ class ContinuousBatchingEngine:
                 if pending:
                     self._emit_spec_block(pending.pop(0))
                 else:
-                    self._wake.clear()
-                    try:
-                        await asyncio.wait_for(self._wake.wait(),
-                                               timeout=0.05)
-                    except asyncio.TimeoutError:
-                        pass
+                    await self._idle(timeout=0.05)
                 if any(r is not None and r.cancelled
                        for r in self.slot_req):
                     drain()
                     carry = None
-                await asyncio.sleep(0)
+                await self._yield()
                 continue
-            self._rng, sub = jax.random.split(self._rng)
-            if carry is None:
-                carry = (jnp.asarray(self.next_tok.copy()),
-                         jnp.asarray(self.seq_lens.copy()),
-                         jnp.asarray(self.hist.copy()))
-                statics = None
-            if statics is None:
-                spec_ok = np.array([
-                    r is not None and not r.cancelled and r.spec
-                    and r.temperature <= 0 for r in self.slot_req])
-                statics = (jnp.asarray(self.aids.copy()),
-                           jnp.asarray(self.page_tables.copy()),
-                           jnp.asarray(active),
-                           jnp.asarray(spec_ok),
-                           jnp.asarray(self.temps.copy()),
-                           spec_ok)
-            aids_d, pt_d, act_d, sok_d, tmp_d, spec_ok = statics
-            tok_d, lens_d, hist_d = carry
+            with tracing.phase("engine.decode_dispatch",
+                               live=int(active.sum())) as ph:
+                self._rng, sub = jax.random.split(self._rng)
+                if carry is None:
+                    carry = (jnp.asarray(self.next_tok.copy()),
+                             jnp.asarray(self.seq_lens.copy()),
+                             jnp.asarray(self.hist.copy()))
+                    statics = None
+                if statics is None:
+                    spec_ok = np.array([
+                        r is not None and not r.cancelled and r.spec
+                        and r.temperature <= 0 for r in self.slot_req])
+                    statics = (jnp.asarray(self.aids.copy()),
+                               jnp.asarray(self.page_tables.copy()),
+                               jnp.asarray(active),
+                               jnp.asarray(spec_ok),
+                               jnp.asarray(self.temps.copy()),
+                               spec_ok)
+                aids_d, pt_d, act_d, sok_d, tmp_d, spec_ok = statics
+                tok_d, lens_d, hist_d = carry
+                if host_draft:
+                    drafts, dlens = self._host_drafts(spec_ok)
+                    ph.set(steps=1)
+                    (toks, n_emit, n_prop, tok_d, lens_d, self.kpool,
+                     self.vpool) = await self._call(
+                        ph, paged_decode_verify, self.params, self.loras,
+                        aids_d, tok_d, lens_d, jnp.asarray(drafts), pt_d,
+                        self.kpool, self.vpool, jnp.asarray(dlens), act_d,
+                        tmp_d, sub, self.cfg, k)
+                else:
+                    S = self._pick_spec_block([d for d in deficits if d > 0])
+                    ph.set(steps=S)
+                    if chaos.ENABLED:
+                        # "llm.spec_block": fires once per fused
+                        # speculative block — a seeded kill here dies
+                        # MID-speculative-window (accepted-but-unsynced
+                        # tokens in flight), the recovery window
+                        # tests/plans/spec_decode_kill exercises
+                        chaos.point("llm.spec_block", steps=S, k=k)
+                    (toks, n_emit, n_prop, tok_d, lens_d, hist_d,
+                     self.kpool, self.vpool) = await self._call(
+                        ph, paged_decode_spec, self.params, self.loras,
+                        aids_d, tok_d, lens_d, hist_d, pt_d, self.kpool,
+                        self.vpool, act_d, sok_d, tmp_d, sub, self.cfg, S,
+                        k, self.spec_ngram)
             if host_draft:
-                drafts, dlens = self._host_drafts(spec_ok)
-                (toks, n_emit, n_prop, tok_d, lens_d, self.kpool,
-                 self.vpool) = await self._call(
-                    paged_decode_verify, self.params, self.loras, aids_d,
-                    tok_d, lens_d, jnp.asarray(drafts), pt_d, self.kpool,
-                    self.vpool, jnp.asarray(dlens), act_d, tmp_d, sub,
-                    self.cfg, k)
                 self._emit_spec_block((1, toks[None], n_emit[None],
                                        n_prop[None], list(self.slot_req),
                                        spec_ok))
                 carry = None  # host state is authoritative per step
             else:
-                S = self._pick_spec_block([d for d in deficits if d > 0])
-                if chaos.ENABLED:
-                    # "llm.spec_block": fires once per fused speculative
-                    # block — a seeded kill here dies MID-speculative-
-                    # window (accepted-but-unsynced tokens in flight),
-                    # the recovery window tests/plans/spec_decode_kill
-                    # exercises
-                    chaos.point("llm.spec_block", steps=S, k=k)
-                (toks, n_emit, n_prop, tok_d, lens_d, hist_d, self.kpool,
-                 self.vpool) = await self._call(
-                    paged_decode_spec, self.params, self.loras, aids_d,
-                    tok_d, lens_d, hist_d, pt_d, self.kpool, self.vpool,
-                    act_d, sok_d, tmp_d, sub, self.cfg, S, k,
-                    self.spec_ngram)
                 carry = (tok_d, lens_d, hist_d)
                 pending.append((S, toks, n_emit, n_prop,
                                 list(self.slot_req), spec_ok))
@@ -1554,4 +1641,4 @@ class ContinuousBatchingEngine:
             if any(r is not None and r.cancelled for r in self.slot_req):
                 drain()
                 carry = None
-            await asyncio.sleep(0)
+            await self._yield()

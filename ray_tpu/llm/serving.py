@@ -202,9 +202,16 @@ class LLMEngineServer:
             self.engine.cancel(rid)  # no-op once finished
 
     def engine_stats(self) -> dict:
+        """Counters of this replica's engine. ``stages``: cumulative sum
+        and count of every stage family of ``utils/metrics.py`` in this
+        process — the engine loop's phases, a request's queue, prefill
+        and decode waits, the prefill counters, the lane's two legs."""
+        from ray_tpu.utils import metrics
+
         return {"steps": self.engine.steps, "tokens_out": self.engine.tokens_out,
                 "waiting": len(self.engine.waiting),
-                "free_pages": len(self.engine.free_pages)}
+                "free_pages": len(self.engine.free_pages),
+                "stages": metrics.stage_totals()}
 
     def device_report(self) -> dict:
         """The device this replica really serves from and its memory high

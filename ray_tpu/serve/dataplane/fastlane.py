@@ -50,7 +50,7 @@ class ReplicaLane:
     """
 
     __slots__ = ("actor_id", "_tmpl", "fast_calls", "rpc_calls",
-                 "traced_calls", "fast_streams", "rpc_streams")
+                 "fast_streams", "rpc_streams")
 
     METHOD = "handle_request"
     STREAM_METHOD = "handle_request_streaming"
@@ -60,9 +60,6 @@ class ReplicaLane:
         self._tmpl = None
         self.fast_calls = 0
         self.rpc_calls = 0
-        # sampled requests whose wire trace leg rode this lane (2.1):
-        # the proof the fast lane is no longer trace-invisible
-        self.traced_calls = 0
         # streams that rode "G" chunk records vs the per-item ObjectRef
         # fallback (wire 2.3)
         self.fast_streams = 0
@@ -85,11 +82,6 @@ class ReplicaLane:
             self.rpc_calls += 1
         else:
             self.fast_calls += 1
-            if getattr(core, "_trace_on", False):
-                from ray_tpu.utils import tracing
-
-                if tracing.current() is not None:
-                    self.traced_calls += 1
         return out
 
     def submit_stream(self, core, args: tuple):
@@ -109,7 +101,6 @@ class ReplicaLane:
 
     def stats(self) -> dict:
         return {"fast_calls": self.fast_calls, "rpc_calls": self.rpc_calls,
-                "traced_calls": self.traced_calls,
                 "fast_streams": self.fast_streams,
                 "rpc_streams": self.rpc_streams}
 

@@ -182,31 +182,89 @@ task_stage_us = Gauge(
     "rt_task_stage_us",
     "fast-lane per-stage latency percentiles over the recorder window (µs)",
     tag_keys=("stage", "q"))
-recorder_samples = Gauge(
-    "rt_recorder_samples", "per-task latency samples recorded (lifetime)")
 # --- LLM decode-plane signals (llm/disagg/telemetry.py) ---------------------
 # Published per decode-worker process; the disagg scheduler and serve
 # router admit on tokens-in-flight + page headroom instead of request
-# counts (cross-replica decode batching), and the spec-decode gauges are
-# the same numbers the bench's A/B arm reports.
+# counts (cross-replica decode batching).
 llm_decode_tokens_in_flight = Gauge(
     "rt_llm_decode_tokens_in_flight",
     "decode tokens still owed by this process's LLM engine")
-llm_spec_accept_rate = Gauge(
-    "rt_llm_spec_accept_rate",
-    "speculative-decode draft acceptance rate (lifetime ratio)")
-llm_tokens_per_step = Gauge(
-    "rt_llm_tokens_per_step",
-    "tokens emitted per fused decode step (recent-block mean)")
 # monotonic spec-decode cumulatives: the rollup plane's derived
 # llm_spec_accept_rate series is accepted/proposed per window slot —
-# restart-safe and windowable, unlike the lifetime-ratio gauge above
+# restart-safe and windowable (tokens per step and the acceptance rate
+# of recent blocks ride the ns="latency" stage windows)
 llm_spec_proposed_total = Counter(
     "rt_llm_spec_proposed_total",
     "draft tokens proposed to the fused spec-decode verify")
 llm_spec_accepted_total = Counter(
     "rt_llm_spec_accepted_total",
     "draft tokens the fused spec-decode verify accepted")
+# --- LLM engine and serve-lane stages (PR 25) -------------------------------
+# What a request waits for and what the engine's loop thread does, taken
+# inside the program: utils/tracing.phase feeds the phase histogram,
+# llm/engine.py the request waits and prefill counters, core/worker.py
+# the lane's two legs. LLMEngineServer.engine_stats()["stages"] hands
+# the cumulative sums and counts to whoever asks (the benchmark's
+# per-layer readers take deltas of them).
+_WAIT_BOUNDS = (0.001, 0.002, 0.005, 0.01, 0.02, 0.05, 0.1, 0.2, 0.5,
+                1.0, 2.0, 5.0, 10.0, 20.0, 50.0, 100.0)
+llm_engine_phase_seconds = Histogram(
+    "rt_llm_engine_phase_seconds",
+    "host phases of the LLM engine loop (engine.admit, engine.block_sync, ...)",
+    boundaries=(1e-5, 1e-4, 1e-3, 1e-2, 0.1, 1.0, 10.0), tag_keys=("phase",))
+llm_queue_wait_seconds = Histogram(
+    "rt_llm_queue_wait_seconds",
+    "engine submit to the dispatch of the request's prefill",
+    boundaries=_WAIT_BOUNDS)
+llm_prefill_wait_seconds = Histogram(
+    "rt_llm_prefill_wait_seconds",
+    "prefill dispatch to the request's first token on the host",
+    boundaries=_WAIT_BOUNDS)
+llm_decode_seconds = Histogram(
+    "rt_llm_decode_seconds",
+    "first token to last token of a finished request",
+    boundaries=_WAIT_BOUNDS)
+llm_decode_tokens_total = Counter(
+    "rt_llm_decode_tokens_total",
+    "tokens after the first of finished requests (rt_llm_decode_seconds' work)")
+llm_prefill_waves_total = Counter(
+    "rt_llm_prefill_waves_total", "batched prefill dispatches")
+llm_prefill_prompts_total = Counter(
+    "rt_llm_prefill_prompts_total", "prompts those dispatches prefilled")
+llm_prefill_true_tokens_total = Counter(
+    "rt_llm_prefill_true_tokens_total", "prompt tokens prefilled")
+llm_prefill_padded_tokens_total = Counter(
+    "rt_llm_prefill_padded_tokens_total",
+    "rows x pad of the prefill programs run, dummy rows included")
+serve_lane_seconds = Histogram(
+    "rt_serve_lane_seconds",
+    "actor-lane call: ring (submit to pop) and loop (pop to the call's "
+    "start on the event loop)",
+    boundaries=_WAIT_BOUNDS, tag_keys=("leg",))
+STAGE_FAMILIES = (
+    llm_engine_phase_seconds, llm_queue_wait_seconds,
+    llm_prefill_wait_seconds, llm_decode_seconds, llm_decode_tokens_total,
+    llm_prefill_waves_total, llm_prefill_prompts_total,
+    llm_prefill_true_tokens_total, llm_prefill_padded_tokens_total,
+    serve_lane_seconds)
+
+
+def stage_totals() -> dict:
+    """``{family: {tag value or "": {"sum", "count"}}}`` of the stage
+    families above, cumulative since process start (a counter has no
+    ``count``)."""
+    out = {}
+    for m in STAGE_FAMILIES:
+        if isinstance(m, Histogram):
+            out[m.name] = {(k[0][1] if k else ""):
+                           {"sum": m._sums.get(k, 0.0), "count": sum(c)}
+                           for k, c in list(m._counts.items())}
+        else:
+            out[m.name] = {(k[0][1] if k else ""): {"sum": v}
+                           for k, v in list(m._values.items())}
+    return out
+
+
 # serve SLO cumulatives: serve_slo_breach_fraction = breaches/requests
 # per window slot (boundary-free, unlike bucketing latencies at the SLO)
 serve_requests_total = Counter(

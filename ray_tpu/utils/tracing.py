@@ -10,8 +10,13 @@ packed fast-lane records and node-tunnel frames carry an optional
 cheap enough to leave on in production. Spans use OTel-shaped ids
 (128-bit trace, 64-bit span), ride the task-event pipeline into the GCS
 trace assembler (``state.get_trace`` / ``state.list_traces``) and the
-chrome timeline. If the ``opentelemetry`` API is installed and
-configured, spans are mirrored onto it as well.
+chrome timeline.
+
+Beside request spans stands :class:`phase`: a timed *host phase of a
+loop* (the LLM engine's admit / dispatch / sync / emit steps), always
+summed into one registry histogram and, while a ``jax.profiler`` trace
+is on, mirrored as a ``TraceAnnotation`` so that it lands in the same
+``.xplane.pb`` — and on the same clock — as the device's own lines.
 
 Enable with ``Config.tracing_enabled`` (env ``RT_TRACING_ENABLED=1``).
 Sampling is HEAD-BASED (``Config.trace_sample_rate``): the decision is
@@ -39,10 +44,12 @@ import contextvars
 import itertools
 import os
 import struct
+import sys
 import threading as _threading
 import time
 
 from ray_tpu.config import get_config
+from ray_tpu.utils import metrics
 
 _ctx: contextvars.ContextVar[tuple[str, str] | None] = contextvars.ContextVar(
     "rt_trace_ctx", default=None)
@@ -58,11 +65,6 @@ _ANCHOR_WALL_NS = time.time_ns()
 
 def _wall_s(t_perf_ns: int) -> float:
     return (_ANCHOR_WALL_NS + (t_perf_ns - _ANCHOR_PERF_NS)) / 1e9
-
-try:  # probe ONCE: a failed import per span would be a hot-path tax
-    from opentelemetry import trace as _otel_trace
-except Exception:  # pragma: no cover - otel genuinely optional
-    _otel_trace = None
 
 
 def enabled() -> bool:
@@ -226,18 +228,11 @@ class span:
         self.parent_span_id = ctx.get("parent_span_id")
         self.span_id = _gen_span_id()
         self._token = None
-        self._otel = None
 
     def __enter__(self):
         self._t0_ns = time.perf_counter_ns()
         self.start = _wall_s(self._t0_ns)
         self._token = _ctx.set((self.trace_id, self.span_id))
-        if _otel_trace is not None:
-            try:  # optional mirror onto a configured OTel SDK
-                self._otel = _otel_trace.get_tracer("ray_tpu").start_span(
-                    self.name)
-            except Exception:
-                self._otel = None
         return self
 
     def __exit__(self, exc_type, exc, tb):
@@ -245,11 +240,6 @@ class span:
         # same monotonic clock as __enter__: end >= start ALWAYS, and a
         # 2µs span reports 2µs instead of 0.0
         end = self.start + (time.perf_counter_ns() - self._t0_ns) / 1e9
-        if self._otel is not None:
-            try:
-                self._otel.end()
-            except Exception:  # raylint: disable=RT012 — optional exporter must never break user code
-                pass
         self.sink({
             "trace_id": self.trace_id,
             "span_id": self.span_id,
@@ -278,12 +268,13 @@ def emit_point(name: str, trace_ctx: dict, sink, **attributes) -> str:
 
 
 def emit_retro(name: str, trace_ctx: dict, sink, dur_s: float,
-               **attributes) -> str:
+               end_ns: int | None = None, **attributes) -> str:
     """Record a span for an operation that already FINISHED (duration
     known after the fact — the disagg telemetry shape, where stage
-    durations are measured first and reported once)."""
+    durations are measured first and reported once). It ended at
+    ``end_ns`` (a ``perf_counter_ns`` stamp), or just now."""
     span_id = _gen_span_id()
-    end = _wall_s(time.perf_counter_ns())
+    end = _wall_s(time.perf_counter_ns() if end_ns is None else end_ns)
     sink({
         "trace_id": trace_ctx["trace_id"], "span_id": span_id,
         "parent_span_id": trace_ctx.get("parent_span_id"),
@@ -305,6 +296,67 @@ def activate(trace_ctx: dict | None):
 def deactivate(token) -> None:
     if token is not None:
         _ctx.reset(token)
+
+
+# ------------------------------------------------------------ loop phases
+_annotation = None  # (TraceMe.is_enabled, TraceAnnotation) once jax is here
+
+
+def _profiler():
+    """jax's profiler hooks, only if the process imported jax already:
+    this module is imported by the driver, the router and every worker,
+    which stay off jax. Resolved once per process that has it."""
+    global _annotation
+    if _annotation is None:
+        prof = sys.modules.get("jax.profiler")
+        if prof is None:
+            return None
+        _annotation = (prof.TraceAnnotation.is_enabled, prof.TraceAnnotation)
+    return _annotation
+
+
+class phase:
+    """Context manager around one host phase of a loop (not a request).
+
+    Always: two ``perf_counter_ns`` reads and one observe into
+    ``rt_llm_engine_phase_seconds{phase=<name>}`` (its ``sum`` and bucket
+    counts are the seconds and the count). While a ``jax.profiler`` trace
+    is on: the same interval as a ``TraceAnnotation(name, **args)`` on
+    this thread's line of the trace's ``/host:CPU`` plane — one file and
+    one clock with the device's ``XLA Modules``. :meth:`set` adds what is
+    only known inside the phase (a block's step count, tokens emitted).
+
+    An annotation belongs to its thread: a phase must not span an
+    ``await`` that suspends while another phase could open."""
+
+    __slots__ = ("name", "args", "_ann", "_t0")
+
+    def __init__(self, name: str, **args):
+        self.name = name
+        self.args = args
+        self._ann = None
+
+    def set(self, **args) -> None:
+        if self._ann is not None:
+            self.args.update(args)  # a phase opened again keeps them
+            self._ann.set_metadata(**args)
+
+    def __enter__(self):
+        prof = _profiler()
+        if prof is not None and prof[0]():
+            self._ann = prof[1](self.name, **self.args)
+            self._ann.__enter__()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        dt = time.perf_counter_ns() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
+        metrics.llm_engine_phase_seconds.observe(dt * 1e-9,
+                                                 {"phase": self.name})
+        return False
 
 
 # -------------------------------------------------------- critical path

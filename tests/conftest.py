@@ -18,8 +18,103 @@ os.environ["RT_FORCE_CPU_DEVICES"] = "8"
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+import faulthandler  # noqa: E402
+import signal  # noqa: E402
+import threading  # noqa: E402
+
 import jax  # noqa: E402
 import pytest  # noqa: E402
+
+import ray_tpu  # noqa: E402
+
+# A time limit of its own for every test: for its set-up, its body and its
+# tear-down, each. The slowest test takes about half a minute; one still
+# running after TEST_LIMIT_S waits for something that will not come (a
+# lost reply behind a ``ray_tpu.get`` with no timeout), and until the
+# run's own limit it would hold its xdist worker and every test queued
+# behind it. At the limit every thread's stack goes to stderr and the
+# test fails with TestTimeLimit; the blocking calls of ray_tpu (get,
+# wait, a dataset's take_all) let the signal through. The cluster such a
+# test leaves behind has lost work somewhere, and the next tests of its
+# module would wait for the same thing: so before the next test the
+# runtime is shut down and started again as the module's fixture started
+# it (the fixtures hand out the ``ray_tpu`` module itself, which follows),
+# and the rest of the module gets AFTER_LIMIT_S a test. Those tests still
+# run and still count, and a module that cannot be saved costs the run
+# no more than TEST_LIMIT_S plus AFTER_LIMIT_S a test.
+TEST_LIMIT_S = 300
+AFTER_LIMIT_S = 30
+_over_limit: set = set()  # modules in which a test ran into its limit
+_restart: set = set()     # of those, the ones whose runtime is still the old
+_last_init = None         # (args, kwargs) of this process's last ray_tpu.init
+_real_stderr = None
+_init = ray_tpu.init
+
+
+def _recording_init(*args, **kwargs):
+    global _last_init
+    _last_init = (args, kwargs)
+    return _init(*args, **kwargs)
+
+
+ray_tpu.init = _recording_init
+
+
+class TestTimeLimit(Exception):
+    __test__ = False  # not a test class, whatever its name starts with
+
+
+def pytest_configure(config):
+    # output capture is not on yet: descriptor 2 is still the run's stderr
+    global _real_stderr
+    if _real_stderr is None:
+        _real_stderr = os.fdopen(os.dup(2), "w")
+
+
+def _limited(item, phase: str):
+    """Generator body of the three hook wrappers below: SIGALRM after the
+    item's limit, raised as TestTimeLimit inside whatever the phase runs."""
+    if threading.current_thread() is not threading.main_thread():
+        yield  # a signal handler belongs to the main thread
+        return
+    module = item.nodeid.split("::", 1)[0]
+    limit = AFTER_LIMIT_S if module in _over_limit else TEST_LIMIT_S
+
+    def on_alarm(signum, frame):
+        _over_limit.add(module)
+        _restart.add(module)
+        print(f"\n{item.nodeid}: {phase} still running after {limit} s",
+              file=_real_stderr, flush=True)
+        faulthandler.dump_traceback(file=_real_stderr, all_threads=True)
+        raise TestTimeLimit(f"{phase} still running after {limit} s")
+
+    before = signal.signal(signal.SIGALRM, on_alarm)
+    signal.setitimer(signal.ITIMER_REAL, limit)
+    try:
+        if (phase == "set-up" and module in _restart
+                and ray_tpu.is_initialized() and _last_init is not None):
+            _restart.discard(module)
+            ray_tpu.shutdown()
+            ray_tpu.init(*_last_init[0], **_last_init[1])
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, before)
+
+
+@pytest.hookimpl(hookwrapper=True)
+def pytest_runtest_setup(item):
+    yield from _limited(item, "set-up")
+
+
+@pytest.hookimpl(hookwrapper=True)
+def pytest_runtest_call(item):
+    yield from _limited(item, "test")
+
+
+@pytest.hookimpl(hookwrapper=True)
+def pytest_runtest_teardown(item):
+    yield from _limited(item, "tear-down")
 
 
 @pytest.fixture(scope="session")
