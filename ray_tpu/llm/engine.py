@@ -76,9 +76,13 @@ def _kv_write(pool, i, row, off, val):
 
 
 def _kv_read(pool, i, page_tables, B, MAXP, PS, KV, hd, dtype):
-    """Gather the decode attention window. int8 pools move HALF the HBM
-    bytes of bf16 through the page-table gather (the decode bottleneck
-    past ~64 slots); the scale gather is hd-times smaller — noise."""
+    """Gather the decode attention window ``[B, MAXP * PS, KV, hd]``: every
+    page of every slot's table, live or not. Since ``_gqa_attn`` contracts
+    q against these KV heads as they lie, this gather (a slice of the
+    layer's pool, the gather itself, one read by each contraction) is the
+    decode step's first bottleneck at any batch (PERF.md section 5). int8
+    pools move HALF the HBM bytes of bf16 through it; the scale gather is
+    hd-times smaller — noise."""
     if not isinstance(pool, dict):
         return pool[i][page_tables].reshape(B, MAXP * PS, KV, hd)
     q = pool["q"][i][page_tables].reshape(B, MAXP * PS, KV, hd)

@@ -20,17 +20,31 @@ from ray_tpu.ops.basic import rms_norm, rope, rope_freqs, swiglu
 
 
 def _gqa_attn(q, k, v, mask):
-    """Masked multi-head attention with GQA key/value repeat.
+    """Masked grouped-query attention. The H query heads are grouped over
+    the KV key/value heads (query head h reads KV head h // G, G = H // KV,
+    read from the shapes): a KV head's G query heads become G * Tq rows of
+    ONE matmul against that head's keys, and of one against its values, as
+    they lie — K and V are never written out to H heads. KV == H (G = 1) is
+    plain multi-head attention through the same two contractions.
+
+    The rows are merged before the contraction, not left to einsum as two
+    free axes: with one free axis the TPU compiler fuses scale, mask and
+    softmax into the contractions at prefill shapes as it did for the
+    repeated form; with (g, q) free it writes the float32 scores out a
+    second time (PERF.md section 6, PR 26).
     q: [B, Tq, H, d]; k/v: [B, Tk, KV, d]; mask: [B, Tq, Tk] (True=attend)."""
     B, Tq, H, d = q.shape
     KV = k.shape[2]
-    if KV != H:
-        k = jnp.repeat(k, H // KV, axis=2)
-        v = jnp.repeat(v, H // KV, axis=2)
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(jnp.float32(d))
-    scores = jnp.where(mask[:, None, :, :], scores, jnp.float32(-1e30))
+    G = H // KV
+    qg = (q.reshape(B, Tq, KV, G, d).transpose(0, 2, 3, 1, 4)
+          .reshape(B, KV, G * Tq, d))
+    scores = jnp.einsum("bkmd,bskd->bkms", qg, k) / jnp.sqrt(jnp.float32(d))
+    scores = jnp.where(mask[:, None, None],
+                       scores.reshape(B, KV, G, Tq, -1), jnp.float32(-1e30))
     w = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", w, v)
+    out = jnp.einsum("bkms,bskd->bkmd", w.reshape(B, KV, G * Tq, -1), v)
+    return (out.reshape(B, KV, G, Tq, d).transpose(0, 3, 1, 2, 4)
+            .reshape(B, Tq, H, d))
 
 
 def _layer_kv(layer, h, cfg):

@@ -386,3 +386,47 @@ def test_engine_int8_kv_matches_bf16_engine(tiny):
     assert agree / total >= 0.85, f"agreement {agree}/{total}"
     with pytest.raises(ValueError, match="kv_dtype"):
         ContinuousBatchingEngine(params, cfg, kv_dtype="fp4")
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("tq", [1, 5])
+@pytest.mark.parametrize("group", [1, 4, 8])
+def test_gqa_attn_matches_repeat_then_attend(group, tq, dtype):
+    """``_gqa_attn`` groups the query heads over the KV heads; the plain
+    float32 reference here repeats K and V to H heads and attends. Query
+    head h must read KV head h // G: every KV head's values sit around
+    their own level, so a wrong head order lands on a wrong level."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.llm.generation import _gqa_attn
+
+    B, KV, d, tk = 3, 2, 16, 11
+    H = KV * group
+    kq, kk, kv_ = jax.random.split(jax.random.PRNGKey(group * 10 + tq), 3)
+    q = jax.random.normal(kq, (B, tq, H, d), jnp.float32)
+    k = jax.random.normal(kk, (B, tk, KV, d), jnp.float32)
+    level = jnp.arange(KV, dtype=jnp.float32)[None, None, :, None]
+    v = level + 0.1 * jax.random.normal(kv_, (B, tk, KV, d), jnp.float32)
+    # decode-like: row j of slot b attends keys 0..pos[b]+j; the last three
+    # keys are masked for every row, and slot 0's first row attends one key
+    pos = jnp.asarray([0, 3, tk - 4 - (tq - 1)])
+    mask = (jnp.arange(tk)[None, None, :]
+            <= pos[:, None, None] + jnp.arange(tq)[None, :, None])
+    assert not bool(mask[:, :, -3:].any()) and int(mask[0, 0].sum()) == 1
+    q, k, v = (x.astype(dtype) for x in (q, k, v))
+
+    got = _gqa_attn(q, k, v, mask)
+    assert got.shape == (B, tq, H, d) and got.dtype == q.dtype
+
+    qf, kf, vf = (x.astype(jnp.float32) for x in (q, k, v))
+    kf, vf = (jnp.repeat(x, group, axis=2) for x in (kf, vf))
+    s = jnp.einsum("bqhd,bkhd->bhqk", qf, kf, precision="highest") / np.sqrt(d)
+    w = jax.nn.softmax(jnp.where(mask[:, None], s, -jnp.inf), axis=-1)
+    want = jnp.einsum("bhqk,bkhd->bqhd", w, vf, precision="highest")
+
+    got = np.asarray(got.astype(jnp.float32))
+    np.testing.assert_allclose(got, np.asarray(want),
+                               atol=1e-5 if dtype == "float32" else 3e-2)
+    levels = np.rint(got.mean(axis=-1))  # [B, tq, H]
+    assert (levels == np.arange(H) // group).all()

@@ -136,7 +136,17 @@ def test_shard_tasks_route_to_owning_node(rt, mesh):
 # ------------------------------------------------------------------- gc
 def test_shard_gc_releases_shm(rt, mesh):
     core = rt.get_core()
+    # earlier tests' shards are released asynchronously: a free that lands
+    # between this reading and the put below reads as a put that stored
+    # too little (512 bytes short, whenever allocation timing moved)
+    gc.collect()
     base = core.store.stats()["bytes_in_use"]
+    for _ in range(25):
+        time.sleep(0.2)
+        settled = core.store.stats()["bytes_in_use"]
+        if settled == base:
+            break
+        base = settled
     arr = np.random.randn(8, 65_536).astype(np.float32)  # 2MB
     sref = rt.put_sharded(
         jax.device_put(arr, NamedSharding(mesh, P("dp"))))
