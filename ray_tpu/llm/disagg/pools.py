@@ -47,6 +47,12 @@ from ray_tpu.llm.disagg.kv_plane import (
 
 
 def _resolve_params(model_config, params, params_fn):
+    # both workers ship and adopt K and V page stacks (kv_plane.py): a
+    # family whose cache is something else is refused before anything loads
+    programs = _engine.serving_programs(model_config)
+    if not programs.page_plane:
+        raise _engine.UnsupportedByModel(
+            "disaggregated serving (disagg/kv_plane.py)", programs.family)
     if params is None:
         params = params_fn() if params_fn is not None else None
     if params is None:

@@ -20,6 +20,12 @@ ideas onto XLA's static-shape world:
 * **LoRA multiplex** (ref: serve/multiplex.py): stacked low-rank adapters
   on the q/v projections, selected per slot — different requests in one
   decode batch can use different adapters (adapter 0 = base model).
+* **The cache is the model's.** The engine owns slots, pages, tables,
+  admission, blocks and the loop; what a page HOLDS is declared by the
+  model family's programs (``ServePrograms``, chosen by the config's type):
+  a K pool and a V pool for the Llama family, one latent pool for
+  ``models/mla_moe.py``. The engine carries it as one tuple of pools
+  (``self.cache``), hands it to every program and takes it back donated.
 """
 from __future__ import annotations
 
@@ -115,6 +121,22 @@ def scatter_pages(pool, page_ids, stack):
     return _scatter_pages_jit(pool, idx, stack)
 
 
+def _sample_tail(logits, temps, key):
+    """The sampling tail every serving program ends in: greedy where a row's
+    temperature is 0, a categorical draw elsewhere. logits: [N, V]; temps:
+    [N]. Returns [N] int32."""
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    def sampled():
+        # Threefry bits for [N, V] gumbels are NOT free at decode batch
+        # sizes — only pay when some row actually samples
+        s = jax.random.categorical(
+            key, logits / jnp.maximum(temps, 1e-6)[:, None]).astype(jnp.int32)
+        return jnp.where(temps > 0, s, greedy)
+
+    return jax.lax.cond(jnp.any(temps > 0), sampled, lambda: greedy)
+
+
 def _decode_body(params, loras, aids, tokens, pos, page_tables,
                  kpool, vpool, active, temps, key, cfg: LlamaConfig):
     """One decode step for every slot (masked where inactive).
@@ -171,16 +193,7 @@ def _decode_body(params, loras, aids, tokens, pos, page_tables,
     x = rms_norm(x, params["norm"]["scale"])
     logits = x[:, 0] @ params["lm_head"]["kernel"]
 
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-    def sampled():
-        # Threefry bits for [B, V] gumbels are NOT free at decode batch
-        # sizes — only pay when some slot actually samples
-        s = jax.random.categorical(
-            key, logits / jnp.maximum(temps, 1e-6)[:, None]).astype(jnp.int32)
-        return jnp.where(temps > 0, s, greedy)
-
-    next_tok = jax.lax.cond(jnp.any(temps > 0), sampled, lambda: greedy)
+    next_tok = _sample_tail(logits, temps, key)
     return jnp.where(active, next_tok, 0), kpool, vpool
 
 
@@ -253,15 +266,7 @@ def paged_prefill_batch(params, loras, aids, tokens, pages, kpool, vpool,
     last = jnp.take_along_axis(
         x, (true_lens - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
     logits = last @ params["lm_head"]["kernel"]  # [N, V]
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-    def sampled():
-        s = jax.random.categorical(
-            key, logits / jnp.maximum(temps, 1e-6)[:, None]).astype(jnp.int32)
-        return jnp.where(temps > 0, s, greedy)
-
-    toks = jax.lax.cond(jnp.any(temps > 0), sampled, lambda: greedy)
-    return toks, kpool, vpool
+    return _sample_tail(logits, temps, key), kpool, vpool
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnums=(5, 6))
@@ -319,15 +324,7 @@ def paged_prefill_suffix(params, loras, aids, tokens, pages, kpool, vpool,
     last = jnp.take_along_axis(
         x, (true_lens - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
     logits = last @ params["lm_head"]["kernel"]
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-    def sampled():
-        s = jax.random.categorical(
-            key, logits / jnp.maximum(temps, 1e-6)[:, None]).astype(jnp.int32)
-        return jnp.where(temps > 0, s, greedy)
-
-    toks = jax.lax.cond(jnp.any(temps > 0), sampled, lambda: greedy)
-    return toks, kpool, vpool
+    return _sample_tail(logits, temps, key), kpool, vpool
 
 
 # --------------------------------------------------------------- speculative
@@ -573,6 +570,67 @@ def make_kv_pools(cfg: LlamaConfig, page_size: int, n_pages: int,
     raise ValueError(f"unknown kv_dtype {kv_dtype!r}")
 
 
+class UnsupportedByModel(NotImplementedError):
+    """A feature of the engine that a model family's programs do not have,
+    refused by name (never a silent read of a pool that is not there)."""
+
+    def __init__(self, feature: str, family: str):
+        super().__init__(
+            f"{feature} is not supported for the {family!r} model family: "
+            f"it assumes a K pool and a V pool of n_kv_heads x head_dim")
+        self.feature, self.family = feature, family
+
+
+@dataclass(frozen=True)
+class ServePrograms:
+    """What the engine needs of a model family. The cache is a TUPLE of
+    pools ``make_cache`` builds; every program takes its members in place
+    (after ``page_tables`` in decode, after ``pages`` in prefill) and
+    returns them last, donated — so the engine threads ``*self.cache``
+    through without knowing what a page holds.
+
+    ``decode_multi(params, loras, aids, tokens, seq_lens, page_tables,
+    *cache, active, temps, key, cfg, n_steps) -> (rows [K, B + len(stats)],
+    tok, pos, *cache)``: a step's row holds the B tokens and then one int32
+    per name in ``stats`` (the model's own per-step sums, read at the
+    block's one sync). ``prefill_batch(params, loras, aids, tokens, pages,
+    *cache, true_lens, temps, key, cfg) -> (first [N], *cache)``. The rest
+    are the Llama family's and None elsewhere: the engine refuses what
+    needs them."""
+    family: str
+    make_cache: callable
+    decode_multi: callable
+    prefill_batch: callable
+    stats: tuple = ()
+    prefill_suffix: callable = None
+    decode_spec: callable = None
+    decode_verify: callable = None
+    lora: bool = False
+    int8_cache: bool = False
+    page_plane: bool = False   # export_pages / submit_prefilled (disagg)
+
+
+def serving_programs(cfg) -> ServePrograms:
+    """The programs that serve ``cfg``, by its type: no option chooses."""
+    if isinstance(cfg, LlamaConfig):
+        return LLAMA_PROGRAMS
+    from ray_tpu.models.mla_moe import MlaMoeConfig
+
+    if isinstance(cfg, MlaMoeConfig):
+        from ray_tpu.llm.mla_moe import PROGRAMS
+
+        return PROGRAMS
+    raise TypeError(f"no serving programs for a {type(cfg).__name__}")
+
+
+LLAMA_PROGRAMS = ServePrograms(
+    family="llama", make_cache=make_kv_pools,
+    decode_multi=paged_decode_multi, prefill_batch=paged_prefill_batch,
+    prefill_suffix=paged_prefill_suffix, decode_spec=paged_decode_spec,
+    decode_verify=paged_decode_verify, lora=True, int8_cache=True,
+    page_plane=True)
+
+
 @dataclass
 class _Request:
     req_id: int
@@ -635,8 +693,15 @@ class ContinuousBatchingEngine:
         # request, so short interactive requests stay low-latency while
         # long generations amortize dispatch 64x
         self.block_buckets = tuple(sorted(block_buckets))
-        self.kpool, self.vpool = make_kv_pools(cfg, page_size, n_pages,
-                                               kv_dtype)
+        self.programs = P = serving_programs(cfg)
+        for feature, asked, has in (
+                ("kv_dtype='int8'", kv_dtype == "int8", P.int8_cache),
+                ("lora_adapters", bool(lora_adapters), P.lora),
+                ("spec_enable", bool(spec_enable), P.decode_spec is not None)):
+            if asked and not has:
+                raise UnsupportedByModel(feature, P.family)
+        # the model's cache, one tuple of pools (see ServePrograms)
+        self.cache = tuple(P.make_cache(cfg, page_size, n_pages, kv_dtype))
         self.kv_dtype = kv_dtype or "native"
         self.n_pages = n_pages
         self.free_pages = list(range(1, n_pages))  # page 0 = junk page
@@ -688,6 +753,25 @@ class ContinuousBatchingEngine:
         # bounded per-block log the disagg telemetry drains:
         # (n_steps, emitted, proposed, accepted) per synced spec block
         self._block_log: collections.deque = collections.deque(maxlen=256)
+        # the model's own per-step sums of the last synced decode block
+        # (ServePrograms.stats), as a mean per step: annotates the next
+        # dispatch and admission phases
+        self._last_stats: dict = {}
+
+    @property
+    def kpool(self):
+        """The Llama family's K pool (``cache[0]``); other families have no
+        such pool and say so."""
+        return self._kv_pool(0)
+
+    @property
+    def vpool(self):
+        return self._kv_pool(1)
+
+    def _kv_pool(self, i: int):
+        if not self.programs.page_plane:
+            raise UnsupportedByModel("a K or V pool", self.programs.family)
+        return self.cache[i]
 
     # ----------------------------------------------------------- public API
     async def start(self):
@@ -763,6 +847,9 @@ class ContinuousBatchingEngine:
         ``len(prompt_tokens)`` with ``first_token`` — no prefill dispatch,
         no recompute. The stacks must cover ``ceil(len(prompt)/PS)`` pages
         of a pool with this engine's page_size and kv_dtype."""
+        if not self.programs.page_plane:
+            raise UnsupportedByModel("submit_prefilled (disagg adoption)",
+                                     self.programs.family)
         if self.error is not None:
             raise RuntimeError("engine loop died") from self.error
         if len(self.waiting) >= self.max_waiting:
@@ -797,6 +884,9 @@ class ContinuousBatchingEngine:
         them)."""
         from ray_tpu.llm.disagg.kv_plane import ship_pages
 
+        if not self.programs.page_plane:
+            raise UnsupportedByModel("export_pages (disagg/kv_plane.py)",
+                                     self.programs.family)
         req = self._reqs.get(req_id)
         if req is None or req.slot < 0:
             raise KeyError(f"request {req_id} is not holding a slot")
@@ -1049,8 +1139,8 @@ class ContinuousBatchingEngine:
             req.prefilled = None  # release the host copies after scatter
             n_cover = -(-len(req.prompt) // self.PS)
             rows = self.page_tables[req.slot, :n_cover].copy()
-            self.kpool = scatter_pages(self.kpool, rows, k_stack)
-            self.vpool = scatter_pages(self.vpool, rows, v_stack)
+            self.cache = (scatter_pages(self.cache[0], rows, k_stack),
+                          scatter_pages(self.cache[1], rows, v_stack))
             req.t_admit = time.perf_counter_ns()
             out.append(([req], np.asarray([first], np.int32)))
         for Tp_pad, reqs in groups.items():
@@ -1058,7 +1148,7 @@ class ContinuousBatchingEngine:
             nb = next(b for b in self._WAVE_BUCKETS if b >= len(reqs)) \
                 if len(reqs) <= self._WAVE_BUCKETS[-1] else len(reqs)
             with tracing.phase("engine.admit", pad=Tp_pad, wave=nb,
-                               prompts=len(reqs)) as ph:
+                               prompts=len(reqs), **self._last_stats) as ph:
                 toks = np.zeros((nb, Tp_pad), np.int32)
                 pages = np.zeros((nb, npages), np.int32)  # dummy rows: junk
                 aids = np.zeros(nb, np.int32)
@@ -1071,11 +1161,12 @@ class ContinuousBatchingEngine:
                     true_lens[j] = len(req.prompt)
                     temps[j] = req.temperature
                 self._rng, sub = jax.random.split(self._rng)
-                first, self.kpool, self.vpool = await self._call(
-                    ph, paged_prefill_batch, self.params, self.loras,
+                first, *cache = await self._call(
+                    ph, self.programs.prefill_batch, self.params, self.loras,
                     jnp.asarray(aids), jnp.asarray(toks), jnp.asarray(pages),
-                    self.kpool, self.vpool, jnp.asarray(true_lens),
+                    *self.cache, jnp.asarray(true_lens),
                     jnp.asarray(temps), sub, self.cfg)
+                self.cache = tuple(cache)
             now = time.perf_counter_ns()
             for req in reqs:
                 req.t_admit = now
@@ -1198,7 +1289,7 @@ class ContinuousBatchingEngine:
         with tracing.phase("engine.decode_dispatch") as ph:
             K = self._pick_block(planned)
             active = np.array([r is not None for r in self.slot_req])
-            ph.set(steps=K, live=int(active.sum()))
+            ph.set(steps=K, live=int(active.sum()), **self._last_stats)
             self._rng, sub = jax.random.split(self._rng)
             # .copy() on every host array that the loops later mutate
             # (page_tables/seq_lens/next_tok/aids/temps): PJRT CPU
@@ -1210,12 +1301,13 @@ class ContinuousBatchingEngine:
                 carry = (jnp.asarray(self.next_tok.copy()),
                          jnp.asarray(self.seq_lens.copy()))
             tok_d, lens_d = carry
-            toks, tok_d, lens_d, self.kpool, self.vpool = await self._call(
-                ph, paged_decode_multi, self.params, self.loras,
+            toks, tok_d, lens_d, *cache = await self._call(
+                ph, self.programs.decode_multi, self.params, self.loras,
                 jnp.asarray(self.aids.copy()), tok_d, lens_d,
-                jnp.asarray(self.page_tables.copy()), self.kpool, self.vpool,
+                jnp.asarray(self.page_tables.copy()), *self.cache,
                 jnp.asarray(active), jnp.asarray(self.temps.copy()), sub,
                 self.cfg, K)
+            self.cache = tuple(cache)
         return K, toks, (tok_d, lens_d)
 
     def _emit_block(self, entry) -> None:
@@ -1227,6 +1319,9 @@ class ContinuousBatchingEngine:
         K, toks, slot_snapshot = entry
         with tracing.phase("engine.block_sync", steps=K):
             toks = np.asarray(toks)  # [K, B]; blocks until the device is done
+        if self.programs.stats:  # [K, B + stats]: the model's sums ride along
+            self._observe_stats(toks[:, self.B:])
+            toks = toks[:, :self.B]
         self.steps += K
         with tracing.phase("engine.emit") as ph:
             before = self.tokens_out
@@ -1246,6 +1341,15 @@ class ContinuousBatchingEngine:
                         self.next_tok[i] = tok
                     self._emit(req, tok)
             ph.set(tokens=self.tokens_out - before)
+
+    def _observe_stats(self, rows) -> None:
+        """A synced block's per-step sums of the model's programs
+        (``ServePrograms.stats``, [K, n] int32) into the ``rt_llm_*_total``
+        counter of each name, and as means per step for the next phases'
+        annotations."""
+        for name, total in zip(self.programs.stats, rows.sum(axis=0)):
+            metrics.LLM_MODEL_STATS[name].inc(int(total))
+            self._last_stats[name] = round(float(total) / rows.shape[0], 2)
 
     def _sweep(self) -> None:
         """Give back the slot and pages of every finished or cancelled
@@ -1609,12 +1713,12 @@ class ContinuousBatchingEngine:
                 if host_draft:
                     drafts, dlens = self._host_drafts(spec_ok)
                     ph.set(steps=1)
-                    (toks, n_emit, n_prop, tok_d, lens_d, self.kpool,
-                     self.vpool) = await self._call(
-                        ph, paged_decode_verify, self.params, self.loras,
-                        aids_d, tok_d, lens_d, jnp.asarray(drafts), pt_d,
-                        self.kpool, self.vpool, jnp.asarray(dlens), act_d,
-                        tmp_d, sub, self.cfg, k)
+                    (toks, n_emit, n_prop, tok_d, lens_d,
+                     *cache) = await self._call(
+                        ph, self.programs.decode_verify, self.params,
+                        self.loras, aids_d, tok_d, lens_d,
+                        jnp.asarray(drafts), pt_d, *self.cache,
+                        jnp.asarray(dlens), act_d, tmp_d, sub, self.cfg, k)
                 else:
                     S = self._pick_spec_block([d for d in deficits if d > 0])
                     ph.set(steps=S)
@@ -1626,11 +1730,12 @@ class ContinuousBatchingEngine:
                         # tests/plans/spec_decode_kill exercises
                         chaos.point("llm.spec_block", steps=S, k=k)
                     (toks, n_emit, n_prop, tok_d, lens_d, hist_d,
-                     self.kpool, self.vpool) = await self._call(
-                        ph, paged_decode_spec, self.params, self.loras,
-                        aids_d, tok_d, lens_d, hist_d, pt_d, self.kpool,
-                        self.vpool, act_d, sok_d, tmp_d, sub, self.cfg, S,
+                     *cache) = await self._call(
+                        ph, self.programs.decode_spec, self.params,
+                        self.loras, aids_d, tok_d, lens_d, hist_d, pt_d,
+                        *self.cache, act_d, sok_d, tmp_d, sub, self.cfg, S,
                         k, self.spec_ngram)
+                self.cache = tuple(cache)
             if host_draft:
                 self._emit_spec_block((1, toks[None], n_emit[None],
                                        n_prop[None], list(self.slot_req),

@@ -175,7 +175,15 @@ def pad_prompts(prompts: list[list[int]], pad_id: int = 0):
 def generate(params, cfg: LlamaConfig, prompts: list[list[int]],
              max_new_tokens: int = 32, temperature: float = 0.0,
              seed: int = 0) -> list[list[int]]:
-    """User-facing batched generate over ragged token prompts."""
+    """User-facing batched generate over ragged token prompts. The
+    static-batch path is the Llama family's; other families are served by
+    the continuous-batching engine (``llm/engine.py``) and refused here by
+    name."""
+    if not isinstance(cfg, LlamaConfig):
+        from ray_tpu.llm.engine import UnsupportedByModel
+
+        raise UnsupportedByModel("the static-batch generate() path",
+                                 type(cfg).__name__)
     tokens, pad_lens = pad_prompts(prompts)
     out = generate_tokens(
         params, tokens, pad_lens, cfg, max_new_tokens,

@@ -1,0 +1,153 @@
+"""The serving programs of ``models/mla_moe.py`` for the continuous-batching
+engine: same slots, pages, tables and block pipeline as the Llama programs
+of ``llm/engine.py``, another cache and another layer.
+
+* **The cache is one latent pool** ``[L, P, PS, r + rope]``: a token leaves
+  its normed latent ``c`` and its one rotary key ``k_rope`` per layer (576
+  numbers at the published widths, against 2 x KV x hd).
+* **Two attention paths over that one cache.** Prefill expands the fresh
+  rows to per-head keys and values once for all the prompt's queries
+  (``mla_attend_expanded``); decode absorbs ``wkv_b`` into the query and
+  the output and reads the window as it lies (``mla_attend_absorbed``).
+* **The expert layer** (``parallel/moe.py``) sees 32 tokens a decode step
+  (bound by the bytes of the experts they touch) and a whole wave's prompt
+  tokens in prefill (bound by the MXU); dead slots and prompt padding are
+  routed nowhere.
+* **What the experts did rides back with the tokens.** A decode step's row
+  is ``[B tokens | STATS]``: routed assignments, distinct experts touched,
+  the largest expert's load and the expert slots they are a share of, each
+  summed over the expert layers — read at the block's one sync, no second
+  device->host read.
+
+LoRA, int8 pools, speculative decoding, suffix prefill and page export
+assume K and V pools; ``llm/engine.py`` refuses them for this family by name.
+"""
+from __future__ import annotations
+
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.llm.engine import ServePrograms, _sample_tail
+from ray_tpu.models.mla_moe import (
+    MlaMoeConfig, mla_attend_absorbed, mla_attend_expanded, mla_moe_ffn,
+    mla_project)
+from ray_tpu.ops.basic import rms_norm, rope_freqs
+
+# extra int32 columns of a decode step's token row, each summed over the
+# expert layers: rows routed to held experts, distinct held experts that got
+# any, the largest expert's rows, and held experts x expert layers (what
+# "touched" is a share of)
+STATS = ("moe_assignments", "moe_experts_touched", "moe_max_load",
+         "moe_expert_slots")
+
+
+def make_latent_pool(cfg: MlaMoeConfig, page_size: int, n_pages: int,
+                     kv_dtype: str | None):
+    """The model's cache: a 1-tuple holding the latent pool."""
+    if kv_dtype not in (None, "native", "bf16"):
+        raise ValueError(f"unknown kv_dtype {kv_dtype!r}")
+    dtype = jnp.bfloat16 if kv_dtype == "bf16" else jnp.dtype(cfg.dtype)
+    return (jnp.zeros((cfg.n_layers, n_pages, page_size, cfg.latent_width),
+                      dtype),)
+
+
+def _load_stats(loads):
+    """A step's rows per held expert, one [held] array an expert layer ->
+    the STATS sums."""
+    if not loads:
+        return jnp.zeros((len(STATS),), jnp.int32)
+    load = jnp.stack(loads)
+    return jnp.stack([load.sum(), (load > 0).sum(), load.max(axis=-1).sum(),
+                      jnp.asarray(load.size)]).astype(jnp.int32)
+
+
+def _decode_body(params, tokens, pos, page_tables, pool, active, temps, key,
+                 cfg: MlaMoeConfig):
+    """One decode step for every slot (masked where inactive), absorbed
+    attention over each slot's pages. Returns (next_tok [B], pool, stats)."""
+    B = tokens.shape[0]
+    L, P, PS, W = pool.shape
+    MAXP = page_tables.shape[1]
+    cos, sin = rope_freqs(cfg.qk_rope_head_dim, cfg.max_seq_len, cfg.rope_theta)
+    positions = pos[:, None]
+    row = jnp.take_along_axis(page_tables, (pos // PS)[:, None], axis=1)[:, 0]
+    off = pos % PS
+    mask = jnp.arange(MAXP * PS)[None, None, :] <= pos[:, None, None]
+    loads = []
+    x = params["tok"]["embedding"][tokens][:, None, :]
+    for i in range(cfg.n_layers):
+        layer = params[f"layers_{i}"]
+        h = rms_norm(x, layer["attn_norm"]["scale"])
+        q, latent = mla_project(layer, h, cos, sin, positions, cfg)
+        pool = pool.at[i, row, off].set(latent[:, 0].astype(pool.dtype))
+        window = pool[i][page_tables].reshape(B, MAXP * PS, W).astype(x.dtype)
+        x = x + mla_attend_absorbed(layer, q, window, mask, cfg
+                                    ) @ layer["wo"]["kernel"]
+        x, load = mla_moe_ffn(layer, x, cfg, valid=active[:, None])
+        if load is not None:
+            loads.append(load)
+    x = rms_norm(x, params["norm"]["scale"])
+    logits = x[:, 0] @ params["lm_head"]["kernel"]
+    next_tok = _sample_tail(logits, temps, key)
+    return jnp.where(active, next_tok, 0), pool, _load_stats(loads)
+
+
+@partial(jax.jit, static_argnames=("cfg", "n_steps"), donate_argnums=(6,))
+def mla_moe_decode_multi(params, loras, aids, tokens, seq_lens, page_tables,
+                         pool, active, temps, key, cfg: MlaMoeConfig,
+                         n_steps: int):
+    """``n_steps`` fused decode steps as one device program: the contract of
+    ``engine.paged_decode_multi`` with one latent pool in place of the K
+    and V pools, and rows of ``[B tokens | STATS]``. ``loras``/``aids`` are
+    the engine's (None / zeros here: refused at construction)."""
+    def step(carry, k):
+        tok, pos, pool = carry
+        nxt, pool, stats = _decode_body(
+            params, tok, pos, page_tables, pool, active, temps,
+            jax.random.fold_in(key, k), cfg)
+        return (nxt, pos + 1, pool), jnp.concatenate([nxt, stats])
+
+    (tok, pos, pool), rows = jax.lax.scan(
+        step, (tokens, seq_lens, pool), jnp.arange(n_steps))
+    return rows, tok, pos, pool
+
+
+@partial(jax.jit, static_argnames=("cfg",), donate_argnums=(5,))
+def mla_moe_prefill_batch(params, loras, aids, tokens, pages, pool,
+                          true_lens, temps, key, cfg: MlaMoeConfig):
+    """Prefill a whole admission wave as one batched forward: the contract
+    of ``engine.paged_prefill_batch``. Attention is over the wave's FRESH
+    cache rows, expanded; what it writes to the pool is what decode reads
+    back absorbed. Returns (first tokens [N], pool)."""
+    N, Tp = tokens.shape
+    L, P, PS, W = pool.shape
+    cos, sin = rope_freqs(cfg.qk_rope_head_dim, cfg.max_seq_len, cfg.rope_theta)
+    idx = jnp.arange(Tp)
+    positions = jnp.broadcast_to(idx[None, :], (N, Tp))
+    mask = jnp.broadcast_to(idx[None, :, None] >= idx[None, None, :],
+                            (N, Tp, Tp))
+    rows = pages[:, idx // PS]
+    offs = jnp.broadcast_to(idx % PS, (N, Tp))
+    valid = idx[None, :] < true_lens[:, None]  # padding is routed nowhere
+    x = params["tok"]["embedding"][tokens]
+    for i in range(cfg.n_layers):
+        layer = params[f"layers_{i}"]
+        h = rms_norm(x, layer["attn_norm"]["scale"])
+        q, latent = mla_project(layer, h, cos, sin, positions, cfg)
+        pool = pool.at[i, rows, offs].set(latent.astype(pool.dtype))
+        x = x + mla_attend_expanded(layer, q, latent, mask, cfg
+                                    ) @ layer["wo"]["kernel"]
+        x, _ = mla_moe_ffn(layer, x, cfg, valid=valid)
+    x = rms_norm(x, params["norm"]["scale"])
+    last = jnp.take_along_axis(
+        x, (true_lens - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
+    logits = last @ params["lm_head"]["kernel"]
+    return _sample_tail(logits, temps, key), pool
+
+
+PROGRAMS = ServePrograms(
+    family="mla_moe", make_cache=make_latent_pool,
+    decode_multi=mla_moe_decode_multi, prefill_batch=mla_moe_prefill_batch,
+    stats=STATS)

@@ -84,7 +84,10 @@ class LLMEngineServer:
     engine the reference delegates to, vllm_engine.py:95 — owned here).
     Requests join the running decode batch at step granularity; responses
     can stream token-by-token; "model" selects a LoRA adapter
-    (ref: serve/multiplex.py model multiplexing)."""
+    (ref: serve/multiplex.py model multiplexing). The model family is the
+    config's type (``LlamaConfig``, ``MlaMoeConfig``): the engine takes its
+    programs and its cache from it, and this deployment is the same call
+    for both."""
 
     def __init__(self, model_config, params=None, params_fn=None, *,
                  max_batch: int = 8, page_size: int = 16, n_pages: int = 512,
@@ -99,9 +102,9 @@ class LLMEngineServer:
         if params is None:
             import jax
 
-            from ray_tpu.models.llama import llama_init
+            from ray_tpu.models import init_fn
 
-            params = llama_init(jax.random.PRNGKey(0), model_config)
+            params = init_fn(model_config)(jax.random.PRNGKey(0), model_config)
         from ray_tpu.llm.engine import ContinuousBatchingEngine
 
         self.engine = ContinuousBatchingEngine(

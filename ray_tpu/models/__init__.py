@@ -1,3 +1,14 @@
-"""Model zoo: flagship Llama-family transformer, ResNet, MLP."""
+"""Model zoo: the Llama family, the MLA + sparse-expert family, ResNet, MLP."""
 
 from ray_tpu.models.llama import LlamaConfig, llama_forward, llama_init  # noqa: F401
+from ray_tpu.models.mla_moe import (  # noqa: F401
+    MlaMoeConfig, mla_moe_forward, mla_moe_init)
+
+
+def init_fn(cfg):
+    """The seeded ``init(key, cfg)`` of a config's family, by its type."""
+    if isinstance(cfg, LlamaConfig):
+        return llama_init
+    if isinstance(cfg, MlaMoeConfig):
+        return mla_moe_init
+    raise TypeError(f"no model for a {type(cfg).__name__}")

@@ -5,9 +5,14 @@ dict keyed so `parallel.sharding.PartitionRules.llama()` maps every weight
 to its TP/FSDP axes by path regex, attention dispatches to
 plain/flash/ring/ulysses by mesh (ops/attention.py), each block is wrapped
 in jax.checkpoint (remat) to trade FLOPs for HBM, and optional MoE layers
-use the expert-parallel dispatch from parallel/moe.py. Matches the model
-families the reference serves through vLLM (Llama-2/3 in BASELINE.json
-north-star configs) but as a native JAX program.
+(``n_experts`` > 0, every ``moe_every``-th layer) use the Switch top-1,
+capacity-dropping, two-matrix branch of parallel/moe.py (``moe_ffn``) —
+reachable from ``llama_forward`` in training only: the serving programs of
+llm/engine.py have no expert path for this family. The expert layer that
+IS served (sigmoid top-k, no capacity, SwiGLU experts, shared experts) is
+the other family's: models/mla_moe.py. Matches the model families the
+reference serves through vLLM (Llama-2/3 in BASELINE.json north-star
+configs) but as a native JAX program.
 """
 
 from __future__ import annotations

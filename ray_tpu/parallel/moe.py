@@ -1,11 +1,24 @@
-"""Expert-parallel mixture-of-experts dispatch.
+"""Mixture-of-experts layers: two routers, two regimes.
 
 Absent from the reference (ref: SURVEY §2.3 — "no MoE expert parallel
-in-tree"; vLLM handles EP internally). TPU-native version uses the einsum
-dispatch/combine formulation: a capacity-bounded one-hot dispatch tensor
-routes tokens to experts, expert weights are sharded on the ``ep`` mesh
-axis, and sharding propagation turns the dispatch/combine einsums into
-all_to_all transfers over ICI — no manual routing code.
+in-tree"; vLLM handles EP internally).
+
+* **Training (``moe_ffn``, reached from ``llama_forward``):** Switch top-1
+  with capacity dropping, two matrices an expert, in the einsum
+  dispatch/combine formulation — a capacity-bounded one-hot ``[T, E, C]``
+  dispatch tensor, expert weights sharded on the ``ep`` mesh axis, and
+  sharding propagation turning the einsums into all_to_all over ICI.
+* **Serving (``sigmoid_topk_route`` + ``routed_experts`` + ``moe_layer``,
+  reached from ``llm/mla_moe.py``):** the DeepSeek-V3 family's layer. Sigmoid
+  scores, the k experts with the largest ``score + bias`` chosen and
+  weighed by the score alone, no capacity (no token is ever dropped),
+  three-matrix SwiGLU experts and shared experts every token passes
+  through. The one-hot dispatch does not scale to 128 experts x 12k prefill
+  tokens, so the routed product is a grouped matmul over the assignments
+  sorted by expert (``jax.lax.ragged_dot``). The layer is told which
+  experts it holds (``held``): it routes over all of them and computes its
+  own experts' part of the sum — on one chip that is all of them, and the
+  exchange between holders is not here.
 """
 
 from __future__ import annotations
@@ -70,3 +83,73 @@ def moe_ffn(x, gate_w, w_up, w_down, *, capacity_factor: float = 1.25,
     expert_out = jnp.einsum("ecf,efd->ecd", h, w_down)
     out = jnp.einsum("tec,ecd->td", combine, expert_out)
     return out.reshape(B, T, D), aux
+
+
+# ------------------------------------------------------------------ serving
+def sigmoid_topk_route(h, router_w, bias, k: int, scale: float,
+                       norm: bool = True):
+    """``noaux_tc`` routing with one group: ``s = sigmoid(h . W)`` in
+    float32 (the family computes its gate in float32 whatever the model's
+    type: a bf16 score would flip near-tied choices), the ``k`` experts with
+    the largest ``s + bias``, weighed by ``s`` alone — the bias chooses and
+    never weighs. Equal sums go to the lower expert index (``lax.top_k``).
+
+    h: [T, D]; router_w: [D, E]; bias: [E]. Returns (idx [T, k] int32,
+    weights [T, k] float32)."""
+    s = jax.nn.sigmoid(jnp.matmul(
+        h.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    if norm:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), w * scale
+
+
+def routed_experts(h, idx, w, experts, held: tuple[int, int], valid=None):
+    """The held experts' part of ``sum_e w_e . swiglu_e(h)``, with no
+    capacity: the ``T * k`` assignments are sorted by expert, each expert's
+    rows form one group of a grouped matmul (``jax.lax.ragged_dot``: on the
+    TPU one kernel that walks the groups), and the weighted rows are summed
+    back per token. Assignments to experts outside ``held = (lo, hi)`` and
+    of rows where ``valid`` is False (dead decode slots, prompt padding)
+    sort behind the last group and add nothing.
+
+    h: [T, D]; idx, w: [T, k]; experts: {"w_gate", "w_up": [hi-lo, D, F],
+    "w_down": [hi-lo, F, D]}. Returns (y [T, D], load [hi-lo] int32: the
+    rows each held expert got)."""
+    T, k = idx.shape
+    lo, hi = held
+    n = hi - lo
+    keep = (idx >= lo) & (idx < hi)
+    if valid is not None:
+        keep &= valid[:, None]
+    group = jnp.where(keep, idx - lo, n).reshape(-1)     # n = "nobody here"
+    order = jnp.argsort(group)                           # stable
+    load = jnp.bincount(group, length=n + 1)[:n].astype(jnp.int32)
+    xs = h[order // k]                                   # [T * k, D]
+    hid = jax.nn.silu(jax.lax.ragged_dot(xs, experts["w_gate"], load)) * (
+        jax.lax.ragged_dot(xs, experts["w_up"], load))
+    ys = jax.lax.ragged_dot(hid, experts["w_down"], load)
+    ws = jnp.where(keep, w, 0.0).reshape(-1)[order]
+    # rows past the last group belong to no expert: whatever the grouped
+    # product left there is dropped, not scaled
+    ys = jnp.where(ws[:, None] != 0, ys * ws[:, None].astype(ys.dtype), 0)
+    y = ys[jnp.argsort(order)].reshape(T, k, -1).sum(axis=1)
+    return y.astype(h.dtype), load
+
+
+def moe_layer(h, moe, *, k: int, scale: float, norm: bool = True,
+              held: tuple[int, int], valid=None):
+    """One expert layer of the family on ``h`` [T, D] (already normed):
+    the held routed experts' part plus the shared experts (one SwiGLU of
+    the summed shared width, which every holder computes alike). Returns
+    (y [T, D], load [hi-lo])."""
+    from ray_tpu.ops.basic import swiglu
+
+    idx, w = sigmoid_topk_route(h, moe["router"]["kernel"],
+                                moe["router"]["bias"], k, scale, norm)
+    y, load = routed_experts(h, idx, w, moe["experts"], held, valid)
+    sh = moe["shared"]
+    return y + swiglu(h, sh["w_gate"]["kernel"], sh["w_up"]["kernel"],
+                      sh["w_down"]["kernel"]), load

@@ -236,6 +236,26 @@ llm_prefill_true_tokens_total = Counter(
 llm_prefill_padded_tokens_total = Counter(
     "rt_llm_prefill_padded_tokens_total",
     "rows x pad of the prefill programs run, dummy rows included")
+# What a model family's decode programs count themselves, a step
+# (llm/engine.py ServePrograms.stats): the sums ride back with each block's
+# tokens and land here when the block is synced. The expert layers of
+# llm/mla_moe.py: rows routed to the experts held here, distinct experts
+# that got any, the largest expert's rows, and experts held x expert
+# layers — each summed over expert layers and decode steps.
+LLM_MODEL_STATS = {
+    "moe_assignments": Counter(
+        "rt_llm_moe_assignments_total",
+        "token-to-expert assignments of live decode slots"),
+    "moe_experts_touched": Counter(
+        "rt_llm_moe_experts_touched_total",
+        "distinct experts with at least one token, a step a layer"),
+    "moe_max_load": Counter(
+        "rt_llm_moe_max_load_total",
+        "tokens of the most loaded expert, a step a layer"),
+    "moe_expert_slots": Counter(
+        "rt_llm_moe_expert_slots_total",
+        "experts held x expert layers x decode steps: what touched is a share of"),
+}
 serve_lane_seconds = Histogram(
     "rt_serve_lane_seconds",
     "actor-lane call: ring (submit to pop) and loop (pop to the call's "
@@ -246,7 +266,7 @@ STAGE_FAMILIES = (
     llm_prefill_wait_seconds, llm_decode_seconds, llm_decode_tokens_total,
     llm_prefill_waves_total, llm_prefill_prompts_total,
     llm_prefill_true_tokens_total, llm_prefill_padded_tokens_total,
-    serve_lane_seconds)
+    *LLM_MODEL_STATS.values(), serve_lane_seconds)
 
 
 def stage_totals() -> dict:

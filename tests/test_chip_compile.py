@@ -156,3 +156,56 @@ def test_train_step_takes_the_flash_kernels(one_chip, monkeypatch):
         one_chip(params), one_chip(opt_state),
         {"tokens": one_chip(_shape((1, 2048 + 1), jnp.int32))}).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def _mla_moe_args(one_chip, n_layers: int):
+    """The `kanana2_gen_closed` cell's programs at published widths (128
+    experts of 768, 576-wide latent rows, 128,256 vocabulary rows), 32 slots
+    of 2048 positions — depth cut to the dense layer and one expert layer."""
+    from ray_tpu.models.mla_moe import MlaMoeConfig, mla_moe_init
+
+    cfg = MlaMoeConfig(n_layers=n_layers, max_seq_len=2048)
+    params = jax.eval_shape(lambda: mla_moe_init(jax.random.PRNGKey(0), cfg))
+    pool = _shape((n_layers, 4097, 16, cfg.latent_width), jnp.bfloat16)
+    return cfg, one_chip(params), one_chip(pool), one_chip(_shape((2,), jnp.uint32))
+
+
+def test_mla_moe_decode_multi_compiles(one_chip):
+    from ray_tpu.llm.mla_moe import STATS, mla_moe_decode_multi
+
+    cfg, params, pool, key = _mla_moe_args(one_chip, 2)
+    B = 32
+    i32 = one_chip(_shape((B,), jnp.int32))
+    lowered = mla_moe_decode_multi.lower(
+        params, None, i32, i32, i32, one_chip(_shape((B, 128), jnp.int32)), pool,
+        one_chip(_shape((B,), jnp.bool_)), one_chip(_shape((B,), jnp.float32)),
+        key, cfg=cfg, n_steps=8)
+    assert lowered.out_info[0].shape == (8, B + len(STATS))
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    # embedding + head 1.05 GB, the dense layer 0.13, one expert layer 1.28,
+    # the pool 0.15
+    assert 2.5e9 < mem.argument_size_in_bytes < 2.8e9
+    assert mem.temp_size_in_bytes < 0.5e9
+    text = compiled.as_text()
+    # the routed experts are grouped matmuls (ragged_dot: Mosaic kernels),
+    # and the window is never expanded to 32 heads of keys or values
+    assert text.count("tpu_custom_call") >= 3
+    wide = re.findall(r"bf16\[32,2048,32,(?:128|192|256)\]", text)
+    assert not wide, sorted(set(wide))
+
+
+def test_mla_moe_prefill_batch_compiles(one_chip):
+    from ray_tpu.llm.mla_moe import mla_moe_prefill_batch
+
+    cfg, params, pool, key = _mla_moe_args(one_chip, 2)
+    N, Tp = 8, 1536  # the largest wave the cell warms
+    compiled = mla_moe_prefill_batch.lower(
+        params, None, one_chip(_shape((N,), jnp.int32)),
+        one_chip(_shape((N, Tp), jnp.int32)),
+        one_chip(_shape((N, Tp // 16), jnp.int32)), pool,
+        one_chip(_shape((N,), jnp.int32)), one_chip(_shape((N,), jnp.float32)),
+        key, cfg=cfg).compile()
+    # heads run a group at a time (models/mla_moe.py _head_groups): all 32
+    # at once wrote 5.2 GB of scores and probabilities
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
