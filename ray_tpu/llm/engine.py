@@ -43,6 +43,7 @@ import numpy as np
 from ray_tpu.devtools import chaos
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.ops.basic import rms_norm, rope, rope_freqs
+from ray_tpu.ops.paged_attention import paged_decode_attention
 from ray_tpu.utils import metrics, tracing
 
 
@@ -82,13 +83,18 @@ def _kv_write(pool, i, row, off, val):
 
 
 def _kv_read(pool, i, page_tables, B, MAXP, PS, KV, hd, dtype):
-    """Gather the decode attention window ``[B, MAXP * PS, KV, hd]``: every
-    page of every slot's table, live or not. Since ``_gqa_attn`` contracts
-    q against these KV heads as they lie, this gather (a slice of the
-    layer's pool, the gather itself, one read by each contraction) is the
-    decode step's first bottleneck at any batch (PERF.md section 5). int8
-    pools move HALF the HBM bytes of bf16 through it; the scale gather is
-    hd-times smaller — noise."""
+    """Gather an attention window ``[B, MAXP * PS, KV, hd]``: every page of
+    every slot's table, live or not — a slice of the layer's pool, the
+    gather itself, then one read by each contraction of ``_gqa_attn``.
+    What still reads the pool this way: several query rows a slot (suffix
+    prefill, speculative decode and verify), int8 pools, and the decode
+    step wherever ``_reads_in_place`` says no. On the chip the decode step
+    of a plain pool does not (``ops/paged_attention.py``): there this
+    window cost half the device's time for a tenth of it live (PERF.md
+    section 6, PR 28), and this function with ``_gqa_attn`` is the plain
+    reference that kernel is tested against. int8 pools move HALF the HBM
+    bytes of bf16 through it; the scale gather is hd-times smaller —
+    noise."""
     if not isinstance(pool, dict):
         return pool[i][page_tables].reshape(B, MAXP * PS, KV, hd)
     q = pool["q"][i][page_tables].reshape(B, MAXP * PS, KV, hd)
@@ -121,6 +127,20 @@ def scatter_pages(pool, page_ids, stack):
     return _scatter_pages_jit(pool, idx, stack)
 
 
+def _reads_in_place(pool) -> bool:
+    """Whether the decode step's attention reads this pool where it lies
+    (``paged_decode_attention``: only the pages that hold tokens) or
+    through ``_kv_read``'s gathered window. Decided by what the code can
+    see, no option: a plain pool on a TPU takes the kernel. An int8 pool
+    keeps the window (the kernel does not dequantise); so does every other
+    backend, where the kernel would be interpreted (seconds a call site to
+    trace, and nothing to gain); and a single KV head under 32 bits, whose
+    one-row page slice Mosaic refuses (tiling (2, 128))."""
+    if isinstance(pool, dict) or jax.default_backend() != "tpu":
+        return False
+    return pool.shape[3] > 1 or pool.dtype.itemsize >= 4
+
+
 def _sample_tail(logits, temps, key):
     """The sampling tail every serving program ends in: greedy where a row's
     temperature is 0, a categorical draw elsewhere. logits: [N, V]; temps:
@@ -145,7 +165,13 @@ def _decode_body(params, loras, aids, tokens, pos, page_tables,
     new token lands at that position); page_tables: [B, MAXP]; aids: [B]
     adapter ids; temps: [B]. Returns (next_tok [B], kpool, vpool).
     Pools are either plain [L, P, PS, KV, hd] arrays (cfg dtype) or int8
-    quantized dicts (see _kv_write) — the engine's kv_dtype option."""
+    quantized dicts (see _kv_write) — the engine's kv_dtype option.
+
+    Every layer writes the new row into the pools, then attends the
+    slot's ``pos + 1`` positions: in place, page by page through the table
+    (``paged_decode_attention``; an inactive slot attends nothing) where
+    ``_reads_in_place`` holds, else over ``_kv_read``'s whole window with
+    the positions past ``pos`` masked."""
     B = tokens.shape[0]
     L, P, PS, KV, hd = _kv_shape(kpool)
     MAXP = page_tables.shape[1]
@@ -153,8 +179,12 @@ def _decode_body(params, loras, aids, tokens, pos, page_tables,
     positions = pos[:, None]
     row = jnp.take_along_axis(page_tables, (pos // PS)[:, None], axis=1)[:, 0]
     off = pos % PS
-    key_idx = jnp.arange(MAXP * PS)
-    mask = key_idx[None, None, :] <= pos[:, None, None]
+    in_place = _reads_in_place(kpool)
+    if in_place:
+        lengths = jnp.where(active, pos + 1, 0)
+    else:
+        key_idx = jnp.arange(MAXP * PS)
+        mask = key_idx[None, None, :] <= pos[:, None, None]
 
     Dq = cfg.n_heads * hd
     Dkv = KV * hd
@@ -179,9 +209,13 @@ def _decode_body(params, loras, aids, tokens, pos, page_tables,
         k = rope(k, cos, sin, positions)
         kpool = _kv_write(kpool, i, row, off, k[:, 0])
         vpool = _kv_write(vpool, i, row, off, v[:, 0])
-        kb = _kv_read(kpool, i, page_tables, B, MAXP, PS, KV, hd, k.dtype)
-        vb = _kv_read(vpool, i, page_tables, B, MAXP, PS, KV, hd, v.dtype)
-        att = _gqa_attn(q, kb, vb, mask)
+        if in_place:
+            att = paged_decode_attention(
+                q[:, 0], kpool, vpool, i, page_tables, lengths)
+        else:
+            kb = _kv_read(kpool, i, page_tables, B, MAXP, PS, KV, hd, k.dtype)
+            vb = _kv_read(vpool, i, page_tables, B, MAXP, PS, KV, hd, v.dtype)
+            att = _gqa_attn(q, kb, vb, mask)
         x = x + att.reshape(B, 1, -1) @ layer["wo"]["kernel"]
         hf = rms_norm(x, layer["ffn_norm"]["scale"])
         w_gu = jnp.concatenate(
@@ -210,10 +244,16 @@ def paged_decode_multi(params, loras, aids, tokens, seq_lens, page_tables,
     is returned ON DEVICE so consecutive blocks chain without any host
     round trip — the engine pipelines the next block's dispatch before
     syncing this block's tokens. Slots that finish mid-block keep decoding
-    junk — their page-table gathers clip to allocated (or junk) pages,
-    future-position writes are masked until legitimately overwritten, and
-    the host discards the extra tokens, so over-decode is pure (bounded)
-    waste, never corruption."""
+    junk — a position past the slot's allocated pages writes to and reads
+    from whatever its table holds there (the junk page 0, or a page the
+    table clips to; the in-place kernel walks ``ceil((pos + 1) / PS)``
+    entries of the table, at most all of it, so it fetches those pages like
+    any other), future-position writes are masked until legitimately
+    overwritten, and the host discards the extra tokens, so over-decode is
+    pure (bounded) waste, never corruption. The pools are updated in place
+    through the scan: the kernel reads them as operands and returns only
+    the attended rows (tests/test_chip_compile.py holds the compiled
+    program to no copy of a pool)."""
     def step(carry, k):
         tok, pos, kpool, vpool = carry
         nxt, kpool, vpool = _decode_body(
@@ -594,14 +634,18 @@ class ServePrograms:
     tok, pos, *cache)``: a step's row holds the B tokens and then one int32
     per name in ``stats`` (the model's own per-step sums, read at the
     block's one sync). ``prefill_batch(params, loras, aids, tokens, pages,
-    *cache, true_lens, temps, key, cfg) -> (first [N], *cache)``. The rest
-    are the Llama family's and None elsewhere: the engine refuses what
-    needs them."""
+    *cache, true_lens, temps, key, cfg) -> (first [N], *cache)``.
+    ``decode_in_place(cache) -> bool``: whether ``decode_multi`` fetches
+    only the pages of that cache that hold tokens; None where it gathers
+    every slot's whole table a step (what the read counters then report).
+    The rest are the Llama family's and None elsewhere: the engine refuses
+    what needs them."""
     family: str
     make_cache: callable
     decode_multi: callable
     prefill_batch: callable
     stats: tuple = ()
+    decode_in_place: callable = None
     prefill_suffix: callable = None
     decode_spec: callable = None
     decode_verify: callable = None
@@ -626,6 +670,7 @@ def serving_programs(cfg) -> ServePrograms:
 LLAMA_PROGRAMS = ServePrograms(
     family="llama", make_cache=make_kv_pools,
     decode_multi=paged_decode_multi, prefill_batch=paged_prefill_batch,
+    decode_in_place=lambda cache: _reads_in_place(cache[0]),
     prefill_suffix=paged_prefill_suffix, decode_spec=paged_decode_spec,
     decode_verify=paged_decode_verify, lora=True, int8_cache=True,
     page_plane=True)
@@ -703,6 +748,8 @@ class ContinuousBatchingEngine:
         # the model's cache, one tuple of pools (see ServePrograms)
         self.cache = tuple(P.make_cache(cfg, page_size, n_pages, kv_dtype))
         self.kv_dtype = kv_dtype or "native"
+        self._kv_in_place = bool(
+            P.decode_in_place and P.decode_in_place(self.cache))
         self.n_pages = n_pages
         self.free_pages = list(range(1, n_pages))  # page 0 = junk page
         self.loras = None
@@ -757,6 +804,9 @@ class ContinuousBatchingEngine:
         # (ServePrograms.stats), as a mean per step: annotates the next
         # dispatch and admission phases
         self._last_stats: dict = {}
+        # positions that block's attention attended and fetched a step
+        # (kv_live, kv_read): annotates the next dispatch
+        self._last_kv: dict = {}
 
     @property
     def kpool(self):
@@ -1289,7 +1339,8 @@ class ContinuousBatchingEngine:
         with tracing.phase("engine.decode_dispatch") as ph:
             K = self._pick_block(planned)
             active = np.array([r is not None for r in self.slot_req])
-            ph.set(steps=K, live=int(active.sum()), **self._last_stats)
+            ph.set(steps=K, live=int(active.sum()), **self._last_stats,
+                   **self._last_kv)
             self._rng, sub = jax.random.split(self._rng)
             # .copy() on every host array that the loops later mutate
             # (page_tables/seq_lens/next_tok/aids/temps): PJRT CPU
@@ -1314,14 +1365,16 @@ class ContinuousBatchingEngine:
         """Host-side emission of one synced decode block. The read below
         (``engine.block_sync``) blocks the calling thread — the replica's
         event loop — until the device has finished the block: up to 64
-        steps, about 2.7 s at 42 ms a step. Nothing else of the replica
-        runs meanwhile: no new call starts, no delta leaves."""
+        steps, about 0.6 s at the 9 ms a step of the benchmark's chat
+        cell. Nothing else of the replica runs meanwhile: no new call
+        starts, no delta leaves."""
         K, toks, slot_snapshot = entry
         with tracing.phase("engine.block_sync", steps=K):
             toks = np.asarray(toks)  # [K, B]; blocks until the device is done
         if self.programs.stats:  # [K, B + stats]: the model's sums ride along
             self._observe_stats(toks[:, self.B:])
             toks = toks[:, :self.B]
+        self._observe_kv_reads(K, slot_snapshot)
         self.steps += K
         with tracing.phase("engine.emit") as ph:
             before = self.tokens_out
@@ -1341,6 +1394,29 @@ class ContinuousBatchingEngine:
                         self.next_tok[i] = tok
                     self._emit(req, tok)
             ph.set(tokens=self.tokens_out - before)
+
+    def _observe_kv_reads(self, K: int, slot_snapshot) -> None:
+        """What a synced block's attention attended and what it fetched
+        for that (``rt_llm_decode_kv_tokens_{live,read}_total``), reckoned
+        from each snapshot slot's length at the block's start: step k of a
+        slot attends ``start + k + 1`` positions. A slot the planned loop
+        has already handed on has its start from the request itself."""
+        starts = np.array(
+            [self.seq_lens[i] if self.slot_req[i] is req
+             else len(req.prompt) + req.emitted - 1
+             for i, req in enumerate(slot_snapshot) if req is not None],
+            np.int64)
+        lens = starts[:, None] + np.arange(1, K + 1)  # [live slots, K]
+        live = int(lens.sum())
+        if self._kv_in_place:  # whole pages, at most the whole table
+            pages = -(-np.minimum(lens, self.MAXP * self.PS) // self.PS)
+            read = int(pages.sum()) * self.PS
+        else:
+            read = K * self.B * self.MAXP * self.PS
+        metrics.llm_decode_kv_tokens_live_total.inc(live)
+        metrics.llm_decode_kv_tokens_read_total.inc(read)
+        self._last_kv = {"kv_live": round(live / K, 2),
+                         "kv_read": round(read / K, 2)}
 
     def _observe_stats(self, rows) -> None:
         """A synced block's per-step sums of the model's programs
@@ -1491,7 +1567,7 @@ class ContinuousBatchingEngine:
         # The loop holds the event loop's thread nearly all the time:
         # every _emit_block sleeps in np.asarray (phase engine.block_sync)
         # until the device has finished a block of up to 64 steps (about
-        # 2.7 s at 42 ms a step), and only the _yield() at the bottom and
+        # 0.6 s at 9 ms a step), and only the _yield() at the bottom and
         # the one after a wave let the replica's other coroutines run.
         pending: list = []
         carry = None  # (tok_dev, lens_dev) device-resident between blocks

@@ -236,6 +236,18 @@ llm_prefill_true_tokens_total = Counter(
 llm_prefill_padded_tokens_total = Counter(
     "rt_llm_prefill_padded_tokens_total",
     "rows x pad of the prefill programs run, dummy rows included")
+# Read amplification of decode attention, reckoned on the host at each
+# block's sync (llm/engine.py _observe_kv_reads): the positions the live
+# slots attended, and the positions the family's decode program fetched
+# from its page pool to attend them — pages walked x page size where the
+# program reads in place, slots x table x page size a step where it gathers
+# the whole window.
+llm_decode_kv_tokens_live_total = Counter(
+    "rt_llm_decode_kv_tokens_live_total",
+    "positions attended by live decode slots, summed over steps")
+llm_decode_kv_tokens_read_total = Counter(
+    "rt_llm_decode_kv_tokens_read_total",
+    "positions the decode programs fetched from the page pool for them")
 # What a model family's decode programs count themselves, a step
 # (llm/engine.py ServePrograms.stats): the sums ride back with each block's
 # tokens and land here when the block is synced. The expert layers of
@@ -266,6 +278,7 @@ STAGE_FAMILIES = (
     llm_prefill_wait_seconds, llm_decode_seconds, llm_decode_tokens_total,
     llm_prefill_waves_total, llm_prefill_prompts_total,
     llm_prefill_true_tokens_total, llm_prefill_padded_tokens_total,
+    llm_decode_kv_tokens_live_total, llm_decode_kv_tokens_read_total,
     *LLM_MODEL_STATS.values(), serve_lane_seconds)
 
 

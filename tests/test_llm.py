@@ -430,3 +430,79 @@ def test_gqa_attn_matches_repeat_then_attend(group, tq, dtype):
                                atol=1e-5 if dtype == "float32" else 3e-2)
     levels = np.rint(got.mean(axis=-1))  # [B, tq, H]
     assert (levels == np.arange(H) // group).all()
+
+
+def _kv_counters():
+    from ray_tpu.utils import metrics
+
+    totals = metrics.stage_totals()
+    return tuple(
+        totals[f"rt_llm_decode_kv_tokens_{n}_total"].get("", {}).get("sum", 0)
+        for n in ("live", "read"))
+
+
+def _lone_then_admission(params, cfg, eos_id):
+    """One lone request whose counters can be reckoned by hand, then a long
+    request (14 tokens: it ends inside a 4-step block), and a short one
+    admitted while it decodes. Returns (counters' growth over the lone
+    request, the lone request's tokens, the long one's, the short one's)."""
+    import asyncio
+
+    from ray_tpu.llm import ContinuousBatchingEngine
+
+    async def go():
+        eng = ContinuousBatchingEngine(
+            params, cfg, max_batch=3, page_size=8, n_pages=64, max_seq_len=64,
+            eos_id=eos_id, block_buckets=(4,))
+        await eng.start()
+        before = _kv_counters()
+        lone = await eng.generate([1, 2, 3, 4, 5], max_tokens=9)
+        grown = tuple(a - b for a, b in zip(_kv_counters(), before))
+        steps = eng.steps
+        long_task = asyncio.get_event_loop().create_task(
+            eng.generate([1, 2, 3], max_tokens=14))
+        while eng.steps < steps + 4:  # the long request is decoding now
+            await asyncio.sleep(0.01)
+        short = await eng.generate([5, 6, 7, 8, 9], max_tokens=6)
+        long_out = await long_task
+        await eng.stop()
+        return grown, lone, long_out, short
+
+    return _run(go())
+
+
+@pytest.mark.parametrize("eos_id", [None, 255], ids=["planned", "reactive"])
+def test_engine_decode_in_place_matches_gathered(tiny, eos_id, monkeypatch):
+    """The decode step that reads K and V in place (the Pallas kernel, here
+    interpreted) gives the greedy tokens of the one that gathers the window,
+    in both loops, across a mid-decode admission and a request that ends
+    inside a block; and the two read counters say which path ran. On this
+    backend the engine gathers unless ``_reads_in_place`` is answered for
+    it, as here — on a TPU a plain pool takes the kernel by itself."""
+    from ray_tpu.llm import engine
+
+    cfg, params = tiny
+    assert not engine._reads_in_place(engine.make_kv_pools(cfg, 8, 4, None)[0])
+    grown_g, *gathered = _lone_then_admission(params, cfg, eos_id)
+    assert [len(o) for o in gathered] == [9, 14, 6]
+
+    monkeypatch.setattr(engine, "_reads_in_place",
+                        lambda pool: not isinstance(pool, dict))
+    engine.paged_decode_multi.clear_cache()  # traced with the other answer
+    try:
+        grown_k, *in_place = _lone_then_admission(params, cfg, eos_id)
+    finally:
+        engine.paged_decode_multi.clear_cache()
+    assert in_place == gathered
+
+    if eos_id is None:
+        # the planned loop dispatches exactly the lone request's 8 decode
+        # steps: two blocks of 4 from lengths 5 and 9, attending 6..13
+        # positions. Gathered, a step fetches 3 slots x 8 pages x 8 tokens;
+        # in place, whole pages: 8 for 6..8, 16 for 9..13.
+        assert grown_g == (sum(range(6, 14)), 8 * 3 * 8 * 8)
+        assert grown_k == (sum(range(6, 14)), 3 * 8 + 5 * 16)
+    else:  # the reactive pipeline may ride a block past the last token
+        assert min(grown_g[0], grown_k[0]) >= sum(range(6, 14))
+        assert grown_g[1] % (4 * 3 * 8 * 8) == 0
+        assert grown_k[0] <= grown_k[1] < 2 * grown_k[0]  # whole pages of 8
