@@ -126,7 +126,7 @@ class Config:
     #: serve data plane: route same-node replica calls over the actor shm
     #: rings (serve/dataplane) instead of the actor RPC plane; per-call
     #: RPC fallback (ref args, big payloads, broken lane) is always kept.
-    #: Off switch for A/B (bench.py serve arm) and paranoia.
+    #: Off switch for A/B and paranoia.
     serve_fastlane: bool = True
 
     # --- cross-node node tunnel (core/tunnel.py; ref: Pathways'
@@ -136,7 +136,7 @@ class Config:
     #: multiplexed connection per node pair carrying the SAME packed
     #: wire records the shm rings use (coalesced frames instead of
     #: per-call pickled RPC specs); per-call RPC fallback always kept.
-    #: Off switch for A/B (bench.py tunnel arm) and paranoia.
+    #: Off switch for A/B and paranoia.
     node_tunnel: bool = True
     #: tunnel records above this many bytes do not ship their big args
     #: inline: each oversized top-level value seals into the sender's
@@ -174,7 +174,7 @@ class Config:
     #: .remote() calls with no active context) that start a sampled
     #: trace; children inherit the decision from the wire leg. The
     #: unsampled path is one contextvar read + one branch and ships no
-    #: trace bytes (bench.py tracing_overhead_us).
+    #: trace bytes.
     trace_sample_rate: float = 1.0
     #: GCS trace assembler: max assembled traces retained. Eviction
     #: protects the slowest ``trace_slow_keep`` fraction (the p99
@@ -244,8 +244,8 @@ class Config:
     # --- observability ---
     task_events_report_interval_s: float = 1.0
     #: hot-path flight recorder (utils/recorder.py): always-on ring of
-    #: ns-stamped stage events per process, < 1µs/task budget (bench.py
-    #: recorder_overhead_us). Off switch for A/B and paranoia.
+    #: ns-stamped stage events per process, < 1µs/task budget. Off
+    #: switch for A/B and paranoia.
     recorder_enabled: bool = True
     #: slots per process recorder ring (also the driver's retained
     #: latency-sample window); fixed-size, drop-oldest

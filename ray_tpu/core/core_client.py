@@ -445,7 +445,7 @@ class CoreClient:
         self._fast_flush_cv = _threading.Condition()
         self._fast_flush_dirty = False
         self._fast_flusher_thread: _threading.Thread | None = None
-        self._fast_tx_flushes = 0   # batch pushes (stats: bench.py)
+        self._fast_tx_flushes = 0   # batch pushes (fast_flush_stats)
         self._fast_tx_records = 0   # records those pushes carried
         self._fast_spilled_results = 0  # completions that arrived via RPC spill
         # flight recorder (utils/recorder.py): the hot paths read this
@@ -1952,7 +1952,7 @@ class CoreClient:
                     self._fast_flush_dirty = True
 
     def fast_flush_stats(self) -> dict:
-        """Coalescing counters for bench.py: batch pushes and the records
+        """Coalescing counters: batch pushes and the records
         they carried (avg_batch == 1.0 means no coalescing happened)."""
         flushes, records = self._fast_tx_flushes, self._fast_tx_records
         return {
@@ -2176,7 +2176,7 @@ class CoreClient:
         return self._tunnels
 
     def tunnel_stats(self) -> dict:
-        """Tunnel coalescing counters (bench.py tunnel arm, tests);
+        """Tunnel coalescing counters (read by tests/test_node_tunnel.py);
         zeros when no tunnel was ever dialed."""
         if self._tunnels is None:
             return {"tunnels": 0, "lanes": 0, "tx_frames": 0,
@@ -3006,7 +3006,7 @@ class CoreClient:
         stats = recorder.get_stats() if self._rec_enabled else None
         # StageStats.add inlined below (ring/cap hoisted per batch): the
         # method-call frame alone is ~8% of the recorder's whole per-task
-        # budget on slow interpreters (bench.py recorder_overhead_us)
+        # budget on slow interpreters
         if stats is not None:
             sring, scap = stats.ring, stats.cap
         astats = self._actor_stats

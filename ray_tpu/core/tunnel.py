@@ -164,7 +164,7 @@ class NodeTunnel:
         self._dial_lock: asyncio.Lock | None = None
         self._dial_fails = 0
         self._next_dial = 0.0  # monotonic: backoff gate for redials
-        # coalescing counters (bench.py tunnel arm / tests)
+        # coalescing counters (stats(); tests)
         self.tx_frames = 0
         self.tx_records = 0
         self.rx_frames = 0
@@ -430,8 +430,8 @@ class TunnelClient:
         return t, lane_id, ring, reply.get("methods")
 
     def stats(self) -> dict:
-        """Aggregate coalescing counters (bench.py tunnel arm; the
-        coalesced-frame proof in tests): avg_batch == 1.0 means every
+        """Aggregate coalescing counters (the coalesced-frame proof in
+        tests): avg_batch == 1.0 means every
         frame carried a single record."""
         tx_f = sum(t.tx_frames for t in self.tunnels.values())
         tx_r = sum(t.tx_records for t in self.tunnels.values())

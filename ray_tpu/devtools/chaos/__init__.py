@@ -15,8 +15,7 @@ Three layers:
   send, GCS WAL append, raylet lease grant, worker exec). Call sites
   guard with ``if chaos.ENABLED:`` — when chaos is off (the default and
   the production state) a fault point is ONE module-attribute load and a
-  falsy branch, no function call, no config lookup (bench.py
-  ``chaos_overhead_us``).
+  falsy branch, no function call, no config lookup.
 - **Native fault arms** (ring.cc / store.cc): env-gated counters below
   Python that force partial ring pushes, ring wait timeouts, and store
   seal failures — see :func:`arm_native`.

@@ -12,8 +12,8 @@ come from a seeded :class:`~ray_tpu.devtools.chaos.plan.ChaosPlan`
 (``gcs.pg_prepare`` / ``gcs.pg_commit`` points), so a failing run
 replays byte-for-byte.
 
-Emits the BENCHVS rows that make scheduling scale under failure a
-tracked number:
+Emits the numbers that make scheduling scale under failure
+observable:
 
 - ``pg_create_removal_per_s`` — PG create+remove cycles sustained while
   nodes churn underneath,
@@ -27,8 +27,7 @@ bundle reservation held by a surviving node must belong to a live,
 CREATED PG that assigns it to exactly that node — anything else is a
 leak (and the tier-1 churn test asserts there are none).
 
-Usage (also the bench.py ``pg_churn`` arm and
-``tests/test_pg_ft.py::test_seeded_churn_plan_zero_leaks``)::
+Usage (also ``tests/test_pg_ft.py::test_seeded_churn_plan_zero_leaks``)::
 
     h = ChurnHarness(nodes=64, seed=7)
     h.start()

@@ -6,8 +6,10 @@ Llama implementation (static shapes, batched MXU matmuls), with a
 continuous-batching paged-KV engine for serving.
 
 - generation: prefill/decode_step/generate with left-padded ragged batches
-- engine: ContinuousBatchingEngine — paged KV, decode-step admission,
-  token streaming, LoRA multiplexing
+- engine: ContinuousBatchingEngine — slots, pages, decode-step admission,
+  token streaming; no device program of its own
+- programs: the seam (ServePrograms by the config's type); llama, mla_moe:
+  each family's jitted programs and cache over its layer in models/
 - serving: LLMServer (@serve.batch coalescing) and LLMEngineServer
   (continuous batching + streaming) deployments
 - batch: build_llm_processor over ray_tpu.data datasets

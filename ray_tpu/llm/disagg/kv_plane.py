@@ -168,8 +168,9 @@ def _pool_components(pool, page_ids) -> dict[str, np.ndarray]:
 
 
 # the adoption scatter lives beside the other pool-shape ops in
-# engine.py (scatter_pages); re-exported here for the adopting side
-from ray_tpu.llm.engine import scatter_pages  # noqa: E402,F401
+# llm/llama.py (scatter_pages); re-exported here for the adopting side
+# (the engine's admission, the prefill workers)
+from ray_tpu.llm.llama import scatter_pages  # noqa: E402,F401
 
 
 def _chaos_kv_ship(phase: str, **ctx):

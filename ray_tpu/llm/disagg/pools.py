@@ -37,6 +37,7 @@ import numpy as np
 from ray_tpu.core.ref import ObjectLostError
 from ray_tpu.devtools import chaos
 from ray_tpu.llm import engine as _engine
+from ray_tpu.llm import llama as _llama
 from ray_tpu.llm.disagg import telemetry
 from ray_tpu.llm.disagg.kv_plane import (
     KVPageManifest,
@@ -44,14 +45,15 @@ from ray_tpu.llm.disagg.kv_plane import (
     adopt_pages,
     ship_pages,
 )
+from ray_tpu.llm.programs import UnsupportedByModel, serving_programs
 
 
 def _resolve_params(model_config, params, params_fn):
     # both workers ship and adopt K and V page stacks (kv_plane.py): a
     # family whose cache is something else is refused before anything loads
-    programs = _engine.serving_programs(model_config)
+    programs = serving_programs(model_config)
     if not programs.page_plane:
-        raise _engine.UnsupportedByModel(
+        raise UnsupportedByModel(
             "disaggregated serving (disagg/kv_plane.py)", programs.family)
     if params is None:
         params = params_fn() if params_fn is not None else None
@@ -103,13 +105,13 @@ class PrefillWorker:
         self.PS = page_size
         self.n_pages = n_pages
         self.kv_dtype = kv_dtype or "native"
-        self.kpool, self.vpool = _engine.make_kv_pools(
+        self.kpool, self.vpool = _llama.make_kv_pools(
             model_config, page_size, n_pages, kv_dtype)
         self.free_pages = list(range(1, n_pages))  # page 0 = junk page
         self.loras = None
         self.lora_index = {"__base__": 0}
         if lora_adapters:
-            self.loras, self.lora_index = _engine.make_lora_stack(
+            self.loras, self.lora_index = _llama.make_lora_stack(
                 model_config, lora_adapters, lora_rank)
         self.max_wave = max_wave
         self.wave_wait_s = wave_wait_s
@@ -279,7 +281,7 @@ class PrefillWorker:
                 true_lens[j] = len(job.tokens)
                 temps[j] = job.temperature
             self._rng, sub = jax.random.split(self._rng)
-            first, self.kpool, self.vpool = _engine.paged_prefill_batch(
+            first, self.kpool, self.vpool = _llama.paged_prefill_batch(
                 self.params, self.loras, jnp.asarray(aids),
                 jnp.asarray(toks), jnp.asarray(pages), self.kpool,
                 self.vpool, jnp.asarray(true_lens), jnp.asarray(temps),
@@ -327,10 +329,8 @@ class PrefillWorker:
                 prows = self._alloc(k)
                 adopted_of.append(prows)
                 k_stack, v_stack = stacks[j]
-                self.kpool = _engine.scatter_pages(self.kpool, prows,
-                                                   k_stack)
-                self.vpool = _engine.scatter_pages(self.vpool, prows,
-                                                   v_stack)
+                self.kpool = _llama.scatter_pages(self.kpool, prows, k_stack)
+                self.vpool = _llama.scatter_pages(self.vpool, prows, v_stack)
                 mine = self._alloc(-(-len(job.tokens) // self.PS))
                 pages_of.append(mine)
                 toks[j, :len(job.tokens)] = job.tokens
@@ -341,7 +341,7 @@ class PrefillWorker:
                 true_lens[j] = len(job.tokens)
                 temps[j] = job.temperature
             self._rng, sub = jax.random.split(self._rng)
-            first, self.kpool, self.vpool = _engine.paged_prefill_suffix(
+            first, self.kpool, self.vpool = _llama.paged_prefill_suffix(
                 self.params, self.loras, jnp.asarray(aids),
                 jnp.asarray(toks), jnp.asarray(pages), self.kpool,
                 self.vpool, jnp.asarray(prefix_lens),

@@ -22,10 +22,13 @@ ideas onto XLA's static-shape world:
   decode batch can use different adapters (adapter 0 = base model).
 * **The cache is the model's.** The engine owns slots, pages, tables,
   admission, blocks and the loop; what a page HOLDS is declared by the
-  model family's programs (``ServePrograms``, chosen by the config's type):
-  a K pool and a V pool for the Llama family, one latent pool for
-  ``models/mla_moe.py``. The engine carries it as one tuple of pools
-  (``self.cache``), hands it to every program and takes it back donated.
+  model family's programs (``llm/programs.py``: ``ServePrograms``, chosen
+  by the config's type): a K pool and a V pool for the Llama family
+  (``llm/llama.py``), one latent pool for ``llm/mla_moe.py``. The engine
+  carries it as one tuple of pools (``self.cache``), hands it to every
+  program and takes it back donated. No device program is defined here and
+  nothing here reads a weight: engine -> seam -> family programs ->
+  ``models/`` -> ``ops/``.
 """
 from __future__ import annotations
 
@@ -34,646 +37,18 @@ import collections
 import itertools
 import time
 from dataclasses import dataclass, field
-from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.devtools import chaos
-from ray_tpu.models.llama import LlamaConfig
-from ray_tpu.ops.basic import rms_norm, rope, rope_freqs
-from ray_tpu.ops.paged_attention import paged_decode_attention
+# benchmarks/sizing.py imports these two from here (a benchmark file, not
+# this PR's to edit); nothing in this module uses them
+from ray_tpu.llm.llama import (  # noqa: F401
+    paged_decode_multi, paged_prefill_batch)
+from ray_tpu.llm.programs import UnsupportedByModel, serving_programs
 from ray_tpu.utils import metrics, tracing
-
-
-def _lora_delta(h, loras, name, aid):
-    """Per-slot low-rank delta: h[B,T,D] x A[aid][D,r] x Bm[aid][r,O]."""
-    if loras is None:
-        return 0.0
-    a = loras[name + "_a"][aid]  # [B, D, r]
-    b = loras[name + "_b"][aid]  # [B, r, O]
-    return jnp.einsum("btd,bdr->btr", h, a) @ b if a.ndim == 3 else (h @ a) @ b
-
-
-# shared with the static-batch path — one implementation of the numerics
-from ray_tpu.llm.generation import _ffn, _gqa_attn  # noqa: E402
-
-
-def _kv_shape(pool):
-    return (pool["q"] if isinstance(pool, dict) else pool).shape
-
-
-def _kv_write(pool, i, row, off, val):
-    """Store new K/V rows; int8 pools ({"q": int8, "s": f32 scales})
-    quantize symmetrically per (token, kv-head) — one scale per hd
-    vector, the granularity that keeps dequant a fused broadcast-mul.
-
-    val: [..., KV, hd] float; row/off index [L, P, PS] positions."""
-    if not isinstance(pool, dict):
-        return pool.at[i, row, off].set(val)
-    s = jnp.max(jnp.abs(val), axis=-1) / 127.0           # [..., KV]
-    # clip BEFORE the int8 cast: low-precision (bf16) scale rounding can
-    # put the max element's quotient at 128, and float->int overflow is
-    # implementation-defined in XLA (saturates here, wraps elsewhere)
-    q = jnp.clip(jnp.round(val / jnp.maximum(s, 1e-8)[..., None]),
-                 -127, 127).astype(jnp.int8)
-    return {"q": pool["q"].at[i, row, off].set(q),
-            "s": pool["s"].at[i, row, off].set(s.astype(jnp.float32))}
-
-
-def _kv_read(pool, i, page_tables, B, MAXP, PS, KV, hd, dtype):
-    """Gather an attention window ``[B, MAXP * PS, KV, hd]``: every page of
-    every slot's table, live or not — a slice of the layer's pool, the
-    gather itself, then one read by each contraction of ``_gqa_attn``.
-    What still reads the pool this way: several query rows a slot (suffix
-    prefill, speculative decode and verify), int8 pools, and the decode
-    step wherever ``_reads_in_place`` says no. On the chip the decode step
-    of a plain pool does not (``ops/paged_attention.py``): there this
-    window cost half the device's time for a tenth of it live (PERF.md
-    section 6, PR 28), and this function with ``_gqa_attn`` is the plain
-    reference that kernel is tested against. int8 pools move HALF the HBM
-    bytes of bf16 through it; the scale gather is hd-times smaller —
-    noise."""
-    if not isinstance(pool, dict):
-        return pool[i][page_tables].reshape(B, MAXP * PS, KV, hd)
-    q = pool["q"][i][page_tables].reshape(B, MAXP * PS, KV, hd)
-    s = pool["s"][i][page_tables].reshape(B, MAXP * PS, KV, 1)
-    return q.astype(dtype) * s.astype(dtype)
-
-
-@partial(jax.jit, donate_argnums=(0,))
-def _scatter_pages_jit(pool, idx, stack):
-    if isinstance(pool, dict):
-        return {"q": pool["q"].at[:, idx].set(stack["q"]),
-                "s": pool["s"].at[:, idx].set(stack["s"])}
-    return pool.at[:, idx].set(stack.astype(pool.dtype))
-
-
-def scatter_pages(pool, page_ids, stack):
-    """Write an adopted page stack into pool rows ``page_ids`` (device
-    op; the engine runs this at admission points, ordered like a prefill
-    dispatch). ``stack`` is a bare ``[L, n, PS, KV, hd]`` array for plain
-    pools or a ``{"q", "s"}`` dict for int8 pools — the shape
-    ``disagg.adopt_pages`` returns. The pool is DONATED: an unjitted
-    ``.at[].set`` copies the entire pool per adoption (tens of MB for a
-    few adopted KB), which priced cache hits above the prefills they
-    save; callers must rebind their pool to the return value."""
-    idx = jnp.asarray(np.asarray(page_ids, np.int32))
-    if isinstance(pool, dict):
-        stack = {"q": jnp.asarray(stack["q"]), "s": jnp.asarray(stack["s"])}
-    else:
-        stack = jnp.asarray(stack)
-    return _scatter_pages_jit(pool, idx, stack)
-
-
-def _reads_in_place(pool) -> bool:
-    """Whether the decode step's attention reads this pool where it lies
-    (``paged_decode_attention``: only the pages that hold tokens) or
-    through ``_kv_read``'s gathered window. Decided by what the code can
-    see, no option: a plain pool on a TPU takes the kernel. An int8 pool
-    keeps the window (the kernel does not dequantise); so does every other
-    backend, where the kernel would be interpreted (seconds a call site to
-    trace, and nothing to gain); and a single KV head under 32 bits, whose
-    one-row page slice Mosaic refuses (tiling (2, 128))."""
-    if isinstance(pool, dict) or jax.default_backend() != "tpu":
-        return False
-    return pool.shape[3] > 1 or pool.dtype.itemsize >= 4
-
-
-def _sample_tail(logits, temps, key):
-    """The sampling tail every serving program ends in: greedy where a row's
-    temperature is 0, a categorical draw elsewhere. logits: [N, V]; temps:
-    [N]. Returns [N] int32."""
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-    def sampled():
-        # Threefry bits for [N, V] gumbels are NOT free at decode batch
-        # sizes — only pay when some row actually samples
-        s = jax.random.categorical(
-            key, logits / jnp.maximum(temps, 1e-6)[:, None]).astype(jnp.int32)
-        return jnp.where(temps > 0, s, greedy)
-
-    return jax.lax.cond(jnp.any(temps > 0), sampled, lambda: greedy)
-
-
-def _decode_body(params, loras, aids, tokens, pos, page_tables,
-                 kpool, vpool, active, temps, key, cfg: LlamaConfig):
-    """One decode step for every slot (masked where inactive).
-
-    tokens: [B] current input token; pos: [B] tokens already cached (the
-    new token lands at that position); page_tables: [B, MAXP]; aids: [B]
-    adapter ids; temps: [B]. Returns (next_tok [B], kpool, vpool).
-    Pools are either plain [L, P, PS, KV, hd] arrays (cfg dtype) or int8
-    quantized dicts (see _kv_write) — the engine's kv_dtype option.
-
-    Every layer writes the new row into the pools, then attends the
-    slot's ``pos + 1`` positions: in place, page by page through the table
-    (``paged_decode_attention``; an inactive slot attends nothing) where
-    ``_reads_in_place`` holds, else over ``_kv_read``'s whole window with
-    the positions past ``pos`` masked."""
-    B = tokens.shape[0]
-    L, P, PS, KV, hd = _kv_shape(kpool)
-    MAXP = page_tables.shape[1]
-    cos, sin = rope_freqs(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
-    positions = pos[:, None]
-    row = jnp.take_along_axis(page_tables, (pos // PS)[:, None], axis=1)[:, 0]
-    off = pos % PS
-    in_place = _reads_in_place(kpool)
-    if in_place:
-        lengths = jnp.where(active, pos + 1, 0)
-    else:
-        key_idx = jnp.arange(MAXP * PS)
-        mask = key_idx[None, None, :] <= pos[:, None, None]
-
-    Dq = cfg.n_heads * hd
-    Dkv = KV * hd
-    x = params["tok"]["embedding"][tokens][:, None, :]
-    for i in range(cfg.n_layers):
-        layer = params[f"layers_{i}"]
-        h = rms_norm(x, layer["attn_norm"]["scale"])
-        # fused qkv / gate-up matmuls: at decode batch sizes each step is
-        # dominated by per-op dispatch, not FLOPs — the concatenated
-        # weights are loop-invariant, so XLA hoists them out of the scan
-        # and every layer runs 2 fat matmuls instead of 5 thin ones
-        wqkv = jnp.concatenate(
-            [layer["wq"]["kernel"], layer["wk"]["kernel"],
-             layer["wv"]["kernel"]], axis=1)
-        qkv = h @ wqkv
-        q = (qkv[..., :Dq] + _lora_delta(h, loras, "wq", aids)
-             ).reshape(B, 1, cfg.n_heads, hd)
-        k = qkv[..., Dq:Dq + Dkv].reshape(B, 1, KV, hd)
-        v = (qkv[..., Dq + Dkv:] + _lora_delta(h, loras, "wv", aids)
-             ).reshape(B, 1, KV, hd)
-        q = rope(q, cos, sin, positions)
-        k = rope(k, cos, sin, positions)
-        kpool = _kv_write(kpool, i, row, off, k[:, 0])
-        vpool = _kv_write(vpool, i, row, off, v[:, 0])
-        if in_place:
-            att = paged_decode_attention(
-                q[:, 0], kpool, vpool, i, page_tables, lengths)
-        else:
-            kb = _kv_read(kpool, i, page_tables, B, MAXP, PS, KV, hd, k.dtype)
-            vb = _kv_read(vpool, i, page_tables, B, MAXP, PS, KV, hd, v.dtype)
-            att = _gqa_attn(q, kb, vb, mask)
-        x = x + att.reshape(B, 1, -1) @ layer["wo"]["kernel"]
-        hf = rms_norm(x, layer["ffn_norm"]["scale"])
-        w_gu = jnp.concatenate(
-            [layer["w_gate"]["kernel"], layer["w_up"]["kernel"]], axis=1)
-        gu = hf @ w_gu
-        ff = gu.shape[-1] // 2
-        x = x + (jax.nn.silu(gu[..., :ff]) * gu[..., ff:]
-                 ) @ layer["w_down"]["kernel"]
-    x = rms_norm(x, params["norm"]["scale"])
-    logits = x[:, 0] @ params["lm_head"]["kernel"]
-
-    next_tok = _sample_tail(logits, temps, key)
-    return jnp.where(active, next_tok, 0), kpool, vpool
-
-
-@partial(jax.jit, static_argnames=("cfg", "n_steps"), donate_argnums=(6, 7))
-def paged_decode_multi(params, loras, aids, tokens, seq_lens, page_tables,
-                       kpool, vpool, active, temps, key, cfg: LlamaConfig,
-                       n_steps: int):
-    """``n_steps`` fused decode steps as ONE device program (lax.scan).
-
-    Decode is memory-bound; what killed throughput was the per-step host
-    round trip (dispatch latency + arg upload + token download + asyncio),
-    ~100x the step itself. Fusing K steps amortizes all of it K-fold; the
-    host sees tokens in [K, B] blocks. The final (tokens, positions) carry
-    is returned ON DEVICE so consecutive blocks chain without any host
-    round trip — the engine pipelines the next block's dispatch before
-    syncing this block's tokens. Slots that finish mid-block keep decoding
-    junk — a position past the slot's allocated pages writes to and reads
-    from whatever its table holds there (the junk page 0, or a page the
-    table clips to; the in-place kernel walks ``ceil((pos + 1) / PS)``
-    entries of the table, at most all of it, so it fetches those pages like
-    any other), future-position writes are masked until legitimately
-    overwritten, and the host discards the extra tokens, so over-decode is
-    pure (bounded) waste, never corruption. The pools are updated in place
-    through the scan: the kernel reads them as operands and returns only
-    the attended rows (tests/test_chip_compile.py holds the compiled
-    program to no copy of a pool)."""
-    def step(carry, k):
-        tok, pos, kpool, vpool = carry
-        nxt, kpool, vpool = _decode_body(
-            params, loras, aids, tok, pos, page_tables, kpool, vpool,
-            active, temps, jax.random.fold_in(key, k), cfg)
-        return (nxt, pos + 1, kpool, vpool), nxt
-
-    (tok, pos, kpool, vpool), toks = jax.lax.scan(
-        step, (tokens, seq_lens, kpool, vpool), jnp.arange(n_steps))
-    return toks, tok, pos, kpool, vpool
-
-
-@partial(jax.jit, static_argnames=("cfg",), donate_argnums=(5, 6))
-def paged_prefill_batch(params, loras, aids, tokens, pages, kpool, vpool,
-                        true_lens, temps, key, cfg: LlamaConfig):
-    """Prefill a whole admission wave as ONE batched forward.
-
-    tokens: [N, Tp_pad] right-padded prompts (same pad bucket); pages:
-    [N, n_pages] pool pages per request (dummy rows use the junk page 0);
-    true_lens/temps: [N]. Returns (first tokens [N], kpool, vpool).
-    Batching the wave (instead of scanning rows at batch 1) matters
-    because small-batch steps are per-op-overhead bound; one fat forward
-    amortizes it across the whole wave."""
-    N, Tp = tokens.shape
-    L, P, PS, KV, hd = _kv_shape(kpool)
-    cos, sin = rope_freqs(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
-    positions = jnp.arange(Tp)[None, :]
-    idx = jnp.arange(Tp)
-    mask = idx[None, :, None] >= idx[None, None, :]  # causal
-    rows = pages[:, idx // PS]  # [N, Tp] pool row per prompt position
-    offs = jnp.broadcast_to(idx % PS, (N, Tp))
-    x = params["tok"]["embedding"][tokens]  # [N, Tp, D]
-    for i in range(cfg.n_layers):
-        layer = params[f"layers_{i}"]
-        h = rms_norm(x, layer["attn_norm"]["scale"])
-        q = (h @ layer["wq"]["kernel"] + _lora_delta(h, loras, "wq", aids)
-             ).reshape(N, Tp, cfg.n_heads, hd)
-        k = (h @ layer["wk"]["kernel"]).reshape(N, Tp, KV, hd)
-        v = (h @ layer["wv"]["kernel"] + _lora_delta(h, loras, "wv", aids)
-             ).reshape(N, Tp, KV, hd)
-        q = rope(q, cos, sin, positions)
-        k = rope(k, cos, sin, positions)
-        kpool = _kv_write(kpool, i, rows, offs, k)
-        vpool = _kv_write(vpool, i, rows, offs, v)
-        att = _gqa_attn(q, k, v, mask)  # prefill attends the FRESH k/v:
-        # quantization only affects what later decode steps read back
-        x = x + att.reshape(N, Tp, -1) @ layer["wo"]["kernel"]
-        x = _ffn(layer, x)
-    x = rms_norm(x, params["norm"]["scale"])
-    last = jnp.take_along_axis(
-        x, (true_lens - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    logits = last @ params["lm_head"]["kernel"]  # [N, V]
-    return _sample_tail(logits, temps, key), kpool, vpool
-
-
-@partial(jax.jit, static_argnames=("cfg",), donate_argnums=(5, 6))
-def paged_prefill_suffix(params, loras, aids, tokens, pages, kpool, vpool,
-                         prefix_lens, true_lens, temps, key, cfg: LlamaConfig):
-    """Prefill only a prompt's SUFFIX over already-resident prefix KV —
-    the cross-request prefix-cache fast path (vLLM's PagedAttention
-    sharing argument run cross-request: a cached prefix of k full pages
-    is adopted into this pool verbatim and never recomputed).
-
-    tokens: [N, Ts_pad] right-padded suffix tokens; pages: [N, W] page
-    table covering prefix AND suffix positions in prompt order (junk
-    page 0 beyond); prefix_lens: [N] PAGE-ALIGNED token counts already
-    in the pool; true_lens: [N] real suffix lengths. Suffix position j
-    sits at absolute position prefix_len + j, so its KV lands in the
-    suffix pages and its attention window — gathered through the page
-    table exactly like decode — covers the prefix for free. Returns
-    (first tokens [N], kpool, vpool).
-
-    int8 pools: the suffix queries read the prefix (and their own fresh
-    K/V) back through dequantization, where full prefill attends the
-    fresh float K/V directly — parity with the aggregated path is exact
-    for float pools and within quantization noise for int8."""
-    N, Ts = tokens.shape
-    L, P, PS, KV, hd = _kv_shape(kpool)
-    W = pages.shape[1]
-    cos, sin = rope_freqs(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
-    positions = prefix_lens[:, None] + jnp.arange(Ts)[None, :]  # [N, Ts]
-    rows = jnp.take_along_axis(pages, positions // PS, axis=1)
-    offs = positions % PS
-    key_idx = jnp.arange(W * PS)
-    # window index == absolute position (the table is prompt-ordered),
-    # so causal masking is one compare; tail junk-page keys sit past
-    # every real position and mask out
-    mask = key_idx[None, None, :] <= positions[:, :, None]  # [N, Ts, W*PS]
-    x = params["tok"]["embedding"][tokens]
-    for i in range(cfg.n_layers):
-        layer = params[f"layers_{i}"]
-        h = rms_norm(x, layer["attn_norm"]["scale"])
-        q = (h @ layer["wq"]["kernel"] + _lora_delta(h, loras, "wq", aids)
-             ).reshape(N, Ts, cfg.n_heads, hd)
-        k = (h @ layer["wk"]["kernel"]).reshape(N, Ts, KV, hd)
-        v = (h @ layer["wv"]["kernel"] + _lora_delta(h, loras, "wv", aids)
-             ).reshape(N, Ts, KV, hd)
-        q = rope(q, cos, sin, positions)
-        k = rope(k, cos, sin, positions)
-        kpool = _kv_write(kpool, i, rows, offs, k)
-        vpool = _kv_write(vpool, i, rows, offs, v)
-        kb = _kv_read(kpool, i, pages, N, W, PS, KV, hd, k.dtype)
-        vb = _kv_read(vpool, i, pages, N, W, PS, KV, hd, v.dtype)
-        att = _gqa_attn(q, kb, vb, mask)
-        x = x + att.reshape(N, Ts, -1) @ layer["wo"]["kernel"]
-        x = _ffn(layer, x)
-    x = rms_norm(x, params["norm"]["scale"])
-    last = jnp.take_along_axis(
-        x, (true_lens - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    logits = last @ params["lm_head"]["kernel"]
-    return _sample_tail(logits, temps, key), kpool, vpool
-
-
-# --------------------------------------------------------------- speculative
-def _ngram_propose(hist, pos, k: int, m: int):
-    """Self-drafting prompt-lookup (Leviathan-style speculative decoding
-    with the request's OWN history as the drafter): find the most recent
-    earlier occurrence of the trailing ``m``-gram in ``hist`` and
-    propose the ``k`` tokens that followed it. Pure device math — the
-    drafter lives INSIDE the fused scan, so a spec block never pays a
-    host round trip to draft.
-
-    hist: [B, H] token history; positions ``0..pos`` are valid and
-    ``hist[b, pos[b]]`` is the pending input token. Returns
-    (drafts [B, k], draft_len [B]) with draft_len 0 where no match."""
-    B, H = hist.shape
-    n_win = H - m + 1
-    gidx = pos[:, None] - (m - 1) + jnp.arange(m)[None, :]
-    pattern = jnp.take_along_axis(hist, jnp.clip(gidx, 0, H - 1), axis=1)
-    # all H-m+1 windows of width m as m shifted views: wins[b, i, t] =
-    # hist[b, i + t] — one [B, n_win, m] compare finds every candidate
-    wins = jnp.stack([hist[:, t:t + n_win] for t in range(m)], axis=-1)
-    match = jnp.all(wins == pattern[:, None, :], axis=-1)     # [B, n_win]
-    ends = jnp.arange(n_win) + (m - 1)                        # window end j
-    valid = (ends[None, :] < pos[:, None]) & (pos[:, None] >= m)
-    # a match at j proposes the pos-j tokens that FOLLOWED it, capped at
-    # k — so prefer the most recent match with a full k followers (on
-    # periodic text the nearest match sits at pos-1 and would draft just
-    # ONE token), falling back to the nearest match otherwise
-    hit = match & valid
-    j_full = jnp.max(jnp.where(hit & (ends[None, :] <= pos[:, None] - k),
-                               ends[None, :], -1), axis=1)
-    j_any = jnp.max(jnp.where(hit, ends[None, :], -1), axis=1)
-    j = jnp.where(j_full >= 0, j_full, j_any)
-    found = j >= 0
-    dl = jnp.where(found, jnp.minimum(k, pos - j), 0).astype(jnp.int32)
-    didx = j[:, None] + 1 + jnp.arange(k)[None, :]
-    drafts = jnp.take_along_axis(hist, jnp.clip(didx, 0, H - 1), axis=1)
-    return drafts, dl
-
-
-def _spec_verify_body(params, loras, aids, inputs, positions, page_tables,
-                      kpool, vpool, temps, key, cfg: LlamaConfig):
-    """One fused multi-position forward over ``T = k+1`` decode
-    positions per slot — the ``paged_prefill_suffix`` shape run at the
-    decode batch: token j of a slot sits at absolute position
-    ``positions[b, j]``, its KV lands in the slot's pages through the
-    page table, and its attention window (gathered exactly like decode)
-    covers everything at or before it — including the sibling draft
-    positions written THIS step, which is precisely the speculative
-    verification semantics (draft j attends drafts 1..j-1).
-
-    Returns (greedy [B, T] target tokens per position, next0 [B] the
-    position-0 token with sampling applied for temps > 0 rows, kpool,
-    vpool)."""
-    B, T = inputs.shape
-    L, P, PS, KV, hd = _kv_shape(kpool)
-    MAXP = page_tables.shape[1]
-    cos, sin = rope_freqs(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
-    rows = jnp.take_along_axis(page_tables, positions // PS, axis=1)
-    offs = positions % PS
-    key_idx = jnp.arange(MAXP * PS)
-    mask = key_idx[None, None, :] <= positions[:, :, None]  # [B,T,MAXP*PS]
-    Dq = cfg.n_heads * hd
-    Dkv = KV * hd
-    x = params["tok"]["embedding"][inputs]  # [B, T, D]
-    for i in range(cfg.n_layers):
-        layer = params[f"layers_{i}"]
-        h = rms_norm(x, layer["attn_norm"]["scale"])
-        wqkv = jnp.concatenate(
-            [layer["wq"]["kernel"], layer["wk"]["kernel"],
-             layer["wv"]["kernel"]], axis=1)
-        qkv = h @ wqkv
-        q = (qkv[..., :Dq] + _lora_delta(h, loras, "wq", aids)
-             ).reshape(B, T, cfg.n_heads, hd)
-        kk = qkv[..., Dq:Dq + Dkv].reshape(B, T, KV, hd)
-        v = (qkv[..., Dq + Dkv:] + _lora_delta(h, loras, "wv", aids)
-             ).reshape(B, T, KV, hd)
-        q = rope(q, cos, sin, positions)
-        kk = rope(kk, cos, sin, positions)
-        kpool = _kv_write(kpool, i, rows, offs, kk)
-        vpool = _kv_write(vpool, i, rows, offs, v)
-        kb = _kv_read(kpool, i, page_tables, B, MAXP, PS, KV, hd, kk.dtype)
-        vb = _kv_read(vpool, i, page_tables, B, MAXP, PS, KV, hd, v.dtype)
-        att = _gqa_attn(q, kb, vb, mask)
-        x = x + att.reshape(B, T, -1) @ layer["wo"]["kernel"]
-        hf = rms_norm(x, layer["ffn_norm"]["scale"])
-        w_gu = jnp.concatenate(
-            [layer["w_gate"]["kernel"], layer["w_up"]["kernel"]], axis=1)
-        gu = hf @ w_gu
-        ff = gu.shape[-1] // 2
-        x = x + (jax.nn.silu(gu[..., :ff]) * gu[..., ff:]
-                 ) @ layer["w_down"]["kernel"]
-    x = rms_norm(x, params["norm"]["scale"])
-    logits = x @ params["lm_head"]["kernel"]  # [B, T, V]
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-    def sampled():
-        s = jax.random.categorical(
-            key, logits[:, 0] / jnp.maximum(temps, 1e-6)[:, None]
-        ).astype(jnp.int32)
-        return jnp.where(temps > 0, s, greedy[:, 0])
-
-    next0 = jax.lax.cond(jnp.any(temps > 0), sampled, lambda: greedy[:, 0])
-    return greedy, next0, kpool, vpool
-
-
-def _spec_verify_accept(params, loras, aids, tok, pos, drafts, dl,
-                        page_tables, kpool, vpool, active, temps, key,
-                        cfg: LlamaConfig):
-    """Verify ``drafts`` against the target in ONE fused forward and
-    apply the greedy accept rule: accept the longest draft prefix the
-    target agrees with, then take the target's own token at the first
-    disagreement (or the bonus token after a full accept). Emission is
-    token-identical to the non-speculative greedy engine by
-    construction — every emitted token IS the target's argmax given the
-    same prefix. Rejected tail positions hold junk KV that the next
-    step's inputs legitimately overwrite (write-before-read per layer),
-    so rollback is pure position arithmetic: no pool copy.
-
-    Returns (out [B, k+1] emission candidates, n_emit [B], n_acc [B],
-    new_tok [B], new_pos [B], kpool, vpool)."""
-    B, k = drafts.shape
-    inputs = jnp.concatenate([tok[:, None], drafts], axis=1)
-    positions = pos[:, None] + jnp.arange(k + 1)[None, :]
-    greedy, next0, kpool, vpool = _spec_verify_body(
-        params, loras, aids, inputs, positions, page_tables, kpool, vpool,
-        temps, key, cfg)
-    okm = (drafts == greedy[:, :-1]) & (jnp.arange(k)[None, :] < dl[:, None])
-    n_acc = jnp.sum(jnp.cumprod(okm.astype(jnp.int32), axis=1), axis=1)
-    out = jnp.concatenate([next0[:, None], greedy[:, 1:]], axis=1)
-    n_emit = jnp.where(active, n_acc + 1, 0).astype(jnp.int32)
-    new_tok = jnp.where(
-        active, jnp.take_along_axis(out, n_acc[:, None], axis=1)[:, 0], 0)
-    return out, n_emit, n_acc, new_tok, pos + n_acc + 1, kpool, vpool
-
-
-@partial(jax.jit, static_argnames=("cfg", "n_steps", "k", "ngram"),
-         donate_argnums=(5, 7, 8))
-def paged_decode_spec(params, loras, aids, tokens, seq_lens, hist,
-                      page_tables, kpool, vpool, active, spec_ok, temps,
-                      key, cfg: LlamaConfig, n_steps: int, k: int,
-                      ngram: int):
-    """``n_steps`` SPECULATIVE decode steps as one device program: each
-    scan step drafts ``k`` tokens per slot with the on-device n-gram
-    matcher, verifies all of them in one fused multi-position forward,
-    and advances each slot by ``n_acc + 1`` positions — so one host
-    round trip can emit up to ``n_steps * (k + 1)`` tokens instead of
-    ``n_steps``. The (token, position, history) carry chains on device
-    between blocks exactly like ``paged_decode_multi``'s; slots where
-    ``spec_ok`` is False (sampled rows, per-request opt-out) run with
-    draft_len 0, i.e. plain one-token decode — a mixed spec/plain wave
-    is one program, one compiled bucket per (n_steps, k).
-
-    Returns (toks [S, B, k+1], n_emit [S, B], n_prop [S, B], tok, pos,
-    hist, kpool, vpool); the host emits the first ``n_emit[s, b]``
-    tokens of each row and discards the rest (the rollback)."""
-    def step(carry, s):
-        tok, pos, hist, kpool, vpool = carry
-        drafts, dl = _ngram_propose(hist, pos, k, ngram)
-        dl = jnp.where(spec_ok, dl, 0)
-        out, n_emit, n_acc, tok, pos, kpool, vpool = _spec_verify_accept(
-            params, loras, aids, tok, pos, drafts, dl, page_tables,
-            kpool, vpool, active, temps, jax.random.fold_in(key, s), cfg)
-        # record the emitted tokens into the history so the NEXT step's
-        # n-gram drafter sees them (indices past n_acc drop out-of-bounds)
-        B, H = hist.shape
-        widx = pos[:, None] - n_acc[:, None] + jnp.arange(k + 1)[None, :]
-        widx = jnp.where(jnp.arange(k + 1)[None, :] <= n_acc[:, None],
-                         widx, H)
-        hist = hist.at[jnp.arange(B)[:, None], widx].set(out, mode="drop")
-        return (tok, pos, hist, kpool, vpool), (out, n_emit, dl)
-
-    (tok, pos, hist, kpool, vpool), (toks, n_emit, n_prop) = jax.lax.scan(
-        step, (tokens, seq_lens, hist, kpool, vpool), jnp.arange(n_steps))
-    return toks, n_emit, n_prop, tok, pos, hist, kpool, vpool
-
-
-@partial(jax.jit, static_argnames=("cfg", "k"), donate_argnums=(7, 8))
-def paged_decode_verify(params, loras, aids, tokens, seq_lens, drafts,
-                        page_tables, kpool, vpool, draft_lens, active,
-                        temps, key, cfg: LlamaConfig, k: int):
-    """One speculative step with HOST-provided drafts — the drafter-hook
-    path (``spec_drafter=``: a real small model, a custom matcher). Same
-    verify/accept as the fused scan, but one step per dispatch since the
-    host drafter needs the accepted tokens back before proposing the
-    next window. Returns (toks [B, k+1], n_emit [B], n_prop [B], tok,
-    pos, kpool, vpool)."""
-    out, n_emit, n_acc, tok, pos, kpool, vpool = _spec_verify_accept(
-        params, loras, aids, tokens, seq_lens, drafts, draft_lens,
-        page_tables, kpool, vpool, active, temps, key, cfg)
-    return out, n_emit, draft_lens, tok, pos, kpool, vpool
-
-
-def make_lora_stack(cfg: LlamaConfig, adapters: dict[str, dict], rank: int):
-    """Stack named adapters into gatherable arrays. Index 0 is the base
-    model (zero delta). adapters: name -> {"wq_a": [D,r], "wq_b": [r,O],
-    "wv_a": ..., "wv_b": ...}. Returns (stack dict, name->index map)."""
-    D = cfg.d_model
-    O_q = cfg.n_heads * cfg.head_dim
-    O_v = cfg.n_kv_heads * cfg.head_dim
-    names = ["__base__"] + sorted(adapters)
-    idx = {n: i for i, n in enumerate(names)}
-    stack = {
-        "wq_a": np.zeros((len(names), D, rank), np.float32),
-        "wq_b": np.zeros((len(names), rank, O_q), np.float32),
-        "wv_a": np.zeros((len(names), D, rank), np.float32),
-        "wv_b": np.zeros((len(names), rank, O_v), np.float32),
-    }
-    for name, ad in adapters.items():
-        i = idx[name]
-        for k in stack:
-            if k in ad:
-                stack[k][i] = np.asarray(ad[k], np.float32)
-    return {k: jnp.asarray(v) for k, v in stack.items()}, idx
-
-
-def make_kv_pools(cfg: LlamaConfig, page_size: int, n_pages: int,
-                  kv_dtype: str | None):
-    """One (kpool, vpool) pair for a paged cache: plain
-    ``[L, P, PS, KV, hd]`` arrays for native/bf16, ``{"q", "s"}``
-    quantized dicts for int8. Shared by the engine and the disagg
-    prefill workers so the two pools are structurally identical and a
-    page sliced from one scatters into the other."""
-    dtype = jnp.dtype(cfg.dtype)
-    pool_shape = (cfg.n_layers, n_pages, page_size, cfg.n_kv_heads,
-                  cfg.head_dim)
-    if kv_dtype == "int8":
-        # quantized cache: half the HBM bytes through the decode
-        # page-table gather (the bottleneck past ~64 slots) at the
-        # cost of per-(token, kv-head) symmetric int8 rounding
-        def make_pool():
-            return {"q": jnp.zeros(pool_shape, jnp.int8),
-                    "s": jnp.zeros(pool_shape[:-1], jnp.float32)}
-
-        return make_pool(), make_pool()
-    if kv_dtype in (None, "native"):
-        kpool = jnp.zeros(pool_shape, dtype)
-        return kpool, jnp.zeros_like(kpool)
-    if kv_dtype == "bf16":
-        # explicit half-precision cache, regardless of cfg.dtype
-        kpool = jnp.zeros(pool_shape, jnp.bfloat16)
-        return kpool, jnp.zeros_like(kpool)
-    raise ValueError(f"unknown kv_dtype {kv_dtype!r}")
-
-
-class UnsupportedByModel(NotImplementedError):
-    """A feature of the engine that a model family's programs do not have,
-    refused by name (never a silent read of a pool that is not there)."""
-
-    def __init__(self, feature: str, family: str):
-        super().__init__(
-            f"{feature} is not supported for the {family!r} model family: "
-            f"it assumes a K pool and a V pool of n_kv_heads x head_dim")
-        self.feature, self.family = feature, family
-
-
-@dataclass(frozen=True)
-class ServePrograms:
-    """What the engine needs of a model family. The cache is a TUPLE of
-    pools ``make_cache`` builds; every program takes its members in place
-    (after ``page_tables`` in decode, after ``pages`` in prefill) and
-    returns them last, donated — so the engine threads ``*self.cache``
-    through without knowing what a page holds.
-
-    ``decode_multi(params, loras, aids, tokens, seq_lens, page_tables,
-    *cache, active, temps, key, cfg, n_steps) -> (rows [K, B + len(stats)],
-    tok, pos, *cache)``: a step's row holds the B tokens and then one int32
-    per name in ``stats`` (the model's own per-step sums, read at the
-    block's one sync). ``prefill_batch(params, loras, aids, tokens, pages,
-    *cache, true_lens, temps, key, cfg) -> (first [N], *cache)``.
-    ``decode_in_place(cache) -> bool``: whether ``decode_multi`` fetches
-    only the pages of that cache that hold tokens; None where it gathers
-    every slot's whole table a step (what the read counters then report).
-    The rest are the Llama family's and None elsewhere: the engine refuses
-    what needs them."""
-    family: str
-    make_cache: callable
-    decode_multi: callable
-    prefill_batch: callable
-    stats: tuple = ()
-    decode_in_place: callable = None
-    prefill_suffix: callable = None
-    decode_spec: callable = None
-    decode_verify: callable = None
-    lora: bool = False
-    int8_cache: bool = False
-    page_plane: bool = False   # export_pages / submit_prefilled (disagg)
-
-
-def serving_programs(cfg) -> ServePrograms:
-    """The programs that serve ``cfg``, by its type: no option chooses."""
-    if isinstance(cfg, LlamaConfig):
-        return LLAMA_PROGRAMS
-    from ray_tpu.models.mla_moe import MlaMoeConfig
-
-    if isinstance(cfg, MlaMoeConfig):
-        from ray_tpu.llm.mla_moe import PROGRAMS
-
-        return PROGRAMS
-    raise TypeError(f"no serving programs for a {type(cfg).__name__}")
-
-
-LLAMA_PROGRAMS = ServePrograms(
-    family="llama", make_cache=make_kv_pools,
-    decode_multi=paged_decode_multi, prefill_batch=paged_prefill_batch,
-    decode_in_place=lambda cache: _reads_in_place(cache[0]),
-    prefill_suffix=paged_prefill_suffix, decode_spec=paged_decode_spec,
-    decode_verify=paged_decode_verify, lora=True, int8_cache=True,
-    page_plane=True)
 
 
 @dataclass
@@ -717,7 +92,7 @@ class ContinuousBatchingEngine:
     """Single-process engine; drive with ``await engine.start()`` then
     ``submit`` / ``stream`` from the same event loop."""
 
-    def __init__(self, params, cfg: LlamaConfig, *, max_batch: int = 8,
+    def __init__(self, params, cfg, *, max_batch: int = 8,
                  page_size: int = 16, n_pages: int = 256,
                  max_seq_len: int = 512, eos_id: int | None = None,
                  lora_adapters: dict[str, dict] | None = None,
@@ -741,7 +116,7 @@ class ContinuousBatchingEngine:
         self.programs = P = serving_programs(cfg)
         for feature, asked, has in (
                 ("kv_dtype='int8'", kv_dtype == "int8", P.int8_cache),
-                ("lora_adapters", bool(lora_adapters), P.lora),
+                ("lora_adapters", bool(lora_adapters), P.lora is not None),
                 ("spec_enable", bool(spec_enable), P.decode_spec is not None)):
             if asked and not has:
                 raise UnsupportedByModel(feature, P.family)
@@ -755,7 +130,7 @@ class ContinuousBatchingEngine:
         self.loras = None
         self.lora_index = {"__base__": 0}
         if lora_adapters:
-            self.loras, self.lora_index = make_lora_stack(
+            self.loras, self.lora_index = P.lora(
                 cfg, lora_adapters, lora_rank)
         # slot state (host side)
         self.slot_req: list[_Request | None] = [None] * self.B
@@ -1179,6 +554,8 @@ class ContinuousBatchingEngine:
                 groups.setdefault(Tp_pad, []).append(nxt)
             ph.set(prompts=len(adopted) + sum(map(len, groups.values())))
         out = []
+        if adopted:
+            from ray_tpu.llm.disagg.kv_plane import scatter_pages
         for req in adopted:
             # slot adoption (llm/disagg): the prompt KV was produced by a
             # prefill worker and fetched via the KV-page plane — scatter

@@ -1,6 +1,6 @@
 """The serving programs of ``models/mla_moe.py`` for the continuous-batching
 engine: same slots, pages, tables and block pipeline as the Llama programs
-of ``llm/engine.py``, another cache and another layer.
+of ``llm/llama.py``, another cache and another layer.
 
 * **The cache is one latent pool** ``[L, P, PS, r + rope]``: a token leaves
   its normed latent ``c`` and its one rotary key ``k_rope`` per layer (576
@@ -29,7 +29,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.llm.engine import ServePrograms, _sample_tail
+from ray_tpu.llm.programs import ServePrograms, _sample_tail
 from ray_tpu.models.mla_moe import (
     MlaMoeConfig, mla_attend_absorbed, mla_attend_expanded, mla_moe_ffn,
     mla_project)
@@ -99,7 +99,7 @@ def mla_moe_decode_multi(params, loras, aids, tokens, seq_lens, page_tables,
                          pool, active, temps, key, cfg: MlaMoeConfig,
                          n_steps: int):
     """``n_steps`` fused decode steps as one device program: the contract of
-    ``engine.paged_decode_multi`` with one latent pool in place of the K
+    ``llm/llama.py`` ``paged_decode_multi`` with one latent pool in place of the K
     and V pools, and rows of ``[B tokens | STATS]``. ``loras``/``aids`` are
     the engine's (None / zeros here: refused at construction)."""
     def step(carry, k):
@@ -118,7 +118,7 @@ def mla_moe_decode_multi(params, loras, aids, tokens, seq_lens, page_tables,
 def mla_moe_prefill_batch(params, loras, aids, tokens, pages, pool,
                           true_lens, temps, key, cfg: MlaMoeConfig):
     """Prefill a whole admission wave as one batched forward: the contract
-    of ``engine.paged_prefill_batch``. Attention is over the wave's FRESH
+    of ``llm/llama.py`` ``paged_prefill_batch``. Attention is over the wave's FRESH
     cache rows, expanded; what it writes to the pool is what decode reads
     back absorbed. Returns (first tokens [N], pool)."""
     N, Tp = tokens.shape

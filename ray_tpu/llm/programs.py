@@ -1,0 +1,92 @@
+"""The seam between the engine and the model families. ``llm/engine.py``
+owns slots, pages, admission and the loops and knows no model: it asks
+``serving_programs(cfg)`` for the family's ``ServePrograms`` by the config's
+TYPE and threads the family's cache through unseen. A family's program
+module (``llm/llama.py``, ``llm/mla_moe.py``) imports this file, ``models/``
+and ``ops/``, never the engine, and is imported when its config is served.
+A new family supplies a config type, its layer's halves in ``models/``, two
+jitted programs, a cache, and one branch of ``serving_programs``.
+"""
+from __future__ import annotations
+
+from dataclasses import dataclass
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.models.llama import LlamaConfig
+from ray_tpu.models.mla_moe import MlaMoeConfig
+
+
+class UnsupportedByModel(NotImplementedError):
+    """A feature of the engine that a model family's programs do not have,
+    refused by name (never a silent read of a pool that is not there)."""
+
+    def __init__(self, feature: str, family: str):
+        super().__init__(
+            f"{feature} is not supported for the {family!r} model family: "
+            f"it assumes a K pool and a V pool of n_kv_heads x head_dim")
+        self.feature, self.family = feature, family
+
+
+@dataclass(frozen=True)
+class ServePrograms:
+    """What the engine needs of a model family. The cache is a TUPLE of
+    pools ``make_cache`` builds; every program takes its members in place
+    (after ``page_tables`` in decode, after ``pages`` in prefill) and
+    returns them last, donated — so the engine threads ``*self.cache``
+    through without knowing what a page holds.
+
+    ``decode_multi(params, loras, aids, tokens, seq_lens, page_tables,
+    *cache, active, temps, key, cfg, n_steps) -> (rows [K, B + len(stats)],
+    tok, pos, *cache)``: a step's row holds the B tokens and then one int32
+    per name in ``stats`` (the model's own per-step sums, read at the
+    block's one sync). ``prefill_batch(params, loras, aids, tokens, pages,
+    *cache, true_lens, temps, key, cfg) -> (first [N], *cache)``.
+    ``decode_in_place(cache) -> bool``: whether ``decode_multi`` fetches
+    only the pages of that cache that hold tokens; None where it gathers
+    every slot's whole table a step (what the read counters then report).
+    ``lora(cfg, adapters, rank) -> (stack, name -> index)`` stacks named
+    adapters. It and the rest are the Llama family's and None elsewhere: the
+    engine refuses what needs them."""
+    family: str
+    make_cache: callable
+    decode_multi: callable
+    prefill_batch: callable
+    stats: tuple = ()
+    decode_in_place: callable = None
+    prefill_suffix: callable = None
+    decode_spec: callable = None
+    decode_verify: callable = None
+    lora: callable = None
+    int8_cache: bool = False
+    page_plane: bool = False   # export_pages / submit_prefilled (disagg)
+
+
+def serving_programs(cfg) -> ServePrograms:
+    """The programs that serve ``cfg``, by its type: no option chooses."""
+    if isinstance(cfg, LlamaConfig):
+        from ray_tpu.llm.llama import PROGRAMS
+
+        return PROGRAMS
+    if isinstance(cfg, MlaMoeConfig):
+        from ray_tpu.llm.mla_moe import PROGRAMS
+
+        return PROGRAMS
+    raise TypeError(f"no serving programs for a {type(cfg).__name__}")
+
+
+def _sample_tail(logits, temps, key):
+    """The sampling tail every serving program ends in: greedy where a row's
+    temperature is 0, a categorical draw elsewhere. logits: [N, V]; temps:
+    [N]. Returns [N] int32."""
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    def sampled():
+        # Threefry bits for [N, V] gumbels are NOT free at decode batch
+        # sizes — only pay when some row actually samples
+        s = jax.random.categorical(
+            key, logits / jnp.maximum(temps, 1e-6)[:, None]).astype(jnp.int32)
+        return jnp.where(temps > 0, s, greedy)
+
+    return jax.lax.cond(jnp.any(temps > 0), sampled, lambda: greedy)

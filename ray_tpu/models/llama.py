@@ -8,17 +8,21 @@ in jax.checkpoint (remat) to trade FLOPs for HBM, and optional MoE layers
 (``n_experts`` > 0, every ``moe_every``-th layer) use the Switch top-1,
 capacity-dropping, two-matrix branch of parallel/moe.py (``moe_ffn``) —
 reachable from ``llama_forward`` in training only: the serving programs of
-llm/engine.py have no expert path for this family. The expert layer that
+llm/llama.py have no expert path for this family. The expert layer that
 IS served (sigmoid top-k, no capacity, SwiGLU experts, shared experts) is
 the other family's: models/mla_moe.py. Matches the model families the
-reference serves through vLLM (Llama-2/3 in BASELINE.json north-star
-configs) but as a native JAX program.
+reference serves through vLLM (Llama-2/3) but as a native JAX program.
+
+The dense layer is written ONCE (``llama_project``, ``llama_attn_out``,
+``llama_ffn``), as models/mla_moe.py writes its own. Where K and V are
+written and what is attended lies between them and is each path's own:
+``_block``, ``_block_tp``, llm/generation.py, the programs of llm/llama.py.
+Nothing else reads a dense layer's seven kernels (tests/test_llm.py).
 """
 
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -114,15 +118,75 @@ def llama_init(key, cfg: LlamaConfig) -> dict:
     return params
 
 
-def _block(layer, x, cos, sin, cfg: LlamaConfig, mesh, attn_impl, seq_axis):
-    B, T, D = x.shape
-    hd = cfg.head_dim
+# ------------------------------------------------- the dense layer, written once
+def _lora_delta(h, loras, name, aid):
+    """Per-slot low-rank delta: h[B,T,D] x A[aid][D,r] x Bm[aid][r,O]."""
+    a = loras[name + "_a"][aid]  # [B, D, r]
+    b = loras[name + "_b"][aid]  # [B, r, O]
+    return jnp.einsum("btd,bdr->btr", h, a) @ b if a.ndim == 3 else (h @ a) @ b
+
+
+def llama_project(layer, x, cos, sin, positions, cfg: LlamaConfig, *,
+                  loras=None, aids=None, fused: bool = False):
+    """The layer's first half on the residual ``x`` [B, T, D]: norm, q/k/v
+    (plus the LoRA deltas of the slots' adapters ``aids`` on q and v where
+    ``loras`` is given), rope at ``positions`` ([B, T]; None = 0..T-1).
+    Returns q [B, T, H, hd], k and v [B, T, KV, hd]: head counts are the
+    kernels' widths over ``head_dim``, so a tensor-parallel slice is the
+    same call.
+
+    ``fused``: ONE matmul against ``wq|wk|wv``. At a decode step's few rows
+    a layer is bound by its count of operations, not FLOPs, and XLA hoists
+    the loop-invariant concatenation out of the step scan; decode and verify
+    pass it, the prefills (fat matmuls already) do not."""
+    B, T, _ = x.shape
     h = rms_norm(x, layer["attn_norm"]["scale"])
-    q = (h @ layer["wq"]["kernel"]).reshape(B, T, cfg.n_heads, hd)
-    k = (h @ layer["wk"]["kernel"]).reshape(B, T, cfg.n_kv_heads, hd)
-    v = (h @ layer["wv"]["kernel"]).reshape(B, T, cfg.n_kv_heads, hd)
-    q = rope(q, cos, sin)
-    k = rope(k, cos, sin)
+    wq, wk, wv = (layer["wq"]["kernel"], layer["wk"]["kernel"],
+                  layer["wv"]["kernel"])
+    if fused:
+        nq, nkv = wq.shape[1], wk.shape[1]
+        qkv = h @ jnp.concatenate([wq, wk, wv], axis=1)
+        q, k, v = qkv[..., :nq], qkv[..., nq:nq + nkv], qkv[..., nq + nkv:]
+    else:
+        q, k, v = h @ wq, h @ wk, h @ wv
+    if loras is not None:
+        q = q + _lora_delta(h, loras, "wq", aids)
+        v = v + _lora_delta(h, loras, "wv", aids)
+    q, k, v = (t.reshape(B, T, -1, cfg.head_dim) for t in (q, k, v))
+    return rope(q, cos, sin, positions), rope(k, cos, sin, positions), v
+
+
+def _rejoin(y, tp_axis):
+    """A row-parallel product back on the residual stream: summed over the
+    tensor-parallel ranks inside a shard_map body, itself elsewhere."""
+    return y if tp_axis is None else jax.lax.psum(y, tp_axis)
+
+
+def llama_attn_out(layer, x, att, tp_axis: str | None = None):
+    """The attended rows ``att`` [B, T, H, hd] (or [B, H, hd] for T = 1)
+    through ``wo``, onto the residual ``x`` [B, T, D]."""
+    B, T, _ = x.shape
+    return x + _rejoin(att.reshape(B, T, -1) @ layer["wo"]["kernel"], tp_axis)
+
+
+def llama_ffn(layer, x, *, fused: bool = False, tp_axis: str | None = None):
+    """The layer's second half on the residual ``x``: norm, SwiGLU,
+    residual. ``fused`` as in ``llama_project``: one matmul against
+    ``w_gate|w_up``."""
+    h = rms_norm(x, layer["ffn_norm"]["scale"])
+    w_gate, w_up, w_down = (layer["w_gate"]["kernel"], layer["w_up"]["kernel"],
+                            layer["w_down"]["kernel"])
+    if fused:
+        gu = h @ jnp.concatenate([w_gate, w_up], axis=1)
+        ff = gu.shape[-1] // 2
+        y = (jax.nn.silu(gu[..., :ff]) * gu[..., ff:]) @ w_down
+    else:
+        y = swiglu(h, w_gate, w_up, w_down)
+    return x + _rejoin(y, tp_axis)
+
+
+def _block(layer, x, cos, sin, cfg: LlamaConfig, mesh, attn_impl, seq_axis):
+    q, k, v = llama_project(layer, x, cos, sin, None, cfg)
     # named for the remat policy: the flash backward consumes q/k/v
     # directly, so saving them skips recomputing three projections + rope
     # per layer in the backward pass (bytes: 3*d_model*T per layer)
@@ -134,26 +198,20 @@ def _block(layer, x, cos, sin, cfg: LlamaConfig, mesh, attn_impl, seq_axis):
     # the O(T^2) attention forward in the backward pass costs ~10 MFU
     # points at 8k context, while saving att is only d_model*T per layer
     att = _checkpoint_name(att, "attn_out")
-    x = x + att.reshape(B, T, cfg.n_heads * hd) @ layer["wo"]["kernel"]
+    x = llama_attn_out(layer, x, att)
+    if "moe" not in layer:
+        return llama_ffn(layer, x), 0.0
+    from ray_tpu.parallel.moe import moe_ffn
 
-    h = rms_norm(x, layer["ffn_norm"]["scale"])
-    if "moe" in layer:
-        from ray_tpu.parallel.moe import moe_ffn
-
-        out, aux = moe_ffn(
-            h,
-            layer["moe"]["gate"]["kernel"],
-            layer["moe"]["w_up"]["kernel"],
-            layer["moe"]["w_down"]["kernel"],
-            capacity_factor=cfg.capacity_factor,
-            mesh=mesh,
-        )
-        x = x + out
-    else:
-        aux = 0.0
-        x = x + swiglu(h, layer["w_gate"]["kernel"], layer["w_up"]["kernel"],
-                       layer["w_down"]["kernel"])
-    return x, aux
+    out, aux = moe_ffn(
+        rms_norm(x, layer["ffn_norm"]["scale"]),
+        layer["moe"]["gate"]["kernel"],
+        layer["moe"]["w_up"]["kernel"],
+        layer["moe"]["w_down"]["kernel"],
+        capacity_factor=cfg.capacity_factor,
+        mesh=mesh,
+    )
+    return x + out, aux
 
 
 def _maybe_remat_block(cfg: LlamaConfig):
@@ -234,25 +292,11 @@ def _block_tp(layer, x, cos, sin, cfg: LlamaConfig, tp_axis: str):
     shard_map body (each tp rank holds a weight slice): q/k/v and
     gate/up are column-parallel (heads / ff split across ranks), wo and
     w_down row-parallel with a psum to rejoin the residual stream."""
-    from jax import lax
-
-    B, T, D = x.shape
-    hd = cfg.head_dim
-    tp = lax.axis_size(tp_axis)
-    h = rms_norm(x, layer["attn_norm"]["scale"])
-    q = (h @ layer["wq"]["kernel"]).reshape(B, T, cfg.n_heads // tp, hd)
-    k = (h @ layer["wk"]["kernel"]).reshape(B, T, cfg.n_kv_heads // tp, hd)
-    v = (h @ layer["wv"]["kernel"]).reshape(B, T, cfg.n_kv_heads // tp, hd)
-    q = rope(q, cos, sin)
-    k = rope(k, cos, sin)
+    q, k, v = llama_project(layer, x, cos, sin, None, cfg)
     att = attention(q, k, v, causal=True, mesh=None, seq_axis=None,
                     impl="plain")
-    att = lax.psum(att.reshape(B, T, -1) @ layer["wo"]["kernel"], tp_axis)
-    x = x + att
-    h = rms_norm(x, layer["ffn_norm"]["scale"])
-    ffn = (jax.nn.silu(h @ layer["w_gate"]["kernel"])
-           * (h @ layer["w_up"]["kernel"])) @ layer["w_down"]["kernel"]
-    return x + lax.psum(ffn, tp_axis)
+    x = llama_attn_out(layer, x, att, tp_axis)
+    return llama_ffn(layer, x, tp_axis=tp_axis)
 
 
 def pp_stage_param_specs(stacked_params, *, pp_axis: str = "pp",

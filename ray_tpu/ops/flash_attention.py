@@ -331,7 +331,7 @@ _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 # r5 sweep on v5e, 551M model, T=8192 train step (MFU): 512/512 54.2,
 # 512/1024 59.4, 1024/512 55.9, **1024/1024 61.7**; bk=2048 overflows
 # VMEM. Bigger tiles amortize the online-softmax rescale + mask overhead
-# over 4x the MXU work per grid cell. Full table in BENCHVS.md.
+# over 4x the MXU work per grid cell.
 _BLOCK_Q = int(os.environ.get("RT_FLASH_BLOCK_Q", "1024"))
 _BLOCK_K = int(os.environ.get("RT_FLASH_BLOCK_K", "1024"))
 

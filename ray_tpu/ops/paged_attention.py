@@ -6,7 +6,7 @@ walks the slot's page table and fetches only the pages that hold tokens, a
 page of a layer being one contiguous ``[PS, KV, hd]`` run. Nothing is sliced
 out of the pool, no ``[B, MAXP * PS, KV, hd]`` window is gathered, and no
 position past a slot's length is contracted (what ``_kv_read`` +
-``_gqa_attn`` do, and stay the plain reference for: ``llm/engine.py``).
+``_gqa_attn`` do, and stay the plain reference for: ``llm/llama.py``).
 
 One kernel invocation serves every slot: a work list of (slot, block) items,
 a block being ``n_pages`` pages (256 tokens), runs through two VMEM buffers — the

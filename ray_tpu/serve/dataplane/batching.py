@@ -24,7 +24,7 @@ import math
 
 
 def _p99(vals) -> float:
-    """Nearest-rank p99 (the repo-wide convention; bench.py, recorder)."""
+    """Nearest-rank p99 (the repo-wide convention, as in the recorder)."""
     s = sorted(vals)
     return s[max(0, math.ceil(len(s) * 0.99) - 1)]
 

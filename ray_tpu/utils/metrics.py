@@ -249,7 +249,7 @@ llm_decode_kv_tokens_read_total = Counter(
     "rt_llm_decode_kv_tokens_read_total",
     "positions the decode programs fetched from the page pool for them")
 # What a model family's decode programs count themselves, a step
-# (llm/engine.py ServePrograms.stats): the sums ride back with each block's
+# (llm/programs.py ServePrograms.stats): the sums ride back with each block's
 # tokens and land here when the block is synced. The expert layers of
 # llm/mla_moe.py: rows routed to the experts held here, distinct experts
 # that got any, the largest expert's rows, and experts held x expert

@@ -26,8 +26,8 @@ Overhead budget: the recorder is ON by default and the task hot path
 pays one ``record()`` per process per task (driver: one latency sample
 at reply-apply; worker: one compact task record at exec end). Each
 ``record()`` is one ``struct.pack_into`` into the mapped ring plus an
-index store — sub-microsecond; ``bench.py`` measures the end-to-end A/B
-as ``recorder_overhead_us`` and the budget is < 1µs/task.
+index store — sub-microsecond; the budget is < 1µs/task end to end
+(``recorder_enabled`` is the A/B switch).
 """
 
 from __future__ import annotations

@@ -104,7 +104,7 @@ def test_paged_decode_multi_compiles(one_chip, monkeypatch, seq, kv_heads):
     by the paged kernel (``_reads_in_place`` asks ``jax.default_backend()``,
     which here is the CPU: the test answers for it, as for the flash
     kernels below)."""
-    from ray_tpu.llm.engine import paged_decode_multi
+    from ray_tpu.llm.llama import paged_decode_multi
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     paged_decode_multi.clear_cache()
@@ -146,7 +146,7 @@ def test_paged_decode_multi_compiles(one_chip, monkeypatch, seq, kv_heads):
 
 
 def test_paged_prefill_batch_compiles(one_chip):
-    from ray_tpu.llm.engine import paged_prefill_batch
+    from ray_tpu.llm.llama import paged_prefill_batch
 
     cfg, params, pool, key = _engine_args(one_chip, 2)
     N, Tp = 4, 256  # a wave of four 256-token prompts

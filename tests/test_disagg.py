@@ -221,11 +221,11 @@ def test_ship_adopt_roundtrip(rt):
     byte-identical, and the ledger counts payload bytes off-driver."""
     import jax.numpy as jnp
 
-    from ray_tpu.llm import engine as _engine
+    from ray_tpu.llm import llama as _programs
     from ray_tpu.llm.disagg import telemetry
 
     cfg = _tiny_cfg()
-    kpool, vpool = _engine.make_kv_pools(cfg, PS, 16, None)
+    kpool, vpool = _programs.make_kv_pools(cfg, PS, 16, None)
     rng = np.random.default_rng(0)
     kpool = jnp.asarray(rng.normal(size=kpool.shape), kpool.dtype)
     vpool = jnp.asarray(rng.normal(size=vpool.shape), vpool.dtype)

@@ -331,10 +331,10 @@ def test_engine_serve_streaming(rt, tiny):
 
 def test_int8_kv_quantize_roundtrip():
     """The per-(token, kv-head) symmetric int8 quantizer loses < 1% on
-    typical KV magnitudes (engine._kv_write/_kv_read contract)."""
+    typical KV magnitudes (llm/llama.py _kv_write/_kv_read contract)."""
     import jax.numpy as jnp
 
-    from ray_tpu.llm.engine import _kv_read, _kv_write
+    from ray_tpu.llm.llama import _kv_read, _kv_write
 
     rng = np.random.default_rng(0)
     L, P, PS, KV, hd = 1, 4, 8, 2, 16
@@ -347,7 +347,7 @@ def test_int8_kv_quantize_roundtrip():
     pool = _kv_write(pool, 0, row, off, val)
     # read the page back through the gather path (1 "slot" seeing page 2)
     page_tables = jnp.asarray([[2]], jnp.int32)
-    got = _kv_read(pool, 0, page_tables, 1, 1, PS, KV, hd, jnp.float32)
+    got = _kv_read(pool, 0, page_tables, jnp.float32)
     err = jnp.abs(got[0] - val) / (jnp.max(jnp.abs(val)) + 1e-9)
     assert float(jnp.max(err)) < 0.01, float(jnp.max(err))
 
@@ -399,7 +399,7 @@ def test_gqa_attn_matches_repeat_then_attend(group, tq, dtype):
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.llm.generation import _gqa_attn
+    from ray_tpu.llm.llama import _gqa_attn
 
     B, KV, d, tk = 3, 2, 16, 11
     H = KV * group
@@ -479,20 +479,20 @@ def test_engine_decode_in_place_matches_gathered(tiny, eos_id, monkeypatch):
     inside a block; and the two read counters say which path ran. On this
     backend the engine gathers unless ``_reads_in_place`` is answered for
     it, as here — on a TPU a plain pool takes the kernel by itself."""
-    from ray_tpu.llm import engine
+    from ray_tpu.llm import llama as programs
 
     cfg, params = tiny
-    assert not engine._reads_in_place(engine.make_kv_pools(cfg, 8, 4, None)[0])
+    assert not programs._reads_in_place(programs.make_kv_pools(cfg, 8, 4, None)[0])
     grown_g, *gathered = _lone_then_admission(params, cfg, eos_id)
     assert [len(o) for o in gathered] == [9, 14, 6]
 
-    monkeypatch.setattr(engine, "_reads_in_place",
+    monkeypatch.setattr(programs, "_reads_in_place",
                         lambda pool: not isinstance(pool, dict))
-    engine.paged_decode_multi.clear_cache()  # traced with the other answer
+    programs.paged_decode_multi.clear_cache()  # traced with the other answer
     try:
         grown_k, *in_place = _lone_then_admission(params, cfg, eos_id)
     finally:
-        engine.paged_decode_multi.clear_cache()
+        programs.paged_decode_multi.clear_cache()
     assert in_place == gathered
 
     if eos_id is None:
@@ -506,3 +506,72 @@ def test_engine_decode_in_place_matches_gathered(tiny, eos_id, monkeypatch):
         assert min(grown_g[0], grown_k[0]) >= sum(range(6, 14))
         assert grown_g[1] % (4 * 3 * 8 * 8) == 0
         assert grown_k[0] <= grown_k[1] < 2 * grown_k[0]  # whole pages of 8
+
+
+# ------------------------------------------------------------------ the seam
+_KERNEL_READ = (r'(?<!\["moe"\])\[\s*"(wq|wk|wv|wo|w_gate|w_up|w_down)"\s*\]')
+
+
+def _weights_read_in_one_place():
+    """A dense Llama layer's seven kernels are subscripted by the shared
+    halves of ``models/llama.py`` and by nothing else: no other function of
+    that file (``llama_init`` builds them; the ``moe`` sub-tree, the LoRA
+    stack's ``wq_a`` names and the partition rules' name sets are not
+    reads), no module under ``ray_tpu/llm/`` (``llm/mla_moe.py`` applies
+    its OWN family's ``wo``)."""
+    import importlib
+    import inspect
+    import pkgutil
+    import re
+
+    import ray_tpu.llm
+    from ray_tpu.models import llama
+
+    halves = {"llama_project", "llama_attn_out", "llama_ffn"}
+    reads = {}
+    for name, fn in inspect.getmembers(llama, inspect.isfunction):
+        if fn.__module__ == llama.__name__ and name != "llama_init":
+            reads[name] = set(re.findall(_KERNEL_READ, inspect.getsource(fn)))
+    assert set().union(*(reads[h] for h in halves)) == {
+        "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down"}
+    assert {n: r for n, r in reads.items() if r and n not in halves} == {}
+    for info in pkgutil.walk_packages(ray_tpu.llm.__path__, "ray_tpu.llm."):
+        if info.name != "ray_tpu.llm.mla_moe":
+            src = inspect.getsource(importlib.import_module(info.name))
+            assert not re.findall(_KERNEL_READ, src), info.name
+
+
+def _arrows_point_one_way():
+    """engine -> seam -> family programs -> models -> ops: the engine
+    defines no device program and does not pull in a family it does not
+    serve; no program module (nor the seam) imports the engine."""
+    import ast
+    import inspect
+    import subprocess
+    import sys
+
+    from ray_tpu.llm import engine, llama, mla_moe, programs
+
+    subprocess.run(
+        [sys.executable, "-c",
+         "import sys, ray_tpu.llm.engine; "
+         "assert 'ray_tpu.llm.mla_moe' not in sys.modules"],
+        check=True, timeout=120)
+    assert "jax.jit" not in inspect.getsource(engine)
+    for mod in (llama, mla_moe, programs):
+        for node in ast.walk(ast.parse(inspect.getsource(mod))):
+            if isinstance(node, ast.ImportFrom):
+                names = {f"{node.module}.{a.name}" for a in node.names}
+                names.add(node.module)
+            elif isinstance(node, ast.Import):
+                names = {a.name for a in node.names}
+            else:
+                continue
+            assert "ray_tpu.llm.engine" not in names, mod.__name__
+
+
+@pytest.mark.parametrize("check", [_weights_read_in_one_place,
+                                   _arrows_point_one_way],
+                         ids=["weights", "imports"])
+def test_llm_seam(check):
+    check()

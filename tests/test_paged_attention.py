@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.llm.engine import _gqa_attn, _kv_read
+from ray_tpu.llm.llama import _gqa_attn, _kv_read
 from ray_tpu.ops.paged_attention import paged_decode_attention
 
 PS, MAXP, HD, L, P = 32, 20, 64, 3, 48  # a 640-token window: 3 blocks of 8 pages
@@ -15,10 +15,8 @@ WINDOW = MAXP * PS
 
 
 def _reference(q, kpool, vpool, layer, tables, lengths):
-    B, H, hd = q.shape
-    KV = kpool.shape[3]
-    kb = _kv_read(kpool, layer, tables, B, MAXP, PS, KV, hd, q.dtype)
-    vb = _kv_read(vpool, layer, tables, B, MAXP, PS, KV, hd, q.dtype)
+    kb = _kv_read(kpool, layer, tables, q.dtype)
+    vb = _kv_read(vpool, layer, tables, q.dtype)
     mask = jnp.arange(WINDOW)[None, None, :] < lengths[:, None, None]
     return _gqa_attn(q[:, None], kb, vb, mask)[:, 0]
 

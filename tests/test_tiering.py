@@ -77,11 +77,11 @@ def test_kv_page_spill_restore_byte_identical(rt):
     promoted back on restore."""
     import jax.numpy as jnp
 
-    from ray_tpu.llm import engine as _engine
+    from ray_tpu.llm import llama as _programs
     from ray_tpu.llm.disagg import telemetry
 
     cfg = _tiny_cfg()
-    kpool, vpool = _engine.make_kv_pools(cfg, PS, 16, None)
+    kpool, vpool = _programs.make_kv_pools(cfg, PS, 16, None)
     rng = np.random.default_rng(7)
     kpool = jnp.asarray(rng.normal(size=kpool.shape), kpool.dtype)
     vpool = jnp.asarray(rng.normal(size=vpool.shape), vpool.dtype)
@@ -249,11 +249,11 @@ def test_adoption_shed_surfaces_backpressure(rt):
     window drains."""
     import jax.numpy as jnp
 
-    from ray_tpu.llm import engine as _engine
+    from ray_tpu.llm import llama as _programs
     from ray_tpu.serve.exceptions import BackPressureError
 
     cfg = _tiny_cfg()
-    kpool, vpool = _engine.make_kv_pools(cfg, PS, 16, None)
+    kpool, vpool = _programs.make_kv_pools(cfg, PS, 16, None)
     rng = np.random.default_rng(3)
     kpool = jnp.asarray(rng.normal(size=kpool.shape), kpool.dtype)
     vpool = jnp.asarray(rng.normal(size=vpool.shape), vpool.dtype)

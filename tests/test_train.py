@@ -1,6 +1,6 @@
 """JaxTrainer end-to-end tests: 2-worker data-parallel training with
 gradient allreduce over the cpu collective fake — the FashionMNIST-DDP
-north-star config shape (BASELINE.md row 1) at test scale."""
+config shape at test scale."""
 
 import os
 
