@@ -8,7 +8,9 @@ of ``llm/llama.py``, another cache and another layer.
 * **Two attention paths over that one cache.** Prefill expands the fresh
   rows to per-head keys and values once for all the prompt's queries
   (``mla_attend_expanded``); decode absorbs ``wkv_b`` into the query and
-  the output and reads the window as it lies (``mla_attend_absorbed``).
+  the output and attends the rows as they lie: in the pool, page by page
+  (``paged_latent_attention``) or over the gathered window
+  (``mla_attend_absorbed``), as ``_reads_in_place`` sees.
 * **The expert layer** (``parallel/moe.py``) sees 32 tokens a decode step
   (bound by the bytes of the experts they touch) and a whole wave's prompt
   tokens in prefill (bound by the MXU); dead slots and prompt padding are
@@ -31,9 +33,10 @@ import jax.numpy as jnp
 
 from ray_tpu.llm.programs import ServePrograms, _sample_tail
 from ray_tpu.models.mla_moe import (
-    MlaMoeConfig, mla_attend_absorbed, mla_attend_expanded, mla_moe_ffn,
-    mla_project)
+    MlaMoeConfig, mla_absorb, mla_attend_absorbed, mla_attend_expanded,
+    mla_expand, mla_moe_ffn, mla_project)
 from ray_tpu.ops.basic import rms_norm, rope_freqs
+from ray_tpu.ops.paged_attention import paged_latent_attention
 
 # extra int32 columns of a decode step's token row, each summed over the
 # expert layers: rows routed to held experts, distinct held experts that got
@@ -63,10 +66,24 @@ def _load_stats(loads):
                       jnp.asarray(load.size)]).astype(jnp.int32)
 
 
+def _reads_in_place(pool) -> bool:
+    """Whether the decode step attends this pool where it lies
+    (``paged_latent_attention``: only the pages that hold tokens) or through
+    the gathered window ``pool[i][page_tables]``. Decided by what the code
+    can see, no option, as ``llm/llama.py``'s: on a TPU the kernel; on every
+    other backend, where it would be interpreted, the window — which thereby
+    stays the kernel's plain reference and what the CPU tests run. The
+    family has one kind of pool, so the pool itself decides nothing yet."""
+    return jax.default_backend() == "tpu"
+
+
 def _decode_body(params, tokens, pos, page_tables, pool, active, temps, key,
                  cfg: MlaMoeConfig):
     """One decode step for every slot (masked where inactive), absorbed
-    attention over each slot's pages. Returns (next_tok [B], pool, stats)."""
+    attention over each slot's ``pos + 1`` cached rows: in place through the
+    page table (an inactive slot attends nothing) where ``_reads_in_place``
+    holds, else over the whole gathered window with the positions past
+    ``pos`` masked. Returns (next_tok [B], pool, stats)."""
     B = tokens.shape[0]
     L, P, PS, W = pool.shape
     MAXP = page_tables.shape[1]
@@ -74,7 +91,11 @@ def _decode_body(params, tokens, pos, page_tables, pool, active, temps, key,
     positions = pos[:, None]
     row = jnp.take_along_axis(page_tables, (pos // PS)[:, None], axis=1)[:, 0]
     off = pos % PS
-    mask = jnp.arange(MAXP * PS)[None, None, :] <= pos[:, None, None]
+    in_place = _reads_in_place(pool)
+    if in_place:
+        lengths = jnp.where(active, pos + 1, 0)
+    else:
+        mask = jnp.arange(MAXP * PS)[None, None, :] <= pos[:, None, None]
     loads = []
     x = params["tok"]["embedding"][tokens][:, None, :]
     for i in range(cfg.n_layers):
@@ -82,9 +103,16 @@ def _decode_body(params, tokens, pos, page_tables, pool, active, temps, key,
         h = rms_norm(x, layer["attn_norm"]["scale"])
         q, latent = mla_project(layer, h, cos, sin, positions, cfg)
         pool = pool.at[i, row, off].set(latent[:, 0].astype(pool.dtype))
-        window = pool[i][page_tables].reshape(B, MAXP * PS, W).astype(x.dtype)
-        x = x + mla_attend_absorbed(layer, q, window, mask, cfg
-                                    ) @ layer["wo"]["kernel"]
+        if in_place:
+            o_lat = paged_latent_attention(
+                mla_absorb(layer, q, cfg)[:, 0].astype(pool.dtype), pool, i,
+                page_tables, lengths, v_width=cfg.kv_lora_rank,
+                sm_scale=cfg.qk_head_dim ** -0.5)
+            att = mla_expand(layer, o_lat[:, None].astype(x.dtype), cfg)
+        else:
+            window = pool[i][page_tables].reshape(B, MAXP * PS, W).astype(x.dtype)
+            att = mla_attend_absorbed(layer, q, window, mask, cfg)
+        x = x + att @ layer["wo"]["kernel"]
         x, load = mla_moe_ffn(layer, x, cfg, valid=active[:, None])
         if load is not None:
             loads.append(load)
@@ -150,4 +178,4 @@ def mla_moe_prefill_batch(params, loras, aids, tokens, pages, pool,
 PROGRAMS = ServePrograms(
     family="mla_moe", make_cache=make_latent_pool,
     decode_multi=mla_moe_decode_multi, prefill_batch=mla_moe_prefill_batch,
-    stats=STATS)
+    stats=STATS, decode_in_place=lambda cache: _reads_in_place(cache[0]))

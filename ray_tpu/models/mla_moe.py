@@ -230,26 +230,47 @@ def mla_attend_expanded(layer, q, latent, mask, cfg: MlaMoeConfig):
     return out.reshape(B, Tq, H * cfg.v_head_dim)
 
 
+def mla_absorb(layer, q, cfg: MlaMoeConfig):
+    """The queries carried into the cache rows' space: ``q_nope`` through the
+    K half of ``wkv_b`` per head, beside ``q_rope`` as it is. q: [B, Tq, H,
+    nope + rope] -> [B, Tq, H, r + rope]."""
+    n = cfg.qk_nope_head_dim
+    return jnp.concatenate(
+        [jnp.einsum("bqhn,rhn->bqhr", q[..., :n], _wkv_b(layer, cfg)[..., :n]),
+         q[..., n:]], axis=-1)
+
+
+def mla_attend_window(q_lat, latent, mask, cfg: MlaMoeConfig):
+    """Absorbed queries against cache rows as they lie: scores over the whole
+    row, the probabilities sum the rows' latent part. q_lat: [B, Tq, H, r +
+    rope]; latent: [B, Tk, r + rope]; mask: [B, Tq, Tk]. Returns [B, Tq, H,
+    r]. The plain form of ``ops/paged_attention.py``'s latent kernel."""
+    p = _softmax_scores(jnp.einsum("bqhc,bkc->bhqk", q_lat, latent), mask, cfg,
+                        q_lat.dtype)
+    return jnp.einsum("bhqk,bkr->bqhr", p, latent[..., :cfg.kv_lora_rank])
+
+
+def mla_expand(layer, o_lat, cfg: MlaMoeConfig):
+    """The V half of ``wkv_b`` applied once to the summed latents: [B, Tq, H,
+    r] -> [B, Tq, H * v]."""
+    out = jnp.einsum("bqhr,rhd->bqhd", o_lat,
+                     _wkv_b(layer, cfg)[..., cfg.qk_nope_head_dim:])
+    return out.reshape(*o_lat.shape[:2], cfg.n_heads * cfg.v_head_dim)
+
+
 def mla_attend_absorbed(layer, q, latent, mask, cfg: MlaMoeConfig):
     """The same sum without expanding the cache: ``q_nope`` is carried into
-    the latent space (``q_abs = q_nope.Wkvb^K`` per head), scored against
-    the cache rows as they lie, the probabilities sum the latents, and the
-    V half of ``wkv_b`` is applied once to the result. The form for few
-    queries over a long cache (decode): the window is read twice and never
-    rewritten to H heads.
+    the latent space (``mla_absorb``), scored against the cache rows as they
+    lie, the probabilities sum the latents (``mla_attend_window``), and the
+    V half of ``wkv_b`` is applied once to the result (``mla_expand``). The
+    form for few queries over a long cache (decode): the window is read
+    twice and never rewritten to H heads. On a TPU the decode step keeps the
+    two ends and lets a kernel attend the pool in place (``llm/mla_moe.py``).
 
     Shapes as ``mla_attend_expanded``."""
-    B, Tq, H, _ = q.shape
-    r, n = cfg.kv_lora_rank, cfg.qk_nope_head_dim
-    w = _wkv_b(layer, cfg)
-    q_lat = jnp.concatenate(
-        [jnp.einsum("bqhn,rhn->bqhr", q[..., :n], w[..., :n]), q[..., n:]],
-        axis=-1)                                        # [B, Tq, H, r + rope]
-    p = _softmax_scores(jnp.einsum("bqhc,bkc->bhqk", q_lat, latent), mask, cfg,
-                        q.dtype)
-    o_lat = jnp.einsum("bhqk,bkr->bqhr", p, latent[..., :r])
-    out = jnp.einsum("bqhr,rhd->bqhd", o_lat, w[..., n:])
-    return out.reshape(B, Tq, H * cfg.v_head_dim)
+    return mla_expand(
+        layer, mla_attend_window(mla_absorb(layer, q, cfg), latent, mask, cfg),
+        cfg)
 
 
 # ---------------------------------------------------------------- feed-forward

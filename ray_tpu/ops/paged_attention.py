@@ -1,28 +1,40 @@
-"""Pallas TPU paged decode attention — K and V read where they lie.
+"""Pallas TPU paged decode attention — the cache read where it lies.
 
-One query row a slot attends the slot's keys and values straight out of the
-engine's page pools ``[L, P, PS, KV, hd]``: the pools stay in HBM, the kernel
-walks the slot's page table and fetches only the pages that hold tokens, a
-page of a layer being one contiguous ``[PS, KV, hd]`` run. Nothing is sliced
-out of the pool, no ``[B, MAXP * PS, KV, hd]`` window is gathered, and no
-position past a slot's length is contracted (what ``_kv_read`` +
-``_gqa_attn`` do, and stay the plain reference for: ``llm/llama.py``).
+One query row a slot attends the slot's cached positions straight out of the
+engine's page pools: the pools stay in HBM, the kernel walks the slot's page
+table and fetches only the pages that hold tokens. Nothing is sliced out of a
+pool, no ``[B, MAXP * PS, ...]`` window is gathered, and no position past a
+slot's length is contracted. Two callers, one walk:
+
+* ``paged_decode_attention`` — a K pool and a V pool ``[L, P, PS, KV, hd]``
+  (``llm/llama.py``; what ``_kv_read`` + ``_gqa_attn`` do, and stay the plain
+  reference for). A page of a layer is one contiguous ``[PS, KV, hd]`` run.
+* ``paged_latent_attention`` — ONE pool ``[L, P, PS, W]`` whose rows are the
+  keys as they lie and whose first ``v_width`` lanes are the values (MLA's
+  latent cache ``[c | k_rope]``, ``llm/mla_moe.py``; the window
+  ``pool[i][page_tables]`` + ``mla_attend_window`` is the reference). A
+  fetched page serves both products out of VMEM, so a live row crosses HBM
+  once. The scale is the caller's: the model's head width is not a shape
+  the kernel sees.
+
+Which of the two a call is shows in the arguments: the number of pools, the
+pool's rank (a 4-D pool is one KV head), the output's width.
 
 One kernel invocation serves every slot: a work list of (slot, block) items,
-a block being ``n_pages`` pages (256 tokens), runs through two VMEM buffers — the
+a block being ``n_pages`` pages (256 tokens; 512 of the latent pool), runs through two VMEM buffers — the
 next item's page copies are in flight while this one is used, across slot
 boundaries too, so only the first block of a program waits for its pages.
-Per block the fetched pages are read as ``[tokens * KV, hd]`` rows, as they
+Per block the fetched pages are read as ``[tokens * KV, width]`` rows, as they
 lie: the H query heads meet ALL rows in one matmul and a head mask keeps,
 for query head h, the rows of KV head ``h // G`` (G = H // KV, read from the
 shapes) — the G rows of a KV head against that head's keys, without a
 strided load or a transpose of the block. The MXU's time is set by the K and
 V tiles it has to hold, which are the same either way. Online softmax in
 float32 (running maximum, sum, accumulator); the probabilities are cast to
-the pool's dtype for w·V as ``_gqa_attn`` casts them.
+the pool's dtype for w·V as ``_gqa_attn`` and ``mla_attend_window`` cast them.
 
 The layer index, the page tables and the lengths are scalar-prefetched, and
-the entry point is a jit of its own, so the call sites of a program's layers
+each entry point is a jit of its own, so the call sites of a program's layers
 share one traced and lowered kernel.
 """
 
@@ -37,17 +49,33 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_BIG = -1e30
-_BLOCK_TOKENS = 256  # tokens a compute block: 1 MB of K and V at 8 x 128 bf16
+# tokens a compute block, which is also what is in flight while one is used:
+# 1 MB of K and V at 8 x 128 bf16; 0.66 MB of 576-wide latent rows as they lie
+# padded (at 256, 0.33 MB in flight kept the page copies at 60 % of the
+# bandwidth with no arithmetic at all: PERF.md, PR 30)
+_BLOCK_TOKENS = 256
+_LATENT_BLOCK_TOKENS = 512
 
 
-def _kernel(layer_ref, tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
-            kbuf, vbuf, sems, *, sm_scale: float, n_pages: int):
-    B, H, hd = q_ref.shape
-    _, _, PS, KV, _ = k_hbm.shape
+def _kernel(layer_ref, tables_ref, lengths_ref, q_ref, *refs,
+            sm_scale: float, n_pages: int):
+    # refs: the pools (HBM), the output, a VMEM buffer a pool, the semaphores
+    n_pools = (len(refs) - 2) // 2
+    pools, o_ref = refs[:n_pools], refs[n_pools]
+    bufs, sems = refs[n_pools + 1:-1], refs[-1]
+    B, H, lanes = q_ref.shape  # the rows' width, and what pads it in HBM
+    PS, width = pools[0].shape[2], pools[0].shape[-1]
+    KV = pools[0].shape[3] if len(pools[0].shape) == 5 else 1
+    v_width = o_ref.shape[-1]
     MAXP = tables_ref.shape[1]
     G = H // KV
     rows = n_pages * PS * KV  # rows of one block, token-major then KV head
     layer = layer_ref[0]
+
+    # a page with its rows' padding, where they have any (_walk_pools)
+    whole_rows = (() if lanes == width else
+                  (slice(None),) * (len(pools[0].shape) - 3)
+                  + (pl.ds(0, lanes),))
 
     def pages_of(b):
         return jnp.minimum(pl.cdiv(lengths_ref[b], PS), MAXP)
@@ -61,9 +89,10 @@ def _kernel(layer_ref, tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
         for j in range(n_pages):
             p = i * n_pages + j
             page = tables_ref[b, jnp.minimum(p, MAXP - 1)]
-            for pool, dst, kv in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+            for kv, (pool, dst) in enumerate(zip(pools, bufs)):
                 out.append((p < live, pltpu.make_async_copy(
-                    pool.at[layer, page], dst.at[buf, j], sems.at[kv, buf])))
+                    pool.at[(layer, page, *whole_rows)], dst.at[buf, j],
+                    sems.at[kv, buf])))
         return out
 
     def start(b, i, buf):
@@ -83,8 +112,8 @@ def _kernel(layer_ref, tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
 
     # dead pages of a block are masked, not fetched: whatever the buffers
     # hold there has to be finite for the w·V product
-    kbuf[...] = jnp.zeros_like(kbuf)
-    vbuf[...] = jnp.zeros_like(vbuf)
+    for dst in bufs:
+        dst[...] = jnp.zeros_like(dst)
 
     first = next_slot(jnp.int32(0))
 
@@ -97,10 +126,15 @@ def _kernel(layer_ref, tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
     row_tok = row // KV
     head_ok = row % KV == jax.lax.broadcasted_iota(jnp.int32, (H, rows), 0) // G
 
+    if lanes > width:  # the last lane tile, [lo, lanes), holds the padding
+        lo = width // 128 * 128
+        pad_ok = jax.lax.broadcasted_iota(
+            jnp.int32, (rows, lanes - lo), 1) < width - lo
+
     def slot(b, buf):
         length = jnp.minimum(lengths_ref[b], MAXP * PS)
         n_blocks = pl.cdiv(pages_of(b), n_pages)
-        q = q_ref[b]  # [H, hd]
+        q = q_ref[b]  # [H, lanes]
 
         def block(i, carry):
             m, l, acc, buf = carry
@@ -113,8 +147,15 @@ def _kernel(layer_ref, tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
                 start(nb, ni, 1 - buf)
 
             wait(b, i, buf)
-            k = kbuf[buf].reshape(rows, hd)
-            v = vbuf[buf].reshape(rows, hd)
+            k = bufs[0][buf].reshape(rows, lanes)
+            if lanes > width:
+                # the last lane tile came with the array's padding, which
+                # may hold anything: zeros there, to meet q's zeros
+                k = jnp.concatenate(
+                    [k[:, :lo], jnp.where(pad_ok, k[:, lo:], 0)], axis=1)
+            # one pool: the values are the leading lanes of the rows fetched
+            v = (bufs[1][buf].reshape(rows, lanes) if n_pools == 2
+                 else k[:, :v_width])
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * sm_scale  # [H, rows]
@@ -134,7 +175,7 @@ def _kernel(layer_ref, tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
             0, n_blocks, block,
             (jnp.full((H, 1), _NEG_BIG, jnp.float32),
              jnp.zeros((H, 1), jnp.float32),
-             jnp.zeros((H, hd), jnp.float32), buf))
+             jnp.zeros((H, v_width), jnp.float32), buf))
         # a slot with no tokens ran no block: zeros over 1e-30 are zeros
         o_ref[b] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
         return buf
@@ -173,34 +214,83 @@ def _paged_decode_attention(q, kpool, vpool, layer, page_tables, lengths, *,
     layer each, a 13-layer decode program took 6-7 s to lower (about 14 s
     on the chip machine's host) before the compile cache was even asked:
     100 s of a replica's set-up over its 7 decode programs (PERF.md, PR 28)."""
-    B, H, hd = q.shape
-    L, P, PS, KV, _ = kpool.shape
+    hd = q.shape[-1]
+    return _walk_pools(q, (kpool, vpool), layer, page_tables, lengths,
+                       v_width=hd, sm_scale=1.0 / math.sqrt(hd),
+                       block_tokens=_BLOCK_TOKENS, interpret=interpret)
+
+
+def paged_latent_attention(q, pool, layer, page_tables, lengths, *,
+                           v_width: int, sm_scale: float,
+                           interpret: bool | None = None):
+    """``paged_decode_attention`` over ONE pool whose rows are keys and, in
+    their first ``v_width`` lanes, values: MLA's absorbed decode attention
+    over the latent cache.
+
+    q: [B, H, W], the query carried into the rows' space; pool: [L, P, PS, W]
+    (whole, in HBM), one KV head that all H query heads attend; ``sm_scale``
+    multiplies the scores (the model's, not ``1 / sqrt(W)``); layer,
+    page_tables, lengths as there. Returns the probabilities' sum of the
+    rows' values, [B, H, v_width] in q's dtype; zeros for an inactive slot."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    return _paged_latent_attention(
+        q, pool, jnp.asarray(layer, jnp.int32), page_tables, lengths,
+        v_width=int(v_width), sm_scale=float(sm_scale),
+        interpret=bool(interpret))
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("v_width", "sm_scale", "interpret"))
+def _paged_latent_attention(q, pool, layer, page_tables, lengths, *,
+                            v_width: int, sm_scale: float, interpret: bool):
+    """A jit of its own for the reason ``_paged_decode_attention`` is one."""
+    return _walk_pools(q, (pool,), layer, page_tables, lengths,
+                       v_width=v_width, sm_scale=sm_scale,
+                       block_tokens=_LATENT_BLOCK_TOKENS, interpret=interpret)
+
+
+def _walk_pools(q, pools, layer, page_tables, lengths, *, v_width: int,
+                sm_scale: float, block_tokens: int, interpret: bool):
+    """The one ``pallas_call`` both entries make: the pools stay where they
+    are (``pl.ANY``), a VMEM buffer of two blocks a pool."""
+    B, H, width = q.shape
+    PS, page = pools[0].shape[2], pools[0].shape[2:]
     MAXP = page_tables.shape[1]
-    n_pages = max(1, min(_BLOCK_TOKENS // PS, MAXP))
-    kernel = functools.partial(
-        _kernel, sm_scale=1.0 / math.sqrt(hd), n_pages=n_pages)
-    buf = pltpu.VMEM((2, n_pages, PS, KV, hd), kpool.dtype)
-    window = B * MAXP * PS * KV * hd * kpool.dtype.itemsize  # at most, K or V
+    n_pages = max(1, min(block_tokens // PS, MAXP))
+    kernel = functools.partial(_kernel, sm_scale=sm_scale, n_pages=n_pages)
+    # Rows whose width is not whole lane tiles (MLA's 576 = 4.5 x 128) lie in
+    # HBM padded to whole ones, and Mosaic takes no slice of a tiled axis that
+    # is not whole tiles, the whole axis included ("Slice shape along
+    # dimension 3 must be aligned to tiling (128), but is 576"). So the
+    # compiled kernel copies a page's rows WITH their padding — the same run
+    # of HBM — and masks it; q gets zeros there. The interpreter has no
+    # padding to fetch and none to mask.
+    lanes = width if interpret else -(-width // 128) * 128
+    if lanes > width:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, lanes - width)))
+    buf = pltpu.VMEM((2, n_pages, *page[:-1], lanes), pools[0].dtype)
+    # at most, a pool: every slot's whole table
+    window = B * MAXP * math.prod(page) * pools[0].dtype.itemsize
+    out = jax.ShapeDtypeStruct((B, H, v_width), q.dtype)
     return pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=out,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(1,),
-            in_specs=[
-                pl.BlockSpec(q.shape, lambda i, *_: (0, 0, 0)),
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=pl.BlockSpec(q.shape, lambda i, *_: (0, 0, 0)),
-            scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2))],
+            in_specs=[pl.BlockSpec(q.shape, lambda i, *_: (0, 0, 0))]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+            out_specs=pl.BlockSpec(out.shape, lambda i, *_: (0, 0, 0)),
+            scratch_shapes=[buf] * len(pools)
+            + [pltpu.SemaphoreType.DMA((len(pools), 2))],
         ),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         cost_estimate=pl.CostEstimate(
-            flops=4 * B * H * MAXP * PS * hd, transcendentals=B * H * MAXP * PS,
-            bytes_accessed=2 * window),
+            flops=2 * B * H * MAXP * PS * (width + v_width),
+            transcendentals=B * H * MAXP * PS,
+            bytes_accessed=len(pools) * window),
         interpret=interpret,
     )(layer.reshape(1),
-      page_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      q, kpool, vpool)
+      page_tables.astype(jnp.int32), lengths.astype(jnp.int32), q, *pools)

@@ -97,13 +97,17 @@ def _engine_args(one_chip, n_layers: int, n_kv_heads: int = 8):
     return cfg, one_chip(params), one_chip(pool), one_chip(_shape((2,), jnp.uint32))
 
 
-@pytest.mark.parametrize("seq,kv_heads", [(512, 8), (2048, 8), (2048, 4)])
-def test_paged_decode_multi_compiles(one_chip, monkeypatch, seq, kv_heads):
+@pytest.mark.parametrize("seq,kv_heads,temporaries", [
+    (512, 8, 571_958_784), (2048, 8, 573_266_432), (2048, 4, 555_181_568)])
+def test_paged_decode_multi_compiles(one_chip, monkeypatch, seq, kv_heads,
+                                     temporaries):
     """At chip_smoke's 512-token window and at the benchmark cells' 2048, at
     Mistral's head ratio and at Yi's. On a TPU a plain pool is read in place
     by the paged kernel (``_reads_in_place`` asks ``jax.default_backend()``,
     which here is the CPU: the test answers for it, as for the flash
-    kernels below)."""
+    kernels below). ``temporaries`` pins the program where PR 28 left it,
+    to the byte: the kernel's walk has a second caller (the latent pool)
+    that must not move this one."""
     from ray_tpu.llm.llama import paged_decode_multi
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -127,7 +131,7 @@ def test_paged_decode_multi_compiles(one_chip, monkeypatch, seq, kv_heads):
     # what the program needs plus a tenth (0.574 GB at every window): the
     # hoisted qkv and gate-up concatenations, 0.285 GB a layer, and nothing
     # of the window — the gathered one added 67 MB at 2048 (0.641)
-    assert mem.temp_size_in_bytes < 0.63e9
+    assert mem.temp_size_in_bytes == temporaries < 0.63e9
     text = compiled.as_text()
     # one Mosaic kernel a layer, reading the pools where they lie
     assert text.count("tpu_custom_call") == cfg.n_layers
@@ -190,29 +194,56 @@ def _mla_moe_args(one_chip, n_layers: int):
     return cfg, one_chip(params), one_chip(pool), one_chip(_shape((2,), jnp.uint32))
 
 
-def test_mla_moe_decode_multi_compiles(one_chip):
+def test_mla_moe_decode_multi_compiles(one_chip, monkeypatch):
+    """On a TPU the latent pool is attended in place by the paged kernel
+    (``llm/mla_moe.py`` ``_reads_in_place`` asks ``jax.default_backend()``:
+    the test answers for it, as above)."""
     from ray_tpu.llm.mla_moe import STATS, mla_moe_decode_multi
 
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mla_moe_decode_multi.clear_cache()
     cfg, params, pool, key = _mla_moe_args(one_chip, 2)
     B = 32
     i32 = one_chip(_shape((B,), jnp.int32))
-    lowered = mla_moe_decode_multi.lower(
-        params, None, i32, i32, i32, one_chip(_shape((B, 128), jnp.int32)), pool,
-        one_chip(_shape((B,), jnp.bool_)), one_chip(_shape((B,), jnp.float32)),
-        key, cfg=cfg, n_steps=8)
-    assert lowered.out_info[0].shape == (8, B + len(STATS))
-    compiled = lowered.compile()
+    try:
+        lowered = mla_moe_decode_multi.lower(
+            params, None, i32, i32, i32, one_chip(_shape((B, 128), jnp.int32)),
+            pool, one_chip(_shape((B,), jnp.bool_)),
+            one_chip(_shape((B,), jnp.float32)), key, cfg=cfg, n_steps=8)
+        compiled = lowered.compile()
+    finally:
+        mla_moe_decode_multi.clear_cache()
+    assert lowered.out_info[0].shape == (8, 32 + len(STATS))
     mem = compiled.memory_analysis()
     # embedding + head 1.05 GB, the dense layer 0.13, one expert layer 1.28,
     # the pool 0.15
     assert 2.5e9 < mem.argument_size_in_bytes < 2.8e9
+    # the pool in the kernel's layout (below) is 0.17 GB of it
     assert mem.temp_size_in_bytes < 0.5e9
     text = compiled.as_text()
     # the routed experts are grouped matmuls (ragged_dot: Mosaic kernels),
-    # and the window is never expanded to 32 heads of keys or values
-    assert text.count("tpu_custom_call") >= 3
-    wide = re.findall(r"bf16\[32,2048,32,(?:128|192|256)\]", text)
-    assert not wide, sorted(set(wide))
+    # and the attention kernel is one Mosaic call a layer more
+    kernel = len(re.findall(r"%_paged_latent_attention\S* = \S+ custom-call\(",
+                            text))
+    assert kernel == cfg.n_layers
+    assert text.count("tpu_custom_call") - kernel >= 3
+    # ... reading the pool where it lies: no layer's pool sliced out of it, no
+    # window gathered from that in either shape, none expanded to 32 heads of
+    # keys or values
+    shapes = (r"bf16\[(?:4097,16,576|4096,16,576|32,128,16,576|32,2048,576"
+              r"|32,2048,32,(?:128|192|256))\]")
+    assert not re.findall(shapes, text), sorted(set(re.findall(shapes, text)))
+    pool_ops = set(re.findall(r"= bf16\[2,4097,16,576\]\S* ([\w-]+)\(", text))
+    assert {"parameter", "scatter"} <= pool_ops, pool_ops
+    assert not pool_ops & {"slice", "dynamic-slice", "gather", "transpose",
+                           "convert"}, pool_ops
+    # Two copies of the whole pool stay, and neither is the scan's: the
+    # device's own layout of a [L, P, 16, 576] bf16 array puts the PAGE axis
+    # minor-most ({1,3,2,0}: 576 is 4.5 lane tiles, 4,097 pads less), so any
+    # program that wants a page's rows together, the gathered one too, turns
+    # the pool row-major on entry and back for the donated output.
+    copies = re.findall(r"= bf16\[2,4097,16,576\](\{[\d,]+)\S* copy\(", text)
+    assert sorted(copies) == ["{1,3,2,0", "{3,2,1,0"], copies
 
 
 def test_mla_moe_prefill_batch_compiles(one_chip):

@@ -300,6 +300,55 @@ def test_engine_generate_is_the_references_greedy_tokens(eos_id):
     assert set(eng._last_stats) == set(serving_programs(CFG).stats)
 
 
+def _kv_counters():
+    from ray_tpu.utils import metrics
+
+    totals = metrics.stage_totals()
+    return tuple(
+        totals[f"rt_llm_decode_kv_tokens_{n}_total"].get("", {}).get("sum", 0)
+        for n in ("live", "read"))
+
+
+def test_engine_read_counters_say_which_decode_path_ran(monkeypatch):
+    """The family's ``decode_in_place`` is what the engine's read counters
+    go by: where the window is gathered (this backend) a step fetches slots
+    x table x page positions; where the latent pool is attended in place
+    (``_reads_in_place`` answered for the test; the kernel interpreted) it
+    fetches the whole pages the live lengths span — and the greedy tokens
+    are the same. The Llama family's twin is ``tests/test_llm.py::
+    test_engine_decode_in_place_matches_gathered``."""
+    from ray_tpu.llm import mla_moe as programs
+
+    async def lone():
+        # the planned loop dispatches exactly the request's 8 decode steps:
+        # two blocks of 4 from lengths 5 and 9, attending 6..13 positions
+        eng = _engine(eos_id=None, block_buckets=(4,))
+        await eng.start()
+        before = _kv_counters()
+        out = await eng.generate([5, 6, 7, 8, 9], max_tokens=9)
+        grown = tuple(a - b for a, b in zip(_kv_counters(), before))
+        await eng.stop()
+        return eng, out, grown
+
+    eng, gathered, grown = asyncio.run(lone())
+    assert not eng._kv_in_place and len(gathered) == 9
+    # 4 slots x 16 pages x 8 tokens a step, whatever is live
+    assert grown == (sum(range(6, 14)), 8 * 4 * 16 * 8)
+    assert eng._last_kv == {"kv_live": sum(range(10, 14)) / 4,
+                            "kv_read": 4 * 16 * 8}
+
+    monkeypatch.setattr(programs, "_reads_in_place", lambda pool: True)
+    programs.mla_moe_decode_multi.clear_cache()  # traced with the other answer
+    try:
+        eng, in_place, grown = asyncio.run(lone())
+    finally:
+        programs.mla_moe_decode_multi.clear_cache()
+    assert eng._kv_in_place and in_place == gathered
+    # whole pages of 8: one for 6..8 positions, two for 9..13
+    assert grown == (sum(range(6, 14)), 3 * 8 + 5 * 16)
+    assert eng._last_kv == {"kv_live": sum(range(10, 14)) / 4, "kv_read": 16}
+
+
 @pytest.mark.parametrize("feature,make", [
     ("kv_dtype='int8'", lambda: _engine(kv_dtype="int8")),
     ("lora_adapters", lambda: _engine(lora_adapters={"a": {}})),

@@ -1,6 +1,7 @@
 """The paged decode attention kernel (``ops/paged_attention.py``), interpreted
 on the CPU, against the plain reference it replaces on the chip: the window
-``_kv_read`` gathers from the same pool and ``_gqa_attn`` over it."""
+``_kv_read`` gathers from the same pool and ``_gqa_attn`` over it; for the
+latent pool, the window ``pool[layer][tables]`` and ``mla_attend_window``."""
 
 import jax
 import jax.numpy as jnp
@@ -8,7 +9,9 @@ import numpy as np
 import pytest
 
 from ray_tpu.llm.llama import _gqa_attn, _kv_read
-from ray_tpu.ops.paged_attention import paged_decode_attention
+from ray_tpu.models.mla_moe import MlaMoeConfig, mla_attend_window
+from ray_tpu.ops.paged_attention import (paged_decode_attention,
+                                         paged_latent_attention)
 
 PS, MAXP, HD, L, P = 32, 20, 64, 3, 48  # a 640-token window: 3 blocks of 8 pages
 WINDOW = MAXP * PS
@@ -81,3 +84,52 @@ def test_heads_must_group():
         paged_decode_attention(jnp.zeros((1, 4, HD)), pool, pool, 0,
                                jnp.zeros((1, MAXP), jnp.int32),
                                jnp.zeros((1,), jnp.int32))
+
+
+# ------------------------------------------------------------ the latent pool
+# rows [c | k_rope] of r + 64 numbers at a small r: the values are not the
+# whole row, the row is not whole lane tiles, and the scores' scale is the
+# model's 1 / sqrt(nope + rope), which no shape the kernel sees gives away
+# the latent form walks blocks of 512 tokens (16 of these pages): its edge too
+MIXED_LATENT = MIXED + [16 * PS - 1, 16 * PS, 16 * PS + 1]
+LATENT_CASES = {
+    # name: (heads, r, rope, nope, dtype, layer, lengths)
+    "f32": (8, 32, 64, 16, jnp.float32, 1, MIXED_LATENT),
+    "bf16": (8, 32, 64, 16, jnp.bfloat16, 1, MIXED_LATENT),
+    "f32_wide_rows": (4, 128, 64, 32, jnp.float32, 1, MIXED_LATENT),
+    "bf16_wide_rows": (4, 128, 64, 32, jnp.bfloat16, 1, MIXED_LATENT),
+    "first_layer": (8, 32, 64, 16, jnp.float32, 0, [5, 0, 300]),
+    "last_layer": (8, 32, 64, 16, jnp.float32, 2, [0, 0, 77, 0]),
+    "all_inactive": (8, 32, 64, 16, jnp.bfloat16, 1, [0, 0, 0]),
+    "all_full": (8, 32, 64, 16, jnp.bfloat16, 1, [WINDOW] * 3),
+    # nope + rope = 192 against a row of 96: 1 / sqrt(W) would be 1.41 x
+    "scale_is_the_models": (8, 32, 64, 128, jnp.float32, 1, [1, 40, 333]),
+}
+
+
+@pytest.mark.parametrize("name", list(LATENT_CASES))
+def test_paged_latent_attention_matches_gathered_window(name):
+    H, r, rope, nope, dtype, layer, lengths = LATENT_CASES[name]
+    cfg = MlaMoeConfig.tiny(n_heads=H, kv_lora_rank=r, qk_rope_head_dim=rope,
+                            qk_nope_head_dim=nope)
+    W = cfg.latent_width
+    assert W % 128 and W != r and cfg.qk_head_dim != W
+    rng = np.random.default_rng(len(name))
+    pool = jnp.asarray(rng.standard_normal((L, P, PS, W)), dtype)
+    q = jnp.asarray(rng.standard_normal((len(lengths), H, W)), dtype)
+    tables = jnp.asarray(_tables(rng, lengths))
+    lens = jnp.asarray(lengths, jnp.int32)
+
+    got = jax.jit(lambda *a: paged_latent_attention(
+        *a, v_width=r, sm_scale=cfg.qk_head_dim ** -0.5))(
+            q, pool, layer, tables, lens)
+    window = pool[layer][tables].reshape(len(lengths), WINDOW, W)
+    mask = jnp.arange(WINDOW)[None, None, :] < lens[:, None, None]
+    want = mla_attend_window(q[:, None], window, mask, cfg)[:, 0]
+
+    assert got.shape == (len(lengths), H, r) and got.dtype == q.dtype
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    active = np.asarray(lengths) > 0
+    assert not got[~active].any(), "an inactive slot must read as zeros"
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(got[active], want[active], atol=tol, rtol=tol)
