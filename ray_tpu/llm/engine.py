@@ -24,9 +24,14 @@ ideas onto XLA's static-shape world:
   admission, blocks and the loop; what a page HOLDS is declared by the
   model family's programs (``llm/programs.py``: ``ServePrograms``, chosen
   by the config's type): a K pool and a V pool for the Llama family
-  (``llm/llama.py``), one latent pool for ``llm/mla_moe.py``. The engine
-  carries it as one tuple of pools (``self.cache``), hands it to every
-  program and takes it back donated. No device program is defined here and
+  (``llm/llama.py``), one latent pool for ``llm/mla_moe.py``, two kinds of
+  K and V pools (window layers, full layers) for ``llm/cohere2_moe.py``. The
+  engine carries it as one tuple of pools (``self.cache``), hands it to every
+  program and takes it back donated. Where the family declares kinds of
+  pages (``ServePrograms.page_kinds``) the engine keeps a table and a free
+  list a kind and draws, for a slot of ``n`` positions, what the kind says
+  it holds: admission waits for whichever kind runs out. No device program
+  is defined here and
   nothing here reads a weight: engine -> seam -> family programs ->
   ``models/`` -> ``ops/``.
 """
@@ -47,7 +52,8 @@ from ray_tpu.devtools import chaos
 # this PR's to edit); nothing in this module uses them
 from ray_tpu.llm.llama import (  # noqa: F401
     paged_decode_multi, paged_prefill_batch)
-from ray_tpu.llm.programs import UnsupportedByModel, serving_programs
+from ray_tpu.llm.programs import (
+    PageKind, UnsupportedByModel, serving_programs)
 from ray_tpu.utils import metrics, tracing
 
 
@@ -126,7 +132,16 @@ class ContinuousBatchingEngine:
         self._kv_in_place = bool(
             P.decode_in_place and P.decode_in_place(self.cache))
         self.n_pages = n_pages
-        self.free_pages = list(range(1, n_pages))  # page 0 = junk page
+        # the kinds of pages a slot holds: one for every layer unless the
+        # family declares its own. n_pages is then a count a kind's name
+        # (or one count for all); a table, a free list and page 0 as the
+        # junk page, a kind
+        self.kinds = (P.page_kinds(cfg, page_size, self.MAXP * page_size)
+                      if P.page_kinds else (PageKind("kv", 1, self.MAXP),))
+        counts = [n_pages[k.name] if isinstance(n_pages, dict) else n_pages
+                  for k in self.kinds]
+        self.free = [list(range(1, n)) for n in counts]
+        self.capacity = [n - 1 for n in counts]
         self.loras = None
         self.lora_index = {"__base__": 0}
         if lora_adapters:
@@ -134,7 +149,8 @@ class ContinuousBatchingEngine:
                 cfg, lora_adapters, lora_rank)
         # slot state (host side)
         self.slot_req: list[_Request | None] = [None] * self.B
-        self.page_tables = np.zeros((self.B, self.MAXP), np.int32)
+        self.tables = [np.zeros((self.B, k.table), np.int32)
+                       for k in self.kinds]
         self.seq_lens = np.zeros(self.B, np.int32)
         self.next_tok = np.zeros(self.B, np.int32)
         self.temps = np.zeros(self.B, np.float32)
@@ -236,11 +252,14 @@ class ContinuousBatchingEngine:
             raise ValueError(
                 f"prompt ({len(prompt_tokens)}) + max_tokens ({max_tokens}) "
                 f"exceeds the engine's max_seq_len ({self.MAXP * self.PS})")
-        n_need = -(-(len(prompt_tokens) + max_tokens) // self.PS)
-        if n_need > self.n_pages - 1:
-            raise ValueError(
-                f"request needs {n_need} KV pages but the pool only has "
-                f"{self.n_pages - 1}")
+        for kind, n_need, has in zip(
+                self.kinds, self._pages_of(len(prompt_tokens) + max_tokens),
+                self.capacity):
+            if n_need > has:
+                raise ValueError(
+                    f"request needs {n_need} KV pages but the pool only has "
+                    f"{has}" + ("" if len(self.kinds) == 1
+                                else f" of the {kind.name!r} kind"))
         aid = self.lora_index.get(adapter or "__base__")
         if aid is None:
             raise ValueError(f"unknown LoRA adapter {adapter!r} "
@@ -316,7 +335,7 @@ class ContinuousBatchingEngine:
         if req is None or req.slot < 0:
             raise KeyError(f"request {req_id} is not holding a slot")
         n_cover = -(-len(req.prompt) // self.PS)
-        page_ids = [int(p) for p in self.page_tables[req.slot, :n_cover]]
+        page_ids = [int(p) for p in self.tables[0][req.slot, :n_cover]]
         return ship_pages(self.kpool, self.vpool, page_ids, req.prompt,
                           page_size=self.PS, kv_dtype=self.kv_dtype)
 
@@ -346,11 +365,19 @@ class ContinuousBatchingEngine:
                                      / max(1, self.spec_proposed)),
                 "blocks": blocks}
 
+    @property
+    def free_pages(self) -> list:
+        """The first kind's free list, under the name that callers who know
+        one kind of page read it by (the benchmark's replicas, the tests)."""
+        return self.free[0]
+
     def headroom(self) -> dict:
         """Admission-control snapshot for the disagg scheduler: free KV
         pages and decode slots, queue depth, and the decode
         tokens-in-flight signal."""
-        return {"free_pages": len(self.free_pages),
+        return {"free_pages": len(self.free[0]),
+                "free_pages_by_kind": {k.name: len(f) for k, f in
+                                       zip(self.kinds, self.free)},
                 "free_slots": sum(r is None for r in self.slot_req),
                 "waiting": len(self.waiting),
                 "tokens_in_flight": self.tokens_in_flight(),
@@ -433,22 +460,52 @@ class ContinuousBatchingEngine:
             self._wake.set()
 
     # ------------------------------------------------------------ internals
-    def _alloc_pages(self, n: int) -> list[int] | None:
-        if len(self.free_pages) < n:
+    def _pages_of(self, n: int) -> list[int]:
+        """Pages of each kind that a slot of ``n`` positions holds."""
+        return [min(-(-n // self.PS), k.table) for k in self.kinds]
+
+    def _alloc_pages(self, n: int) -> list[list[int]] | None:
+        """Draw a slot's pages for ``n`` positions, a list a kind, or
+        nothing if ANY kind has too few left."""
+        need = self._pages_of(n)
+        if any(len(f) < m for f, m in zip(self.free, need)):
             return None
-        out = self.free_pages[:n]
-        del self.free_pages[:n]
+        out = []
+        for i, (free, m) in enumerate(zip(self.free, need)):
+            out.append(free[:m])
+            del free[:m]
+            self._count_pages(i, drawn=m)
         return out
+
+    def _count_pages(self, i: int, drawn: int = 0) -> None:
+        """Kind ``i``'s pages drawn and held, where a family has kinds."""
+        if len(self.kinds) == 1:
+            return
+        tags = {"kind": self.kinds[i].name}
+        if drawn:
+            metrics.llm_pages_drawn_total.inc(drawn, tags)
+        metrics.llm_pages_held.set(
+            self.capacity[i] - len(self.free[i]), tags)
+
+    def _release_pages(self, slot: int, reached: int) -> None:
+        """Give back every page of ``slot``'s tables, all kinds. A table
+        holds ALL pages drawn at admission (prompt + max_tokens worth), not
+        just the ones reached — free every entry. ``reached``: the
+        positions the slot got to, for the count of pages a ring reused."""
+        for i, (kind, free, table) in enumerate(
+                zip(self.kinds, self.free, self.tables)):
+            free.extend(int(p) for p in table[slot] if p != 0)
+            table[slot, :] = 0
+            self._count_pages(i)
+            if kind.reach is not None:  # a ring: the pages it wrote over
+                metrics.llm_window_pages_released_total.inc(
+                    max(0, -(-reached // self.PS) - kind.table))
+        self.seq_lens[slot] = 0
 
     def _free_slot(self, slot: int):
         req = self.slot_req[slot]
         self.slot_req[slot] = None
-        # the table holds ALL pages allocated at admission (prompt +
-        # max_tokens worth), not just the ones reached — free every entry
-        self.free_pages.extend(
-            int(p) for p in self.page_tables[slot] if p != 0)
-        self.page_tables[slot, :] = 0
-        self.seq_lens[slot] = 0
+        self._release_pages(slot, int(self.seq_lens[slot]))
         if req is not None:
             self._finish_stream(req)
 
@@ -470,14 +527,14 @@ class ContinuousBatchingEngine:
         if slot < 0:
             return None
         Tp = len(req.prompt)
-        n_need = -(-(Tp + req.max_tokens) // self.PS)
-        pages = self._alloc_pages(n_need)
+        pages = self._alloc_pages(Tp + req.max_tokens)
         if pages is None:
             return None
         req.slot = slot
         self.slot_req[slot] = req
-        self.page_tables[slot, :] = 0
-        self.page_tables[slot, :n_need] = pages
+        for table, drawn in zip(self.tables, pages):
+            table[slot, :] = 0
+            table[slot, :len(drawn)] = drawn
         self.seq_lens[slot] = Tp
         self.temps[slot] = req.temperature
         self.aids[slot] = req.adapter
@@ -514,6 +571,26 @@ class ContinuousBatchingEngine:
         return fn(*args)
 
     _WAVE_BUCKETS = (1, 2, 4, 8, 16)
+
+    def _split_wave(self, pad: int, reqs: list) -> list[list]:
+        """A pad group as the prefill programs it becomes: one, unless the
+        family states the most prompts and tokens a program may hold
+        (``ServePrograms.prefill_wave_limit``) — then runs of the largest
+        wave bucket within both."""
+        limit = self.programs.prefill_wave_limit
+        if limit is None:
+            return [reqs]
+        most = max(1, min(limit[0], limit[1] // pad))
+        most = max(b for b in self._WAVE_BUCKETS if b <= most)
+        return [reqs[i:i + most] for i in range(0, len(reqs), most)]
+
+    def _tables_arg(self, tables):
+        """A slot-table argument of a program: the one array, or a tuple of
+        them where the family has kinds of pages (copies: see
+        ``_dispatch_block``)."""
+        if len(self.kinds) == 1:
+            return jnp.asarray(tables[0].copy())
+        return tuple(jnp.asarray(t.copy()) for t in tables)
 
     async def _admit_wave(self) -> bool:
         """Admit every waiting request that fits, prefilling each pad
@@ -552,7 +629,13 @@ class ContinuousBatchingEngine:
                     continue
                 Tp_pad = -(-len(nxt.prompt) // self.PS) * self.PS
                 groups.setdefault(Tp_pad, []).append(nxt)
-            ph.set(prompts=len(adopted) + sum(map(len, groups.values())))
+            waves = [(pad, reqs) for pad, group in groups.items()
+                     for reqs in self._split_wave(pad, group)]
+            splits = len(waves) - len(groups)
+            ph.set(prompts=len(adopted) + sum(map(len, groups.values())),
+                   splits=splits)
+        if splits:
+            metrics.llm_prefill_wave_splits_total.inc(splits)
         out = []
         if adopted:
             from ray_tpu.llm.disagg.kv_plane import scatter_pages
@@ -565,33 +648,40 @@ class ContinuousBatchingEngine:
             k_stack, v_stack, first = req.prefilled
             req.prefilled = None  # release the host copies after scatter
             n_cover = -(-len(req.prompt) // self.PS)
-            rows = self.page_tables[req.slot, :n_cover].copy()
+            rows = self.tables[0][req.slot, :n_cover].copy()
             self.cache = (scatter_pages(self.cache[0], rows, k_stack),
                           scatter_pages(self.cache[1], rows, v_stack))
             req.t_admit = time.perf_counter_ns()
             out.append(([req], np.asarray([first], np.int32)))
-        for Tp_pad, reqs in groups.items():
+        for Tp_pad, reqs in waves:
             npages = Tp_pad // self.PS
             nb = next(b for b in self._WAVE_BUCKETS if b >= len(reqs)) \
                 if len(reqs) <= self._WAVE_BUCKETS[-1] else len(reqs)
+            true_tokens = sum(len(r.prompt) for r in reqs)
             with tracing.phase("engine.admit", pad=Tp_pad, wave=nb,
-                               prompts=len(reqs), **self._last_stats) as ph:
+                               prompts=len(reqs), tokens=true_tokens,
+                               **self._last_stats) as ph:
                 toks = np.zeros((nb, Tp_pad), np.int32)
-                pages = np.zeros((nb, npages), np.int32)  # dummy rows: junk
+                # dummy rows: junk. A kind's table may be shorter than the
+                # prompt (a ring): its pages are then the whole table
+                pages = [np.zeros((nb, min(npages, k.table)), np.int32)
+                         for k in self.kinds]
                 aids = np.zeros(nb, np.int32)
                 true_lens = np.ones(nb, np.int32)
                 temps = np.zeros(nb, np.float32)
                 for j, req in enumerate(reqs):
                     toks[j, :len(req.prompt)] = req.prompt
-                    pages[j] = self.page_tables[req.slot, :npages]
+                    for mine, table in zip(pages, self.tables):
+                        mine[j] = table[req.slot, :mine.shape[1]]
                     aids[j] = req.adapter
                     true_lens[j] = len(req.prompt)
                     temps[j] = req.temperature
                 self._rng, sub = jax.random.split(self._rng)
                 first, *cache = await self._call(
                     ph, self.programs.prefill_batch, self.params, self.loras,
-                    jnp.asarray(aids), jnp.asarray(toks), jnp.asarray(pages),
-                    *self.cache, jnp.asarray(true_lens),
+                    jnp.asarray(aids), jnp.asarray(toks),
+                    self._tables_arg(pages), *self.cache,
+                    jnp.asarray(true_lens),
                     jnp.asarray(temps), sub, self.cfg)
                 self.cache = tuple(cache)
             now = time.perf_counter_ns()
@@ -599,8 +689,7 @@ class ContinuousBatchingEngine:
                 req.t_admit = now
             metrics.llm_prefill_waves_total.inc()
             metrics.llm_prefill_prompts_total.inc(len(reqs))
-            metrics.llm_prefill_true_tokens_total.inc(
-                sum(len(r.prompt) for r in reqs))
+            metrics.llm_prefill_true_tokens_total.inc(true_tokens)
             metrics.llm_prefill_padded_tokens_total.inc(nb * Tp_pad)
             out.append((reqs, first))
         return out
@@ -732,7 +821,7 @@ class ContinuousBatchingEngine:
             toks, tok_d, lens_d, *cache = await self._call(
                 ph, self.programs.decode_multi, self.params, self.loras,
                 jnp.asarray(self.aids.copy()), tok_d, lens_d,
-                jnp.asarray(self.page_tables.copy()), *self.cache,
+                self._tables_arg(self.tables), *self.cache,
                 jnp.asarray(active), jnp.asarray(self.temps.copy()), sub,
                 self.cfg, K)
             self.cache = tuple(cache)
@@ -776,7 +865,9 @@ class ContinuousBatchingEngine:
         """What a synced block's attention attended and what it fetched
         for that (``rt_llm_decode_kv_tokens_{live,read}_total``), reckoned
         from each snapshot slot's length at the block's start: step k of a
-        slot attends ``start + k + 1`` positions. A slot the planned loop
+        slot attends ``start + k + 1`` positions, or as many of them as a
+        layer of the kind reaches back; over kinds, the mean over layers. A
+        slot the planned loop
         has already handed on has its start from the request itself."""
         starts = np.array(
             [self.seq_lens[i] if self.slot_req[i] is req
@@ -784,12 +875,23 @@ class ContinuousBatchingEngine:
              for i, req in enumerate(slot_snapshot) if req is not None],
             np.int64)
         lens = starts[:, None] + np.arange(1, K + 1)  # [live slots, K]
-        live = int(lens.sum())
-        if self._kv_in_place:  # whole pages, at most the whole table
-            pages = -(-np.minimum(lens, self.MAXP * self.PS) // self.PS)
-            read = int(pages.sum()) * self.PS
-        else:
-            read = K * self.B * self.MAXP * self.PS
+        live = read = 0.0
+        layers = sum(k.layers for k in self.kinds)
+        for kind in self.kinds:
+            reach = lens if kind.reach is None else np.minimum(lens, kind.reach)
+            within = int(reach.sum())
+            if self._kv_in_place:  # whole pages, from the first within reach
+                ends = np.minimum(lens, self.MAXP * self.PS)
+                pages = -(-ends // self.PS) - (lens - reach) // self.PS
+                fetched = int(pages.sum()) * self.PS
+            else:
+                fetched = K * self.B * kind.table * self.PS
+            if len(self.kinds) > 1:
+                tags = {"kind": kind.name}
+                metrics.llm_decode_kv_tokens_live_total.inc(within, tags)
+                metrics.llm_decode_kv_tokens_read_total.inc(fetched, tags)
+            live += within * kind.layers / layers
+            read += fetched * kind.layers / layers
         metrics.llm_decode_kv_tokens_live_total.inc(live)
         metrics.llm_decode_kv_tokens_read_total.inc(read)
         self._last_kv = {"kv_live": round(live / K, 2),
@@ -878,10 +980,8 @@ class ContinuousBatchingEngine:
                                             or req.cancelled):
                         req.slot = -1  # emission closes the stream at finish
                         self.slot_req[i] = None
-                        self.free_pages.extend(
-                            int(p) for p in self.page_tables[i] if p != 0)
-                        self.page_tables[i, :] = 0
-                        self.seq_lens[i] = 0
+                        self._release_pages(
+                            i, len(req.prompt) + max(req.planned, 1) - 1)
                         freed += 1
                         if req.cancelled and not req.finished:
                             # user-cancelled: no finish emission will ever
@@ -1156,7 +1256,7 @@ class ContinuousBatchingEngine:
                         r is not None and not r.cancelled and r.spec
                         and r.temperature <= 0 for r in self.slot_req])
                     statics = (jnp.asarray(self.aids.copy()),
-                               jnp.asarray(self.page_tables.copy()),
+                               jnp.asarray(self.tables[0].copy()),
                                jnp.asarray(active),
                                jnp.asarray(spec_ok),
                                jnp.asarray(self.temps.copy()),

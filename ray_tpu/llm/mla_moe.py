@@ -16,7 +16,7 @@ of ``llm/llama.py``, another cache and another layer.
   tokens in prefill (bound by the MXU); dead slots and prompt padding are
   routed nowhere.
 * **What the experts did rides back with the tokens.** A decode step's row
-  is ``[B tokens | STATS]``: routed assignments, distinct experts touched,
+  is ``[B tokens | MOE_STATS]``: routed assignments, distinct experts touched,
   the largest expert's load and the expert slots they are a share of, each
   summed over the expert layers — read at the block's one sync, no second
   device->host read.
@@ -31,19 +31,13 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.llm.programs import ServePrograms, _sample_tail
+from ray_tpu.llm.programs import (
+    MOE_STATS, ServePrograms, _sample_tail, moe_load_stats)
 from ray_tpu.models.mla_moe import (
     MlaMoeConfig, mla_absorb, mla_attend_absorbed, mla_attend_expanded,
     mla_expand, mla_moe_ffn, mla_project)
 from ray_tpu.ops.basic import rms_norm, rope_freqs
 from ray_tpu.ops.paged_attention import paged_latent_attention
-
-# extra int32 columns of a decode step's token row, each summed over the
-# expert layers: rows routed to held experts, distinct held experts that got
-# any, the largest expert's rows, and held experts x expert layers (what
-# "touched" is a share of)
-STATS = ("moe_assignments", "moe_experts_touched", "moe_max_load",
-         "moe_expert_slots")
 
 
 def make_latent_pool(cfg: MlaMoeConfig, page_size: int, n_pages: int,
@@ -54,16 +48,6 @@ def make_latent_pool(cfg: MlaMoeConfig, page_size: int, n_pages: int,
     dtype = jnp.bfloat16 if kv_dtype == "bf16" else jnp.dtype(cfg.dtype)
     return (jnp.zeros((cfg.n_layers, n_pages, page_size, cfg.latent_width),
                       dtype),)
-
-
-def _load_stats(loads):
-    """A step's rows per held expert, one [held] array an expert layer ->
-    the STATS sums."""
-    if not loads:
-        return jnp.zeros((len(STATS),), jnp.int32)
-    load = jnp.stack(loads)
-    return jnp.stack([load.sum(), (load > 0).sum(), load.max(axis=-1).sum(),
-                      jnp.asarray(load.size)]).astype(jnp.int32)
 
 
 def _reads_in_place(pool) -> bool:
@@ -119,7 +103,7 @@ def _decode_body(params, tokens, pos, page_tables, pool, active, temps, key,
     x = rms_norm(x, params["norm"]["scale"])
     logits = x[:, 0] @ params["lm_head"]["kernel"]
     next_tok = _sample_tail(logits, temps, key)
-    return jnp.where(active, next_tok, 0), pool, _load_stats(loads)
+    return jnp.where(active, next_tok, 0), pool, moe_load_stats(loads)
 
 
 @partial(jax.jit, static_argnames=("cfg", "n_steps"), donate_argnums=(6,))
@@ -128,7 +112,7 @@ def mla_moe_decode_multi(params, loras, aids, tokens, seq_lens, page_tables,
                          n_steps: int):
     """``n_steps`` fused decode steps as one device program: the contract of
     ``llm/llama.py`` ``paged_decode_multi`` with one latent pool in place of the K
-    and V pools, and rows of ``[B tokens | STATS]``. ``loras``/``aids`` are
+    and V pools, and rows of ``[B tokens | MOE_STATS]``. ``loras``/``aids`` are
     the engine's (None / zeros here: refused at construction)."""
     def step(carry, k):
         tok, pos, pool = carry
@@ -178,4 +162,4 @@ def mla_moe_prefill_batch(params, loras, aids, tokens, pages, pool,
 PROGRAMS = ServePrograms(
     family="mla_moe", make_cache=make_latent_pool,
     decode_multi=mla_moe_decode_multi, prefill_batch=mla_moe_prefill_batch,
-    stats=STATS, decode_in_place=lambda cache: _reads_in_place(cache[0]))
+    stats=MOE_STATS, decode_in_place=lambda cache: _reads_in_place(cache[0]))

@@ -2,8 +2,8 @@
 owns slots, pages, admission and the loops and knows no model: it asks
 ``serving_programs(cfg)`` for the family's ``ServePrograms`` by the config's
 TYPE and threads the family's cache through unseen. A family's program
-module (``llm/llama.py``, ``llm/mla_moe.py``) imports this file, ``models/``
-and ``ops/``, never the engine, and is imported when its config is served.
+module (``llm/llama.py``, ``llm/mla_moe.py``, ``llm/cohere2_moe.py``) imports
+this file, ``models/`` and ``ops/``, never the engine, and is imported when its config is served.
 A new family supplies a config type, its layer's halves in ``models/``, two
 jitted programs, a cache, and one branch of ``serving_programs``.
 """
@@ -14,6 +14,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models.cohere2_moe import Cohere2MoeConfig
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.models.mla_moe import MlaMoeConfig
 
@@ -25,8 +26,41 @@ class UnsupportedByModel(NotImplementedError):
     def __init__(self, feature: str, family: str):
         super().__init__(
             f"{feature} is not supported for the {family!r} model family: "
-            f"it assumes a K pool and a V pool of n_kv_heads x head_dim")
+            f"it assumes one K pool and one V pool of n_kv_heads x head_dim "
+            f"over every layer")
         self.feature, self.family = feature, family
+
+
+@dataclass(frozen=True)
+class PageKind:
+    """One kind of page of a family's cache, as far as the engine sees it:
+    a name, how many layers' rows its pools hold (the weight of its reads
+    in the read counters), how many entries a slot's table of it has, and
+    how far back a layer of it attends (None: to position 0). A slot of
+    ``n`` positions holds ``min(ceil(n / PS), table)`` of its pages: a
+    table shorter than the sequence is the family's ring."""
+    name: str
+    layers: int
+    table: int
+    reach: int | None = None
+
+
+# extra int32 columns of a decode step's token row of the families with
+# expert layers, each summed over the expert layers: rows routed to held
+# experts, distinct held experts that got any, the largest expert's rows,
+# and held experts x expert layers (what "touched" is a share of)
+MOE_STATS = ("moe_assignments", "moe_experts_touched", "moe_max_load",
+             "moe_expert_slots")
+
+
+def moe_load_stats(loads):
+    """A step's rows per held expert, one [held] array an expert layer ->
+    the MOE_STATS sums."""
+    if not loads:
+        return jnp.zeros((len(MOE_STATS),), jnp.int32)
+    load = jnp.stack(loads)
+    return jnp.stack([load.sum(), (load > 0).sum(), load.max(axis=-1).sum(),
+                      jnp.asarray(load.size)]).astype(jnp.int32)
 
 
 @dataclass(frozen=True)
@@ -46,6 +80,14 @@ class ServePrograms:
     ``decode_in_place(cache) -> bool``: whether ``decode_multi`` fetches
     only the pages of that cache that hold tokens; None where it gathers
     every slot's whole table a step (what the read counters then report).
+    ``page_kinds(cfg, page_size, max_seq_len) -> (PageKind, ...)``: the
+    kinds of pages of a cache that has more than one (window and full
+    layers). The engine then keeps a table and a free list a kind, and hands
+    the programs a TUPLE of tables where it hands one (``page_tables`` in
+    decode, ``pages`` in prefill, in the kinds' order); None is one kind
+    for every layer. ``prefill_wave_limit = (prompts, tokens)``: the most
+    one prefill program may hold, so a pad group is split; None splits
+    nothing.
     ``lora(cfg, adapters, rank) -> (stack, name -> index)`` stacks named
     adapters. It and the rest are the Llama family's and None elsewhere: the
     engine refuses what needs them."""
@@ -55,6 +97,8 @@ class ServePrograms:
     prefill_batch: callable
     stats: tuple = ()
     decode_in_place: callable = None
+    page_kinds: callable = None
+    prefill_wave_limit: tuple | None = None
     prefill_suffix: callable = None
     decode_spec: callable = None
     decode_verify: callable = None
@@ -71,6 +115,10 @@ def serving_programs(cfg) -> ServePrograms:
         return PROGRAMS
     if isinstance(cfg, MlaMoeConfig):
         from ray_tpu.llm.mla_moe import PROGRAMS
+
+        return PROGRAMS
+    if isinstance(cfg, Cohere2MoeConfig):
+        from ray_tpu.llm.cohere2_moe import PROGRAMS
 
         return PROGRAMS
     raise TypeError(f"no serving programs for a {type(cfg).__name__}")

@@ -213,7 +213,7 @@ class LLMEngineServer:
 
         return {"steps": self.engine.steps, "tokens_out": self.engine.tokens_out,
                 "waiting": len(self.engine.waiting),
-                "free_pages": len(self.engine.free_pages),
+                "free_pages": len(self.engine.free[0]),
                 "stages": metrics.stage_totals()}
 
     def device_report(self) -> dict:

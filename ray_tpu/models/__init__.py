@@ -1,5 +1,8 @@
-"""Model zoo: the Llama family, the MLA + sparse-expert family, ResNet, MLP."""
+"""Model zoo: the Llama family, the MLA + sparse-expert family, the window +
+full attention sparse-expert family, ResNet, MLP."""
 
+from ray_tpu.models.cohere2_moe import (  # noqa: F401
+    Cohere2MoeConfig, cohere2_moe_forward, cohere2_moe_init)
 from ray_tpu.models.llama import LlamaConfig, llama_forward, llama_init  # noqa: F401
 from ray_tpu.models.mla_moe import (  # noqa: F401
     MlaMoeConfig, mla_moe_forward, mla_moe_init)
@@ -11,4 +14,6 @@ def init_fn(cfg):
         return llama_init
     if isinstance(cfg, MlaMoeConfig):
         return mla_moe_init
+    if isinstance(cfg, Cohere2MoeConfig):
+        return cohere2_moe_init
     raise TypeError(f"no model for a {type(cfg).__name__}")

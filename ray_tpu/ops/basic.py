@@ -52,3 +52,24 @@ def swiglu(x, w_gate, w_up, w_down):
     gate = checkpoint_name(x @ w_gate, "ffn_hidden")
     up = checkpoint_name(x @ w_up, "ffn_hidden")
     return (jax.nn.silu(gate) * up) @ w_down
+
+
+def layer_norm(x, scale, eps: float = 1e-5):
+    """LayerNorm without a bias (mean removed, unit variance, one gain) with
+    fp32 accumulation, output in input dtype."""
+    x32 = x.astype(jnp.float32)
+    x32 = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+    var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+    return (x32 * jax.lax.rsqrt(var + eps) * scale).astype(x.dtype)
+
+
+def rope_pairs(x, cos, sin, positions):
+    """Rotary embedding in the adjacent-pair form (``rope_gptj``): lanes
+    (2i, 2i + 1) rotate together by ``positions * theta^(-2i / D)``. x:
+    [B, T, H, D]; cos/sin: [T_max, D/2] (``rope_freqs``); positions: [B, T]."""
+    c = cos[positions][:, :, None, :]
+    s = sin[positions][:, :, None, :]
+    pairs = x.reshape(*x.shape[:-1], x.shape[-1] // 2, 2)
+    x1, x2 = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([x1 * c - x2 * s, x1 * s + x2 * c], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)

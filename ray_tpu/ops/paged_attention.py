@@ -57,9 +57,13 @@ _BLOCK_TOKENS = 256
 _LATENT_BLOCK_TOKENS = 512
 
 
-def _kernel(layer_ref, tables_ref, lengths_ref, q_ref, *refs,
-            sm_scale: float, n_pages: int):
-    # refs: the pools (HBM), the output, a VMEM buffer a pool, the semaphores
+def _kernel(layer_ref, tables_ref, lengths_ref, *refs,
+            sm_scale: float, n_pages: int, ring: bool = False):
+    # refs: [the starts, where the table is a ring,] the queries, the pools
+    # (HBM), the output, a VMEM buffer a pool, the semaphores
+    if ring:
+        starts_ref, *refs = refs
+    q_ref, *refs = refs
     n_pools = (len(refs) - 2) // 2
     pools, o_ref = refs[:n_pools], refs[n_pools]
     bufs, sems = refs[n_pools + 1:-1], refs[-1]
@@ -77,7 +81,16 @@ def _kernel(layer_ref, tables_ref, lengths_ref, q_ref, *refs,
                   (slice(None),) * (len(pools[0].shape) - 3)
                   + (pl.ds(0, lanes),))
 
+    def first_page(b):
+        """The page the walk starts at: 0, or the one that holds the slot's
+        first position within reach (a ring table)."""
+        return starts_ref[b] // PS if ring else 0
+
     def pages_of(b):
+        """How many pages the walk of slot ``b`` fetches."""
+        if ring:  # from the first position within reach to the last
+            return jnp.minimum(
+                pl.cdiv(lengths_ref[b], PS) - first_page(b), MAXP)
         return jnp.minimum(pl.cdiv(lengths_ref[b], PS), MAXP)
 
     def copies(b, i, buf):
@@ -88,7 +101,10 @@ def _kernel(layer_ref, tables_ref, lengths_ref, q_ref, *refs,
         live = pages_of(b)
         for j in range(n_pages):
             p = i * n_pages + j
-            page = tables_ref[b, jnp.minimum(p, MAXP - 1)]
+            if ring:  # page p of the sequence lies at entry p mod the table
+                page = tables_ref[b, (first_page(b) + p) % MAXP]
+            else:
+                page = tables_ref[b, jnp.minimum(p, MAXP - 1)]
             for kv, (pool, dst) in enumerate(zip(pools, bufs)):
                 out.append((p < live, pltpu.make_async_copy(
                     pool.at[(layer, page, *whole_rows)], dst.at[buf, j],
@@ -132,7 +148,11 @@ def _kernel(layer_ref, tables_ref, lengths_ref, q_ref, *refs,
             jnp.int32, (rows, lanes - lo), 1) < width - lo
 
     def slot(b, buf):
-        length = jnp.minimum(lengths_ref[b], MAXP * PS)
+        if ring:  # positions [reach_lo, length), counted from the first page
+            base = first_page(b) * PS
+            reach_lo, length = starts_ref[b] - base, lengths_ref[b] - base
+        else:
+            length = jnp.minimum(lengths_ref[b], MAXP * PS)
         n_blocks = pl.cdiv(pages_of(b), n_pages)
         q = q_ref[b]  # [H, lanes]
 
@@ -161,6 +181,9 @@ def _kernel(layer_ref, tables_ref, lengths_ref, q_ref, *refs,
                 preferred_element_type=jnp.float32) * sm_scale  # [H, rows]
             ok = jnp.logical_and(
                 head_ok, i * (n_pages * PS) + row_tok < length)
+            if ring:
+                ok = jnp.logical_and(
+                    ok, i * (n_pages * PS) + row_tok >= reach_lo)
             s = jnp.where(ok, s, _NEG_BIG)
             m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
             p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
@@ -184,7 +207,7 @@ def _kernel(layer_ref, tables_ref, lengths_ref, q_ref, *refs,
 
 
 def paged_decode_attention(q, kpool, vpool, layer, page_tables, lengths, *,
-                           interpret: bool | None = None):
+                           starts=None, interpret: bool | None = None):
     """Attention of one query row a slot over the slot's pages, in place.
 
     q: [B, H, hd]; kpool, vpool: [L, P, PS, KV, hd] (handed over whole; they
@@ -195,14 +218,26 @@ def paged_decode_attention(q, kpool, vpool, layer, page_tables, lengths, *,
     nothing and gets zeros. A length past MAXP * PS attends the whole table.
     Returns [B, H, hd] in q's dtype. H // KV query heads share a KV head,
     read from the shapes (KV == H is plain multi-head attention). The
-    kernel compiles for the TPU and is interpreted anywhere else."""
+    kernel compiles for the TPU and is interpreted anywhere else.
+
+    ``starts`` [B] int32 makes the call a WINDOW's: a slot attends positions
+    ``[starts, lengths)`` and its table is a ring — the page of positions
+    ``[p * PS, (p + 1) * PS)`` lies at entry ``p % MAXP``, so a table of
+    ``window / PS + 1`` entries serves a sequence of any length. The walk
+    begins at the page that holds ``starts``: nothing before it is fetched,
+    and of that one page the positions before ``starts`` are masked."""
     H, KV = q.shape[1], kpool.shape[3]
     if H % KV:
         raise ValueError(f"{H} query heads do not group over {KV} KV heads")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    layer = jnp.asarray(layer, jnp.int32)
+    if starts is not None:
+        return _paged_window_attention(
+            q, kpool, vpool, layer, page_tables, lengths, starts,
+            interpret=bool(interpret))
     return _paged_decode_attention(
-        q, kpool, vpool, jnp.asarray(layer, jnp.int32), page_tables, lengths,
+        q, kpool, vpool, layer, page_tables, lengths,
         interpret=bool(interpret))
 
 
@@ -218,6 +253,17 @@ def _paged_decode_attention(q, kpool, vpool, layer, page_tables, lengths, *,
     return _walk_pools(q, (kpool, vpool), layer, page_tables, lengths,
                        v_width=hd, sm_scale=1.0 / math.sqrt(hd),
                        block_tokens=_BLOCK_TOKENS, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _paged_window_attention(q, kpool, vpool, layer, page_tables, lengths,
+                            starts, *, interpret: bool):
+    """A jit of its own for the reason ``_paged_decode_attention`` is one."""
+    hd = q.shape[-1]
+    return _walk_pools(q, (kpool, vpool), layer, page_tables, lengths,
+                       v_width=hd, sm_scale=1.0 / math.sqrt(hd),
+                       block_tokens=_BLOCK_TOKENS, interpret=interpret,
+                       starts=starts)
 
 
 def paged_latent_attention(q, pool, layer, page_tables, lengths, *,
@@ -251,14 +297,22 @@ def _paged_latent_attention(q, pool, layer, page_tables, lengths, *,
 
 
 def _walk_pools(q, pools, layer, page_tables, lengths, *, v_width: int,
-                sm_scale: float, block_tokens: int, interpret: bool):
-    """The one ``pallas_call`` both entries make: the pools stay where they
-    are (``pl.ANY``), a VMEM buffer of two blocks a pool."""
+                sm_scale: float, block_tokens: int, interpret: bool,
+                starts=None):
+    """The one ``pallas_call`` every entry makes: the pools stay where they
+    are (``pl.ANY``), a VMEM buffer of two blocks a pool. ``starts`` is one
+    more scalar-prefetched array, and a ring table (``_kernel``)."""
     B, H, width = q.shape
     PS, page = pools[0].shape[2], pools[0].shape[2:]
     MAXP = page_tables.shape[1]
     n_pages = max(1, min(block_tokens // PS, MAXP))
-    kernel = functools.partial(_kernel, sm_scale=sm_scale, n_pages=n_pages)
+    if starts is None:
+        kernel = functools.partial(_kernel, sm_scale=sm_scale, n_pages=n_pages)
+        prefetch = ()
+    else:
+        kernel = functools.partial(_kernel, sm_scale=sm_scale,
+                                   n_pages=n_pages, ring=True)
+        prefetch = (starts.astype(jnp.int32),)
     # Rows whose width is not whole lane tiles (MLA's 576 = 4.5 x 128) lie in
     # HBM padded to whole ones, and Mosaic takes no slice of a tiled axis that
     # is not whole tiles, the whole axis included ("Slice shape along
@@ -277,7 +331,7 @@ def _walk_pools(q, pools, layer, page_tables, lengths, *, v_width: int,
         kernel,
         out_shape=out,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=3 + len(prefetch),
             grid=(1,),
             in_specs=[pl.BlockSpec(q.shape, lambda i, *_: (0, 0, 0))]
             + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
@@ -293,4 +347,5 @@ def _walk_pools(q, pools, layer, page_tables, lengths, *, v_width: int,
             bytes_accessed=len(pools) * window),
         interpret=interpret,
     )(layer.reshape(1),
-      page_tables.astype(jnp.int32), lengths.astype(jnp.int32), q, *pools)
+      page_tables.astype(jnp.int32), lengths.astype(jnp.int32), *prefetch, q,
+      *pools)

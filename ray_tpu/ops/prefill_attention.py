@@ -1,0 +1,171 @@
+"""Pallas TPU prefill attention for grouped heads and a window — forward
+only, no ``[T, T]`` array anywhere.
+
+What ``ops/flash_attention.py`` is to the train path this is to a serving
+prefill over thousands of positions: online softmax over blocks of keys, with
+two things that kernel has not.
+
+* **The heads stay grouped.** q is ``[N, T, H * hd]`` and k, v are ``[N, T,
+  KV * hd]`` as the projections leave them. A grid cell is one KV head and
+  one block of queries: it takes the ``G = H // KV`` query heads of that KV
+  head as lane slices of one ``[block_q, G * hd]`` block and runs them, one
+  after the other, against the SAME fetched block of keys and values — K and
+  V cross HBM once a block of queries, not once a query head, and are never
+  written out to H heads.
+* **A lower bound.** ``window=W`` lets position i attend j where ``0 <= i -
+  j < W``. A block of queries then visits only the blocks of keys that hold
+  such j: the innermost grid axis counts from the first of them, so neither
+  the blocks the window has slid past nor the ones above the diagonal are
+  fetched (their index repeats the last one needed, which copies nothing)
+  or computed.
+"""
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+_NEG_BIG = -1e30
+_LANES = 128
+BLOCK_Q, BLOCK_K = 256, 512
+
+
+def _key_blocks(i, block_q: int, block_k: int, window: int | None):
+    """First and last block of keys that the queries of block ``i`` attend."""
+    first = 0 if window is None else (
+        jnp.maximum(i * block_q - (window - 1), 0) // block_k)
+    return first, (i * block_q + block_q - 1) // block_k
+
+
+def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
+            sm_scale: float, window: int | None, block_q: int, block_k: int,
+            G: int, hd: int):
+    i, j = pl.program_id(2), pl.program_id(3)
+    first, last = _key_blocks(i, block_q, block_k, window)
+    kj = first + j
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, _NEG_BIG)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(kj <= last)
+    def _compute():
+        k, v = k_ref[0], v_ref[0]  # [block_k, hd]
+        rows = i * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0)
+        cols = kj * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
+        ok = rows >= cols
+        if window is not None:
+            ok = jnp.logical_and(ok, rows - cols < window)
+        for g in range(G):
+            q = q_ref[0, :, g * hd:(g + 1) * hd]  # [block_q, hd]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale
+            s = jnp.where(ok, s, _NEG_BIG)
+            m_prev = m_scr[g, :, 0]
+            m_new = jnp.maximum(m_prev, s.max(axis=1))
+            p = jnp.where(ok, jnp.exp(s - m_new[:, None]), 0.0)
+            fix = jnp.exp(m_prev - m_new)
+            l_new = l_scr[g, :, 0] * fix + p.sum(axis=1)
+            acc_scr[g] = acc_scr[g] * fix[:, None] + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[g] = jnp.broadcast_to(m_new[:, None], (block_q, _LANES))
+            l_scr[g] = jnp.broadcast_to(l_new[:, None], (block_q, _LANES))
+
+    @pl.when(j == pl.num_programs(3) - 1)
+    def _finalize():
+        for g in range(G):
+            denom = jnp.maximum(l_scr[g, :, 0], 1e-30)
+            o_ref[0, :, g * hd:(g + 1) * hd] = (
+                acc_scr[g] / denom[:, None]).astype(o_ref.dtype)
+
+
+def blocks_for(T: int) -> tuple[int, int] | None:
+    """The (queries, keys) block sizes for ``T`` positions, or None where
+    ``T`` is not whole blocks of at least 128 (the caller's plain form
+    then)."""
+    bq = next((b for b in (BLOCK_Q, 128) if T % b == 0), None)
+    bk = next((b for b in (BLOCK_K, 256, 128) if T % b == 0), None)
+    return None if bq is None or bk is None else (bq, bk)
+
+
+def gqa_prefill_attention(q, k, v, *, n_kv_heads: int, window: int | None = None,
+                          interpret: bool | None = None):
+    """Causal attention of every position of a prompt over the prompt, the
+    heads grouped, optionally within a window.
+
+    q: [N, T, H * hd]; k, v: [N, T, KV * hd] with ``KV = n_kv_heads`` and hd
+    a multiple of 128; query head h reads KV head ``h // (H // KV)``.
+    ``window``: position i attends j where ``0 <= i - j < window`` (None:
+    every ``j <= i``). T must be whole blocks (``blocks_for``). Returns
+    [N, T, H * hd] in q's dtype. Compiled for the TPU, interpreted anywhere
+    else."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    return _gqa_prefill_attention(q, k, v, n_kv_heads=int(n_kv_heads),
+                                  window=None if window is None else int(window),
+                                  interpret=bool(interpret))
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("n_kv_heads", "window", "interpret"))
+def _gqa_prefill_attention(q, k, v, *, n_kv_heads: int, window: int | None,
+                           interpret: bool):
+    """A jit of its own: the layers of a program are call sites of one
+    traced and lowered kernel a kind (``ops/paged_attention.py``)."""
+    N, T, HD = q.shape
+    KV = n_kv_heads
+    hd = k.shape[-1] // KV
+    G = HD // hd // KV
+    bq, bk = blocks_for(T)
+    # the most blocks of keys any block of queries visits
+    visits = max(
+        (i * bq + bq - 1) // bk
+        - (0 if window is None else max(i * bq - (window - 1), 0) // bk) + 1
+        for i in range(T // bq))
+
+    def key_block(n, kv, i, j):
+        first, last = _key_blocks(i, bq, bk, window)
+        return n, jnp.minimum(first + j, last), kv
+
+    kernel = functools.partial(
+        _kernel, sm_scale=1.0 / math.sqrt(hd), window=window, block_q=bq,
+        block_k=bk, G=G, hd=hd)
+    pairs = T * (T + 1) // 2 if window is None or window >= T else (
+        window * (window + 1) // 2 + (T - window) * window)
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        grid=(N, KV, T // bq, visits),
+        in_specs=[
+            pl.BlockSpec((1, bq, G * hd), lambda n, kv, i, j: (n, i, kv)),
+            pl.BlockSpec((1, bk, hd), key_block),
+            pl.BlockSpec((1, bk, hd), key_block),
+        ],
+        out_specs=pl.BlockSpec((1, bq, G * hd), lambda n, kv, i, j: (n, i, kv)),
+        scratch_shapes=[
+            pltpu.VMEM((G, bq, _LANES), jnp.float32),
+            pltpu.VMEM((G, bq, _LANES), jnp.float32),
+            pltpu.VMEM((G, bq, hd), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        cost_estimate=pl.CostEstimate(
+            flops=4 * N * KV * G * hd * pairs,
+            transcendentals=N * KV * G * pairs,
+            bytes_accessed=2 * q.size * q.dtype.itemsize
+            + 2 * k.size * k.dtype.itemsize * (T // bq) * visits * bk // T),
+        interpret=interpret,
+        name="gqa_prefill_attention",
+    )(q, k, v)

@@ -9,7 +9,9 @@ in-tree"; vLLM handles EP internally).
   dispatch tensor, expert weights sharded on the ``ep`` mesh axis, and
   sharding propagation turning the einsums into all_to_all over ICI.
 * **Serving (``sigmoid_topk_route`` + ``routed_experts`` + ``moe_layer``,
-  reached from ``llm/mla_moe.py``):** the DeepSeek-V3 family's layer. Sigmoid
+  reached from ``llm/mla_moe.py`` and ``llm/cohere2_moe.py``):** the
+  DeepSeek-V3 family's layer, and Cohere2's with no bias, no routed scale and
+  its shared experts averaged. Sigmoid
   scores, the k experts with the largest ``score + bias`` chosen and
   weighed by the score alone, no capacity (no token is ever dropped),
   three-matrix SwiGLU experts and shared experts every token passes
@@ -94,12 +96,14 @@ def sigmoid_topk_route(h, router_w, bias, k: int, scale: float,
     the largest ``s + bias``, weighed by ``s`` alone — the bias chooses and
     never weighs. Equal sums go to the lower expert index (``lax.top_k``).
 
-    h: [T, D]; router_w: [D, E]; bias: [E]. Returns (idx [T, k] int32,
-    weights [T, k] float32)."""
+    h: [T, D]; router_w: [D, E]; bias: [E], or None for a router that
+    chooses by the score itself. Returns (idx [T, k] int32, weights [T, k]
+    float32)."""
     s = jax.nn.sigmoid(jnp.matmul(
         h.astype(jnp.float32), router_w.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
-    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), k)
+    _, idx = jax.lax.top_k(
+        s if bias is None else s + bias.astype(jnp.float32), k)
     w = jnp.take_along_axis(s, idx, axis=-1)
     if norm:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
@@ -140,16 +144,21 @@ def routed_experts(h, idx, w, experts, held: tuple[int, int], valid=None):
 
 
 def moe_layer(h, moe, *, k: int, scale: float, norm: bool = True,
-              held: tuple[int, int], valid=None):
+              held: tuple[int, int], valid=None, shared_scale: float = 1.0):
     """One expert layer of the family on ``h`` [T, D] (already normed):
     the held routed experts' part plus the shared experts (one SwiGLU of
-    the summed shared width, which every holder computes alike). Returns
-    (y [T, D], load [hi-lo])."""
+    the summed shared width, which every holder computes alike) times
+    ``shared_scale`` — 1 where the shared experts are summed, 1 / their
+    number where they are averaged. A router without a ``bias`` chooses by
+    its scores. Returns (y [T, D], load [hi-lo])."""
     from ray_tpu.ops.basic import swiglu
 
     idx, w = sigmoid_topk_route(h, moe["router"]["kernel"],
-                                moe["router"]["bias"], k, scale, norm)
+                                moe["router"].get("bias"), k, scale, norm)
     y, load = routed_experts(h, idx, w, moe["experts"], held, valid)
     sh = moe["shared"]
-    return y + swiglu(h, sh["w_gate"]["kernel"], sh["w_up"]["kernel"],
-                      sh["w_down"]["kernel"]), load
+    shared = swiglu(h, sh["w_gate"]["kernel"], sh["w_up"]["kernel"],
+                    sh["w_down"]["kernel"])
+    if shared_scale != 1:
+        shared = shared * jnp.asarray(shared_scale, shared.dtype)
+    return y + shared, load

@@ -242,12 +242,34 @@ llm_prefill_padded_tokens_total = Counter(
 # from its page pool to attend them — pages walked x page size where the
 # program reads in place, slots x table x page size a step where it gathers
 # the whole window.
+# A cache of more than one kind of page (window and full layers,
+# llm/programs.py PageKind): the untagged sample is the mean over the layers
+# — positions within a layer's REACH, and fetched for it — and each kind has
+# a sample of its own layers under its name.
 llm_decode_kv_tokens_live_total = Counter(
     "rt_llm_decode_kv_tokens_live_total",
-    "positions attended by live decode slots, summed over steps")
+    "positions attended by live decode slots, summed over steps",
+    tag_keys=("kind",))
 llm_decode_kv_tokens_read_total = Counter(
     "rt_llm_decode_kv_tokens_read_total",
-    "positions the decode programs fetched from the page pool for them")
+    "positions the decode programs fetched from the page pool for them",
+    tag_keys=("kind",))
+# The allocator of such a cache, a kind: pages its slots hold now, pages
+# drawn at admissions, and the pages of window layers that a ring table
+# wrote over as the window slid past them (never drawn a second time).
+llm_pages_held = Gauge(
+    "rt_llm_pages_held", "pages of a kind that slots hold now",
+    tag_keys=("kind",))
+llm_pages_drawn_total = Counter(
+    "rt_llm_pages_drawn_total", "pages of a kind drawn at admissions",
+    tag_keys=("kind",))
+llm_window_pages_released_total = Counter(
+    "rt_llm_window_pages_released_total",
+    "pages a window slid past that its ring table reused, counted when the "
+    "slot is freed")
+llm_prefill_wave_splits_total = Counter(
+    "rt_llm_prefill_wave_splits_total",
+    "prefill programs a family's wave limit added to their pad groups")
 # What a model family's decode programs count themselves, a step
 # (llm/programs.py ServePrograms.stats): the sums ride back with each block's
 # tokens and land here when the block is synced. The expert layers of
@@ -279,6 +301,8 @@ STAGE_FAMILIES = (
     llm_prefill_waves_total, llm_prefill_prompts_total,
     llm_prefill_true_tokens_total, llm_prefill_padded_tokens_total,
     llm_decode_kv_tokens_live_total, llm_decode_kv_tokens_read_total,
+    llm_pages_held, llm_pages_drawn_total, llm_window_pages_released_total,
+    llm_prefill_wave_splits_total,
     *LLM_MODEL_STATS.values(), serve_lane_seconds)
 
 

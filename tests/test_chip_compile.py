@@ -198,7 +198,8 @@ def test_mla_moe_decode_multi_compiles(one_chip, monkeypatch):
     """On a TPU the latent pool is attended in place by the paged kernel
     (``llm/mla_moe.py`` ``_reads_in_place`` asks ``jax.default_backend()``:
     the test answers for it, as above)."""
-    from ray_tpu.llm.mla_moe import STATS, mla_moe_decode_multi
+    from ray_tpu.llm.mla_moe import mla_moe_decode_multi
+    from ray_tpu.llm.programs import MOE_STATS as STATS
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     mla_moe_decode_multi.clear_cache()
@@ -260,3 +261,84 @@ def test_mla_moe_prefill_batch_compiles(one_chip):
     # heads run a group at a time (models/mla_moe.py _head_groups): all 32
     # at once wrote 5.2 GB of scores and probabilities
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+
+
+# ------------------------------------------- window + full attention experts
+def _cohere2_args(one_chip, layer_types):
+    """command-a-plus-05-2026 at its published widths (128 query heads on 8
+    KV heads, 16 held experts of 4096), a layer a kind given, the cell's
+    pools cut to 2,049 pages a kind."""
+    from ray_tpu.llm.cohere2_moe import make_pools
+    from ray_tpu.models.cohere2_moe import Cohere2MoeConfig, cohere2_moe_init
+
+    cfg = Cohere2MoeConfig(vocab_size=32768, n_layers=len(layer_types),
+                           layer_types=layer_types, max_seq_len=13312,
+                           experts_held=(0, 16), vocab_held=(0, 32768))
+    params = one_chip(jax.eval_shape(
+        lambda: cohere2_moe_init(jax.random.PRNGKey(0), cfg)))
+    cache = one_chip(jax.eval_shape(lambda: make_pools(cfg, 16, 2049, None)))
+    return cfg, params, cache, one_chip(_shape((2,), jnp.uint32))
+
+
+def test_cohere2_moe_decode_multi_compiles(one_chip, monkeypatch):
+    """One window and one full layer: each attends its own kind of pool in
+    place, the window layer through the ring table's 257 entries from its
+    first live page, 16 query heads a KV head in a 256-token block."""
+    from ray_tpu.llm.cohere2_moe import cohere2_moe_decode_multi
+    from ray_tpu.llm.programs import MOE_STATS
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cohere2_moe_decode_multi.clear_cache()
+    cfg, params, cache, key = _cohere2_args(
+        one_chip, ("sliding_attention", "full_attention"))
+    B = 48
+    i32 = one_chip(_shape((B,), jnp.int32))
+    tables = (one_chip(_shape((B, 832), jnp.int32)),
+              one_chip(_shape((B, 257), jnp.int32)))
+    try:
+        lowered = cohere2_moe_decode_multi.lower(
+            params, None, i32, i32, i32, tables, *cache,
+            one_chip(_shape((B,), jnp.bool_)),
+            one_chip(_shape((B,), jnp.float32)), key, cfg=cfg, n_steps=8)
+        compiled = lowered.compile()
+    finally:
+        cohere2_moe_decode_multi.clear_cache()
+    assert lowered.out_info[0].shape == (8, B + len(MOE_STATS))
+    text = compiled.as_text()
+    assert len(re.findall(r"%_paged_window_attention\S* = \S+ custom-call\(",
+                          text)) == 1
+    assert len(re.findall(r"%_paged_decode_attention\S* = \S+ custom-call\(",
+                          text)) == 1
+    # no table gathered out of a pool: neither [B, entries * PS, ...] shape
+    gathered = r"bf16\[48,(?:257|832|4112|13312),(?:16,)?8,128\]"
+    assert not re.findall(gathered, text)
+    # the temporaries are a step's activations, not a copy of a pool
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+
+
+def test_cohere2_moe_prefill_batch_compiles(one_chip, monkeypatch):
+    """The longest prompt of the cell as one program: blocked attention a
+    layer (no [T, T] scores: 128 heads x 12,288 squared would be 77 GB),
+    the expert layer a chunk of tokens at a time."""
+    from ray_tpu.llm.cohere2_moe import cohere2_moe_prefill_batch
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cohere2_moe_prefill_batch.clear_cache()
+    cfg, params, cache, key = _cohere2_args(
+        one_chip, ("sliding_attention", "full_attention"))
+    N, Tp = 1, 12288
+    pages = (one_chip(_shape((N, Tp // 16), jnp.int32)),
+             one_chip(_shape((N, 257), jnp.int32)))
+    try:
+        compiled = cohere2_moe_prefill_batch.lower(
+            params, None, one_chip(_shape((N,), jnp.int32)),
+            one_chip(_shape((N, Tp), jnp.int32)), pages, *cache,
+            one_chip(_shape((N,), jnp.int32)),
+            one_chip(_shape((N,), jnp.float32)), key, cfg=cfg).compile()
+    finally:
+        cohere2_moe_prefill_batch.clear_cache()
+    text = compiled.as_text()
+    assert len(re.findall(r"%gqa_prefill_attention\S* = \S+ custom-call\(",
+                          text)) == 2
+    assert not re.findall(r"\[(?:\d+,)*12288,12288\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.6e9

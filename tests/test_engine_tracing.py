@@ -141,6 +141,9 @@ def test_phases_land_in_the_profilers_trace(tiny, tmp_path):
                               and e[3]["live"] >= 1 for e in dispatches)
     assert sum(e[3]["tokens"] for e in events
                if e[2] == "engine.emit") == 2 * 29  # all but the first tokens
+    # a prefill wave's admit says how many prompts and true tokens it holds
+    waves = [e[3] for e in events if e[2] == "engine.admit" and e[3].get("pad")]
+    assert waves and all(w["tokens"] == 3 * w["prompts"] for w in waves)
     # no two intervals of the thread overlap unless one holds the other
     for a, b in zip(events, events[1:]):
         assert b[0] >= a[1] or b[1] <= a[1], (a, b)

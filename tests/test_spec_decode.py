@@ -130,7 +130,7 @@ def test_spec_kv_rollback_equivalent_pool(tiny):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
     # pool bookkeeping equivalent to the never-speculated run
     assert sorted(e_spec.free_pages) == sorted(e_plain.free_pages)
-    assert not e_spec.page_tables.any() and not e_plain.page_tables.any()
+    assert not e_spec.tables[0].any() and not e_plain.tables[0].any()
 
 
 def test_mixed_spec_plain_wave_one_ring(tiny):
