@@ -163,7 +163,7 @@ def _decode_body(params, tokens, pos, tables, cache, active, temps, key,
     logits = cohere2_logits(params, x[:, 0], cfg)
     next_tok = _sample_tail(logits, temps, key)
     return (jnp.where(active, next_tok, 0), (kf, vf, kw, vw),
-            moe_load_stats(loads))
+            moe_load_stats(loads, B * cfg.n_experts_per_tok))
 
 
 @partial(jax.jit, static_argnames=("cfg", "n_steps"),

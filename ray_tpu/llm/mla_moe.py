@@ -12,13 +12,15 @@ of ``llm/llama.py``, another cache and another layer.
   (``paged_latent_attention``) or over the gathered window
   (``mla_attend_absorbed``), as ``_reads_in_place`` sees.
 * **The expert layer** (``parallel/moe.py``) sees 32 tokens a decode step
-  (bound by the bytes of the experts they touch) and a whole wave's prompt
-  tokens in prefill (bound by the MXU); dead slots and prompt padding are
-  routed nowhere.
+  (bound by the bytes of the experts they touch: on a TPU one kernel streams
+  them, ``ops/grouped_swiglu.py``) and a whole wave's prompt tokens in
+  prefill (bound by the MXU: ``ragged_dot``); dead slots and prompt padding
+  are routed nowhere.
 * **What the experts did rides back with the tokens.** A decode step's row
   is ``[B tokens | MOE_STATS]``: routed assignments, distinct experts touched,
-  the largest expert's load and the expert slots they are a share of, each
-  summed over the expert layers — read at the block's one sync, no second
+  the largest expert's load, the expert slots they are a share of and the
+  grouped product's passes over an expert's matrices, each summed over the
+  expert layers — read at the block's one sync, no second
   device->host read.
 
 LoRA, int8 pools, speculative decoding, suffix prefill and page export
@@ -103,7 +105,8 @@ def _decode_body(params, tokens, pos, page_tables, pool, active, temps, key,
     x = rms_norm(x, params["norm"]["scale"])
     logits = x[:, 0] @ params["lm_head"]["kernel"]
     next_tok = _sample_tail(logits, temps, key)
-    return jnp.where(active, next_tok, 0), pool, moe_load_stats(loads)
+    return (jnp.where(active, next_tok, 0), pool,
+            moe_load_stats(loads, B * cfg.n_experts_per_tok))
 
 
 @partial(jax.jit, static_argnames=("cfg", "n_steps"), donate_argnums=(6,))

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from ray_tpu.models.cohere2_moe import Cohere2MoeConfig
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.models.mla_moe import MlaMoeConfig
+from ray_tpu.parallel.moe import expert_passes
 
 
 class UnsupportedByModel(NotImplementedError):
@@ -48,19 +49,22 @@ class PageKind:
 # extra int32 columns of a decode step's token row of the families with
 # expert layers, each summed over the expert layers: rows routed to held
 # experts, distinct held experts that got any, the largest expert's rows,
-# and held experts x expert layers (what "touched" is a share of)
+# held experts x expert layers (what "touched" is a share of), and how often
+# the grouped product put an expert's matrices through the MXU (once a
+# touched expert, or the kernel's row chunks: ``parallel/moe.py``)
 MOE_STATS = ("moe_assignments", "moe_experts_touched", "moe_max_load",
-             "moe_expert_slots")
+             "moe_expert_slots", "moe_passes")
 
 
-def moe_load_stats(loads):
-    """A step's rows per held expert, one [held] array an expert layer ->
-    the MOE_STATS sums."""
+def moe_load_stats(loads, rows: int):
+    """A step's rows per held expert, one [held] array an expert layer, of
+    ``rows`` assignments a layer -> the MOE_STATS sums."""
     if not loads:
         return jnp.zeros((len(MOE_STATS),), jnp.int32)
     load = jnp.stack(loads)
     return jnp.stack([load.sum(), (load > 0).sum(), load.max(axis=-1).sum(),
-                      jnp.asarray(load.size)]).astype(jnp.int32)
+                      jnp.asarray(load.size), expert_passes(load, rows)]
+                     ).astype(jnp.int32)
 
 
 @dataclass(frozen=True)

@@ -17,7 +17,8 @@ in-tree"; vLLM handles EP internally).
   three-matrix SwiGLU experts and shared experts every token passes
   through. The one-hot dispatch does not scale to 128 experts x 12k prefill
   tokens, so the routed product is a grouped matmul over the assignments
-  sorted by expert (``jax.lax.ragged_dot``). The layer is told which
+  sorted by expert (``jax.lax.ragged_dot`` for prefill's many rows a group,
+  ``ops/grouped_swiglu.py`` for a decode step's few). The layer is told which
   experts it holds (``held``): it routes over all of them and computes its
   own experts' part of the sum — on one chip that is all of them, and the
   exchange between holders is not here.
@@ -27,6 +28,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from ray_tpu.ops import grouped_swiglu
 
 
 def top1_gating(logits, n_experts: int, capacity: int):
@@ -110,14 +113,44 @@ def sigmoid_topk_route(h, router_w, bias, k: int, scale: float,
     return idx.astype(jnp.int32), w * scale
 
 
+# rows of a routed product at or under which it is a decode step's: the two
+# cells' steps route 192 and 384 rows, their smallest prefill program 3,072
+_FEW_ROWS = 512
+
+
+def _streams_experts(rows: int) -> bool:
+    """Which of the two grouped products ``routed_experts`` runs, decided by
+    what the code can see and by no option. On a TPU, ``rows = T * k`` at or
+    under ``_FEW_ROWS`` — a decode step: a few rows an expert, bound by the
+    bytes of the touched experts — goes through ``ops/grouped_swiglu.py``,
+    which reads each touched expert's three matrices once (measured alone on
+    a v5e, PR 32: 192 rows over 46 of 128 experts of 2048 x 768, 631 us
+    against 931; 384 rows over 16 experts of 4096 x 4096, 2,190 us against
+    3,168). More rows — every prefill program: thousands of rows a group,
+    bound by the MXU — and every other backend, where the kernel would be
+    interpreted, keep the three ``jax.lax.ragged_dot`` calls, which thereby
+    stay the kernel's plain reference and what the CPU tests run."""
+    return jax.default_backend() == "tpu" and rows <= _FEW_ROWS
+
+
+def expert_passes(load, rows: int):
+    """How often the grouped product of ``rows`` assignments puts an
+    expert's matrices through the MXU, summed over ``load`` [..., held]:
+    the kernel's row chunks where it runs, else once a touched expert."""
+    if _streams_experts(rows):
+        return grouped_swiglu.expert_passes(load)
+    return (load > 0).sum()
+
+
 def routed_experts(h, idx, w, experts, held: tuple[int, int], valid=None):
     """The held experts' part of ``sum_e w_e . swiglu_e(h)``, with no
     capacity: the ``T * k`` assignments are sorted by expert, each expert's
-    rows form one group of a grouped matmul (``jax.lax.ragged_dot``: on the
-    TPU one kernel that walks the groups), and the weighted rows are summed
-    back per token. Assignments to experts outside ``held = (lo, hi)`` and
-    of rows where ``valid`` is False (dead decode slots, prompt padding)
-    sort behind the last group and add nothing.
+    rows form one group of a grouped product (``_streams_experts`` says
+    which: one kernel that streams the touched experts for a decode step's
+    few rows, ``jax.lax.ragged_dot`` for prefill's many), and the weighted
+    rows are summed back per token. Assignments to experts outside ``held =
+    (lo, hi)`` and of rows where ``valid`` is False (dead decode slots,
+    prompt padding) sort behind the last group and add nothing.
 
     h: [T, D]; idx, w: [T, k]; experts: {"w_gate", "w_up": [hi-lo, D, F],
     "w_down": [hi-lo, F, D]}. Returns (y [T, D], load [hi-lo] int32: the
@@ -132,9 +165,13 @@ def routed_experts(h, idx, w, experts, held: tuple[int, int], valid=None):
     order = jnp.argsort(group)                           # stable
     load = jnp.bincount(group, length=n + 1)[:n].astype(jnp.int32)
     xs = h[order // k]                                   # [T * k, D]
-    hid = jax.nn.silu(jax.lax.ragged_dot(xs, experts["w_gate"], load)) * (
-        jax.lax.ragged_dot(xs, experts["w_up"], load))
-    ys = jax.lax.ragged_dot(hid, experts["w_down"], load)
+    if _streams_experts(T * k):
+        ys = grouped_swiglu.grouped_swiglu(
+            xs, experts["w_gate"], experts["w_up"], experts["w_down"], load)
+    else:
+        hid = jax.nn.silu(jax.lax.ragged_dot(xs, experts["w_gate"], load)) * (
+            jax.lax.ragged_dot(xs, experts["w_up"], load))
+        ys = jax.lax.ragged_dot(hid, experts["w_down"], load)
     ws = jnp.where(keep, w, 0.0).reshape(-1)[order]
     # rows past the last group belong to no expert: whatever the grouped
     # product left there is dropped, not scaled

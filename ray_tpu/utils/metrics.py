@@ -274,8 +274,9 @@ llm_prefill_wave_splits_total = Counter(
 # (llm/programs.py ServePrograms.stats): the sums ride back with each block's
 # tokens and land here when the block is synced. The expert layers of
 # llm/mla_moe.py: rows routed to the experts held here, distinct experts
-# that got any, the largest expert's rows, and experts held x expert
-# layers — each summed over expert layers and decode steps.
+# that got any, the largest expert's rows, experts held x expert layers,
+# and the grouped product's passes over an expert's matrices — each summed
+# over expert layers and decode steps.
 LLM_MODEL_STATS = {
     "moe_assignments": Counter(
         "rt_llm_moe_assignments_total",
@@ -289,6 +290,10 @@ LLM_MODEL_STATS = {
     "moe_expert_slots": Counter(
         "rt_llm_moe_expert_slots_total",
         "experts held x expert layers x decode steps: what touched is a share of"),
+    "moe_passes": Counter(
+        "rt_llm_moe_expert_passes_total",
+        "times an expert's matrices went through the MXU, a step a layer: "
+        "once a touched expert, more where its rows took several chunks"),
 }
 serve_lane_seconds = Histogram(
     "rt_serve_lane_seconds",

@@ -182,6 +182,12 @@ def test_train_step_takes_the_flash_kernels(one_chip, monkeypatch):
     assert compiled.as_text().count("tpu_custom_call") >= 3
 
 
+# the routed experts' grouped product in a compiled program: the decode
+# step's kernel (ops/grouped_swiglu.py) and ragged_dot's Mosaic kernels
+_SWIGLU = r"%ragged-dot-swiglu\S* = \S+ custom-call\("
+_RAGGED_DOT = r"%ragged-dot-none\S* = \S+ custom-call\("
+
+
 def _mla_moe_args(one_chip, n_layers: int):
     """The `kanana2_gen_closed` cell's programs at published widths (128
     experts of 768, 576-wide latent rows, 128,256 vocabulary rows), 32 slots
@@ -222,12 +228,15 @@ def test_mla_moe_decode_multi_compiles(one_chip, monkeypatch):
     # the pool in the kernel's layout (below) is 0.17 GB of it
     assert mem.temp_size_in_bytes < 0.5e9
     text = compiled.as_text()
-    # the routed experts are grouped matmuls (ragged_dot: Mosaic kernels),
-    # and the attention kernel is one Mosaic call a layer more
+    # the attention kernel is one Mosaic call a layer, and a step's few rows
+    # an expert go through the grouped SwiGLU kernel, one call an expert
+    # layer in place of ragged_dot's three (parallel/moe.py _streams_experts)
     kernel = len(re.findall(r"%_paged_latent_attention\S* = \S+ custom-call\(",
                             text))
     assert kernel == cfg.n_layers
-    assert text.count("tpu_custom_call") - kernel >= 3
+    assert len(re.findall(_SWIGLU, text)) == cfg.n_moe_layers == 1
+    assert not re.findall(_RAGGED_DOT, text)
+    assert text.count("tpu_custom_call") == kernel + cfg.n_moe_layers
     # ... reading the pool where it lies: no layer's pool sliced out of it, no
     # window gathered from that in either shape, none expanded to 32 heads of
     # keys or values
@@ -247,20 +256,31 @@ def test_mla_moe_decode_multi_compiles(one_chip, monkeypatch):
     assert sorted(copies) == ["{1,3,2,0", "{3,2,1,0"], copies
 
 
-def test_mla_moe_prefill_batch_compiles(one_chip):
+@pytest.mark.parametrize("N,Tp", [(8, 1536), (1, 512)])
+def test_mla_moe_prefill_batch_compiles(one_chip, monkeypatch, N, Tp):
+    """The largest and the smallest wave the cell warms, as on a TPU (the
+    routed product's branch asks the backend)."""
     from ray_tpu.llm.mla_moe import mla_moe_prefill_batch
 
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mla_moe_prefill_batch.clear_cache()
     cfg, params, pool, key = _mla_moe_args(one_chip, 2)
-    N, Tp = 8, 1536  # the largest wave the cell warms
-    compiled = mla_moe_prefill_batch.lower(
-        params, None, one_chip(_shape((N,), jnp.int32)),
-        one_chip(_shape((N, Tp), jnp.int32)),
-        one_chip(_shape((N, Tp // 16), jnp.int32)), pool,
-        one_chip(_shape((N,), jnp.int32)), one_chip(_shape((N,), jnp.float32)),
-        key, cfg=cfg).compile()
+    try:
+        compiled = mla_moe_prefill_batch.lower(
+            params, None, one_chip(_shape((N,), jnp.int32)),
+            one_chip(_shape((N, Tp), jnp.int32)),
+            one_chip(_shape((N, Tp // 16), jnp.int32)), pool,
+            one_chip(_shape((N,), jnp.int32)),
+            one_chip(_shape((N,), jnp.float32)), key, cfg=cfg).compile()
+    finally:
+        mla_moe_prefill_batch.clear_cache()
     # heads run a group at a time (models/mla_moe.py _head_groups): all 32
     # at once wrote 5.2 GB of scores and probabilities
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+    # thousands of rows a group: the grouped product stays ragged_dot's three
+    text = compiled.as_text()
+    assert len(re.findall(_RAGGED_DOT, text)) == 3 * cfg.n_moe_layers
+    assert not re.findall(_SWIGLU, text)
 
 
 # ------------------------------------------- window + full attention experts
@@ -309,6 +329,10 @@ def test_cohere2_moe_decode_multi_compiles(one_chip, monkeypatch):
                           text)) == 1
     assert len(re.findall(r"%_paged_decode_attention\S* = \S+ custom-call\(",
                           text)) == 1
+    # 384 rows over 16 held experts: one grouped SwiGLU kernel a layer, none
+    # of ragged_dot's
+    assert len(re.findall(_SWIGLU, text)) == cfg.n_layers
+    assert not re.findall(_RAGGED_DOT, text)
     # no table gathered out of a pool: neither [B, entries * PS, ...] shape
     gathered = r"bf16\[48,(?:257|832|4112|13312),(?:16,)?8,128\]"
     assert not re.findall(gathered, text)
@@ -342,3 +366,7 @@ def test_cohere2_moe_prefill_batch_compiles(one_chip, monkeypatch):
                           text)) == 2
     assert not re.findall(r"\[(?:\d+,)*12288,12288\]", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 2.6e9
+    # 2,048 tokens x 8 choices a chunk: ragged_dot's three a layer, mapped
+    # over the chunks, and no decode kernel
+    assert len(re.findall(_RAGGED_DOT, text)) == 3 * cfg.n_layers
+    assert not re.findall(_SWIGLU, text)
