@@ -1,0 +1,107 @@
+#!/usr/bin/env python3
+"""``sizing.py`` for the learned-sparse-attention expert family (``sizing.py``
+names the Llama programs; a copy of ``sizing_cohere2_moe.py`` with one table
+and three pools): compile the cell's two programs at their real sizes for a
+*described* v5e chip and print ``memory_analysis()``. Nothing runs.
+
+    python benchmarks/sizing_sparse_moe.py --config keye-vl-2.0-30b-a3b \
+        --decode 64 --prefill 1x14336 --prefill 2x8192 --prefill 4x4096
+"""
+from __future__ import annotations
+
+import argparse
+import os
+import sys
+import time
+
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--config", required=True)
+    ap.add_argument("--decode", type=int, action="append", default=[])
+    ap.add_argument("--prefill", action="append", default=[])
+    ap.add_argument("--max-batch", type=int)
+    ap.add_argument("--pages", type=int, help="pages to size instead of the file's")
+    args = ap.parse_args()
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from benchmarks.drivers.serve_sparse_moe import sparse_moe_config
+    from benchmarks.lib.configs import load_json
+    from ray_tpu.llm import sparse_moe as programs
+    from ray_tpu.models.sparse_moe import sparse_moe_init
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    chip = SingleDeviceSharding(topo.devices[0])
+    # the chip's branches, compiled here: the paged kernels, and the grouped
+    # SwiGLU kernel under a decode step's routed product
+    jax.default_backend = lambda: "tpu"
+
+    def placed(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tree)
+
+    def sd(shape, dtype):
+        return placed(jax.ShapeDtypeStruct(shape, dtype))
+
+    cf = load_json("configs", args.config + ".json")
+    cfg = sparse_moe_config(cf)
+    e = dict(cf["engine"])
+    if args.max_batch:
+        e["max_batch"] = args.max_batch
+    if args.pages:
+        e["n_pages"] = args.pages
+    B, PS = e["max_batch"], e["page_size"]
+    params = placed(jax.eval_shape(
+        lambda: sparse_moe_init(jax.random.PRNGKey(0), cfg)))
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    cache = placed(jax.eval_shape(
+        lambda: programs.make_pools(cfg, PS, e["n_pages"], None)))
+    pools = sum(x.size * x.dtype.itemsize for x in cache)
+    maxp = -(-e["max_seq_len"] // PS)
+    print(f"weights {weights / 1e9:.3f} GB, pools {pools / 1e9:.3f} GB "
+          f"({e['n_pages']} pages), slots {B}, table {maxp}", flush=True)
+    key = sd((2,), jnp.uint32)
+
+    def report(name, lowered):
+        t0 = time.monotonic()
+        try:
+            mem = lowered.compile().memory_analysis()
+        except Exception as ex:  # the compiler's refusal is the finding
+            print(f"{name}: REFUSED {str(ex)[:400]}", flush=True)
+            return
+        gb = 1e9
+        print(f"{name}: arguments {mem.argument_size_in_bytes / gb:.2f} GB, "
+              f"temporaries {mem.temp_size_in_bytes / gb:.2f} GB; arguments + "
+              f"temporaries {(mem.argument_size_in_bytes + mem.temp_size_in_bytes) / gb:.2f}"
+              f" GB; compiled in {time.monotonic() - t0:.0f}s", flush=True)
+
+    i32 = sd((B,), jnp.int32)
+    tables = sd((B, maxp), jnp.int32)
+    for k in args.decode:
+        report(f"sparse_moe_decode_multi n_steps={k}",
+               programs.sparse_moe_decode_multi.lower(
+                   params, None, i32, i32, i32, tables, *cache,
+                   sd((B,), jnp.bool_), sd((B,), jnp.float32), key, cfg=cfg,
+                   n_steps=k))
+    for spec in args.prefill:
+        n, tp = (int(x) for x in spec.split("x"))
+        pages = sd((n, tp // PS), jnp.int32)
+        report(f"sparse_moe_prefill_batch wave={n} pad={tp}",
+               programs.sparse_moe_prefill_batch.lower(
+                   params, None, sd((n,), jnp.int32), sd((n, tp), jnp.int32),
+                   pages, *cache, sd((n,), jnp.int32), sd((n,), jnp.float32),
+                   key, cfg=cfg))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -879,7 +879,7 @@ class ContinuousBatchingEngine:
         layers = sum(k.layers for k in self.kinds)
         for kind in self.kinds:
             reach = lens if kind.reach is None else np.minimum(lens, kind.reach)
-            within = int(reach.sum())
+            within = int(self._attended(reach).sum())
             if self._kv_in_place:  # whole pages, from the first within reach
                 ends = np.minimum(lens, self.MAXP * self.PS)
                 pages = -(-ends // self.PS) - (lens - reach) // self.PS
@@ -1304,3 +1304,13 @@ class ContinuousBatchingEngine:
                 drain()
                 carry = None
             await self._yield()
+
+    def _attended(self, reach):
+        """Of the positions within reach ([slots, steps]), how many a decode
+        step's attention attends: all of them, or at most what a family that
+        picks its keys says (``ServePrograms.attends_most``). Read by
+        ``_observe_kv_reads``; down here so that no line above moves (the
+        decode programs' compile-cache keys hold the loops' line numbers:
+        PERF.md section 7)."""
+        most = self.programs.attends_most
+        return reach if most is None else np.minimum(reach, most(self.cfg))

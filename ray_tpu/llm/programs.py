@@ -2,8 +2,9 @@
 owns slots, pages, admission and the loops and knows no model: it asks
 ``serving_programs(cfg)`` for the family's ``ServePrograms`` by the config's
 TYPE and threads the family's cache through unseen. A family's program
-module (``llm/llama.py``, ``llm/mla_moe.py``, ``llm/cohere2_moe.py``) imports
-this file, ``models/`` and ``ops/``, never the engine, and is imported when its config is served.
+module (``llm/llama.py``, ``llm/mla_moe.py``, ``llm/cohere2_moe.py``,
+``llm/sparse_moe.py``) imports this file, ``models/`` and ``ops/``, never the
+engine, and is imported when its config is served.
 A new family supplies a config type, its layer's halves in ``models/``, two
 jitted programs, a cache, and one branch of ``serving_programs``.
 """
@@ -17,6 +18,7 @@ import jax.numpy as jnp
 from ray_tpu.models.cohere2_moe import Cohere2MoeConfig
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.models.mla_moe import MlaMoeConfig
+from ray_tpu.models.sparse_moe import SparseMoeConfig
 from ray_tpu.parallel.moe import expert_passes
 
 
@@ -27,8 +29,9 @@ class UnsupportedByModel(NotImplementedError):
     def __init__(self, feature: str, family: str):
         super().__init__(
             f"{feature} is not supported for the {family!r} model family: "
-            f"it assumes one K pool and one V pool of n_kv_heads x head_dim "
-            f"over every layer")
+            f"it is a program of the Llama family's (one K pool and one V "
+            f"pool over every layer, every cached position attended), and "
+            f"this family's programs have no such form")
         self.feature, self.family = feature, family
 
 
@@ -91,7 +94,10 @@ class ServePrograms:
     decode, ``pages`` in prefill, in the kinds' order); None is one kind
     for every layer. ``prefill_wave_limit = (prompts, tokens)``: the most
     one prefill program may hold, so a pad group is split; None splits
-    nothing.
+    nothing. ``attends_most(cfg) -> int``: the most positions a decode
+    step's attention ATTENDS of a slot however many it fetches (a model that
+    picks its keys: the read counters then say what was attended and what
+    was fetched for it); None attends everything within reach.
     ``lora(cfg, adapters, rank) -> (stack, name -> index)`` stacks named
     adapters. It and the rest are the Llama family's and None elsewhere: the
     engine refuses what needs them."""
@@ -103,6 +109,7 @@ class ServePrograms:
     decode_in_place: callable = None
     page_kinds: callable = None
     prefill_wave_limit: tuple | None = None
+    attends_most: callable = None
     prefill_suffix: callable = None
     decode_spec: callable = None
     decode_verify: callable = None
@@ -123,6 +130,10 @@ def serving_programs(cfg) -> ServePrograms:
         return PROGRAMS
     if isinstance(cfg, Cohere2MoeConfig):
         from ray_tpu.llm.cohere2_moe import PROGRAMS
+
+        return PROGRAMS
+    if isinstance(cfg, SparseMoeConfig):
+        from ray_tpu.llm.sparse_moe import PROGRAMS
 
         return PROGRAMS
     raise TypeError(f"no serving programs for a {type(cfg).__name__}")

@@ -1,0 +1,233 @@
+"""The serving programs of ``models/sparse_moe.py`` for the continuous-
+batching engine: same slots, blocks and loop as the other families, a cache
+of THREE pools on one kind of page and an attention that picks its keys.
+
+* **Three pools, one kind of page.** Keys and values ``[L, P, PS, KV, hd]``
+  as the Llama family's, and the indexer's keys beside them, packed two to a
+  128-lane row (``ops/paged_indexer.py``: ``[L, P, PS . dk / 128, 128]``). A
+  slot's one page table indexes all three, so the engine draws, frees and
+  counts pages as it does for every family and never learns of the third.
+* **Decode** scores every cached position of a slot with the indexer, picks
+  the ``topk`` largest (``ops/select.py``: exact, ties to the lower position)
+  and attends the picked rows of K and V. On a TPU the three are a kernel
+  each and both ends read the pools where they lie (``paged_index_scores``,
+  ``topk_prefix_mask``, ``paged_decode_attention``'s ``selected``); anywhere else, where the kernels would be interpreted, the
+  gathered table in the plain form — the kernels' reference and what the CPU
+  tests run (``_reads_in_place``, as the other families: decided by what the
+  code can see). **Walked, not gathered:** the attention fetches every live
+  page and masks the rows not picked. 2,048 picked rows x 2 pools x 32 slots
+  x 12 layers would be 1.6 M copies of 1 KB a step, and under seeded weights
+  the picks are scattered (at 9k positions 97 % of the 16-token pages hold
+  one), so skipping pages buys nothing; the walk is exact, and what it
+  fetches beyond the picks is on the record (``sparse_kv_fetched``).
+* **Prefill** is whole-prompt per pad bucket: the indexer scores a block of
+  ``q_chunk`` queries at a time against the prompt's keys, the selection
+  leaves one byte a (query, key) pair, and the blocked kernel
+  (``ops/prefill_attention.py``) attends each query's own picks — no float
+  ``[T, T]`` array. A wave holds at most ``WAVE_LIMIT`` prompts and tokens.
+* **The expert layer** routes over all experts (softmax, top-k) and computes
+  the held ones' part; with no shared expert, holders' parts add up to the
+  layer. ``MOE_STATS`` and three sums of the attention's own ride back with
+  the tokens: positions scored, rows in the selected sets, K/V positions
+  fetched.
+
+LoRA, int8 pools, speculative decoding, suffix prefill and page export are
+the Llama family's programs; ``llm/engine.py`` refuses them for this family
+by name.
+"""
+from __future__ import annotations
+
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.llm.programs import (
+    MOE_STATS, ServePrograms, _sample_tail, moe_load_stats)
+from ray_tpu.models.sparse_moe import (
+    SparseMoeConfig, attend_plain, indexer_scores, sparse_attn_out,
+    sparse_experts, sparse_index, sparse_logits, sparse_project,
+    sparse_rope_freqs, sparse_select)
+from ray_tpu.ops.basic import rms_norm
+from ray_tpu.ops.paged_attention import paged_decode_attention
+from ray_tpu.ops.paged_indexer import (
+    keys_per_row, pack_keys, paged_index_scores, unpack_keys)
+from ray_tpu.ops.prefill_attention import blocks_for, gqa_prefill_attention
+
+# the most prompts and tokens one prefill program may hold
+WAVE_LIMIT = (8, 16384)
+# a decode step's own sums, after MOE_STATS, each over layers and live slots
+SPARSE_STATS = ("sparse_scored", "sparse_attended", "sparse_kv_fetched")
+
+
+def make_pools(cfg: SparseMoeConfig, page_size: int, n_pages: int, kv_dtype):
+    """The model's cache: (K, V, the indexer's keys packed)."""
+    if kv_dtype not in (None, "native", "bf16"):
+        raise ValueError(f"unknown kv_dtype {kv_dtype!r}")
+    dtype = jnp.bfloat16 if kv_dtype == "bf16" else jnp.dtype(cfg.dtype)
+    per = keys_per_row(cfg.indexer_head_dim, page_size)
+    kv = (cfg.n_layers, n_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
+    return (jnp.zeros(kv, dtype), jnp.zeros(kv, dtype),
+            jnp.zeros((cfg.n_layers, n_pages, page_size // per,
+                       per * cfg.indexer_head_dim), dtype))
+
+
+def _reads_in_place() -> bool:
+    """Whether the programs score and attend through the Pallas kernels (on
+    a TPU) or in the plain form (anywhere else, where the kernels would be
+    interpreted): decided by what the code can see, no option."""
+    return jax.default_backend() == "tpu"
+
+
+def _decode_body(params, tokens, pos, tables, cache, active, temps, key,
+                 cfg: SparseMoeConfig):
+    """One decode step for every slot (masked where inactive). Returns
+    (next_tok [B], cache, stats)."""
+    kpool, vpool, ipool = cache
+    B, (MAXP, PS) = tokens.shape[0], (tables.shape[1], kpool.shape[2])
+    dk, rows = cfg.indexer_head_dim, ipool.shape[2]
+    freqs = sparse_rope_freqs(cfg)
+    positions = pos[:, None]
+    page = jnp.take_along_axis(tables, (pos // PS)[:, None], axis=1)[:, 0]
+    off = pos % PS
+    lane_group = jnp.arange(ipool.shape[3]) // dk
+    in_place = _reads_in_place()
+    lengths = jnp.where(active, pos + 1, 0)
+    loads = []
+    x = params["tok"]["embedding"][tokens][:, None, :]
+    for i in range(cfg.n_layers):
+        layer = params[f"layers_{i}"]
+        h = rms_norm(x, layer["attn_norm"]["scale"], cfg.rms_norm_eps)
+        q, k, v = sparse_project(layer, h, freqs, positions, cfg)
+        qi, ki, w = sparse_index(layer, h, freqs, positions, cfg)
+        kpool = kpool.at[i, page, off].set(k[:, 0].astype(kpool.dtype))
+        vpool = vpool.at[i, page, off].set(v[:, 0].astype(vpool.dtype))
+        # the key's half of its packed row; the row's other keys stay
+        row = jnp.where(
+            lane_group[None, :] == (off // rows)[:, None],
+            jnp.tile(ki[:, 0].astype(ipool.dtype), (1, ipool.shape[3] // dk)),
+            ipool[i, page, off % rows])
+        ipool = ipool.at[i, page, off % rows].set(row)
+        if in_place:
+            scores = paged_index_scores(qi[:, 0], w[:, 0], ipool, i, tables,
+                                        lengths)
+        else:
+            scores = indexer_scores(
+                qi, w, unpack_keys(ipool[i][tables], dk).astype(qi.dtype))[:, 0]
+        picked = sparse_select(scores[:, None], jnp.where(active, pos, -1)[
+            :, None], cfg, jnp.float32)[:, 0]  # [B, MAXP * PS] of 0 / 1
+        if in_place:
+            att = paged_decode_attention(
+                q[:, 0].astype(kpool.dtype), kpool, vpool, i, tables, lengths,
+                selected=picked).reshape(B, 1, -1).astype(x.dtype)
+        else:
+            att = attend_plain(
+                q, kpool[i][tables].reshape(B, MAXP * PS, *kpool.shape[3:]
+                                            ).astype(q.dtype),
+                vpool[i][tables].reshape(B, MAXP * PS, *vpool.shape[3:]
+                                         ).astype(q.dtype), picked[:, None] != 0)
+        x = x + sparse_attn_out(layer, att)
+        h = rms_norm(x, layer["ffn_norm"]["scale"], cfg.rms_norm_eps)
+        y, load = sparse_experts(layer, h, cfg, valid=active[:, None])
+        loads.append(load)
+        x = x + y
+    logits = sparse_logits(params, x[:, 0], cfg)
+    next_tok = _sample_tail(logits, temps, key)
+    fetched = (-(-lengths // PS) * PS).sum() if in_place else (
+        jnp.asarray(B * MAXP * PS))  # the gathered form: every slot's table
+    sparse = cfg.n_layers * jnp.stack([
+        lengths.sum(), jnp.minimum(lengths, cfg.topk).sum(), fetched])
+    return (jnp.where(active, next_tok, 0), (kpool, vpool, ipool),
+            jnp.concatenate([
+                moe_load_stats(loads, B * cfg.n_experts_per_tok),
+                sparse.astype(jnp.int32)]))
+
+
+@partial(jax.jit, static_argnames=("cfg", "n_steps"),
+         donate_argnums=(6, 7, 8))
+def sparse_moe_decode_multi(params, loras, aids, tokens, seq_lens, tables,
+                            kpool, vpool, ipool, active, temps, key,
+                            cfg: SparseMoeConfig, n_steps: int):
+    """``n_steps`` fused decode steps as one device program: the contract of
+    ``ServePrograms.decode_multi`` with three pools, rows of ``[B tokens |
+    MOE_STATS | SPARSE_STATS]``. ``loras``/``aids`` are the engine's (None /
+    zeros here: refused at construction)."""
+    def step(carry, k):
+        tok, pos, cache = carry
+        nxt, cache, stats = _decode_body(
+            params, tok, pos, tables, cache, active, temps,
+            jax.random.fold_in(key, k), cfg)
+        return (nxt, pos + 1, cache), jnp.concatenate([nxt, stats])
+
+    (tok, pos, cache), rows = jax.lax.scan(
+        step, (tokens, seq_lens, (kpool, vpool, ipool)), jnp.arange(n_steps))
+    return (rows, tok, pos, *cache)
+
+
+def _prefill_picks(qi, w, ki, cfg: SparseMoeConfig):
+    """Every query's selected set over its own prompt, one byte a pair:
+    [N, T, T] int8. Scored and selected ``q_chunk`` queries at a time, so the
+    float scores of one block are all that ever exist."""
+    N, T = ki.shape[:2]
+    idx = jnp.arange(T)
+    bq = cfg.q_chunk if T % cfg.q_chunk == 0 else T
+
+    def block(c):
+        q_pos = jnp.broadcast_to(c[2][None, :], (N, bq))
+        return sparse_select(indexer_scores(c[0], c[1], ki), q_pos, cfg)
+
+    def blocks(a):  # [N, T, ...] -> [T / bq, N, bq, ...]
+        return jnp.moveaxis(a.reshape(N, T // bq, bq, *a.shape[2:]), 1, 0)
+
+    picked = jax.lax.map(block, (blocks(qi), blocks(w), idx.reshape(-1, bq)))
+    return jnp.moveaxis(picked, 0, 1).reshape(N, T, T)
+
+
+@partial(jax.jit, static_argnames=("cfg",), donate_argnums=(5, 6, 7))
+def sparse_moe_prefill_batch(params, loras, aids, tokens, pages, kpool, vpool,
+                             ipool, true_lens, temps, key,
+                             cfg: SparseMoeConfig):
+    """Prefill a whole admission wave as one batched forward: the contract
+    of ``ServePrograms.prefill_batch``, writing all three pools. Returns
+    (first tokens [N], the three pools)."""
+    N, Tp = tokens.shape
+    PS = kpool.shape[2]
+    freqs = sparse_rope_freqs(cfg)
+    idx = jnp.arange(Tp)
+    positions = jnp.broadcast_to(idx[None, :], (N, Tp))
+    rows = pages[:, idx // PS]
+    offs = jnp.broadcast_to(idx % PS, (N, Tp))
+    valid = idx[None, :] < true_lens[:, None]  # padding is routed nowhere
+    blocked = _reads_in_place() and blocks_for(Tp) is not None
+    x = params["tok"]["embedding"][tokens]
+    for i in range(cfg.n_layers):
+        layer = params[f"layers_{i}"]
+        h = rms_norm(x, layer["attn_norm"]["scale"], cfg.rms_norm_eps)
+        q, k, v = sparse_project(layer, h, freqs, positions, cfg)
+        qi, ki, w = sparse_index(layer, h, freqs, positions, cfg)
+        kpool = kpool.at[i, rows, offs].set(k.astype(kpool.dtype))
+        vpool = vpool.at[i, rows, offs].set(v.astype(vpool.dtype))
+        ipool = ipool.at[i, pages].set(pack_keys(ki.astype(ipool.dtype), PS))
+        picked = _prefill_picks(qi, w, ki, cfg)
+        if blocked:
+            att = gqa_prefill_attention(
+                q.reshape(N, Tp, -1), k.reshape(N, Tp, -1),
+                v.reshape(N, Tp, -1), n_kv_heads=cfg.n_kv_heads, picked=picked)
+        else:
+            att = attend_plain(q, k, v, picked != 0)
+        x = x + sparse_attn_out(layer, att)
+        h = rms_norm(x, layer["ffn_norm"]["scale"], cfg.rms_norm_eps)
+        y, _ = sparse_experts(layer, h, cfg, valid=valid)
+        x = x + y
+    last_x = jnp.take_along_axis(
+        x, (true_lens - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
+    logits = sparse_logits(params, last_x, cfg)
+    return _sample_tail(logits, temps, key), kpool, vpool, ipool
+
+
+PROGRAMS = ServePrograms(
+    family="sparse_moe", make_cache=make_pools,
+    decode_multi=sparse_moe_decode_multi,
+    prefill_batch=sparse_moe_prefill_batch, stats=MOE_STATS + SPARSE_STATS,
+    decode_in_place=lambda cache: _reads_in_place(),
+    prefill_wave_limit=WAVE_LIMIT, attends_most=lambda cfg: cfg.topk)

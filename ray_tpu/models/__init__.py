@@ -1,11 +1,14 @@
 """Model zoo: the Llama family, the MLA + sparse-expert family, the window +
-full attention sparse-expert family, ResNet, MLP."""
+full attention sparse-expert family, the learned-sparse-attention expert
+family, ResNet, MLP."""
 
 from ray_tpu.models.cohere2_moe import (  # noqa: F401
     Cohere2MoeConfig, cohere2_moe_forward, cohere2_moe_init)
 from ray_tpu.models.llama import LlamaConfig, llama_forward, llama_init  # noqa: F401
 from ray_tpu.models.mla_moe import (  # noqa: F401
     MlaMoeConfig, mla_moe_forward, mla_moe_init)
+from ray_tpu.models.sparse_moe import (  # noqa: F401
+    SparseMoeConfig, sparse_moe_forward, sparse_moe_init)
 
 
 def init_fn(cfg):
@@ -16,4 +19,6 @@ def init_fn(cfg):
         return mla_moe_init
     if isinstance(cfg, Cohere2MoeConfig):
         return cohere2_moe_init
+    if isinstance(cfg, SparseMoeConfig):
+        return sparse_moe_init
     raise TypeError(f"no model for a {type(cfg).__name__}")

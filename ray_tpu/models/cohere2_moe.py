@@ -33,7 +33,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models.mla_moe import _dense, _experts
 from ray_tpu.ops.basic import layer_norm, rope_freqs, rope_pairs
-from ray_tpu.parallel.moe import moe_layer
+from ray_tpu.parallel.moe import moe_layer_chunked
 
 WINDOW, FULL = "sliding_attention", "full_attention"
 
@@ -191,30 +191,13 @@ def cohere2_reach(q_pos, k_pos, cfg: Cohere2MoeConfig, window: bool):
     return ok
 
 
-# tokens an expert layer takes at a time in a long prefill: the sorted
-# assignments ([tokens * k, D] and three [tokens * k, F] hidden arrays) are
-# its largest temporaries, and nothing couples one token's experts to another's
-_MOE_CHUNK = 2048
-
-
 def cohere2_experts(layer, h, cfg: Cohere2MoeConfig, valid=None):
     """The expert half on the normed ``h`` [B, T, D] -> (y [B, T, D], load
     [held experts])."""
-    B, T, D = h.shape
-    kw = dict(k=cfg.n_experts_per_tok, scale=1.0, norm=cfg.norm_topk_prob,
-              held=cfg.held, shared_scale=1.0 / cfg.n_shared_experts)
-    flat = h.reshape(B * T, D)
-    ok = None if valid is None else valid.reshape(B * T)
-    n = B * T
-    if n <= _MOE_CHUNK or n % _MOE_CHUNK:
-        y, load = moe_layer(flat, layer["moe"], valid=ok, **kw)
-        return y.reshape(B, T, D), load
-    chunks = n // _MOE_CHUNK
-    ok = jnp.ones((n,), bool) if ok is None else ok
-    y, load = jax.lax.map(
-        lambda c: moe_layer(c[0], layer["moe"], valid=c[1], **kw),
-        (flat.reshape(chunks, _MOE_CHUNK, D), ok.reshape(chunks, _MOE_CHUNK)))
-    return y.reshape(B, T, D), load.sum(axis=0)
+    return moe_layer_chunked(
+        h, layer["moe"], valid, k=cfg.n_experts_per_tok, scale=1.0,
+        norm=cfg.norm_topk_prob, held=cfg.held,
+        shared_scale=1.0 / cfg.n_shared_experts)
 
 
 def cohere2_logits(params, x, cfg: Cohere2MoeConfig):

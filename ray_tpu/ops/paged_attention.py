@@ -18,7 +18,11 @@ slot's length is contracted. Two callers, one walk:
   the kernel sees.
 
 Which of the two a call is shows in the arguments: the number of pools, the
-pool's rank (a 4-D pool is one KV head), the output's width.
+pool's rank (a 4-D pool is one KV head), the output's width. Two more forms of
+the K and V walk, an argument each: ``starts`` (a window's: the table a ring,
+the walk from the first page within reach) and ``selected`` (a learned sparse
+attention's: every live page walked, the rows the model did not pick masked;
+a block of pages that lie one after the other in the pool is one copy).
 
 One kernel invocation serves every slot: a work list of (slot, block) items,
 a block being ``n_pages`` pages (256 tokens; 512 of the latent pool), runs through two VMEM buffers — the
@@ -55,14 +59,36 @@ _NEG_BIG = -1e30
 # bandwidth with no arithmetic at all: PERF.md, PR 30)
 _BLOCK_TOKENS = 256
 _LATENT_BLOCK_TOKENS = 512
+# the selected walk's block (1 MB of K and V in flight at 4 KV heads: alone on
+# the chip at the cell's shapes 2,103 us a call against 2,192 at 256; PERF.md,
+# PR 33)
+_SELECTED_BLOCK_TOKENS = 512
+
+
+def block_run(tables_ref, b, i, n_pages: int, live):
+    """Whether block ``i`` (``n_pages`` table entries) of slot ``b`` is one
+    run of the pool — every page among the slot's ``live`` ones and each
+    right after the one before — and the run's first page. Scalar code of a
+    kernel: ``tables_ref`` is the scalar-prefetched [B, MAXP] table."""
+    last = tables_ref.shape[1] - 1
+    first = tables_ref[b, jnp.minimum(i * n_pages, last)]
+    run = (i + 1) * n_pages <= live
+    for j in range(1, n_pages):
+        run = jnp.logical_and(run, tables_ref[b, jnp.minimum(
+            i * n_pages + j, last)] == first + j)
+    return run, first
 
 
 def _kernel(layer_ref, tables_ref, lengths_ref, *refs,
-            sm_scale: float, n_pages: int, ring: bool = False):
-    # refs: [the starts, where the table is a ring,] the queries, the pools
-    # (HBM), the output, a VMEM buffer a pool, the semaphores
+            sm_scale: float, n_pages: int, ring: bool = False,
+            select: bool = False):
+    # refs: [the starts, where the table is a ring,] [the positions picked,
+    # where the model picks them,] the queries, the pools (HBM), the output,
+    # a VMEM buffer a pool, the semaphores
     if ring:
         starts_ref, *refs = refs
+    if select:
+        sel_ref, *refs = refs
     q_ref, *refs = refs
     n_pools = (len(refs) - 2) // 2
     pools, o_ref = refs[:n_pools], refs[n_pools]
@@ -111,13 +137,36 @@ def _kernel(layer_ref, tables_ref, lengths_ref, *refs,
                     sems.at[kv, buf])))
         return out
 
+    def transfer(b, i, buf, how: str):
+        """Start, or wait for, the copies of block ``i`` of slot ``b``. The
+        selected walk takes a block that is one run of the pool as ONE copy
+        a pool and skips the page-by-page code altogether: that code — a
+        table entry, a bound, a descriptor and a branch a page and pool — is
+        what the walk waits for at 16 KB pages, not the bytes (PERF.md, PR
+        33), and an allocator that draws from the front of a free list
+        hands a slot its pages in runs."""
+        def by_page():
+            for cond, cp in copies(b, i, buf):
+                pl.when(cond)(getattr(cp, how))
+
+        if not select:
+            return by_page()
+        run, first = block_run(tables_ref, b, i, n_pages, pages_of(b))
+
+        @pl.when(run)
+        def _():
+            for kv, (pool, dst) in enumerate(zip(pools, bufs)):
+                getattr(pltpu.make_async_copy(
+                    pool.at[(layer, pl.ds(first, n_pages), *whole_rows)],
+                    dst.at[buf], sems.at[kv, buf]), how)()
+
+        pl.when(jnp.logical_not(run))(by_page)
+
     def start(b, i, buf):
-        for cond, cp in copies(b, i, buf):
-            pl.when(cond)(cp.start)
+        transfer(b, i, buf, "start")
 
     def wait(b, i, buf):
-        for cond, cp in copies(b, i, buf):
-            pl.when(cond)(cp.wait)
+        transfer(b, i, buf, "wait")
 
     def next_slot(b):
         """The first slot at or after ``b`` that holds tokens, or B."""
@@ -184,6 +233,9 @@ def _kernel(layer_ref, tables_ref, lengths_ref, *refs,
             if ring:
                 ok = jnp.logical_and(
                     ok, i * (n_pages * PS) + row_tok >= reach_lo)
+            if select:  # [1, rows]: this slot's picks, a row as the block's
+                ok = jnp.logical_and(ok, sel_ref[pl.ds(b, 1), pl.ds(
+                    pl.multiple_of(i * rows, rows), rows)] > 0.5)
             s = jnp.where(ok, s, _NEG_BIG)
             m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
             p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
@@ -207,7 +259,8 @@ def _kernel(layer_ref, tables_ref, lengths_ref, *refs,
 
 
 def paged_decode_attention(q, kpool, vpool, layer, page_tables, lengths, *,
-                           starts=None, interpret: bool | None = None):
+                           starts=None, selected=None,
+                           interpret: bool | None = None):
     """Attention of one query row a slot over the slot's pages, in place.
 
     q: [B, H, hd]; kpool, vpool: [L, P, PS, KV, hd] (handed over whole; they
@@ -225,7 +278,14 @@ def paged_decode_attention(q, kpool, vpool, layer, page_tables, lengths, *,
     ``[p * PS, (p + 1) * PS)`` lies at entry ``p % MAXP``, so a table of
     ``window / PS + 1`` entries serves a sequence of any length. The walk
     begins at the page that holds ``starts``: nothing before it is fetched,
-    and of that one page the positions before ``starts`` are masked."""
+    and of that one page the positions before ``starts`` are masked.
+
+    ``selected`` [B, MAXP * PS] bool makes the call a learned sparse
+    attention's: a slot attends, of its ``lengths`` positions, those where
+    ``selected`` — the softmax runs over them alone. The walk still fetches
+    every live page (the picks of a scattered selection touch nearly all of
+    them) and masks the rows not picked; a block whose pages lie one after
+    the other in the pool is fetched as one copy a pool."""
     H, KV = q.shape[1], kpool.shape[3]
     if H % KV:
         raise ValueError(f"{H} query heads do not group over {KV} KV heads")
@@ -235,6 +295,10 @@ def paged_decode_attention(q, kpool, vpool, layer, page_tables, lengths, *,
     if starts is not None:
         return _paged_window_attention(
             q, kpool, vpool, layer, page_tables, lengths, starts,
+            interpret=bool(interpret))
+    if selected is not None:
+        return _paged_selected_attention(
+            q, kpool, vpool, layer, page_tables, lengths, selected,
             interpret=bool(interpret))
     return _paged_decode_attention(
         q, kpool, vpool, layer, page_tables, lengths,
@@ -264,6 +328,17 @@ def _paged_window_attention(q, kpool, vpool, layer, page_tables, lengths,
                        v_width=hd, sm_scale=1.0 / math.sqrt(hd),
                        block_tokens=_BLOCK_TOKENS, interpret=interpret,
                        starts=starts)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _paged_selected_attention(q, kpool, vpool, layer, page_tables, lengths,
+                              selected, *, interpret: bool):
+    """A jit of its own for the reason ``_paged_decode_attention`` is one."""
+    hd = q.shape[-1]
+    return _walk_pools(q, (kpool, vpool), layer, page_tables, lengths,
+                       v_width=hd, sm_scale=1.0 / math.sqrt(hd),
+                       block_tokens=_SELECTED_BLOCK_TOKENS,
+                       interpret=interpret, selected=selected)
 
 
 def paged_latent_attention(q, pool, layer, page_tables, lengths, *,
@@ -298,10 +373,14 @@ def _paged_latent_attention(q, pool, layer, page_tables, lengths, *,
 
 def _walk_pools(q, pools, layer, page_tables, lengths, *, v_width: int,
                 sm_scale: float, block_tokens: int, interpret: bool,
-                starts=None):
+                starts=None, selected=None):
     """The one ``pallas_call`` every entry makes: the pools stay where they
     are (``pl.ANY``), a VMEM buffer of two blocks a pool. ``starts`` is one
-    more scalar-prefetched array, and a ring table (``_kernel``)."""
+    more scalar-prefetched array, and a ring table (``_kernel``);
+    ``selected`` one more input in VMEM: float 0 / 1 a ROW of the blocks (a
+    position's pick repeated over its KV heads here, in XLA: a repeat that
+    interleaves lanes is no vector operation of the kernel's), whole blocks
+    a slot — 8 MB at 32 slots of 16,384 positions of 4 KV heads."""
     B, H, width = q.shape
     PS, page = pools[0].shape[2], pools[0].shape[2:]
     MAXP = page_tables.shape[1]
@@ -313,6 +392,13 @@ def _walk_pools(q, pools, layer, page_tables, lengths, *, v_width: int,
         kernel = functools.partial(_kernel, sm_scale=sm_scale,
                                    n_pages=n_pages, ring=True)
         prefetch = (starts.astype(jnp.int32),)
+    picks = ()
+    if selected is not None:
+        kernel = functools.partial(kernel, select=True)
+        n_blocks, KV = -(-MAXP // n_pages), math.prod(page[1:-1])
+        picks = (jnp.repeat(jnp.pad(selected.astype(jnp.float32), (
+            (0, 0), (0, n_blocks * n_pages * PS - selected.shape[1]))),
+            KV, axis=1),)
     # Rows whose width is not whole lane tiles (MLA's 576 = 4.5 x 128) lie in
     # HBM padded to whole ones, and Mosaic takes no slice of a tiled axis that
     # is not whole tiles, the whole axis included ("Slice shape along
@@ -333,19 +419,21 @@ def _walk_pools(q, pools, layer, page_tables, lengths, *, v_width: int,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3 + len(prefetch),
             grid=(1,),
-            in_specs=[pl.BlockSpec(q.shape, lambda i, *_: (0, 0, 0))]
+            in_specs=[pl.BlockSpec(p.shape, lambda i, *_: (0, 0)) for p in picks]
+            + [pl.BlockSpec(q.shape, lambda i, *_: (0, 0, 0))]
             + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
             out_specs=pl.BlockSpec(out.shape, lambda i, *_: (0, 0, 0)),
             scratch_shapes=[buf] * len(pools)
             + [pltpu.SemaphoreType.DMA((len(pools), 2))],
         ),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=("arbitrary",),
+            **({"vmem_limit_bytes": 64 * 1024 * 1024} if picks else {})),
         cost_estimate=pl.CostEstimate(
             flops=2 * B * H * MAXP * PS * (width + v_width),
             transcendentals=B * H * MAXP * PS,
             bytes_accessed=len(pools) * window),
         interpret=interpret,
     )(layer.reshape(1),
-      page_tables.astype(jnp.int32), lengths.astype(jnp.int32), *prefetch, q,
-      *pools)
+      page_tables.astype(jnp.int32), lengths.astype(jnp.int32), *prefetch,
+      *picks, q, *pools)

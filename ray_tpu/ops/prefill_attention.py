@@ -18,6 +18,11 @@ two things that kernel has not.
   the blocks the window has slid past nor the ones above the diagonal are
   fetched (their index repeats the last one needed, which copies nothing)
   or computed.
+* **A pick a query.** ``picked`` [N, T, T] int8 lets position i attend j only
+  where ``picked[n, i, j]`` (a learned sparse attention's selected set,
+  ``models/sparse_moe.py``): the mask comes a block beside the keys, one byte
+  a pair, and no float ``[T, T]`` array exists. Every causal block is still
+  visited: the picks of a scattered selection touch nearly all of them.
 """
 from __future__ import annotations
 
@@ -41,9 +46,11 @@ def _key_blocks(i, block_q: int, block_k: int, window: int | None):
     return first, (i * block_q + block_q - 1) // block_k
 
 
-def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
-            sm_scale: float, window: int | None, block_q: int, block_k: int,
-            G: int, hd: int):
+def _kernel(q_ref, k_ref, v_ref, *refs, sm_scale: float, window: int | None,
+            block_q: int, block_k: int, G: int, hd: int, picks: bool = False):
+    if picks:
+        picked_ref, *refs = refs
+    o_ref, m_scr, l_scr, acc_scr = refs
     i, j = pl.program_id(2), pl.program_id(3)
     first, last = _key_blocks(i, block_q, block_k, window)
     kj = first + j
@@ -64,6 +71,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         ok = rows >= cols
         if window is not None:
             ok = jnp.logical_and(ok, rows - cols < window)
+        if picks:
+            ok = jnp.logical_and(ok, picked_ref[0].astype(jnp.int32) != 0)
         for g in range(G):
             q = q_ref[0, :, g * hd:(g + 1) * hd]  # [block_q, hd]
             s = jax.lax.dot_general(
@@ -99,18 +108,23 @@ def blocks_for(T: int) -> tuple[int, int] | None:
 
 
 def gqa_prefill_attention(q, k, v, *, n_kv_heads: int, window: int | None = None,
-                          interpret: bool | None = None):
+                          picked=None, interpret: bool | None = None):
     """Causal attention of every position of a prompt over the prompt, the
     heads grouped, optionally within a window.
 
     q: [N, T, H * hd]; k, v: [N, T, KV * hd] with ``KV = n_kv_heads`` and hd
     a multiple of 128; query head h reads KV head ``h // (H // KV)``.
     ``window``: position i attends j where ``0 <= i - j < window`` (None:
-    every ``j <= i``). T must be whole blocks (``blocks_for``). Returns
-    [N, T, H * hd] in q's dtype. Compiled for the TPU, interpreted anywhere
-    else."""
+    every ``j <= i``). ``picked``: [N, T, T] int8 (or bool), position i
+    attends ``j <= i`` only where it is set. T must be whole blocks
+    (``blocks_for``). Returns [N, T, H * hd] in q's dtype. Compiled for the
+    TPU, interpreted anywhere else."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    if picked is not None:
+        return _gqa_picked_attention(q, k, v, picked.astype(jnp.int8),
+                                     n_kv_heads=int(n_kv_heads),
+                                     interpret=bool(interpret))
     return _gqa_prefill_attention(q, k, v, n_kv_heads=int(n_kv_heads),
                                   window=None if window is None else int(window),
                                   interpret=bool(interpret))
@@ -122,6 +136,19 @@ def _gqa_prefill_attention(q, k, v, *, n_kv_heads: int, window: int | None,
                            interpret: bool):
     """A jit of its own: the layers of a program are call sites of one
     traced and lowered kernel a kind (``ops/paged_attention.py``)."""
+    return _blocked(q, k, v, None, n_kv_heads, window, interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("n_kv_heads", "interpret"))
+def _gqa_picked_attention(q, k, v, picked, *, n_kv_heads: int,
+                          interpret: bool):
+    """A jit of its own for the reason ``_gqa_prefill_attention`` is one."""
+    return _blocked(q, k, v, picked, n_kv_heads, None, interpret)
+
+
+def _blocked(q, k, v, picked, n_kv_heads: int, window: int | None,
+             interpret: bool):
+    """The one ``pallas_call`` both entries make."""
     N, T, HD = q.shape
     KV = n_kv_heads
     hd = k.shape[-1] // KV
@@ -139,7 +166,8 @@ def _gqa_prefill_attention(q, k, v, *, n_kv_heads: int, window: int | None,
 
     kernel = functools.partial(
         _kernel, sm_scale=1.0 / math.sqrt(hd), window=window, block_q=bq,
-        block_k=bk, G=G, hd=hd)
+        block_k=bk, G=G, hd=hd, picks=picked is not None)
+    masks = () if picked is None else (picked,)
     pairs = T * (T + 1) // 2 if window is None or window >= T else (
         window * (window + 1) // 2 + (T - window) * window)
     return pl.pallas_call(
@@ -150,7 +178,8 @@ def _gqa_prefill_attention(q, k, v, *, n_kv_heads: int, window: int | None,
             pl.BlockSpec((1, bq, G * hd), lambda n, kv, i, j: (n, i, kv)),
             pl.BlockSpec((1, bk, hd), key_block),
             pl.BlockSpec((1, bk, hd), key_block),
-        ],
+        ] + [pl.BlockSpec((1, bq, bk), lambda n, kv, i, j: (
+            n, i, key_block(n, kv, i, j)[1])) for _ in masks],
         out_specs=pl.BlockSpec((1, bq, G * hd), lambda n, kv, i, j: (n, i, kv)),
         scratch_shapes=[
             pltpu.VMEM((G, bq, _LANES), jnp.float32),
@@ -167,5 +196,6 @@ def _gqa_prefill_attention(q, k, v, *, n_kv_heads: int, window: int | None,
             bytes_accessed=2 * q.size * q.dtype.itemsize
             + 2 * k.size * k.dtype.itemsize * (T // bq) * visits * bk // T),
         interpret=interpret,
-        name="gqa_prefill_attention",
-    )(q, k, v)
+        name="gqa_prefill_attention" if picked is None
+        else "gqa_picked_attention",
+    )(q, k, v, *masks)

@@ -1,4 +1,4 @@
-"""Mixture-of-experts layers: two routers, two regimes.
+"""Mixture-of-experts layers: three routers, two regimes.
 
 Absent from the reference (ref: SURVEY §2.3 — "no MoE expert parallel
 in-tree"; vLLM handles EP internally).
@@ -8,14 +8,16 @@ in-tree"; vLLM handles EP internally).
   dispatch/combine formulation — a capacity-bounded one-hot ``[T, E, C]``
   dispatch tensor, expert weights sharded on the ``ep`` mesh axis, and
   sharding propagation turning the einsums into all_to_all over ICI.
-* **Serving (``sigmoid_topk_route`` + ``routed_experts`` + ``moe_layer``,
-  reached from ``llm/mla_moe.py`` and ``llm/cohere2_moe.py``):** the
-  DeepSeek-V3 family's layer, and Cohere2's with no bias, no routed scale and
-  its shared experts averaged. Sigmoid
+* **Serving (``sigmoid_topk_route`` / ``softmax_topk_route`` +
+  ``routed_experts`` + ``moe_layer``, reached from ``llm/mla_moe.py``,
+  ``llm/cohere2_moe.py`` and ``llm/sparse_moe.py``):** the DeepSeek-V3
+  family's layer, Cohere2's with no bias, no routed scale and its shared
+  experts averaged, and the Qwen3-MoE shape's: a softmax over all experts,
+  the k most probable renormalised, no shared expert at all. Sigmoid
   scores, the k experts with the largest ``score + bias`` chosen and
   weighed by the score alone, no capacity (no token is ever dropped),
-  three-matrix SwiGLU experts and shared experts every token passes
-  through. The one-hot dispatch does not scale to 128 experts x 12k prefill
+  three-matrix SwiGLU experts and, where the model has them, shared experts
+  every token passes through. The one-hot dispatch does not scale to 128 experts x 12k prefill
   tokens, so the routed product is a grouped matmul over the assignments
   sorted by expert (``jax.lax.ragged_dot`` for prefill's many rows a group,
   ``ops/grouped_swiglu.py`` for a decode step's few). The layer is told which
@@ -180,22 +182,72 @@ def routed_experts(h, idx, w, experts, held: tuple[int, int], valid=None):
     return y.astype(h.dtype), load
 
 
+def softmax_topk_route(h, router_w, k: int, norm: bool = True):
+    """The Qwen3-MoE router: ``p = softmax(h . W)`` over ALL experts in
+    float32 (for the reason ``sigmoid_topk_route`` is), the ``k`` most
+    probable, renormalised to sum 1 where ``norm``. Equal probabilities go
+    to the lower expert index. h: [T, D]; router_w: [D, E]. Returns
+    (idx [T, k] int32, weights [T, k] float32)."""
+    p = jax.nn.softmax(jnp.matmul(
+        h.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST), axis=-1)
+    w, idx = jax.lax.top_k(p, k)
+    if norm:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), w
+
+
 def moe_layer(h, moe, *, k: int, scale: float, norm: bool = True,
-              held: tuple[int, int], valid=None, shared_scale: float = 1.0):
+              held: tuple[int, int], valid=None, shared_scale: float = 1.0,
+              softmax: bool = False):
     """One expert layer of the family on ``h`` [T, D] (already normed):
     the held routed experts' part plus the shared experts (one SwiGLU of
     the summed shared width, which every holder computes alike) times
     ``shared_scale`` — 1 where the shared experts are summed, 1 / their
-    number where they are averaged. A router without a ``bias`` chooses by
-    its scores. Returns (y [T, D], load [hi-lo])."""
+    number where they are averaged; a layer with no ``shared`` sub-tree has
+    none, and its holders' parts add up to the layer. A router without a
+    ``bias`` chooses by its scores; ``softmax`` is the model's router being
+    ``softmax_topk_route`` (it has no scale). Returns (y [T, D], load
+    [hi-lo])."""
     from ray_tpu.ops.basic import swiglu
 
-    idx, w = sigmoid_topk_route(h, moe["router"]["kernel"],
-                                moe["router"].get("bias"), k, scale, norm)
+    if softmax:
+        idx, w = softmax_topk_route(h, moe["router"]["kernel"], k, norm)
+    else:
+        idx, w = sigmoid_topk_route(h, moe["router"]["kernel"],
+                                    moe["router"].get("bias"), k, scale, norm)
     y, load = routed_experts(h, idx, w, moe["experts"], held, valid)
+    if "shared" not in moe:
+        return y, load
     sh = moe["shared"]
     shared = swiglu(h, sh["w_gate"]["kernel"], sh["w_up"]["kernel"],
                     sh["w_down"]["kernel"])
     if shared_scale != 1:
         shared = shared * jnp.asarray(shared_scale, shared.dtype)
     return y + shared, load
+
+
+# tokens an expert layer takes at a time in a long prefill: the sorted
+# assignments ([tokens * k, D] and three [tokens * k, F] hidden arrays) are
+# its largest temporaries, and nothing couples one token's experts to another's
+_MOE_CHUNK = 2048
+
+
+def moe_layer_chunked(h, moe, valid=None, **kw):
+    """``moe_layer`` on ``h`` [B, T, D] (already normed), ``_MOE_CHUNK``
+    tokens at a time where there are whole chunks of them (a long prefill).
+    ``valid``: [B, T] or None; ``kw``: ``moe_layer``'s. Returns (y [B, T, D],
+    load [held experts])."""
+    B, T, D = h.shape
+    flat = h.reshape(B * T, D)
+    ok = None if valid is None else valid.reshape(B * T)
+    n = B * T
+    if n <= _MOE_CHUNK or n % _MOE_CHUNK:
+        y, load = moe_layer(flat, moe, valid=ok, **kw)
+        return y.reshape(B, T, D), load
+    chunks = n // _MOE_CHUNK
+    ok = jnp.ones((n,), bool) if ok is None else ok
+    y, load = jax.lax.map(
+        lambda c: moe_layer(c[0], moe, valid=c[1], **kw),
+        (flat.reshape(chunks, _MOE_CHUNK, D), ok.reshape(chunks, _MOE_CHUNK)))
+    return y.reshape(B, T, D), load.sum(axis=0)

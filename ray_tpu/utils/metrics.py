@@ -294,6 +294,18 @@ LLM_MODEL_STATS = {
         "rt_llm_moe_expert_passes_total",
         "times an expert's matrices went through the MXU, a step a layer: "
         "once a touched expert, more where its rows took several chunks"),
+    # the learned sparse attention of llm/sparse_moe.py, each summed over
+    # layers, live slots and decode steps
+    "sparse_scored": Counter(
+        "rt_llm_sparse_positions_scored_total",
+        "cached positions the indexer scored for live decode slots"),
+    "sparse_attended": Counter(
+        "rt_llm_sparse_rows_attended_total",
+        "positions in the selected sets: min(length, topk) a slot a layer"),
+    "sparse_kv_fetched": Counter(
+        "rt_llm_sparse_kv_positions_fetched_total",
+        "K/V positions the attention fetched for them: whole pages walked, "
+        "or the rows gathered"),
 }
 serve_lane_seconds = Histogram(
     "rt_serve_lane_seconds",

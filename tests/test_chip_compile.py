@@ -370,3 +370,97 @@ def test_cohere2_moe_prefill_batch_compiles(one_chip, monkeypatch):
     # over the chunks, and no decode kernel
     assert len(re.findall(_RAGGED_DOT, text)) == 3 * cfg.n_layers
     assert not re.findall(_SWIGLU, text)
+
+
+# --------------------------------------- learned sparse attention over experts
+def _sparse_moe_args(one_chip, n_layers: int = 2):
+    """Keye-VL-2.0-30B-A3B's language model at its published widths (32 query
+    heads on 4 KV heads, a 16 x 64 indexer, 16 held experts of 768), two
+    layers, the cell's three pools cut to 2,049 pages."""
+    from ray_tpu.llm.sparse_moe import make_pools
+    from ray_tpu.models.sparse_moe import SparseMoeConfig, sparse_moe_init
+
+    cfg = SparseMoeConfig(vocab_size=18992, n_layers=n_layers,
+                          max_seq_len=16384, experts_held=(0, 16),
+                          vocab_held=(0, 18992))
+    params = one_chip(jax.eval_shape(
+        lambda: sparse_moe_init(jax.random.PRNGKey(0), cfg)))
+    cache = one_chip(jax.eval_shape(lambda: make_pools(cfg, 16, 2049, None)))
+    assert cache[2].shape == (n_layers, 2049, 8, 128)   # two keys a row
+    return cfg, params, cache, one_chip(_shape((2,), jnp.uint32))
+
+
+def test_sparse_moe_decode_multi_compiles(one_chip, monkeypatch):
+    """A layer scores its slots' indexer keys in the packed pool, selects in
+    XLA and attends K and V in place under the picks: two Mosaic calls of the
+    attention's a layer and the grouped SwiGLU's one, 8 query heads a KV
+    head, a table of 1,024 pages."""
+    from ray_tpu.llm.programs import MOE_STATS
+    from ray_tpu.llm.sparse_moe import SPARSE_STATS, sparse_moe_decode_multi
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    sparse_moe_decode_multi.clear_cache()
+    cfg, params, cache, key = _sparse_moe_args(one_chip)
+    B = 32
+    i32 = one_chip(_shape((B,), jnp.int32))
+    try:
+        lowered = sparse_moe_decode_multi.lower(
+            params, None, i32, i32, i32, one_chip(_shape((B, 1024), jnp.int32)),
+            *cache, one_chip(_shape((B,), jnp.bool_)),
+            one_chip(_shape((B,), jnp.float32)), key, cfg=cfg, n_steps=8)
+        compiled = lowered.compile()
+    finally:
+        sparse_moe_decode_multi.clear_cache()
+    assert lowered.out_info[0].shape == (
+        8, B + len(MOE_STATS) + len(SPARSE_STATS))
+    text = compiled.as_text()
+    assert len(re.findall(r"%_paged_selected_attention\S* = \S+ custom-call\(",
+                          text)) == cfg.n_layers
+    assert len(re.findall(r"%paged_index_scores\S* = \S+ custom-call\(",
+                          text)) == cfg.n_layers
+    # the selection between them is one kernel a layer too: no 32-pass loop
+    # of XLA operations (the scan over the steps is the program's one while)
+    assert len(re.findall(r"%topk_prefix_mask\S* = \S+ custom-call\(",
+                          text)) == cfg.n_layers
+    assert len(re.findall(r" while\(", text)) == 1
+    # 256 rows over 16 held experts: one grouped SwiGLU kernel a layer
+    assert len(re.findall(_SWIGLU, text)) == cfg.n_layers
+    assert not re.findall(_RAGGED_DOT, text)
+    # no table gathered out of a pool: neither K/V rows nor indexer keys
+    assert not re.findall(r"bf16\[32,(?:1024|16384),(?:16,)?4,128\]", text)
+    assert not re.findall(r"bf16\[32,(?:1024,8,128|16384,64)\]", text)
+    # the temporaries are a step's activations and its [32, 16384] scores,
+    # not a copy of a pool
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
+
+
+def test_sparse_moe_prefill_batch_compiles(one_chip, monkeypatch):
+    """The longest prompt of the cell as one program: a block of 512 queries
+    scored and selected at a time, one byte a (query, key) pair, the picked
+    kernel a layer — no float [T, T] array (14,336 squared in float32 would be
+    822 MB a layer, the indexer's 16 heads of it 13 GB)."""
+    from ray_tpu.llm.sparse_moe import sparse_moe_prefill_batch
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    sparse_moe_prefill_batch.clear_cache()
+    cfg, params, cache, key = _sparse_moe_args(one_chip)
+    N, Tp = 1, 14336
+    try:
+        compiled = sparse_moe_prefill_batch.lower(
+            params, None, one_chip(_shape((N,), jnp.int32)),
+            one_chip(_shape((N, Tp), jnp.int32)),
+            one_chip(_shape((N, Tp // 16), jnp.int32)), *cache,
+            one_chip(_shape((N,), jnp.int32)),
+            one_chip(_shape((N,), jnp.float32)), key, cfg=cfg).compile()
+    finally:
+        sparse_moe_prefill_batch.clear_cache()
+    text = compiled.as_text()
+    assert len(re.findall(r"%gqa_picked_attention\S* = \S+ custom-call\(",
+                          text)) == cfg.n_layers
+    assert not re.findall(r"(?:f32|bf16)\[(?:\d+,)*14336,14336\]", text)
+    assert re.findall(r"s8\[(?:1,)?14336,14336\]", text)   # the picks: a byte
+    assert len(re.findall(r"%topk_prefix_mask\S* = \S+ custom-call\(",
+                          text)) == cfg.n_layers
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.3e9
+    assert len(re.findall(_RAGGED_DOT, text)) == 3 * cfg.n_layers
+    assert not re.findall(_SWIGLU, text)
