@@ -20,6 +20,13 @@ of THREE pools on one kind of page and an attention that picks its keys.
   the picks are scattered (at 9k positions 97 % of the 16-token pages hold
   one), so skipping pages buys nothing; the walk is exact, and what it
   fetches beyond the picks is on the record (``sparse_kv_fetched``).
+  **Runs found once a program.** Both walks take a block of the table whose
+  pages lie one after the other in the pool as ONE copy. Which blocks those
+  are is the table's alone, and the table does not change inside a decode
+  program: ``_table_runs`` finds them before the scan over the steps, the
+  kernels scalar-prefetch them, and what is left to a step is whether the
+  block's pages hold tokens yet (``sparse_walk_blocks`` /
+  ``sparse_walk_run_blocks``: how often an allocator's tables let it be).
 * **Prefill** is whole-prompt per pad bucket: the indexer scores a block of
   ``q_chunk`` queries at a time against the prompt's keys, the selection
   leaves one byte a (query, key) pair, and the blocked kernel
@@ -27,9 +34,9 @@ of THREE pools on one kind of page and an attention that picks its keys.
   ``[T, T]`` array. A wave holds at most ``WAVE_LIMIT`` prompts and tokens.
 * **The expert layer** routes over all experts (softmax, top-k) and computes
   the held ones' part; with no shared expert, holders' parts add up to the
-  layer. ``MOE_STATS`` and three sums of the attention's own ride back with
+  layer. ``MOE_STATS`` and five sums of the attention's own ride back with
   the tokens: positions scored, rows in the selected sets, K/V positions
-  fetched.
+  fetched, blocks the two walks made and those of them that were one copy.
 
 LoRA, int8 pools, speculative decoding, suffix prefill and page export are
 the Llama family's programs; ``llm/engine.py`` refuses them for this family
@@ -49,15 +56,16 @@ from ray_tpu.models.sparse_moe import (
     sparse_experts, sparse_index, sparse_logits, sparse_project,
     sparse_rope_freqs, sparse_select)
 from ray_tpu.ops.basic import rms_norm
-from ray_tpu.ops.paged_attention import paged_decode_attention
+from ray_tpu.ops.paged_attention import paged_decode_attention, selected_runs
 from ray_tpu.ops.paged_indexer import (
-    keys_per_row, pack_keys, paged_index_scores, unpack_keys)
+    index_runs, keys_per_row, pack_keys, paged_index_scores, unpack_keys)
 from ray_tpu.ops.prefill_attention import blocks_for, gqa_prefill_attention
 
 # the most prompts and tokens one prefill program may hold
 WAVE_LIMIT = (8, 16384)
 # a decode step's own sums, after MOE_STATS, each over layers and live slots
-SPARSE_STATS = ("sparse_scored", "sparse_attended", "sparse_kv_fetched")
+SPARSE_STATS = ("sparse_scored", "sparse_attended", "sparse_kv_fetched",
+                "sparse_walk_blocks", "sparse_walk_run_blocks")
 
 
 def make_pools(cfg: SparseMoeConfig, page_size: int, n_pages: int, kv_dtype):
@@ -79,10 +87,28 @@ def _reads_in_place() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _table_runs(tables, page_size: int):
+    """The runs of a program's page tables, for the indexer's walk and the
+    selected one — (flags [B, blocks], pages a block) each: made ONCE a
+    program, before the scan over its steps. Nothing where the kernels do
+    not run."""
+    if not _reads_in_place():
+        return None
+    return index_runs(tables), selected_runs(tables, page_size)
+
+
+def _walk_blocks(runs, n_pages: int, pages_live):
+    """(blocks walked, blocks fetched as one copy) by one walk of slots
+    holding ``pages_live`` [B] pages: a block is one copy where the table
+    says run (bit 0 of ``runs`` [B, blocks]) and all its pages hold tokens."""
+    whole = jnp.arange(runs.shape[1])[None, :] < (pages_live // n_pages)[:, None]
+    return (-(-pages_live // n_pages)).sum(), (whole * (runs & 1)).sum()
+
+
 def _decode_body(params, tokens, pos, tables, cache, active, temps, key,
-                 cfg: SparseMoeConfig):
-    """One decode step for every slot (masked where inactive). Returns
-    (next_tok [B], cache, stats)."""
+                 cfg: SparseMoeConfig, runs):
+    """One decode step for every slot (masked where inactive); ``runs`` is
+    the tables' ``_table_runs``. Returns (next_tok [B], cache, stats)."""
     kpool, vpool, ipool = cache
     B, (MAXP, PS) = tokens.shape[0], (tables.shape[1], kpool.shape[2])
     dk, rows = cfg.indexer_head_dim, ipool.shape[2]
@@ -92,6 +118,8 @@ def _decode_body(params, tokens, pos, tables, cache, active, temps, key,
     off = pos % PS
     lane_group = jnp.arange(ipool.shape[3]) // dk
     in_place = _reads_in_place()
+    if in_place:
+        (index_flags, _), (selected_flags, _) = runs
     lengths = jnp.where(active, pos + 1, 0)
     loads = []
     x = params["tok"]["embedding"][tokens][:, None, :]
@@ -110,7 +138,7 @@ def _decode_body(params, tokens, pos, tables, cache, active, temps, key,
         ipool = ipool.at[i, page, off % rows].set(row)
         if in_place:
             scores = paged_index_scores(qi[:, 0], w[:, 0], ipool, i, tables,
-                                        lengths)
+                                        lengths, runs=index_flags)
         else:
             scores = indexer_scores(
                 qi, w, unpack_keys(ipool[i][tables], dk).astype(qi.dtype))[:, 0]
@@ -119,7 +147,8 @@ def _decode_body(params, tokens, pos, tables, cache, active, temps, key,
         if in_place:
             att = paged_decode_attention(
                 q[:, 0].astype(kpool.dtype), kpool, vpool, i, tables, lengths,
-                selected=picked).reshape(B, 1, -1).astype(x.dtype)
+                selected=picked, runs=selected_flags
+            ).reshape(B, 1, -1).astype(x.dtype)
         else:
             att = attend_plain(
                 q, kpool[i][tables].reshape(B, MAXP * PS, *kpool.shape[3:]
@@ -133,10 +162,15 @@ def _decode_body(params, tokens, pos, tables, cache, active, temps, key,
         x = x + y
     logits = sparse_logits(params, x[:, 0], cfg)
     next_tok = _sample_tail(logits, temps, key)
-    fetched = (-(-lengths // PS) * PS).sum() if in_place else (
-        jnp.asarray(B * MAXP * PS))  # the gathered form: every slot's table
+    pages_live = -(-lengths // PS)
+    if in_place:
+        fetched = (pages_live * PS).sum()
+        walked = [sum(n) for n in zip(*(
+            _walk_blocks(*r, pages_live) for r in runs))]
+    else:  # the gathered form: every slot's table, and no walk
+        fetched, walked = jnp.asarray(B * MAXP * PS), [jnp.asarray(0)] * 2
     sparse = cfg.n_layers * jnp.stack([
-        lengths.sum(), jnp.minimum(lengths, cfg.topk).sum(), fetched])
+        lengths.sum(), jnp.minimum(lengths, cfg.topk).sum(), fetched, *walked])
     return (jnp.where(active, next_tok, 0), (kpool, vpool, ipool),
             jnp.concatenate([
                 moe_load_stats(loads, B * cfg.n_experts_per_tok),
@@ -152,11 +186,13 @@ def sparse_moe_decode_multi(params, loras, aids, tokens, seq_lens, tables,
     ``ServePrograms.decode_multi`` with three pools, rows of ``[B tokens |
     MOE_STATS | SPARSE_STATS]``. ``loras``/``aids`` are the engine's (None /
     zeros here: refused at construction)."""
+    runs = _table_runs(tables, kpool.shape[2])
+
     def step(carry, k):
         tok, pos, cache = carry
         nxt, cache, stats = _decode_body(
             params, tok, pos, tables, cache, active, temps,
-            jax.random.fold_in(key, k), cfg)
+            jax.random.fold_in(key, k), cfg, runs)
         return (nxt, pos + 1, cache), jnp.concatenate([nxt, stats])
 
     (tok, pos, cache), rows = jax.lax.scan(

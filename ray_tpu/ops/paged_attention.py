@@ -22,7 +22,10 @@ pool's rank (a 4-D pool is one KV head), the output's width. Two more forms of
 the K and V walk, an argument each: ``starts`` (a window's: the table a ring,
 the walk from the first page within reach) and ``selected`` (a learned sparse
 attention's: every live page walked, the rows the model did not pick masked;
-a block of pages that lie one after the other in the pool is one copy).
+a block of pages that lie one after the other in the pool is one copy —
+which blocks those are is found from the table once a program,
+``table_runs`` — and the pages land as the rows they are, read a whole
+register a load).
 
 One kernel invocation serves every slot: a work list of (slot, block) items,
 a block being ``n_pages`` pages (256 tokens; 512 of the latent pool), runs through two VMEM buffers — the
@@ -65,30 +68,54 @@ _LATENT_BLOCK_TOKENS = 512
 _SELECTED_BLOCK_TOKENS = 512
 
 
-def block_run(tables_ref, b, i, n_pages: int, live):
-    """Whether block ``i`` (``n_pages`` table entries) of slot ``b`` is one
-    run of the pool — every page among the slot's ``live`` ones and each
-    right after the one before — and the run's first page. Scalar code of a
-    kernel: ``tables_ref`` is the scalar-prefetched [B, MAXP] table."""
-    last = tables_ref.shape[1] - 1
-    first = tables_ref[b, jnp.minimum(i * n_pages, last)]
-    run = (i + 1) * n_pages <= live
-    for j in range(1, n_pages):
-        run = jnp.logical_and(run, tables_ref[b, jnp.minimum(
-            i * n_pages + j, last)] == first + j)
-    return run, first
+def table_runs(page_tables, n_pages: int, sub: int | None = None):
+    """Which blocks of a page table are runs of the pool: int32 [B, blocks of
+    ``n_pages`` entries]. Bit 0: the block's entries lie one after the other
+    in the pool, so the block is ONE copy where all its pages hold tokens;
+    bit 1 + c: so do the ``sub`` entries of its sub-run c. Plain XLA over the
+    table alone, so a program makes it ONCE for all its steps and layers and
+    the kernels scalar-prefetch it: what is left to a kernel is the one
+    compare that moves with the step, whether the pages hold tokens yet."""
+    B, MAXP = page_tables.shape
+    sub = sub or n_pages
+    if n_pages % sub or n_pages // sub > 30:
+        raise ValueError(f"{n_pages} pages a block do not split into at most "
+                         f"30 sub-runs of {sub}")
+    n_blocks = -(-MAXP // n_pages)
+    # entries past the table break every run that reaches them
+    t = jnp.pad(page_tables.astype(jnp.int32),
+                ((0, 0), (0, n_blocks * n_pages - MAXP)), constant_values=-1)
+    follows = jnp.concatenate(
+        [jnp.zeros((B, 1), bool), t[:, 1:] == t[:, :-1] + 1], axis=1
+    ).reshape(B, n_blocks, n_pages // sub, sub)
+    inner = follows[..., 1:].all(-1)  # a sub-run's entries follow each other
+    whole = jnp.logical_and(inner.all(-1), follows[..., 1:, 0].all(-1))
+    bits = (inner.astype(jnp.int32) << (1 + jnp.arange(n_pages // sub))).sum(-1)
+    return bits + whole.astype(jnp.int32)
+
+
+def block_rows(buf, cur):
+    """Block ``cur`` of a VMEM buffer [blocks, rows, lanes] as a value. Rows
+    of 16 bits are read as the 32-bit words they lie in — two rows a word,
+    a whole vector register's worth a load — and taken apart in registers:
+    read as 16-bit rows Mosaic loads half a register a tile of 8 rows and
+    shuffles two of them into one for the MXU, 160 operations a block of 512
+    rows, which is what a walk then waits for (PERF.md, PR 34)."""
+    if buf.dtype.itemsize != 2 or buf.shape[1] % 16:
+        return buf[cur]
+    return pltpu.bitcast(buf.bitcast(jnp.uint32)[cur], buf.dtype)
 
 
 def _kernel(layer_ref, tables_ref, lengths_ref, *refs,
             sm_scale: float, n_pages: int, ring: bool = False,
             select: bool = False):
-    # refs: [the starts, where the table is a ring,] [the positions picked,
-    # where the model picks them,] the queries, the pools (HBM), the output,
-    # a VMEM buffer a pool, the semaphores
+    # refs: [the starts, where the table is a ring,] [the table's runs and the
+    # positions picked, where the model picks them,] the queries, the pools
+    # (HBM), the output, a VMEM buffer a pool, the semaphores
     if ring:
         starts_ref, *refs = refs
     if select:
-        sel_ref, *refs = refs
+        runs_ref, sel_ref, *refs = refs
     q_ref, *refs = refs
     n_pools = (len(refs) - 2) // 2
     pools, o_ref = refs[:n_pools], refs[n_pools]
@@ -99,7 +126,8 @@ def _kernel(layer_ref, tables_ref, lengths_ref, *refs,
     v_width = o_ref.shape[-1]
     MAXP = tables_ref.shape[1]
     G = H // KV
-    rows = n_pages * PS * KV  # rows of one block, token-major then KV head
+    page_rows = PS * KV
+    rows = n_pages * page_rows  # of one block, token-major then KV head
     layer = layer_ref[0]
 
     # a page with its rows' padding, where they have any (_walk_pools)
@@ -119,6 +147,20 @@ def _kernel(layer_ref, tables_ref, lengths_ref, *refs,
                 pl.cdiv(lengths_ref[b], PS) - first_page(b), MAXP)
         return jnp.minimum(pl.cdiv(lengths_ref[b], PS), MAXP)
 
+    def landing(kv: int, buf, j, page, n: int = 1):
+        """The copy of pages [page, page + n) of pool ``kv`` to entries [j,
+        j + n) of its buffer ``buf``. The selected walk's buffers hold the
+        rows and no page axis (``block_rows``), so there a page is the rows
+        it is on both sides."""
+        pool, dst = pools[kv], bufs[kv]
+        if select:
+            src = pool.reshape(pool.shape[0], pool.shape[1] * page_rows, width
+                               ).at[layer, pl.ds(page * page_rows, n * page_rows)]
+            to = dst.at[buf, pl.ds(j * page_rows, n * page_rows)]
+        else:
+            src, to = pool.at[(layer, page, *whole_rows)], dst.at[buf, j]
+        return pltpu.make_async_copy(src, to, sems.at[kv, buf])
+
     def copies(b, i, buf):
         """The page copies of block ``i`` of slot ``b`` into buffer ``buf``,
         each with whether the page holds tokens (dead pages are not
@@ -131,10 +173,8 @@ def _kernel(layer_ref, tables_ref, lengths_ref, *refs,
                 page = tables_ref[b, (first_page(b) + p) % MAXP]
             else:
                 page = tables_ref[b, jnp.minimum(p, MAXP - 1)]
-            for kv, (pool, dst) in enumerate(zip(pools, bufs)):
-                out.append((p < live, pltpu.make_async_copy(
-                    pool.at[(layer, page, *whole_rows)], dst.at[buf, j],
-                    sems.at[kv, buf])))
+            out += [(p < live, landing(kv, buf, j, page))
+                    for kv in range(n_pools)]
         return out
 
     def transfer(b, i, buf, how: str):
@@ -151,14 +191,16 @@ def _kernel(layer_ref, tables_ref, lengths_ref, *refs,
 
         if not select:
             return by_page()
-        run, first = block_run(tables_ref, b, i, n_pages, pages_of(b))
+        # found once a program (``table_runs``); the step's part is whether
+        # the block's pages all hold tokens at this length
+        run = jnp.logical_and(runs_ref[b, i] & 1 == 1,
+                              (i + 1) * n_pages <= pages_of(b))
 
         @pl.when(run)
         def _():
-            for kv, (pool, dst) in enumerate(zip(pools, bufs)):
-                getattr(pltpu.make_async_copy(
-                    pool.at[(layer, pl.ds(first, n_pages), *whole_rows)],
-                    dst.at[buf], sems.at[kv, buf]), how)()
+            first = tables_ref[b, i * n_pages]
+            for kv in range(n_pools):
+                getattr(landing(kv, buf, 0, first, n_pages), how)()
 
         pl.when(jnp.logical_not(run))(by_page)
 
@@ -216,14 +258,16 @@ def _kernel(layer_ref, tables_ref, lengths_ref, *refs,
                 start(nb, ni, 1 - buf)
 
             wait(b, i, buf)
-            k = bufs[0][buf].reshape(rows, lanes)
+            k = (block_rows(bufs[0], buf) if select
+                 else bufs[0][buf].reshape(rows, lanes))
             if lanes > width:
                 # the last lane tile came with the array's padding, which
                 # may hold anything: zeros there, to meet q's zeros
                 k = jnp.concatenate(
                     [k[:, :lo], jnp.where(pad_ok, k[:, lo:], 0)], axis=1)
             # one pool: the values are the leading lanes of the rows fetched
-            v = (bufs[1][buf].reshape(rows, lanes) if n_pools == 2
+            v = (block_rows(bufs[1], buf) if select
+                 else bufs[1][buf].reshape(rows, lanes) if n_pools == 2
                  else k[:, :v_width])
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
@@ -259,7 +303,7 @@ def _kernel(layer_ref, tables_ref, lengths_ref, *refs,
 
 
 def paged_decode_attention(q, kpool, vpool, layer, page_tables, lengths, *,
-                           starts=None, selected=None,
+                           starts=None, selected=None, runs=None,
                            interpret: bool | None = None):
     """Attention of one query row a slot over the slot's pages, in place.
 
@@ -285,7 +329,9 @@ def paged_decode_attention(q, kpool, vpool, layer, page_tables, lengths, *,
     ``selected`` — the softmax runs over them alone. The walk still fetches
     every live page (the picks of a scattered selection touch nearly all of
     them) and masks the rows not picked; a block whose pages lie one after
-    the other in the pool is fetched as one copy a pool."""
+    the other in the pool is fetched as one copy a pool. ``runs`` is the
+    table's ``selected_runs``, for a program that makes it once and calls
+    this a layer a step; made here where it is not given."""
     H, KV = q.shape[1], kpool.shape[3]
     if H % KV:
         raise ValueError(f"{H} query heads do not group over {KV} KV heads")
@@ -297,8 +343,10 @@ def paged_decode_attention(q, kpool, vpool, layer, page_tables, lengths, *,
             q, kpool, vpool, layer, page_tables, lengths, starts,
             interpret=bool(interpret))
     if selected is not None:
+        if runs is None:
+            runs, _ = selected_runs(page_tables, kpool.shape[2])
         return _paged_selected_attention(
-            q, kpool, vpool, layer, page_tables, lengths, selected,
+            q, kpool, vpool, layer, page_tables, lengths, selected, runs,
             interpret=bool(interpret))
     return _paged_decode_attention(
         q, kpool, vpool, layer, page_tables, lengths,
@@ -330,15 +378,23 @@ def _paged_window_attention(q, kpool, vpool, layer, page_tables, lengths,
                        starts=starts)
 
 
+def selected_runs(page_tables, page_size: int):
+    """``table_runs`` at the selected walk's block, [B, blocks] int32, and
+    the pages a block."""
+    n_pages = max(1, min(_SELECTED_BLOCK_TOKENS // page_size,
+                         page_tables.shape[1]))
+    return table_runs(page_tables, n_pages), n_pages
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _paged_selected_attention(q, kpool, vpool, layer, page_tables, lengths,
-                              selected, *, interpret: bool):
+                              selected, runs, *, interpret: bool):
     """A jit of its own for the reason ``_paged_decode_attention`` is one."""
     hd = q.shape[-1]
     return _walk_pools(q, (kpool, vpool), layer, page_tables, lengths,
                        v_width=hd, sm_scale=1.0 / math.sqrt(hd),
                        block_tokens=_SELECTED_BLOCK_TOKENS,
-                       interpret=interpret, selected=selected)
+                       interpret=interpret, selected=selected, runs=runs)
 
 
 def paged_latent_attention(q, pool, layer, page_tables, lengths, *,
@@ -373,11 +429,12 @@ def _paged_latent_attention(q, pool, layer, page_tables, lengths, *,
 
 def _walk_pools(q, pools, layer, page_tables, lengths, *, v_width: int,
                 sm_scale: float, block_tokens: int, interpret: bool,
-                starts=None, selected=None):
+                starts=None, selected=None, runs=None):
     """The one ``pallas_call`` every entry makes: the pools stay where they
     are (``pl.ANY``), a VMEM buffer of two blocks a pool. ``starts`` is one
     more scalar-prefetched array, and a ring table (``_kernel``);
-    ``selected`` one more input in VMEM: float 0 / 1 a ROW of the blocks (a
+    ``selected`` comes with the table's ``runs`` (scalar-prefetched too) and
+    is one more input in VMEM: float 0 / 1 a ROW of the blocks (a
     position's pick repeated over its KV heads here, in XLA: a repeat that
     interleaves lanes is no vector operation of the kernel's), whole blocks
     a slot — 8 MB at 32 slots of 16,384 positions of 4 KV heads."""
@@ -395,6 +452,7 @@ def _walk_pools(q, pools, layer, page_tables, lengths, *, v_width: int,
     picks = ()
     if selected is not None:
         kernel = functools.partial(kernel, select=True)
+        prefetch = (runs.astype(jnp.int32),)
         n_blocks, KV = -(-MAXP // n_pages), math.prod(page[1:-1])
         picks = (jnp.repeat(jnp.pad(selected.astype(jnp.float32), (
             (0, 0), (0, n_blocks * n_pages * PS - selected.shape[1]))),
@@ -409,7 +467,10 @@ def _walk_pools(q, pools, layer, page_tables, lengths, *, v_width: int,
     lanes = width if interpret else -(-width // 128) * 128
     if lanes > width:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, lanes - width)))
-    buf = pltpu.VMEM((2, n_pages, *page[:-1], lanes), pools[0].dtype)
+    # two blocks a pool; the selected walk's hold rows (``_kernel`` landing)
+    buf = pltpu.VMEM((2, n_pages * math.prod(page[:-1]), lanes)
+                     if picks else (2, n_pages, *page[:-1], lanes),
+                     pools[0].dtype)
     # at most, a pool: every slot's whole table
     window = B * MAXP * math.prod(page) * pools[0].dtype.itemsize
     out = jax.ShapeDtypeStruct((B, H, v_width), q.dtype)

@@ -306,6 +306,13 @@ LLM_MODEL_STATS = {
         "rt_llm_sparse_kv_positions_fetched_total",
         "K/V positions the attention fetched for them: whole pages walked, "
         "or the rows gathered"),
+    "sparse_walk_blocks": Counter(
+        "rt_llm_sparse_walk_blocks_total",
+        "blocks of pages the indexer's and the selected walk fetched"),
+    "sparse_walk_run_blocks": Counter(
+        "rt_llm_sparse_walk_run_blocks_total",
+        "those of them fetched as ONE copy: all pages hold tokens and lie "
+        "one after the other in the pool"),
 }
 serve_lane_seconds = Histogram(
     "rt_serve_lane_seconds",

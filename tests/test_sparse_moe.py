@@ -21,9 +21,11 @@ from ray_tpu.llm.engine import (ContinuousBatchingEngine, UnsupportedByModel,
                                 serving_programs)
 from ray_tpu.models.sparse_moe import (SparseMoeConfig, attend_plain,
                                        sparse_moe_forward, sparse_moe_init)
-from ray_tpu.ops.paged_attention import paged_decode_attention
-from ray_tpu.ops.paged_indexer import (pack_keys, paged_index_scores,
-                                       unpack_keys)
+from ray_tpu.ops import paged_attention, paged_indexer
+from ray_tpu.ops.paged_attention import (paged_decode_attention,
+                                         selected_runs, table_runs)
+from ray_tpu.ops.paged_indexer import (index_runs, pack_keys,
+                                       paged_index_scores, unpack_keys)
 from ray_tpu.ops.prefill_attention import gqa_prefill_attention
 from ray_tpu.ops.select import topk_mask
 from ray_tpu.parallel.moe import routed_experts, softmax_topk_route
@@ -322,20 +324,22 @@ def test_the_stats_columns_and_read_counters_against_a_hand_count():
     them; fetched (the gathered form off the TPU): every slot's whole table
     a step."""
     eng = _engine()
-    assert eng.programs.stats[-3:] == programs.SPARSE_STATS
+    assert eng.programs.stats[-5:] == programs.SPARSE_STATS
     before = metrics.stage_totals()
     _serve(eng, [(20, 13)])
     after = metrics.stage_totals()
 
     def grown(name):
-        return (after[name][""]["sum"]
-                - before.get(name, {}).get("", {"sum": 0})["sum"])
+        return _grown(before, after, name)
 
     L, steps = CFG.n_layers, 12
     assert grown("rt_llm_sparse_positions_scored_total") == L * sum(range(21, 33))
     assert grown("rt_llm_sparse_rows_attended_total") == L * steps * CFG.topk
     assert grown("rt_llm_sparse_kv_positions_fetched_total") == (
         L * steps * eng.B * eng.MAXP * PS)
+    # the gathered form walks no pool: no blocks, none of them one copy
+    assert grown("rt_llm_sparse_walk_blocks_total") == 0
+    assert grown("rt_llm_sparse_walk_run_blocks_total") == 0
     assert grown("rt_llm_moe_expert_slots_total") == L * steps * 8
     # what the engine reckons itself says the same: attended = selected rows
     assert grown("rt_llm_decode_kv_tokens_live_total") == steps * CFG.topk
@@ -346,34 +350,65 @@ def test_the_stats_columns_and_read_counters_against_a_hand_count():
 
 
 # ---------------------------------------------------------------- the kernels
-def _tables(rng, B, entries, pages, runs: bool = False):
+def _tables(rng, B, entries, pages, runs=False):
     """Page tables over distinct pages: scattered, or — as an allocator that
     draws from the front of a free list leaves them — runs of consecutive
-    pages, with a break in the middle of slot 1's second block."""
+    pages: True, one run a slot with a break in the middle of slot 1's
+    second block; a number, runs of that many pages in a shuffled order (7:
+    shorter than a sub-run of 8; 23: they end inside blocks and sub-runs)."""
     if not runs:
         return rng.permutation(np.arange(1, pages))[:B * entries].reshape(
             B, entries).astype(np.int32)
-    t = (1 + np.arange(B * entries)).reshape(B, entries).astype(np.int32)
-    t[1, entries // 2:] = t[1, entries // 2:][::-1]
-    return t
+    t = (1 + np.arange(B * entries)).astype(np.int32)
+    if runs is True:
+        t = t.reshape(B, entries)
+        t[1, entries // 2:] = t[1, entries // 2:][::-1]
+        return t
+    pieces = np.split(t, np.arange(runs, len(t), runs))
+    return np.concatenate([pieces[i] for i in rng.permutation(len(pieces))]
+                          ).reshape(B, entries)
 
 
-@pytest.mark.parametrize("runs", [False, True])
-def test_paged_selected_attention_matches_a_dense_masked_softmax_at_g8(runs):
+def test_table_runs_against_a_hand_count():
+    """Blocks of 4 entries, sub-runs of 2: bit 0 the block, bits 1 and 2 its
+    halves. A run that ends inside a block leaves the half before the break;
+    entries past the table break the last block."""
+    t = jnp.asarray([[5, 6, 7, 8, 9, 10, 3, 4, 11, 12],
+                     [1, 2, 4, 5, 0, 0, 0, 0, 20, 19]], jnp.int32)
+    assert table_runs(t, 4, 2).tolist() == [[7, 6, 2], [6, 0, 0]]
+    assert table_runs(t, 4).tolist() == [[3, 0, 0], [0, 0, 0]]
+    assert table_runs(t[:, :8], 8).tolist() == [[0], [0]]
+    assert table_runs(t[:1, :6], 6, 3).tolist() == [[7]]
+    runs, n_pages = index_runs(t)  # a table under a block: the table, whole
+    assert (runs.shape, n_pages) == ((2, 1), 10)
+    runs, n_pages = selected_runs(jnp.zeros((2, 150), jnp.int32), 8)
+    assert (runs.shape, n_pages) == ((2, 3), 64) and not runs.any()
+
+
+@pytest.mark.parametrize("runs,entries,lengths", [
+    (False, 37, [5, 290, 0, 131]), (True, 37, [5, 290, 0, 131]),
+    # three blocks of 64 pages, the last of 22: the largest table, an
+    # inactive slot between live ones, a last block partly live (3 tokens
+    # into the second, the whole first), exactly two blocks
+    (True, 150, [5, 1200, 0, 515, 1024]),
+    (23, 150, [5, 1200, 0, 515, 1024]), (7, 150, [700, 0, 1200])])
+def test_paged_selected_attention_matches_a_dense_masked_softmax_at_g8(
+        runs, entries, lengths):
     """The masked walk in the interpreter, 8 query heads a KV head: slots
     under a block, over one, an inactive one; picks scattered over the live
     pages; a table that is not whole blocks; pages scattered over the pool
     (a copy a page) and in runs (a block that is one run is ONE copy)."""
-    KV, G, hd, ps, entries = 2, 8, 128, 8, 37
+    KV, G, hd, ps = 2, 8, 128, 8
     H = KV * G
     rng = np.random.default_rng(0)
-    lengths = np.array([5, 290, 0, 131], np.int32)
+    lengths = np.array(lengths, np.int32)
     B = len(lengths)
+    pages = max(160, B * entries + 1)
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(ks[0], (B, H, hd), jnp.float32)
-    kpool = jax.random.normal(ks[1], (2, 160, ps, KV, hd), jnp.float32)
-    vpool = jax.random.normal(ks[2], (2, 160, ps, KV, hd), jnp.float32)
-    tables = jnp.asarray(_tables(rng, B, entries, 160, runs))
+    kpool = jax.random.normal(ks[1], (2, pages, ps, KV, hd), jnp.float32)
+    vpool = jax.random.normal(ks[2], (2, pages, ps, KV, hd), jnp.float32)
+    tables = jnp.asarray(_tables(rng, B, entries, pages, runs))
     picked = rng.random((B, entries * ps)) < 0.3
     picked[:, 0] = True
     got = paged_decode_attention(q, kpool, vpool, 1, tables,
@@ -385,7 +420,9 @@ def test_paged_selected_attention_matches_a_dense_masked_softmax_at_g8(runs):
         vpool[1][tables].reshape(B, -1, KV, hd), jnp.asarray(ok)[:, None])
     want = np.where(lengths[:, None] > 0, np.asarray(want)[:, 0], 0)
     assert float(np.abs(np.asarray(got).reshape(B, -1) - want).max()) < 2e-5
-    assert not np.asarray(got)[2].any()
+    assert not np.asarray(got)[lengths == 0].any()
+    if entries > 37:
+        return
     # every position picked is the kernel without a selection
     plain = paged_decode_attention(q, kpool, vpool, 1, tables,
                                    jnp.asarray(lengths), interpret=True)
@@ -395,15 +432,43 @@ def test_paged_selected_attention_matches_a_dense_masked_softmax_at_g8(runs):
     assert float(jnp.abs(plain - every).max()) < 1e-6
 
 
-@pytest.mark.parametrize("ps,dk,runs", [(16, 64, False), (16, 64, True),
-                                        (8, 16, False)])
-def test_paged_index_scores_match_the_plain_form(ps, dk, runs):
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_a_block_of_the_ring_reads_as_the_rows_it_holds(dtype):
+    """16-bit rows are read as the 32-bit words they lie in and taken apart
+    in registers (``block_rows``): the same rows in the same order."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    x = jax.random.normal(jax.random.PRNGKey(0), (3, 32, 128)).astype(dtype)
+
+    def kernel(x_ref, o_ref):
+        o_ref[...] = paged_attention.block_rows(x_ref, 1)
+
+    got = pl.pallas_call(kernel, out_shape=jax.ShapeDtypeStruct(
+        (32, 128), dtype), interpret=True)(x)
+    assert jnp.array_equal(got, x[1])
+
+
+@pytest.mark.parametrize("ps,dk,runs,entries,lengths", [
+    (16, 64, False, 70, [3, 0, 70 * 16, 64 * 16 + 1]),
+    (16, 64, True, 70, [3, 0, 70 * 16, 64 * 16 + 1]),
+    (8, 16, False, 70, [3, 0, 70 * 8, 64 * 8 + 1]),
+    # more blocks of 64 pages than buffers in the ring, the last of 10: the
+    # largest table, an inactive slot between live ones, last blocks partly
+    # live, a slot of exactly two blocks; runs that end inside blocks and
+    # sub-runs (23), runs shorter than a sub-run (7), one run a slot
+    (16, 64, 23, 394, [394 * 16, 0, 5000, 17, 2048]),
+    (16, 64, 7, 394, [0, 394 * 16, 0, 1031]),
+    (16, 64, True, 394, [4000, 394 * 16, 0, 2048])])
+def test_paged_index_scores_match_the_plain_form(ps, dk, runs, entries,
+                                                 lengths):
     """The indexer's scores out of the packed pool, in the interpreter, at
     the published packing (two keys a row) and the tiny one (eight), over
     scattered pages and over runs."""
     from ray_tpu.models.sparse_moe import indexer_scores
 
-    J, entries, pages, B = 4, 70, 300, 4
+    J, B = 4, len(lengths)
+    pages = max(300, B * entries + 1)
     rng = np.random.default_rng(ps)
     ks = jax.random.split(jax.random.PRNGKey(ps), 3)
     rows = jax.random.normal(ks[0], (2, pages * ps, dk), jnp.float32)
@@ -413,7 +478,7 @@ def test_paged_index_scores_match_the_plain_form(ps, dk, runs):
     qi = jax.random.normal(ks[1], (B, J, dk), jnp.float32)
     w = jax.random.normal(ks[2], (B, J), jnp.float32)
     tables = jnp.asarray(_tables(rng, B, entries, pages, runs))
-    lengths = jnp.asarray([3, 0, entries * ps, 64 * ps + 1], jnp.int32)
+    lengths = jnp.asarray(lengths, jnp.int32)
     got = paged_index_scores(qi, w, pool, 1, tables, lengths, interpret=True)
     keys = rows[1].reshape(pages, ps, dk)[tables].reshape(B, entries * ps, dk)
     want = indexer_scores(qi[:, None], w[:, None], keys)[:, 0]
@@ -438,13 +503,41 @@ def test_blocked_prefill_attention_with_picks_matches_the_plain_form():
     assert float(jnp.abs(got - want).max()) < 1e-5
 
 
-def test_engine_decode_through_the_kernels_matches_the_gathered_form(monkeypatch):
+def _grown(before, after, name):
+    return after[name][""]["sum"] - before.get(name, {}).get("", {"sum": 0})["sum"]
+
+
+@pytest.mark.parametrize("cases,small_blocks,fetched,blocks,run_blocks", [
+    # lengths 21..24 are 3 pages of 8, in 3 layers; a table of 12 pages is
+    # one block of either walk, never whole: 2 walks x 4 steps x 3 layers
+    ([(20, 5)], False, 3 * 4 * 24, 3 * 4 * 2, 0),
+    # blocks of 2 pages (sub-runs of 1) under both walks, two programs of 4
+    # steps: the first slot's lengths 14..21 cross a page and a block (16 |
+    # 17) inside the first program, the second's 22..29 a page inside it (24
+    # | 25) and a block inside the second (32 | 33). The free list hands
+    # both their pages in runs, so every block whose pages all hold tokens
+    # is ONE copy: pages 2 2 2 3 3 3 3 3 + 3 3 3 4 4 4 4 4 a step (50), blocks
+    # 1 1 1 2 2 2 2 2 + 2 2 2 2 2 2 2 2, whole 1 1 1 1 1 1 1 1 + 1 1 1 2 2
+    # 2 2 2 — of each walk, in 3 layers
+    ([(13, 9), (21, 9)], True, 3 * 8 * (21 + 29), 3 * 2 * (13 + 16),
+     3 * 2 * (8 + 13))])
+def test_engine_decode_through_the_kernels_matches_the_gathered_form(
+        monkeypatch, cases, small_blocks, fetched, blocks, run_blocks):
     """The chip's branch without a chip: both decode kernels interpreted
-    under the engine, against the gathered form's tokens and counters."""
-    cases = [(20, 5)]
+    under the engine, against the gathered form's tokens and counters. The
+    tables' runs are found once a program: they must stay right while the
+    lengths grow over pages and blocks inside it."""
     _, want = _serve(_engine(block_buckets=(4,)), cases)
     monkeypatch.setattr(programs, "_reads_in_place", lambda: True)
-    programs.sparse_moe_decode_multi.clear_cache()
+    if small_blocks:
+        monkeypatch.setattr(paged_indexer, "_BLOCK_PAGES", 2)
+        monkeypatch.setattr(paged_indexer, "_RUN_PAGES", 1)
+        monkeypatch.setattr(paged_attention, "_SELECTED_BLOCK_TOKENS", 2 * PS)
+    jits = (programs.sparse_moe_decode_multi,
+            paged_indexer._paged_index_scores,
+            paged_attention._paged_selected_attention)
+    for f in jits:
+        f.clear_cache()
     try:
         eng = _engine(block_buckets=(4,))
         assert eng._kv_in_place
@@ -452,12 +545,15 @@ def test_engine_decode_through_the_kernels_matches_the_gathered_form(monkeypatch
         _, got = _serve(eng, cases)
         after = metrics.stage_totals()
     finally:
-        programs.sparse_moe_decode_multi.clear_cache()
+        for f in jits:
+            f.clear_cache()
     assert got == want
-    name = "rt_llm_sparse_kv_positions_fetched_total"
-    fetched = after[name][""]["sum"] - before[name][""]["sum"]
-    # whole pages walked: lengths 21..24 are 3 pages of 8, in 3 layers
-    assert fetched == CFG.n_layers * 4 * 24
+    # whole pages walked; blocks of both walks, and those that were one copy
+    assert _grown(before, after,
+                  "rt_llm_sparse_kv_positions_fetched_total") == fetched
+    assert _grown(before, after, "rt_llm_sparse_walk_blocks_total") == blocks
+    assert _grown(before, after,
+                  "rt_llm_sparse_walk_run_blocks_total") == run_blocks
 
 
 # ---------------------------------------------------------------- refusals
