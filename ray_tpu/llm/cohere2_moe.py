@@ -46,6 +46,7 @@ from ray_tpu.models.cohere2_moe import (
 from ray_tpu.ops.basic import layer_norm
 from ray_tpu.ops.paged_attention import paged_decode_attention
 from ray_tpu.ops.prefill_attention import blocks_for, gqa_prefill_attention
+from ray_tpu.utils import tracing
 
 # the most prompts and tokens one prefill program may hold: eight waiting
 # 12,288-token prompts would otherwise be one 98k-token program
@@ -94,6 +95,7 @@ def _reads_in_place() -> bool:
     return jax.default_backend() == "tpu"
 
 
+@tracing.part("attention")
 def _attend_gathered(q, kpool, vpool, table, pos, cfg, window: bool):
     """The plain form of a decode step's attention: every entry of the
     slot's table gathered, each row masked by the position it holds. In a
@@ -136,21 +138,26 @@ def _decode_body(params, tokens, pos, tables, cache, active, temps, key,
     starts = jnp.maximum(lengths - cfg.sliding_window, 0)
     at = {False: 0, True: 0}  # the layer's place in its kind's pools
     loads = []
-    x = params["tok"]["embedding"][tokens][:, None, :]
+    with tracing.part("embed"):
+        x = params["tok"]["embedding"][tokens][:, None, :]
     for i in range(cfg.n_layers):
         layer, window = params[f"layers_{i}"], cfg.is_window(i)
         j, at[window] = at[window], at[window] + 1
-        h = layer_norm(x, layer["norm"]["scale"], cfg.layer_norm_eps)
-        q, k, v = cohere2_project(layer, h, cos, sin, positions, cfg, window)
+        with tracing.part("project"):
+            h = layer_norm(x, layer["norm"]["scale"], cfg.layer_norm_eps)
+            q, k, v = cohere2_project(layer, h, cos, sin, positions, cfg,
+                                      window)
         kp, vp = (kw, vw) if window else (kf, vf)
-        kp = kp.at[j, rows[window], off].set(k[:, 0].astype(kp.dtype))
-        vp = vp.at[j, rows[window], off].set(v[:, 0].astype(vp.dtype))
+        with tracing.part("kv_write"):
+            kp = kp.at[j, rows[window], off].set(k[:, 0].astype(kp.dtype))
+            vp = vp.at[j, rows[window], off].set(v[:, 0].astype(vp.dtype))
         table = t_win if window else t_full
         if in_place:
-            att = paged_decode_attention(
-                q[:, 0].astype(kp.dtype), kp, vp, j, table, lengths,
-                starts=starts if window else None)
-            att = att.reshape(B, 1, -1).astype(x.dtype)
+            with tracing.part("attention"):
+                att = paged_decode_attention(
+                    q[:, 0].astype(kp.dtype), kp, vp, j, table, lengths,
+                    starts=starts if window else None)
+                att = att.reshape(B, 1, -1).astype(x.dtype)
         else:
             att = _attend_gathered(q, kp[j], vp[j], table, pos, cfg, window)
         if window:
@@ -213,23 +220,28 @@ def cohere2_moe_prefill_batch(params, loras, aids, tokens, pages, kf, vf, kw,
     valid = idx[None, :] < true_lens[:, None]  # padding is routed nowhere
     blocked = _reads_in_place() and blocks_for(Tp) is not None
     at = {False: 0, True: 0}
-    x = params["tok"]["embedding"][tokens]
+    with tracing.part("embed"):
+        x = params["tok"]["embedding"][tokens]
     for i in range(cfg.n_layers):
         layer, window = params[f"layers_{i}"], cfg.is_window(i)
         j, at[window] = at[window], at[window] + 1
-        h = layer_norm(x, layer["norm"]["scale"], cfg.layer_norm_eps)
-        q, k, v = cohere2_project(layer, h, cos, sin, positions, cfg, window)
-        if window:
-            kw = kw.at[j, rows[True], offs].set(k.astype(kw.dtype))
-            vw = vw.at[j, rows[True], offs].set(v.astype(vw.dtype))
-        else:
-            kf = kf.at[j, rows[False], offs].set(k.astype(kf.dtype))
-            vf = vf.at[j, rows[False], offs].set(v.astype(vf.dtype))
+        with tracing.part("project"):
+            h = layer_norm(x, layer["norm"]["scale"], cfg.layer_norm_eps)
+            q, k, v = cohere2_project(layer, h, cos, sin, positions, cfg,
+                                      window)
+        with tracing.part("kv_write"):
+            if window:
+                kw = kw.at[j, rows[True], offs].set(k.astype(kw.dtype))
+                vw = vw.at[j, rows[True], offs].set(v.astype(vw.dtype))
+            else:
+                kf = kf.at[j, rows[False], offs].set(k.astype(kf.dtype))
+                vf = vf.at[j, rows[False], offs].set(v.astype(vf.dtype))
         if blocked:
-            att = gqa_prefill_attention(
-                q.reshape(N, Tp, -1), k.reshape(N, Tp, -1),
-                v.reshape(N, Tp, -1), n_kv_heads=cfg.n_kv_heads,
-                window=cfg.sliding_window if window else None)
+            with tracing.part("attention"):
+                att = gqa_prefill_attention(
+                    q.reshape(N, Tp, -1), k.reshape(N, Tp, -1),
+                    v.reshape(N, Tp, -1), n_kv_heads=cfg.n_kv_heads,
+                    window=cfg.sliding_window if window else None)
         else:
             mask = jnp.broadcast_to(
                 cohere2_reach(idx[:, None], idx[None, :], cfg, window),

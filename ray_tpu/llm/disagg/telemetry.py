@@ -164,10 +164,10 @@ def _span_sink():
 
 def publish_decode_signals(engine) -> None:
     """Drain one engine's per-block speculative log into the stage
-    windows and refresh the tokens-in-flight gauge — called by the decode
-    worker after each request and from ``headroom()`` probes, so the
-    scheduler's admission signal, Prometheus, the dashboard LLM panel
-    and the bench all read the SAME numbers."""
+    windows — called by the decode worker after each request and from
+    ``headroom()`` probes, so Prometheus, the dashboard LLM panel and the
+    bench all read the SAME numbers. (Tokens in flight, the scheduler's
+    admission signal, ride the ``headroom()`` reply itself.)"""
     st = engine.spec_stats(drain=True)
     for n_steps, emitted, proposed, accepted in st["blocks"]:
         record(TOKENS_PER_STEP, emitted * 1000 // max(1, n_steps))
@@ -180,7 +180,6 @@ def publish_decode_signals(engine) -> None:
             metrics.llm_spec_accepted_total.inc(accepted)
         count(spec_proposed=proposed, spec_accepted=accepted,
               spec_steps=n_steps, spec_tokens=emitted)
-    metrics.llm_decode_tokens_in_flight.set(engine.tokens_in_flight())
 
 
 def count(**deltas: int) -> None:

@@ -39,6 +39,7 @@ from __future__ import annotations
 
 import asyncio
 import collections
+import concurrent.futures
 import itertools
 import time
 from dataclasses import dataclass, field
@@ -92,6 +93,12 @@ class _Request:
 
 class EngineFull(Exception):
     """No free slot/pages and the waiting queue is at capacity."""
+
+
+# one thread reads the compiled programs' texts (``tracing.compiled_parts``):
+# one at a time, so that the loop never shares the interpreter with two
+_PARTS_READER = concurrent.futures.ThreadPoolExecutor(
+    1, thread_name_prefix="program-parts")
 
 
 class ContinuousBatchingEngine:
@@ -168,7 +175,9 @@ class ContinuousBatchingEngine:
         self._task = None
         self._rng = jax.random.PRNGKey(0)
         self.error: BaseException | None = None  # fatal loop failure
-        self._compiled: set = set()  # (program, shapes) seen by _call
+        # (program, shapes) seen by _call -> future of its
+        # tracing.compiled_parts
+        self._compiled: dict = {}
         # speculative decoding (README § Speculative decoding): greedy
         # requests draft spec_k tokens per step (on-device n-gram
         # matcher over spec_ngram-grams, or the spec_drafter hook) and
@@ -553,9 +562,13 @@ class ContinuousBatchingEngine:
         the first use lowers and compiles in a thread, and the call that
         follows finds the executable in jit's own cache. Lowering donates
         nothing: the pools stay readable (``export_pages``) meanwhile.
-        Steady state costs one set lookup and never suspends; the compile
+        Steady state costs one dict lookup and never suspends; the compile
         does, so it closes ``ph`` (a phase belongs to its thread), waits
-        under ``engine.compile`` and opens ``ph`` again for the call."""
+        under ``engine.compile`` and opens ``ph`` again for the call. What
+        the compiled text says of its instructions' layer parts
+        (``program_parts``) is read by ``_PARTS_READER`` while the program's
+        first run holds the device (0.1-0.6 s of Python a program at real
+        widths, which the loop does not wait for)."""
         # what can differ between two calls under one engine: an array's
         # shape (pad and wave buckets) and the static step counts
         key = (fn, *(a if isinstance(a, int) else getattr(a, "shape", None)
@@ -564,11 +577,21 @@ class ContinuousBatchingEngine:
             ph.__exit__(None, None, None)
             with tracing.phase("engine.compile", program=fn.__name__,
                                shape=str(key[1:])):
-                await asyncio.get_running_loop().run_in_executor(
+                compiled = await asyncio.get_running_loop().run_in_executor(
                     None, lambda: fn.lower(*args).compile())
-            self._compiled.add(key)
+            self._compiled[key] = _PARTS_READER.submit(
+                tracing.compiled_parts, compiled)
             ph.__enter__()
         return fn(*args)
+
+    def program_parts(self) -> dict:
+        """``{program: {"parts": {"<instruction>|<shape>": part}, "stale",
+        "variants", "seconds"}}`` of every program ``_call`` compiled, by
+        the name a profiler trace gives it (``jit_paged_decode_multi``):
+        what joins a trace's device events to ``tracing.PARTS``. Waits for
+        a table still being read (a program compiled this instant)."""
+        return tracing.merged_parts(
+            table.result() for table in list(self._compiled.values()))
 
     _WAVE_BUCKETS = (1, 2, 4, 8, 16)
 

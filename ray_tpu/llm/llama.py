@@ -26,8 +26,10 @@ from ray_tpu.models.llama import (
     LlamaConfig, llama_attn_out, llama_ffn, llama_project)
 from ray_tpu.ops.basic import rms_norm, rope_freqs
 from ray_tpu.ops.paged_attention import paged_decode_attention
+from ray_tpu.utils import tracing
 
 
+@tracing.part("attention")
 def _gqa_attn(q, k, v, mask):
     """Masked grouped-query attention. The H query heads are grouped over
     the KV key/value heads (query head h reads KV head h // G, G = H // KV,
@@ -60,6 +62,7 @@ def _kv_shape(pool):
     return (pool["q"] if isinstance(pool, dict) else pool).shape
 
 
+@tracing.part("kv_write")
 def _kv_write(pool, i, row, off, val):
     """Store new K/V rows; int8 pools ({"q": int8, "s": f32 scales})
     quantize symmetrically per (token, kv-head) — one scale per hd
@@ -78,6 +81,7 @@ def _kv_write(pool, i, row, off, val):
             "s": pool["s"].at[i, row, off].set(s.astype(jnp.float32))}
 
 
+@tracing.part("attention")
 def _kv_read(pool, i, page_tables, dtype):
     """Gather an attention window ``[B, MAXP * PS, KV, hd]``: every page of
     every slot's table, live or not — a slice of the layer's pool, the
@@ -166,7 +170,8 @@ def _decode_body(params, loras, aids, tokens, pos, page_tables,
     else:
         key_idx = jnp.arange(MAXP * PS)
         mask = key_idx[None, None, :] <= pos[:, None, None]
-    x = params["tok"]["embedding"][tokens][:, None, :]
+    with tracing.part("embed"):
+        x = params["tok"]["embedding"][tokens][:, None, :]
     for i in range(cfg.n_layers):
         layer = params[f"layers_{i}"]
         q, k, v = llama_project(layer, x, cos, sin, positions, cfg,
@@ -174,16 +179,17 @@ def _decode_body(params, loras, aids, tokens, pos, page_tables,
         kpool = _kv_write(kpool, i, row, off, k[:, 0])
         vpool = _kv_write(vpool, i, row, off, v[:, 0])
         if in_place:
-            att = paged_decode_attention(
-                q[:, 0], kpool, vpool, i, page_tables, lengths)
+            with tracing.part("attention"):
+                att = paged_decode_attention(
+                    q[:, 0], kpool, vpool, i, page_tables, lengths)
         else:
             kb = _kv_read(kpool, i, page_tables, k.dtype)
             vb = _kv_read(vpool, i, page_tables, v.dtype)
             att = _gqa_attn(q, kb, vb, mask)
         x = llama_ffn(layer, llama_attn_out(layer, x, att), fused=True)
-    x = rms_norm(x, params["norm"]["scale"])
-    logits = x[:, 0] @ params["lm_head"]["kernel"]
-
+    with tracing.part("head"):
+        x = rms_norm(x, params["norm"]["scale"])
+        logits = x[:, 0] @ params["lm_head"]["kernel"]
     next_tok = _sample_tail(logits, temps, key)
     return jnp.where(active, next_tok, 0), kpool, vpool
 
@@ -242,7 +248,8 @@ def paged_prefill_batch(params, loras, aids, tokens, pages, kpool, vpool,
     mask = idx[None, :, None] >= idx[None, None, :]  # causal
     rows = pages[:, idx // PS]  # [N, Tp] pool row per prompt position
     offs = jnp.broadcast_to(idx % PS, (N, Tp))
-    x = params["tok"]["embedding"][tokens]  # [N, Tp, D]
+    with tracing.part("embed"):
+        x = params["tok"]["embedding"][tokens]  # [N, Tp, D]
     for i in range(cfg.n_layers):
         layer = params[f"layers_{i}"]
         q, k, v = llama_project(layer, x, cos, sin, positions, cfg,
@@ -252,10 +259,11 @@ def paged_prefill_batch(params, loras, aids, tokens, pages, kpool, vpool,
         att = _gqa_attn(q, k, v, mask)  # prefill attends the FRESH k/v:
         # quantization only affects what later decode steps read back
         x = llama_ffn(layer, llama_attn_out(layer, x, att))
-    x = rms_norm(x, params["norm"]["scale"])
-    last = jnp.take_along_axis(
-        x, (true_lens - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    logits = last @ params["lm_head"]["kernel"]  # [N, V]
+    with tracing.part("head"):
+        x = rms_norm(x, params["norm"]["scale"])
+        last = jnp.take_along_axis(
+            x, (true_lens - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
+        logits = last @ params["lm_head"]["kernel"]  # [N, V]
     return _sample_tail(logits, temps, key), kpool, vpool
 
 
@@ -292,7 +300,8 @@ def paged_prefill_suffix(params, loras, aids, tokens, pages, kpool, vpool,
     # so causal masking is one compare; tail junk-page keys sit past
     # every real position and mask out
     mask = key_idx[None, None, :] <= positions[:, :, None]  # [N, Ts, W*PS]
-    x = params["tok"]["embedding"][tokens]
+    with tracing.part("embed"):
+        x = params["tok"]["embedding"][tokens]
     for i in range(cfg.n_layers):
         layer = params[f"layers_{i}"]
         q, k, v = llama_project(layer, x, cos, sin, positions, cfg,
@@ -303,10 +312,11 @@ def paged_prefill_suffix(params, loras, aids, tokens, pages, kpool, vpool,
         vb = _kv_read(vpool, i, pages, v.dtype)
         att = _gqa_attn(q, kb, vb, mask)
         x = llama_ffn(layer, llama_attn_out(layer, x, att))
-    x = rms_norm(x, params["norm"]["scale"])
-    last = jnp.take_along_axis(
-        x, (true_lens - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    logits = last @ params["lm_head"]["kernel"]
+    with tracing.part("head"):
+        x = rms_norm(x, params["norm"]["scale"])
+        last = jnp.take_along_axis(
+            x, (true_lens - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
+        logits = last @ params["lm_head"]["kernel"]
     return _sample_tail(logits, temps, key), kpool, vpool
 
 
@@ -369,7 +379,8 @@ def _spec_verify_body(params, loras, aids, inputs, positions, page_tables,
     offs = positions % PS
     key_idx = jnp.arange(MAXP * PS)
     mask = key_idx[None, None, :] <= positions[:, :, None]  # [B,T,MAXP*PS]
-    x = params["tok"]["embedding"][inputs]  # [B, T, D]
+    with tracing.part("embed"):
+        x = params["tok"]["embedding"][inputs]  # [B, T, D]
     for i in range(cfg.n_layers):
         layer = params[f"layers_{i}"]
         q, k, v = llama_project(layer, x, cos, sin, positions, cfg,
@@ -380,9 +391,10 @@ def _spec_verify_body(params, loras, aids, inputs, positions, page_tables,
         vb = _kv_read(vpool, i, page_tables, v.dtype)
         att = _gqa_attn(q, kb, vb, mask)
         x = llama_ffn(layer, llama_attn_out(layer, x, att), fused=True)
-    x = rms_norm(x, params["norm"]["scale"])
-    logits = x @ params["lm_head"]["kernel"]  # [B, T, V]
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    with tracing.part("head"):
+        x = rms_norm(x, params["norm"]["scale"])
+        logits = x @ params["lm_head"]["kernel"]  # [B, T, V]
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     return greedy, _sample_tail(logits[:, 0], temps, key), kpool, vpool
 
 

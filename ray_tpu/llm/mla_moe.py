@@ -40,6 +40,7 @@ from ray_tpu.models.mla_moe import (
     mla_expand, mla_moe_ffn, mla_project)
 from ray_tpu.ops.basic import rms_norm, rope_freqs
 from ray_tpu.ops.paged_attention import paged_latent_attention
+from ray_tpu.utils import tracing
 
 
 def make_latent_pool(cfg: MlaMoeConfig, page_size: int, n_pages: int,
@@ -83,27 +84,34 @@ def _decode_body(params, tokens, pos, page_tables, pool, active, temps, key,
     else:
         mask = jnp.arange(MAXP * PS)[None, None, :] <= pos[:, None, None]
     loads = []
-    x = params["tok"]["embedding"][tokens][:, None, :]
+    with tracing.part("embed"):
+        x = params["tok"]["embedding"][tokens][:, None, :]
     for i in range(cfg.n_layers):
         layer = params[f"layers_{i}"]
-        h = rms_norm(x, layer["attn_norm"]["scale"])
-        q, latent = mla_project(layer, h, cos, sin, positions, cfg)
-        pool = pool.at[i, row, off].set(latent[:, 0].astype(pool.dtype))
-        if in_place:
-            o_lat = paged_latent_attention(
-                mla_absorb(layer, q, cfg)[:, 0].astype(pool.dtype), pool, i,
-                page_tables, lengths, v_width=cfg.kv_lora_rank,
-                sm_scale=cfg.qk_head_dim ** -0.5)
-            att = mla_expand(layer, o_lat[:, None].astype(x.dtype), cfg)
-        else:
-            window = pool[i][page_tables].reshape(B, MAXP * PS, W).astype(x.dtype)
-            att = mla_attend_absorbed(layer, q, window, mask, cfg)
-        x = x + att @ layer["wo"]["kernel"]
+        with tracing.part("project"):
+            h = rms_norm(x, layer["attn_norm"]["scale"])
+            q, latent = mla_project(layer, h, cos, sin, positions, cfg)
+        with tracing.part("kv_write"):
+            pool = pool.at[i, row, off].set(latent[:, 0].astype(pool.dtype))
+        with tracing.part("attention"):
+            if in_place:
+                o_lat = paged_latent_attention(
+                    mla_absorb(layer, q, cfg)[:, 0].astype(pool.dtype), pool,
+                    i, page_tables, lengths, v_width=cfg.kv_lora_rank,
+                    sm_scale=cfg.qk_head_dim ** -0.5)
+                att = mla_expand(layer, o_lat[:, None].astype(x.dtype), cfg)
+            else:
+                window = pool[i][page_tables].reshape(
+                    B, MAXP * PS, W).astype(x.dtype)
+                att = mla_attend_absorbed(layer, q, window, mask, cfg)
+        with tracing.part("attn_out"):
+            x = x + att @ layer["wo"]["kernel"]
         x, load = mla_moe_ffn(layer, x, cfg, valid=active[:, None])
         if load is not None:
             loads.append(load)
-    x = rms_norm(x, params["norm"]["scale"])
-    logits = x[:, 0] @ params["lm_head"]["kernel"]
+    with tracing.part("head"):
+        x = rms_norm(x, params["norm"]["scale"])
+        logits = x[:, 0] @ params["lm_head"]["kernel"]
     next_tok = _sample_tail(logits, temps, key)
     return (jnp.where(active, next_tok, 0), pool,
             moe_load_stats(loads, B * cfg.n_experts_per_tok))
@@ -146,19 +154,24 @@ def mla_moe_prefill_batch(params, loras, aids, tokens, pages, pool,
     rows = pages[:, idx // PS]
     offs = jnp.broadcast_to(idx % PS, (N, Tp))
     valid = idx[None, :] < true_lens[:, None]  # padding is routed nowhere
-    x = params["tok"]["embedding"][tokens]
+    with tracing.part("embed"):
+        x = params["tok"]["embedding"][tokens]
     for i in range(cfg.n_layers):
         layer = params[f"layers_{i}"]
-        h = rms_norm(x, layer["attn_norm"]["scale"])
-        q, latent = mla_project(layer, h, cos, sin, positions, cfg)
-        pool = pool.at[i, rows, offs].set(latent.astype(pool.dtype))
-        x = x + mla_attend_expanded(layer, q, latent, mask, cfg
-                                    ) @ layer["wo"]["kernel"]
+        with tracing.part("project"):
+            h = rms_norm(x, layer["attn_norm"]["scale"])
+            q, latent = mla_project(layer, h, cos, sin, positions, cfg)
+        with tracing.part("kv_write"):
+            pool = pool.at[i, rows, offs].set(latent.astype(pool.dtype))
+        att = mla_attend_expanded(layer, q, latent, mask, cfg)
+        with tracing.part("attn_out"):
+            x = x + att @ layer["wo"]["kernel"]
         x, _ = mla_moe_ffn(layer, x, cfg, valid=valid)
-    x = rms_norm(x, params["norm"]["scale"])
-    last = jnp.take_along_axis(
-        x, (true_lens - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    logits = last @ params["lm_head"]["kernel"]
+    with tracing.part("head"):
+        x = rms_norm(x, params["norm"]["scale"])
+        last = jnp.take_along_axis(
+            x, (true_lens - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
+        logits = last @ params["lm_head"]["kernel"]
     return _sample_tail(logits, temps, key), pool
 
 

@@ -20,6 +20,7 @@ from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.models.mla_moe import MlaMoeConfig
 from ray_tpu.models.sparse_moe import SparseMoeConfig
 from ray_tpu.parallel.moe import expert_passes
+from ray_tpu.utils import tracing
 
 
 class UnsupportedByModel(NotImplementedError):
@@ -59,6 +60,7 @@ MOE_STATS = ("moe_assignments", "moe_experts_touched", "moe_max_load",
              "moe_expert_slots", "moe_passes")
 
 
+@tracing.part("router")
 def moe_load_stats(loads, rows: int):
     """A step's rows per held expert, one [held] array an expert layer, of
     ``rows`` assignments a layer -> the MOE_STATS sums."""
@@ -139,6 +141,7 @@ def serving_programs(cfg) -> ServePrograms:
     raise TypeError(f"no serving programs for a {type(cfg).__name__}")
 
 
+@tracing.part("sample")
 def _sample_tail(logits, temps, key):
     """The sampling tail every serving program ends in: greedy where a row's
     temperature is 0, a categorical draw elsewhere. logits: [N, V]; temps:

@@ -208,13 +208,24 @@ class LLMEngineServer:
         """Counters of this replica's engine. ``stages``: cumulative sum
         and count of every stage family of ``utils/metrics.py`` in this
         process — the engine loop's phases, a request's queue, prefill
-        and decode waits, the prefill counters, the lane's two legs."""
-        from ray_tpu.utils import metrics
+        and decode waits, the prefill counters, the lane's two legs. While
+        a ``jax.profiler`` trace is on, also ``program_parts``: whoever
+        takes the trace needs the table to read it by layer part, and
+        nobody else is sent it."""
+        from ray_tpu.utils import metrics, tracing
 
-        return {"steps": self.engine.steps, "tokens_out": self.engine.tokens_out,
-                "waiting": len(self.engine.waiting),
-                "free_pages": len(self.engine.free[0]),
-                "stages": metrics.stage_totals()}
+        out = {"steps": self.engine.steps, "tokens_out": self.engine.tokens_out,
+               "waiting": len(self.engine.waiting),
+               "free_pages": len(self.engine.free[0]),
+               "stages": metrics.stage_totals()}
+        if tracing.profiling():
+            out["program_parts"] = self.engine.program_parts()
+        return out
+
+    def program_parts(self) -> dict:
+        """The engine's ``program_parts``: an operator joins their own
+        profiler trace with it."""
+        return self.engine.program_parts()
 
     def device_report(self) -> dict:
         """The device this replica really serves from and its memory high

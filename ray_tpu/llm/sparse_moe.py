@@ -60,6 +60,7 @@ from ray_tpu.ops.paged_attention import paged_decode_attention, selected_runs
 from ray_tpu.ops.paged_indexer import (
     index_runs, keys_per_row, pack_keys, paged_index_scores, unpack_keys)
 from ray_tpu.ops.prefill_attention import blocks_for, gqa_prefill_attention
+from ray_tpu.utils import tracing
 
 # the most prompts and tokens one prefill program may hold
 WAVE_LIMIT = (8, 16384)
@@ -122,41 +123,49 @@ def _decode_body(params, tokens, pos, tables, cache, active, temps, key,
         (index_flags, _), (selected_flags, _) = runs
     lengths = jnp.where(active, pos + 1, 0)
     loads = []
-    x = params["tok"]["embedding"][tokens][:, None, :]
+    with tracing.part("embed"):
+        x = params["tok"]["embedding"][tokens][:, None, :]
     for i in range(cfg.n_layers):
         layer = params[f"layers_{i}"]
-        h = rms_norm(x, layer["attn_norm"]["scale"], cfg.rms_norm_eps)
-        q, k, v = sparse_project(layer, h, freqs, positions, cfg)
+        with tracing.part("project"):
+            h = rms_norm(x, layer["attn_norm"]["scale"], cfg.rms_norm_eps)
+            q, k, v = sparse_project(layer, h, freqs, positions, cfg)
         qi, ki, w = sparse_index(layer, h, freqs, positions, cfg)
-        kpool = kpool.at[i, page, off].set(k[:, 0].astype(kpool.dtype))
-        vpool = vpool.at[i, page, off].set(v[:, 0].astype(vpool.dtype))
-        # the key's half of its packed row; the row's other keys stay
-        row = jnp.where(
-            lane_group[None, :] == (off // rows)[:, None],
-            jnp.tile(ki[:, 0].astype(ipool.dtype), (1, ipool.shape[3] // dk)),
-            ipool[i, page, off % rows])
-        ipool = ipool.at[i, page, off % rows].set(row)
-        if in_place:
-            scores = paged_index_scores(qi[:, 0], w[:, 0], ipool, i, tables,
-                                        lengths, runs=index_flags)
-        else:
-            scores = indexer_scores(
-                qi, w, unpack_keys(ipool[i][tables], dk).astype(qi.dtype))[:, 0]
+        with tracing.part("kv_write"):
+            kpool = kpool.at[i, page, off].set(k[:, 0].astype(kpool.dtype))
+            vpool = vpool.at[i, page, off].set(v[:, 0].astype(vpool.dtype))
+            # the key's half of its packed row; the row's other keys stay
+            row = jnp.where(
+                lane_group[None, :] == (off // rows)[:, None],
+                jnp.tile(ki[:, 0].astype(ipool.dtype),
+                         (1, ipool.shape[3] // dk)),
+                ipool[i, page, off % rows])
+            ipool = ipool.at[i, page, off % rows].set(row)
+        with tracing.part("indexer"):
+            if in_place:
+                scores = paged_index_scores(qi[:, 0], w[:, 0], ipool, i,
+                                            tables, lengths, runs=index_flags)
+            else:
+                scores = indexer_scores(qi, w, unpack_keys(
+                    ipool[i][tables], dk).astype(qi.dtype))[:, 0]
         picked = sparse_select(scores[:, None], jnp.where(active, pos, -1)[
             :, None], cfg, jnp.float32)[:, 0]  # [B, MAXP * PS] of 0 / 1
-        if in_place:
-            att = paged_decode_attention(
-                q[:, 0].astype(kpool.dtype), kpool, vpool, i, tables, lengths,
-                selected=picked, runs=selected_flags
-            ).reshape(B, 1, -1).astype(x.dtype)
-        else:
-            att = attend_plain(
-                q, kpool[i][tables].reshape(B, MAXP * PS, *kpool.shape[3:]
-                                            ).astype(q.dtype),
-                vpool[i][tables].reshape(B, MAXP * PS, *vpool.shape[3:]
-                                         ).astype(q.dtype), picked[:, None] != 0)
+        with tracing.part("attention"):
+            if in_place:
+                att = paged_decode_attention(
+                    q[:, 0].astype(kpool.dtype), kpool, vpool, i, tables,
+                    lengths, selected=picked, runs=selected_flags
+                ).reshape(B, 1, -1).astype(x.dtype)
+            else:
+                att = attend_plain(
+                    q, kpool[i][tables].reshape(
+                        B, MAXP * PS, *kpool.shape[3:]).astype(q.dtype),
+                    vpool[i][tables].reshape(
+                        B, MAXP * PS, *vpool.shape[3:]).astype(q.dtype),
+                    picked[:, None] != 0)
         x = x + sparse_attn_out(layer, att)
-        h = rms_norm(x, layer["ffn_norm"]["scale"], cfg.rms_norm_eps)
+        with tracing.part("ffn"):
+            h = rms_norm(x, layer["ffn_norm"]["scale"], cfg.rms_norm_eps)
         y, load = sparse_experts(layer, h, cfg, valid=active[:, None])
         loads.append(load)
         x = x + y
@@ -200,6 +209,7 @@ def sparse_moe_decode_multi(params, loras, aids, tokens, seq_lens, tables,
     return (rows, tok, pos, *cache)
 
 
+@tracing.part("indexer")
 def _prefill_picks(qi, w, ki, cfg: SparseMoeConfig):
     """Every query's selected set over its own prompt, one byte a pair:
     [N, T, T] int8. Scored and selected ``q_chunk`` queries at a time, so the
@@ -235,24 +245,31 @@ def sparse_moe_prefill_batch(params, loras, aids, tokens, pages, kpool, vpool,
     offs = jnp.broadcast_to(idx % PS, (N, Tp))
     valid = idx[None, :] < true_lens[:, None]  # padding is routed nowhere
     blocked = _reads_in_place() and blocks_for(Tp) is not None
-    x = params["tok"]["embedding"][tokens]
+    with tracing.part("embed"):
+        x = params["tok"]["embedding"][tokens]
     for i in range(cfg.n_layers):
         layer = params[f"layers_{i}"]
-        h = rms_norm(x, layer["attn_norm"]["scale"], cfg.rms_norm_eps)
-        q, k, v = sparse_project(layer, h, freqs, positions, cfg)
+        with tracing.part("project"):
+            h = rms_norm(x, layer["attn_norm"]["scale"], cfg.rms_norm_eps)
+            q, k, v = sparse_project(layer, h, freqs, positions, cfg)
         qi, ki, w = sparse_index(layer, h, freqs, positions, cfg)
-        kpool = kpool.at[i, rows, offs].set(k.astype(kpool.dtype))
-        vpool = vpool.at[i, rows, offs].set(v.astype(vpool.dtype))
-        ipool = ipool.at[i, pages].set(pack_keys(ki.astype(ipool.dtype), PS))
+        with tracing.part("kv_write"):
+            kpool = kpool.at[i, rows, offs].set(k.astype(kpool.dtype))
+            vpool = vpool.at[i, rows, offs].set(v.astype(vpool.dtype))
+            ipool = ipool.at[i, pages].set(
+                pack_keys(ki.astype(ipool.dtype), PS))
         picked = _prefill_picks(qi, w, ki, cfg)
-        if blocked:
-            att = gqa_prefill_attention(
-                q.reshape(N, Tp, -1), k.reshape(N, Tp, -1),
-                v.reshape(N, Tp, -1), n_kv_heads=cfg.n_kv_heads, picked=picked)
-        else:
-            att = attend_plain(q, k, v, picked != 0)
+        with tracing.part("attention"):
+            if blocked:
+                att = gqa_prefill_attention(
+                    q.reshape(N, Tp, -1), k.reshape(N, Tp, -1),
+                    v.reshape(N, Tp, -1), n_kv_heads=cfg.n_kv_heads,
+                    picked=picked)
+            else:
+                att = attend_plain(q, k, v, picked != 0)
         x = x + sparse_attn_out(layer, att)
-        h = rms_norm(x, layer["ffn_norm"]["scale"], cfg.rms_norm_eps)
+        with tracing.part("ffn"):
+            h = rms_norm(x, layer["ffn_norm"]["scale"], cfg.rms_norm_eps)
         y, _ = sparse_experts(layer, h, cfg, valid=valid)
         x = x + y
     last_x = jnp.take_along_axis(

@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from ray_tpu.models.mla_moe import _dense, _experts
 from ray_tpu.ops.basic import layer_norm, rope_freqs, rope_pairs
 from ray_tpu.parallel.moe import moe_layer_chunked
+from ray_tpu.utils import tracing
 
 WINDOW, FULL = "sliding_attention", "full_attention"
 
@@ -147,6 +148,7 @@ def cohere2_rope_freqs(cfg: Cohere2MoeConfig):
     return rope_freqs(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
 
 
+@tracing.part("project")
 def cohere2_project(layer, h, cos, sin, positions, cfg: Cohere2MoeConfig,
                     window: bool):
     """The attention half's projections of the normed ``h`` [B, T, D]:
@@ -162,6 +164,7 @@ def cohere2_project(layer, h, cos, sin, positions, cfg: Cohere2MoeConfig,
     return q, k, v
 
 
+@tracing.part("attention")
 def cohere2_attend_plain(q, k, v, mask):
     """Masked grouped-query attention with the scores written out: the plain
     form (the no-cache forward, and the serving programs off the TPU). q:
@@ -177,6 +180,7 @@ def cohere2_attend_plain(q, k, v, mask):
     return jnp.einsum("bkgqs,bskd->bqkgd", w, v).reshape(B, Tq, H * d)
 
 
+@tracing.part("attn_out")
 def cohere2_attn_out(layer, att):
     """The attention half's output projection. att: [B, T, H * hd]."""
     return att @ layer["wo"]["kernel"]
@@ -191,6 +195,7 @@ def cohere2_reach(q_pos, k_pos, cfg: Cohere2MoeConfig, window: bool):
     return ok
 
 
+@tracing.part("experts")
 def cohere2_experts(layer, h, cfg: Cohere2MoeConfig, valid=None):
     """The expert half on the normed ``h`` [B, T, D] -> (y [B, T, D], load
     [held experts])."""
@@ -200,6 +205,7 @@ def cohere2_experts(layer, h, cfg: Cohere2MoeConfig, valid=None):
         shared_scale=1.0 / cfg.n_shared_experts)
 
 
+@tracing.part("head")
 def cohere2_logits(params, x, cfg: Cohere2MoeConfig):
     """The tied head over the held rows of the embedding. x: [..., D]."""
     x = layer_norm(x, params["norm"]["scale"], cfg.layer_norm_eps)

@@ -30,6 +30,7 @@ from jax.ad_checkpoint import checkpoint_name as _checkpoint_name
 
 from ray_tpu.ops.attention import attention
 from ray_tpu.ops.basic import rms_norm, rope, rope_freqs, swiglu
+from ray_tpu.utils import tracing
 
 
 @dataclasses.dataclass(frozen=True)
@@ -126,6 +127,7 @@ def _lora_delta(h, loras, name, aid):
     return jnp.einsum("btd,bdr->btr", h, a) @ b if a.ndim == 3 else (h @ a) @ b
 
 
+@tracing.part("project")
 def llama_project(layer, x, cos, sin, positions, cfg: LlamaConfig, *,
                   loras=None, aids=None, fused: bool = False):
     """The layer's first half on the residual ``x`` [B, T, D]: norm, q/k/v
@@ -145,7 +147,9 @@ def llama_project(layer, x, cos, sin, positions, cfg: LlamaConfig, *,
                   layer["wv"]["kernel"])
     if fused:
         nq, nkv = wq.shape[1], wk.shape[1]
-        qkv = h @ jnp.concatenate([wq, wk, wv], axis=1)
+        with tracing.part("weights_concat"):
+            wqkv = jnp.concatenate([wq, wk, wv], axis=1)
+        qkv = h @ wqkv
         q, k, v = qkv[..., :nq], qkv[..., nq:nq + nkv], qkv[..., nq + nkv:]
     else:
         q, k, v = h @ wq, h @ wk, h @ wv
@@ -162,6 +166,7 @@ def _rejoin(y, tp_axis):
     return y if tp_axis is None else jax.lax.psum(y, tp_axis)
 
 
+@tracing.part("attn_out")
 def llama_attn_out(layer, x, att, tp_axis: str | None = None):
     """The attended rows ``att`` [B, T, H, hd] (or [B, H, hd] for T = 1)
     through ``wo``, onto the residual ``x`` [B, T, D]."""
@@ -169,6 +174,7 @@ def llama_attn_out(layer, x, att, tp_axis: str | None = None):
     return x + _rejoin(att.reshape(B, T, -1) @ layer["wo"]["kernel"], tp_axis)
 
 
+@tracing.part("ffn")
 def llama_ffn(layer, x, *, fused: bool = False, tp_axis: str | None = None):
     """The layer's second half on the residual ``x``: norm, SwiGLU,
     residual. ``fused`` as in ``llama_project``: one matmul against
@@ -177,7 +183,9 @@ def llama_ffn(layer, x, *, fused: bool = False, tp_axis: str | None = None):
     w_gate, w_up, w_down = (layer["w_gate"]["kernel"], layer["w_up"]["kernel"],
                             layer["w_down"]["kernel"])
     if fused:
-        gu = h @ jnp.concatenate([w_gate, w_up], axis=1)
+        with tracing.part("weights_concat"):
+            w_gu = jnp.concatenate([w_gate, w_up], axis=1)
+        gu = h @ w_gu
         ff = gu.shape[-1] // 2
         y = (jax.nn.silu(gu[..., :ff]) * gu[..., ff:]) @ w_down
     else:

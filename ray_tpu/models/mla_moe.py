@@ -29,6 +29,7 @@ import jax.numpy as jnp
 
 from ray_tpu.ops.basic import rms_norm, rope, rope_freqs, swiglu
 from ray_tpu.parallel.moe import moe_layer
+from ray_tpu.utils import tracing
 
 
 @dataclasses.dataclass(frozen=True)
@@ -156,6 +157,7 @@ def mla_moe_init(key, cfg: MlaMoeConfig) -> dict:
 
 
 # ------------------------------------------------------------------ attention
+@tracing.part("project")
 def mla_project(layer, h, cos, sin, positions, cfg: MlaMoeConfig):
     """The two projections of a layer's attention input ``h`` [B, T, D]:
     queries ``[B, T, H, nope + rope]`` (rope part rotated) and the cache row
@@ -193,6 +195,7 @@ def _head_groups(B, H, Tq, Tk, limit: int = 1 << 29) -> int:
     return next(g for g in range(1, H + 1) if H % g == 0 and g >= min(want, H))
 
 
+@tracing.part("attention")
 def mla_attend_expanded(layer, q, latent, mask, cfg: MlaMoeConfig):
     """Attention with the cache rows expanded to per-head keys and values
     (``[k_nope, v] = c.Wkvb``): the form for many queries (prefill, the
@@ -230,6 +233,7 @@ def mla_attend_expanded(layer, q, latent, mask, cfg: MlaMoeConfig):
     return out.reshape(B, Tq, H * cfg.v_head_dim)
 
 
+@tracing.part("attention")
 def mla_absorb(layer, q, cfg: MlaMoeConfig):
     """The queries carried into the cache rows' space: ``q_nope`` through the
     K half of ``wkv_b`` per head, beside ``q_rope`` as it is. q: [B, Tq, H,
@@ -240,6 +244,7 @@ def mla_absorb(layer, q, cfg: MlaMoeConfig):
          q[..., n:]], axis=-1)
 
 
+@tracing.part("attention")
 def mla_attend_window(q_lat, latent, mask, cfg: MlaMoeConfig):
     """Absorbed queries against cache rows as they lie: scores over the whole
     row, the probabilities sum the rows' latent part. q_lat: [B, Tq, H, r +
@@ -250,6 +255,7 @@ def mla_attend_window(q_lat, latent, mask, cfg: MlaMoeConfig):
     return jnp.einsum("bhqk,bkr->bqhr", p, latent[..., :cfg.kv_lora_rank])
 
 
+@tracing.part("attention")
 def mla_expand(layer, o_lat, cfg: MlaMoeConfig):
     """The V half of ``wkv_b`` applied once to the summed latents: [B, Tq, H,
     r] -> [B, Tq, H * v]."""
@@ -274,6 +280,7 @@ def mla_attend_absorbed(layer, q, latent, mask, cfg: MlaMoeConfig):
 
 
 # ---------------------------------------------------------------- feed-forward
+@tracing.part("ffn")
 def mla_moe_ffn(layer, x, cfg: MlaMoeConfig, valid=None):
     """The layer's second half on the residual ``x`` [B, T, D]. Returns
     (x, load): ``load`` [held experts] is None for a dense layer."""

@@ -42,6 +42,7 @@ from ray_tpu.models.mla_moe import _dense, _experts
 from ray_tpu.ops.basic import layer_norm, rms_norm, rope, rope_freqs
 from ray_tpu.ops.select import topk_prefix_mask
 from ray_tpu.parallel.moe import moe_layer_chunked
+from ray_tpu.utils import tracing
 
 
 @dataclasses.dataclass(frozen=True)
@@ -146,6 +147,7 @@ def sparse_rope_freqs(cfg: SparseMoeConfig):
             rope_freqs(cfg.indexer_head_dim, cfg.max_seq_len, cfg.rope_theta))
 
 
+@tracing.part("project")
 def sparse_project(layer, h, freqs, positions, cfg: SparseMoeConfig):
     """The attention half's projections of the normed ``h`` [B, T, D]:
     q [B, T, H, hd], k and v [B, T, KV, hd]; q and k normed per head, then
@@ -160,6 +162,7 @@ def sparse_project(layer, h, freqs, positions, cfg: SparseMoeConfig):
     return rope(q, cos, sin, positions), rope(k, cos, sin, positions), v
 
 
+@tracing.part("indexer")
 def sparse_index(layer, h, freqs, positions, cfg: SparseMoeConfig):
     """The indexer's projections of the normed ``h`` [B, T, D]: its queries
     qI [B, T, J, dk], its one key kI [B, T, dk] (what the cache keeps) and
@@ -175,16 +178,17 @@ def sparse_index(layer, h, freqs, positions, cfg: SparseMoeConfig):
     return qi, ki, w
 
 
+@tracing.part("indexer")
 def indexer_scores(qi, w, ki):
     """``I[t, s] = sum_j w[t, j] . relu(qI[t, j] . kI[s])`` written out, in
     float32 from the inputs as they are: the plain form. qi: [B, Tq, J, dk];
     w: [B, Tq, J]; ki: [B, Tk, dk]. Returns [B, Tq, Tk] float32."""
-    with jax.named_scope("indexer_scores"):
-        s = jnp.einsum("bqjd,bsd->bqjs", qi, ki.astype(qi.dtype),
-                       preferred_element_type=jnp.float32)
-        return (jax.nn.relu(s) * w[..., None]).sum(axis=2)
+    s = jnp.einsum("bqjd,bsd->bqjs", qi, ki.astype(qi.dtype),
+                   preferred_element_type=jnp.float32)
+    return (jax.nn.relu(s) * w[..., None]).sum(axis=2)
 
 
+@tracing.part("select")
 def sparse_select(scores, q_pos, cfg: SparseMoeConfig, dtype=jnp.int8):
     """``S_t`` as a 0 / 1 mask of ``dtype``: of the key positions ``0 ..
     q_pos`` the ``topk`` with the largest score, ties to the lower position;
@@ -193,11 +197,13 @@ def sparse_select(scores, q_pos, cfg: SparseMoeConfig, dtype=jnp.int8):
     return topk_prefix_mask(scores, q_pos, cfg.topk, dtype)
 
 
+@tracing.part("attn_out")
 def sparse_attn_out(layer, att):
     """The attention half's output projection. att: [B, T, H * hd]."""
     return att @ layer["wo"]["kernel"]
 
 
+@tracing.part("experts")
 def sparse_experts(layer, h, cfg: SparseMoeConfig, valid=None):
     """The expert half on the normed ``h`` [B, T, D] -> (y [B, T, D], load
     [held experts])."""
@@ -206,6 +212,7 @@ def sparse_experts(layer, h, cfg: SparseMoeConfig, valid=None):
         norm=cfg.norm_topk_prob, held=cfg.held, softmax=True)
 
 
+@tracing.part("head")
 def sparse_logits(params, x, cfg: SparseMoeConfig):
     """The untied head over the held rows. x: [..., D]."""
     x = rms_norm(x, params["norm"]["scale"], cfg.rms_norm_eps)

@@ -30,6 +30,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.utils import tracing
+
 _INT_MIN = -(1 << 31)
 
 
@@ -44,26 +46,26 @@ def _ordered_bits(scores):
     return jax.lax.bitcast_convert_type(key, jnp.uint32) ^ jnp.uint32(1 << 31)
 
 
+@tracing.part("select")
 def topk_mask(scores, valid, k: int):
     """Which entries of the last axis are among the ``k`` largest of those
     where ``valid``: every valid one where there are at most ``k``. Equal
     scores go to the lower index. scores: [..., S] float; valid: [..., S]
     bool. Returns [..., S] bool with ``min(k, valid.sum(-1))`` set a row."""
-    with jax.named_scope("topk_mask"):
-        u = jnp.where(valid, _ordered_bits(scores), jnp.uint32(0))
+    u = jnp.where(valid, _ordered_bits(scores), jnp.uint32(0))
 
-        def bit(i, prefix):
-            # the largest x with at least k entries >= x, a bit at a time
-            cand = prefix | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
-            enough = (u >= cand[..., None]).sum(-1, dtype=jnp.int32) >= k
-            return jnp.where(enough, cand, prefix)
+    def bit(i, prefix):
+        # the largest x with at least k entries >= x, a bit at a time
+        cand = prefix | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        enough = (u >= cand[..., None]).sum(-1, dtype=jnp.int32) >= k
+        return jnp.where(enough, cand, prefix)
 
-        kth = jax.lax.fori_loop(0, 32, bit,
-                                jnp.zeros(u.shape[:-1], jnp.uint32))[..., None]
-        above, equal = u > kth, u == kth
-        left = k - above.sum(-1, keepdims=True, dtype=jnp.int32)
-        rank = jnp.cumsum(equal, axis=-1, dtype=jnp.int32)
-        return valid & (above | (equal & (rank <= left)))
+    kth = jax.lax.fori_loop(0, 32, bit,
+                            jnp.zeros(u.shape[:-1], jnp.uint32))[..., None]
+    above, equal = u > kth, u == kth
+    left = k - above.sum(-1, keepdims=True, dtype=jnp.int32)
+    rank = jnp.cumsum(equal, axis=-1, dtype=jnp.int32)
+    return valid & (above | (equal & (rank <= left)))
 
 
 def _selects_in_kernel(width: int) -> bool:

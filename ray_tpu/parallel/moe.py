@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops import grouped_swiglu
+from ray_tpu.utils import tracing
 
 
 def top1_gating(logits, n_experts: int, capacity: int):
@@ -93,6 +94,7 @@ def moe_ffn(x, gate_w, w_up, w_down, *, capacity_factor: float = 1.25,
 
 
 # ------------------------------------------------------------------ serving
+@tracing.part("router")
 def sigmoid_topk_route(h, router_w, bias, k: int, scale: float,
                        norm: bool = True):
     """``noaux_tc`` routing with one group: ``s = sigmoid(h . W)`` in
@@ -144,6 +146,7 @@ def expert_passes(load, rows: int):
     return (load > 0).sum()
 
 
+@tracing.part("experts")
 def routed_experts(h, idx, w, experts, held: tuple[int, int], valid=None):
     """The held experts' part of ``sum_e w_e . swiglu_e(h)``, with no
     capacity: the ``T * k`` assignments are sorted by expert, each expert's
@@ -160,12 +163,13 @@ def routed_experts(h, idx, w, experts, held: tuple[int, int], valid=None):
     T, k = idx.shape
     lo, hi = held
     n = hi - lo
-    keep = (idx >= lo) & (idx < hi)
-    if valid is not None:
-        keep &= valid[:, None]
-    group = jnp.where(keep, idx - lo, n).reshape(-1)     # n = "nobody here"
-    order = jnp.argsort(group)                           # stable
-    load = jnp.bincount(group, length=n + 1)[:n].astype(jnp.int32)
+    with tracing.part("router"):
+        keep = (idx >= lo) & (idx < hi)
+        if valid is not None:
+            keep &= valid[:, None]
+        group = jnp.where(keep, idx - lo, n).reshape(-1)  # n = "nobody here"
+        order = jnp.argsort(group)                        # stable
+        load = jnp.bincount(group, length=n + 1)[:n].astype(jnp.int32)
     xs = h[order // k]                                   # [T * k, D]
     if _streams_experts(T * k):
         ys = grouped_swiglu.grouped_swiglu(
@@ -182,6 +186,7 @@ def routed_experts(h, idx, w, experts, held: tuple[int, int], valid=None):
     return y.astype(h.dtype), load
 
 
+@tracing.part("router")
 def softmax_topk_route(h, router_w, k: int, norm: bool = True):
     """The Qwen3-MoE router: ``p = softmax(h . W)`` over ALL experts in
     float32 (for the reason ``sigmoid_topk_route`` is), the ``k`` most
@@ -220,11 +225,12 @@ def moe_layer(h, moe, *, k: int, scale: float, norm: bool = True,
     if "shared" not in moe:
         return y, load
     sh = moe["shared"]
-    shared = swiglu(h, sh["w_gate"]["kernel"], sh["w_up"]["kernel"],
-                    sh["w_down"]["kernel"])
-    if shared_scale != 1:
-        shared = shared * jnp.asarray(shared_scale, shared.dtype)
-    return y + shared, load
+    with tracing.part("ffn"):
+        shared = swiglu(h, sh["w_gate"]["kernel"], sh["w_up"]["kernel"],
+                        sh["w_down"]["kernel"])
+        if shared_scale != 1:
+            shared = shared * jnp.asarray(shared_scale, shared.dtype)
+        return y + shared, load
 
 
 # tokens an expert layer takes at a time in a long prefill: the sorted

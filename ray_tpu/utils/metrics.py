@@ -183,12 +183,9 @@ task_stage_us = Gauge(
     "fast-lane per-stage latency percentiles over the recorder window (µs)",
     tag_keys=("stage", "q"))
 # --- LLM decode-plane signals (llm/disagg/telemetry.py) ---------------------
-# Published per decode-worker process; the disagg scheduler and serve
-# router admit on tokens-in-flight + page headroom instead of request
-# counts (cross-replica decode batching).
-llm_decode_tokens_in_flight = Gauge(
-    "rt_llm_decode_tokens_in_flight",
-    "decode tokens still owed by this process's LLM engine")
+# Published per decode-worker process. (Tokens in flight, which the disagg
+# scheduler and the serve router admit on, travel in the workers' own
+# ``headroom()`` / ``signals()`` replies, not through the registry.)
 # monotonic spec-decode cumulatives: the rollup plane's derived
 # llm_spec_accept_rate series is accepted/proposed per window slot —
 # restart-safe and windowable (tokens per step and the acceptance rate

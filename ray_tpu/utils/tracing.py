@@ -18,6 +18,13 @@ summed into one registry histogram and, while a ``jax.profiler`` trace
 is on, mirrored as a ``TraceAnnotation`` so that it lands in the same
 ``.xplane.pb`` — and on the same clock — as the device's own lines.
 
+Inside the jitted programs stands :func:`part`: the one vocabulary of named
+scopes (``PARTS``) by which a serve program says which part of a layer each
+of its instructions belongs to. The profiler drops a scope from the device's
+events, but the compiled program keeps it (``op_name`` in its text), so
+:func:`instruction_parts` reduces that text to a table a reader joins with
+the trace's events by instruction name and result shape.
+
 Enable with ``Config.tracing_enabled`` (env ``RT_TRACING_ENABLED=1``).
 Sampling is HEAD-BASED (``Config.trace_sample_rate``): the decision is
 made once where a trace starts (the serve router's root, a driver
@@ -43,6 +50,7 @@ from __future__ import annotations
 import contextvars
 import itertools
 import os
+import re
 import struct
 import sys
 import threading as _threading
@@ -315,6 +323,12 @@ def _profiler():
     return _annotation
 
 
+def profiling() -> bool:
+    """Whether a ``jax.profiler`` trace is on in this process."""
+    prof = _profiler()
+    return prof is not None and prof[0]()
+
+
 class phase:
     """Context manager around one host phase of a loop (not a request).
 
@@ -342,9 +356,8 @@ class phase:
             self._ann.set_metadata(**args)
 
     def __enter__(self):
-        prof = _profiler()
-        if prof is not None and prof[0]():
-            self._ann = prof[1](self.name, **self.args)
+        if profiling():
+            self._ann = _annotation[1](self.name, **self.args)
             self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
@@ -357,6 +370,195 @@ class phase:
         metrics.llm_engine_phase_seconds.observe(dt * 1e-9,
                                                  {"phase": self.name})
         return False
+
+
+# ------------------------------------------------ parts of a jitted program
+# The layer parts a serve program's instructions are summed by. Scopes nest
+# and a reader takes the innermost: ``weights_concat`` stands inside
+# ``project`` and ``ffn``, ``router`` and ``experts`` inside an expert layer.
+PARTS = (
+    "embed",           # the token rows of the embedding
+    "project",         # input norm, q/k/v or latent projections, rotary
+    "kv_write",        # the new rows into the pools
+    "attention",       # scores, softmax, values, or the paged/prefill kernel
+    "attn_out",        # the output projection onto the residual
+    "ffn",             # dense feed-forward and shared experts, their norm
+    "router",          # scores, top-k, sort, the load counters
+    "experts",         # the routed product and its weighted sum
+    "indexer",         # the indexer's projections and scores
+    "select",          # the top-k mask over the indexer's scores
+    "weights_concat",  # wq|wk|wv and w_gate|w_up joined in the fused branches
+    "head",            # final norm and logits
+    "sample",          # the sampling tail, rng
+)
+# what a table holds besides: a scan's own instructions that no scope names
+# (slices of stacked weights and pools, the carry), and a key that two
+# shape variants of one program give different parts
+SCAN, AMBIGUOUS = "scan", "?"
+
+
+def part(name: str):
+    """``jax.named_scope(name)`` for a name of ``PARTS``, as a context
+    manager or a decorator; any other name raises where it is written, so a
+    typo cannot ship. jax is taken as ``_profiler`` takes it: from the
+    modules the process has, for only code that traces a program comes
+    here."""
+    if name not in PARTS:
+        raise ValueError(f"{name!r} is not a part of a program: {PARTS}")
+    return sys.modules["jax"].named_scope(name)
+
+
+# one instruction of a compiled program's text: name, result, the rest
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\((.*)$")
+_SHAPE = re.compile(r"(pred|[a-z]+\d+)\[[\d,]*\]")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+_TO_APPLY = re.compile(r"\bto_apply=%?([\w.\-]+)")
+_OPERAND = re.compile(r"%([\w.\-]+)")
+# opcodes that cost the device nothing and never are an event of a trace
+_FREE = frozenset(("parameter", "constant", "get-tuple-element", "tuple",
+                   "bitcast", "after-all", "partition-id", "replica-id"))
+# a transform's wrapper around the scopes under it: ``transpose(jvp(ffn))``
+_WRAPPED = re.compile(r"^(?:jvp|transpose|vmap|checkpoint|remat|custom_jvp|"
+                      r"custom_vjp|shard_map)\((.*)\)$")
+
+
+def instruction_key(name: str, result: str) -> str:
+    """``<instruction name>|<first array shape of its result>``: what a
+    device event of a trace and an instruction of the program's text have
+    in common (``fusion.16|bf16[16,128]``)."""
+    m = _SHAPE.search(result)
+    return f"{name}|{m.group(0) if m else ''}"
+
+
+def op_part(op_name: str) -> str | None:
+    """The innermost ``PARTS`` component of an ``op_name`` path
+    (``jit(f)/while/body/ffn/weights_concat/concatenate``), ``SCAN`` for a
+    scan's own unnamed instruction, else None. The last component is the
+    primitive and names nothing."""
+    path = op_name.split("/")[:-1]
+    for comp in reversed(path):
+        m = _WRAPPED.match(comp)
+        while m:
+            comp = m.group(1)
+            m = _WRAPPED.match(comp)
+        if comp in PARTS:
+            return comp
+    if "while" in path and "body" in path:
+        return SCAN
+    return None
+
+
+def program_instructions(hlo_text: str) -> tuple[str, list]:
+    """(module name, computations) of a compiled program's text
+    (``Compiled.as_text()``): per computation whose instructions can be
+    device events of their own — those of fused computations and of the
+    scalar bodies of reductions are inside an event, never one — its
+    instructions in the text's (the schedule's) order, each ``(name,
+    instruction_key, opcode, op_name or None, names in its operands)``."""
+    module = hlo_text[:200].split(",", 1)[0].removeprefix("HloModule ").strip()
+    lines = hlo_text.splitlines()
+    # computations that are inside an instruction: most of the text, and
+    # known only once their callers, which stand after them, are read
+    inside: set[str] = set()
+    for line in lines:
+        if "calls=" in line:
+            inside.update(_CALLS.findall(line))
+        elif "to_apply=" in line:
+            m = _INSTRUCTION.match(line)
+            if m and m.group(3) != "call":
+                inside.update(_TO_APPLY.findall(m.group(4)))
+    computations, rows = [], None
+    for line in lines:
+        if not line.startswith(" "):
+            m = _COMPUTATION.match(line)
+            rows = [] if m and m.group(1) not in inside else None
+            if rows is not None:
+                computations.append(rows)
+            continue
+        m = _INSTRUCTION.match(line) if rows is not None else None
+        if m is None:
+            continue
+        name, result, opcode, rest = m.groups()
+        op = _OP_NAME.search(rest)
+        rows.append((name, instruction_key(name, result), opcode,
+                     op.group(1) if op else None,
+                     _OPERAND.findall(rest.split(", metadata=", 1)[0])))
+    return module, computations
+
+
+def _agreed(parts: dict, names) -> str | None:
+    """The one part that those of ``names`` which have a part all have."""
+    found = {parts.get(n) for n in names} - {None}
+    return found.pop() if len(found) == 1 else None
+
+
+def instruction_parts(hlo_text: str) -> tuple[str, dict[str, str]]:
+    """(module name, ``{instruction_key: part}``) of a compiled program's
+    text, over the instructions that can be device events. An instruction's
+    part is its ``op_name``'s (``op_part``). One that has none — the
+    compiler's own: a weight fetched in slices ahead of its matmul, a copy
+    into fast memory, a layout change — takes the part that all of its
+    users have, else the part that all of its operands have: a fetch belongs
+    to what it feeds. What is still without a part is absent."""
+    module, computations = program_instructions(hlo_text)
+    out: dict[str, str] = {}
+    for rows in computations:
+        named = {name: p for name, _, _, op, _ in rows
+                 if op and (p := op_part(op)) is not None}
+        users: dict[str, list] = {}
+        for name, _, _, _, operands in rows:
+            for o in operands:
+                users.setdefault(o, []).append(name)
+        part = dict(named)
+        for name, *_ in reversed(rows):   # the schedule's order: users later
+            if name not in part:
+                part[name] = _agreed(part, users.get(name, ()))
+        down = dict(named)                # what flows on from the producers
+        for name, _, _, _, operands in rows:
+            if part[name] is None:
+                part[name] = down[name] = _agreed(down, operands)
+        out.update((key, part[name]) for name, key, opcode, _, _ in rows
+                   if opcode not in _FREE and part[name] is not None)
+    return module, out
+
+
+def compiled_parts(compiled) -> dict:
+    """What a reader needs of one compiled program (a ``jax.stages.
+    Compiled``): its module's name as a trace prints it, its
+    ``instruction_parts`` and the seconds making them took. ``stale``: no
+    instruction names a part — the executable came from a compile cache
+    whose key leaves the scopes out, written by a tree without them."""
+    t0 = time.perf_counter()
+    module, parts = instruction_parts(compiled.as_text())
+    return {"module": module, "parts": parts,
+            "stale": not any(p in PARTS for p in parts.values()),
+            "seconds": time.perf_counter() - t0}
+
+
+def merged_parts(variants) -> dict:
+    """``compiled_parts`` of a process's programs -> ``{module: {"parts",
+    "stale", "variants", "seconds"}}``: the shape variants of one program
+    (pads, waves, step counts) as one table, in which a key that two of them
+    give different parts names neither. A program with a stale variant is
+    stale as a whole and has no table: its events must read as unnamed,
+    never as another variant's parts."""
+    out: dict[str, dict] = {}
+    for v in variants:
+        p = out.setdefault(v["module"], {"parts": {}, "stale": False,
+                                         "variants": 0, "seconds": 0.0})
+        p["variants"] += 1
+        p["seconds"] += v["seconds"]
+        p["stale"] |= v["stale"]
+        for key, name in v["parts"].items():
+            if p["parts"].setdefault(key, name) != name:
+                p["parts"][key] = AMBIGUOUS
+    for p in out.values():
+        if p["stale"]:
+            p["parts"] = {}
+    return out
 
 
 # -------------------------------------------------------- critical path
