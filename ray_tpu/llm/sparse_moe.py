@@ -11,7 +11,9 @@ of THREE pools on one kind of page and an attention that picks its keys.
   the ``topk`` largest (``ops/select.py``: exact, ties to the lower position)
   and attends the picked rows of K and V. On a TPU the three are a kernel
   each and both ends read the pools where they lie (``paged_index_scores``,
-  ``topk_prefix_mask``, ``paged_decode_attention``'s ``selected``); anywhere else, where the kernels would be interpreted, the
+  ``topk_prefix_mask`` — whose passes stop at the longest live slot, not at
+  the table's width — and ``paged_decode_attention``'s ``selected``);
+  anywhere else, where the kernels would be interpreted, the
   gathered table in the plain form — the kernels' reference and what the CPU
   tests run (``_reads_in_place``, as the other families: decided by what the
   code can see). **Walked, not gathered:** the attention fetches every live
@@ -27,16 +29,24 @@ of THREE pools on one kind of page and an attention that picks its keys.
   kernels scalar-prefetch them, and what is left to a step is whether the
   block's pages hold tokens yet (``sparse_walk_blocks`` /
   ``sparse_walk_run_blocks``: how often an allocator's tables let it be).
-* **Prefill** is whole-prompt per pad bucket: the indexer scores a block of
-  ``q_chunk`` queries at a time against the prompt's keys, the selection
-  leaves one byte a (query, key) pair, and the blocked kernel
+* **Prefill** is whole-prompt per pad bucket. The picks of a layer are ONE
+  kernel on a TPU (``ops/prefill_picks.py``): a tile of 128 queries is scored
+  against the keys it can see — column blocks up to its last position, the
+  triangle and not the square — selected with the scores still in VMEM, and
+  leaves one byte a (query, key) pair; the tiles under ``topk`` pick all
+  they see and are neither scored nor selected. The blocked kernel
   (``ops/prefill_attention.py``) attends each query's own picks — no float
-  ``[T, T]`` array. A wave holds at most ``WAVE_LIMIT`` prompts and tokens.
+  ``[T, T]`` array, no block of float scores in HBM either. Off the TPU, and
+  for a prompt that is not whole tiles, the plain form (``_prefill_picks``):
+  ``q_chunk`` queries at a time against all the prompt's keys. A wave holds
+  at most ``WAVE_LIMIT`` prompts and tokens.
 * **The expert layer** routes over all experts (softmax, top-k) and computes
   the held ones' part; with no shared expert, holders' parts add up to the
-  layer. ``MOE_STATS`` and five sums of the attention's own ride back with
+  layer. ``MOE_STATS`` and seven sums of the attention's own ride back with
   the tokens: positions scored, rows in the selected sets, K/V positions
-  fetched, blocks the two walks made and those of them that were one copy.
+  fetched, blocks the two walks made and those of them that were one copy,
+  table columns the selection's passes walked (``ops/select.py``: up to the
+  longest live slot of a tile of slots) and the width they are a share of.
 
 LoRA, int8 pools, speculative decoding, suffix prefill and page export are
 the Llama family's programs; ``llm/engine.py`` refuses them for this family
@@ -60,13 +70,16 @@ from ray_tpu.ops.paged_attention import paged_decode_attention, selected_runs
 from ray_tpu.ops.paged_indexer import (
     index_runs, keys_per_row, pack_keys, paged_index_scores, unpack_keys)
 from ray_tpu.ops.prefill_attention import blocks_for, gqa_prefill_attention
+from ray_tpu.ops.prefill_picks import picks_block, prefill_picks
+from ray_tpu.ops.select import prefix_walked
 from ray_tpu.utils import tracing
 
 # the most prompts and tokens one prefill program may hold
 WAVE_LIMIT = (8, 16384)
 # a decode step's own sums, after MOE_STATS, each over layers and live slots
 SPARSE_STATS = ("sparse_scored", "sparse_attended", "sparse_kv_fetched",
-                "sparse_walk_blocks", "sparse_walk_run_blocks")
+                "sparse_walk_blocks", "sparse_walk_run_blocks",
+                "sparse_select_walked", "sparse_select_width")
 
 
 def make_pools(cfg: SparseMoeConfig, page_size: int, n_pages: int, kv_dtype):
@@ -122,6 +135,7 @@ def _decode_body(params, tokens, pos, tables, cache, active, temps, key,
     if in_place:
         (index_flags, _), (selected_flags, _) = runs
     lengths = jnp.where(active, pos + 1, 0)
+    limit = jnp.where(active, pos, -1)  # a slot's last candidate position
     loads = []
     with tracing.part("embed"):
         x = params["tok"]["embedding"][tokens][:, None, :]
@@ -148,8 +162,8 @@ def _decode_body(params, tokens, pos, tables, cache, active, temps, key,
             else:
                 scores = indexer_scores(qi, w, unpack_keys(
                     ipool[i][tables], dk).astype(qi.dtype))[:, 0]
-        picked = sparse_select(scores[:, None], jnp.where(active, pos, -1)[
-            :, None], cfg, jnp.float32)[:, 0]  # [B, MAXP * PS] of 0 / 1
+        picked = sparse_select(scores[:, None], limit[:, None], cfg,
+                               jnp.float32)[:, 0]  # [B, MAXP * PS] of 0 / 1
         with tracing.part("attention"):
             if in_place:
                 att = paged_decode_attention(
@@ -179,7 +193,8 @@ def _decode_body(params, tokens, pos, tables, cache, active, temps, key,
     else:  # the gathered form: every slot's table, and no walk
         fetched, walked = jnp.asarray(B * MAXP * PS), [jnp.asarray(0)] * 2
     sparse = cfg.n_layers * jnp.stack([
-        lengths.sum(), jnp.minimum(lengths, cfg.topk).sum(), fetched, *walked])
+        lengths.sum(), jnp.minimum(lengths, cfg.topk).sum(), fetched, *walked,
+        prefix_walked(limit, MAXP * PS), jnp.asarray(B * MAXP * PS)])
     return (jnp.where(active, next_tok, 0), (kpool, vpool, ipool),
             jnp.concatenate([
                 moe_load_stats(loads, B * cfg.n_experts_per_tok),
@@ -212,9 +227,17 @@ def sparse_moe_decode_multi(params, loras, aids, tokens, seq_lens, tables,
 @tracing.part("indexer")
 def _prefill_picks(qi, w, ki, cfg: SparseMoeConfig):
     """Every query's selected set over its own prompt, one byte a pair:
-    [N, T, T] int8. Scored and selected ``q_chunk`` queries at a time, so the
-    float scores of one block are all that ever exist."""
+    [N, T, T] int8. On a TPU, for a prompt of whole tiles, ONE kernel a layer
+    (``ops/prefill_picks.py``) that scores and selects over the triangle — a
+    tile of queries against the keys it can see, none for the tiles under
+    ``topk`` — and keeps the scores in VMEM. Anywhere else the plain form,
+    which is the kernel's reference: ``q_chunk`` queries at a time against
+    all the prompt's keys, so the float scores of one block are all that
+    ever exist."""
     N, T = ki.shape[:2]
+    if _reads_in_place() and picks_block(T) is not None:
+        with tracing.part("select"):
+            return prefill_picks(qi, w, ki, cfg.topk)
     idx = jnp.arange(T)
     bq = cfg.q_chunk if T % cfg.q_chunk == 0 else T
 

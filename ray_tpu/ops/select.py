@@ -13,13 +13,25 @@ position), with no sort and nothing approximate.
 
 Two forms of one rule. ``topk_mask`` is plain XLA operations over any mask of
 candidates: the reference, and what runs off the TPU. ``topk_prefix_mask`` is
-what the serving programs call — a row's candidates are its entries up to a
-limit (a query's own position) — and on a TPU it is ONE Pallas kernel a call:
-a tile of rows stays in VMEM through the 32 passes over the bits and, for the
-ties, 14 more over the positions (which of the equal entries are the lowest:
-a bisection again, no prefix sum), where XLA ran each pass as operations of
-its own — a hundred a call, which is also what a profiler trace of a decode
-step then holds (PERF.md, PR 33).
+what a decode step calls — a row's candidates are its entries up to a limit
+(a query's own position) — and on a TPU it is ONE Pallas kernel a call: a
+tile of rows stays in VMEM through the 32 passes over the bits and, for the
+ties, up to 14 more over the positions (which of the equal entries are the
+lowest: a bisection again, no prefix sum), where XLA ran each pass as
+operations of its own (PERF.md, PR 33).
+
+**The passes walk what the rows can see** (PR 37). A row picks from ``0 ..
+limit``, so nothing past a tile's largest limit can be a pick: the kernel
+keeps the tile's scores as order-preserving integer keys in VMEM, made once,
+in column blocks, and every pass is a loop over the blocks ``0 ..
+walk_blocks(largest limit)`` — a decode step's slots whose longest holds
+9,000 live positions walk 9,216 columns of a 16,384-wide table, not all of
+it; what lies past is never read (it may be anything) and is written 0. The
+position passes run only where some row of the tile has more entries equal
+to its k-th value than places left for them; otherwise every equal entry is
+a pick and there is nothing to order. ``pick_walked`` is that selection over
+keys already in VMEM: ``ops/prefill_picks.py`` scores into the same scratch
+and calls it, so a prompt's float scores never exist in HBM.
 """
 from __future__ import annotations
 
@@ -33,6 +45,14 @@ from jax.experimental.pallas import tpu as pltpu
 from ray_tpu.utils import tracing
 
 _INT_MIN = -(1 << 31)
+_LANES = 128
+# columns a block of the kernel's walk: the largest that divides the width
+_BLOCKS = (1024, 512, 256, 128)
+# rows a tile of the kernel, whatever the mask's type (a tile of bytes is 32
+# rows; a float mask's 8-row tiles took 57 us a decode step's call where 32
+# rows take 40: PERF.md, PR 37), and the cells of a pass's partial sums
+_TILE = 32
+_COUNT_CELLS = 16 * 8 * _LANES
 
 
 def _ordered_bits(scores):
@@ -73,7 +93,23 @@ def _selects_in_kernel(width: int) -> bool:
     the kernel on a TPU for rows of whole lane tiles, XLA operations anywhere
     else (where the kernel would be interpreted) — which thereby stay the
     kernel's reference and what the CPU tests run."""
-    return jax.default_backend() == "tpu" and width % 128 == 0
+    return jax.default_backend() == "tpu" and width % _LANES == 0
+
+
+def _block_for(width: int) -> int:
+    return next(b for b in _BLOCKS if width % b == 0)
+
+
+def walk_blocks(last, block: int, floor: int = 0):
+    """Column blocks the passes of a tile of rows walk, the tile's largest
+    candidate position being ``last``: those that hold ``0 .. last``; none
+    where ``last`` < ``floor`` (no candidate at -1; in a prompt, a tile whose
+    queries all see at most k keys picks them all unscored). The ONE rule the
+    kernels' trip counts and the counters' hand counts share: ints or
+    arrays."""
+    if isinstance(last, int):
+        return 0 if last < floor else last // block + 1
+    return jnp.where(last < floor, 0, last // block + 1)
 
 
 def topk_prefix_mask(scores, limit, k: int, dtype=jnp.float32):
@@ -90,47 +126,131 @@ def topk_prefix_mask(scores, limit, k: int, dtype=jnp.float32):
     return topk_mask(scores, valid, k).astype(dtype)
 
 
-def _kernel(s_ref, limit_ref, o_ref, *, k: int):
-    s = s_ref[...]
-    rows, S = s.shape
+def _tile_tops(limit):
+    """The largest limit of each tile of rows (rows past the last: -1)."""
+    return jnp.pad(limit, (0, -limit.shape[0] % _TILE), constant_values=-1
+                   ).reshape(-1, _TILE).max(axis=1)
+
+
+def prefix_walked(limit, width: int):
+    """Columns ``topk_prefix_mask``'s passes walk for rows of these limits,
+    summed over the rows: a row walks what its tile's largest limit asks for
+    in the kernel, the whole width in the plain form. limit: [R] int."""
+    R = limit.shape[0]
+    if not _selects_in_kernel(width):
+        return jnp.asarray(R * width)
+    block = _block_for(width)
+    blocks = walk_blocks(_tile_tops(limit), block)
+    return (jnp.repeat(blocks, _TILE)[:R] * block).sum()
+
+
+def ordered_key(s):
+    """In a kernel: float32 -> int32 whose SIGNED order is the floats' order;
+    every float above the lowest int, which is kept for "not a candidate"."""
     s = jnp.where(s == 0, jnp.float32(0), s)
     bits = pltpu.bitcast(s, jnp.int32)
-    # int32 whose SIGNED order is the floats' order; every float above the
-    # lowest int, which is kept for "not a candidate"
-    key = jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
-    col = jax.lax.broadcasted_iota(jnp.int32, (rows, S), 1)
-    valid = col <= limit_ref[...]
-    key = jnp.where(valid, key, jnp.int32(_INT_MIN))
+    return jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
 
-    def count(mask):
-        return jnp.sum(mask.astype(jnp.int32), axis=1, keepdims=True)
+
+def put_keys(key_ref, b, scores, limit, block: int):
+    """In a kernel: column block ``b`` of a tile's keys from its scores
+    [rows, block]: ``ordered_key`` where a row's candidates are (columns up
+    to its ``limit`` [rows, 1]), ``_INT_MIN`` elsewhere."""
+    col = b * block + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+    key_ref[:, pl.ds(pl.multiple_of(b * block, block), block)] = jnp.where(
+        col <= limit, ordered_key(scores), jnp.int32(_INT_MIN))
+
+
+def pick_walked(key_ref, o_ref, limit, n_blocks, *, k: int, block: int):
+    """In a kernel: the selection of a tile of rows whose keys lie in VMEM.
+    key_ref: [rows, S] int32, of which column blocks ``0 .. n_blocks`` hold
+    ``ordered_key`` of the candidates' scores and ``_INT_MIN`` elsewhere (the
+    rest is never read); limit: [rows, 1] the rows' last candidate position.
+    Writes o_ref [rows, S] whole: 1 at a pick, 0 elsewhere."""
+    rows, S = key_ref.shape
+    # a pass counts into 16 registers of partial sums, so that its adds are
+    # 16 chains and not one
+    lanes = min(block, max(_LANES, _COUNT_CELLS // rows))
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, lanes), 1)
+
+    def at(b):
+        return pl.ds(pl.multiple_of(b * block, block), block)
+
+    def wide(x):  # [rows, 1] -> the width of the partial sums, once a pass
+        return jnp.broadcast_to(x, (rows, lanes))
+
+    def count(hit):
+        """Entries a row over the walked blocks where ``hit(a run of keys,
+        its first column)``."""
+        def body(b, acc):
+            key = key_ref[:, at(b)]
+            for t in range(block // lanes):
+                acc = acc + hit(key[:, t * lanes:(t + 1) * lanes],
+                                b * block + t * lanes).astype(jnp.int32)
+            return acc
+
+        acc = jax.lax.fori_loop(0, n_blocks, body,
+                                jnp.zeros((rows, lanes), jnp.int32))
+        return jnp.sum(acc, axis=1, keepdims=True)
 
     def bit(i, prefix):
         # ``prefix``: the answer's leading bits in the order-preserving
         # UNSIGNED form (signed key with its top bit flipped)
         cand = prefix | (jnp.int32(1) << (31 - i))
-        enough = count(key >= (cand ^ jnp.int32(_INT_MIN))) >= k
+        floor = wide(cand ^ jnp.int32(_INT_MIN))
+        enough = count(lambda key, _: key >= floor) >= k
         return jnp.where(enough, cand, prefix)
 
     kth = jax.lax.fori_loop(0, 32, bit, jnp.zeros((rows, 1), jnp.int32)
                             ) ^ jnp.int32(_INT_MIN)
-    above, equal = key > kth, key == kth
-    left = k - count(above)
+    kth_wide = wide(kth)
+    left = k - count(lambda key, _: key > kth_wide)
+    n_equal = count(lambda key, _: key == kth_wide)
     # of the entries equal to the k-th, the ``left`` lowest positions: the
     # largest position ``last`` with fewer than ``left`` of them at or under
-    # it, found a bit at a time, then everything up to ``last + 1``
+    # it, found a bit at a time, then everything up to ``last + 1``. Where no
+    # row has more of them than places (a row short of k candidates has none
+    # to order: its k-th is no score) every one is a pick.
     n_bits = max(1, (S - 1).bit_length())
 
     def place(i, last):
         step = jnp.int32(1) << (n_bits - 1 - i)
-        few = count(jnp.logical_and(equal, col <= last + step)) < left
+        upto = wide(last + step)
+        few = count(lambda key, col: jnp.logical_and(
+            key == kth_wide, lane + col <= upto)) < left
         return jnp.where(few, last + step, last)
 
-    last = jax.lax.fori_loop(0, n_bits, place,
-                             jnp.full((rows, 1), -1, jnp.int32))
-    picked = jnp.logical_and(valid, jnp.logical_or(
-        above, jnp.logical_and(equal, col <= last + 1)))
-    o_ref[...] = jnp.where(picked, 1, 0).astype(o_ref.dtype)
+    crowded = jnp.max(jnp.where(kth == _INT_MIN, 0, n_equal - left)) > 0
+    last = jax.lax.cond(
+        crowded,
+        lambda: jax.lax.fori_loop(0, n_bits, place,
+                                  jnp.full((rows, 1), -1, jnp.int32)),
+        lambda: jnp.full((rows, 1), S, jnp.int32))
+
+    def write(b, _):
+        key = key_ref[:, at(b)]
+        col = b * block + jax.lax.broadcasted_iota(jnp.int32, key.shape, 1)
+        picked = jnp.logical_and(col <= limit, jnp.logical_or(
+            key > kth, jnp.logical_and(key == kth, col <= last + 1)))
+        o_ref[:, at(b)] = jnp.where(picked, 1, 0).astype(o_ref.dtype)
+
+    def blank(b, _):
+        o_ref[:, at(b)] = jnp.zeros((rows, block), o_ref.dtype)
+
+    jax.lax.fori_loop(0, n_blocks, write, None)
+    jax.lax.fori_loop(n_blocks, S // block, blank, None)
+
+
+def _kernel(top_ref, s_ref, limit_ref, o_ref, key_ref, *, k: int, block: int):
+    limit = limit_ref[...]
+    n_blocks = walk_blocks(top_ref[pl.program_id(0)], block)
+
+    def keys(b, _):
+        at = pl.ds(pl.multiple_of(b * block, block), block)
+        put_keys(key_ref, b, s_ref[:, at], limit, block)
+
+    jax.lax.fori_loop(0, n_blocks, keys, None)
+    pick_walked(key_ref, o_ref, limit, n_blocks, k=k, block=block)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "dtype", "interpret"))
@@ -139,24 +259,24 @@ def _topk_prefix_mask(scores, limit, *, k: int, dtype, interpret: bool):
     and lowered kernel (``ops/paged_attention.py``). scores: [R, S] float32;
     limit: [R] int32."""
     R, S = scores.shape
-    # rows a tile: a byte mask packs 32 rows a tile, a float one 8; a tile
-    # of float32 scores stays under 2 MB of VMEM
-    tile = 32 if jnp.dtype(dtype).itemsize == 1 else 8
+    tile, block = _TILE, _block_for(S)
     pad = -R % tile
-    if pad:
-        scores = jnp.pad(scores, ((0, pad), (0, 0)))
-        limit = jnp.pad(limit, (0, pad), constant_values=-1)
+    scores = jnp.pad(scores, ((0, pad), (0, 0)))
+    limit = jnp.pad(limit, (0, pad), constant_values=-1)
     out = pl.pallas_call(
-        functools.partial(_kernel, k=k),
+        functools.partial(_kernel, k=k, block=block),
         out_shape=jax.ShapeDtypeStruct(scores.shape, dtype),
-        grid=(scores.shape[0] // tile,),
-        in_specs=[pl.BlockSpec((tile, S), lambda i: (i, 0)),
-                  pl.BlockSpec((tile, 1), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((tile, S), lambda i: (i, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,  # a tile's largest limit: its trip count
+            grid=(scores.shape[0] // tile,),
+            in_specs=[pl.BlockSpec((tile, S), lambda i, _: (i, 0)),
+                      pl.BlockSpec((tile, 1), lambda i, _: (i, 0))],
+            out_specs=pl.BlockSpec((tile, S), lambda i, _: (i, 0)),
+            scratch_shapes=[pltpu.VMEM((tile, S), jnp.int32)]),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
         name="topk_prefix_mask",
-    )(scores, limit[:, None])
+    )(_tile_tops(limit), scores, limit[:, None])
     return out[:R]

@@ -310,6 +310,13 @@ LLM_MODEL_STATS = {
         "rt_llm_sparse_walk_run_blocks_total",
         "those of them fetched as ONE copy: all pages hold tokens and lie "
         "one after the other in the pool"),
+    "sparse_select_walked": Counter(
+        "rt_llm_sparse_select_columns_walked_total",
+        "table columns the selection's passes walked: up to the longest "
+        "live slot of each tile of slots"),
+    "sparse_select_width": Counter(
+        "rt_llm_sparse_select_columns_width_total",
+        "slots x table width: what walked is a share of"),
 }
 serve_lane_seconds = Histogram(
     "rt_serve_lane_seconds",

@@ -21,7 +21,7 @@ from ray_tpu.llm.engine import (ContinuousBatchingEngine, UnsupportedByModel,
                                 serving_programs)
 from ray_tpu.models.sparse_moe import (SparseMoeConfig, attend_plain,
                                        sparse_moe_forward, sparse_moe_init)
-from ray_tpu.ops import paged_attention, paged_indexer
+from ray_tpu.ops import paged_attention, paged_indexer, prefill_picks, select
 from ray_tpu.ops.paged_attention import (paged_decode_attention,
                                          selected_runs, table_runs)
 from ray_tpu.ops.paged_indexer import (index_runs, pack_keys,
@@ -254,6 +254,142 @@ def test_the_selection_kernel_is_the_plain_form(R, S, k, dtype):
     assert np.array_equal(np.asarray(plain), got)
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.int8])
+@pytest.mark.parametrize("limits", [
+    "none", "first", "last", "mixed", "short"])
+def test_the_bounded_selection_walks_to_the_tiles_longest_row(limits, dtype):
+    """The kernel's passes stop at the block of a tile's largest limit: rows
+    with no candidate (-1), one (0), all (S - 1), mixed limits in one tile
+    and a tile that walks 2 of its 5 blocks — the plain form's picks in every
+    case, and whatever lies past the bound (huge, of either sign) changes
+    nothing and reads 0."""
+    from ray_tpu.ops.select import _topk_prefix_mask
+
+    S, k = 640, 48                      # five blocks of 128 columns
+    rows = 2 * select._TILE + 3
+    rng = np.random.default_rng(11)
+    s = rng.standard_normal((rows, S)).astype(np.float32)
+    s[1, ::2] = 1.5                     # ties at the k-th across block edges
+    limit = {"none": np.full(rows, -1), "first": np.zeros(rows),
+             "last": np.full(rows, S - 1),
+             "mixed": rng.integers(-1, S, rows),
+             "short": rng.integers(60, 250, rows)}[limits].astype(np.int32)
+    if limits == "mixed":
+        limit[:4] = [-1, 0, S - 1, 127]
+    valid = np.arange(S)[None] <= limit[:, None]
+    want = np.asarray(topk_mask(jnp.asarray(s), jnp.asarray(valid), k))
+    wild = np.where(np.arange(S)[None] > limit[:, None],
+                    np.where(rng.random((rows, S)) < 0.5, 3e38, -3e38), s
+                    ).astype(np.float32)
+    for scores in (s, wild):
+        got = np.asarray(_topk_prefix_mask(
+            jnp.asarray(scores), jnp.asarray(limit), k=k,
+            dtype=jnp.dtype(dtype), interpret=True))
+        assert got.dtype == np.dtype(dtype)
+        assert np.array_equal(got.astype(bool), want)
+
+
+def test_columns_walked_against_a_hand_count(monkeypatch):
+    """The one rule of the kernels' trip counts. Prefill, tiles of 32 queries
+    and blocks of 128 keys under a topk of 48 over 256 positions: the first
+    tile (ends at 31) walks nothing, the three ending at 63, 95, 127 one
+    block, the four after them two. At the cell's sizes in the issue's
+    tiling (512 x 512): 26 of 64 and 396 of 784 blocks of the square. Decode:
+    a row walks to its tile's largest limit, a tile with no live row
+    nothing."""
+    assert [select.walk_blocks(e, 128, 48) for e in (31, 47, 48, 127, 128)
+            ] == [0, 0, 1, 1, 2]
+    assert prefill_picks.columns_walked(256, 48, 32, 128) == (
+        3 * 32 * 128 + 4 * 32 * 256)
+    assert prefill_picks.columns_walked(4096, 2048, 512, 512) == 26 * 512 * 512
+    assert prefill_picks.columns_walked(14336, 2048, 512, 512) == (
+        396 * 512 * 512)
+    # at the kernel's own tiling a 14,336-token prompt walks half the square
+    assert prefill_picks.picks_block(14336) == 512
+    assert prefill_picks.columns_walked(14336, 2048) / 14336 ** 2 == (
+        pytest.approx(0.506, abs=1e-3))
+    assert prefill_picks.picks_block(40) is None    # not whole tiles
+    assert prefill_picks.picks_block(1024 + 64) is None
+    limit = jnp.asarray([-1] * 32 + [5, 300, -1, 1023] + [9] * 28
+                        + [1024] + [-1] * 31 + [4095] * 3)
+    # off the TPU the plain form walks the whole width of every row
+    assert int(select.prefix_walked(limit, 4096)) == 99 * 4096
+    monkeypatch.setattr(select, "_selects_in_kernel", lambda width: True)
+    assert (select._TILE, select._block_for(4096)) == (32, 1024)
+    assert int(select.prefix_walked(limit, 4096)) == (
+        0 + 32 * 1024 + 32 * 2048 + 3 * 4096)
+
+
+def _exact_indexer_inputs(rng, N, T, J=4, dk=16):
+    """Inputs whose scores are small dyadic numbers: every order of the sum
+    over the heads gives the same float32, and many scores tie."""
+    qi = rng.integers(-2, 3, (N, T, J, dk)).astype(np.float32)
+    ki = rng.integers(-2, 3, (N, T, dk)).astype(np.float32)
+    w = (rng.integers(-4, 5, (N, T, J)) / 4).astype(np.float32)
+    return jnp.asarray(qi), jnp.asarray(w), jnp.asarray(ki)
+
+
+@pytest.mark.parametrize("N,T,k,block,rows", [
+    (1, 256, 48, 128, 128),   # the first tile straddles topk; two blocks
+    (1, 256, 48, 128, 32),    # tiles of 32: one under topk, one straddling
+    (3, 128, 16, 128, 32),    # three prompts, every tile past topk
+    (1, 128, 200, 128, 128),  # a prompt under topk: "all it sees", unscored
+    (3, 256, 64, 128, 128),   # ties at the k-th value across the block edge
+    (1, 384, 100, 128, 128),  # three blocks, the tiles walk one, two, three
+])
+def test_the_prefill_picks_kernel_is_the_plain_form_bit_for_bit(
+        N, T, k, block, rows):
+    """``ops/prefill_picks.py`` in the interpreter against the plain
+    ``_prefill_picks`` (scored a block of queries at a time against all keys,
+    selected by ``topk_mask``) on inputs whose float32 scores are the same in
+    any order of the sum: the same bytes."""
+    cfg = dataclasses.replace(CFG, topk=k, q_chunk=32)
+    qi, w, ki = _exact_indexer_inputs(np.random.default_rng(T + k), N, T)
+    want = np.asarray(programs._prefill_picks(qi, w, ki, cfg))
+    got = np.asarray(prefill_picks._prefill_picks(
+        qi, w, ki, k=k, block=block, interpret=True, rows=rows))
+    assert got.dtype == np.int8 and got.shape == (N, T, T)
+    assert np.array_equal(got, want)
+    t = np.arange(T)
+    assert np.array_equal(got.sum(-1), np.broadcast_to(
+        np.minimum(t + 1, k), (N, T)))
+    if k == 64:
+        # the case is what it says: some query's k-th value has equal entries
+        # on both sides of column 128, and not all of them are picks
+        from ray_tpu.models.sparse_moe import indexer_scores
+        sc = np.asarray(indexer_scores(qi, w, ki))
+        cut = False
+        for n, q in [(n, q) for n in range(N) for q in range(200, T, 7)]:
+            row = sc[n, q, :q + 1]
+            kth = np.sort(row)[-k]
+            eq = np.flatnonzero(row == kth)
+            cut |= bool(eq.min() < 128 <= eq.max()
+                        and not got[n, q, eq].all() and got[n, q, eq].any())
+        assert cut
+
+
+def test_prefill_takes_the_kernel_for_whole_tiles_and_the_plain_form_else(
+        monkeypatch):
+    """What ``_prefill_picks`` runs is decided by what it sees: the kernel
+    where the programs read in place and the prompt is whole tiles, the
+    plain form for a prompt that is not (40 positions) — equal bytes."""
+    cfg = dataclasses.replace(CFG, topk=16, q_chunk=8)
+    calls = []
+    real = prefill_picks.prefill_picks
+    monkeypatch.setattr(programs, "prefill_picks", lambda *a, **kw: (
+        calls.append(a[2].shape), real(*a, **kw))[1])
+    for T, kernel in [(128, True), (40, False)]:
+        qi, w, ki = _exact_indexer_inputs(np.random.default_rng(T), 2, T)
+        want = np.asarray(programs._prefill_picks(qi, w, ki, cfg))
+        assert not calls
+        monkeypatch.setattr(programs, "_reads_in_place", lambda: True)
+        got = np.asarray(programs._prefill_picks(qi, w, ki, cfg))
+        monkeypatch.setattr(programs, "_reads_in_place", lambda: False)
+        assert np.array_equal(got, want)
+        assert bool(calls) == kernel
+        calls.clear()
+
+
 # ---------------------------------------------------------------- the share
 @pytest.mark.parametrize("holders", [8, 2])
 def test_holders_parts_add_up_to_the_uncut_layer(holders):
@@ -322,9 +458,10 @@ def test_the_stats_columns_and_read_counters_against_a_hand_count():
     """A request of 20 + 13 tokens: 12 decode steps (blocks 8 + 4) at
     lengths 21..32 in 3 layers. Scored: every position; attended: 16 of
     them; fetched (the gathered form off the TPU): every slot's whole table
-    a step."""
+    a step; the selection's passes (the plain form off the TPU) walk the
+    whole width of every slot's table."""
     eng = _engine()
-    assert eng.programs.stats[-5:] == programs.SPARSE_STATS
+    assert eng.programs.stats[-7:] == programs.SPARSE_STATS
     before = metrics.stage_totals()
     _serve(eng, [(20, 13)])
     after = metrics.stage_totals()
@@ -340,6 +477,10 @@ def test_the_stats_columns_and_read_counters_against_a_hand_count():
     # the gathered form walks no pool: no blocks, none of them one copy
     assert grown("rt_llm_sparse_walk_blocks_total") == 0
     assert grown("rt_llm_sparse_walk_run_blocks_total") == 0
+    assert grown("rt_llm_sparse_select_columns_walked_total") == (
+        L * steps * eng.B * eng.MAXP * PS)
+    assert grown("rt_llm_sparse_select_columns_width_total") == (
+        L * steps * eng.B * eng.MAXP * PS)
     assert grown("rt_llm_moe_expert_slots_total") == L * steps * 8
     # what the engine reckons itself says the same: attended = selected rows
     assert grown("rt_llm_decode_kv_tokens_live_total") == steps * CFG.topk
