@@ -890,7 +890,8 @@ class ContinuousBatchingEngine:
         from each snapshot slot's length at the block's start: step k of a
         slot attends ``start + k + 1`` positions, or as many of them as a
         layer of the kind reaches back; over kinds, the mean over layers. A
-        slot the planned loop
+        kind that holds no positions (a slot's state: ``PageKind.positions``)
+        is no part of either. A slot the planned loop
         has already handed on has its start from the request itself."""
         starts = np.array(
             [self.seq_lens[i] if self.slot_req[i] is req
@@ -899,8 +900,9 @@ class ContinuousBatchingEngine:
             np.int64)
         lens = starts[:, None] + np.arange(1, K + 1)  # [live slots, K]
         live = read = 0.0
-        layers = sum(k.layers for k in self.kinds)
-        for kind in self.kinds:
+        kinds = [k for k in self.kinds if k.positions]
+        layers = sum(k.layers for k in kinds)
+        for kind in kinds:
             reach = lens if kind.reach is None else np.minimum(lens, kind.reach)
             within = int(self._attended(reach).sum())
             if self._kv_in_place:  # whole pages, from the first within reach

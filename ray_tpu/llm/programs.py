@@ -3,10 +3,12 @@ owns slots, pages, admission and the loops and knows no model: it asks
 ``serving_programs(cfg)`` for the family's ``ServePrograms`` by the config's
 TYPE and threads the family's cache through unseen. A family's program
 module (``llm/llama.py``, ``llm/mla_moe.py``, ``llm/cohere2_moe.py``,
-``llm/sparse_moe.py``) imports this file, ``models/`` and ``ops/``, never the
-engine, and is imported when its config is served.
+``llm/sparse_moe.py``, ``llm/ssm_moe.py``) imports this file, ``models/`` and
+``ops/``, never the engine, and is imported when its config is served.
 A new family supplies a config type, its layer's halves in ``models/``, two
-jitted programs, a cache, and one branch of ``serving_programs``.
+jitted programs, a cache, and one branch of ``serving_programs``. What a slot
+caches is the family's: pages that grow with the sequence (K and V, a latent,
+a window's ring, an indexer's keys), or a state of fixed size.
 """
 from __future__ import annotations
 
@@ -19,6 +21,7 @@ from ray_tpu.models.cohere2_moe import Cohere2MoeConfig
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.models.mla_moe import MlaMoeConfig
 from ray_tpu.models.sparse_moe import SparseMoeConfig
+from ray_tpu.models.ssm_moe import SsmMoeConfig
 from ray_tpu.parallel.moe import expert_passes
 from ray_tpu.utils import tracing
 
@@ -30,9 +33,10 @@ class UnsupportedByModel(NotImplementedError):
     def __init__(self, feature: str, family: str):
         super().__init__(
             f"{feature} is not supported for the {family!r} model family: "
-            f"it is a program of the Llama family's (one K pool and one V "
-            f"pool over every layer, every cached position attended), and "
-            f"this family's programs have no such form")
+            f"it is a program of the Llama family's (what a slot caches is "
+            f"K and V pages of every layer, so a prefix of its pages is a "
+            f"prefix of the sequence), and this family's programs have no "
+            f"such form")
         self.feature, self.family = feature, family
 
 
@@ -43,11 +47,15 @@ class PageKind:
     in the read counters), how many entries a slot's table of it has, and
     how far back a layer of it attends (None: to position 0). A slot of
     ``n`` positions holds ``min(ceil(n / PS), table)`` of its pages: a
-    table shorter than the sequence is the family's ring."""
+    table shorter than the sequence is the family's ring, and a table of
+    ONE entry a row that does not grow with the sequence at all — a
+    recurrent layer's state. Such a kind holds no ``positions``: nothing
+    attends it, and the read counters leave it out."""
     name: str
     layers: int
     table: int
     reach: int | None = None
+    positions: bool = True
 
 
 # extra int32 columns of a decode step's token row of the families with
@@ -61,15 +69,16 @@ MOE_STATS = ("moe_assignments", "moe_experts_touched", "moe_max_load",
 
 
 @tracing.part("router")
-def moe_load_stats(loads, rows: int):
+def moe_load_stats(loads, rows: int, gated: bool = True):
     """A step's rows per held expert, one [held] array an expert layer, of
-    ``rows`` assignments a layer -> the MOE_STATS sums."""
+    ``rows`` assignments a layer -> the MOE_STATS sums. ``gated``: the
+    experts' form, which decides the grouped product that ran."""
     if not loads:
         return jnp.zeros((len(MOE_STATS),), jnp.int32)
     load = jnp.stack(loads)
     return jnp.stack([load.sum(), (load > 0).sum(), load.max(axis=-1).sum(),
-                      jnp.asarray(load.size), expert_passes(load, rows)]
-                     ).astype(jnp.int32)
+                      jnp.asarray(load.size),
+                      expert_passes(load, rows, gated)]).astype(jnp.int32)
 
 
 @dataclass(frozen=True)
@@ -91,12 +100,12 @@ class ServePrograms:
     every slot's whole table a step (what the read counters then report).
     ``page_kinds(cfg, page_size, max_seq_len) -> (PageKind, ...)``: the
     kinds of pages of a cache that has more than one (window and full
-    layers). The engine then keeps a table and a free list a kind, and hands
-    the programs a TUPLE of tables where it hands one (``page_tables`` in
-    decode, ``pages`` in prefill, in the kinds' order); None is one kind
-    for every layer. ``prefill_wave_limit = (prompts, tokens)``: the most
-    one prefill program may hold, so a pad group is split; None splits
-    nothing. ``attends_most(cfg) -> int``: the most positions a decode
+    layers; K/V pages and state rows). The engine then keeps a table and a
+    free list a kind, and hands the programs a TUPLE of tables where it
+    hands one (``page_tables`` in decode, ``pages`` in prefill, in the
+    kinds' order); None is one kind for every layer.
+    ``prefill_wave_limit = (prompts, tokens)``: the most one prefill program
+    may hold, so a pad group is split; None splits nothing. ``attends_most(cfg) -> int``: the most positions a decode
     step's attention ATTENDS of a slot however many it fetches (a model that
     picks its keys: the read counters then say what was attended and what
     was fetched for it); None attends everything within reach.
@@ -136,6 +145,10 @@ def serving_programs(cfg) -> ServePrograms:
         return PROGRAMS
     if isinstance(cfg, SparseMoeConfig):
         from ray_tpu.llm.sparse_moe import PROGRAMS
+
+        return PROGRAMS
+    if isinstance(cfg, SsmMoeConfig):
+        from ray_tpu.llm.ssm_moe import PROGRAMS
 
         return PROGRAMS
     raise TypeError(f"no serving programs for a {type(cfg).__name__}")

@@ -1,6 +1,6 @@
 """Model zoo: the Llama family, the MLA + sparse-expert family, the window +
 full attention sparse-expert family, the learned-sparse-attention expert
-family, ResNet, MLP."""
+family, the state-space + attention + ungated-expert family, ResNet, MLP."""
 
 from ray_tpu.models.cohere2_moe import (  # noqa: F401
     Cohere2MoeConfig, cohere2_moe_forward, cohere2_moe_init)
@@ -9,6 +9,8 @@ from ray_tpu.models.mla_moe import (  # noqa: F401
     MlaMoeConfig, mla_moe_forward, mla_moe_init)
 from ray_tpu.models.sparse_moe import (  # noqa: F401
     SparseMoeConfig, sparse_moe_forward, sparse_moe_init)
+from ray_tpu.models.ssm_moe import (  # noqa: F401
+    SsmMoeConfig, ssm_moe_forward, ssm_moe_init)
 
 
 def init_fn(cfg):
@@ -21,4 +23,6 @@ def init_fn(cfg):
         return cohere2_moe_init
     if isinstance(cfg, SparseMoeConfig):
         return sparse_moe_init
+    if isinstance(cfg, SsmMoeConfig):
+        return ssm_moe_init
     raise TypeError(f"no model for a {type(cfg).__name__}")

@@ -1,4 +1,4 @@
-"""Mixture-of-experts layers: three routers, two regimes.
+"""Mixture-of-experts layers: three routers, two regimes, two expert forms.
 
 Absent from the reference (ref: SURVEY §2.3 — "no MoE expert parallel
 in-tree"; vLLM handles EP internally).
@@ -9,21 +9,21 @@ in-tree"; vLLM handles EP internally).
   dispatch tensor, expert weights sharded on the ``ep`` mesh axis, and
   sharding propagation turning the einsums into all_to_all over ICI.
 * **Serving (``sigmoid_topk_route`` / ``softmax_topk_route`` +
-  ``routed_experts`` + ``moe_layer``, reached from ``llm/mla_moe.py``,
-  ``llm/cohere2_moe.py`` and ``llm/sparse_moe.py``):** the DeepSeek-V3
-  family's layer, Cohere2's with no bias, no routed scale and its shared
-  experts averaged, and the Qwen3-MoE shape's: a softmax over all experts,
-  the k most probable renormalised, no shared expert at all. Sigmoid
-  scores, the k experts with the largest ``score + bias`` chosen and
-  weighed by the score alone, no capacity (no token is ever dropped),
-  three-matrix SwiGLU experts and, where the model has them, shared experts
-  every token passes through. The one-hot dispatch does not scale to 128 experts x 12k prefill
-  tokens, so the routed product is a grouped matmul over the assignments
-  sorted by expert (``jax.lax.ragged_dot`` for prefill's many rows a group,
-  ``ops/grouped_swiglu.py`` for a decode step's few). The layer is told which
-  experts it holds (``held``): it routes over all of them and computes its
-  own experts' part of the sum — on one chip that is all of them, and the
-  exchange between holders is not here.
+  ``routed_experts`` + ``moe_layer``, reached from the four expert families'
+  program modules under ``llm/``):** no capacity, so no token is ever
+  dropped. The router is the DeepSeek-V3 family's (sigmoid scores, the k
+  largest ``score + bias`` chosen and weighed by the score alone, scaled),
+  Cohere2's (that with no bias and no scale) or Qwen3-MoE's (a softmax over
+  all experts, the k most probable renormalised). An expert, routed or
+  shared, is a three-matrix SwiGLU or — where its tree has no ``w_gate`` —
+  two matrices, ``W_down . relu(W_up . h)^2``. A one-hot dispatch does not
+  scale to 128 experts x 12k prefill tokens, so the routed product is a
+  grouped matmul over the assignments sorted by expert (``_streams_experts``
+  says which) — or, for a decode step's few rows of two-matrix experts,
+  every held expert on every token with the unchosen weighed by zero
+  (``_applies_every_expert``). The layer is told which experts it holds
+  (``held``): it routes over all of them and computes its own experts' part
+  of the sum — the exchange between holders is not here.
 """
 
 from __future__ import annotations
@@ -123,26 +123,52 @@ _FEW_ROWS = 512
 
 
 def _streams_experts(rows: int) -> bool:
-    """Which of the two grouped products ``routed_experts`` runs, decided by
+    """Which of the grouped products ``routed_experts`` runs, decided by
     what the code can see and by no option. On a TPU, ``rows = T * k`` at or
     under ``_FEW_ROWS`` — a decode step: a few rows an expert, bound by the
-    bytes of the touched experts — goes through ``ops/grouped_swiglu.py``,
-    which reads each touched expert's three matrices once (measured alone on
-    a v5e, PR 32: 192 rows over 46 of 128 experts of 2048 x 768, 631 us
-    against 931; 384 rows over 16 experts of 4096 x 4096, 2,190 us against
+    bytes of the touched experts' matrices — streams them through ONE kernel
+    (the bound is what the kernel's row block and float32 output take of
+    VMEM at the widest model served, about 5 MB of the chip's 128 MB, and a
+    row count past which ``ragged_dot`` stops reading the weights alone:
     3,168). More rows — every prefill program: thousands of rows a group,
     bound by the MXU — and every other backend, where the kernel would be
     interpreted, keep the three ``jax.lax.ragged_dot`` calls, which thereby
-    stay the kernel's plain reference and what the CPU tests run."""
+    stay the kernel's plain reference and what the CPU tests run. Asked for
+    three-matrix experts only: two-matrix experts (a tree with no
+    ``w_gate``) have no streamed form — ``_applies_every_expert`` says what
+    a step's few rows of theirs take instead, and more rows are two
+    ``ragged_dot`` calls."""
     return jax.default_backend() == "tpu" and rows <= _FEW_ROWS
 
 
-def expert_passes(load, rows: int):
+# rows at or under which two-matrix experts are ALL applied to every token:
+# at 6 or 8 experts a token that is at most 170 tokens, under the 240 or so
+# where 16 held experts' products would outweigh the bytes of their matrices
+_DENSE_ROWS = 1024
+
+
+def _applies_every_expert(rows: int, gated: bool = True) -> bool:
+    """The two-matrix experts' form for a decode step's few rows, on every
+    backend: one batched product of every token with EVERY held expert, the
+    unchosen weighed by zero. A step of 128 slots touches most of the 16
+    held experts anyway (10.5 under a seeded router, all at an even one's 6
+    rows each), and the MXU has the room: on the chip two ``ragged_dot``
+    calls over those 768 sorted rows took 3.3 ms an expert block for 0.32 GB
+    of weights, an eighth of the bandwidth, this product 0.43 (PERF.md
+    section 6, PR 38). Past ``_DENSE_ROWS`` the rows of a prompt are sorted
+    and grouped as every family's."""
+    return not gated and rows <= _DENSE_ROWS
+
+
+def expert_passes(load, rows: int, gated: bool = True):
     """How often the grouped product of ``rows`` assignments puts an
     expert's matrices through the MXU, summed over ``load`` [..., held]:
-    the kernel's row chunks where it runs, else once a touched expert."""
-    if _streams_experts(rows):
+    the kernel's row chunks where it runs, every held expert once where all
+    are applied, else once a touched expert."""
+    if gated and _streams_experts(rows):
         return grouped_swiglu.expert_passes(load)
+    if _applies_every_expert(rows, gated):
+        return jnp.asarray(load.size)
     return (load > 0).sum()
 
 
@@ -153,13 +179,14 @@ def routed_experts(h, idx, w, experts, held: tuple[int, int], valid=None):
     rows form one group of a grouped product (``_streams_experts`` says
     which: one kernel that streams the touched experts for a decode step's
     few rows, ``jax.lax.ragged_dot`` for prefill's many), and the weighted
-    rows are summed back per token. Assignments to experts outside ``held =
+    rows are summed back per token; a step's few rows of two-matrix experts
+    skip the sort (``_applies_every_expert``). Assignments to experts outside ``held =
     (lo, hi)`` and of rows where ``valid`` is False (dead decode slots,
     prompt padding) sort behind the last group and add nothing.
 
     h: [T, D]; idx, w: [T, k]; experts: {"w_gate", "w_up": [hi-lo, D, F],
-    "w_down": [hi-lo, F, D]}. Returns (y [T, D], load [hi-lo] int32: the
-    rows each held expert got)."""
+    "w_down": [hi-lo, F, D]}, or the two-matrix form without ``w_gate``.
+    Returns (y [T, D], load [hi-lo] int32: the rows each held expert got)."""
     T, k = idx.shape
     lo, hi = held
     n = hi - lo
@@ -170,14 +197,16 @@ def routed_experts(h, idx, w, experts, held: tuple[int, int], valid=None):
         group = jnp.where(keep, idx - lo, n).reshape(-1)  # n = "nobody here"
         order = jnp.argsort(group)                        # stable
         load = jnp.bincount(group, length=n + 1)[:n].astype(jnp.int32)
+    if _applies_every_expert(T * k, "w_gate" in experts):
+        return _every_expert(h, idx - lo, w, keep, experts), load
     xs = h[order // k]                                   # [T * k, D]
-    if _streams_experts(T * k):
+    if "w_gate" in experts and _streams_experts(T * k):
         ys = grouped_swiglu.grouped_swiglu(
             xs, experts["w_gate"], experts["w_up"], experts["w_down"], load)
     else:
-        hid = jax.nn.silu(jax.lax.ragged_dot(xs, experts["w_gate"], load)) * (
-            jax.lax.ragged_dot(xs, experts["w_up"], load))
-        ys = jax.lax.ragged_dot(hid, experts["w_down"], load)
+        # prefill's many rows a group and every other backend:
+        # ``_streams_experts`` says why
+        ys = _ragged_experts(xs, experts, load)
     ws = jnp.where(keep, w, 0.0).reshape(-1)[order]
     # rows past the last group belong to no expert: whatever the grouped
     # product left there is dropped, not scaled
@@ -212,10 +241,10 @@ def moe_layer(h, moe, *, k: int, scale: float, norm: bool = True,
     number where they are averaged; a layer with no ``shared`` sub-tree has
     none, and its holders' parts add up to the layer. A router without a
     ``bias`` chooses by its scores; ``softmax`` is the model's router being
-    ``softmax_topk_route`` (it has no scale). Returns (y [T, D], load
-    [hi-lo])."""
-    from ray_tpu.ops.basic import swiglu
-
+    ``softmax_topk_route`` (it has no scale). A ``shared`` sub-tree with no
+    ``w_gate`` is the two-matrix form, as the routed experts beside it are
+    (``_shared_expert``).
+    Returns (y [T, D], load [hi-lo])."""
     if softmax:
         idx, w = softmax_topk_route(h, moe["router"]["kernel"], k, norm)
     else:
@@ -224,10 +253,10 @@ def moe_layer(h, moe, *, k: int, scale: float, norm: bool = True,
     y, load = routed_experts(h, idx, w, moe["experts"], held, valid)
     if "shared" not in moe:
         return y, load
-    sh = moe["shared"]
     with tracing.part("ffn"):
-        shared = swiglu(h, sh["w_gate"]["kernel"], sh["w_up"]["kernel"],
-                        sh["w_down"]["kernel"])
+        # every holder computes the shared experts alike, so a sum over
+        # holders' outputs has to count them once, not once a holder
+        shared = _shared_expert(h, moe["shared"])
         if shared_scale != 1:
             shared = shared * jnp.asarray(shared_scale, shared.dtype)
         return y + shared, load
@@ -257,3 +286,44 @@ def moe_layer_chunked(h, moe, valid=None, **kw):
         lambda c: moe_layer(c[0], moe, valid=c[1], **kw),
         (flat.reshape(chunks, _MOE_CHUNK, D), ok.reshape(chunks, _MOE_CHUNK)))
     return y.reshape(B, T, D), load.sum(axis=0)
+
+
+def _relu2(a):
+    """``relu(a)^2``: the ungated experts' activation."""
+    return jnp.square(jax.nn.relu(a))
+
+
+def _ragged_experts(xs, experts, load):
+    """The routed product as ``jax.lax.ragged_dot`` calls over the sorted
+    rows: three for SwiGLU experts, two for the ungated form."""
+    if "w_gate" in experts:
+        hid = jax.nn.silu(jax.lax.ragged_dot(xs, experts["w_gate"], load)) * (
+            jax.lax.ragged_dot(xs, experts["w_up"], load))
+    else:
+        hid = _relu2(jax.lax.ragged_dot(xs, experts["w_up"], load))
+    return jax.lax.ragged_dot(hid, experts["w_down"], load)
+
+
+def _every_expert(h, held_idx, w, keep, experts):
+    """Every held two-matrix expert on every token, each token's outputs
+    summed under its weights (zero for an expert it did not choose). h:
+    [T, D]; held_idx [T, k]: the chosen experts' places among the held;
+    w, keep: [T, k]. Returns [T, D] in h's dtype."""
+    n = experts["w_up"].shape[0]
+    hot = (held_idx[:, :, None] == jnp.arange(n)) & keep[:, :, None]
+    combine = jnp.where(hot, w[:, :, None], 0.0).sum(axis=1)      # [T, n]
+    hid = _relu2(jnp.einsum("td,edf->etf", h, experts["w_up"]))
+    ys = jnp.einsum("etf,efd->etd", hid, experts["w_down"])
+    return jnp.einsum("etd,te->td", ys, combine.astype(ys.dtype),
+                      preferred_element_type=jnp.float32).astype(h.dtype)
+
+
+def _shared_expert(h, sh):
+    """The shared experts as one expert of their summed width, in the form
+    their tree has."""
+    if "w_gate" in sh:
+        from ray_tpu.ops.basic import swiglu
+
+        return swiglu(h, sh["w_gate"]["kernel"], sh["w_up"]["kernel"],
+                      sh["w_down"]["kernel"])
+    return _relu2(h @ sh["w_up"]["kernel"]) @ sh["w_down"]["kernel"]

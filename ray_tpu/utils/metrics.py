@@ -303,6 +303,10 @@ LLM_MODEL_STATS = {
         "rt_llm_sparse_kv_positions_fetched_total",
         "K/V positions the attention fetched for them: whole pages walked, "
         "or the rows gathered"),
+    "ssm_updates": Counter(
+        "rt_llm_ssm_state_updates_total",
+        "state rows a decode step read and wrote: live slots x state-space "
+        "blocks"),
     "sparse_walk_blocks": Counter(
         "rt_llm_sparse_walk_blocks_total",
         "blocks of pages the indexer's and the selected walk fetched"),

@@ -387,6 +387,8 @@ PARTS = (
     "experts",         # the routed product and its weighted sum
     "indexer",         # the indexer's projections and scores
     "select",          # the top-k mask over the indexer's scores
+    "conv",            # a state-space block's depthwise convolution, its saved inputs
+    "ssm",             # dt, the recurrence in either form, the D skip, the gated norm
     "weights_concat",  # wq|wk|wv and w_gate|w_up joined in the fused branches
     "head",            # final norm and logits
     "sample",          # the sampling tail, rng

@@ -472,3 +472,102 @@ def test_sparse_moe_prefill_batch_compiles(one_chip, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9
     assert len(re.findall(_RAGGED_DOT, text)) == 3 * cfg.n_layers
     assert not re.findall(_SWIGLU, text)
+
+
+# ------------------------------ state-space blocks beside attention and experts
+_POOL_COPY = (r"= (?:f32\[8,129,64,64,128\]|bf16\[8,129,18432\]|"
+              r"bf16\[2,20000,16,2,128\])\S* copy\(")
+
+
+def _ssm_moe_args(one_chip):
+    """NVIDIA-Nemotron-3-Nano-30B-A3B at its published widths (64 x 64
+    Mamba-2 heads on 8 groups of state 128, 32 query heads on 2 KV heads, 16
+    held two-matrix experts of 1856), the cell's 18 blocks, 129 state rows
+    and 20,000 K/V pages."""
+    from ray_tpu.llm.ssm_moe import make_pools
+    from ray_tpu.models.ssm_moe import SsmMoeConfig, ssm_moe_init
+
+    cfg = SsmMoeConfig(vocab_size=16384, pattern="MEMEM*EMEMEM*EMEME",
+                       max_seq_len=4096, experts_held=(0, 16),
+                       vocab_held=(0, 16384))
+    params = one_chip(jax.eval_shape(
+        lambda: ssm_moe_init(jax.random.PRNGKey(0), cfg)))
+    cache = one_chip(jax.eval_shape(
+        lambda: make_pools(cfg, 16, {"kv": 20000, "state": 129}, None)))
+    assert cache[2].shape == (8, 129, 64, 64, 128) and cache[2].dtype == jnp.float32
+    assert cache[3].shape == (8, 129, 3 * 6144)   # the conv rows lie flat
+    return cfg, params, cache, one_chip(_shape((2,), jnp.uint32))
+
+
+def test_ssm_moe_decode_multi_compiles(one_chip, monkeypatch):
+    """128 slots a step: the two attention blocks read their pages where
+    they lie (2 KV heads, 16 query heads each), every expert block applies
+    its 16 held two-matrix experts to all 128 tokens in one batched product
+    (no sort, no ``ragged_dot``, no streamed kernel), and each Mamba-2
+    block's state pool is updated where it lies — one fused in-place update
+    a block, no gathered ``[128, 64, 64, 128]`` rows, no loop over slots —
+    with no copy of a whole state or K/V pool on entry or exit."""
+    from ray_tpu.llm.programs import MOE_STATS
+    from ray_tpu.llm.ssm_moe import ssm_moe_decode_multi
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    ssm_moe_decode_multi.clear_cache()
+    cfg, params, cache, key = _ssm_moe_args(one_chip)
+    B = 128
+    i32 = one_chip(_shape((B,), jnp.int32))
+    tables = (one_chip(_shape((B, 256), jnp.int32)),
+              one_chip(_shape((B, 1), jnp.int32)))
+    try:
+        lowered = ssm_moe_decode_multi.lower(
+            params, None, i32, i32, i32, tables, *cache,
+            one_chip(_shape((B,), jnp.bool_)),
+            one_chip(_shape((B,), jnp.float32)), key, cfg=cfg, n_steps=8)
+        compiled = lowered.compile()
+    finally:
+        ssm_moe_decode_multi.clear_cache()
+    assert lowered.out_info[0].shape == (8, B + len(MOE_STATS) + 1)
+    text = compiled.as_text()
+    assert len(re.findall(r"%_paged_decode_attention\S* = \S+ custom-call\(",
+                          text)) == 2
+    assert not re.findall(_RAGGED_DOT, text)
+    assert not re.findall(_SWIGLU, text)
+    assert not re.findall(_POOL_COPY, text)
+    # no table of K/V rows gathered out of a pool, no state rows gathered
+    # by slot, and the scan over the steps is the program's only loop
+    assert not re.findall(r"bf16\[128,(?:256|4096),(?:16,)?2,128\]", text)
+    assert not re.findall(r"f32\[128,64,64,128\]", text)
+    assert len(re.findall(r" while\(", text)) == 1
+    # 86 MB: the step's activations; no state row leaves its pool
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
+
+
+def test_ssm_moe_prefill_batch_compiles(one_chip, monkeypatch):
+    """The cell's largest wave, 8 prompts of 2,048: sixteen chunks of 128 a
+    Mamba-2 block, the blocked attention kernel in the two attention blocks,
+    two ``ragged_dot`` calls an expert block, one state row a prompt a
+    Mamba-2 block written in place."""
+    from ray_tpu.llm.ssm_moe import ssm_moe_prefill_batch
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    ssm_moe_prefill_batch.clear_cache()
+    cfg, params, cache, key = _ssm_moe_args(one_chip)
+    N, Tp = 8, 2048
+    pages = (one_chip(_shape((N, Tp // 16), jnp.int32)),
+             one_chip(_shape((N, 1), jnp.int32)))
+    try:
+        compiled = ssm_moe_prefill_batch.lower(
+            params, None, one_chip(_shape((N,), jnp.int32)),
+            one_chip(_shape((N, Tp), jnp.int32)), pages, *cache,
+            one_chip(_shape((N,), jnp.int32)),
+            one_chip(_shape((N,), jnp.float32)), key, cfg=cfg).compile()
+    finally:
+        ssm_moe_prefill_batch.clear_cache()
+    text = compiled.as_text()
+    assert len(re.findall(r"%gqa_prefill_attention\S* = \S+ custom-call\(",
+                          text)) == 2
+    assert len(re.findall(_RAGGED_DOT, text)) == 2 * 8
+    assert not re.findall(_SWIGLU, text)
+    assert not re.findall(_POOL_COPY, text)
+    # the in-projection of 16,384 tokens, the chunks' decay blocks and
+    # states in float32: 2.94 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.4e9
