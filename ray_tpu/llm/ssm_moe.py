@@ -15,14 +15,16 @@ kinds of which ONE DOES NOT GROW, and a block a pattern character.
   the junk row, as page 0 is the junk page: dead decode slots and a wave's
   dummy prompts write there. The conv rows lie flat, ``[R, (K - 1) . C]``: a
   second-minor axis of 3 would pad to a whole sublane tile on the device.
-* **Decode** advances each live slot's row one position a step by
-  ``ops/ssm.py``'s one-step forms — XLA operations, exact. The state is
-  updated where it lies: the step's small inputs are laid out by row, every
-  row takes the update (``dt`` 0 where no live slot owns it: unchanged), and
-  only the outputs come back by slot (``_mamba_step``). Attention reads its
-  pages where they lie (``ops/paged_attention.py``); off the TPU the
-  gathered table with a position mask (``_reads_in_place``, as the other
-  families).
+* **Decode** advances each live slot's row one position a step, exactly.
+  The state is updated where it lies: the step's small inputs are laid out
+  by row, every row takes the update (``dt`` 0 where no live slot owns it:
+  unchanged), and only the outputs come back by slot (``_mamba_step``). On
+  a TPU that is ONE pass over a block's pool — ``ops/ssm_pool.py``'s kernel
+  reads a row, advances it, reads it out and writes it; anywhere else
+  ``ops/ssm.py``'s one-step form, plain XLA operations and the kernel's
+  reference. Attention likewise reads its pages where they lie
+  (``ops/paged_attention.py``) or, off the TPU, the gathered table with a
+  position mask: one switch, ``_reads_in_place``, as the other families.
 * **Prefill** is whole-prompt per pad bucket: the chunked scan from a zero
   state (a reused row is overwritten, never read), blocked attention over
   the fresh keys, and the state written **at each prompt's true length** —
@@ -58,6 +60,7 @@ from ray_tpu.models.ssm_moe import (
 from ray_tpu.ops import ssm
 from ray_tpu.ops.paged_attention import paged_decode_attention
 from ray_tpu.ops.prefill_attention import blocks_for, gqa_prefill_attention
+from ray_tpu.ops.ssm_pool import ssm_pool_step
 from ray_tpu.utils import tracing
 
 # the most prompts and tokens one prefill program may hold, as the other
@@ -98,13 +101,15 @@ def _mamba_step(layer, x, j, row, live, states, convs, cfg: SsmMoeConfig):
     blocks) for every slot, through the slots' rows. x: [B, 1, D]; row: [B]
     int32 (0, the junk row, for a slot that is not ``live``). The state pool
     is updated WHERE IT LIES: the step's small inputs (x, dt, B, C of a
-    slot) are laid out by row, every row of the block's pool takes one
-    elementwise update — a row of no live slot has ``dt`` 0, which decays
-    nothing and adds nothing, so it stays bit for bit — and only the
-    outputs are gathered back by slot. A gather of the rows, the update and
-    a scatter back moved the state six times a step and took 3.4 ms a block
-    at 128 slots; this moves it twice (PERF.md section 6, PR 38). Returns
-    (y [B, 1, D], states, convs)."""
+    slot) are laid out by row, every row of the block's pool takes the
+    update — a row of no live slot has ``dt`` 0, which decays nothing and
+    adds nothing, so it stays bit for bit — and only the outputs are
+    gathered back by slot. A gather of the rows, the update and a scatter
+    back moved the state six times a step and took 3.4 ms a block at 128
+    slots; the plain form in place moves it twice and reads it a third time
+    for ``y`` (1.19 ms); ``ssm_pool_step`` reads it once and writes it once
+    (0.83 ms: PERF.md section 6, PRs 38 and 39). Returns (y [B, 1, D],
+    states, convs)."""
     B, R = x.shape[0], states.shape[1]
     z, u, dt = mamba_in(layer, x, cfg)
     with tracing.part("conv"):
@@ -119,10 +124,13 @@ def _mamba_step(layer, x, j, row, live, states, convs, cfg: SsmMoeConfig):
             return jnp.zeros((R,) + a.shape[1:], a.dtype).at[row].set(a)
 
         xs, Bm, Cm = split_conv(xbc, cfg)
-        S, y = ssm.ssm_step(
-            states[j], by_row(xs), by_row(mamba_dt(layer, dt[:, 0])),
-            mamba_decay(layer), by_row(Bm), by_row(Cm), layer["D"])
-        states = states.at[j].set(S)
+        step = (by_row(xs), by_row(mamba_dt(layer, dt[:, 0])),
+                mamba_decay(layer), by_row(Bm), by_row(Cm), layer["D"])
+        if _reads_in_place():
+            states, y = ssm_pool_step(states, j, *step)
+        else:
+            S, y = ssm.ssm_step(states[j], *step)
+            states = states.at[j].set(S)
         y = gated_norm(layer, y[row], z[:, 0], cfg, x.dtype)
     return mixer_out(layer, y, "out_proj")[:, None], states, convs
 

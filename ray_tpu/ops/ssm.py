@@ -13,6 +13,9 @@ exponentials of their differences and the state in float32 and every
 float32 product at the highest precision. Nothing is dropped below a
 threshold and no history is cut. A position whose ``dt`` is 0 decays nothing
 and adds nothing: that is how a caller keeps padding out of a state.
+``ssm_step`` is also the reference of ``ops/ssm_pool.py``'s kernel, which is
+what a decode step on a TPU runs in its place: the same step over a whole
+pool's block, each row read once and written once.
 """
 
 from __future__ import annotations

@@ -479,41 +479,35 @@ _POOL_COPY = (r"= (?:f32\[8,129,64,64,128\]|bf16\[8,129,18432\]|"
               r"bf16\[2,20000,16,2,128\])\S* copy\(")
 
 
-def _ssm_moe_args(one_chip):
+def _ssm_moe_args(one_chip, pattern="MEMEM*EMEMEM*EMEME", kv=20000, state=129):
     """NVIDIA-Nemotron-3-Nano-30B-A3B at its published widths (64 x 64
     Mamba-2 heads on 8 groups of state 128, 32 query heads on 2 KV heads, 16
-    held two-matrix experts of 1856), the cell's 18 blocks, 129 state rows
-    and 20,000 K/V pages."""
+    held two-matrix experts of 1856); by default the cell's 18 blocks, 129
+    state rows and 20,000 K/V pages."""
     from ray_tpu.llm.ssm_moe import make_pools
-    from ray_tpu.models.ssm_moe import SsmMoeConfig, ssm_moe_init
+    from ray_tpu.models.ssm_moe import MAMBA, SsmMoeConfig, ssm_moe_init
 
-    cfg = SsmMoeConfig(vocab_size=16384, pattern="MEMEM*EMEMEM*EMEME",
-                       max_seq_len=4096, experts_held=(0, 16),
-                       vocab_held=(0, 16384))
+    cfg = SsmMoeConfig(vocab_size=16384, pattern=pattern, max_seq_len=4096,
+                       experts_held=(0, 16), vocab_held=(0, 16384))
+    n_m = len(cfg.blocks_of(MAMBA))
     params = one_chip(jax.eval_shape(
         lambda: ssm_moe_init(jax.random.PRNGKey(0), cfg)))
     cache = one_chip(jax.eval_shape(
-        lambda: make_pools(cfg, 16, {"kv": 20000, "state": 129}, None)))
-    assert cache[2].shape == (8, 129, 64, 64, 128) and cache[2].dtype == jnp.float32
-    assert cache[3].shape == (8, 129, 3 * 6144)   # the conv rows lie flat
+        lambda: make_pools(cfg, 16, {"kv": kv, "state": state}, None)))
+    assert cache[2].shape == (n_m, state, 64, 64, 128)
+    assert cache[2].dtype == jnp.float32
+    assert cache[3].shape == (n_m, state, 3 * 6144)   # the conv rows lie flat
     return cfg, params, cache, one_chip(_shape((2,), jnp.uint32))
 
 
-def test_ssm_moe_decode_multi_compiles(one_chip, monkeypatch):
-    """128 slots a step: the two attention blocks read their pages where
-    they lie (2 KV heads, 16 query heads each), every expert block applies
-    its 16 held two-matrix experts to all 128 tokens in one batched product
-    (no sort, no ``ragged_dot``, no streamed kernel), and each Mamba-2
-    block's state pool is updated where it lies — one fused in-place update
-    a block, no gathered ``[128, 64, 64, 128]`` rows, no loop over slots —
-    with no copy of a whole state or K/V pool on entry or exit."""
-    from ray_tpu.llm.programs import MOE_STATS
+def _ssm_moe_decode(one_chip, monkeypatch, args, B, n_steps):
+    """(lowered, compiled) decode program of ``args`` for ``B`` slots, as a
+    TPU's backend would choose its forms."""
     from ray_tpu.llm.ssm_moe import ssm_moe_decode_multi
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     ssm_moe_decode_multi.clear_cache()
-    cfg, params, cache, key = _ssm_moe_args(one_chip)
-    B = 128
+    cfg, params, cache, key = args
     i32 = one_chip(_shape((B,), jnp.int32))
     tables = (one_chip(_shape((B, 256), jnp.int32)),
               one_chip(_shape((B, 1), jnp.int32)))
@@ -521,10 +515,27 @@ def test_ssm_moe_decode_multi_compiles(one_chip, monkeypatch):
         lowered = ssm_moe_decode_multi.lower(
             params, None, i32, i32, i32, tables, *cache,
             one_chip(_shape((B,), jnp.bool_)),
-            one_chip(_shape((B,), jnp.float32)), key, cfg=cfg, n_steps=8)
-        compiled = lowered.compile()
+            one_chip(_shape((B,), jnp.float32)), key, cfg=cfg,
+            n_steps=n_steps)
+        return lowered, lowered.compile()
     finally:
         ssm_moe_decode_multi.clear_cache()
+
+
+def test_ssm_moe_decode_multi_compiles(one_chip, monkeypatch):
+    """128 slots a step: the two attention blocks read their pages where
+    they lie (2 KV heads, 16 query heads each), every expert block applies
+    its 16 held two-matrix experts to all 128 tokens in one batched product
+    (no sort, no ``ragged_dot``, no streamed kernel), and each Mamba-2
+    block's state pool is updated where it lies — one ``ssm_pool_step`` call
+    a block (the test below), no gathered ``[128, 64, 64, 128]`` rows, no
+    loop over slots — with no copy of a whole state or K/V pool on entry or
+    exit."""
+    from ray_tpu.llm.programs import MOE_STATS
+
+    B = 128
+    lowered, compiled = _ssm_moe_decode(
+        one_chip, monkeypatch, _ssm_moe_args(one_chip), B, 8)
     assert lowered.out_info[0].shape == (8, B + len(MOE_STATS) + 1)
     text = compiled.as_text()
     assert len(re.findall(r"%_paged_decode_attention\S* = \S+ custom-call\(",
@@ -537,8 +548,34 @@ def test_ssm_moe_decode_multi_compiles(one_chip, monkeypatch):
     assert not re.findall(r"bf16\[128,(?:256|4096),(?:16,)?2,128\]", text)
     assert not re.findall(r"f32\[128,64,64,128\]", text)
     assert len(re.findall(r" while\(", text)) == 1
-    # 86 MB: the step's activations; no state row leaves its pool
+    # 75 MB: the step's activations; no state row leaves its pool
     assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
+
+
+def test_ssm_moe_decode_moves_the_state_in_one_pass(one_chip, monkeypatch):
+    """The decode program at a reduced pool (33 rows, 32 slots, one period
+    of the pattern): inside the part ``ssm`` ONE operation a Mamba-2 block
+    takes the state pool — the ``ssm_pool_step`` kernel, which hands the
+    pool back in the buffer it came in — with no plain in-place update or
+    read-out fusion beside it and no copy of the pool around the calls: an
+    alias that did not hold would show as one."""
+    from ray_tpu.utils import tracing
+
+    args = _ssm_moe_args(one_chip, pattern="MEMEM*EME", kv=2000, state=33)
+    text = _ssm_moe_decode(one_chip, monkeypatch, args, 32, 4)[1].as_text()
+    rows = [r for c in tracing.program_instructions(text)[1] for r in c]
+    shape = {name: key.split("|")[1] for name, key, *_ in rows}
+    pool = "f32[%d,%d,%d,%d,%d]" % args[2][2].shape
+    # whatever reads or writes a pool: a kernel call, a fusion, a copy
+    moves = [(name, opcode, op) for name, _, opcode, op, operands in rows
+             if opcode not in ("get-tuple-element", "tuple", "while", "bitcast")
+             and any(shape.get(o) == pool for o in operands)]
+    assert len(moves) == args[2][2].shape[0], moves
+    for name, opcode, op in moves:
+        assert opcode == "custom-call" and name.startswith("ssm_pool_step")
+        assert tracing.op_part(op) == "ssm", op
+        call, = re.findall(rf"%{re.escape(name)} = .*", text)
+        assert "output_to_operand_aliasing={{0}: (2, {})}" in call
 
 
 def test_ssm_moe_prefill_batch_compiles(one_chip, monkeypatch):

@@ -23,6 +23,7 @@ from ray_tpu.models.ssm_moe import (ATTENTION, EXPERTS, MAMBA, SsmMoeConfig,
                                     ssm_moe_forward, ssm_moe_init)
 from ray_tpu.ops import ssm
 from ray_tpu.ops.paged_attention import paged_decode_attention
+from ray_tpu.ops.ssm_pool import ssm_pool_step
 from ray_tpu.parallel import moe
 from ray_tpu.parallel.moe import (expert_passes, routed_experts,
                                   sigmoid_topk_route)
@@ -565,6 +566,118 @@ def test_paged_decode_attention_at_two_kv_heads_and_sixteen_a_group():
             s = k[:, h // G] @ np.asarray(q)[b, h] / np.sqrt(hd)
             p = np.exp(s - s.max())
             assert np.abs(got[b, h] - (p / p.sum()) @ v[:, h // G]).max() < 2e-5
+
+
+def _pool_inputs(L, R, H, P, N, G, owned, seed=0, dtype=jnp.float32):
+    """A state pool and one step's inputs laid out by row, as ``_mamba_step``
+    hands them over: rows outside ``owned`` carry ``dt`` 0 and zeros."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 7)
+    keep = np.zeros(R, bool)
+    keep[list(owned)] = True
+
+    def by_row(a):
+        return jnp.where(keep.reshape((R,) + (1,) * (a.ndim - 1)), a, 0)
+
+    return (jax.random.normal(ks[0], (L, R, H, P, N), jnp.float32),
+            by_row(jax.random.normal(ks[1], (R, H, P), dtype)),
+            by_row(jax.random.uniform(ks[2], (R, H), jnp.float32, 0.001, 0.1)),
+            -jax.random.uniform(ks[3], (H,), jnp.float32, 1.0, 16.0),
+            by_row(jax.random.normal(ks[4], (R, G, N), dtype)),
+            by_row(jax.random.normal(ks[5], (R, G, N), dtype)),
+            jax.random.normal(ks[6], (H,), jnp.float32))
+
+
+@pytest.mark.parametrize("owned", [range(5), (1, 3), ()],
+                         ids=["all", "some", "none"])
+@pytest.mark.parametrize("H,P,N,G,dtype", [
+    (16, 8, 16, 2, jnp.float32),      # the tiny configuration's
+    (8, 8, 128, 2, jnp.bfloat16),     # a state as wide as the lanes
+    (4, 16, 128, 4, jnp.bfloat16),    # a head a group
+    (6, 32, 128, 2, jnp.bfloat16),    # four heads fill the lanes, 6 % 4 != 0
+], ids=["tiny", "lanes", "head-a-group", "heads-not-a-tile"])
+def test_the_pool_step_kernel_is_the_plain_step(H, P, N, G, dtype, owned):
+    """``ssm_pool_step`` in the interpreter against ``ssm.ssm_step`` on one
+    block of a pool of three: every row live, some rows unowned (``dt`` 0),
+    all of them unowned."""
+    pool, *step = _pool_inputs(3, 5, H, P, N, G, owned, dtype=dtype)
+    want_S, want_y = ssm.ssm_step(pool[1], *step)
+    got, y = ssm_pool_step(pool, 1, *step, interpret=True)
+    assert y.shape == want_y.shape and y.dtype == jnp.float32
+    assert float(jnp.abs(got[1] - want_S).max()) <= 1e-6 * float(
+        jnp.abs(want_S).max())
+    assert float(jnp.abs(y - want_y).max()) <= 1e-6 * max(
+        float(jnp.abs(want_y).max()), 1.0)
+    assert jnp.array_equal(got[0], pool[0]) and jnp.array_equal(got[2], pool[2])
+
+
+def test_the_pool_step_kernel_leaves_unowned_rows_and_other_blocks_bit_for_bit():
+    """A row whose ``dt`` is 0 — the junk row, a row no live slot owns —
+    comes back as it entered (``array_equal``), whatever block is stepped,
+    the block's index traced or not; the pool's other blocks are not
+    touched; an unowned row's ``y`` is zero."""
+    owned = np.array([2, 4])
+    unowned = np.array([0, 1, 3, 5])
+    pool, *step = _pool_inputs(4, 6, 8, 8, 128, 2, owned, seed=1)
+    traced = jax.jit(lambda p, j: ssm_pool_step(p, j, *step, interpret=True))
+    for j, call in ((0, traced), (3, traced), (3, lambda p, j: ssm_pool_step(
+            p, j, *step, interpret=True))):
+        got, y = (np.asarray(a) for a in call(pool, j))
+        assert np.array_equal(got[j, unowned], pool[j, unowned])
+        assert not y[unowned].any()
+        others = np.array([b for b in range(4) if b != j])
+        assert np.array_equal(got[others], pool[others])
+        assert not np.array_equal(got[j, 2], pool[j, 2])
+        assert rel(got[j, owned], ssm.ssm_step(pool[j], *step)[0][owned]) < 1e-6
+
+
+def test_decode_through_the_pool_step_kernel_is_the_plain_decode(monkeypatch):
+    """Two blocks of four steps with the in-place forms interpreted (the
+    ``_reads_in_place`` switch patched) against the plain forms: all three
+    slots live, then one of them dead from the second block on — its steps
+    go to the junk row. The tokens, the states and what the live slots hold
+    of the other pools agree; the junk row's state stays bit for bit."""
+    eng = _engine()
+    B = eng.B
+    rng = np.random.default_rng(4)
+    kp, vp, states, convs = eng.cache
+    start = (kp, vp, jnp.asarray(rng.normal(size=states.shape), states.dtype),
+             jnp.asarray(rng.normal(size=convs.shape), convs.dtype))
+    tables = (jnp.asarray(rng.permutation(np.arange(1, 37)).reshape(B, 12),
+                          jnp.int32),
+              jnp.asarray([[2], [1], [3]], jnp.int32))
+
+    def two_blocks():
+        programs.ssm_moe_decode_multi.clear_cache()
+        tok, pos = jnp.asarray([7, 9, 11], jnp.int32), jnp.asarray(
+            [5, 8, 17], jnp.int32)
+        cache, rows = tuple(jnp.copy(a) for a in start), []
+        for active in ([True, True, True], [True, False, True]):
+            out, tok, pos, *cache = programs.ssm_moe_decode_multi(
+                eng.params, None, jnp.zeros(B, jnp.int32), tok, pos, tables,
+                *cache, jnp.asarray(active), jnp.zeros(B),
+                jax.random.PRNGKey(0), cfg=CFG, n_steps=4)
+            rows.append(np.asarray(out))
+        programs.ssm_moe_decode_multi.clear_cache()
+        return np.concatenate(rows), cache
+
+    want_rows, want = two_blocks()
+    monkeypatch.setattr(programs, "_reads_in_place", lambda: True)
+    calls = []
+    monkeypatch.setattr(programs, "ssm_pool_step", lambda *a: (
+        calls.append(a[1]), ssm_pool_step(*a))[1])
+    got_rows, got = two_blocks()
+    assert calls == list(range(N_M))                 # once a block a trace
+    assert np.array_equal(got_rows, want_rows)       # tokens and stats
+    assert got_rows[:, :B].any() and not got_rows[4:, 1].any()
+    live = np.asarray(tables[0])[[0, 2]].ravel()     # a dead slot's K/V page,
+    for a, b in zip(got[:2], want[:2]):              # as the junk conv row,
+        assert rel(a[:, live], b[:, live]) < 1e-5    # holds what nobody reads
+    assert rel(got[2], want[2]) < 1e-5 and rel(got[3][:, 1:], want[3][:, 1:]) < 1e-5
+    for a in (got[2], want[2]):                      # the junk row's state
+        assert jnp.array_equal(a[:, 0], start[2][:, 0])
+    # the dead slot's row moved in the first block and not in the second
+    assert not jnp.array_equal(got[2][:, 1], start[2][:, 1])
+    assert rel(got[2][:, 1], want[2][:, 1]) < 1e-6
 
 
 # ---------------------------------------------------------------- refusals
