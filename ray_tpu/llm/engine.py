@@ -21,19 +21,19 @@ ideas onto XLA's static-shape world:
   on the q/v projections, selected per slot — different requests in one
   decode batch can use different adapters (adapter 0 = base model).
 * **The cache is the model's.** The engine owns slots, pages, tables,
-  admission, blocks and the loop; what a page HOLDS is declared by the
-  model family's programs (``llm/programs.py``: ``ServePrograms``, chosen
-  by the config's type): a K pool and a V pool for the Llama family
-  (``llm/llama.py``), one latent pool for ``llm/mla_moe.py``, two kinds of
-  K and V pools (window layers, full layers) for ``llm/cohere2_moe.py``. The
-  engine carries it as one tuple of pools (``self.cache``), hands it to every
-  program and takes it back donated. Where the family declares kinds of
-  pages (``ServePrograms.page_kinds``) the engine keeps a table and a free
-  list a kind and draws, for a slot of ``n`` positions, what the kind says
-  it holds: admission waits for whichever kind runs out. No device program
-  is defined here and
-  nothing here reads a weight: engine -> seam -> family programs ->
-  ``models/`` -> ``ops/``.
+  admission, blocks and the loop; what a page HOLDS is the model family's
+  (``llm/programs.py``: ``ServePrograms``, chosen by the config's type). It
+  is carried as one tuple of pools (``self.cache``), handed to every program
+  and taken back donated. Where the family declares kinds of pages
+  (``ServePrograms.page_kinds``) the engine keeps a table and a free list a
+  kind, and a kind's rows are one of three things (``PageKind``): a row a
+  POSITION (K and V, a latent, an indexer's key; the table as long as the
+  sequence, or a ring of one window's pages); a row a STRIDE of positions (a
+  chunk's pooled pair: a page of ``PS`` rows stands for ``PS * stride``
+  positions); or a row with NO positions (a recurrent state: one entry,
+  whatever the length). A slot of ``n`` positions draws what each kind says
+  it holds, and admission waits for whichever kind runs out. No device program
+  is defined here, nothing reads a weight: engine -> seam -> programs -> ``ops/``.
 """
 from __future__ import annotations
 
@@ -471,7 +471,7 @@ class ContinuousBatchingEngine:
     # ------------------------------------------------------------ internals
     def _pages_of(self, n: int) -> list[int]:
         """Pages of each kind that a slot of ``n`` positions holds."""
-        return [min(-(-n // self.PS), k.table) for k in self.kinds]
+        return [min(-(-n // (self.PS * k.stride)), k.table) for k in self.kinds]
 
     def _alloc_pages(self, n: int) -> list[list[int]] | None:
         """Draw a slot's pages for ``n`` positions, a list a kind, or
@@ -508,7 +508,7 @@ class ContinuousBatchingEngine:
             self._count_pages(i)
             if kind.reach is not None:  # a ring: the pages it wrote over
                 metrics.llm_window_pages_released_total.inc(
-                    max(0, -(-reached // self.PS) - kind.table))
+                    max(0, -(-reached // (self.PS * kind.stride)) - kind.table))
         self.seq_lens[slot] = 0
 
     def _free_slot(self, slot: int):
@@ -686,9 +686,10 @@ class ContinuousBatchingEngine:
                                **self._last_stats) as ph:
                 toks = np.zeros((nb, Tp_pad), np.int32)
                 # dummy rows: junk. A kind's table may be shorter than the
-                # prompt (a ring): its pages are then the whole table
-                pages = [np.zeros((nb, min(npages, k.table)), np.int32)
-                         for k in self.kinds]
+                # prompt (a ring): its pages are then the whole table; a
+                # page of a strided kind stands for ``stride`` pages' positions
+                pages = [np.zeros((nb, min(-(-npages // k.stride), k.table)),
+                                  np.int32) for k in self.kinds]
                 aids = np.zeros(nb, np.int32)
                 true_lens = np.ones(nb, np.int32)
                 temps = np.zeros(nb, np.float32)
@@ -889,7 +890,8 @@ class ContinuousBatchingEngine:
         for that (``rt_llm_decode_kv_tokens_{live,read}_total``), reckoned
         from each snapshot slot's length at the block's start: step k of a
         slot attends ``start + k + 1`` positions, or as many of them as a
-        layer of the kind reaches back; over kinds, the mean over layers. A
+        layer of the kind reaches back, counted in the kind's ROWS
+        (``_rows_within``); over kinds, the mean over layers. A
         kind that holds no positions (a slot's state: ``PageKind.positions``)
         is no part of either. A slot the planned loop
         has already handed on has its start from the request itself."""
@@ -903,11 +905,14 @@ class ContinuousBatchingEngine:
         kinds = [k for k in self.kinds if k.positions]
         layers = sum(k.layers for k in kinds)
         for kind in kinds:
-            reach = lens if kind.reach is None else np.minimum(lens, kind.reach)
+            reach = self._rows_within(kind, lens)
             within = int(self._attended(reach).sum())
             if self._kv_in_place:  # whole pages, from the first within reach
-                ends = np.minimum(lens, self.MAXP * self.PS)
-                pages = -(-ends // self.PS) - (lens - reach) // self.PS
+                # what the walk's table counts: positions, or a strided
+                # kind's rows from its first
+                span = lens if kind.stride == 1 else reach
+                ends = np.minimum(span, self.MAXP * self.PS)
+                pages = -(-ends // self.PS) - (span - reach) // self.PS
                 fetched = int(pages.sum()) * self.PS
             else:
                 fetched = K * self.B * kind.table * self.PS
@@ -1008,9 +1013,9 @@ class ContinuousBatchingEngine:
                         self._release_pages(
                             i, len(req.prompt) + max(req.planned, 1) - 1)
                         freed += 1
-                        if req.cancelled and not req.finished:
-                            # user-cancelled: no finish emission will ever
-                            # close this stream — close it here
+                        if req.cancelled:
+                            # user-cancelled, or finished while it still held
+                            # the slot: no later emission closes this stream
                             self._finish_stream(req)
                 ph.set(freed=freed)
             if self.waiting and any(r is None for r in self.slot_req):
@@ -1339,3 +1344,18 @@ class ContinuousBatchingEngine:
         PERF.md section 7)."""
         most = self.programs.attends_most
         return reach if most is None else np.minimum(reach, most(self.cfg))
+
+    @staticmethod
+    def _rows_within(kind: PageKind, lens):
+        """Of ``lens`` positions ([slots, steps], the query's own included),
+        how many ROWS of ``kind`` a step attends: every position; as many as
+        the kind reaches back from the query (a sliding window); or, where
+        the reach is ``aligned``, those since its last multiple (a kind of a
+        row a position) and one row a ``stride`` of the whole reaches before
+        it (a strided kind). Down here for ``_attended``'s reason."""
+        if kind.reach is None:
+            return lens
+        if not kind.aligned:
+            return np.minimum(lens, kind.reach)
+        whole = (lens - 1) // kind.reach * kind.reach
+        return lens - whole if kind.stride == 1 else whole // kind.stride

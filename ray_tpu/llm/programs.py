@@ -3,12 +3,14 @@ owns slots, pages, admission and the loops and knows no model: it asks
 ``serving_programs(cfg)`` for the family's ``ServePrograms`` by the config's
 TYPE and threads the family's cache through unseen. A family's program
 module (``llm/llama.py``, ``llm/mla_moe.py``, ``llm/cohere2_moe.py``,
-``llm/sparse_moe.py``, ``llm/ssm_moe.py``) imports this file, ``models/`` and
-``ops/``, never the engine, and is imported when its config is served.
+``llm/sparse_moe.py``, ``llm/ssm_moe.py``, ``llm/eva.py``) imports this file,
+``models/`` and ``ops/``, never the engine, and is imported when its config is
+served.
 A new family supplies a config type, its layer's halves in ``models/``, two
 jitted programs, a cache, and one branch of ``serving_programs``. What a slot
 caches is the family's: pages that grow with the sequence (K and V, a latent,
-a window's ring, an indexer's keys), or a state of fixed size.
+a window's ring, an indexer's keys), rows that each stand for a stride of
+positions (a chunk's pooled pair), or a state of fixed size.
 """
 from __future__ import annotations
 
@@ -18,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.cohere2_moe import Cohere2MoeConfig
+from ray_tpu.models.eva import EvaConfig
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.models.mla_moe import MlaMoeConfig
 from ray_tpu.models.sparse_moe import SparseMoeConfig
@@ -31,13 +34,30 @@ class UnsupportedByModel(NotImplementedError):
     refused by name (never a silent read of a pool that is not there)."""
 
     def __init__(self, feature: str, family: str):
+        caches = CACHES.get(family)
         super().__init__(
             f"{feature} is not supported for the {family!r} model family: "
             f"it is a program of the Llama family's (what a slot caches is "
             f"K and V pages of every layer, so a prefix of its pages is a "
             f"prefix of the sequence), and this family's programs have no "
-            f"such form")
+            f"such form" + (f" (a slot of it caches {caches})" if caches else ""))
         self.feature, self.family = feature, family
+
+
+# what a slot of each family caches where that is not K and V pages of every
+# layer: the reason a refusal gives
+CACHES = {
+    "mla_moe": "one latent row a position, keys and values in one pool",
+    "cohere2_moe": "full layers' pages and a ring of the window layers' "
+                   "pages, which holds the last window alone",
+    "sparse_moe": "K, V and the indexer's key pages, of which a step attends "
+                  "the rows it picks",
+    "ssm_moe": "K and V pages of its attention blocks and one state row of "
+               "its recurrent blocks, which holds no positions",
+    "eva": "a ring of exact K and V pages for its own window alone and one "
+           "pooled pair for every chunk before it: a prefix of its pages is "
+           "no prefix of the sequence",
+}
 
 
 @dataclass(frozen=True)
@@ -50,12 +70,21 @@ class PageKind:
     table shorter than the sequence is the family's ring, and a table of
     ONE entry a row that does not grow with the sequence at all — a
     recurrent layer's state. Such a kind holds no ``positions``: nothing
-    attends it, and the read counters leave it out."""
+    attends it, and the read counters leave it out. ``stride``: the positions
+    ONE ROW of a page stands for (1: a row a position; a chunk's pooled pair:
+    the chunk), so a slot holds ``ceil(n / (PS * stride))`` such pages.
+    ``aligned``: the reach is counted from its last multiple, not from the
+    query — position ``t`` attends the rows of positions ``[reach * (t //
+    reach), t]`` of a kind of stride 1 (whose ring is then exactly ``reach /
+    PS`` pages, dropped whole at the boundary), and of a strided kind the rows
+    of the WHOLE reaches before that, ``[0, reach * (t // reach))``."""
     name: str
     layers: int
     table: int
     reach: int | None = None
     positions: bool = True
+    stride: int = 1
+    aligned: bool = False
 
 
 # extra int32 columns of a decode step's token row of the families with
@@ -149,6 +178,10 @@ def serving_programs(cfg) -> ServePrograms:
         return PROGRAMS
     if isinstance(cfg, SsmMoeConfig):
         from ray_tpu.llm.ssm_moe import PROGRAMS
+
+        return PROGRAMS
+    if isinstance(cfg, EvaConfig):
+        from ray_tpu.llm.eva import PROGRAMS
 
         return PROGRAMS
     raise TypeError(f"no serving programs for a {type(cfg).__name__}")

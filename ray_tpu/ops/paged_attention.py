@@ -26,6 +26,10 @@ a block of pages that lie one after the other in the pool is one copy —
 which blocks those are is found from the table once a program,
 ``table_runs`` — and the pages land as the rows they are, read a whole
 register a load).
+And one more way to give a walk out: as a PART of a softmax that runs
+over more than one table (``paged_attention_part``: the plain walk or the
+ring's, with its running maximum and sum beside its output;
+``merge_attention_parts`` joins such parts exactly).
 
 One kernel invocation serves every slot: a work list of (slot, block) items,
 a block being ``n_pages`` pages (256 tokens; 512 of the latent pool), runs through two VMEM buffers — the
@@ -108,18 +112,20 @@ def block_rows(buf, cur):
 
 def _kernel(layer_ref, tables_ref, lengths_ref, *refs,
             sm_scale: float, n_pages: int, ring: bool = False,
-            select: bool = False):
+            select: bool = False, parts: bool = False):
     # refs: [the starts, where the table is a ring,] [the table's runs and the
     # positions picked, where the model picks them,] the queries, the pools
-    # (HBM), the output, a VMEM buffer a pool, the semaphores
+    # (HBM), the output [and, of a walk that is a PART of a softmax, its
+    # running maximum and sum], a VMEM buffer a pool, the semaphores
     if ring:
         starts_ref, *refs = refs
     if select:
         runs_ref, sel_ref, *refs = refs
     q_ref, *refs = refs
-    n_pools = (len(refs) - 2) // 2
+    n_outs = 3 if parts else 1
+    n_pools = (len(refs) - 1 - n_outs) // 2
     pools, o_ref = refs[:n_pools], refs[n_pools]
-    bufs, sems = refs[n_pools + 1:-1], refs[-1]
+    bufs, sems = refs[n_pools + n_outs:-1], refs[-1]
     B, H, lanes = q_ref.shape  # the rows' width, and what pads it in HBM
     PS, width = pools[0].shape[2], pools[0].shape[-1]
     KV = pools[0].shape[3] if len(pools[0].shape) == 5 else 1
@@ -295,6 +301,10 @@ def _kernel(layer_ref, tables_ref, lengths_ref, *refs,
             (jnp.full((H, 1), _NEG_BIG, jnp.float32),
              jnp.zeros((H, 1), jnp.float32),
              jnp.zeros((H, v_width), jnp.float32), buf))
+        if parts:  # a lane tile each: what joins this walk to another
+            m_ref, l_ref = refs[n_pools + 1:n_pools + 3]
+            m_ref[b] = jnp.broadcast_to(m, m_ref.shape[1:])
+            l_ref[b] = jnp.broadcast_to(l, l_ref.shape[1:])
         # a slot with no tokens ran no block: zeros over 1e-30 are zeros
         o_ref[b] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
         return buf
@@ -429,7 +439,7 @@ def _paged_latent_attention(q, pool, layer, page_tables, lengths, *,
 
 def _walk_pools(q, pools, layer, page_tables, lengths, *, v_width: int,
                 sm_scale: float, block_tokens: int, interpret: bool,
-                starts=None, selected=None, runs=None):
+                starts=None, selected=None, runs=None, parts: bool = False):
     """The one ``pallas_call`` every entry makes: the pools stay where they
     are (``pl.ANY``), a VMEM buffer of two blocks a pool. ``starts`` is one
     more scalar-prefetched array, and a ring table (``_kernel``);
@@ -437,7 +447,9 @@ def _walk_pools(q, pools, layer, page_tables, lengths, *, v_width: int,
     is one more input in VMEM: float 0 / 1 a ROW of the blocks (a
     position's pick repeated over its KV heads here, in XLA: a repeat that
     interleaves lanes is no vector operation of the kernel's), whole blocks
-    a slot — 8 MB at 32 slots of 16,384 positions of 4 KV heads."""
+    a slot — 8 MB at 32 slots of 16,384 positions of 4 KV heads. ``parts``:
+    the output in float32 and, beside it, the walk's running maximum and sum
+    ``[B, H, 128]`` (a lane tile each, every lane the same)."""
     B, H, width = q.shape
     PS, page = pools[0].shape[2], pools[0].shape[2:]
     MAXP = page_tables.shape[1]
@@ -474,16 +486,23 @@ def _walk_pools(q, pools, layer, page_tables, lengths, *, v_width: int,
     # at most, a pool: every slot's whole table
     window = B * MAXP * math.prod(page) * pools[0].dtype.itemsize
     out = jax.ShapeDtypeStruct((B, H, v_width), q.dtype)
+    outs = out
+    if parts:
+        kernel = functools.partial(kernel, parts=True)
+        out = jax.ShapeDtypeStruct((B, H, v_width), jnp.float32)
+        outs = [out] + [jax.ShapeDtypeStruct((B, H, 128), jnp.float32)] * 2
     return pl.pallas_call(
         kernel,
-        out_shape=out,
+        out_shape=outs,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3 + len(prefetch),
             grid=(1,),
             in_specs=[pl.BlockSpec(p.shape, lambda i, *_: (0, 0)) for p in picks]
             + [pl.BlockSpec(q.shape, lambda i, *_: (0, 0, 0))]
             + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
-            out_specs=pl.BlockSpec(out.shape, lambda i, *_: (0, 0, 0)),
+            out_specs=pl.BlockSpec(out.shape, lambda i, *_: (0, 0, 0))
+            if not parts else
+            [pl.BlockSpec(o.shape, lambda i, *_: (0, 0, 0)) for o in outs],
             scratch_shapes=[buf] * len(pools)
             + [pltpu.SemaphoreType.DMA((len(pools), 2))],
         ),
@@ -495,6 +514,66 @@ def _walk_pools(q, pools, layer, page_tables, lengths, *, v_width: int,
             transcendentals=B * H * MAXP * PS,
             bytes_accessed=len(pools) * window),
         interpret=interpret,
+        name=None if not parts else
+        "paged_attention_part" if starts is None else "paged_window_part",
     )(layer.reshape(1),
       page_tables.astype(jnp.int32), lengths.astype(jnp.int32), *prefetch,
       *picks, q, *pools)
+
+
+def paged_attention_part(q, kpool, vpool, layer, page_tables, lengths, *,
+                         starts=None, interpret: bool | None = None):
+    """``paged_decode_attention`` as ONE PART of a softmax that runs over
+    more than one table: the same walk (plain, or a ring's with ``starts``),
+    given out with what joins it to another — ``(o [B, H, hd] float32, the
+    walk's own normalised output; m [B, H] float32, its largest score; l [B,
+    H] float32, the sum of ``exp(score - m)`` over its rows)``. A slot with
+    no rows gives ``l = 0``. ``merge_attention_parts`` joins them exactly.
+    The block is as many BYTES as the plain walk's at 8 KV heads, whatever
+    the number of KV heads (64 tokens at 32)."""
+    H, KV = q.shape[1], kpool.shape[3]
+    if H % KV:
+        raise ValueError(f"{H} query heads do not group over {KV} KV heads")
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    layer = jnp.asarray(layer, jnp.int32)
+    if starts is None:
+        return _paged_attention_part(q, kpool, vpool, layer, page_tables,
+                                     lengths, interpret=bool(interpret))
+    return _paged_window_part(q, kpool, vpool, layer, page_tables, lengths,
+                              starts, interpret=bool(interpret))
+
+
+def _part(q, kpool, vpool, layer, page_tables, lengths, starts, interpret):
+    hd, PS, KV = q.shape[-1], kpool.shape[2], kpool.shape[3]
+    o, m, l = _walk_pools(
+        q, (kpool, vpool), layer, page_tables, lengths, v_width=hd,
+        sm_scale=1.0 / math.sqrt(hd), interpret=interpret, starts=starts,
+        block_tokens=max(PS, _BLOCK_TOKENS * 8 // KV), parts=True)
+    return o, m[..., 0], l[..., 0]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _paged_attention_part(q, kpool, vpool, layer, page_tables, lengths, *,
+                          interpret: bool):
+    """A jit of its own for the reason ``_paged_decode_attention`` is one."""
+    return _part(q, kpool, vpool, layer, page_tables, lengths, None, interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _paged_window_part(q, kpool, vpool, layer, page_tables, lengths, starts,
+                       *, interpret: bool):
+    """A jit of its own for the reason ``_paged_decode_attention`` is one."""
+    return _part(q, kpool, vpool, layer, page_tables, lengths, starts,
+                 interpret)
+
+
+def merge_attention_parts(*parts):
+    """One softmax over the union of the rows that several walks ran over:
+    each ``(o, m, l)`` of ``paged_attention_part``. Exact — part i's rows
+    weigh ``l_i exp(m_i - max m)`` of the whole. Returns [B, H, hd] float32;
+    zeros where no part had a row."""
+    m = functools.reduce(jnp.maximum, (p[1] for p in parts))
+    w = [p[2] * jnp.exp(p[1] - m) for p in parts]
+    total = jnp.maximum(sum(w), 1e-30)
+    return sum(p[0] * (wi / total)[..., None] for p, wi in zip(parts, w))

@@ -199,3 +199,151 @@ def _blocked(q, k, v, picked, n_kv_heads: int, window: int | None,
         name="gqa_prefill_attention" if picked is None
         else "gqa_picked_attention",
     )(q, k, v, *masks)
+
+
+# ------------------------------------------------- exact window + pooled pairs
+def eva_blocks_for(T: int, window: int) -> tuple[int, int] | None:
+    """``blocks_for`` where a block of queries must lie in ONE aligned window
+    and a window must be whole blocks of keys; None where it cannot."""
+    bq = next((b for b in (BLOCK_Q, 128) if T % b == 0 and window % b == 0), None)
+    bk = next((b for b in (BLOCK_K, 256, 128)
+               if T % b == 0 and window % b == 0), None)
+    return None if bq is None or bk is None else (bq, bk)
+
+
+def _eva_visits(i, block_q: int, block_k: int, window: int, pairs: int):
+    """Of the queries of block ``i``: the first and last block of exact keys
+    (from their window's start to the diagonal), and how many blocks of
+    ``_LANES`` pooled pairs — those of every earlier window — follow."""
+    w = i * block_q // window
+    return (w * (window // block_k), (i * block_q + block_q - 1) // block_k,
+            (w * pairs + _LANES - 1) // _LANES)
+
+
+def _eva_kernel(q_ref, k_ref, v_ref, kh_ref, vh_ref, o_ref, m_scr, l_scr,
+                acc_scr, *, sm_scale: float, window: int, pairs: int,
+                block_q: int, block_k: int):
+    i, j = pl.program_id(2), pl.program_id(3)
+    first, last, n_sum = _eva_visits(i, block_q, block_k, window, pairs)
+    n_win = last - first + 1
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, _NEG_BIG)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def update(k, v, ok):
+        s = jax.lax.dot_general(
+            q_ref[0], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale
+        s = jnp.where(ok, s, _NEG_BIG)
+        m_prev = m_scr[:, 0]
+        m_new = jnp.maximum(m_prev, s.max(axis=1))
+        p = jnp.where(ok, jnp.exp(s - m_new[:, None]), 0.0)
+        fix = jnp.exp(m_prev - m_new)
+        l_new = l_scr[:, 0] * fix + p.sum(axis=1)
+        acc_scr[...] = acc_scr[...] * fix[:, None] + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[...] = jnp.broadcast_to(m_new[:, None], m_scr.shape)
+        l_scr[...] = jnp.broadcast_to(l_new[:, None], l_scr.shape)
+
+    @pl.when(j < n_win)
+    def _exact():  # the window starts a block of keys: nothing before it comes
+        rows = i * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0)
+        cols = (first + j) * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
+        update(k_ref[0], v_ref[0], rows >= cols)
+
+    @pl.when(jnp.logical_and(j >= n_win, j - n_win < n_sum))
+    def _pooled():
+        c = (j - n_win) * _LANES + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, _LANES), 1)
+        update(kh_ref[0], vh_ref[0], c < i * block_q // window * pairs)
+
+    @pl.when(j == pl.num_programs(3) - 1)
+    def _finalize():
+        denom = jnp.maximum(l_scr[:, 0], 1e-30)
+        o_ref[0] = (acc_scr[...] / denom[:, None]).astype(o_ref.dtype)
+
+
+def eva_prefill_attention(q, k, v, kh, vh, *, n_heads: int, window: int,
+                          chunk: int, interpret: bool | None = None):
+    """Attention of every position of a prompt over the exact keys of its own
+    ALIGNED window (``window * (i // window) <= j <= i``) and the pooled pairs
+    of every chunk of every earlier window (``c < (window / chunk) * (i //
+    window)``), under one softmax; multi-head (a KV head a query head).
+
+    q, k, v: [N, T, H * hd]; kh, vh: [N, T // chunk, H * hd], chunk c's pair
+    at row c (pairs of chunks no query may see — the last window's, a pad's —
+    are never scored); hd a multiple of 128, T and the window whole blocks
+    (``eva_blocks_for``). Returns [N, T, H * hd] in q's dtype. A block of
+    queries visits the blocks of keys from its window's start to the
+    diagonal, then the blocks of 128 pairs it may see: nothing of an earlier
+    window's keys is fetched and no ``[T, T]`` array exists."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    return _eva_prefill_attention(
+        q, k, v, kh, vh, n_heads=int(n_heads), window=int(window),
+        chunk=int(chunk), interpret=bool(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "n_heads", "window", "chunk", "interpret"))
+def _eva_prefill_attention(q, k, v, kh, vh, *, n_heads: int, window: int,
+                           chunk: int, interpret: bool):
+    """A jit of its own for the reason ``_gqa_prefill_attention`` is one."""
+    N, T, HD = q.shape
+    H, hd, pairs = n_heads, HD // n_heads, window // chunk
+    bq, bk = eva_blocks_for(T, window)
+    # whole blocks of pairs: the rows added are never scored
+    short = -kh.shape[1] % _LANES
+    kh, vh = (jnp.pad(a, ((0, 0), (0, short), (0, 0))) for a in (kh, vh))
+    n_q = T // bq
+
+    # blocks of exact keys and of pairs that each block of queries visits
+    visits = [_eva_visits(i, bq, bk, window, pairs) for i in range(n_q)]
+    visits = [(last - first + 1, n_sum) for first, last, n_sum in visits]
+
+    def key_block(n, h, i, j):
+        first, last, _ = _eva_visits(i, bq, bk, window, pairs)
+        return n, jnp.minimum(first + j, last), h
+
+    def pair_block(n, h, i, j):
+        first, last, n_sum = _eva_visits(i, bq, bk, window, pairs)
+        return n, jnp.clip(j - (last - first + 1), 0,
+                           jnp.maximum(n_sum - 1, 0)), h
+
+    scored = sum((t % window + 1) + t // window * pairs for t in range(T))
+    return pl.pallas_call(
+        functools.partial(_eva_kernel, sm_scale=1.0 / math.sqrt(hd),
+                          window=window, pairs=pairs, block_q=bq, block_k=bk),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        grid=(N, H, n_q, max(a + b for a, b in visits)),
+        in_specs=[
+            pl.BlockSpec((1, bq, hd), lambda n, h, i, j: (n, i, h)),
+            pl.BlockSpec((1, bk, hd), key_block),
+            pl.BlockSpec((1, bk, hd), key_block),
+            pl.BlockSpec((1, _LANES, hd), pair_block),
+            pl.BlockSpec((1, _LANES, hd), pair_block),
+        ],
+        out_specs=pl.BlockSpec((1, bq, hd), lambda n, h, i, j: (n, i, h)),
+        scratch_shapes=[
+            pltpu.VMEM((bq, _LANES), jnp.float32),
+            pltpu.VMEM((bq, _LANES), jnp.float32),
+            pltpu.VMEM((bq, hd), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        cost_estimate=pl.CostEstimate(
+            flops=4 * N * H * hd * scored, transcendentals=N * H * scored,
+            bytes_accessed=2 * q.size * q.dtype.itemsize
+            + 2 * k.dtype.itemsize * N * H * hd * sum(
+                a * bk + b * _LANES for a, b in visits)),
+        interpret=interpret,
+        name="eva_prefill_attention",
+    )(q, k, v, kh, vh)

@@ -307,6 +307,9 @@ LLM_MODEL_STATS = {
         "rt_llm_ssm_state_updates_total",
         "state rows a decode step read and wrote: live slots x state-space "
         "blocks"),
+    "eva_pairs": Counter(
+        "rt_llm_eva_pairs_written_total",
+        "pooled key/value pairs a decode step wrote: chunks filled x layers"),
     "sparse_walk_blocks": Counter(
         "rt_llm_sparse_walk_blocks_total",
         "blocks of pages the indexer's and the selected walk fetched"),

@@ -389,6 +389,7 @@ PARTS = (
     "select",          # the top-k mask over the indexer's scores
     "conv",            # a state-space block's depthwise convolution, its saved inputs
     "ssm",             # dt, the recurrence in either form, the D skip, the gated norm
+    "summary",         # a chunk's keys and values pooled into its pair, and its write
     "weights_concat",  # wq|wk|wv and w_gate|w_up joined in the fused branches
     "head",            # final norm and logits
     "sample",          # the sampling tail, rng

@@ -608,3 +608,90 @@ def test_ssm_moe_prefill_batch_compiles(one_chip, monkeypatch):
     # the in-projection of 16,384 tokens, the chunks' decay blocks and
     # states in float32: 2.94 GB
     assert compiled.memory_analysis().temp_size_in_bytes < 3.4e9
+
+
+# ------------------------------ exact window rows beside pooled pairs, 32 KV heads
+_EVA_POOL_COPY = r"= bf16\[8,(?:3073|1300),16,32,128\]\S* copy\("
+
+
+def _eva_args(one_chip):
+    """EvaByte at its published widths (32 heads of 128 on as many KV heads,
+    SwiGLU 11008, 320 byte ids, 8 next-byte heads), the cell's 8 layers, 24
+    slots, 3,073 window pages and 1,300 pages of pairs of one page shape."""
+    from ray_tpu.llm.eva import make_pools, page_kinds
+    from ray_tpu.models.eva import EvaConfig, eva_init
+
+    cfg = EvaConfig(n_layers=8)
+    params = one_chip(jax.eval_shape(lambda: eva_init(jax.random.PRNGKey(0), cfg)))
+    cache = one_chip(jax.eval_shape(
+        lambda: make_pools(cfg, 16, {"window": 3073, "summary": 1300}, None)))
+    assert {c.shape for c in cache} == {(8, 3073, 16, 32, 128),
+                                        (8, 1300, 16, 32, 128)}
+    kinds = page_kinds(cfg, 16, 32768)
+    assert [(k.table, k.stride, k.aligned) for k in kinds] == [
+        (128, 1, True), (128, 16, True)]
+    return cfg, params, cache, kinds, one_chip(_shape((2,), jnp.uint32))
+
+
+def test_eva_decode_multi_compiles(one_chip, monkeypatch):
+    """24 slots a step: every layer walks the slot's ring of window pages and
+    its pages of pairs where they lie — two calls of the paged walk a layer,
+    each given out with its maximum and sum, at G = 1 on 32 KV heads — with
+    no table of rows gathered out of a pool and no copy of a whole pool on
+    entry or exit; the scan over the steps is the program's only loop."""
+    from ray_tpu.llm.eva import eva_decode_multi
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    eva_decode_multi.clear_cache()
+    cfg, params, cache, kinds, key = _eva_args(one_chip)
+    B = 24
+    i32 = one_chip(_shape((B,), jnp.int32))
+    tables = tuple(one_chip(_shape((B, k.table), jnp.int32)) for k in kinds)
+    try:
+        lowered = eva_decode_multi.lower(
+            params, None, i32, i32, i32, tables, *cache,
+            one_chip(_shape((B,), jnp.bool_)),
+            one_chip(_shape((B,), jnp.float32)), key, cfg=cfg, n_steps=8)
+        compiled = lowered.compile()
+    finally:
+        eva_decode_multi.clear_cache()
+    assert lowered.out_info[0].shape == (8, B + 1)   # tokens | eva_pairs
+    text = compiled.as_text()
+    for walk in ("paged_window_part", "paged_attention_part"):  # (o, m, l)
+        assert len(re.findall(rf"%{walk}\S* = \(f32\[24,32,128\]", text)) == 8
+    assert not re.findall(_EVA_POOL_COPY, text)
+    assert not re.findall(r"bf16\[24,2048,32,128\]", text)   # a gathered ring
+    assert len(re.findall(r" while\(", text)) == 1
+    # 0.83 GB: wq, wk, wv of every layer laid out once a program for 24 rows
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
+def test_eva_prefill_batch_compiles(one_chip, monkeypatch):
+    """The cell's longest prompt, 1 x 15,360: the blocked kernel over exact
+    rows and pairs in every layer, no ``[T, T]`` array, the rows and pairs
+    written in place a layer at a time (every layer's scatter put off to the
+    program's end kept 2 GB of keys and values alive: 3.66 GB of temporaries
+    where 1.32)."""
+    from ray_tpu.llm.eva import eva_prefill_batch
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    eva_prefill_batch.clear_cache()
+    cfg, params, cache, kinds, key = _eva_args(one_chip)
+    N, Tp = 1, 15360
+    pages = tuple(one_chip(_shape(
+        (N, min(-(-Tp // (16 * k.stride)), k.table)), jnp.int32)) for k in kinds)
+    assert [p.shape for p in pages] == [(1, 128), (1, 60)]
+    try:
+        compiled = eva_prefill_batch.lower(
+            params, None, one_chip(_shape((N,), jnp.int32)),
+            one_chip(_shape((N, Tp), jnp.int32)), pages, *cache,
+            one_chip(_shape((N,), jnp.int32)),
+            one_chip(_shape((N,), jnp.float32)), key, cfg=cfg).compile()
+    finally:
+        eva_prefill_batch.clear_cache()
+    text = compiled.as_text()
+    assert len(re.findall(r"%eva_prefill_attention\S* = \S+ custom-call\(",
+                          text)) == 8
+    assert not re.findall(_EVA_POOL_COPY, text)
+    assert not re.findall(r"f32\[(?:1,)?(?:32,)?15360,15360\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.6e9
