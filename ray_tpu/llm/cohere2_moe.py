@@ -44,7 +44,7 @@ from ray_tpu.models.cohere2_moe import (
     Cohere2MoeConfig, cohere2_attend_plain, cohere2_attn_out, cohere2_experts,
     cohere2_logits, cohere2_project, cohere2_reach, cohere2_rope_freqs)
 from ray_tpu.ops.basic import layer_norm
-from ray_tpu.ops.paged_attention import paged_decode_attention
+from ray_tpu.ops.paged_attention import paged_decode_attention, run_lengths
 from ray_tpu.ops.prefill_attention import blocks_for, gqa_prefill_attention
 from ray_tpu.utils import tracing
 
@@ -120,9 +120,10 @@ def _attend_gathered(q, kpool, vpool, table, pos, cfg, window: bool):
 
 
 def _decode_body(params, tokens, pos, tables, cache, active, temps, key,
-                 cfg: Cohere2MoeConfig):
-    """One decode step for every slot (masked where inactive). Returns
-    (next_tok [B], cache, stats)."""
+                 cfg: Cohere2MoeConfig, runs):
+    """One decode step for every slot (masked where inactive); ``runs``: the
+    two tables' ``run_lengths`` (None each where the kernel does not run).
+    Returns (next_tok [B], cache, stats)."""
     t_full, t_win = tables
     kf, vf, kw, vw = cache
     B, PS = tokens.shape[0], kf.shape[2]
@@ -156,7 +157,7 @@ def _decode_body(params, tokens, pos, tables, cache, active, temps, key,
             with tracing.part("attention"):
                 att = paged_decode_attention(
                     q[:, 0].astype(kp.dtype), kp, vp, j, table, lengths,
-                    starts=starts if window else None)
+                    starts=starts if window else None, runs=runs[window])
                 att = att.reshape(B, 1, -1).astype(x.dtype)
         else:
             att = _attend_gathered(q, kp[j], vp[j], table, pos, cfg, window)
@@ -182,11 +183,14 @@ def cohere2_moe_decode_multi(params, loras, aids, tokens, seq_lens, tables,
     ``ServePrograms.decode_multi`` with one table a kind (full, window) and
     four pools, rows of ``[B tokens | MOE_STATS]``. ``loras``/``aids`` are
     the engine's (None / zeros here: refused at construction)."""
+    # (full, window): found once a program, not a layer a step
+    runs = [run_lengths(t) if _reads_in_place() else None for t in tables]
+
     def step(carry, k):
         tok, pos, cache = carry
         nxt, cache, stats = _decode_body(
             params, tok, pos, tables, cache, active, temps,
-            jax.random.fold_in(key, k), cfg)
+            jax.random.fold_in(key, k), cfg, runs)
         return (nxt, pos + 1, cache), jnp.concatenate([nxt, stats])
 
     (tok, pos, cache), rows = jax.lax.scan(

@@ -56,7 +56,7 @@ from ray_tpu.models.eva import (
     EvaConfig, eva_attend_plain, eva_attn_out, eva_ffn, eva_logits,
     eva_pairs_seen, eva_project, eva_reach, eva_rope_freqs, eva_summarize)
 from ray_tpu.ops.paged_attention import (
-    merge_attention_parts, paged_attention_part)
+    merge_attention_parts, paged_attention_part, run_lengths)
 from ray_tpu.ops.prefill_attention import eva_blocks_for, eva_prefill_attention
 from ray_tpu.utils import tracing
 
@@ -127,9 +127,10 @@ def _attend_gathered(q, kw, vw, ks, vs, t_win, t_sum, pos, cfg: EvaConfig):
 
 
 def _decode_body(params, tokens, pos, tables, cache, active, temps, key,
-                 cfg: EvaConfig):
-    """One decode step for every slot (masked where inactive). Returns
-    (next_tok [B], cache, stats)."""
+                 cfg: EvaConfig, runs):
+    """One decode step for every slot (masked where inactive); ``runs``: the
+    two tables' ``run_lengths`` (None each where the kernel does not run).
+    Returns (next_tok [B], cache, stats)."""
     t_win, t_sum = tables
     kw, vw, ks, vs = cache
     B, PS = tokens.shape[0], kw.shape[2]
@@ -161,8 +162,9 @@ def _decode_body(params, tokens, pos, tables, cache, active, temps, key,
                 q1 = q[:, 0].astype(kw.dtype)
                 att = merge_attention_parts(
                     paged_attention_part(q1, kw, vw, i, t_win, lengths,
-                                         starts=starts),
-                    paged_attention_part(q1, ks, vs, i, t_sum, n_pairs))
+                                         starts=starts, runs=runs[0]),
+                    paged_attention_part(q1, ks, vs, i, t_sum, n_pairs,
+                                         runs=runs[1]))
                 att = att.reshape(B, 1, -1).astype(q.dtype)
         else:
             att = _attend_gathered(q, kw[i], vw[i], ks[i], vs[i], t_win,
@@ -191,11 +193,14 @@ def eva_decode_multi(params, loras, aids, tokens, seq_lens, tables, kw, vw,
     ``ServePrograms.decode_multi`` with one table a kind (window, summary)
     and four pools, rows of ``[B tokens | STATS]``. ``loras``/``aids`` are
     the engine's (None / zeros here: refused at construction)."""
+    # (window, summary): found once a program, not a layer a step
+    runs = [run_lengths(t) if _reads_in_place() else None for t in tables]
+
     def step(carry, k):
         tok, pos, cache = carry
         nxt, cache, stats = _decode_body(
             params, tok, pos, tables, cache, active, temps,
-            jax.random.fold_in(key, k), cfg)
+            jax.random.fold_in(key, k), cfg, runs)
         return (nxt, pos + 1, cache), jnp.concatenate([nxt, stats])
 
     (tok, pos, cache), rows = jax.lax.scan(

@@ -10,6 +10,10 @@ engine, beside the other family's (``llm/mla_moe.py``). Imports the seam
   fresh K and V under a causal mask (``paged_prefill_batch``), the
   table-ordered window (``paged_prefill_suffix``, speculative verify), the
   pool in place or the gathered window as ``_reads_in_place`` sees (decode).
+  In place, the walk takes table entries that lie one after the other in the
+  pool as ONE copy; which do is the table's alone, so ``paged_decode_multi``
+  finds it once (``run_lengths``, before the scan over its steps) and every
+  layer of every step hands it to the kernel.
 * **LoRA multiplex** (ref: serve/multiplex.py): stacked low-rank adapters on
   q and v, selected per slot (``make_lora_stack``; adapter 0 = base model).
 """
@@ -25,7 +29,7 @@ from ray_tpu.llm.programs import ServePrograms, _sample_tail
 from ray_tpu.models.llama import (
     LlamaConfig, llama_attn_out, llama_ffn, llama_project)
 from ray_tpu.ops.basic import rms_norm, rope_freqs
-from ray_tpu.ops.paged_attention import paged_decode_attention
+from ray_tpu.ops.paged_attention import paged_decode_attention, run_lengths
 from ray_tpu.utils import tracing
 
 
@@ -136,15 +140,18 @@ def _reads_in_place(pool) -> bool:
     see, no option: a plain pool on a TPU takes the kernel. An int8 pool
     keeps the window (the kernel does not dequantise); so does every other
     backend, where the kernel would be interpreted (seconds a call site to
-    trace, and nothing to gain); and a single KV head under 32 bits, whose
-    one-row page slice Mosaic refuses (tiling (2, 128))."""
+    trace, and nothing to gain); a single KV head under 32 bits, whose
+    one-row page slice Mosaic refuses (tiling (2, 128)); and a head that is
+    not whole lane tiles, whose rows lie padded in HBM (no run of pages is a
+    run of rows there)."""
     if isinstance(pool, dict) or jax.default_backend() != "tpu":
         return False
-    return pool.shape[3] > 1 or pool.dtype.itemsize >= 4
+    return (pool.shape[-1] % 128 == 0
+            and (pool.shape[3] > 1 or pool.dtype.itemsize >= 4))
 
 
 def _decode_body(params, loras, aids, tokens, pos, page_tables,
-                 kpool, vpool, active, temps, key, cfg: LlamaConfig):
+                 kpool, vpool, active, temps, key, cfg: LlamaConfig, runs):
     """One decode step for every slot (masked where inactive).
 
     tokens: [B] current input token; pos: [B] tokens already cached (the
@@ -157,7 +164,10 @@ def _decode_body(params, loras, aids, tokens, pos, page_tables,
     slot's ``pos + 1`` positions: in place, page by page through the table
     (``paged_decode_attention``; an inactive slot attends nothing) where
     ``_reads_in_place`` holds, else over ``_kv_read``'s whole window with
-    the positions past ``pos`` masked."""
+    the positions past ``pos`` masked. ``runs``: the table's ``run_lengths``
+    — which entries lie one after the other in the pool, so that the walk
+    takes them as one copy — made by the program once for all its steps and
+    layers (None where the kernel does not run)."""
     PS = _kv_shape(kpool)[2]
     MAXP = page_tables.shape[1]
     cos, sin = rope_freqs(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
@@ -181,7 +191,7 @@ def _decode_body(params, loras, aids, tokens, pos, page_tables,
         if in_place:
             with tracing.part("attention"):
                 att = paged_decode_attention(
-                    q[:, 0], kpool, vpool, i, page_tables, lengths)
+                    q[:, 0], kpool, vpool, i, page_tables, lengths, runs=runs)
         else:
             kb = _kv_read(kpool, i, page_tables, k.dtype)
             vb = _kv_read(vpool, i, page_tables, v.dtype)
@@ -217,11 +227,14 @@ def paged_decode_multi(params, loras, aids, tokens, seq_lens, page_tables,
     through the scan: the kernel reads them as operands and returns only
     the attended rows (tests/test_chip_compile.py holds the compiled
     program to no copy of a pool)."""
+    # the table is the program's: its runs are found once, not a layer a step
+    runs = run_lengths(page_tables) if _reads_in_place(kpool) else None
+
     def step(carry, k):
         tok, pos, kpool, vpool = carry
         nxt, kpool, vpool = _decode_body(
             params, loras, aids, tok, pos, page_tables, kpool, vpool,
-            active, temps, jax.random.fold_in(key, k), cfg)
+            active, temps, jax.random.fold_in(key, k), cfg, runs)
         return (nxt, pos + 1, kpool, vpool), nxt
 
     (tok, pos, kpool, vpool), toks = jax.lax.scan(

@@ -66,7 +66,8 @@ from ray_tpu.models.sparse_moe import (
     sparse_experts, sparse_index, sparse_logits, sparse_project,
     sparse_rope_freqs, sparse_select)
 from ray_tpu.ops.basic import rms_norm
-from ray_tpu.ops.paged_attention import paged_decode_attention, selected_runs
+from ray_tpu.ops.paged_attention import (
+    kv_block, paged_decode_attention, run_lengths, walk_copies)
 from ray_tpu.ops.paged_indexer import (
     index_runs, keys_per_row, pack_keys, paged_index_scores, unpack_keys)
 from ray_tpu.ops.prefill_attention import blocks_for, gqa_prefill_attention
@@ -101,22 +102,27 @@ def _reads_in_place() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _table_runs(tables, page_size: int):
-    """The runs of a program's page tables, for the indexer's walk and the
-    selected one — (flags [B, blocks], pages a block) each: made ONCE a
-    program, before the scan over its steps. Nothing where the kernels do
-    not run."""
+def _table_runs(tables, kpool):
+    """The runs of a program's page tables, for the indexer's walk —
+    (``index_runs``' flags [B, blocks], pages a block) — and the selected
+    one — (``run_lengths`` [B, MAXP], pages a block): made ONCE a program,
+    before the scan over its steps. Nothing where the kernels do not run."""
     if not _reads_in_place():
         return None
-    return index_runs(tables), selected_runs(tables, page_size)
+    return (index_runs(tables),
+            (run_lengths(tables), kv_block(kpool, tables.shape[1])[0]))
 
 
-def _walk_blocks(runs, n_pages: int, pages_live):
-    """(blocks walked, blocks fetched as one copy) by one walk of slots
+def _walk_blocks(runs, pages_live):
+    """(blocks walked, blocks fetched as one copy) by the two walks of slots
     holding ``pages_live`` [B] pages: a block is one copy where the table
-    says run (bit 0 of ``runs`` [B, blocks]) and all its pages hold tokens."""
-    whole = jnp.arange(runs.shape[1])[None, :] < (pages_live // n_pages)[:, None]
-    return (-(-pages_live // n_pages)).sum(), (whole * (runs & 1)).sum()
+    says run (bit 0 of the indexer's flags [B, blocks]; ``walk_copies`` of
+    the selected walk's) and all its pages hold tokens."""
+    (flags, n_pages), selected = runs
+    whole = jnp.arange(flags.shape[1])[None, :] < (pages_live // n_pages)[:, None]
+    copies = walk_copies(*selected, pages_live)
+    return ((-(-pages_live // n_pages)).sum() + copies[0],
+            (whole * (flags & 1)).sum() + copies[1])
 
 
 def _decode_body(params, tokens, pos, tables, cache, active, temps, key,
@@ -133,7 +139,7 @@ def _decode_body(params, tokens, pos, tables, cache, active, temps, key,
     lane_group = jnp.arange(ipool.shape[3]) // dk
     in_place = _reads_in_place()
     if in_place:
-        (index_flags, _), (selected_flags, _) = runs
+        (index_flags, _), (selected_runs, _) = runs
     lengths = jnp.where(active, pos + 1, 0)
     limit = jnp.where(active, pos, -1)  # a slot's last candidate position
     loads = []
@@ -168,7 +174,7 @@ def _decode_body(params, tokens, pos, tables, cache, active, temps, key,
             if in_place:
                 att = paged_decode_attention(
                     q[:, 0].astype(kpool.dtype), kpool, vpool, i, tables,
-                    lengths, selected=picked, runs=selected_flags
+                    lengths, selected=picked, runs=selected_runs
                 ).reshape(B, 1, -1).astype(x.dtype)
             else:
                 att = attend_plain(
@@ -188,8 +194,7 @@ def _decode_body(params, tokens, pos, tables, cache, active, temps, key,
     pages_live = -(-lengths // PS)
     if in_place:
         fetched = (pages_live * PS).sum()
-        walked = [sum(n) for n in zip(*(
-            _walk_blocks(*r, pages_live) for r in runs))]
+        walked = _walk_blocks(runs, pages_live)
     else:  # the gathered form: every slot's table, and no walk
         fetched, walked = jnp.asarray(B * MAXP * PS), [jnp.asarray(0)] * 2
     sparse = cfg.n_layers * jnp.stack([
@@ -210,7 +215,7 @@ def sparse_moe_decode_multi(params, loras, aids, tokens, seq_lens, tables,
     ``ServePrograms.decode_multi`` with three pools, rows of ``[B tokens |
     MOE_STATS | SPARSE_STATS]``. ``loras``/``aids`` are the engine's (None /
     zeros here: refused at construction)."""
-    runs = _table_runs(tables, kpool.shape[2])
+    runs = _table_runs(tables, kpool)
 
     def step(carry, k):
         tok, pos, cache = carry

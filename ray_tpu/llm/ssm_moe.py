@@ -25,6 +25,11 @@ kinds of which ONE DOES NOT GROW, and a block a pattern character.
   reference. Attention likewise reads its pages where they lie
   (``ops/paged_attention.py``) or, off the TPU, the gathered table with a
   position mask: one switch, ``_reads_in_place``, as the other families.
+  Which of a table's entries lie one after the other in the pool — what the
+  walk fetches as ONE copy, at 8 KB a page the difference between a sixth of
+  the bandwidth and most of it — is found once a program
+  (``ssm_moe_decode_multi``: ``run_lengths`` before the scan over the steps)
+  and counted (``walk_blocks`` / ``walk_run_blocks``).
 * **Prefill** is whole-prompt per pad bucket: the chunked scan from a zero
   state (a reused row is overwritten, never read), blocked attention over
   the fresh keys, and the state written **at each prompt's true length** —
@@ -58,7 +63,8 @@ from ray_tpu.models.ssm_moe import (
     gated_norm, mamba_decay, mamba_dt, mamba_in, mamba_mixer, mixer_out,
     split_conv, ssm_moe_logits)
 from ray_tpu.ops import ssm
-from ray_tpu.ops.paged_attention import paged_decode_attention
+from ray_tpu.ops.paged_attention import (
+    kv_block, paged_decode_attention, run_lengths, walk_copies)
 from ray_tpu.ops.prefill_attention import blocks_for, gqa_prefill_attention
 from ray_tpu.ops.ssm_pool import ssm_pool_step
 from ray_tpu.utils import tracing
@@ -66,7 +72,10 @@ from ray_tpu.utils import tracing
 # the most prompts and tokens one prefill program may hold, as the other
 # expert families
 WAVE_LIMIT = (8, 16384)
-STATS = MOE_STATS + ("ssm_updates",)
+# after MOE_STATS: state rows read and written, and the copies of the
+# attention blocks' walks — sub-runs of a block fetched, and those of them
+# that came as ONE copy or inside a block's — each over blocks and live slots
+STATS = MOE_STATS + ("ssm_updates", "walk_blocks", "walk_run_blocks")
 
 
 def page_kinds(cfg: SsmMoeConfig, page_size: int, max_seq_len: int):
@@ -136,9 +145,10 @@ def _mamba_step(layer, x, j, row, live, states, convs, cfg: SsmMoeConfig):
 
 
 def _decode_body(params, tokens, pos, tables, cache, active, temps, key,
-                 cfg: SsmMoeConfig):
-    """One decode step for every slot (masked where inactive). Returns
-    (next_tok [B], cache, stats)."""
+                 cfg: SsmMoeConfig, runs):
+    """One decode step for every slot (masked where inactive); ``runs`` is
+    the K/V table's ``run_lengths`` (None where the kernels do not run).
+    Returns (next_tok [B], cache, stats)."""
     t_kv, t_state = tables
     kp, vp, states, convs = cache
     B, PS = tokens.shape[0], kp.shape[2]
@@ -166,7 +176,8 @@ def _decode_body(params, tokens, pos, tables, cache, active, temps, key,
             if in_place:
                 with tracing.part("attention"):
                     att = paged_decode_attention(
-                        q[:, 0].astype(kp.dtype), kp, vp, j, t_kv, lengths)
+                        q[:, 0].astype(kp.dtype), kp, vp, j, t_kv, lengths,
+                        runs=runs)
                     att = att.reshape(B, 1, -1).astype(x.dtype)
             else:
                 att = _attend_gathered(q, kp[j], vp[j], t_kv, pos, cfg, False)
@@ -177,9 +188,14 @@ def _decode_body(params, tokens, pos, tables, cache, active, temps, key,
         x = x + y
     logits = ssm_moe_logits(params, x[:, 0], cfg)
     next_tok = _sample_tail(logits, temps, key)
+    # by the kernel's own rule, a sub-run the unit of a copy; the gathered
+    # form walks nothing
+    walked = walk_copies(runs, kv_block(kp, t_kv.shape[1])[1],
+                         -(-lengths // PS)) if in_place else [jnp.int32(0)] * 2
     stats = jnp.concatenate([
         moe_load_stats(loads, B * cfg.n_experts_per_tok, gated=False),
-        (active.sum() * at[MAMBA]).astype(jnp.int32)[None]])
+        jnp.stack([active.sum() * at[MAMBA],
+                   *(at[ATTENTION] * n for n in walked)]).astype(jnp.int32)])
     return (jnp.where(active, next_tok, 0), (kp, vp, states, convs), stats)
 
 
@@ -192,11 +208,13 @@ def ssm_moe_decode_multi(params, loras, aids, tokens, seq_lens, tables,
     ``ServePrograms.decode_multi`` with one table a kind (K/V pages, state
     rows) and four pools, rows of ``[B tokens | STATS]``. ``loras``/``aids``
     are the engine's (None / zeros here: refused at construction)."""
+    runs = run_lengths(tables[0]) if _reads_in_place() else None
+
     def step(carry, k):
         tok, pos, cache = carry
         nxt, cache, stats = _decode_body(
             params, tok, pos, tables, cache, active, temps,
-            jax.random.fold_in(key, k), cfg)
+            jax.random.fold_in(key, k), cfg, runs)
         return (nxt, pos + 1, cache), jnp.concatenate([nxt, stats])
 
     (tok, pos, cache), rows = jax.lax.scan(

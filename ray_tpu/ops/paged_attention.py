@@ -19,22 +19,31 @@ slot's length is contracted. Two callers, one walk:
 
 Which of the two a call is shows in the arguments: the number of pools, the
 pool's rank (a 4-D pool is one KV head), the output's width. Two more forms of
-the K and V walk, an argument each: ``starts`` (a window's: the table a ring,
-the walk from the first page within reach) and ``selected`` (a learned sparse
-attention's: every live page walked, the rows the model did not pick masked;
-a block of pages that lie one after the other in the pool is one copy —
-which blocks those are is found from the table once a program,
-``table_runs`` — and the pages land as the rows they are, read a whole
-register a load).
-And one more way to give a walk out: as a PART of a softmax that runs
-over more than one table (``paged_attention_part``: the plain walk or the
-ring's, with its running maximum and sum beside its output;
-``merge_attention_parts`` joins such parts exactly).
+the K and V walk, an argument each, that differ in what they MASK: ``starts``
+(a window's: the table a ring, the walk from the first page within reach) and
+``selected`` (a learned sparse attention's: every live page walked, the rows
+the model did not pick masked). And one more way to give a walk out: as a
+PART of a softmax that runs over more than one table
+(``paged_attention_part``: the plain walk or the ring's, with its running
+maximum and sum beside its output; ``merge_attention_parts`` joins such parts
+exactly).
 
-One kernel invocation serves every slot: a work list of (slot, block) items,
-a block being ``n_pages`` pages (256 tokens; 512 of the latent pool), runs through two VMEM buffers — the
-next item's page copies are in flight while this one is used, across slot
-boundaries too, so only the first block of a program waits for its pages.
+One kernel invocation serves every slot: a work list of (slot, block) items
+runs through two VMEM buffers — the next item's page copies are in flight
+while this one is used, across slot boundaries too, so only the first block
+of a program waits for its pages. **Every walk over a K and a V pool fetches
+and reads a block the same way**, whatever it masks: a block is as many pages
+as make 1 MB of K and V (``kv_block``: 1,024 tokens at 2 KV heads of 128 bf16
+lanes, 256 at 8, 64 at 32; the latent pool's is 512 tokens); where the
+block's table entries — or those of a sub-run of 8 pages of it — lie one
+after the other in the pool and all hold tokens, the block (sub-run) is ONE
+copy a pool, with the page-by-page code out of its path (which entries follow
+each other is the table's alone and found once a program, ``run_lengths``,
+for a ring as for a table read from its start); the pages land as the rows
+they are, ``[2, tokens * KV, width]`` with no page axis, and are read as the
+32-bit words they lie in (``block_rows``). The latent pool's rows come with
+padding (576 lanes in 640) and need the lane slice a page first: that walk
+keeps a page axis and the page-by-page copies.
 Per block the fetched pages are read as ``[tokens * KV, width]`` rows, as they
 lie: the H query heads meet ALL rows in one matmul and a head mask keeps,
 for query head h, the rows of KV head ``h // G`` (G = H // KV, read from the
@@ -60,42 +69,56 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_BIG = -1e30
-# tokens a compute block, which is also what is in flight while one is used:
-# 1 MB of K and V at 8 x 128 bf16; 0.66 MB of 576-wide latent rows as they lie
-# padded (at 256, 0.33 MB in flight kept the page copies at 60 % of the
-# bandwidth with no arithmetic at all: PERF.md, PR 30)
-_BLOCK_TOKENS = 256
+# a compute block, which is also what is in flight while one is used: 1 MB of
+# K and V whatever the number of KV heads (256 tokens at 8 x 128 bf16, 512 at
+# 4: alone on the chip 2,103 us a call against 2,192 at 256, PERF.md, PR 33;
+# 64 at 32: PR 40; 1,024 at 2: PR 41), 0.66 MB of 576-wide latent rows as they
+# lie padded (at 256 tokens, 0.33 MB in flight kept the page copies at 60 % of
+# the bandwidth with no arithmetic at all: PERF.md, PR 30)
+_BLOCK_BYTES = 1 << 20
 _LATENT_BLOCK_TOKENS = 512
-# the selected walk's block (1 MB of K and V in flight at 4 KV heads: alone on
-# the chip at the cell's shapes 2,103 us a call against 2,192 at 256; PERF.md,
-# PR 33)
-_SELECTED_BLOCK_TOKENS = 512
+# pages a sub-run: what a block that is not one run, or whose last pages hold
+# no tokens yet, still fetches as one copy
+_RUN_PAGES = 8
 
 
-def table_runs(page_tables, n_pages: int, sub: int | None = None):
-    """Which blocks of a page table are runs of the pool: int32 [B, blocks of
-    ``n_pages`` entries]. Bit 0: the block's entries lie one after the other
-    in the pool, so the block is ONE copy where all its pages hold tokens;
-    bit 1 + c: so do the ``sub`` entries of its sub-run c. Plain XLA over the
+def run_lengths(page_tables):
+    """How long the run of the pool that ends at each table entry is, less
+    one: int32 [B, MAXP], entry e the number of entries just before it that
+    lie one after the other in the pool up to it. Entries ``[e, e + n)`` are
+    ONE copy where ``run_lengths[e + n - 1] >= n - 1``, wherever in the table
+    a walk's block starts — a ring's starts anywhere. Plain XLA over the
     table alone, so a program makes it ONCE for all its steps and layers and
-    the kernels scalar-prefetch it: what is left to a kernel is the one
-    compare that moves with the step, whether the pages hold tokens yet."""
-    B, MAXP = page_tables.shape
-    sub = sub or n_pages
-    if n_pages % sub or n_pages // sub > 30:
-        raise ValueError(f"{n_pages} pages a block do not split into at most "
-                         f"30 sub-runs of {sub}")
-    n_blocks = -(-MAXP // n_pages)
-    # entries past the table break every run that reaches them
-    t = jnp.pad(page_tables.astype(jnp.int32),
-                ((0, 0), (0, n_blocks * n_pages - MAXP)), constant_values=-1)
-    follows = jnp.concatenate(
-        [jnp.zeros((B, 1), bool), t[:, 1:] == t[:, :-1] + 1], axis=1
-    ).reshape(B, n_blocks, n_pages // sub, sub)
-    inner = follows[..., 1:].all(-1)  # a sub-run's entries follow each other
-    whole = jnp.logical_and(inner.all(-1), follows[..., 1:, 0].all(-1))
-    bits = (inner.astype(jnp.int32) << (1 + jnp.arange(n_pages // sub))).sum(-1)
-    return bits + whole.astype(jnp.int32)
+    the kernel scalar-prefetches it (``runs=``): what is left to the kernel
+    is what moves with the step, whether those pages hold tokens yet."""
+    t = page_tables.astype(jnp.int32)
+    e = jnp.arange(t.shape[1], dtype=jnp.int32)[None, :]
+    breaks = jnp.concatenate(
+        [jnp.ones((t.shape[0], 1), bool), t[:, 1:] != t[:, :-1] + 1], axis=1)
+    return e - jax.lax.cummax(jnp.where(breaks, e, 0), axis=1)
+
+
+def kv_block(pool, MAXP: int):
+    """(pages a block, pages a sub-run) of a walk over a K and a V pool like
+    ``pool`` under a table of ``MAXP`` entries: ``_BLOCK_BYTES`` of K and V
+    rows as they lie (a head under 128 lanes padded to them)."""
+    PS, KV = pool.shape[2], pool.shape[3]
+    token = 2 * KV * -(-pool.shape[-1] // 128) * 128 * pool.dtype.itemsize
+    n_pages = max(1, min(_BLOCK_BYTES // token // PS, MAXP))
+    return n_pages, _RUN_PAGES if n_pages % _RUN_PAGES == 0 else n_pages
+
+
+def walk_copies(runs, unit: int, pages_live):
+    """(units of ``unit`` pages a walk from the table's start fetched, those
+    of them fetched as ONE copy or inside one) for slots holding
+    ``pages_live`` [B] pages under tables whose ``run_lengths`` are ``runs``:
+    the kernel's own rule — a unit is one copy where all its pages hold
+    tokens and its entries lie one after the other in the pool."""
+    pages_live = jnp.minimum(pages_live, runs.shape[1])
+    last = jnp.arange(unit - 1, runs.shape[1], unit)  # a whole unit's last entry
+    whole = last[None, :] < pages_live[:, None]
+    return ((-(-pages_live // unit)).sum(),
+            (whole & (runs[:, last] >= unit - 1)).sum())
 
 
 def block_rows(buf, cur):
@@ -111,16 +134,20 @@ def block_rows(buf, cur):
 
 
 def _kernel(layer_ref, tables_ref, lengths_ref, *refs,
-            sm_scale: float, n_pages: int, ring: bool = False,
-            select: bool = False, parts: bool = False):
-    # refs: [the starts, where the table is a ring,] [the table's runs and the
-    # positions picked, where the model picks them,] the queries, the pools
-    # (HBM), the output [and, of a walk that is a PART of a softmax, its
-    # running maximum and sum], a VMEM buffer a pool, the semaphores
+            sm_scale: float, n_pages: int, sub: int | None = None,
+            ring: bool = False, select: bool = False, parts: bool = False):
+    # refs: [the starts, where the table is a ring,] [the table's run
+    # lengths, where a run of pages lands as one copy: ``sub`` pages a
+    # sub-run,] [the positions picked, where the model picks them,] the
+    # queries, the pools (HBM), the output [and, of a walk that is a PART of
+    # a softmax, its running maximum and sum], a VMEM buffer a pool, the
+    # semaphores
     if ring:
         starts_ref, *refs = refs
+    if sub:
+        runs_ref, *refs = refs
     if select:
-        runs_ref, sel_ref, *refs = refs
+        sel_ref, *refs = refs
     q_ref, *refs = refs
     n_outs = 3 if parts else 1
     n_pools = (len(refs) - 1 - n_outs) // 2
@@ -154,12 +181,12 @@ def _kernel(layer_ref, tables_ref, lengths_ref, *refs,
         return jnp.minimum(pl.cdiv(lengths_ref[b], PS), MAXP)
 
     def landing(kv: int, buf, j, page, n: int = 1):
-        """The copy of pages [page, page + n) of pool ``kv`` to entries [j,
-        j + n) of its buffer ``buf``. The selected walk's buffers hold the
-        rows and no page axis (``block_rows``), so there a page is the rows
-        it is on both sides."""
+        """The copy of pages [page, page + n) of pool ``kv`` to pages [j,
+        j + n) of block ``buf`` of its buffer: as the rows they are on both
+        sides, or (the latent pool's, with their padding) one page under a
+        page axis."""
         pool, dst = pools[kv], bufs[kv]
-        if select:
+        if sub:
             src = pool.reshape(pool.shape[0], pool.shape[1] * page_rows, width
                                ).at[layer, pl.ds(page * page_rows, n * page_rows)]
             to = dst.at[buf, pl.ds(j * page_rows, n * page_rows)]
@@ -167,48 +194,63 @@ def _kernel(layer_ref, tables_ref, lengths_ref, *refs,
             src, to = pool.at[(layer, page, *whole_rows)], dst.at[buf, j]
         return pltpu.make_async_copy(src, to, sems.at[kv, buf])
 
-    def copies(b, i, buf):
-        """The page copies of block ``i`` of slot ``b`` into buffer ``buf``,
-        each with whether the page holds tokens (dead pages are not
-        fetched; what the buffer held before stays there, masked)."""
-        out = []
-        live = pages_of(b)
-        for j in range(n_pages):
-            p = i * n_pages + j
-            if ring:  # page p of the sequence lies at entry p mod the table
-                page = tables_ref[b, (first_page(b) + p) % MAXP]
-            else:
-                page = tables_ref[b, jnp.minimum(p, MAXP - 1)]
-            out += [(p < live, landing(kv, buf, j, page))
-                    for kv in range(n_pools)]
-        return out
-
     def transfer(b, i, buf, how: str):
-        """Start, or wait for, the copies of block ``i`` of slot ``b``. The
-        selected walk takes a block that is one run of the pool as ONE copy
-        a pool and skips the page-by-page code altogether: that code — a
-        table entry, a bound, a descriptor and a branch a page and pool — is
-        what the walk waits for at 16 KB pages, not the bytes (PERF.md, PR
-        33), and an allocator that draws from the front of a free list
-        hands a slot its pages in runs."""
-        def by_page():
-            for cond, cp in copies(b, i, buf):
-                pl.when(cond)(getattr(cp, how))
+        """Start, or wait for, the copies of block ``i`` of slot ``b``: ONE
+        a pool where its pages all hold tokens and lie one after the other
+        in the pool; else one a sub-run of ``sub`` pages of which the same
+        holds, and one a page that holds tokens for the rest (dead pages are
+        not fetched; what the buffer held before stays there, masked). The
+        page-by-page code — a table entry, a bound, a descriptor and a
+        branch a page and pool — is what a walk waits for at 8 and 16 KB
+        pages, not the bytes (PERF.md, PRs 33 and 41), and an allocator that
+        draws from the front of a free list hands a slot its pages in runs;
+        a run skips that code altogether."""
+        live = pages_of(b) - i * n_pages  # of this block's pages hold tokens
+        if ring:  # page p of the sequence lies at entry p mod the table
+            e0 = (first_page(b) + i * n_pages) % MAXP
 
-        if not select:
-            return by_page()
-        # found once a program (``table_runs``); the step's part is whether
-        # the block's pages all hold tokens at this length
-        run = jnp.logical_and(runs_ref[b, i] & 1 == 1,
-                              (i + 1) * n_pages <= pages_of(b))
+        def entry(j: int):
+            """Where page ``j`` of the block lies in the table (a block is
+            no longer than the table: a ring wraps at most once in it)."""
+            if not ring:
+                return jnp.minimum(i * n_pages + j, MAXP - 1)
+            return jnp.where(e0 + j >= MAXP, e0 + j - MAXP, e0 + j)
 
-        @pl.when(run)
-        def _():
-            first = tables_ref[b, i * n_pages]
+        def by_page(lo: int, hi: int):
+            for j in range(lo, hi):
+                page = tables_ref[b, entry(j)]
+                for kv in range(n_pools):
+                    pl.when(j < live)(getattr(landing(kv, buf, j, page), how))
+
+        if not sub:
+            return by_page(0, n_pages)
+
+        def is_run(j: int, n: int):
+            """Pages [j, j + n) of the block all hold tokens, and their
+            entries lie one after the other in the table (a ring's do not
+            past its last) and, found once a program, in the pool."""
+            e = entry(j)
+            at = jnp.minimum(e + n - 1, MAXP - 1)
+            ok = jnp.logical_and(live >= j + n, runs_ref[b, at] >= n - 1)
+            return jnp.logical_and(ok, e + n <= MAXP) if ring else ok
+
+        def run(j: int, n: int):
+            first = tables_ref[b, entry(j)]
             for kv in range(n_pools):
-                getattr(landing(kv, buf, 0, first, n_pages), how)()
+                getattr(landing(kv, buf, j, first, n), how)()
 
-        pl.when(jnp.logical_not(run))(by_page)
+        whole = is_run(0, n_pages)
+        pl.when(whole)(functools.partial(run, 0, n_pages))
+
+        @pl.when(jnp.logical_not(whole))
+        def _():
+            if sub == n_pages:
+                return by_page(0, n_pages)
+            for j in range(0, n_pages, sub):
+                one = is_run(j, sub)
+                pl.when(one)(functools.partial(run, j, sub))
+                pl.when(jnp.logical_and(jnp.logical_not(one), live > j))(
+                    functools.partial(by_page, j, j + sub))
 
     def start(b, i, buf):
         transfer(b, i, buf, "start")
@@ -264,7 +306,7 @@ def _kernel(layer_ref, tables_ref, lengths_ref, *refs,
                 start(nb, ni, 1 - buf)
 
             wait(b, i, buf)
-            k = (block_rows(bufs[0], buf) if select
+            k = (block_rows(bufs[0], buf) if sub
                  else bufs[0][buf].reshape(rows, lanes))
             if lanes > width:
                 # the last lane tile came with the array's padding, which
@@ -272,7 +314,7 @@ def _kernel(layer_ref, tables_ref, lengths_ref, *refs,
                 k = jnp.concatenate(
                     [k[:, :lo], jnp.where(pad_ok, k[:, lo:], 0)], axis=1)
             # one pool: the values are the leading lanes of the rows fetched
-            v = (block_rows(bufs[1], buf) if select
+            v = (block_rows(bufs[1], buf) if sub
                  else bufs[1][buf].reshape(rows, lanes) if n_pools == 2
                  else k[:, :v_width])
             s = jax.lax.dot_general(
@@ -327,6 +369,9 @@ def paged_decode_attention(q, kpool, vpool, layer, page_tables, lengths, *,
     read from the shapes (KV == H is plain multi-head attention). The
     kernel compiles for the TPU and is interpreted anywhere else.
 
+    ``runs`` is the table's ``run_lengths``, for a program that makes it once
+    and calls this a layer a step; made here where it is not given.
+
     ``starts`` [B] int32 makes the call a WINDOW's: a slot attends positions
     ``[starts, lengths)`` and its table is a ring — the page of positions
     ``[p * PS, (p + 1) * PS)`` lies at entry ``p % MAXP``, so a table of
@@ -338,73 +383,66 @@ def paged_decode_attention(q, kpool, vpool, layer, page_tables, lengths, *,
     attention's: a slot attends, of its ``lengths`` positions, those where
     ``selected`` — the softmax runs over them alone. The walk still fetches
     every live page (the picks of a scattered selection touch nearly all of
-    them) and masks the rows not picked; a block whose pages lie one after
-    the other in the pool is fetched as one copy a pool. ``runs`` is the
-    table's ``selected_runs``, for a program that makes it once and calls
-    this a layer a step; made here where it is not given."""
+    them) and masks the rows not picked."""
+    args, static = _kv_call(q, kpool, vpool, layer, page_tables, lengths,
+                            runs, interpret)
+    if starts is not None:
+        return _paged_window_attention(*args, starts, **static)
+    if selected is not None:
+        return _paged_selected_attention(*args, selected, **static)
+    return _paged_decode_attention(*args, **static)
+
+
+def _kv_call(q, kpool, vpool, layer, page_tables, lengths, runs, interpret):
+    """The arguments every K and V entry hands its jit, and the static ones:
+    the block follows from the shapes, here, where a test can see it move."""
     H, KV = q.shape[1], kpool.shape[3]
     if H % KV:
         raise ValueError(f"{H} query heads do not group over {KV} KV heads")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    layer = jnp.asarray(layer, jnp.int32)
-    if starts is not None:
-        return _paged_window_attention(
-            q, kpool, vpool, layer, page_tables, lengths, starts,
-            interpret=bool(interpret))
-    if selected is not None:
-        if runs is None:
-            runs, _ = selected_runs(page_tables, kpool.shape[2])
-        return _paged_selected_attention(
-            q, kpool, vpool, layer, page_tables, lengths, selected, runs,
-            interpret=bool(interpret))
-    return _paged_decode_attention(
-        q, kpool, vpool, layer, page_tables, lengths,
-        interpret=bool(interpret))
+    if runs is None:
+        runs = run_lengths(page_tables)
+    return ((q, kpool, vpool, jnp.asarray(layer, jnp.int32), page_tables,
+             lengths, runs),
+            {"block": kv_block(kpool, page_tables.shape[1]),
+             "interpret": bool(interpret)})
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _paged_decode_attention(q, kpool, vpool, layer, page_tables, lengths, *,
-                            interpret: bool):
+def _kv_walk(q, kpool, vpool, layer, page_tables, lengths, runs, block,
+             interpret, **form):
+    hd = q.shape[-1]
+    return _walk_pools(q, (kpool, vpool), layer, page_tables, lengths,
+                       v_width=hd, sm_scale=1.0 / math.sqrt(hd), block=block,
+                       interpret=interpret, runs=runs, **form)
+
+
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+def _paged_decode_attention(q, kpool, vpool, layer, page_tables, lengths,
+                            runs, *, block, interpret: bool):
     """A jit of its own, with the layer a traced scalar: the layers of a
     program are then call sites of ONE traced and lowered kernel. Traced a
     layer each, a 13-layer decode program took 6-7 s to lower (about 14 s
     on the chip machine's host) before the compile cache was even asked:
     100 s of a replica's set-up over its 7 decode programs (PERF.md, PR 28)."""
-    hd = q.shape[-1]
-    return _walk_pools(q, (kpool, vpool), layer, page_tables, lengths,
-                       v_width=hd, sm_scale=1.0 / math.sqrt(hd),
-                       block_tokens=_BLOCK_TOKENS, interpret=interpret)
+    return _kv_walk(q, kpool, vpool, layer, page_tables, lengths, runs,
+                    block, interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def _paged_window_attention(q, kpool, vpool, layer, page_tables, lengths,
-                            starts, *, interpret: bool):
+                            runs, starts, *, block, interpret: bool):
     """A jit of its own for the reason ``_paged_decode_attention`` is one."""
-    hd = q.shape[-1]
-    return _walk_pools(q, (kpool, vpool), layer, page_tables, lengths,
-                       v_width=hd, sm_scale=1.0 / math.sqrt(hd),
-                       block_tokens=_BLOCK_TOKENS, interpret=interpret,
-                       starts=starts)
+    return _kv_walk(q, kpool, vpool, layer, page_tables, lengths, runs,
+                    block, interpret, starts=starts)
 
 
-def selected_runs(page_tables, page_size: int):
-    """``table_runs`` at the selected walk's block, [B, blocks] int32, and
-    the pages a block."""
-    n_pages = max(1, min(_SELECTED_BLOCK_TOKENS // page_size,
-                         page_tables.shape[1]))
-    return table_runs(page_tables, n_pages), n_pages
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def _paged_selected_attention(q, kpool, vpool, layer, page_tables, lengths,
-                              selected, runs, *, interpret: bool):
+                              runs, selected, *, block, interpret: bool):
     """A jit of its own for the reason ``_paged_decode_attention`` is one."""
-    hd = q.shape[-1]
-    return _walk_pools(q, (kpool, vpool), layer, page_tables, lengths,
-                       v_width=hd, sm_scale=1.0 / math.sqrt(hd),
-                       block_tokens=_SELECTED_BLOCK_TOKENS,
-                       interpret=interpret, selected=selected, runs=runs)
+    return _kv_walk(q, kpool, vpool, layer, page_tables, lengths, runs,
+                    block, interpret, selected=selected)
 
 
 def paged_latent_attention(q, pool, layer, page_tables, lengths, *,
@@ -432,43 +470,30 @@ def paged_latent_attention(q, pool, layer, page_tables, lengths, *,
 def _paged_latent_attention(q, pool, layer, page_tables, lengths, *,
                             v_width: int, sm_scale: float, interpret: bool):
     """A jit of its own for the reason ``_paged_decode_attention`` is one."""
+    n_pages = max(1, min(_LATENT_BLOCK_TOKENS // pool.shape[2],
+                         page_tables.shape[1]))
     return _walk_pools(q, (pool,), layer, page_tables, lengths,
                        v_width=v_width, sm_scale=sm_scale,
-                       block_tokens=_LATENT_BLOCK_TOKENS, interpret=interpret)
+                       block=(n_pages, None), interpret=interpret)
 
 
 def _walk_pools(q, pools, layer, page_tables, lengths, *, v_width: int,
-                sm_scale: float, block_tokens: int, interpret: bool,
+                sm_scale: float, block, interpret: bool,
                 starts=None, selected=None, runs=None, parts: bool = False):
     """The one ``pallas_call`` every entry makes: the pools stay where they
-    are (``pl.ANY``), a VMEM buffer of two blocks a pool. ``starts`` is one
-    more scalar-prefetched array, and a ring table (``_kernel``);
-    ``selected`` comes with the table's ``runs`` (scalar-prefetched too) and
-    is one more input in VMEM: float 0 / 1 a ROW of the blocks (a
-    position's pick repeated over its KV heads here, in XLA: a repeat that
-    interleaves lanes is no vector operation of the kernel's), whole blocks
-    a slot — 8 MB at 32 slots of 16,384 positions of 4 KV heads. ``parts``:
-    the output in float32 and, beside it, the walk's running maximum and sum
-    ``[B, H, 128]`` (a lane tile each, every lane the same)."""
+    are (``pl.ANY``), a VMEM buffer of two blocks a pool. ``block`` is
+    (pages a block, pages a sub-run: ``kv_block``; none for the latent
+    pool, whose rows come with padding). ``starts`` is one more scalar-prefetched array, and a
+    ring table (``_kernel``); so is ``runs``, the table's ``run_lengths``.
+    ``selected`` is one more input in VMEM: float 0 / 1 a ROW of the blocks
+    (a position's pick repeated over its KV heads here, in XLA: a repeat
+    that interleaves lanes is no vector operation of the kernel's), whole
+    blocks a slot — 8 MB at 32 slots of 16,384 positions of 4 KV heads.
+    ``parts``: the output in float32 and, beside it, the walk's running
+    maximum and sum ``[B, H, 128]`` (a lane tile each, every lane the same)."""
     B, H, width = q.shape
     PS, page = pools[0].shape[2], pools[0].shape[2:]
     MAXP = page_tables.shape[1]
-    n_pages = max(1, min(block_tokens // PS, MAXP))
-    if starts is None:
-        kernel = functools.partial(_kernel, sm_scale=sm_scale, n_pages=n_pages)
-        prefetch = ()
-    else:
-        kernel = functools.partial(_kernel, sm_scale=sm_scale,
-                                   n_pages=n_pages, ring=True)
-        prefetch = (starts.astype(jnp.int32),)
-    picks = ()
-    if selected is not None:
-        kernel = functools.partial(kernel, select=True)
-        prefetch = (runs.astype(jnp.int32),)
-        n_blocks, KV = -(-MAXP // n_pages), math.prod(page[1:-1])
-        picks = (jnp.repeat(jnp.pad(selected.astype(jnp.float32), (
-            (0, 0), (0, n_blocks * n_pages * PS - selected.shape[1]))),
-            KV, axis=1),)
     # Rows whose width is not whole lane tiles (MLA's 576 = 4.5 x 128) lie in
     # HBM padded to whole ones, and Mosaic takes no slice of a tiled axis that
     # is not whole tiles, the whole axis included ("Slice shape along
@@ -479,18 +504,31 @@ def _walk_pools(q, pools, layer, page_tables, lengths, *, v_width: int,
     lanes = width if interpret else -(-width // 128) * 128
     if lanes > width:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, lanes - width)))
-    # two blocks a pool; the selected walk's hold rows (``_kernel`` landing)
-    buf = pltpu.VMEM((2, n_pages * math.prod(page[:-1]), lanes)
-                     if picks else (2, n_pages, *page[:-1], lanes),
-                     pools[0].dtype)
+    n_pages, sub = block
+    kernel = functools.partial(_kernel, sm_scale=sm_scale, n_pages=n_pages,
+                               sub=sub, ring=starts is not None,
+                               select=selected is not None, parts=parts)
+    prefetch = [a.astype(jnp.int32) for a in (
+        starts, runs if sub else None) if a is not None]
+    picks = ()
+    if selected is not None:
+        n_blocks, KV = -(-MAXP // n_pages), math.prod(page[1:-1])
+        picks = (jnp.repeat(jnp.pad(selected.astype(jnp.float32), (
+            (0, 0), (0, n_blocks * n_pages * PS - selected.shape[1]))),
+            KV, axis=1),)
+    # two blocks a pool: of rows, or of pages of rows with their padding
+    buf = pltpu.VMEM((2, n_pages * math.prod(page[:-1]), lanes) if sub
+                     else (2, n_pages, *page[:-1], lanes), pools[0].dtype)
     # at most, a pool: every slot's whole table
     window = B * MAXP * math.prod(page) * pools[0].dtype.itemsize
     out = jax.ShapeDtypeStruct((B, H, v_width), q.dtype)
     outs = out
     if parts:
-        kernel = functools.partial(kernel, parts=True)
         out = jax.ShapeDtypeStruct((B, H, v_width), jnp.float32)
         outs = [out] + [jax.ShapeDtypeStruct((B, H, 128), jnp.float32)] * 2
+    # what the kernel holds in VMEM beside its blocks: over the default
+    # limit's room with a long table's picks
+    held = sum(p.size * p.dtype.itemsize for p in picks)
     return pl.pallas_call(
         kernel,
         out_shape=outs,
@@ -508,7 +546,8 @@ def _walk_pools(q, pools, layer, page_tables, lengths, *, v_width: int,
         ),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
-            **({"vmem_limit_bytes": 64 * 1024 * 1024} if picks else {})),
+            **({"vmem_limit_bytes": 64 * 1024 * 1024}
+               if held > 4 * 1024 * 1024 else {})),
         cost_estimate=pl.CostEstimate(
             flops=2 * B * H * MAXP * PS * (width + v_width),
             transcendentals=B * H * MAXP * PS,
@@ -522,50 +561,38 @@ def _walk_pools(q, pools, layer, page_tables, lengths, *, v_width: int,
 
 
 def paged_attention_part(q, kpool, vpool, layer, page_tables, lengths, *,
-                         starts=None, interpret: bool | None = None):
+                         starts=None, runs=None,
+                         interpret: bool | None = None):
     """``paged_decode_attention`` as ONE PART of a softmax that runs over
-    more than one table: the same walk (plain, or a ring's with ``starts``),
-    given out with what joins it to another — ``(o [B, H, hd] float32, the
-    walk's own normalised output; m [B, H] float32, its largest score; l [B,
-    H] float32, the sum of ``exp(score - m)`` over its rows)``. A slot with
-    no rows gives ``l = 0``. ``merge_attention_parts`` joins them exactly.
-    The block is as many BYTES as the plain walk's at 8 KV heads, whatever
-    the number of KV heads (64 tokens at 32)."""
-    H, KV = q.shape[1], kpool.shape[3]
-    if H % KV:
-        raise ValueError(f"{H} query heads do not group over {KV} KV heads")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    layer = jnp.asarray(layer, jnp.int32)
+    more than one table: the same walk (plain, or a ring's with ``starts``;
+    ``runs`` as there), given out with what joins it to another — ``(o [B,
+    H, hd] float32, the walk's own normalised output; m [B, H] float32, its
+    largest score; l [B, H] float32, the sum of ``exp(score - m)`` over its
+    rows)``. A slot with no rows gives ``l = 0``. ``merge_attention_parts``
+    joins them exactly."""
+    args, static = _kv_call(q, kpool, vpool, layer, page_tables, lengths,
+                            runs, interpret)
     if starts is None:
-        return _paged_attention_part(q, kpool, vpool, layer, page_tables,
-                                     lengths, interpret=bool(interpret))
-    return _paged_window_part(q, kpool, vpool, layer, page_tables, lengths,
-                              starts, interpret=bool(interpret))
-
-
-def _part(q, kpool, vpool, layer, page_tables, lengths, starts, interpret):
-    hd, PS, KV = q.shape[-1], kpool.shape[2], kpool.shape[3]
-    o, m, l = _walk_pools(
-        q, (kpool, vpool), layer, page_tables, lengths, v_width=hd,
-        sm_scale=1.0 / math.sqrt(hd), interpret=interpret, starts=starts,
-        block_tokens=max(PS, _BLOCK_TOKENS * 8 // KV), parts=True)
+        o, m, l = _paged_attention_part(*args, **static)
+    else:
+        o, m, l = _paged_window_part(*args, starts, **static)
     return o, m[..., 0], l[..., 0]
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _paged_attention_part(q, kpool, vpool, layer, page_tables, lengths, *,
-                          interpret: bool):
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+def _paged_attention_part(q, kpool, vpool, layer, page_tables, lengths, runs,
+                          *, block, interpret: bool):
     """A jit of its own for the reason ``_paged_decode_attention`` is one."""
-    return _part(q, kpool, vpool, layer, page_tables, lengths, None, interpret)
+    return _kv_walk(q, kpool, vpool, layer, page_tables, lengths, runs,
+                    block, interpret, parts=True)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _paged_window_part(q, kpool, vpool, layer, page_tables, lengths, starts,
-                       *, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+def _paged_window_part(q, kpool, vpool, layer, page_tables, lengths, runs,
+                       starts, *, block, interpret: bool):
     """A jit of its own for the reason ``_paged_decode_attention`` is one."""
-    return _part(q, kpool, vpool, layer, page_tables, lengths, starts,
-                 interpret)
+    return _kv_walk(q, kpool, vpool, layer, page_tables, lengths, runs,
+                    block, interpret, starts=starts, parts=True)
 
 
 def merge_attention_parts(*parts):

@@ -33,7 +33,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ray_tpu.ops.paged_attention import block_rows, table_runs
+from ray_tpu.ops.paged_attention import block_rows
 
 _LANES = 128
 # pages a block (64 pages of 16 are 1,024 positions, 128 KB), blocks in VMEM (one
@@ -42,6 +42,32 @@ _LANES = 128
 _BLOCK_PAGES = 64
 _RING = 5
 _RUN_PAGES = 8
+
+
+def table_runs(page_tables, n_pages: int, sub: int | None = None):
+    """Which blocks of a page table are runs of the pool: int32 [B, blocks of
+    ``n_pages`` entries]. Bit 0: the block's entries lie one after the other
+    in the pool, so the block is ONE copy where all its pages hold tokens;
+    bit 1 + c: so do the ``sub`` entries of its sub-run c. Plain XLA over the
+    table alone, so a program makes it ONCE for all its steps and layers and
+    the kernels scalar-prefetch it: what is left to a kernel is the one
+    compare that moves with the step, whether the pages hold tokens yet."""
+    B, MAXP = page_tables.shape
+    sub = sub or n_pages
+    if n_pages % sub or n_pages // sub > 30:
+        raise ValueError(f"{n_pages} pages a block do not split into at most "
+                         f"30 sub-runs of {sub}")
+    n_blocks = -(-MAXP // n_pages)
+    # entries past the table break every run that reaches them
+    t = jnp.pad(page_tables.astype(jnp.int32),
+                ((0, 0), (0, n_blocks * n_pages - MAXP)), constant_values=-1)
+    follows = jnp.concatenate(
+        [jnp.zeros((B, 1), bool), t[:, 1:] == t[:, :-1] + 1], axis=1
+    ).reshape(B, n_blocks, n_pages // sub, sub)
+    inner = follows[..., 1:].all(-1)  # a sub-run's entries follow each other
+    whole = jnp.logical_and(inner.all(-1), follows[..., 1:, 0].all(-1))
+    bits = (inner.astype(jnp.int32) << (1 + jnp.arange(n_pages // sub))).sum(-1)
+    return bits + whole.astype(jnp.int32)
 
 
 def keys_per_row(dk: int, page_size: int) -> int:

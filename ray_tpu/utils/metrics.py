@@ -307,6 +307,17 @@ LLM_MODEL_STATS = {
         "rt_llm_ssm_state_updates_total",
         "state rows a decode step read and wrote: live slots x state-space "
         "blocks"),
+    # the walks of ops/paged_attention.py over a K and a V pool, as the
+    # program that makes them counts (llm/ssm_moe.py), each summed over
+    # attention layers, live slots and decode steps
+    "walk_blocks": Counter(
+        "rt_llm_walk_blocks_total",
+        "sub-runs of a block of pages (the unit of a copy) a K/V walk "
+        "fetched"),
+    "walk_run_blocks": Counter(
+        "rt_llm_walk_run_blocks_total",
+        "those of them fetched as ONE copy, or inside a whole block's: all "
+        "pages hold tokens and lie one after the other in the pool"),
     "eva_pairs": Counter(
         "rt_llm_eva_pairs_written_total",
         "pooled key/value pairs a decode step wrote: chunks filled x layers"),

@@ -98,15 +98,17 @@ def _engine_args(one_chip, n_layers: int, n_kv_heads: int = 8):
 
 
 @pytest.mark.parametrize("seq,kv_heads,temporaries", [
-    (512, 8, 571_958_784), (2048, 8, 573_266_432), (2048, 4, 555_181_568)])
+    (512, 8, 572_176_896), (2048, 8, 573_613_568), (2048, 4, 555_464_192)])
 def test_paged_decode_multi_compiles(one_chip, monkeypatch, seq, kv_heads,
                                      temporaries):
     """At chip_smoke's 512-token window and at the benchmark cells' 2048, at
     Mistral's head ratio and at Yi's. On a TPU a plain pool is read in place
     by the paged kernel (``_reads_in_place`` asks ``jax.default_backend()``,
     which here is the CPU: the test answers for it, as for the flash
-    kernels below). ``temporaries`` pins the program where PR 28 left it,
-    to the byte: the kernel's walk has a second caller (the latent pool)
+    kernels below). ``temporaries`` pins the program to the byte — where PR
+    28 left it plus the 0.2-0.35 MB PR 41's walk added (the table's
+    ``run_lengths`` and a kernel that holds the one-copy path): the kernel's
+    walk has other callers (the latent pool, the ring, the selected walk)
     that must not move this one."""
     from ray_tpu.llm.llama import paged_decode_multi
 
@@ -500,6 +502,23 @@ def _ssm_moe_args(one_chip, pattern="MEMEM*EMEMEM*EMEME", kv=20000, state=129):
     return cfg, params, cache, one_chip(_shape((2,), jnp.uint32))
 
 
+def _mosaic_modules(lowered):
+    """The Mosaic module of every Pallas call of a lowered program, as text:
+    the custom call's payload is the kernel's MLIR in bytecode."""
+    import base64
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib import tpu
+    from jaxlib.mlir import ir
+
+    ctx = mlir.make_ir_context()
+    tpu.register_dialect(ctx)
+    ctx.allow_unregistered_dialects = True  # serialised as `stable_mosaic`
+    bodies = re.findall(r'\\22body\\22: *\\22([^\\]*)\\22',
+                        lowered.as_text())
+    return [str(ir.Module.parse(base64.b64decode(b), ctx)) for b in bodies]
+
+
 def _ssm_moe_decode(one_chip, monkeypatch, args, B, n_steps):
     """(lowered, compiled) decode program of ``args`` for ``B`` slots, as a
     TPU's backend would choose its forms."""
@@ -536,10 +555,22 @@ def test_ssm_moe_decode_multi_compiles(one_chip, monkeypatch):
     B = 128
     lowered, compiled = _ssm_moe_decode(
         one_chip, monkeypatch, _ssm_moe_args(one_chip), B, 8)
-    assert lowered.out_info[0].shape == (8, B + len(MOE_STATS) + 1)
+    # tokens | MOE_STATS | ssm_updates, walk_blocks, walk_run_blocks
+    assert lowered.out_info[0].shape == (8, B + len(MOE_STATS) + 3)
     text = compiled.as_text()
     assert len(re.findall(r"%_paged_decode_attention\S* = \S+ custom-call\(",
                           text)) == 2
+    # the walk's Mosaic module holds the one-copy path at a 1,024-token
+    # block: two blocks of 1,024 x 2 KV heads rows a pool with no page axis,
+    # and DMA starts (2 pools x the 2 places a block's copies start) of a
+    # whole block, of its 8 sub-runs of 8 pages, and of its 64 pages
+    # (the two blocks' calls are sites of ONE lowered kernel)
+    walk, = [m for m in _mosaic_modules(lowered) if "enqueue_dma" in m]
+    assert re.findall(r"memref<2x2048x128xbf16, [^>]*vmem>", walk)
+    assert not re.findall(r"memref<2x\d+x16x2x128xbf16, [^>]*vmem>", walk)
+    starts = re.findall(r"enqueue_dma.*?memref<(\d+)x128xbf16", walk)
+    assert {n: starts.count(n) for n in set(starts)} == {
+        "2048": 4, "256": 4 * 8, "32": 4 * 64}
     assert not re.findall(_RAGGED_DOT, text)
     assert not re.findall(_SWIGLU, text)
     assert not re.findall(_POOL_COPY, text)

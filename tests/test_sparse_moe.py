@@ -22,10 +22,10 @@ from ray_tpu.llm.engine import (ContinuousBatchingEngine, UnsupportedByModel,
 from ray_tpu.models.sparse_moe import (SparseMoeConfig, attend_plain,
                                        sparse_moe_forward, sparse_moe_init)
 from ray_tpu.ops import paged_attention, paged_indexer, prefill_picks, select
-from ray_tpu.ops.paged_attention import (paged_decode_attention,
-                                         selected_runs, table_runs)
+from ray_tpu.ops.paged_attention import paged_decode_attention
 from ray_tpu.ops.paged_indexer import (index_runs, pack_keys,
-                                       paged_index_scores, unpack_keys)
+                                       paged_index_scores, table_runs,
+                                       unpack_keys)
 from ray_tpu.ops.prefill_attention import gqa_prefill_attention
 from ray_tpu.ops.select import topk_mask
 from ray_tpu.parallel.moe import routed_experts, softmax_topk_route
@@ -522,8 +522,6 @@ def test_table_runs_against_a_hand_count():
     assert table_runs(t[:1, :6], 6, 3).tolist() == [[7]]
     runs, n_pages = index_runs(t)  # a table under a block: the table, whole
     assert (runs.shape, n_pages) == ((2, 1), 10)
-    runs, n_pages = selected_runs(jnp.zeros((2, 150), jnp.int32), 8)
-    assert (runs.shape, n_pages) == ((2, 3), 64) and not runs.any()
 
 
 @pytest.mark.parametrize("runs,entries,lengths", [
@@ -673,7 +671,10 @@ def test_engine_decode_through_the_kernels_matches_the_gathered_form(
     if small_blocks:
         monkeypatch.setattr(paged_indexer, "_BLOCK_PAGES", 2)
         monkeypatch.setattr(paged_indexer, "_RUN_PAGES", 1)
-        monkeypatch.setattr(paged_attention, "_SELECTED_BLOCK_TOKENS", 2 * PS)
+        # 2 pages of K and V rows as they lie: 2 KV heads of 128 float lanes
+        monkeypatch.setattr(paged_attention, "_BLOCK_BYTES",
+                            2 * PS * 2 * CFG.n_kv_heads * 128 * 4)
+        monkeypatch.setattr(paged_attention, "_RUN_PAGES", 1)
     jits = (programs.sparse_moe_decode_multi,
             paged_indexer._paged_index_scores,
             paged_attention._paged_selected_attention)

@@ -490,8 +490,8 @@ def test_the_stats_column_and_read_counters_against_a_hand_count():
     step. The K/V read counters count the attention block's positions only:
     the state kind holds none, so it adds nothing and dilutes nothing."""
     eng = _engine()
-    assert eng.programs.stats[-1] == "ssm_updates"
-    assert eng.programs.stats[:-1] == programs.MOE_STATS
+    assert eng.programs.stats == programs.MOE_STATS + (
+        "ssm_updates", "walk_blocks", "walk_run_blocks")
     before = metrics.stage_totals()
     _serve(eng, [(20, 13)])
     after = metrics.stage_totals()
@@ -515,6 +515,40 @@ def test_the_stats_column_and_read_counters_against_a_hand_count():
     assert eng._last_kv["kv_live"] == sum(range(29, 33)) / 4
     assert eng._last_stats["ssm_updates"] == N_M
     assert {"moe_passes", "ssm_updates"} <= set(eng._last_stats)
+    # the gathered form walks nothing
+    assert grown("rt_llm_walk_blocks_total") == 0
+    assert grown("rt_llm_walk_run_blocks_total") == 0
+
+
+def test_the_walks_copies_are_counted_where_the_kernel_runs(monkeypatch):
+    """The chip's branch without a chip: the decode kernels interpreted
+    under the engine at blocks of 4 pages and sub-runs of 2, two programs of
+    4 steps. The first slot's lengths 14..21 cross a page and a sub-run (16 |
+    17) inside the first program, the second's 22..29 a page and a sub-run
+    (24 | 25) between the two. The table's runs are found once a program and
+    the free list hands both slots their pages in runs, so every sub-run
+    whose pages all hold tokens is inside ONE copy: pages 2 2 2 3 3 3 3 3 + 3
+    3 3 4 4 4 4 4 a step, sub-runs 1 1 1 2 2 2 2 2 + 2 2 2 2 2 2 2 2, whole
+    1 1 1 1 1 1 1 1 + 1 1 1 2 2 2 2 2 — in the one attention block."""
+    from ray_tpu.ops import paged_attention
+
+    cases = [(13, 9), (21, 9)]
+    _, want = _serve(_engine(block_buckets=(4,)), cases)
+    monkeypatch.setattr(programs, "_reads_in_place", lambda: True)
+    monkeypatch.setattr(paged_attention, "_BLOCK_BYTES",
+                        4 * PS * 2 * CFG.n_kv_heads * 128 * 4)
+    monkeypatch.setattr(paged_attention, "_RUN_PAGES", 2)
+    programs.ssm_moe_decode_multi.clear_cache()
+    try:
+        eng = _engine(block_buckets=(4,))
+        before = metrics.stage_totals()
+        _, got = _serve(eng, cases)
+        after = metrics.stage_totals()
+    finally:
+        programs.ssm_moe_decode_multi.clear_cache()
+    assert got == want
+    assert _grown(before, after, "rt_llm_walk_blocks_total") == 13 + 16
+    assert _grown(before, after, "rt_llm_walk_run_blocks_total") == 8 + 13
 
 
 def test_both_programs_name_the_new_parts():
@@ -667,7 +701,9 @@ def test_decode_through_the_pool_step_kernel_is_the_plain_decode(monkeypatch):
         calls.append(a[1]), ssm_pool_step(*a))[1])
     got_rows, got = two_blocks()
     assert calls == list(range(N_M))                 # once a block a trace
-    assert np.array_equal(got_rows, want_rows)       # tokens and stats
+    # tokens and stats; the walk's copies alone are the kernel's to count
+    assert np.array_equal(got_rows[:, :-2], want_rows[:, :-2])
+    assert got_rows[:, -2].all() and not want_rows[:, -2:].any()
     assert got_rows[:, :B].any() and not got_rows[4:, 1].any()
     live = np.asarray(tables[0])[[0, 2]].ravel()     # a dead slot's K/V page,
     for a, b in zip(got[:2], want[:2]):              # as the junk conv row,
