@@ -114,7 +114,6 @@ class ContinuousBatchingEngine:
                  kv_dtype: str | None = None, spec_enable: bool = False,
                  spec_k: int = 4, spec_ngram: int = 2,
                  spec_drafter=None):
-        self.params = params
         self.cfg = cfg
         self.B = max_batch
         self.PS = page_size
@@ -133,6 +132,7 @@ class ContinuousBatchingEngine:
                 ("spec_enable", bool(spec_enable), P.decode_spec is not None)):
             if asked and not has:
                 raise UnsupportedByModel(feature, P.family)
+        self.params = params
         # the model's cache, one tuple of pools (see ServePrograms)
         self.cache = tuple(P.make_cache(cfg, page_size, n_pages, kv_dtype))
         self.kv_dtype = kv_dtype or "native"
@@ -592,6 +592,27 @@ class ContinuousBatchingEngine:
         a table still being read (a program compiled this instant)."""
         return tracing.merged_parts(
             table.result() for table in list(self._compiled.values()))
+
+    # trees taken so far: 1 after construction, one more every assignment
+    weights_prepared = 0
+
+    @property
+    def params(self):
+        """The parameter tree as the family's programs read it. Assigning
+        one (construction does) lays it out for serving ONCE, by the
+        family's ``ServePrograms.prepare``, so that no program lays a weight
+        out again; the engine keeps what the hook returns and no other
+        reference to what it was handed. None drops the tree (room for the
+        next one)."""
+        return self._params
+
+    @params.setter
+    def params(self, tree):
+        if tree is not None:
+            if self.programs.prepare is not None:
+                tree = self.programs.prepare(tree, self.cfg)
+            self.weights_prepared += 1
+        self._params = tree
 
     _WAVE_BUCKETS = (1, 2, 4, 8, 16)
 

@@ -6,7 +6,10 @@ engine, beside the other family's (``llm/mla_moe.py``). Imports the seam
   arrays or int8 ``{"q", "s"}`` dicts (``make_kv_pools``); ``_kv_write``,
   ``_kv_read`` and ``scatter_pages`` alone know which.
 * **Every program's loop** is ``llama_project`` -> write K and V -> attend
-  -> ``llama_attn_out`` -> ``llama_ffn``. The middle is the program's own:
+  -> ``llama_attn_out`` -> ``llama_ffn``, on the tree as it is handed in: the
+  engine's lies in the serving layout (``PROGRAMS.prepare``: wq|wk|wv and
+  w_gate|w_up joined once, when it takes the tree), any other caller's as
+  ``llama_init`` made it. The middle is the program's own:
   fresh K and V under a causal mask (``paged_prefill_batch``), the
   table-ordered window (``paged_prefill_suffix``, speculative verify), the
   pool in place or the gathered window as ``_reads_in_place`` sees (decode).
@@ -27,7 +30,8 @@ import numpy as np
 
 from ray_tpu.llm.programs import ServePrograms, _sample_tail
 from ray_tpu.models.llama import (
-    LlamaConfig, llama_attn_out, llama_ffn, llama_project)
+    LlamaConfig, llama_attn_out, llama_ffn, llama_project,
+    llama_serving_layout)
 from ray_tpu.ops.basic import rms_norm, rope_freqs
 from ray_tpu.ops.paged_attention import paged_decode_attention, run_lengths
 from ray_tpu.utils import tracing
@@ -185,7 +189,7 @@ def _decode_body(params, loras, aids, tokens, pos, page_tables,
     for i in range(cfg.n_layers):
         layer = params[f"layers_{i}"]
         q, k, v = llama_project(layer, x, cos, sin, positions, cfg,
-                                loras=loras, aids=aids, fused=True)
+                                loras=loras, aids=aids)
         kpool = _kv_write(kpool, i, row, off, k[:, 0])
         vpool = _kv_write(vpool, i, row, off, v[:, 0])
         if in_place:
@@ -196,7 +200,7 @@ def _decode_body(params, loras, aids, tokens, pos, page_tables,
             kb = _kv_read(kpool, i, page_tables, k.dtype)
             vb = _kv_read(vpool, i, page_tables, v.dtype)
             att = _gqa_attn(q, kb, vb, mask)
-        x = llama_ffn(layer, llama_attn_out(layer, x, att), fused=True)
+        x = llama_ffn(layer, llama_attn_out(layer, x, att))
     with tracing.part("head"):
         x = rms_norm(x, params["norm"]["scale"])
         logits = x[:, 0] @ params["lm_head"]["kernel"]
@@ -397,13 +401,13 @@ def _spec_verify_body(params, loras, aids, inputs, positions, page_tables,
     for i in range(cfg.n_layers):
         layer = params[f"layers_{i}"]
         q, k, v = llama_project(layer, x, cos, sin, positions, cfg,
-                                loras=loras, aids=aids, fused=True)
+                                loras=loras, aids=aids)
         kpool = _kv_write(kpool, i, rows, offs, k)
         vpool = _kv_write(vpool, i, rows, offs, v)
         kb = _kv_read(kpool, i, page_tables, k.dtype)
         vb = _kv_read(vpool, i, page_tables, v.dtype)
         att = _gqa_attn(q, kb, vb, mask)
-        x = llama_ffn(layer, llama_attn_out(layer, x, att), fused=True)
+        x = llama_ffn(layer, llama_attn_out(layer, x, att))
     with tracing.part("head"):
         x = rms_norm(x, params["norm"]["scale"])
         logits = x @ params["lm_head"]["kernel"]  # [B, T, V]
@@ -552,4 +556,4 @@ PROGRAMS = ServePrograms(
     decode_in_place=lambda cache: _reads_in_place(cache[0]),
     prefill_suffix=paged_prefill_suffix, decode_spec=paged_decode_spec,
     decode_verify=paged_decode_verify, lora=make_lora_stack, int8_cache=True,
-    page_plane=True)
+    page_plane=True, prepare=llama_serving_layout)

@@ -140,7 +140,11 @@ class ServePrograms:
     was fetched for it); None attends everything within reach.
     ``lora(cfg, adapters, rank) -> (stack, name -> index)`` stacks named
     adapters. It and the rest are the Llama family's and None elsewhere: the
-    engine refuses what needs them."""
+    engine refuses what needs them.
+    ``prepare(params, cfg) -> params``: the tree in the layout the programs
+    read fastest, derived ONCE, when the engine takes a tree (its ``params``
+    setter), so that no program lays a weight out again; it may re-lay the
+    tree it is given in place. None: the programs read the tree as it comes."""
     family: str
     make_cache: callable
     decode_multi: callable
@@ -156,6 +160,7 @@ class ServePrograms:
     lora: callable = None
     int8_cache: bool = False
     page_plane: bool = False   # export_pages / submit_prefilled (disagg)
+    prepare: callable = None
 
 
 def serving_programs(cfg) -> ServePrograms:

@@ -208,7 +208,9 @@ class LLMEngineServer:
         """Counters of this replica's engine. ``stages``: cumulative sum
         and count of every stage family of ``utils/metrics.py`` in this
         process — the engine loop's phases, a request's queue, prefill
-        and decode waits, the prefill counters, the lane's two legs. While
+        and decode waits, the prefill counters, the lane's two legs.
+        ``weights_prepared``: parameter trees the engine has taken and laid
+        out for serving (1 unless somebody assigned ``engine.params``). While
         a ``jax.profiler`` trace is on, also ``program_parts``: whoever
         takes the trace needs the table to read it by layer part, and
         nobody else is sent it."""
@@ -217,6 +219,7 @@ class LLMEngineServer:
         out = {"steps": self.engine.steps, "tokens_out": self.engine.tokens_out,
                "waiting": len(self.engine.waiting),
                "free_pages": len(self.engine.free[0]),
+               "weights_prepared": self.engine.weights_prepared,
                "stages": metrics.stage_totals()}
         if tracing.profiling():
             out["program_parts"] = self.engine.program_parts()

@@ -129,30 +129,24 @@ def _lora_delta(h, loras, name, aid):
 
 @tracing.part("project")
 def llama_project(layer, x, cos, sin, positions, cfg: LlamaConfig, *,
-                  loras=None, aids=None, fused: bool = False):
+                  loras=None, aids=None):
     """The layer's first half on the residual ``x`` [B, T, D]: norm, q/k/v
     (plus the LoRA deltas of the slots' adapters ``aids`` on q and v where
     ``loras`` is given), rope at ``positions`` ([B, T]; None = 0..T-1).
     Returns q [B, T, H, hd], k and v [B, T, KV, hd]: head counts are the
     kernels' widths over ``head_dim``, so a tensor-parallel slice is the
-    same call.
-
-    ``fused``: ONE matmul against ``wq|wk|wv``. At a decode step's few rows
-    a layer is bound by its count of operations, not FLOPs, and XLA hoists
-    the loop-invariant concatenation out of the step scan; decode and verify
-    pass it, the prefills (fat matmuls already) do not."""
+    same call. A layer in the serving layout (``llama_serving_layout``:
+    ``wqkv``) takes ONE product and the result is sliced; the weights are
+    read as they lie either way."""
     B, T, _ = x.shape
     h = rms_norm(x, layer["attn_norm"]["scale"])
-    wq, wk, wv = (layer["wq"]["kernel"], layer["wk"]["kernel"],
-                  layer["wv"]["kernel"])
-    if fused:
-        nq, nkv = wq.shape[1], wk.shape[1]
-        with tracing.part("weights_concat"):
-            wqkv = jnp.concatenate([wq, wk, wv], axis=1)
-        qkv = h @ wqkv
+    if "wqkv" in layer:
+        nq, nkv = (n * cfg.head_dim for n in (cfg.n_heads, cfg.n_kv_heads))
+        qkv = h @ layer["wqkv"]["kernel"]
         q, k, v = qkv[..., :nq], qkv[..., nq:nq + nkv], qkv[..., nq + nkv:]
     else:
-        q, k, v = h @ wq, h @ wk, h @ wv
+        q, k, v = (h @ layer["wq"]["kernel"], h @ layer["wk"]["kernel"],
+                   h @ layer["wv"]["kernel"])
     if loras is not None:
         q = q + _lora_delta(h, loras, "wq", aids)
         v = v + _lora_delta(h, loras, "wv", aids)
@@ -175,22 +169,51 @@ def llama_attn_out(layer, x, att, tp_axis: str | None = None):
 
 
 @tracing.part("ffn")
-def llama_ffn(layer, x, *, fused: bool = False, tp_axis: str | None = None):
+def llama_ffn(layer, x, *, tp_axis: str | None = None):
     """The layer's second half on the residual ``x``: norm, SwiGLU,
-    residual. ``fused`` as in ``llama_project``: one matmul against
-    ``w_gate|w_up``."""
+    residual. A layer in the serving layout (``w_gate_up``) takes one
+    product for gate and up."""
     h = rms_norm(x, layer["ffn_norm"]["scale"])
-    w_gate, w_up, w_down = (layer["w_gate"]["kernel"], layer["w_up"]["kernel"],
-                            layer["w_down"]["kernel"])
-    if fused:
-        with tracing.part("weights_concat"):
-            w_gu = jnp.concatenate([w_gate, w_up], axis=1)
-        gu = h @ w_gu
+    w_down = layer["w_down"]["kernel"]
+    if "w_gate_up" in layer:
+        gu = h @ layer["w_gate_up"]["kernel"]
         ff = gu.shape[-1] // 2
         y = (jax.nn.silu(gu[..., :ff]) * gu[..., ff:]) @ w_down
     else:
-        y = swiglu(h, w_gate, w_up, w_down)
+        y = swiglu(h, layer["w_gate"]["kernel"], layer["w_up"]["kernel"],
+                   w_down)
     return x + _rejoin(y, tp_axis)
+
+
+# a serving layout's joined kernels, and the kernels each lays side by side
+_JOINED = {"wqkv": ("wq", "wk", "wv"), "w_gate_up": ("w_gate", "w_up")}
+
+
+def llama_serving_layout(params, cfg: LlamaConfig):
+    """The tree as a serving engine keeps it: every layer's ``wq``, ``wk``,
+    ``wv`` side by side as ONE kernel ``wqkv`` [D, (H + 2 KV) hd] and
+    ``w_gate``, ``w_up`` as one ``w_gate_up`` [D, 2 ff] — a decode step is
+    bound by its count of operations, so the halves above take one product
+    where the layer holds the joined kernel, and no program lays a weight
+    out again. Norms, ``wo``, ``w_down``, an expert layer's ``moe``, the
+    embedding and the head stay as they are; a layer already joined is left
+    alone.
+
+    Done IN PLACE, a kernel at a time: the layer's dicts lose the originals
+    as the joined kernel is made, so the most held is one joined kernel above
+    the tree — a second set of them (3.7 GB for 13 layers at Mistral's widths)
+    beside a tree that fills half the chip would not be freed before the
+    pools are made. The tree stays one every function of this file takes;
+    train, checkpoints and ``parallel/``'s column slices want the three
+    apart and never come here. Works on a tree of tracers
+    (``jax.eval_shape``)."""
+    for i in range(cfg.n_layers):
+        layer = params[f"layers_{i}"]
+        for joined, names in _JOINED.items():
+            if names[0] in layer:
+                layer[joined] = {"kernel": jnp.concatenate(
+                    [layer.pop(n)["kernel"] for n in names], axis=1)}
+    return params
 
 
 def _block(layer, x, cos, sin, cfg: LlamaConfig, mesh, attn_impl, seq_axis):

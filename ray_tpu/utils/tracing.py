@@ -374,8 +374,8 @@ class phase:
 
 # ------------------------------------------------ parts of a jitted program
 # The layer parts a serve program's instructions are summed by. Scopes nest
-# and a reader takes the innermost: ``weights_concat`` stands inside
-# ``project`` and ``ffn``, ``router`` and ``experts`` inside an expert layer.
+# and a reader takes the innermost: ``router`` and ``experts`` stand inside an
+# expert layer. ``weights_concat`` is a guard that stands nowhere (below).
 PARTS = (
     "embed",           # the token rows of the embedding
     "project",         # input norm, q/k/v or latent projections, rotary
@@ -390,7 +390,7 @@ PARTS = (
     "conv",            # a state-space block's depthwise convolution, its saved inputs
     "ssm",             # dt, the recurrence in either form, the D skip, the gated norm
     "summary",         # a chunk's keys and values pooled into its pair, and its write
-    "weights_concat",  # wq|wk|wv and w_gate|w_up joined in the fused branches
+    "weights_concat",  # a weight laid out again by a program: none may hold it
     "head",            # final norm and logits
     "sample",          # the sampling tail, rng
 )

@@ -18,6 +18,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from ray_tpu.models.llama import LlamaConfig, llama_init, make_train_step
+from ray_tpu.utils import tracing
 
 
 @pytest.fixture(scope="module")
@@ -88,28 +89,36 @@ def _engine_args(one_chip, n_layers: int, n_kv_heads: int = 8):
     """chip_smoke's serve phase: Llama-3-8B widths, batch 16, a 32k-token
     bf16 pool in 16-token pages, 512-token sequences — depth cut to two
     layers, which is what keeps the compile to seconds. 32 query heads on 8
-    KV heads is Mistral's ratio too; on 4 it is Yi's."""
+    KV heads is Mistral's ratio too; on 4 it is Yi's. The tree is the one an
+    engine holds: in the family's serving layout (``PROGRAMS.prepare``, here
+    on shapes alone)."""
+    from ray_tpu.llm.llama import PROGRAMS
+
     cfg = dataclasses.replace(LlamaConfig.llama3_8b(), n_layers=n_layers,
                               n_kv_heads=n_kv_heads)
-    params = jax.eval_shape(lambda: llama_init(jax.random.PRNGKey(0), cfg))
+    params = jax.eval_shape(lambda: PROGRAMS.prepare(
+        llama_init(jax.random.PRNGKey(0), cfg), cfg))
+    assert "wqkv" in params["layers_0"] and "wq" not in params["layers_0"]
     pool = _shape((n_layers, 2048, 16, cfg.n_kv_heads, cfg.head_dim),
                   jnp.bfloat16)
     return cfg, one_chip(params), one_chip(pool), one_chip(_shape((2,), jnp.uint32))
 
 
 @pytest.mark.parametrize("seq,kv_heads,temporaries", [
-    (512, 8, 572_176_896), (2048, 8, 573_613_568), (2048, 4, 555_464_192)])
+    (512, 8, 3_979_776), (2048, 8, 3_883_008), (2048, 4, 3_414_016)])
 def test_paged_decode_multi_compiles(one_chip, monkeypatch, seq, kv_heads,
                                      temporaries):
     """At chip_smoke's 512-token window and at the benchmark cells' 2048, at
     Mistral's head ratio and at Yi's. On a TPU a plain pool is read in place
     by the paged kernel (``_reads_in_place`` asks ``jax.default_backend()``,
     which here is the CPU: the test answers for it, as for the flash
-    kernels below). ``temporaries`` pins the program to the byte — where PR
-    28 left it plus the 0.2-0.35 MB PR 41's walk added (the table's
-    ``run_lengths`` and a kernel that holds the one-copy path): the kernel's
+    kernels below). ``temporaries`` pins the program to the byte — a step's
+    activations and the table's ``run_lengths``, 3.4-4.0 MB: the kernel's
     walk has other callers (the latent pool, the ring, the selected walk)
-    that must not move this one."""
+    that must not move this one, and the tree is the engine's (``wqkv``,
+    ``w_gate_up``: PR 42), so no weight is laid out inside the program. On
+    ``llama_init``'s tree the same program held 0.572 GB: wq|wk|wv and
+    w_gate|w_up concatenated before the scan, 0.285 GB a layer."""
     from ray_tpu.llm.llama import paged_decode_multi
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -130,11 +139,19 @@ def test_paged_decode_multi_compiles(one_chip, monkeypatch, seq, kv_heads,
     # embedding + head 2.1 GB, two layers 0.87 GB (0.84 at 4 KV heads), two
     # pools 0.27 GB each (0.13)
     assert 2.7e9 < mem.argument_size_in_bytes < 4.0e9
-    # what the program needs plus a tenth (0.574 GB at every window): the
-    # hoisted qkv and gate-up concatenations, 0.285 GB a layer, and nothing
-    # of the window — the gathered one added 67 MB at 2048 (0.641)
-    assert mem.temp_size_in_bytes == temporaries < 0.63e9
+    # nothing of the weights and nothing of the window (the gathered one
+    # added 67 MB at 2048)
+    assert mem.temp_size_in_bytes == temporaries < 5e6
     text = compiled.as_text()
+    # the joined kernels are read as they lie: nothing makes an array of their
+    # shape — on the plain tree two ``pad_maximum_fusion`` a layer did (the
+    # concatenations). The compiler's own fetch of a weight in slices ahead
+    # of its product (``slice-start``, as for ``wo``) is no such thing.
+    joined = re.compile(rf"bf16\[4096,(?:28672|{(32 + 2 * kv_heads) * 128})\]")
+    assert not [row for rows in tracing.program_instructions(text)[1]
+                for row in rows if joined.search(row[1]) and row[2] in (
+                    "fusion", "concatenate", "pad", "copy", "copy-start",
+                    "transpose", "convert", "dynamic-update-slice")]
     # one Mosaic kernel a layer, reading the pools where they lie
     assert text.count("tpu_custom_call") == cfg.n_layers
     # ... so nothing but the in-place row writes touches a pool: no layer's
@@ -590,7 +607,6 @@ def test_ssm_moe_decode_moves_the_state_in_one_pass(one_chip, monkeypatch):
     pool back in the buffer it came in — with no plain in-place update or
     read-out fusion beside it and no copy of the pool around the calls: an
     alias that did not hold would show as one."""
-    from ray_tpu.utils import tracing
 
     args = _ssm_moe_args(one_chip, pattern="MEMEM*EME", kv=2000, state=33)
     text = _ssm_moe_decode(one_chip, monkeypatch, args, 32, 4)[1].as_text()

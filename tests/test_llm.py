@@ -515,10 +515,12 @@ _KERNEL_READ = (r'(?<!\["moe"\])\[\s*"(wq|wk|wv|wo|w_gate|w_up|w_down)"\s*\]')
 def _weights_read_in_one_place():
     """A dense Llama layer's seven kernels are subscripted by the shared
     halves of ``models/llama.py`` and by nothing else: no other function of
-    that file (``llama_init`` builds them; the ``moe`` sub-tree, the LoRA
-    stack's ``wq_a`` names and the partition rules' name sets are not
-    reads), no module under ``ray_tpu/llm/`` (``llm/mla_moe.py`` applies
-    its OWN family's ``wo``)."""
+    that file (``llama_init`` builds them; ``llama_serving_layout`` takes
+    five of them OUT of a layer to join them and reads none; the ``moe``
+    sub-tree, the LoRA stack's ``wq_a`` names and the partition rules' name
+    sets are not reads), no module under ``ray_tpu/llm/`` (``llm/mla_moe.py``
+    applies its OWN family's ``wo``). The joined kernels have two readers
+    too: ``llama_project`` (``wqkv``) and ``llama_ffn`` (``w_gate_up``)."""
     import importlib
     import inspect
     import pkgutil
@@ -535,6 +537,11 @@ def _weights_read_in_one_place():
     assert set().union(*(reads[h] for h in halves)) == {
         "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down"}
     assert {n: r for n, r in reads.items() if r and n not in halves} == {}
+    joined = {n: set(re.findall(r'\[\s*"(wqkv|w_gate_up)"\s*\]',
+                                inspect.getsource(fn)))
+              for n, fn in inspect.getmembers(llama, inspect.isfunction)}
+    assert {n: r for n, r in joined.items() if r} == {
+        "llama_project": {"wqkv"}, "llama_ffn": {"w_gate_up"}}
     for info in pkgutil.walk_packages(ray_tpu.llm.__path__, "ray_tpu.llm."):
         if info.name != "ray_tpu.llm.mla_moe":
             src = inspect.getsource(importlib.import_module(info.name))

@@ -26,8 +26,7 @@ FAMILIES = {
     # family: (config, init, engine options, decode program, prefill
     # program, the parts every one of its programs must name)
     "llama": (LlamaConfig.tiny(), llama_init, {"n_pages": 64},
-              "jit_paged_decode_multi", "jit_paged_prefill_batch",
-              DENSE | {"weights_concat"}),
+              "jit_paged_decode_multi", "jit_paged_prefill_batch", DENSE),
     "mla_moe": (MlaMoeConfig.tiny(), mla_moe_init, {"n_pages": 64},
                 "jit_mla_moe_decode_multi", "jit_mla_moe_prefill_batch",
                 EXPERTS | {"ffn"}),
@@ -82,11 +81,46 @@ def test_every_program_names_its_familys_parts(served, family, phase):
     assert not program["stale"] and program["variants"] >= 1
     found = set(program["parts"].values())
     assert found <= set(tracing.PARTS) | {tracing.SCAN, tracing.AMBIGUOUS}
-    if phase == "prefill":
-        want = want - {"weights_concat"}  # only the fused (decode) branches
     assert want <= found, (family, phase, sorted(want - found))
+    # a guard that names nothing: no program may lay a weight out (PR 42)
+    assert "weights_concat" not in found
     for key in program["parts"]:
         assert re.fullmatch(r"[\w.\-]+\|((pred|[a-z]+\d+)\[[\d,]*\])?", key), key
+
+
+@pytest.mark.parametrize("phase", ["decode", "prefill"])
+def test_no_program_of_an_engine_lays_a_weight_out(served, phase):
+    """The tiny ``paged_decode_multi`` and ``paged_prefill_batch`` on the tree
+    an engine holds (the serving layout: ``wqkv``, ``w_gate_up``): no
+    instruction of part ``weights_concat``, and no ``concatenate`` — alone or
+    inside a fusion — whose result has the shape of a joined kernel. On
+    ``llama_init``'s tree the decode program had both before PR 42."""
+    from ray_tpu.llm.llama import (
+        make_kv_pools, paged_decode_multi, paged_prefill_batch)
+
+    eng, _ = served("llama")
+    cfg, params, B = eng.cfg, eng.params, 3
+    assert {"wqkv", "w_gate_up"} <= set(params["layers_0"])
+    kpool, vpool = make_kv_pools(cfg, 8, 16, None)
+    i32, temps = jnp.zeros(B, jnp.int32), jnp.zeros(B, jnp.float32)
+    if phase == "decode":
+        lowered = paged_decode_multi.lower(
+            params, None, i32, i32, i32 + 1, jnp.zeros((B, 4), jnp.int32),
+            kpool, vpool, jnp.ones(B, bool), temps, jax.random.PRNGKey(0),
+            cfg=cfg, n_steps=4)
+    else:
+        lowered = paged_prefill_batch.lower(
+            params, None, i32, jnp.zeros((B, 16), jnp.int32),
+            jnp.zeros((B, 2), jnp.int32), kpool, vpool, i32 + 5, temps,
+            jax.random.PRNGKey(0), cfg=cfg)
+    text = lowered.compile().as_text()
+    assert "weights_concat" not in set(
+        tracing.instruction_parts(text)[1].values())
+    assert "weights_concat" not in text
+    joined = {str(tuple(params["layers_0"][n]["kernel"].shape)).replace(
+        " ", "")[1:-1] for n in ("wqkv", "w_gate_up")}
+    made = re.findall(r"= \w+\[([\d,]+)\]\S* concatenate\(", text)
+    assert not joined & set(made), made
 
 
 def test_most_named_instructions_of_the_decode_program_get_a_part():
@@ -229,8 +263,10 @@ def test_engine_stats_carries_the_table_only_under_a_profiler_trace(tmp_path):
 
     out, plain, traced, after = asyncio.run(go())
     assert len(out["completion_tokens"]) == 4
-    assert set(plain) == set(after) == {"steps", "tokens_out", "waiting",
-                                        "free_pages", "stages"}
+    assert set(plain) == set(after) == {
+        "steps", "tokens_out", "waiting", "free_pages", "weights_prepared",
+        "stages"}
+    assert plain["weights_prepared"] == 1
     assert set(traced) == set(plain) | {"program_parts"}
     assert traced["program_parts"] == server.program_parts()
     assert "attention" in traced["program_parts"][
