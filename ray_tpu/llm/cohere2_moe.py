@@ -17,7 +17,8 @@ of TWO KINDS of pages and a parallel attention + expert layer.
   window layer from the page that holds ``pos + 1 - window`` on — nothing
   before it is fetched. Off the TPU, where the kernel would be interpreted,
   the gathered table with a position mask: the kernel's plain reference and
-  what the CPU tests run (``_reads_in_place``, as the other families).
+  what the CPU tests run (the seam's one rule, bound here as
+  ``_reads_in_place``).
 * **Prefill** is whole-prompt per pad bucket. Attention over the fresh keys
   is blocked (``ops/prefill_attention.py``: no ``[T, T]`` array, the window's
   lower bound skips blocks); of a prompt longer than the ring, only the
@@ -39,10 +40,12 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.llm.programs import (
-    MOE_STATS, PageKind, ServePrograms, _sample_tail, moe_load_stats)
+    MOE_STATS, PageKind, ServePrograms, _sample_tail, decode_frame, last_rows,
+    moe_load_stats, reads_in_place)
 from ray_tpu.models.cohere2_moe import (
-    Cohere2MoeConfig, cohere2_attend_plain, cohere2_attn_out, cohere2_experts,
-    cohere2_logits, cohere2_project, cohere2_reach, cohere2_rope_freqs)
+    Cohere2MoeConfig, cohere2_attn_out, cohere2_experts, cohere2_logits,
+    cohere2_moe_init, cohere2_project, cohere2_reach, cohere2_rope_freqs)
+from ray_tpu.ops.attention import gathered_attention, masked_attention
 from ray_tpu.ops.basic import layer_norm
 from ray_tpu.ops.paged_attention import paged_decode_attention, run_lengths
 from ray_tpu.ops.prefill_attention import blocks_for, gqa_prefill_attention
@@ -51,6 +54,11 @@ from ray_tpu.utils import tracing
 # the most prompts and tokens one prefill program may hold: eight waiting
 # 12,288-token prompts would otherwise be one 98k-token program
 WAVE_LIMIT = (8, 16384)
+# The seam's platform rule under this module's own name, asked through this
+# global by every program here and by ``PROGRAMS.decode_in_place``:
+# ``benchmarks/sizing_cohere2_moe.py`` (to compile the chip's branch on a CPU)
+# and ``tests/`` (to run the kernels interpreted) ASSIGN an answer here.
+_reads_in_place = reads_in_place
 
 
 def ring_entries(cfg: Cohere2MoeConfig, page_size: int) -> int:
@@ -86,37 +94,6 @@ def make_pools(cfg: Cohere2MoeConfig, page_size: int, n_pages, kv_dtype):
                  cfg.n_kv_heads, cfg.head_dim)
         out += [jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)]
     return tuple(out)
-
-
-def _reads_in_place() -> bool:
-    """Whether the programs attend through the Pallas kernels (on a TPU) or
-    in the plain form (anywhere else, where the kernels would be
-    interpreted): decided by what the code can see, no option."""
-    return jax.default_backend() == "tpu"
-
-
-@tracing.part("attention")
-def _attend_gathered(q, kpool, vpool, table, pos, cfg, window: bool):
-    """The plain form of a decode step's attention: every entry of the
-    slot's table gathered, each row masked by the position it holds. In a
-    ring, entry e holds the latest page ``p <= pos // PS`` with ``p %
-    entries == e``. q: [B, 1, H, hd]; kpool, vpool: [P, PS, KV, hd] (one
-    layer); table: [B, entries]; pos: [B]. Returns [B, 1, H * hd]."""
-    B, entries = table.shape
-    PS = kpool.shape[1]
-    e = jnp.arange(entries)[None, :]
-    last = (pos // PS)[:, None]
-    page = last - (last - e) % entries if window else jnp.broadcast_to(
-        e, (B, entries))
-    k_pos = (page[:, :, None] * PS + jnp.arange(PS)[None, None, :]
-             ).reshape(B, entries * PS)
-    mask = (k_pos >= 0) & cohere2_reach(pos[:, None], k_pos, cfg, window)
-
-    def rows(pool):
-        return pool[table].reshape(B, entries * PS, *pool.shape[2:]
-                                   ).astype(q.dtype)
-
-    return cohere2_attend_plain(q, rows(kpool), rows(vpool), mask[:, None])
 
 
 def _decode_body(params, tokens, pos, tables, cache, active, temps, key,
@@ -160,7 +137,9 @@ def _decode_body(params, tokens, pos, tables, cache, active, temps, key,
                     starts=starts if window else None, runs=runs[window])
                 att = att.reshape(B, 1, -1).astype(x.dtype)
         else:
-            att = _attend_gathered(q, kp[j], vp[j], table, pos, cfg, window)
+            att = gathered_attention(
+                q, kp[j], vp[j], table, pos,
+                cfg.sliding_window if window else None)
         if window:
             kw, vw = kp, vp
         else:
@@ -185,17 +164,9 @@ def cohere2_moe_decode_multi(params, loras, aids, tokens, seq_lens, tables,
     the engine's (None / zeros here: refused at construction)."""
     # (full, window): found once a program, not a layer a step
     runs = [run_lengths(t) if _reads_in_place() else None for t in tables]
-
-    def step(carry, k):
-        tok, pos, cache = carry
-        nxt, cache, stats = _decode_body(
-            params, tok, pos, tables, cache, active, temps,
-            jax.random.fold_in(key, k), cfg, runs)
-        return (nxt, pos + 1, cache), jnp.concatenate([nxt, stats])
-
-    (tok, pos, cache), rows = jax.lax.scan(
-        step, (tokens, seq_lens, (kf, vf, kw, vw)), jnp.arange(n_steps))
-    return (rows, tok, pos, *cache)
+    return decode_frame(_decode_body, params, tokens, seq_lens, tables,
+                        (kf, vf, kw, vw), active, temps, key, cfg, n_steps,
+                        runs)
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnums=(5, 6, 7, 8))
@@ -250,18 +221,18 @@ def cohere2_moe_prefill_batch(params, loras, aids, tokens, pages, kf, vf, kw,
             mask = jnp.broadcast_to(
                 cohere2_reach(idx[:, None], idx[None, :], cfg, window),
                 (N, Tp, Tp))
-            att = cohere2_attend_plain(q, k, v, mask)
+            att = masked_attention(q, k, v, mask)
         y, _ = cohere2_experts(layer, h, cfg, valid=valid)
         x = x + cohere2_attn_out(layer, att) + y
-    last_x = jnp.take_along_axis(
-        x, (true_lens - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    logits = cohere2_logits(params, last_x, cfg)
+    logits = cohere2_logits(params, last_rows(x, true_lens), cfg)
     return _sample_tail(logits, temps, key), kf, vf, kw, vw
 
 
 PROGRAMS = ServePrograms(
     family="cohere2_moe", make_cache=make_pools,
     decode_multi=cohere2_moe_decode_multi,
-    prefill_batch=cohere2_moe_prefill_batch, stats=MOE_STATS,
-    decode_in_place=lambda cache: _reads_in_place(),
-    page_kinds=page_kinds, prefill_wave_limit=WAVE_LIMIT)
+    prefill_batch=cohere2_moe_prefill_batch, init=cohere2_moe_init,
+    stats=MOE_STATS, decode_in_place=lambda cache: _reads_in_place(),
+    page_kinds=page_kinds, prefill_wave_limit=WAVE_LIMIT,
+    caches="full layers' pages and a ring of the window layers' pages, which "
+           "holds the last window alone")

@@ -54,7 +54,7 @@ def _resolve_params(model_config, params, params_fn):
     programs = serving_programs(model_config)
     if not programs.page_plane:
         raise UnsupportedByModel(
-            "disaggregated serving (disagg/kv_plane.py)", programs.family)
+            "disaggregated serving (disagg/kv_plane.py)", programs)
     if params is None:
         params = params_fn() if params_fn is not None else None
     if params is None:

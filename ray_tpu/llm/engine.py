@@ -10,11 +10,17 @@ ideas onto XLA's static-shape world:
   new request's prompt KV into a free slot's pages *between* decode steps
   — a request never waits for the running batch to drain (continuous
   batching at decode-step granularity).
-* **Paged KV.** One global pool ``[layers, n_pages, page_size, kv, hd]``;
-  each slot owns a page table. Decode gathers the slot's pages for
-  attention; prefill scatters prompt KV into freshly allocated pages.
-  Shapes never depend on sequence length, so XLA compiles exactly one
-  decode program (plus one prefill program per prompt-length bucket).
+* **Paged cache.** The family's pools (``self.cache``: K and V ``[layers,
+  n_pages, page_size, kv, hd]`` for the Llama family, other pools for
+  others) and, for each KIND of page the family declares, a page table a
+  slot and a free list. Prefill scatters a prompt's rows into freshly drawn
+  pages; a decode step writes one row and attends the slot's pages WHERE
+  THEY LIE — on a TPU a Pallas walk fetches only the pages that hold tokens,
+  anywhere else the programs gather the table and mask it (the seam's one
+  rule, ``llm/programs.py`` ``reads_in_place``; ``_kv_in_place`` tells the
+  read counters which ran). Shapes never depend on sequence length, so XLA
+  compiles one decode program a block size and one prefill program a
+  (wave, prompt-pad) bucket.
 * **Streaming.** Every request gets an asyncio queue; tokens land there
   the step they are sampled.
 * **LoRA multiplex** (ref: serve/multiplex.py): stacked low-rank adapters
@@ -22,7 +28,8 @@ ideas onto XLA's static-shape world:
   decode batch can use different adapters (adapter 0 = base model).
 * **The cache is the model's.** The engine owns slots, pages, tables,
   admission, blocks and the loop; what a page HOLDS is the model family's
-  (``llm/programs.py``: ``ServePrograms``, chosen by the config's type). It
+  (``llm/programs.py``: ``ServePrograms``, those of the family the config's
+  class names; the engine branches on no family). It
   is carried as one tuple of pools (``self.cache``), handed to every program
   and taken back donated. Where the family declares kinds of pages
   (``ServePrograms.page_kinds``) the engine keeps a table and a free list a
@@ -131,7 +138,7 @@ class ContinuousBatchingEngine:
                 ("lora_adapters", bool(lora_adapters), P.lora is not None),
                 ("spec_enable", bool(spec_enable), P.decode_spec is not None)):
             if asked and not has:
-                raise UnsupportedByModel(feature, P.family)
+                raise UnsupportedByModel(feature, P)
         self.params = params
         # the model's cache, one tuple of pools (see ServePrograms)
         self.cache = tuple(P.make_cache(cfg, page_size, n_pages, kv_dtype))
@@ -220,7 +227,7 @@ class ContinuousBatchingEngine:
 
     def _kv_pool(self, i: int):
         if not self.programs.page_plane:
-            raise UnsupportedByModel("a K or V pool", self.programs.family)
+            raise UnsupportedByModel("a K or V pool", self.programs)
         return self.cache[i]
 
     # ----------------------------------------------------------- public API
@@ -302,7 +309,7 @@ class ContinuousBatchingEngine:
         of a pool with this engine's page_size and kv_dtype."""
         if not self.programs.page_plane:
             raise UnsupportedByModel("submit_prefilled (disagg adoption)",
-                                     self.programs.family)
+                                     self.programs)
         if self.error is not None:
             raise RuntimeError("engine loop died") from self.error
         if len(self.waiting) >= self.max_waiting:
@@ -339,7 +346,7 @@ class ContinuousBatchingEngine:
 
         if not self.programs.page_plane:
             raise UnsupportedByModel("export_pages (disagg/kv_plane.py)",
-                                     self.programs.family)
+                                     self.programs)
         req = self._reqs.get(req_id)
         if req is None or req.slot < 0:
             raise KeyError(f"request {req_id} is not holding a slot")

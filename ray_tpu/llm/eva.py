@@ -24,10 +24,10 @@ KINDS of pages of which one holds a row for every CHUNK of positions.
   other families run, rather than one work list over two tables, because the
   pools are two arrays and the walk's block and buffers are one pool pair's.
   Anywhere else, the gathered tables with position masks and one softmax
-  written out: the plain form and what the CPU tests run (``_reads_in_place``,
-  as the other families). A chunk is pooled as it fills, not a window at its
-  boundary: the same mathematics at 1/128 of the burst, and a step that
-  closes a window costs what any other does.
+  written out: the plain form and what the CPU tests run (the seam's rule,
+  bound here as ``_reads_in_place``). A chunk is pooled as it fills, not a
+  window at its boundary: the same mathematics at 1/128 of the burst, and a
+  step that closes a window costs what any other does.
 * **Prefill** is whole-prompt per pad bucket: the pairs of every chunk that
   is complete at the prompt's TRUE length are made and written (a pad
   position never enters a pair), attention over the fresh keys and pairs is
@@ -50,10 +50,11 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.llm.cohere2_moe import _reads_in_place
-from ray_tpu.llm.programs import PageKind, ServePrograms, _sample_tail
+from ray_tpu.llm.programs import (
+    PageKind, ServePrograms, _sample_tail, decode_frame, last_rows,
+    reads_in_place)
 from ray_tpu.models.eva import (
-    EvaConfig, eva_attend_plain, eva_attn_out, eva_ffn, eva_logits,
+    EvaConfig, eva_attend_plain, eva_attn_out, eva_ffn, eva_init, eva_logits,
     eva_pairs_seen, eva_project, eva_reach, eva_rope_freqs, eva_summarize)
 from ray_tpu.ops.paged_attention import (
     merge_attention_parts, paged_attention_part, run_lengths)
@@ -65,6 +66,10 @@ from ray_tpu.utils import tracing
 WAVE_LIMIT = (8, 16384)
 # pairs written a step, summed over layers
 STATS = ("eva_pairs",)
+# The seam's platform rule under this module's own name, asked through this
+# global by every program here and by ``PROGRAMS.decode_in_place``: ``tests/``
+# ASSIGN an answer here to run the kernels interpreted.
+_reads_in_place = reads_in_place
 
 
 def ring_entries(cfg: EvaConfig, page_size: int) -> int:
@@ -195,17 +200,9 @@ def eva_decode_multi(params, loras, aids, tokens, seq_lens, tables, kw, vw,
     the engine's (None / zeros here: refused at construction)."""
     # (window, summary): found once a program, not a layer a step
     runs = [run_lengths(t) if _reads_in_place() else None for t in tables]
-
-    def step(carry, k):
-        tok, pos, cache = carry
-        nxt, cache, stats = _decode_body(
-            params, tok, pos, tables, cache, active, temps,
-            jax.random.fold_in(key, k), cfg, runs)
-        return (nxt, pos + 1, cache), jnp.concatenate([nxt, stats])
-
-    (tok, pos, cache), rows = jax.lax.scan(
-        step, (tokens, seq_lens, (kw, vw, ks, vs)), jnp.arange(n_steps))
-    return (rows, tok, pos, *cache)
+    return decode_frame(_decode_body, params, tokens, seq_lens, tables,
+                        (kw, vw, ks, vs), active, temps, key, cfg, n_steps,
+                        runs)
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnums=(5, 6, 7, 8))
@@ -269,14 +266,15 @@ def eva_prefill_batch(params, loras, aids, tokens, pages, kw, vw, ks, vs,
         # itself the compiler puts every layer's scatter at the program's end
         # and keeps all their keys and values until then (2 GB at 15,360 rows)
         x, kw, vw, ks, vs = jax.lax.optimization_barrier((x, kw, vw, ks, vs))
-    last_x = jnp.take_along_axis(
-        x, (true_lens - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    logits = eva_logits(params, last_x, cfg, heads=1)[:, 0]
+    logits = eva_logits(params, last_rows(x, true_lens), cfg, heads=1)[:, 0]
     return _sample_tail(logits, temps, key), kw, vw, ks, vs
 
 
 PROGRAMS = ServePrograms(
     family="eva", make_cache=make_pools, decode_multi=eva_decode_multi,
-    prefill_batch=eva_prefill_batch, stats=STATS,
+    prefill_batch=eva_prefill_batch, init=eva_init, stats=STATS,
     decode_in_place=lambda cache: _reads_in_place(),
-    page_kinds=page_kinds, prefill_wave_limit=WAVE_LIMIT)
+    page_kinds=page_kinds, prefill_wave_limit=WAVE_LIMIT,
+    caches="a ring of exact K and V pages for its own window alone and one "
+           "pooled pair for every chunk before it: a prefix of its pages is "
+           "no prefix of the sequence")

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.llm.llama import _gqa_attn
-from ray_tpu.llm.programs import UnsupportedByModel
+from ray_tpu.llm.programs import UnsupportedByModel, serving_programs
 from ray_tpu.models.llama import (
     LlamaConfig, llama_attn_out, llama_ffn, llama_project)
 from ray_tpu.ops.basic import rms_norm, rope_freqs
@@ -130,7 +130,7 @@ def generate(params, cfg: LlamaConfig, prompts: list[list[int]],
     name."""
     if not isinstance(cfg, LlamaConfig):
         raise UnsupportedByModel("the static-batch generate() path",
-                                 type(cfg).__name__)
+                                 serving_programs(cfg))
     tokens, pad_lens = pad_prompts(prompts)
     out = generate_tokens(
         params, tokens, pad_lens, cfg, max_new_tokens,

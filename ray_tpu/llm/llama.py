@@ -12,7 +12,7 @@ engine, beside the other family's (``llm/mla_moe.py``). Imports the seam
   ``llama_init`` made it. The middle is the program's own:
   fresh K and V under a causal mask (``paged_prefill_batch``), the
   table-ordered window (``paged_prefill_suffix``, speculative verify), the
-  pool in place or the gathered window as ``_reads_in_place`` sees (decode).
+  pool in place or the gathered window as ``_walks`` sees (decode).
   In place, the walk takes table entries that lie one after the other in the
   pool as ONE copy; which do is the table's alone, so ``paged_decode_multi``
   finds it once (``run_lengths``, before the scan over its steps) and every
@@ -28,13 +28,19 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.llm.programs import ServePrograms, _sample_tail
+from ray_tpu.llm.programs import (
+    ServePrograms, _sample_tail, decode_frame, last_rows, reads_in_place)
 from ray_tpu.models.llama import (
-    LlamaConfig, llama_attn_out, llama_ffn, llama_project,
+    LlamaConfig, llama_attn_out, llama_ffn, llama_init, llama_project,
     llama_serving_layout)
 from ray_tpu.ops.basic import rms_norm, rope_freqs
 from ray_tpu.ops.paged_attention import paged_decode_attention, run_lengths
 from ray_tpu.utils import tracing
+
+# The seam's platform rule under this module's own name, asked through this
+# global by every program here and by ``PROGRAMS.decode_in_place``: ``tests/``
+# ASSIGN an answer here to run the kernels interpreted.
+_reads_in_place = reads_in_place
 
 
 @tracing.part("attention")
@@ -96,7 +102,7 @@ def _kv_read(pool, i, page_tables, dtype):
     gather itself, then one read by each contraction of ``_gqa_attn``.
     What still reads the pool this way: several query rows a slot (suffix
     prefill, speculative decode and verify), int8 pools, and the decode
-    step wherever ``_reads_in_place`` says no. On the chip the decode step
+    step wherever ``_walks`` says no. On the chip the decode step
     of a plain pool does not (``ops/paged_attention.py``): there this
     window cost half the device's time for a tenth of it live (PERF.md
     section 6, PR 28), and this function with ``_gqa_attn`` is the plain
@@ -137,48 +143,52 @@ def scatter_pages(pool, page_ids, stack):
     return _scatter_pages_jit(pool, idx, stack)
 
 
-def _reads_in_place(pool) -> bool:
-    """Whether the decode step's attention reads this pool where it lies
-    (``paged_decode_attention``: only the pages that hold tokens) or
-    through ``_kv_read``'s gathered window. Decided by what the code can
-    see, no option: a plain pool on a TPU takes the kernel. An int8 pool
-    keeps the window (the kernel does not dequantise); so does every other
-    backend, where the kernel would be interpreted (seconds a call site to
-    trace, and nothing to gain); a single KV head under 32 bits, whose
-    one-row page slice Mosaic refuses (tiling (2, 128)); and a head that is
-    not whole lane tiles, whose rows lie padded in HBM (no run of pages is a
-    run of rows there)."""
-    if isinstance(pool, dict) or jax.default_backend() != "tpu":
-        return False
-    return (pool.shape[-1] % 128 == 0
+def _pool_walkable(pool) -> bool:
+    """What this family adds to the seam's rule, where the pool's layout is
+    known: whether the kernel can walk THIS pool at all. Not an int8 pool
+    (the kernel does not dequantise); not a single KV head under 32 bits,
+    whose one-row page slice Mosaic refuses (tiling (2, 128)); not a head
+    that is not whole lane tiles, whose rows lie padded in HBM (no run of
+    pages is a run of rows there)."""
+    return (not isinstance(pool, dict) and pool.shape[-1] % 128 == 0
             and (pool.shape[3] > 1 or pool.dtype.itemsize >= 4))
 
 
-def _decode_body(params, loras, aids, tokens, pos, page_tables,
-                 kpool, vpool, active, temps, key, cfg: LlamaConfig, runs):
+def _walks(pool) -> bool:
+    """Whether the decode step's attention reads this pool where it lies
+    (``paged_decode_attention``: only the pages that hold tokens) or through
+    ``_kv_read``'s gathered window: the seam's rule, and a pool the kernel
+    can walk."""
+    return _reads_in_place() and _pool_walkable(pool)
+
+
+def _decode_body(params, tokens, pos, page_tables, cache, active, temps, key,
+                 cfg: LlamaConfig, runs, loras, aids):
     """One decode step for every slot (masked where inactive).
 
     tokens: [B] current input token; pos: [B] tokens already cached (the
     new token lands at that position); page_tables: [B, MAXP]; aids: [B]
-    adapter ids; temps: [B]. Returns (next_tok [B], kpool, vpool).
+    adapter ids; temps: [B]. Returns (next_tok [B], (kpool, vpool), None:
+    the family has no stats of its own).
     Pools are either plain [L, P, PS, KV, hd] arrays (cfg dtype) or int8
     quantized dicts (see _kv_write) — the engine's kv_dtype option.
 
     Every layer writes the new row into the pools, then attends the
     slot's ``pos + 1`` positions: in place, page by page through the table
     (``paged_decode_attention``; an inactive slot attends nothing) where
-    ``_reads_in_place`` holds, else over ``_kv_read``'s whole window with
+    ``_walks`` holds, else over ``_kv_read``'s whole window with
     the positions past ``pos`` masked. ``runs``: the table's ``run_lengths``
     — which entries lie one after the other in the pool, so that the walk
     takes them as one copy — made by the program once for all its steps and
     layers (None where the kernel does not run)."""
+    kpool, vpool = cache
     PS = _kv_shape(kpool)[2]
     MAXP = page_tables.shape[1]
     cos, sin = rope_freqs(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
     positions = pos[:, None]
     row = jnp.take_along_axis(page_tables, (pos // PS)[:, None], axis=1)[:, 0]
     off = pos % PS
-    in_place = _reads_in_place(kpool)
+    in_place = _walks(kpool)
     if in_place:
         lengths = jnp.where(active, pos + 1, 0)
     else:
@@ -205,7 +215,7 @@ def _decode_body(params, loras, aids, tokens, pos, page_tables,
         x = rms_norm(x, params["norm"]["scale"])
         logits = x[:, 0] @ params["lm_head"]["kernel"]
     next_tok = _sample_tail(logits, temps, key)
-    return jnp.where(active, next_tok, 0), kpool, vpool
+    return jnp.where(active, next_tok, 0), (kpool, vpool), None
 
 
 @partial(jax.jit, static_argnames=("cfg", "n_steps"), donate_argnums=(6, 7))
@@ -232,18 +242,10 @@ def paged_decode_multi(params, loras, aids, tokens, seq_lens, page_tables,
     the attended rows (tests/test_chip_compile.py holds the compiled
     program to no copy of a pool)."""
     # the table is the program's: its runs are found once, not a layer a step
-    runs = run_lengths(page_tables) if _reads_in_place(kpool) else None
-
-    def step(carry, k):
-        tok, pos, kpool, vpool = carry
-        nxt, kpool, vpool = _decode_body(
-            params, loras, aids, tok, pos, page_tables, kpool, vpool,
-            active, temps, jax.random.fold_in(key, k), cfg, runs)
-        return (nxt, pos + 1, kpool, vpool), nxt
-
-    (tok, pos, kpool, vpool), toks = jax.lax.scan(
-        step, (tokens, seq_lens, kpool, vpool), jnp.arange(n_steps))
-    return toks, tok, pos, kpool, vpool
+    runs = run_lengths(page_tables) if _walks(kpool) else None
+    return decode_frame(_decode_body, params, tokens, seq_lens, page_tables,
+                        (kpool, vpool), active, temps, key, cfg, n_steps,
+                        runs, loras, aids)
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnums=(5, 6))
@@ -278,9 +280,7 @@ def paged_prefill_batch(params, loras, aids, tokens, pages, kpool, vpool,
         x = llama_ffn(layer, llama_attn_out(layer, x, att))
     with tracing.part("head"):
         x = rms_norm(x, params["norm"]["scale"])
-        last = jnp.take_along_axis(
-            x, (true_lens - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-        logits = last @ params["lm_head"]["kernel"]  # [N, V]
+        logits = last_rows(x, true_lens) @ params["lm_head"]["kernel"]  # [N, V]
     return _sample_tail(logits, temps, key), kpool, vpool
 
 
@@ -331,9 +331,7 @@ def paged_prefill_suffix(params, loras, aids, tokens, pages, kpool, vpool,
         x = llama_ffn(layer, llama_attn_out(layer, x, att))
     with tracing.part("head"):
         x = rms_norm(x, params["norm"]["scale"])
-        last = jnp.take_along_axis(
-            x, (true_lens - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-        logits = last @ params["lm_head"]["kernel"]
+        logits = last_rows(x, true_lens) @ params["lm_head"]["kernel"]
     return _sample_tail(logits, temps, key), kpool, vpool
 
 
@@ -553,7 +551,7 @@ def make_kv_pools(cfg: LlamaConfig, page_size: int, n_pages: int,
 PROGRAMS = ServePrograms(
     family="llama", make_cache=make_kv_pools,
     decode_multi=paged_decode_multi, prefill_batch=paged_prefill_batch,
-    decode_in_place=lambda cache: _reads_in_place(cache[0]),
+    init=llama_init, decode_in_place=lambda cache: _walks(cache[0]),
     prefill_suffix=paged_prefill_suffix, decode_spec=paged_decode_spec,
     decode_verify=paged_decode_verify, lora=make_lora_stack, int8_cache=True,
     page_plane=True, prepare=llama_serving_layout)

@@ -10,7 +10,8 @@ of ``llm/llama.py``, another cache and another layer.
   (``mla_attend_expanded``); decode absorbs ``wkv_b`` into the query and
   the output and attends the rows as they lie: in the pool, page by page
   (``paged_latent_attention``) or over the gathered window
-  (``mla_attend_absorbed``), as ``_reads_in_place`` sees.
+  (``mla_attend_absorbed``), by the seam's rule, bound here as
+  ``_reads_in_place``.
 * **The expert layer** (``parallel/moe.py``) sees 32 tokens a decode step
   (bound by the bytes of the experts they touch: on a TPU one kernel streams
   them, ``ops/grouped_swiglu.py``) and a whole wave's prompt tokens in
@@ -34,13 +35,19 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.llm.programs import (
-    MOE_STATS, ServePrograms, _sample_tail, moe_load_stats)
+    MOE_STATS, ServePrograms, _sample_tail, decode_frame, last_rows,
+    moe_load_stats, reads_in_place)
 from ray_tpu.models.mla_moe import (
     MlaMoeConfig, mla_absorb, mla_attend_absorbed, mla_attend_expanded,
-    mla_expand, mla_moe_ffn, mla_project)
+    mla_expand, mla_moe_ffn, mla_moe_init, mla_project)
 from ray_tpu.ops.basic import rms_norm, rope_freqs
 from ray_tpu.ops.paged_attention import paged_latent_attention
 from ray_tpu.utils import tracing
+
+# The seam's platform rule under this module's own name, asked through this
+# global by every program here and by ``PROGRAMS.decode_in_place``: ``tests/``
+# ASSIGN an answer here to run the kernels interpreted.
+_reads_in_place = reads_in_place
 
 
 def make_latent_pool(cfg: MlaMoeConfig, page_size: int, n_pages: int,
@@ -53,24 +60,14 @@ def make_latent_pool(cfg: MlaMoeConfig, page_size: int, n_pages: int,
                       dtype),)
 
 
-def _reads_in_place(pool) -> bool:
-    """Whether the decode step attends this pool where it lies
-    (``paged_latent_attention``: only the pages that hold tokens) or through
-    the gathered window ``pool[i][page_tables]``. Decided by what the code
-    can see, no option, as ``llm/llama.py``'s: on a TPU the kernel; on every
-    other backend, where it would be interpreted, the window — which thereby
-    stays the kernel's plain reference and what the CPU tests run. The
-    family has one kind of pool, so the pool itself decides nothing yet."""
-    return jax.default_backend() == "tpu"
-
-
-def _decode_body(params, tokens, pos, page_tables, pool, active, temps, key,
+def _decode_body(params, tokens, pos, page_tables, cache, active, temps, key,
                  cfg: MlaMoeConfig):
     """One decode step for every slot (masked where inactive), absorbed
     attention over each slot's ``pos + 1`` cached rows: in place through the
     page table (an inactive slot attends nothing) where ``_reads_in_place``
     holds, else over the whole gathered window with the positions past
-    ``pos`` masked. Returns (next_tok [B], pool, stats)."""
+    ``pos`` masked. Returns (next_tok [B], cache, stats)."""
+    pool, = cache
     B = tokens.shape[0]
     L, P, PS, W = pool.shape
     MAXP = page_tables.shape[1]
@@ -78,7 +75,7 @@ def _decode_body(params, tokens, pos, page_tables, pool, active, temps, key,
     positions = pos[:, None]
     row = jnp.take_along_axis(page_tables, (pos // PS)[:, None], axis=1)[:, 0]
     off = pos % PS
-    in_place = _reads_in_place(pool)
+    in_place = _reads_in_place()
     if in_place:
         lengths = jnp.where(active, pos + 1, 0)
     else:
@@ -113,7 +110,7 @@ def _decode_body(params, tokens, pos, page_tables, pool, active, temps, key,
         x = rms_norm(x, params["norm"]["scale"])
         logits = x[:, 0] @ params["lm_head"]["kernel"]
     next_tok = _sample_tail(logits, temps, key)
-    return (jnp.where(active, next_tok, 0), pool,
+    return (jnp.where(active, next_tok, 0), (pool,),
             moe_load_stats(loads, B * cfg.n_experts_per_tok))
 
 
@@ -125,16 +122,8 @@ def mla_moe_decode_multi(params, loras, aids, tokens, seq_lens, page_tables,
     ``llm/llama.py`` ``paged_decode_multi`` with one latent pool in place of the K
     and V pools, and rows of ``[B tokens | MOE_STATS]``. ``loras``/``aids`` are
     the engine's (None / zeros here: refused at construction)."""
-    def step(carry, k):
-        tok, pos, pool = carry
-        nxt, pool, stats = _decode_body(
-            params, tok, pos, page_tables, pool, active, temps,
-            jax.random.fold_in(key, k), cfg)
-        return (nxt, pos + 1, pool), jnp.concatenate([nxt, stats])
-
-    (tok, pos, pool), rows = jax.lax.scan(
-        step, (tokens, seq_lens, pool), jnp.arange(n_steps))
-    return rows, tok, pos, pool
+    return decode_frame(_decode_body, params, tokens, seq_lens, page_tables,
+                        (pool,), active, temps, key, cfg, n_steps)
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnums=(5,))
@@ -169,13 +158,13 @@ def mla_moe_prefill_batch(params, loras, aids, tokens, pages, pool,
         x, _ = mla_moe_ffn(layer, x, cfg, valid=valid)
     with tracing.part("head"):
         x = rms_norm(x, params["norm"]["scale"])
-        last = jnp.take_along_axis(
-            x, (true_lens - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-        logits = last @ params["lm_head"]["kernel"]
+        logits = last_rows(x, true_lens) @ params["lm_head"]["kernel"]
     return _sample_tail(logits, temps, key), pool
 
 
 PROGRAMS = ServePrograms(
     family="mla_moe", make_cache=make_latent_pool,
     decode_multi=mla_moe_decode_multi, prefill_batch=mla_moe_prefill_batch,
-    stats=MOE_STATS, decode_in_place=lambda cache: _reads_in_place(cache[0]))
+    init=mla_moe_init, stats=MOE_STATS,
+    decode_in_place=lambda cache: _reads_in_place(),
+    caches="one latent row a position, keys and values in one pool")

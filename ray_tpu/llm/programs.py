@@ -1,30 +1,33 @@
 """The seam between the engine and the model families. ``llm/engine.py``
 owns slots, pages, admission and the loops and knows no model: it asks
-``serving_programs(cfg)`` for the family's ``ServePrograms`` by the config's
-TYPE and threads the family's cache through unseen. A family's program
-module (``llm/llama.py``, ``llm/mla_moe.py``, ``llm/cohere2_moe.py``,
-``llm/sparse_moe.py``, ``llm/ssm_moe.py``, ``llm/eva.py``) imports this file,
-``models/`` and ``ops/``, never the engine, and is imported when its config is
-served.
-A new family supplies a config type, its layer's halves in ``models/``, two
-jitted programs, a cache, and one branch of ``serving_programs``. What a slot
-caches is the family's: pages that grow with the sequence (K and V, a latent,
-a window's ring, an indexer's keys), rows that each stand for a stride of
-positions (a chunk's pooled pair), or a state of fixed size.
+``serving_programs(cfg)`` for the family's ``ServePrograms`` and threads the
+family's cache through unseen. This file names no family either: a config
+class says whose programs serve it (``family = "eva"``), and
+``ray_tpu.llm.<family>`` is imported when such a config is served.
+
+A new family supplies ``models/<family>.py`` (a config type with its
+``family``, a seeded init, its layer's halves) and ``llm/<family>.py`` (a
+cache, a ``_decode_body``, two jitted programs and ``PROGRAMS``), with its
+stats' counters in ``utils/metrics.py`` ``LLM_MODEL_STATS`` and its parts in
+``tracing.PARTS`` — and no line of this file, the engine or another family:
+a family's module imports this file, its own ``models/`` module and ``ops/``,
+never the engine and never another family. What a slot caches is the
+family's: pages that grow with the sequence (K and V, a latent, a window's
+ring, an indexer's keys), rows that each stand for a stride of positions (a
+chunk's pooled pair), or a state of fixed size.
+
+What the families share is here, once: the platform rule (``reads_in_place``),
+the frame of a fused decode program (``decode_frame``), a prefill's last rows
+(``last_rows``), the sampling tail and the expert layers' stats.
 """
 from __future__ import annotations
 
+import importlib
 from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.cohere2_moe import Cohere2MoeConfig
-from ray_tpu.models.eva import EvaConfig
-from ray_tpu.models.llama import LlamaConfig
-from ray_tpu.models.mla_moe import MlaMoeConfig
-from ray_tpu.models.sparse_moe import SparseMoeConfig
-from ray_tpu.models.ssm_moe import SsmMoeConfig
 from ray_tpu.parallel.moe import expert_passes
 from ray_tpu.utils import tracing
 
@@ -33,8 +36,8 @@ class UnsupportedByModel(NotImplementedError):
     """A feature of the engine that a model family's programs do not have,
     refused by name (never a silent read of a pool that is not there)."""
 
-    def __init__(self, feature: str, family: str):
-        caches = CACHES.get(family)
+    def __init__(self, feature: str, programs: ServePrograms):
+        family, caches = programs.family, programs.caches
         super().__init__(
             f"{feature} is not supported for the {family!r} model family: "
             f"it is a program of the Llama family's (what a slot caches is "
@@ -42,22 +45,6 @@ class UnsupportedByModel(NotImplementedError):
             f"prefix of the sequence), and this family's programs have no "
             f"such form" + (f" (a slot of it caches {caches})" if caches else ""))
         self.feature, self.family = feature, family
-
-
-# what a slot of each family caches where that is not K and V pages of every
-# layer: the reason a refusal gives
-CACHES = {
-    "mla_moe": "one latent row a position, keys and values in one pool",
-    "cohere2_moe": "full layers' pages and a ring of the window layers' "
-                   "pages, which holds the last window alone",
-    "sparse_moe": "K, V and the indexer's key pages, of which a step attends "
-                  "the rows it picks",
-    "ssm_moe": "K and V pages of its attention blocks and one state row of "
-               "its recurrent blocks, which holds no positions",
-    "eva": "a ring of exact K and V pages for its own window alone and one "
-           "pooled pair for every chunk before it: a prefix of its pages is "
-           "no prefix of the sequence",
-}
 
 
 @dataclass(frozen=True)
@@ -144,11 +131,15 @@ class ServePrograms:
     ``prepare(params, cfg) -> params``: the tree in the layout the programs
     read fastest, derived ONCE, when the engine takes a tree (its ``params``
     setter), so that no program lays a weight out again; it may re-lay the
-    tree it is given in place. None: the programs read the tree as it comes."""
+    tree it is given in place. None: the programs read the tree as it comes.
+    ``init(key, cfg) -> params``: the family's seeded tree. ``caches``: what
+    a slot caches where that is not K and V pages of every layer — the
+    reason a refusal gives (``UnsupportedByModel``)."""
     family: str
     make_cache: callable
     decode_multi: callable
     prefill_batch: callable
+    init: callable
     stats: tuple = ()
     decode_in_place: callable = None
     page_kinds: callable = None
@@ -161,35 +152,57 @@ class ServePrograms:
     int8_cache: bool = False
     page_plane: bool = False   # export_pages / submit_prefilled (disagg)
     prepare: callable = None
+    caches: str = ""
 
 
 def serving_programs(cfg) -> ServePrograms:
-    """The programs that serve ``cfg``, by its type: no option chooses."""
-    if isinstance(cfg, LlamaConfig):
-        from ray_tpu.llm.llama import PROGRAMS
+    """The programs that serve ``cfg``: those of the family its class names.
+    No option chooses, and only that family's module is imported."""
+    family = getattr(type(cfg), "family", None)
+    if not isinstance(family, str):
+        raise TypeError(f"no serving programs for a {type(cfg).__name__}")
+    return importlib.import_module(f"ray_tpu.llm.{family}").PROGRAMS
 
-        return PROGRAMS
-    if isinstance(cfg, MlaMoeConfig):
-        from ray_tpu.llm.mla_moe import PROGRAMS
 
-        return PROGRAMS
-    if isinstance(cfg, Cohere2MoeConfig):
-        from ray_tpu.llm.cohere2_moe import PROGRAMS
+def reads_in_place() -> bool:
+    """THE platform rule: the programs attend (score, select, advance a
+    state) through the Pallas kernels on a TPU and in the plain form — the
+    gathered table with a position mask, the kernels' reference and what the
+    CPU tests run — anywhere else, where a kernel would be interpreted.
+    Decided by what the code can see, no option. Every family binds it under
+    its own ``_reads_in_place`` and asks through that name."""
+    return jax.default_backend() == "tpu"
 
-        return PROGRAMS
-    if isinstance(cfg, SparseMoeConfig):
-        from ray_tpu.llm.sparse_moe import PROGRAMS
 
-        return PROGRAMS
-    if isinstance(cfg, SsmMoeConfig):
-        from ray_tpu.llm.ssm_moe import PROGRAMS
+def decode_frame(body, params, tokens, seq_lens, tables, cache, active, temps,
+                 key, cfg, n_steps: int, *closed):
+    """``n_steps`` fused decode steps as one ``lax.scan``: what every
+    family's ``decode_multi`` is around its own step. ``body(params, tok,
+    pos, tables, cache, active, temps, key, cfg, *closed) -> (next [B],
+    cache, stats)``: ``cache`` the tuple of the family's pools, ``key`` the
+    program's with the step's index folded in, ``stats`` the step's own
+    int32 sums (None: a family without) — they ride behind the tokens in
+    the step's row. ``closed``: what the family found once for all steps
+    (its tables' runs). Returns ``(rows [n_steps, B + stats], tok, pos,
+    *cache)``, the last token and position on the device for the next
+    block."""
+    def step(carry, k):
+        tok, pos, cache = carry
+        nxt, cache, stats = body(params, tok, pos, tables, cache, active,
+                                 temps, jax.random.fold_in(key, k), cfg,
+                                 *closed)
+        return (nxt, pos + 1, cache), (
+            nxt if stats is None else jnp.concatenate([nxt, stats]))
 
-        return PROGRAMS
-    if isinstance(cfg, EvaConfig):
-        from ray_tpu.llm.eva import PROGRAMS
+    (tok, pos, cache), rows = jax.lax.scan(
+        step, (tokens, seq_lens, cache), jnp.arange(n_steps))
+    return (rows, tok, pos, *cache)
 
-        return PROGRAMS
-    raise TypeError(f"no serving programs for a {type(cfg).__name__}")
+
+def last_rows(x, true_lens):
+    """Each prompt's row at its last true position. x: [N, T, D] -> [N, D]."""
+    return jnp.take_along_axis(
+        x, (true_lens - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
 
 
 @tracing.part("sample")

@@ -102,9 +102,10 @@ class LLMEngineServer:
         if params is None:
             import jax
 
-            from ray_tpu.models import init_fn
+            from ray_tpu.llm.programs import serving_programs
 
-            params = init_fn(model_config)(jax.random.PRNGKey(0), model_config)
+            params = serving_programs(model_config).init(
+                jax.random.PRNGKey(0), model_config)
         from ray_tpu.llm.engine import ContinuousBatchingEngine
 
         self.engine = ContinuousBatchingEngine(

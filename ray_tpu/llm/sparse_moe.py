@@ -15,9 +15,9 @@ of THREE pools on one kind of page and an attention that picks its keys.
   the table's width — and ``paged_decode_attention``'s ``selected``);
   anywhere else, where the kernels would be interpreted, the
   gathered table in the plain form — the kernels' reference and what the CPU
-  tests run (``_reads_in_place``, as the other families: decided by what the
-  code can see). **Walked, not gathered:** the attention fetches every live
-  page and masks the rows not picked. 2,048 picked rows x 2 pools x 32 slots
+  tests run (the seam's rule, bound here as ``_reads_in_place``: decided by
+  what the code can see). **Walked, not gathered:** the attention fetches
+  every live page and masks the rows not picked. 2,048 picked rows x 2 pools x 32 slots
   x 12 layers would be 1.6 M copies of 1 KB a step, and under seeded weights
   the picks are scattered (at 9k positions 97 % of the 16-token pages hold
   one), so skipping pages buys nothing; the walk is exact, and what it
@@ -60,11 +60,13 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.llm.programs import (
-    MOE_STATS, ServePrograms, _sample_tail, moe_load_stats)
+    MOE_STATS, ServePrograms, _sample_tail, decode_frame, last_rows,
+    moe_load_stats, reads_in_place)
 from ray_tpu.models.sparse_moe import (
-    SparseMoeConfig, attend_plain, indexer_scores, sparse_attn_out,
-    sparse_experts, sparse_index, sparse_logits, sparse_project,
+    SparseMoeConfig, indexer_scores, sparse_attn_out, sparse_experts,
+    sparse_index, sparse_logits, sparse_moe_init, sparse_project,
     sparse_rope_freqs, sparse_select)
+from ray_tpu.ops.attention import masked_attention
 from ray_tpu.ops.basic import rms_norm
 from ray_tpu.ops.paged_attention import (
     kv_block, paged_decode_attention, run_lengths, walk_copies)
@@ -81,6 +83,10 @@ WAVE_LIMIT = (8, 16384)
 SPARSE_STATS = ("sparse_scored", "sparse_attended", "sparse_kv_fetched",
                 "sparse_walk_blocks", "sparse_walk_run_blocks",
                 "sparse_select_walked", "sparse_select_width")
+# The seam's platform rule under this module's own name, asked through this
+# global by every program here and by ``PROGRAMS.decode_in_place``: ``tests/``
+# ASSIGN an answer here to run the kernels interpreted.
+_reads_in_place = reads_in_place
 
 
 def make_pools(cfg: SparseMoeConfig, page_size: int, n_pages: int, kv_dtype):
@@ -93,13 +99,6 @@ def make_pools(cfg: SparseMoeConfig, page_size: int, n_pages: int, kv_dtype):
     return (jnp.zeros(kv, dtype), jnp.zeros(kv, dtype),
             jnp.zeros((cfg.n_layers, n_pages, page_size // per,
                        per * cfg.indexer_head_dim), dtype))
-
-
-def _reads_in_place() -> bool:
-    """Whether the programs score and attend through the Pallas kernels (on
-    a TPU) or in the plain form (anywhere else, where the kernels would be
-    interpreted): decided by what the code can see, no option."""
-    return jax.default_backend() == "tpu"
 
 
 def _table_runs(tables, kpool):
@@ -177,7 +176,7 @@ def _decode_body(params, tokens, pos, tables, cache, active, temps, key,
                     lengths, selected=picked, runs=selected_runs
                 ).reshape(B, 1, -1).astype(x.dtype)
             else:
-                att = attend_plain(
+                att = masked_attention(
                     q, kpool[i][tables].reshape(
                         B, MAXP * PS, *kpool.shape[3:]).astype(q.dtype),
                     vpool[i][tables].reshape(
@@ -215,18 +214,9 @@ def sparse_moe_decode_multi(params, loras, aids, tokens, seq_lens, tables,
     ``ServePrograms.decode_multi`` with three pools, rows of ``[B tokens |
     MOE_STATS | SPARSE_STATS]``. ``loras``/``aids`` are the engine's (None /
     zeros here: refused at construction)."""
-    runs = _table_runs(tables, kpool)
-
-    def step(carry, k):
-        tok, pos, cache = carry
-        nxt, cache, stats = _decode_body(
-            params, tok, pos, tables, cache, active, temps,
-            jax.random.fold_in(key, k), cfg, runs)
-        return (nxt, pos + 1, cache), jnp.concatenate([nxt, stats])
-
-    (tok, pos, cache), rows = jax.lax.scan(
-        step, (tokens, seq_lens, (kpool, vpool, ipool)), jnp.arange(n_steps))
-    return (rows, tok, pos, *cache)
+    return decode_frame(_decode_body, params, tokens, seq_lens, tables,
+                        (kpool, vpool, ipool), active, temps, key, cfg,
+                        n_steps, _table_runs(tables, kpool))
 
 
 @tracing.part("indexer")
@@ -294,21 +284,22 @@ def sparse_moe_prefill_batch(params, loras, aids, tokens, pages, kpool, vpool,
                     v.reshape(N, Tp, -1), n_kv_heads=cfg.n_kv_heads,
                     picked=picked)
             else:
-                att = attend_plain(q, k, v, picked != 0)
+                att = masked_attention(q, k, v, picked != 0)
         x = x + sparse_attn_out(layer, att)
         with tracing.part("ffn"):
             h = rms_norm(x, layer["ffn_norm"]["scale"], cfg.rms_norm_eps)
         y, _ = sparse_experts(layer, h, cfg, valid=valid)
         x = x + y
-    last_x = jnp.take_along_axis(
-        x, (true_lens - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    logits = sparse_logits(params, last_x, cfg)
+    logits = sparse_logits(params, last_rows(x, true_lens), cfg)
     return _sample_tail(logits, temps, key), kpool, vpool, ipool
 
 
 PROGRAMS = ServePrograms(
     family="sparse_moe", make_cache=make_pools,
     decode_multi=sparse_moe_decode_multi,
-    prefill_batch=sparse_moe_prefill_batch, stats=MOE_STATS + SPARSE_STATS,
+    prefill_batch=sparse_moe_prefill_batch, init=sparse_moe_init,
+    stats=MOE_STATS + SPARSE_STATS,
     decode_in_place=lambda cache: _reads_in_place(),
-    prefill_wave_limit=WAVE_LIMIT, attends_most=lambda cfg: cfg.topk)
+    prefill_wave_limit=WAVE_LIMIT, attends_most=lambda cfg: cfg.topk,
+    caches="K, V and the indexer's key pages, of which a step attends the "
+           "rows it picks")

@@ -24,7 +24,7 @@ kinds of which ONE DOES NOT GROW, and a block a pattern character.
   ``ops/ssm.py``'s one-step form, plain XLA operations and the kernel's
   reference. Attention likewise reads its pages where they lie
   (``ops/paged_attention.py``) or, off the TPU, the gathered table with a
-  position mask: one switch, ``_reads_in_place``, as the other families.
+  position mask: one switch, the seam's rule bound here as ``_reads_in_place``.
   Which of a table's entries lie one after the other in the pool — what the
   walk fetches as ONE copy, at 8 KB a page the difference between a sixth of
   the bandwidth and most of it — is found once a program
@@ -55,14 +55,15 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.llm.cohere2_moe import _attend_gathered, _reads_in_place
 from ray_tpu.llm.programs import (
-    MOE_STATS, PageKind, ServePrograms, _sample_tail, moe_load_stats)
+    MOE_STATS, PageKind, ServePrograms, _sample_tail, decode_frame, last_rows,
+    moe_load_stats, reads_in_place)
 from ray_tpu.models.ssm_moe import (
-    ATTENTION, MAMBA, SsmMoeConfig, attend_plain, attn_project, expert_block,
-    gated_norm, mamba_decay, mamba_dt, mamba_in, mamba_mixer, mixer_out,
-    split_conv, ssm_moe_logits)
+    ATTENTION, MAMBA, SsmMoeConfig, attn_project, expert_block, gated_norm,
+    mamba_decay, mamba_dt, mamba_in, mamba_mixer, mixer_out, split_conv,
+    ssm_moe_init, ssm_moe_logits)
 from ray_tpu.ops import ssm
+from ray_tpu.ops.attention import gathered_attention, masked_attention
 from ray_tpu.ops.paged_attention import (
     kv_block, paged_decode_attention, run_lengths, walk_copies)
 from ray_tpu.ops.prefill_attention import blocks_for, gqa_prefill_attention
@@ -76,6 +77,10 @@ WAVE_LIMIT = (8, 16384)
 # attention blocks' walks — sub-runs of a block fetched, and those of them
 # that came as ONE copy or inside a block's — each over blocks and live slots
 STATS = MOE_STATS + ("ssm_updates", "walk_blocks", "walk_run_blocks")
+# The seam's platform rule under this module's own name, asked through this
+# global by every program here and by ``PROGRAMS.decode_in_place``: ``tests/``
+# ASSIGN an answer here to run the kernels interpreted.
+_reads_in_place = reads_in_place
 
 
 def page_kinds(cfg: SsmMoeConfig, page_size: int, max_seq_len: int):
@@ -180,7 +185,7 @@ def _decode_body(params, tokens, pos, tables, cache, active, temps, key,
                         runs=runs)
                     att = att.reshape(B, 1, -1).astype(x.dtype)
             else:
-                att = _attend_gathered(q, kp[j], vp[j], t_kv, pos, cfg, False)
+                att = gathered_attention(q, kp[j], vp[j], t_kv, pos)
             y = mixer_out(layer, att, "wo")
         else:
             y, load = expert_block(layer, x, cfg, valid=active[:, None])
@@ -209,17 +214,9 @@ def ssm_moe_decode_multi(params, loras, aids, tokens, seq_lens, tables,
     rows) and four pools, rows of ``[B tokens | STATS]``. ``loras``/``aids``
     are the engine's (None / zeros here: refused at construction)."""
     runs = run_lengths(tables[0]) if _reads_in_place() else None
-
-    def step(carry, k):
-        tok, pos, cache = carry
-        nxt, cache, stats = _decode_body(
-            params, tok, pos, tables, cache, active, temps,
-            jax.random.fold_in(key, k), cfg, runs)
-        return (nxt, pos + 1, cache), jnp.concatenate([nxt, stats])
-
-    (tok, pos, cache), rows = jax.lax.scan(
-        step, (tokens, seq_lens, (kp, vp, states, convs)), jnp.arange(n_steps))
-    return (rows, tok, pos, *cache)
+    return decode_frame(_decode_body, params, tokens, seq_lens, tables,
+                        (kp, vp, states, convs), active, temps, key, cfg,
+                        n_steps, runs)
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnums=(5, 6, 7, 8))
@@ -262,20 +259,21 @@ def ssm_moe_prefill_batch(params, loras, aids, tokens, pages, kp, vp, states,
                         q.reshape(N, Tp, -1), k.reshape(N, Tp, -1),
                         v.reshape(N, Tp, -1), n_kv_heads=cfg.n_kv_heads)
             else:
-                att = attend_plain(q, k, v, jnp.broadcast_to(
+                att = masked_attention(q, k, v, jnp.broadcast_to(
                     idx[:, None] >= idx[None, :], (N, Tp, Tp)))
             y = mixer_out(layer, att, "wo")
         else:
             y, _ = expert_block(layer, x, cfg, valid=valid)
         x = x + y
-    last_x = jnp.take_along_axis(
-        x, (true_lens - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    logits = ssm_moe_logits(params, last_x, cfg)
+    logits = ssm_moe_logits(params, last_rows(x, true_lens), cfg)
     return _sample_tail(logits, temps, key), kp, vp, states, convs
 
 
 PROGRAMS = ServePrograms(
     family="ssm_moe", make_cache=make_pools,
     decode_multi=ssm_moe_decode_multi, prefill_batch=ssm_moe_prefill_batch,
-    stats=STATS, decode_in_place=lambda cache: _reads_in_place(),
-    page_kinds=page_kinds, prefill_wave_limit=WAVE_LIMIT)
+    init=ssm_moe_init, stats=STATS,
+    decode_in_place=lambda cache: _reads_in_place(), page_kinds=page_kinds,
+    prefill_wave_limit=WAVE_LIMIT,
+    caches="K and V pages of its attention blocks and one state row of its "
+           "recurrent blocks, which holds no positions")

@@ -14,19 +14,3 @@ from ray_tpu.models.sparse_moe import (  # noqa: F401
 from ray_tpu.models.ssm_moe import (  # noqa: F401
     SsmMoeConfig, ssm_moe_forward, ssm_moe_init)
 
-
-def init_fn(cfg):
-    """The seeded ``init(key, cfg)`` of a config's family, by its type."""
-    if isinstance(cfg, LlamaConfig):
-        return llama_init
-    if isinstance(cfg, MlaMoeConfig):
-        return mla_moe_init
-    if isinstance(cfg, Cohere2MoeConfig):
-        return cohere2_moe_init
-    if isinstance(cfg, SparseMoeConfig):
-        return sparse_moe_init
-    if isinstance(cfg, SsmMoeConfig):
-        return ssm_moe_init
-    if isinstance(cfg, EvaConfig):
-        return eva_init
-    raise TypeError(f"no model for a {type(cfg).__name__}")

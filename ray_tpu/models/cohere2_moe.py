@@ -31,8 +31,9 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.mla_moe import _dense, _experts
-from ray_tpu.ops.basic import layer_norm, rope_freqs, rope_pairs
+from ray_tpu.ops.attention import masked_attention
+from ray_tpu.ops.basic import (
+    dense_init, experts_init, layer_norm, rope_freqs, rope_pairs)
 from ray_tpu.parallel.moe import moe_layer_chunked
 from ray_tpu.utils import tracing
 
@@ -41,6 +42,7 @@ WINDOW, FULL = "sliding_attention", "full_attention"
 
 @dataclasses.dataclass(frozen=True)
 class Cohere2MoeConfig:
+    family = "cohere2_moe"   # whose programs serve it: ray_tpu.llm.<family>
     vocab_size: int = 262144          # rows of the embedding held HERE
     d_model: int = 4096
     n_layers: int = 32
@@ -112,22 +114,22 @@ def cohere2_moe_layer_init(key, cfg: Cohere2MoeConfig) -> dict:
     k = jax.random.split(key, 11)
     return {
         "norm": {"scale": jnp.ones((D,), dtype)},
-        "wq": _dense(k[0], D, H * hd, dtype),
-        "wk": _dense(k[1], D, KV * hd, dtype),
-        "wv": _dense(k[2], D, KV * hd, dtype),
-        "wo": _dense(k[3], H * hd, D, dtype),
+        "wq": dense_init(k[0], D, H * hd, dtype),
+        "wk": dense_init(k[1], D, KV * hd, dtype),
+        "wv": dense_init(k[2], D, KV * hd, dtype),
+        "wo": dense_init(k[3], H * hd, D, dtype),
         "moe": {
-            "router": {"kernel": _dense(k[4], D, cfg.n_experts, dtype)["kernel"]},
+            "router": {"kernel": dense_init(k[4], D, cfg.n_experts, dtype)["kernel"]},
             # every holder draws all experts' numbers and keeps its own, so
             # the shares of one seed are slices of one model
             "experts": {
-                "w_gate": _experts(k[5], cfg.n_experts, D, F, dtype)[lo:hi],
-                "w_up": _experts(k[6], cfg.n_experts, D, F, dtype)[lo:hi],
-                "w_down": _experts(k[7], cfg.n_experts, F, D, dtype)[lo:hi],
+                "w_gate": experts_init(k[5], cfg.n_experts, D, F, dtype)[lo:hi],
+                "w_up": experts_init(k[6], cfg.n_experts, D, F, dtype)[lo:hi],
+                "w_down": experts_init(k[7], cfg.n_experts, F, D, dtype)[lo:hi],
             },
-            "shared": {"w_gate": _dense(k[8], D, Fs, dtype),
-                       "w_up": _dense(k[9], D, Fs, dtype),
-                       "w_down": _dense(k[10], Fs, D, dtype)},
+            "shared": {"w_gate": dense_init(k[8], D, Fs, dtype),
+                       "w_up": dense_init(k[9], D, Fs, dtype),
+                       "w_down": dense_init(k[10], Fs, D, dtype)},
         },
     }
 
@@ -162,22 +164,6 @@ def cohere2_project(layer, h, cos, sin, positions, cfg: Cohere2MoeConfig,
         q = rope_pairs(q, cos, sin, positions)
         k = rope_pairs(k, cos, sin, positions)
     return q, k, v
-
-
-@tracing.part("attention")
-def cohere2_attend_plain(q, k, v, mask):
-    """Masked grouped-query attention with the scores written out: the plain
-    form (the no-cache forward, and the serving programs off the TPU). q:
-    [B, Tq, H, hd]; k, v: [B, Tk, KV, hd]; mask: [B, Tq, Tk]. Returns
-    [B, Tq, H * hd]."""
-    B, Tq, H, d = q.shape
-    KV = k.shape[2]
-    qg = q.reshape(B, Tq, KV, H // KV, d)
-    s = jnp.einsum("bqkgd,bskd->bkgqs", qg, k).astype(jnp.float32)
-    s = jnp.where(mask[:, None, None], s / jnp.sqrt(jnp.float32(d)),
-                  jnp.float32(-1e30))
-    w = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-    return jnp.einsum("bkgqs,bskd->bqkgd", w, v).reshape(B, Tq, H * d)
 
 
 @tracing.part("attn_out")
@@ -227,7 +213,7 @@ def cohere2_moe_forward(params, tokens, cfg: Cohere2MoeConfig):
         q, k, v = cohere2_project(layer, h, cos, sin, positions, cfg, window)
         mask = jnp.broadcast_to(
             cohere2_reach(idx[:, None], idx[None, :], cfg, window), (B, T, T))
-        att = cohere2_attend_plain(q, k, v, mask)
+        att = masked_attention(q, k, v, mask)
         y, _ = cohere2_experts(layer, h, cfg)
         x = x + cohere2_attn_out(layer, att) + y
     return cohere2_logits(params, x, cfg)

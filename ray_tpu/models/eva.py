@@ -34,8 +34,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.mla_moe import _dense
-from ray_tpu.ops.basic import rms_norm, rope, rope_freqs, swiglu
+from ray_tpu.ops.basic import dense_init, rms_norm, rope, rope_freqs, swiglu
 from ray_tpu.utils import tracing
 
 _HI = jax.lax.Precision.HIGHEST
@@ -43,6 +42,7 @@ _HI = jax.lax.Precision.HIGHEST
 
 @dataclasses.dataclass(frozen=True)
 class EvaConfig:
+    family = "eva"   # whose programs serve it: ray_tpu.llm.<family>
     vocab_size: int = 320
     d_model: int = 4096
     n_layers: int = 32
@@ -87,15 +87,15 @@ def eva_layer_init(key, cfg: EvaConfig) -> dict:
 
     return {
         "attn_norm": {"scale": jnp.zeros((D,), dtype)},  # N(x) . (1 + g)
-        "wq": _dense(k[0], D, H * hd, dtype),
-        "wk": _dense(k[1], D, H * hd, dtype),
-        "wv": _dense(k[2], D, H * hd, dtype),
-        "wo": _dense(k[3], H * hd, D, dtype),
+        "wq": dense_init(k[0], D, H * hd, dtype),
+        "wk": dense_init(k[1], D, H * hd, dtype),
+        "wv": dense_init(k[2], D, H * hd, dtype),
+        "wo": dense_init(k[3], H * hd, D, dtype),
         "phi": per_head(k[4]), "mu": per_head(k[5]),
         "ffn_norm": {"scale": jnp.zeros((D,), dtype)},
-        "w_gate": _dense(k[6], D, cfg.d_ff, dtype),
-        "w_up": _dense(k[7], D, cfg.d_ff, dtype),
-        "w_down": _dense(k[8], cfg.d_ff, D, dtype),
+        "w_gate": dense_init(k[6], D, cfg.d_ff, dtype),
+        "w_up": dense_init(k[7], D, cfg.d_ff, dtype),
+        "w_down": dense_init(k[8], cfg.d_ff, D, dtype),
     }
 
 
@@ -107,7 +107,7 @@ def eva_init(key, cfg: EvaConfig) -> dict:
     for i in range(cfg.n_layers):
         params[f"layers_{i}"] = eva_layer_init(keys[1 + i], cfg)
     params["norm"] = {"scale": jnp.zeros((cfg.d_model,), dtype)}
-    params["lm_head"] = _dense(keys[-1], cfg.d_model,
+    params["lm_head"] = dense_init(keys[-1], cfg.d_model,
                                cfg.n_pred_heads * cfg.vocab_size, dtype)
     return params
 

@@ -29,12 +29,13 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name as _checkpoint_name
 
 from ray_tpu.ops.attention import attention
-from ray_tpu.ops.basic import rms_norm, rope, rope_freqs, swiglu
+from ray_tpu.ops.basic import dense_init, rms_norm, rope, rope_freqs, swiglu
 from ray_tpu.utils import tracing
 
 
 @dataclasses.dataclass(frozen=True)
 class LlamaConfig:
+    family = "llama"   # whose programs serve it: ray_tpu.llm.<family>
     vocab_size: int = 32000
     d_model: int = 4096
     n_layers: int = 32
@@ -71,11 +72,6 @@ class LlamaConfig:
                    rope_theta=500000.0)
 
 
-def _dense(key, d_in, d_out, dtype):
-    scale = (2.0 / (d_in + d_out)) ** 0.5
-    return {"kernel": (jax.random.normal(key, (d_in, d_out)) * scale).astype(dtype)}
-
-
 def _is_moe_layer(cfg: LlamaConfig, i: int) -> bool:
     return cfg.n_experts > 0 and (i % cfg.moe_every == cfg.moe_every - 1)
 
@@ -95,10 +91,10 @@ def llama_init(key, cfg: LlamaConfig) -> dict:
     for i in range(cfg.n_layers):
         layer = {
             "attn_norm": {"scale": jnp.ones((cfg.d_model,), dtype)},
-            "wq": _dense(keys[next(ki)], cfg.d_model, cfg.n_heads * hd, dtype),
-            "wk": _dense(keys[next(ki)], cfg.d_model, cfg.n_kv_heads * hd, dtype),
-            "wv": _dense(keys[next(ki)], cfg.d_model, cfg.n_kv_heads * hd, dtype),
-            "wo": _dense(keys[next(ki)], cfg.n_heads * hd, cfg.d_model, dtype),
+            "wq": dense_init(keys[next(ki)], cfg.d_model, cfg.n_heads * hd, dtype),
+            "wk": dense_init(keys[next(ki)], cfg.d_model, cfg.n_kv_heads * hd, dtype),
+            "wv": dense_init(keys[next(ki)], cfg.d_model, cfg.n_kv_heads * hd, dtype),
+            "wo": dense_init(keys[next(ki)], cfg.n_heads * hd, cfg.d_model, dtype),
             "ffn_norm": {"scale": jnp.ones((cfg.d_model,), dtype)},
         }
         if _is_moe_layer(cfg, i):
@@ -110,12 +106,12 @@ def llama_init(key, cfg: LlamaConfig) -> dict:
                 "w_down": {"kernel": (jax.random.normal(k3, (e, cfg.d_ff, cfg.d_model)) * 0.02).astype(dtype)},
             }
         else:
-            layer["w_gate"] = _dense(keys[next(ki)], cfg.d_model, cfg.d_ff, dtype)
-            layer["w_up"] = _dense(keys[next(ki)], cfg.d_model, cfg.d_ff, dtype)
-            layer["w_down"] = _dense(keys[next(ki)], cfg.d_ff, cfg.d_model, dtype)
+            layer["w_gate"] = dense_init(keys[next(ki)], cfg.d_model, cfg.d_ff, dtype)
+            layer["w_up"] = dense_init(keys[next(ki)], cfg.d_model, cfg.d_ff, dtype)
+            layer["w_down"] = dense_init(keys[next(ki)], cfg.d_ff, cfg.d_model, dtype)
         params[f"layers_{i}"] = layer
     params["norm"] = {"scale": jnp.ones((cfg.d_model,), dtype)}
-    params["lm_head"] = _dense(keys[next(ki)], cfg.d_model, cfg.vocab_size, dtype)
+    params["lm_head"] = dense_init(keys[next(ki)], cfg.d_model, cfg.vocab_size, dtype)
     return params
 
 

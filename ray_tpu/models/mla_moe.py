@@ -27,13 +27,15 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.basic import rms_norm, rope, rope_freqs, swiglu
+from ray_tpu.ops.basic import (
+    dense_init, experts_init, rms_norm, rope, rope_freqs, swiglu)
 from ray_tpu.parallel.moe import moe_layer
 from ray_tpu.utils import tracing
 
 
 @dataclasses.dataclass(frozen=True)
 class MlaMoeConfig:
+    family = "mla_moe"   # whose programs serve it: ray_tpu.llm.<family>
     vocab_size: int = 128256
     d_model: int = 2048
     n_layers: int = 48
@@ -90,41 +92,31 @@ class MlaMoeConfig:
         return cls(**{**base, **kw})
 
 
-def _dense(key, d_in, d_out, dtype):
-    scale = (2.0 / (d_in + d_out)) ** 0.5
-    return {"kernel": (jax.random.normal(key, (d_in, d_out)) * scale).astype(dtype)}
-
-
-def _experts(key, n, d_in, d_out, dtype):
-    scale = (2.0 / (d_in + d_out)) ** 0.5
-    return (jax.random.normal(key, (n, d_in, d_out)) * scale).astype(dtype)
-
-
 def mla_moe_layer_init(key, cfg: MlaMoeConfig, i: int) -> dict:
     dtype = jnp.dtype(cfg.dtype)
     D, H, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
     k = jax.random.split(key, 12)
     layer = {
         "attn_norm": {"scale": jnp.ones((D,), dtype)},
-        "wq": _dense(k[0], D, H * cfg.qk_head_dim, dtype),
-        "wkv_a": _dense(k[1], D, cfg.latent_width, dtype),
+        "wq": dense_init(k[0], D, H * cfg.qk_head_dim, dtype),
+        "wkv_a": dense_init(k[1], D, cfg.latent_width, dtype),
         "kv_norm": {"scale": jnp.ones((r,), dtype)},
         # per head: [k_nope | v]
-        "wkv_b": _dense(k[2], r, H * (cfg.qk_nope_head_dim + cfg.v_head_dim),
+        "wkv_b": dense_init(k[2], r, H * (cfg.qk_nope_head_dim + cfg.v_head_dim),
                         dtype),
-        "wo": _dense(k[3], H * cfg.v_head_dim, D, dtype),
+        "wo": dense_init(k[3], H * cfg.v_head_dim, D, dtype),
         "ffn_norm": {"scale": jnp.ones((D,), dtype)},
     }
     if not cfg.is_moe_layer(i):
-        layer["w_gate"] = _dense(k[4], D, cfg.d_ff, dtype)
-        layer["w_up"] = _dense(k[5], D, cfg.d_ff, dtype)
-        layer["w_down"] = _dense(k[6], cfg.d_ff, D, dtype)
+        layer["w_gate"] = dense_init(k[4], D, cfg.d_ff, dtype)
+        layer["w_up"] = dense_init(k[5], D, cfg.d_ff, dtype)
+        layer["w_down"] = dense_init(k[6], cfg.d_ff, D, dtype)
         return layer
     lo, hi = cfg.held
     F, Fs = cfg.d_expert, cfg.n_shared_experts * cfg.d_expert
     layer["moe"] = {
         "router": {
-            "kernel": _dense(k[4], D, cfg.n_experts, dtype)["kernel"],
+            "kernel": dense_init(k[4], D, cfg.n_experts, dtype)["kernel"],
             # e_score_correction_bias; non-zero so that choosing by s + b
             # and weighing by s are two things
             "bias": 0.1 * jax.random.normal(k[5], (cfg.n_experts,)),
@@ -132,13 +124,13 @@ def mla_moe_layer_init(key, cfg: MlaMoeConfig, i: int) -> dict:
         # every holder draws all experts' numbers and keeps its own, so the
         # shares of one seed are slices of one model
         "experts": {
-            "w_gate": _experts(k[6], cfg.n_experts, D, F, dtype)[lo:hi],
-            "w_up": _experts(k[7], cfg.n_experts, D, F, dtype)[lo:hi],
-            "w_down": _experts(k[8], cfg.n_experts, F, D, dtype)[lo:hi],
+            "w_gate": experts_init(k[6], cfg.n_experts, D, F, dtype)[lo:hi],
+            "w_up": experts_init(k[7], cfg.n_experts, D, F, dtype)[lo:hi],
+            "w_down": experts_init(k[8], cfg.n_experts, F, D, dtype)[lo:hi],
         },
-        "shared": {"w_gate": _dense(k[9], D, Fs, dtype),
-                   "w_up": _dense(k[10], D, Fs, dtype),
-                   "w_down": _dense(k[11], Fs, D, dtype)},
+        "shared": {"w_gate": dense_init(k[9], D, Fs, dtype),
+                   "w_up": dense_init(k[10], D, Fs, dtype),
+                   "w_down": dense_init(k[11], Fs, D, dtype)},
     }
     return layer
 
@@ -152,7 +144,7 @@ def mla_moe_init(key, cfg: MlaMoeConfig) -> dict:
     for i in range(cfg.n_layers):
         params[f"layers_{i}"] = mla_moe_layer_init(keys[2 + i], cfg, i)
     params["norm"] = {"scale": jnp.ones((cfg.d_model,), dtype)}
-    params["lm_head"] = _dense(keys[1], cfg.d_model, cfg.vocab_size, dtype)
+    params["lm_head"] = dense_init(keys[1], cfg.d_model, cfg.vocab_size, dtype)
     return params
 
 

@@ -37,9 +37,9 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.cohere2_moe import cohere2_attend_plain as attend_plain
-from ray_tpu.models.mla_moe import _dense, _experts
-from ray_tpu.ops.basic import layer_norm, rms_norm, rope, rope_freqs
+from ray_tpu.ops.attention import masked_attention
+from ray_tpu.ops.basic import (
+    dense_init, experts_init, layer_norm, rms_norm, rope, rope_freqs)
 from ray_tpu.ops.select import topk_prefix_mask
 from ray_tpu.parallel.moe import moe_layer_chunked
 from ray_tpu.utils import tracing
@@ -47,6 +47,7 @@ from ray_tpu.utils import tracing
 
 @dataclasses.dataclass(frozen=True)
 class SparseMoeConfig:
+    family = "sparse_moe"   # whose programs serve it: ray_tpu.llm.<family>
     vocab_size: int = 151936          # rows of embedding and head held HERE
     d_model: int = 2048
     n_layers: int = 48
@@ -107,21 +108,21 @@ def sparse_moe_layer_init(key, cfg: SparseMoeConfig) -> dict:
     one = lambda n: {"scale": jnp.ones((n,), dtype)}  # noqa: E731
     return {
         "attn_norm": one(D), "ffn_norm": one(D),
-        "wq": _dense(k[0], D, H * hd, dtype), "q_norm": one(hd),
-        "wk": _dense(k[1], D, KV * hd, dtype), "k_norm": one(hd),
-        "wv": _dense(k[2], D, KV * hd, dtype),
-        "wo": _dense(k[3], H * hd, D, dtype),
-        "indexer": {"wq": _dense(k[4], D, J * dk, dtype),
-                    "wk": _dense(k[5], D, dk, dtype), "k_norm": one(dk),
-                    "w": _dense(k[6], D, J, dtype)},
+        "wq": dense_init(k[0], D, H * hd, dtype), "q_norm": one(hd),
+        "wk": dense_init(k[1], D, KV * hd, dtype), "k_norm": one(hd),
+        "wv": dense_init(k[2], D, KV * hd, dtype),
+        "wo": dense_init(k[3], H * hd, D, dtype),
+        "indexer": {"wq": dense_init(k[4], D, J * dk, dtype),
+                    "wk": dense_init(k[5], D, dk, dtype), "k_norm": one(dk),
+                    "w": dense_init(k[6], D, J, dtype)},
         "moe": {
-            "router": {"kernel": _dense(k[7], D, cfg.n_experts, dtype)["kernel"]},
+            "router": {"kernel": dense_init(k[7], D, cfg.n_experts, dtype)["kernel"]},
             # every holder draws all experts' numbers and keeps its own, so
             # the shares of one seed are slices of one model
             "experts": {
-                "w_gate": _experts(k[8], cfg.n_experts, D, F, dtype)[lo:hi],
-                "w_up": _experts(k[9], cfg.n_experts, D, F, dtype)[lo:hi],
-                "w_down": _experts(k[10], cfg.n_experts, F, D, dtype)[lo:hi],
+                "w_gate": experts_init(k[8], cfg.n_experts, D, F, dtype)[lo:hi],
+                "w_up": experts_init(k[9], cfg.n_experts, D, F, dtype)[lo:hi],
+                "w_down": experts_init(k[10], cfg.n_experts, F, D, dtype)[lo:hi],
             },
         },
     }
@@ -135,7 +136,7 @@ def sparse_moe_init(key, cfg: SparseMoeConfig) -> dict:
     for i in range(cfg.n_layers):
         params[f"layers_{i}"] = sparse_moe_layer_init(keys[2 + i], cfg)
     params["norm"] = {"scale": jnp.ones((cfg.d_model,), dtype)}
-    params["lm_head"] = _dense(keys[1], cfg.d_model, cfg.vocab_size, dtype)
+    params["lm_head"] = dense_init(keys[1], cfg.d_model, cfg.vocab_size, dtype)
     return params
 
 
@@ -233,7 +234,7 @@ def sparse_moe_forward(params, tokens, cfg: SparseMoeConfig):
         q, k, v = sparse_project(layer, h, freqs, positions, cfg)
         qi, ki, w = sparse_index(layer, h, freqs, positions, cfg)
         picked = sparse_select(indexer_scores(qi, w, ki), positions, cfg) != 0
-        x = x + sparse_attn_out(layer, attend_plain(q, k, v, picked))
+        x = x + sparse_attn_out(layer, masked_attention(q, k, v, picked))
         h = rms_norm(x, layer["ffn_norm"]["scale"], cfg.rms_norm_eps)
         y, _ = sparse_experts(layer, h, cfg)
         x = x + y

@@ -34,10 +34,9 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.cohere2_moe import cohere2_attend_plain as attend_plain
-from ray_tpu.models.mla_moe import _dense, _experts
+from ray_tpu.ops.attention import masked_attention
 from ray_tpu.ops import ssm
-from ray_tpu.ops.basic import rms_norm
+from ray_tpu.ops.basic import dense_init, experts_init, rms_norm
 from ray_tpu.parallel.moe import moe_layer_chunked
 from ray_tpu.utils import tracing
 
@@ -47,6 +46,7 @@ _PUBLISHED = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
 
 @dataclasses.dataclass(frozen=True)
 class SsmMoeConfig:
+    family = "ssm_moe"   # whose programs serve it: ray_tpu.llm.<family>
     vocab_size: int = 131072          # rows of embedding and head held HERE
     d_model: int = 2688
     pattern: str = _PUBLISHED         # one character a block: M, E or *
@@ -127,7 +127,7 @@ def ssm_moe_layer_init(key, cfg: SsmMoeConfig, kind: str) -> dict:
     if kind == MAMBA:
         Hm, C = cfg.mamba_heads, cfg.conv_width
         layer |= {
-            "in_proj": _dense(k[0], D, cfg.d_inner + C + Hm, dtype),
+            "in_proj": dense_init(k[0], D, cfg.d_inner + C + Hm, dtype),
             "conv": {"kernel": (jax.random.normal(k[1], (cfg.conv_kernel, C))
                                 * cfg.conv_kernel ** -0.5).astype(dtype),
                      "bias": jnp.zeros((C,), dtype)},
@@ -139,27 +139,27 @@ def ssm_moe_layer_init(key, cfg: SsmMoeConfig, kind: str) -> dict:
                 k[3], (Hm,), jnp.float32, jnp.log(1e-3), jnp.log(1e-1)))),
             "D": jnp.ones((Hm,), jnp.float32),
             "gate_norm": {"scale": jnp.ones((cfg.d_inner,), dtype)},
-            "out_proj": _dense(k[4], cfg.d_inner, D, dtype),
+            "out_proj": dense_init(k[4], cfg.d_inner, D, dtype),
         }
     elif kind == ATTENTION:
         H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-        layer |= {"wq": _dense(k[0], D, H * hd, dtype),
-                  "wk": _dense(k[1], D, KV * hd, dtype),
-                  "wv": _dense(k[2], D, KV * hd, dtype),
-                  "wo": _dense(k[3], H * hd, D, dtype)}
+        layer |= {"wq": dense_init(k[0], D, H * hd, dtype),
+                  "wk": dense_init(k[1], D, KV * hd, dtype),
+                  "wv": dense_init(k[2], D, KV * hd, dtype),
+                  "wo": dense_init(k[3], H * hd, D, dtype)}
     else:
         F, Fs = cfg.d_expert, cfg.n_shared_experts * cfg.d_shared
         lo, hi = cfg.held
         layer["moe"] = {
-            "router": {"kernel": _dense(k[0], D, cfg.n_experts, dtype)["kernel"],
+            "router": {"kernel": dense_init(k[0], D, cfg.n_experts, dtype)["kernel"],
                        "bias": jnp.zeros((cfg.n_experts,), jnp.float32)},
             # every holder draws all experts' numbers and keeps its own, so
             # the shares of one seed are slices of one model
             "experts": {
-                "w_up": _experts(k[1], cfg.n_experts, D, F, dtype)[lo:hi],
-                "w_down": _experts(k[2], cfg.n_experts, F, D, dtype)[lo:hi]},
-            "shared": {"w_up": _dense(k[3], D, Fs, dtype),
-                       "w_down": _dense(k[4], Fs, D, dtype)},
+                "w_up": experts_init(k[1], cfg.n_experts, D, F, dtype)[lo:hi],
+                "w_down": experts_init(k[2], cfg.n_experts, F, D, dtype)[lo:hi]},
+            "shared": {"w_up": dense_init(k[3], D, Fs, dtype),
+                       "w_down": dense_init(k[4], Fs, D, dtype)},
         }
     return layer
 
@@ -176,7 +176,7 @@ def ssm_moe_init(key, cfg: SsmMoeConfig) -> dict:
     for i, kind in enumerate(cfg.pattern):
         params[f"layers_{i}"] = ssm_moe_layer_init(keys[2 + i], cfg, kind)
     params["norm"] = {"scale": jnp.ones((cfg.d_model,), dtype)}
-    params["lm_head"] = _dense(keys[1], cfg.d_model, cfg.vocab_size, dtype)
+    params["lm_head"] = dense_init(keys[1], cfg.d_model, cfg.vocab_size, dtype)
     return params
 
 
@@ -299,7 +299,7 @@ def ssm_moe_forward(params, tokens, cfg: SsmMoeConfig):
             y, _, _ = mamba_mixer(layer, x, cfg)
         elif kind == ATTENTION:
             q, k, v = attn_project(layer, x, cfg)
-            y = mixer_out(layer, attend_plain(q, k, v, causal), "wo")
+            y = mixer_out(layer, masked_attention(q, k, v, causal), "wo")
         else:
             y, _ = expert_block(layer, x, cfg)
         x = x + y

@@ -4,6 +4,10 @@
 - pallas flash attention on TPU (ops/flash_attention.py) for long T
 - ring attention over the sp mesh axis when sequence is sharded
 - ulysses all-to-all variant for head-divisible meshes
+
+Beside it, the two plain forms the serving programs of more than one model
+family share (XLA operations only): masked attention with the scores written
+out, and a decode step's attention over a slot's gathered page table.
 """
 
 from __future__ import annotations
@@ -12,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.parallel.ring_attention import reference_attention
+from ray_tpu.utils import tracing
 
 
 def attention(q, k, v, *, causal: bool = True, sm_scale=None, mesh=None,
@@ -58,3 +63,50 @@ def _default_local_impl(q) -> str:
             and D in (64, 128, 256)):
         return "flash"
     return "plain"
+
+
+@tracing.part("attention")
+def masked_attention(q, k, v, mask):
+    """Masked grouped-query attention with the scores written out: the plain
+    form (a no-cache forward, and the serving programs off the TPU). q:
+    [B, Tq, H, hd]; k, v: [B, Tk, KV, hd]; mask: [B, Tq, Tk]. Returns
+    [B, Tq, H * hd]."""
+    B, Tq, H, d = q.shape
+    KV = k.shape[2]
+    qg = q.reshape(B, Tq, KV, H // KV, d)
+    s = jnp.einsum("bqkgd,bskd->bkgqs", qg, k).astype(jnp.float32)
+    s = jnp.where(mask[:, None, None], s / jnp.sqrt(jnp.float32(d)),
+                  jnp.float32(-1e30))
+    w = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    return jnp.einsum("bkgqs,bskd->bqkgd", w, v).reshape(B, Tq, H * d)
+
+
+@tracing.part("attention")
+def gathered_attention(q, kpool, vpool, table, pos, window: int | None = None):
+    """The plain form of a decode step's walk over K and V pages: every entry
+    of the slot's table gathered, each row masked by the position it holds.
+    ``window`` None: entry e holds page e and a query attends back to
+    position 0. A number: the table is a RING — entry e holds the latest page
+    ``p <= pos // PS`` with ``p % entries == e`` — and a query attends the
+    ``window`` positions up to its own. q: [B, 1, H, hd]; kpool, vpool: [P,
+    PS, KV, hd] (one layer); table: [B, entries]; pos: [B]. Returns [B, 1,
+    H * hd]."""
+    B, entries = table.shape
+    PS = kpool.shape[1]
+    e = jnp.arange(entries)[None, :]
+    last = (pos // PS)[:, None]
+    page = jnp.broadcast_to(e, (B, entries)) if window is None else (
+        last - (last - e) % entries)
+    k_pos = (page[:, :, None] * PS + jnp.arange(PS)[None, None, :]
+             ).reshape(B, entries * PS)
+    held, q_pos = k_pos >= 0, pos[:, None]  # a ring's entry not yet written
+    ok = q_pos >= k_pos
+    if window is not None:
+        ok &= q_pos - k_pos < window
+    mask = held & ok
+
+    def rows(pool):
+        return pool[table].reshape(B, entries * PS, *pool.shape[2:]
+                                   ).astype(q.dtype)
+
+    return masked_attention(q, rows(kpool), rows(vpool), mask[:, None])

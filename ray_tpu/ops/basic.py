@@ -73,3 +73,17 @@ def rope_pairs(x, cos, sin, positions):
     x1, x2 = pairs[..., 0], pairs[..., 1]
     out = jnp.stack([x1 * c - x2 * s, x1 * s + x2 * c], axis=-1)
     return out.reshape(x.shape).astype(x.dtype)
+
+
+def dense_init(key, d_in, d_out, dtype):
+    """A seeded Glorot-scaled matrix as the tree's ``{"kernel": [d_in,
+    d_out]}``: what every model family's init draws."""
+    scale = (2.0 / (d_in + d_out)) ** 0.5
+    kernel = jax.random.normal(key, (d_in, d_out)) * scale
+    return {"kernel": kernel.astype(dtype)}
+
+
+def experts_init(key, n, d_in, d_out, dtype):
+    """``n`` experts' matrices ``[n, d_in, d_out]``, scaled as one."""
+    scale = (2.0 / (d_in + d_out)) ** 0.5
+    return (jax.random.normal(key, (n, d_in, d_out)) * scale).astype(dtype)

@@ -477,16 +477,22 @@ def test_engine_decode_in_place_matches_gathered(tiny, eos_id, monkeypatch):
     interpreted) gives the greedy tokens of the one that gathers the window,
     in both loops, across a mid-decode admission and a request that ends
     inside a block; and the two read counters say which path ran. On this
-    backend the engine gathers unless ``_reads_in_place`` is answered for
-    it, as here — on a TPU a plain pool takes the kernel by itself."""
+    backend the engine gathers unless the seam's rule, which every family
+    binds as ``_reads_in_place``, is answered for it, as here — on a TPU a
+    plain pool takes the kernel by itself. The Llama family asks its pool too
+    (``_pool_walkable``: the tiny head is not whole lane tiles, which the
+    interpreted kernel does not mind)."""
     from ray_tpu.llm import llama as programs
 
     cfg, params = tiny
-    assert not programs._reads_in_place(programs.make_kv_pools(cfg, 8, 4, None)[0])
+    pool = programs.make_kv_pools(cfg, 8, 4, None)[0]
+    assert not programs._reads_in_place() and not programs._walks(pool)
+    assert not programs._pool_walkable(programs.make_kv_pools(cfg, 8, 4, "int8")[0])
     grown_g, *gathered = _lone_then_admission(params, cfg, eos_id)
     assert [len(o) for o in gathered] == [9, 14, 6]
 
-    monkeypatch.setattr(programs, "_reads_in_place",
+    monkeypatch.setattr(programs, "_reads_in_place", lambda: True)
+    monkeypatch.setattr(programs, "_pool_walkable",
                         lambda pool: not isinstance(pool, dict))
     programs.paged_decode_multi.clear_cache()  # traced with the other answer
     try:
@@ -548,37 +554,121 @@ def _weights_read_in_one_place():
             assert not re.findall(_KERNEL_READ, src), info.name
 
 
+def _imports_of(source: str) -> set:
+    """Every module a source names in an import, a ``from`` import counted
+    as the module and as each name under it."""
+    import ast
+
+    names = set()
+    for node in ast.walk(ast.parse(source)):
+        if isinstance(node, ast.ImportFrom):
+            names |= {f"{node.module}.{a.name}" for a in node.names}
+            names.add(node.module)
+        elif isinstance(node, ast.Import):
+            names |= {a.name for a in node.names}
+    return names
+
+
+def _families() -> dict:
+    """name -> module, of every module under ``ray_tpu/llm/`` that defines
+    ``PROGRAMS``: found, not listed."""
+    import importlib
+    import pkgutil
+
+    import ray_tpu.llm
+
+    out = {}
+    for info in pkgutil.iter_modules(ray_tpu.llm.__path__):
+        mod = importlib.import_module(f"ray_tpu.llm.{info.name}")
+        if hasattr(mod, "PROGRAMS"):
+            out[info.name] = mod
+    return out
+
+
 def _arrows_point_one_way():
     """engine -> seam -> family programs -> models -> ops: the engine
     defines no device program and does not pull in a family it does not
-    serve; no program module (nor the seam) imports the engine."""
-    import ast
+    serve (but the Llama family's, which ``llm/__init__.py`` exports and the
+    engine re-imports for ``benchmarks/sizing.py``: ROADMAP D11); no program
+    module (nor the seam) imports the engine."""
     import inspect
     import subprocess
     import sys
 
-    from ray_tpu.llm import engine, llama, mla_moe, programs
+    from ray_tpu.llm import engine, programs
 
+    families = _families()
+    assert {"llama", "mla_moe", "cohere2_moe", "sparse_moe", "ssm_moe",
+            "eva"} <= set(families)
     subprocess.run(
         [sys.executable, "-c",
          "import sys, ray_tpu.llm.engine; "
-         "assert 'ray_tpu.llm.mla_moe' not in sys.modules"],
+         f"assert not [f for f in {sorted(set(families) - {'llama'})} "
+         "if 'ray_tpu.llm.' + f in sys.modules]"],
         check=True, timeout=120)
     assert "jax.jit" not in inspect.getsource(engine)
-    for mod in (llama, mla_moe, programs):
-        for node in ast.walk(ast.parse(inspect.getsource(mod))):
-            if isinstance(node, ast.ImportFrom):
-                names = {f"{node.module}.{a.name}" for a in node.names}
-                names.add(node.module)
-            elif isinstance(node, ast.Import):
-                names = {a.name for a in node.names}
-            else:
-                continue
-            assert "ray_tpu.llm.engine" not in names, mod.__name__
+    for mod in (*families.values(), programs):
+        assert "ray_tpu.llm.engine" not in _imports_of(
+            inspect.getsource(mod)), mod.__name__
+
+
+def _a_family_is_declared_once():
+    """No family's file names another family's, in ``llm/`` or in
+    ``models/``; the seam names no family and imports nothing of ``models/``;
+    the platform rule is read in one place; and serving a config loads its
+    own family's programs and no other's."""
+    import inspect
+    import pathlib
+    import re
+    import subprocess
+    import sys
+
+    import ray_tpu.llm
+    import ray_tpu.models
+    from ray_tpu.llm import programs
+
+    families = _families()
+    for pkg in (ray_tpu.llm, ray_tpu.models):
+        for name in families:
+            source = pathlib.Path(pkg.__path__[0], f"{name}.py").read_text()
+            theirs = {f"{p}.{other}" for other in families if other != name
+                      for p in ("ray_tpu.llm", "ray_tpu.models")}
+            assert not theirs & _imports_of(source), (pkg.__name__, name)
+    seam = inspect.getsource(programs)
+    assert not [m for m in _imports_of(seam) if m.startswith("ray_tpu.models")]
+    assert "isinstance(cfg" not in seam
+    assert "isinstance(cfg" not in inspect.getsource(ray_tpu.models)
+    asks = [f"{path.name}:{n}" for path in
+            sorted(pathlib.Path(ray_tpu.llm.__path__[0]).rglob("*.py"))
+            for n, line in enumerate(path.read_text().splitlines(), 1)
+            if "default_backend" in line]
+    assert len(asks) == 1 and asks[0].startswith("programs.py:"), asks
+    # the one definition, bound by name in every family and answered there
+    for name, mod in families.items():
+        assert mod._reads_in_place is programs.reads_in_place, name
+        assert not re.search(r"def _reads_in_place", inspect.getsource(mod))
+    script = (
+        "import sys, importlib\n"
+        "from ray_tpu.llm.programs import serving_programs\n"
+        f"families = {sorted(families)}\n"
+        "name, cls = sys.argv[1:]\n"
+        "cfg = getattr(importlib.import_module('ray_tpu.models.' + name), cls).tiny()\n"
+        "P = serving_programs(cfg)\n"
+        "assert P.family == name and P.init is not None\n"
+        "loaded = {f for f in families if 'ray_tpu.llm.' + f in sys.modules}\n"
+        "assert loaded - {'llama'} == {name} - {'llama'}, loaded\n")
+    configs = {"llama": "LlamaConfig", "mla_moe": "MlaMoeConfig",
+               "cohere2_moe": "Cohere2MoeConfig", "sparse_moe": "SparseMoeConfig",
+               "ssm_moe": "SsmMoeConfig", "eva": "EvaConfig"}
+    assert set(configs) == set(families)
+    procs = [subprocess.Popen([sys.executable, "-c", script, name, cls])
+             for name, cls in configs.items()]
+    assert [p.wait(timeout=240) for p in procs] == [0] * len(procs)
 
 
 @pytest.mark.parametrize("check", [_weights_read_in_one_place,
-                                   _arrows_point_one_way],
-                         ids=["weights", "imports"])
+                                   _arrows_point_one_way,
+                                   _a_family_is_declared_once],
+                         ids=["weights", "imports", "families"])
 def test_llm_seam(check):
     check()

@@ -337,7 +337,7 @@ def test_engine_read_counters_say_which_decode_path_ran(monkeypatch):
     assert eng._last_kv == {"kv_live": sum(range(10, 14)) / 4,
                             "kv_read": 4 * 16 * 8}
 
-    monkeypatch.setattr(programs, "_reads_in_place", lambda pool: True)
+    monkeypatch.setattr(programs, "_reads_in_place", lambda: True)
     programs.mla_moe_decode_multi.clear_cache()  # traced with the other answer
     try:
         eng, in_place, grown = asyncio.run(lone())

@@ -14,7 +14,6 @@ import pytest
 from ray_tpu.llm.engine import ContinuousBatchingEngine
 from ray_tpu.llm.programs import serving_programs
 from ray_tpu.llm.serving import LLMEngineServer
-from ray_tpu.models import init_fn
 from ray_tpu.models.cohere2_moe import Cohere2MoeConfig
 from ray_tpu.models.eva import EvaConfig
 from ray_tpu.models.llama import (
@@ -205,7 +204,7 @@ def test_the_other_families_prepare_nothing(family):
     cfg, kw = OTHERS[family]
     programs = serving_programs(cfg)
     assert programs.family == family and programs.prepare is None
-    tree = init_fn(cfg)(jax.random.PRNGKey(0), cfg)
+    tree = programs.init(jax.random.PRNGKey(0), cfg)
     eng = ContinuousBatchingEngine(tree, cfg, max_batch=3, page_size=8,
                                    max_seq_len=96, eos_id=None, **kw)
     assert eng.params is tree and eng.weights_prepared == 1

@@ -19,9 +19,10 @@ from benchmarks.reference import sparse_moe as R
 from ray_tpu.llm import sparse_moe as programs
 from ray_tpu.llm.engine import (ContinuousBatchingEngine, UnsupportedByModel,
                                 serving_programs)
-from ray_tpu.models.sparse_moe import (SparseMoeConfig, attend_plain,
-                                       sparse_moe_forward, sparse_moe_init)
+from ray_tpu.models.sparse_moe import (SparseMoeConfig, sparse_moe_forward,
+                                       sparse_moe_init)
 from ray_tpu.ops import paged_attention, paged_indexer, prefill_picks, select
+from ray_tpu.ops.attention import masked_attention
 from ray_tpu.ops.paged_attention import paged_decode_attention
 from ray_tpu.ops.paged_indexer import (index_runs, pack_keys,
                                        paged_index_scores, table_runs,
@@ -554,7 +555,7 @@ def test_paged_selected_attention_matches_a_dense_masked_softmax_at_g8(
                                  jnp.asarray(lengths),
                                  selected=jnp.asarray(picked), interpret=True)
     ok = picked & (np.arange(entries * ps)[None] < lengths[:, None])
-    want = attend_plain(
+    want = masked_attention(
         q[:, None], kpool[1][tables].reshape(B, -1, KV, hd),
         vpool[1][tables].reshape(B, -1, KV, hd), jnp.asarray(ok)[:, None])
     want = np.where(lengths[:, None] > 0, np.asarray(want)[:, 0], 0)
@@ -635,7 +636,7 @@ def test_blocked_prefill_attention_with_picks_matches_the_plain_form():
     v = jax.random.normal(ks[2], (N, T, KV, hd))
     picked = (jax.random.uniform(ks[3], (N, T, T)) < 0.3) | jnp.eye(T, dtype=bool)
     ok = picked & (jnp.arange(T)[:, None] >= jnp.arange(T)[None, :])
-    want = attend_plain(q, k, v, ok)
+    want = masked_attention(q, k, v, ok)
     got = gqa_prefill_attention(q.reshape(N, T, -1), k.reshape(N, T, -1),
                                 v.reshape(N, T, -1), n_kv_heads=KV,
                                 picked=picked, interpret=True)

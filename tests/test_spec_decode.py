@@ -12,6 +12,7 @@ import json
 import os
 import subprocess
 import sys
+import time
 
 import numpy as np
 import pytest
@@ -229,15 +230,35 @@ def test_cross_replica_steal_zero_duplicate_prefill(rt, tiny):
                             max_batch=4, page_size=PS, n_pages=64,
                             max_seq_len=128, decode_share_group="t-steal",
                             signal_refresh_s=0.05)
+        async def watched():
+            # a sibling nobody asks anything retires its probe loop after 3
+            # idle seconds and its registry entry ages out 5 s later: b is a
+            # steal target for as long as something watches it, as a
+            # deployment's monitoring does (``stats()`` counts as interest).
+            # Under six test workers a's warm-up alone can outlast those 8 s.
+            while True:
+                await b.stats()
+                await asyncio.sleep(0.5)
+
         # one request each warms both registries, then let them discover
         await b({"prompt_tokens": list(range(1, 9)), "max_tokens": 4})
+        watch = asyncio.get_running_loop().create_task(watched())
         await a({"prompt_tokens": list(range(1, 9)), "max_tokens": 4})
-        await asyncio.sleep(0.5)
+        # discovered: a sees a worker of b's WITH its probed headroom (what
+        # ``_pick_foreign`` reads) — not a fixed sleep, which a loaded host
+        # outlasts
+        deadline = time.monotonic() + 60
+        while not any(e["signal"].get("free_pages", 0) >= 2
+                      for e in a._foreign.values()):
+            assert time.monotonic() < deadline, (a._foreign, await b.stats())
+            await a.stats()   # keeps a's own probe loop, which discovers
+            await asyncio.sleep(0.1)
         reqs = [list(map(int, rng.integers(1, 512, 8))) + [j]
                 for j in range(12)]
         outs = await asyncio.gather(
             *(a({"prompt_tokens": r, "max_tokens": 6}) for r in reqs),
             return_exceptions=True)
+        watch.cancel()
         sa, sb = await a.stats(), await b.stats()
         await a.shutdown()
         await b.shutdown()
