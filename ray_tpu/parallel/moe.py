@@ -9,10 +9,11 @@ in-tree"; vLLM handles EP internally).
   dispatch tensor, expert weights sharded on the ``ep`` mesh axis, and
   sharding propagation turning the einsums into all_to_all over ICI.
 * **Serving (``sigmoid_topk_route`` / ``softmax_topk_route`` +
-  ``routed_experts`` + ``moe_layer``, reached from the four expert families'
+  ``routed_experts`` + ``moe_layer``, reached from the five expert families'
   program modules under ``llm/``):** no capacity, so no token is ever
   dropped. The router is the DeepSeek-V3 family's (sigmoid scores, the k
-  largest ``score + bias`` chosen and weighed by the score alone, scaled),
+  largest ``score + bias`` chosen — among all experts, or inside the few
+  routing groups of largest score — and weighed by the score alone, scaled),
   Cohere2's (that with no bias and no scale) or Qwen3-MoE's (a softmax over
   all experts, the k most probable renormalised). An expert, routed or
   shared, is a three-matrix SwiGLU or — where its tree has no ``w_gate`` —
@@ -96,12 +97,19 @@ def moe_ffn(x, gate_w, w_up, w_down, *, capacity_factor: float = 1.25,
 # ------------------------------------------------------------------ serving
 @tracing.part("router")
 def sigmoid_topk_route(h, router_w, bias, k: int, scale: float,
-                       norm: bool = True):
-    """``noaux_tc`` routing with one group: ``s = sigmoid(h . W)`` in
-    float32 (the family computes its gate in float32 whatever the model's
-    type: a bf16 score would flip near-tied choices), the ``k`` experts with
-    the largest ``s + bias``, weighed by ``s`` alone — the bias chooses and
-    never weighs. Equal sums go to the lower expert index (``lax.top_k``).
+                       norm: bool = True, n_group: int = 1,
+                       topk_group: int = 1):
+    """``noaux_tc`` routing: ``s = sigmoid(h . W)`` in float32 (the family
+    computes its gate in float32 whatever the model's type: a bf16 score
+    would flip near-tied choices), the ``k`` experts with the largest ``s +
+    bias``, weighed by ``s`` alone — the bias chooses and never weighs. Equal
+    sums go to the lower expert index (``lax.top_k``). With ``n_group`` > 1
+    the choice is **group-limited**: the experts are ``n_group`` groups of
+    consecutive ids, a group's score is the sum of its two largest ``s +
+    bias``, the ``topk_group`` groups of largest score stay (equal scores: the
+    lower group) and the ``k`` are chosen among their experts alone — a token
+    then reaches at most ``topk_group`` of the groups' holders. One group
+    chooses among all.
 
     h: [T, D]; router_w: [D, E]; bias: [E], or None for a router that
     chooses by the score itself. Returns (idx [T, k] int32, weights [T, k]
@@ -109,35 +117,53 @@ def sigmoid_topk_route(h, router_w, bias, k: int, scale: float,
     s = jax.nn.sigmoid(jnp.matmul(
         h.astype(jnp.float32), router_w.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
-    _, idx = jax.lax.top_k(
-        s if bias is None else s + bias.astype(jnp.float32), k)
+    by = s if bias is None else s + bias.astype(jnp.float32)
+    if n_group > 1:
+        T, E = by.shape
+        groups = by.reshape(T, n_group, E // n_group)
+        # a group's two largest as two passes of max (``lax.top_k`` of 2 in
+        # 64 became a whole sort on the chip, 2.7 % of a decode step)
+        first = groups.max(axis=-1, keepdims=True)
+        at = jnp.argmax(groups, axis=-1, keepdims=True)
+        second = jnp.where(jnp.arange(E // n_group) == at, -jnp.inf,
+                           groups).max(axis=-1, keepdims=True)
+        _, stay = jax.lax.top_k((first + second)[..., 0], topk_group)
+        kept = (stay[:, :, None] == jnp.arange(n_group)).any(axis=1)
+        by = jnp.where(kept[:, :, None], groups, -jnp.inf).reshape(T, E)
+    _, idx = jax.lax.top_k(by, k)
     w = jnp.take_along_axis(s, idx, axis=-1)
     if norm:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
     return idx.astype(jnp.int32), w * scale
 
 
-# rows of a routed product at or under which it is a decode step's: the two
-# cells' steps route 192 and 384 rows, their smallest prefill program 3,072
-_FEW_ROWS = 512
+# rows HANDED to a routed product (T * k, whoever holds their experts) at or
+# under which it is a decode step's. The steps hand 192 (32 slots x 6, all
+# held), 256 (32 x 8, an eighth held), 384 (48 x 8, an eighth) and 768 (96 x
+# 8, an eighth): 192, 32, 48 and 96 rows expected on the held experts. The
+# smallest prefill program hands 3,072 (384 expected on the held), the
+# others 18,000 and more
+_FEW_ROWS = 1024
 
 
 def _streams_experts(rows: int) -> bool:
     """Which of the grouped products ``routed_experts`` runs, decided by
     what the code can see and by no option. On a TPU, ``rows = T * k`` at or
-    under ``_FEW_ROWS`` — a decode step: a few rows an expert, bound by the
-    bytes of the touched experts' matrices — streams them through ONE kernel
-    (the bound is what the kernel's row block and float32 output take of
-    VMEM at the widest model served, about 5 MB of the chip's 128 MB, and a
-    row count past which ``ragged_dot`` stops reading the weights alone:
-    3,168). More rows — every prefill program: thousands of rows a group,
-    bound by the MXU — and every other backend, where the kernel would be
-    interpreted, keep the three ``jax.lax.ragged_dot`` calls, which thereby
-    stay the kernel's plain reference and what the CPU tests run. Asked for
-    three-matrix experts only: two-matrix experts (a tree with no
-    ``w_gate``) have no streamed form — ``_applies_every_expert`` says what
-    a step's few rows of theirs take instead, and more rows are two
-    ``ragged_dot`` calls."""
+    under ``_FEW_ROWS`` — a decode step: a row or a few on each held expert,
+    bound by the bytes of the touched experts' matrices — streams them through
+    ONE kernel. The bound is on the rows the kernel is HANDED, those of
+    experts held elsewhere too (they sort behind the last group and cost no
+    weight): its row block and float32 output stay in VMEM, twice each — 26
+    MB at 768 x 2560, 22 at 384 x 4096, of the 100 MB the kernel asks for,
+    beside 48 for the weights in flight. More rows — every prefill program:
+    hundreds to thousands of rows a group, bound by the MXU, and past 3,168
+    rows ``ragged_dot`` stops reading the weights alone — and every other
+    backend, where the kernel would be interpreted, keep the three
+    ``jax.lax.ragged_dot`` calls, which thereby stay the kernel's plain
+    reference and what the CPU tests run. Asked for three-matrix experts only:
+    two-matrix experts (a tree with no ``w_gate``) have no streamed form —
+    ``_applies_every_expert`` says what a step's few rows of theirs take
+    instead, and more rows are two ``ragged_dot`` calls."""
     return jax.default_backend() == "tpu" and rows <= _FEW_ROWS
 
 
@@ -231,25 +257,50 @@ def softmax_topk_route(h, router_w, k: int, norm: bool = True):
     return idx.astype(jnp.int32), w
 
 
+def moe_route(h, moe, *, k: int, scale: float, norm: bool = True,
+              softmax: bool = False, n_group: int = 1, topk_group: int = 1):
+    """The layer's router on ``h`` [T, D] by its tree and the model's kind:
+    ``softmax_topk_route`` (it has no scale), else ``sigmoid_topk_route``,
+    a router without a ``bias`` choosing by its scores. Returns (idx, w)."""
+    if softmax:
+        return softmax_topk_route(h, moe["router"]["kernel"], k, norm)
+    return sigmoid_topk_route(h, moe["router"]["kernel"],
+                              moe["router"].get("bias"), k, scale, norm,
+                              n_group, topk_group)
+
+
+def tokens_here(idx, held: tuple[int, int], valid=None):
+    """How many of the tokens chose at least one of the ``held`` experts: the
+    share of a step's tokens this holder has to see at all. idx: [T, k];
+    valid: [T] or None. Returns an int32 scalar."""
+    here = ((idx >= held[0]) & (idx < held[1])).any(axis=-1)
+    if valid is not None:
+        here &= valid
+    return here.sum().astype(jnp.int32)
+
+
 def moe_layer(h, moe, *, k: int, scale: float, norm: bool = True,
               held: tuple[int, int], valid=None, shared_scale: float = 1.0,
-              softmax: bool = False):
+              softmax: bool = False, n_group: int = 1, topk_group: int = 1):
     """One expert layer of the family on ``h`` [T, D] (already normed):
     the held routed experts' part plus the shared experts (one SwiGLU of
     the summed shared width, which every holder computes alike) times
     ``shared_scale`` — 1 where the shared experts are summed, 1 / their
     number where they are averaged; a layer with no ``shared`` sub-tree has
-    none, and its holders' parts add up to the layer. A router without a
-    ``bias`` chooses by its scores; ``softmax`` is the model's router being
-    ``softmax_topk_route`` (it has no scale). A ``shared`` sub-tree with no
-    ``w_gate`` is the two-matrix form, as the routed experts beside it are
+    none, and its holders' parts add up to the layer. The router is
+    ``moe_route``'s. A ``shared`` sub-tree with no ``w_gate`` is the
+    two-matrix form, as the routed experts beside it are
     (``_shared_expert``).
     Returns (y [T, D], load [hi-lo])."""
-    if softmax:
-        idx, w = softmax_topk_route(h, moe["router"]["kernel"], k, norm)
-    else:
-        idx, w = sigmoid_topk_route(h, moe["router"]["kernel"],
-                                    moe["router"].get("bias"), k, scale, norm)
+    idx, w = moe_route(h, moe, k=k, scale=scale, norm=norm, softmax=softmax,
+                       n_group=n_group, topk_group=topk_group)
+    return moe_experts(h, idx, w, moe, held, valid, shared_scale)
+
+
+def moe_experts(h, idx, w, moe, held: tuple[int, int], valid=None,
+                shared_scale: float = 1.0):
+    """``moe_layer`` behind its router: the held experts' part for the
+    choices ``idx``, ``w`` plus the shared experts. Returns (y, load)."""
     y, load = routed_experts(h, idx, w, moe["experts"], held, valid)
     if "shared" not in moe:
         return y, load

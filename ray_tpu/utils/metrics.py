@@ -318,6 +318,14 @@ LLM_MODEL_STATS = {
         "rt_llm_walk_run_blocks_total",
         "those of them fetched as ONE copy, or inside a whole block's: all "
         "pages hold tokens and lie one after the other in the pool"),
+    "delta_updates": Counter(
+        "rt_llm_delta_state_updates_total",
+        "delta-rule state rows a decode step read and wrote: live slots x "
+        "delta-rule layers"),
+    "moe_tokens_here": Counter(
+        "rt_llm_moe_tokens_here_total",
+        "live tokens that chose at least one held expert, a step an expert "
+        "layer: of live slots x expert layers, the share this holder sees"),
     "eva_pairs": Counter(
         "rt_llm_eva_pairs_written_total",
         "pooled key/value pairs a decode step wrote: chunks filled x layers"),

@@ -389,6 +389,7 @@ PARTS = (
     "select",          # the top-k mask over the indexer's scores
     "conv",            # a state-space block's depthwise convolution, its saved inputs
     "ssm",             # dt, the recurrence in either form, the D skip, the gated norm
+    "delta",           # a delta-rule layer's gate, q/k norms, recurrence in either form, head norm and gate
     "summary",         # a chunk's keys and values pooled into its pair, and its write
     "weights_concat",  # a weight laid out again by a program: none may hold it
     "head",            # final norm and logits

@@ -742,3 +742,108 @@ def test_eva_prefill_batch_compiles(one_chip, monkeypatch):
     assert not re.findall(_EVA_POOL_COPY, text)
     assert not re.findall(r"f32\[(?:1,)?(?:32,)?15360,15360\]", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.6e9
+
+
+# ------------------- delta-rule state rows beside a latent pool, group-routed experts
+_KDA_STATE_COPY = r"= f32\[10,97,32,128,128\]\S* (?:copy|transpose)\("
+
+
+def _kda_moe_args(one_chip):
+    """Ling-3.0-flash's language model at its published widths (32 KDA heads
+    of 128 x 128 state, 576-wide latent rows, 64 held experts of 768 with a
+    512-wide router in 8 groups), the cell's 12 layers, 97 state rows and
+    24,600 latent pages."""
+    from ray_tpu.llm.kda_moe import make_pools
+    from ray_tpu.models.kda_moe import KdaMoeConfig, kda_moe_init
+
+    cfg = KdaMoeConfig(vocab_size=19648, n_layers=12, max_seq_len=6144,
+                       experts_held=(0, 64), vocab_held=(0, 19648))
+    params = one_chip(jax.eval_shape(
+        lambda: kda_moe_init(jax.random.PRNGKey(0), cfg)))
+    cache = one_chip(jax.eval_shape(
+        lambda: make_pools(cfg, 16, {"latent": 24600, "state": 97}, None)))
+    assert [c.shape for c in cache] == [
+        (2, 24600, 16, 576), (10, 97, 32, 128, 128), (10, 97, 3 * 12288)]
+    assert cache[1].dtype == jnp.float32   # 2,097,152 B a row a layer
+    return cfg, params, cache, one_chip(_shape((2,), jnp.uint32))
+
+
+def test_kda_moe_decode_multi_compiles(one_chip, monkeypatch):
+    """96 slots a step: each of the 10 KDA layers advances its state pool
+    where it lies — ONE ``kda_pool_step`` call a layer, which hands the pool
+    back in the buffer it came in — the two MLA layers attend the latent pool
+    in place, every expert layer streams its touched held experts through the
+    grouped SwiGLU kernel (768 rows handed: ``parallel/moe.py``
+    ``_streams_experts``), and no whole state pool (2.1 GB) is copied on
+    entry or exit. The latent pool's two copies are the device layout's
+    (``test_mla_moe_decode_multi_compiles`` says why)."""
+    from ray_tpu.llm.kda_moe import STATS, kda_moe_decode_multi
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    kda_moe_decode_multi.clear_cache()
+    cfg, params, cache, key = _kda_moe_args(one_chip)
+    B = 96
+    i32 = one_chip(_shape((B,), jnp.int32))
+    tables = (one_chip(_shape((B, 384), jnp.int32)),
+              one_chip(_shape((B, 1), jnp.int32)))
+    try:
+        lowered = kda_moe_decode_multi.lower(
+            params, None, i32, i32, i32, tables, *cache,
+            one_chip(_shape((B,), jnp.bool_)),
+            one_chip(_shape((B,), jnp.float32)), key, cfg=cfg, n_steps=8)
+        compiled = lowered.compile()
+    finally:
+        kda_moe_decode_multi.clear_cache()
+    assert lowered.out_info[0].shape == (8, B + len(STATS))
+    mem = compiled.memory_analysis()
+    # weights 9.27 GB, state rows 2.11, latent pool 0.91
+    assert 12.2e9 < mem.argument_size_in_bytes < 12.4e9
+    # 0.91 GB of it is the latent pool in the kernel's layout
+    assert mem.temp_size_in_bytes < 1.4e9
+    text = compiled.as_text()
+    steps = re.findall(r"%kda_pool_step\S* = .* custom-call\(.*", text)
+    assert len(steps) == 10
+    assert all("output_to_operand_aliasing={{0}: (1, {})}" in s for s in steps)
+    assert len(re.findall(r"%_paged_latent_attention\S* = \S+ custom-call\(",
+                          text)) == 2
+    assert len(re.findall(_SWIGLU, text)) == cfg.n_moe_layers == 10
+    assert not re.findall(_RAGGED_DOT, text)
+    assert not re.findall(_KDA_STATE_COPY, text)
+    # no state rows gathered by slot, and the scan over the steps is the
+    # program's only loop
+    assert not re.findall(r"f32\[96,32,128,128\]", text)
+    assert len(re.findall(r" while\(", text)) == 1
+
+
+def test_kda_moe_prefill_batch_compiles(one_chip, monkeypatch):
+    """The cell's largest wave by tokens, 4 prompts of 4,096: the chunked
+    delta rule a prompt at a time (``ops/kda.py`` ``_SCAN_TOKENS``), the
+    expanded MLA a group of heads at a time, three ``ragged_dot`` calls an
+    expert layer, one state row a prompt a KDA layer written in place — and
+    the whole beside 12.3 GB of arguments inside the chip's 16.9."""
+    from ray_tpu.llm.kda_moe import kda_moe_prefill_batch
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    kda_moe_prefill_batch.clear_cache()
+    cfg, params, cache, key = _kda_moe_args(one_chip)
+    N, Tp = 4, 4096
+    pages = (one_chip(_shape((N, Tp // 16), jnp.int32)),
+             one_chip(_shape((N, 1), jnp.int32)))
+    try:
+        compiled = kda_moe_prefill_batch.lower(
+            params, None, one_chip(_shape((N,), jnp.int32)),
+            one_chip(_shape((N, Tp), jnp.int32)), pages, *cache,
+            one_chip(_shape((N,), jnp.int32)),
+            one_chip(_shape((N,), jnp.float32)), key, cfg=cfg).compile()
+    finally:
+        kda_moe_prefill_batch.clear_cache()
+    text = compiled.as_text()
+    # 16,384 tokens are eight chunks of the expert layer's 2,048
+    assert len(re.findall(_RAGGED_DOT, text)) == 3 * cfg.n_moe_layers
+    assert not re.findall(_SWIGLU, text)
+    assert not re.findall(_KDA_STATE_COPY, text)
+    mem = compiled.memory_analysis()
+    # every layer's conv input kept to the program's end was 5.29 GB
+    # (models/kda_moe.py kda_mixer's barrier): 3.31
+    assert mem.temp_size_in_bytes < 3.6e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.2e9
