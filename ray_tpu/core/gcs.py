@@ -836,15 +836,24 @@ class GcsServer:
                 await asyncio.sleep(base * (0.5 + random.random() / 2))
 
             worker_addr = tuple(lease["worker_address"])
-            wconn = await rpc.connect(*worker_addr)
             try:
-                await wconn.call(
-                    "create_actor",
-                    {"spec": info.spec, "tpu_chips": lease.get("tpu_chips")},
-                    timeout=self.cfg.worker_start_timeout_s,
-                )
-            finally:
-                await wconn.close()
+                wconn = await rpc.connect(*worker_addr)
+                try:
+                    await wconn.call(
+                        "create_actor",
+                        {"spec": info.spec, "tpu_chips": lease.get("tpu_chips")},
+                        timeout=self.cfg.worker_start_timeout_s,
+                    )
+                finally:
+                    await wconn.close()
+            except BaseException:
+                # the worker was leased for this actor alone and still
+                # lives: return it (kill), or it keeps its allocation — on
+                # a TPU host its chips — for the raylet's life, and the
+                # next actor that asks for them waits for ever
+                self._bg.spawn(
+                    self._return_orphan_lease(tuple(node.address), lease))
+                raise
             info.state = ALIVE
             info.address = worker_addr
             info.node_id = node.node_id

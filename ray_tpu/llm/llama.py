@@ -10,7 +10,10 @@ engine, beside the other family's (``llm/mla_moe.py``). Imports the seam
   engine's lies in the serving layout (``PROGRAMS.prepare``: wq|wk|wv and
   w_gate|w_up joined once, when it takes the tree), any other caller's as
   ``llama_init`` made it. The middle is the program's own:
-  fresh K and V under a causal mask (``paged_prefill_batch``), the
+  fresh K and V under a causal mask (``paged_prefill_batch``: blocked,
+  ``ops/prefill_attention.py``, no ``[T, T]`` array, where the seam's rule
+  and the shapes allow — a TPU, a pad of whole blocks, a head of whole lane
+  tiles — and ``_gqa_attn`` over the whole square elsewhere), the
   table-ordered window (``paged_prefill_suffix``, speculative verify), the
   pool in place or the gathered window as ``_walks`` sees (decode).
   In place, the walk takes table entries that lie one after the other in the
@@ -35,6 +38,7 @@ from ray_tpu.models.llama import (
     llama_serving_layout)
 from ray_tpu.ops.basic import rms_norm, rope_freqs
 from ray_tpu.ops.paged_attention import paged_decode_attention, run_lengths
+from ray_tpu.ops.prefill_attention import blocks_for, gqa_prefill_attention
 from ray_tpu.utils import tracing
 
 # The seam's platform rule under this module's own name, asked through this
@@ -264,7 +268,11 @@ def paged_prefill_batch(params, loras, aids, tokens, pages, kpool, vpool,
     cos, sin = rope_freqs(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
     positions = jnp.arange(Tp)[None, :]
     idx = jnp.arange(Tp)
-    mask = idx[None, :, None] >= idx[None, None, :]  # causal
+    # the other families' rule, and a head of whole lane tiles (the kernel
+    # slices a KV head's query heads out of the lanes)
+    blocked = (_reads_in_place() and blocks_for(Tp) is not None
+               and cfg.head_dim % 128 == 0)
+    mask = None if blocked else idx[None, :, None] >= idx[None, None, :]
     rows = pages[:, idx // PS]  # [N, Tp] pool row per prompt position
     offs = jnp.broadcast_to(idx % PS, (N, Tp))
     with tracing.part("embed"):
@@ -275,8 +283,16 @@ def paged_prefill_batch(params, loras, aids, tokens, pages, kpool, vpool,
                                 loras=loras, aids=aids)
         kpool = _kv_write(kpool, i, rows, offs, k)
         vpool = _kv_write(vpool, i, rows, offs, v)
-        att = _gqa_attn(q, k, v, mask)  # prefill attends the FRESH k/v:
-        # quantization only affects what later decode steps read back
+        # prefill attends the FRESH k/v: quantization only affects what
+        # later decode steps read back
+        if blocked:
+            with tracing.part("attention"):
+                att = gqa_prefill_attention(
+                    q.reshape(N, Tp, -1), k.reshape(N, Tp, -1),
+                    v.reshape(N, Tp, -1),
+                    n_kv_heads=cfg.n_kv_heads).reshape(q.shape)
+        else:
+            att = _gqa_attn(q, k, v, mask)
         x = llama_ffn(layer, llama_attn_out(layer, x, att))
     with tracing.part("head"):
         x = rms_norm(x, params["norm"]["scale"])

@@ -15,16 +15,30 @@ SURVEY §7 "hard parts").
 
 from __future__ import annotations
 
+import logging
 import random
 import time
 import traceback
 from typing import Any, Callable
 
 import ray_tpu
-from ray_tpu.core.ref import ActorError, TaskError
+from ray_tpu.core.ref import ActorError, GetTimeoutError, TaskError
 from ray_tpu.train.checkpoint import Checkpoint, CheckpointManager
 from ray_tpu.train.config import RunConfig, ScalingConfig
 from ray_tpu.train.session import TrainContext, init_session
+
+
+log = logging.getLogger(__name__)
+
+#: pauses before a worker group that did not come up is started again. Such
+#: a group has run no user code, so these tries are apart from
+#: FailureConfig.max_failures, which counts failures of the training loop
+#: (the reference's controller_failure_limit beside max_failures). On a TPU
+#: host the case is a chip its last holder has not let go of yet: libtpu
+#: refuses the second process at once ("libtpu multi-process lockfile": the
+#: worker's creation fails in seconds) or waits (set-up outlasts its limit).
+_START_BACKOFF_S = (5.0, 10.0, 20.0)
+_SETUP_TIMEOUT_S = 120.0
 
 
 class TrainingFailedError(RuntimeError):
@@ -160,56 +174,83 @@ class JaxTrainer:
                 time.sleep(min(5.0, 0.5 * (2 ** (attempt - 1)))
                            * (0.5 + random.random()))
 
-    def _run_attempt(self, name: str, attempt: int, manager: CheckpointManager,
-                     history: list[dict]) -> dict:
+    def _start_group(self, name: str, attempt: int, manager: CheckpointManager):
+        """Placement, the worker actors and their set-up: ``(pg, workers)``
+        once every worker is set up. A worker whose creation failed or a
+        set-up that outlasted its limit tears the group down and starts it
+        again after each pause of ``_START_BACKOFF_S``; no placement is not
+        tried again (waiting is what ``pg.ready`` did)."""
         scaling = self.scaling
         n = scaling.num_workers
-        group_name = f"{name}_g{attempt}"
-
-        pg = ray_tpu.placement_group(
-            [scaling.worker_resources() for _ in range(n)],
-            strategy=scaling.placement_strategy,
-        )
-        if not pg.ready(timeout=60):
-            ray_tpu.remove_placement_group(pg)
-            raise TrainingFailedError(
-                f"no placement for {n} worker(s) of {scaling.worker_resources()} "
-                f"within 60s; the cluster has {ray_tpu.available_resources()} free")
-        WorkerCls = ray_tpu.remote(TrainWorker)
-        workers = [
-            # per-worker bundle_index: options differ every iteration
-            WorkerCls.options(  # raylint: disable=RT009
-                num_cpus=scaling.worker_resources().get("CPU", 1.0),
-                resources={k: v for k, v in scaling.worker_resources().items()
-                           if k != "CPU"},
-                placement_group=pg,
-                placement_group_bundle_index=i,
-                # poll() must be servable while run() blocks an executor thread
-                max_concurrency=2,
-            ).remote(i, n, name, scaling.backend(), group_name)
-            for i in range(n)
-        ]
-        try:
-            resume = manager.latest() or self.resume_from_checkpoint
-            ray_tpu.get(
-                [w.setup.remote(resume.path if resume else None) for w in workers],
-                timeout=120,
+        for tries in range(len(_START_BACKOFF_S) + 1):
+            # a group's name is its rendezvous key: never one a dead try left
+            group_name = f"{name}_g{attempt}" + (f"r{tries}" if tries else "")
+            pg = ray_tpu.placement_group(
+                [scaling.worker_resources() for _ in range(n)],
+                strategy=scaling.placement_strategy,
             )
+            if not pg.ready(timeout=60):
+                ray_tpu.remove_placement_group(pg)
+                raise TrainingFailedError(
+                    f"no placement for {n} worker(s) of {scaling.worker_resources()} "
+                    f"within 60s; the cluster has {ray_tpu.available_resources()} free")
+            WorkerCls = ray_tpu.remote(TrainWorker)
+            workers = [
+                # per-worker bundle_index: options differ every iteration
+                WorkerCls.options(  # raylint: disable=RT009
+                    num_cpus=scaling.worker_resources().get("CPU", 1.0),
+                    resources={k: v for k, v in scaling.worker_resources().items()
+                               if k != "CPU"},
+                    placement_group=pg,
+                    placement_group_bundle_index=i,
+                    # poll() must be servable while run() blocks an executor thread
+                    max_concurrency=2,
+                ).remote(i, n, name, scaling.backend(), group_name)
+                for i in range(n)
+            ]
+            try:
+                resume = manager.latest() or self.resume_from_checkpoint
+                ray_tpu.get(
+                    [w.setup.remote(resume.path if resume else None) for w in workers],
+                    timeout=_SETUP_TIMEOUT_S,
+                )
+                return pg, workers
+            except (ActorError, GetTimeoutError) as e:
+                self._stop_group(pg, workers)
+                if tries == len(_START_BACKOFF_S):
+                    raise TrainingFailedError(
+                        f"the worker group did not start in {tries + 1} tries: "
+                        f"{type(e).__name__}: {e}") from e
+                log.warning("worker group %s did not start (%s: %s): again in %g s",
+                            group_name, type(e).__name__, e, _START_BACKOFF_S[tries])
+                time.sleep(_START_BACKOFF_S[tries])
+            except BaseException:
+                self._stop_group(pg, workers)
+                raise
+
+    @staticmethod
+    def _stop_group(pg, workers) -> None:
+        for w in workers:
+            try:
+                ray_tpu.kill(w)
+            except Exception:  # raylint: disable=RT012 — teardown: worker may already be dead
+                pass
+        try:
+            ray_tpu.remove_placement_group(pg)
+        except Exception:  # raylint: disable=RT012 — teardown: PG dies with the cluster anyway
+            pass
+
+    def _run_attempt(self, name: str, attempt: int, manager: CheckpointManager,
+                     history: list[dict]) -> dict:
+        pg, workers = self._start_group(name, attempt, manager)
+        try:
             run_refs = [
                 w.run.remote(self.train_loop, self.train_loop_config) for w in workers
             ]
             final = self._poll_loop(workers, run_refs, manager, history)
             return final
         finally:
-            for w in workers:
-                try:
-                    ray_tpu.kill(w)
-                except Exception:  # raylint: disable=RT012 — teardown: worker may already be dead
-                    pass
-            try:
-                ray_tpu.remove_placement_group(pg)
-            except Exception:  # raylint: disable=RT012 — teardown: PG dies with the cluster anyway
-                pass
+            self._stop_group(pg, workers)
 
     def _poll_loop(self, workers, run_refs, manager: CheckpointManager,
                    history: list[dict]) -> dict:

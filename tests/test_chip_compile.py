@@ -85,17 +85,19 @@ def test_flash_backward_compiles(one_chip):
     assert compiled.as_text().count("tpu_custom_call") >= 3
 
 
-def _engine_args(one_chip, n_layers: int, n_kv_heads: int = 8):
+def _engine_args(one_chip, n_layers: int, n_kv_heads: int = 8,
+                 vocab_size: int = 128256):
     """chip_smoke's serve phase: Llama-3-8B widths, batch 16, a 32k-token
     bf16 pool in 16-token pages, 512-token sequences — depth cut to two
     layers, which is what keeps the compile to seconds. 32 query heads on 8
-    KV heads is Mistral's ratio too; on 4 it is Yi's. The tree is the one an
-    engine holds: in the family's serving layout (``PROGRAMS.prepare``, here
-    on shapes alone)."""
+    KV heads is Mistral's ratio too (with ``vocab_size`` 32,768 they are
+    Mistral's widths); on 4 it is Yi's. The tree is the one an engine holds:
+    in the family's serving layout (``PROGRAMS.prepare``, here on shapes
+    alone)."""
     from ray_tpu.llm.llama import PROGRAMS
 
     cfg = dataclasses.replace(LlamaConfig.llama3_8b(), n_layers=n_layers,
-                              n_kv_heads=n_kv_heads)
+                              n_kv_heads=n_kv_heads, vocab_size=vocab_size)
     params = jax.eval_shape(lambda: PROGRAMS.prepare(
         llama_init(jax.random.PRNGKey(0), cfg), cfg))
     assert "wqkv" in params["layers_0"] and "wq" not in params["layers_0"]
@@ -168,18 +170,39 @@ def test_paged_decode_multi_compiles(one_chip, monkeypatch, seq, kv_heads,
                            "transpose", "convert"}, pool_ops
 
 
-def test_paged_prefill_batch_compiles(one_chip):
+@pytest.mark.parametrize("backend,N,Tp,vocab,temporaries", [
+    ("cpu", 4, 256, 128256, 2.0e9), ("tpu", 8, 1792, 32768, 1.2e9)],
+    ids=["plain-4x256", "blocked-8x1792"])
+def test_paged_prefill_batch_compiles(one_chip, monkeypatch, backend, N, Tp,
+                                      vocab, temporaries):
+    """A wave of four 256-token prompts as this backend's rule has it (the
+    plain form: ``_gqa_attn`` over the masked square), and the largest wave
+    of `mistral7b_batch_closed` — eight prompts of 1,792 at Mistral's widths
+    — as a TPU has it: one blocked attention kernel a layer
+    (``ops/prefill_attention.py``) and no ``[N, KV, G * Tp, Tp]`` float32
+    scores. The 8 x 1,792 wave's temporaries read 1,059,922,432 bytes
+    blocked and 3,599,460,864 in the plain form (two layers, PR 47)."""
     from ray_tpu.llm.llama import paged_prefill_batch
 
-    cfg, params, pool, key = _engine_args(one_chip, 2)
-    N, Tp = 4, 256  # a wave of four 256-token prompts
-    compiled = paged_prefill_batch.lower(
-        params, None, one_chip(_shape((N,), jnp.int32)),
-        one_chip(_shape((N, Tp), jnp.int32)),
-        one_chip(_shape((N, Tp // 16), jnp.int32)), pool, pool,
-        one_chip(_shape((N,), jnp.int32)), one_chip(_shape((N,), jnp.float32)),
-        key, cfg=cfg).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < 2.0e9
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    paged_prefill_batch.clear_cache()
+    cfg, params, pool, key = _engine_args(one_chip, 2, vocab_size=vocab)
+    try:
+        compiled = paged_prefill_batch.lower(
+            params, None, one_chip(_shape((N,), jnp.int32)),
+            one_chip(_shape((N, Tp), jnp.int32)),
+            one_chip(_shape((N, Tp // 16), jnp.int32)), pool, pool,
+            one_chip(_shape((N,), jnp.int32)),
+            one_chip(_shape((N,), jnp.float32)), key, cfg=cfg).compile()
+    finally:
+        paged_prefill_batch.clear_cache()
+    assert compiled.memory_analysis().temp_size_in_bytes < temporaries
+    text = compiled.as_text()
+    blocked = backend == "tpu"
+    assert len(re.findall(r"%gqa_prefill_attention\S* = \S+ custom-call\(",
+                          text)) == (cfg.n_layers if blocked else 0)
+    KV, G = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads
+    assert (f"f32[{N},{KV},{G * Tp},{Tp}]" in text) == (not blocked)
 
 
 def test_train_step_takes_the_flash_kernels(one_chip, monkeypatch):

@@ -205,6 +205,30 @@ def test_actor_exception(rt):
         rt.get(b.fail.remote())
 
 
+def test_failed_actor_creation_returns_its_lease(rt):
+    """An actor whose creation fails (a constructor that raises; on a TPU
+    host a backend that cannot start) leaves no leased worker behind: its
+    allocation comes back, or the next actor that needs it — a TPU worker's
+    chips — waits for ever."""
+    from ray_tpu.core.ref import ActorError
+
+    @rt.remote(num_cpus=5)  # of the node's 8: two of them never fit
+    class MostOfTheNode:
+        def __init__(self, fail):
+            if fail:
+                raise RuntimeError("no backend")
+
+        def ping(self):
+            return "ok"
+
+    bad = MostOfTheNode.remote(True)
+    with pytest.raises(ActorError, match="actor creation failed"):
+        rt.get(bad.ping.remote(), timeout=60)
+    good = MostOfTheNode.remote(False)
+    assert rt.get(good.ping.remote(), timeout=60) == "ok"
+    rt.kill(good)
+
+
 def test_kill_actor(rt):
     @rt.remote
     class Victim:

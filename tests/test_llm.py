@@ -514,6 +514,112 @@ def test_engine_decode_in_place_matches_gathered(tiny, eos_id, monkeypatch):
         assert grown_k[0] <= grown_k[1] < 2 * grown_k[0]  # whole pages of 8
 
 
+def _prefill_wave(programs, cfg, params, N, Tp, kv_dtype, told: bool):
+    """``paged_prefill_batch`` once over a wave of ``N`` prompts padded to
+    ``Tp`` (the last row of several right-padded), with the seam's rule
+    answered ``told``. Returns the logits of the last real rows (the sampling
+    tail set aside), both pools as float arrays and whether the traced
+    program holds a Pallas call."""
+    import jax
+    import jax.numpy as jnp
+
+    PS = 16
+    rng = np.random.default_rng(N * 1000 + Tp)
+    true_lens = np.full(N, Tp, np.int32)
+    if N > 1:
+        true_lens[-1] = Tp - 37
+    tokens = rng.integers(3, cfg.vocab_size, (N, Tp)).astype(np.int32)
+    tokens[np.arange(Tp)[None, :] >= true_lens[:, None]] = 0
+    n_pages = -(-Tp // PS)
+    pages = 1 + np.arange(N * n_pages, dtype=np.int32).reshape(N, n_pages)
+    args = (params, None, jnp.zeros(N, jnp.int32), jnp.asarray(tokens),
+            jnp.asarray(pages))
+    tail = (jnp.asarray(true_lens), jnp.zeros(N, jnp.float32),
+            jax.random.PRNGKey(0))
+    was = programs._reads_in_place, programs._sample_tail
+    programs._reads_in_place = lambda: told
+    programs._sample_tail = lambda logits, temps, key: logits
+    programs.paged_prefill_batch.clear_cache()  # traced with other answers
+    try:
+        def run(*pools):  # fresh pools a call: the program donates them
+            return programs.paged_prefill_batch(*args, *pools, *tail, cfg=cfg)
+
+        def pools():
+            return programs.make_kv_pools(cfg, PS, 1 + N * n_pages, kv_dtype)
+
+        kernel = "pallas_call" in str(jax.make_jaxpr(run)(*pools()))
+        logits, kpool, vpool = run(*pools())
+    finally:
+        programs._reads_in_place, programs._sample_tail = was
+        programs.paged_prefill_batch.clear_cache()
+
+    def rows(pool):
+        if isinstance(pool, dict):
+            return (np.asarray(pool["q"], np.float32)
+                    * np.asarray(pool["s"], np.float32)[..., None])
+        return np.asarray(pool.astype(jnp.float32))
+
+    return np.asarray(logits.astype(jnp.float32)), rows(kpool), rows(vpool), kernel
+
+
+@pytest.mark.parametrize("G,hd,N,Tp,dtype,kv_dtype,blocked", [
+    (1, 128, 1, 128, "bfloat16", None, True),
+    (4, 128, 1, 128, "bfloat16", None, True),
+    (8, 128, 1, 128, "bfloat16", None, True),
+    (1, 128, 3, 384, "bfloat16", None, True),
+    (4, 128, 3, 384, "bfloat16", None, True),
+    (8, 128, 3, 384, "bfloat16", None, True),
+    (4, 128, 1, 384, "float32", None, True),
+    (8, 128, 3, 128, "float32", None, True),
+    (4, 128, 3, 128, "bfloat16", "int8", True),
+    (4, 128, 3, 144, "bfloat16", None, False),
+    (4, 64, 3, 128, "bfloat16", None, False),
+], ids=str)
+def test_prefill_batch_blocked_matches_plain(G, hd, N, Tp, dtype, kv_dtype,
+                                             blocked):
+    """The dense family's whole-prompt prefill attends through the blocked
+    kernel (``ops/prefill_attention.py``, here interpreted) where the seam's
+    rule says so and the shapes are the kernel's — a pad of whole blocks of
+    128, a head of whole lane tiles — and gives what ``_gqa_attn`` over the
+    masked square gives: the same first tokens, the logits and every layer's
+    K and V rows to bf16's rounding (two bf16 programs that round in another
+    order read 0.008-0.015 apart as the benchmark's ``kv_rel_err`` counts,
+    0.009-0.018 in the worst element; a float32 model's agree to 1e-5; layer
+    0's rows exactly: nothing attends before them), at one, four and eight
+    query heads a KV head, for a lone prompt and a wave with a right-padded
+    row, into int8 pools too (the kernel sees the fresh K and V either way).
+    A pad of 144 and a 64-wide head take the plain form told or not: the
+    same program, bit for bit."""
+    import jax
+
+    from ray_tpu.llm import llama as programs
+
+    cfg = LlamaConfig(vocab_size=256, d_model=2 * G * hd, n_layers=2,
+                      n_heads=2 * G, n_kv_heads=2, d_ff=256, max_seq_len=512,
+                      dtype=dtype)
+    assert cfg.head_dim == hd
+    params = llama_init(jax.random.PRNGKey(G), cfg)
+    plain = _prefill_wave(programs, cfg, params, N, Tp, kv_dtype, told=False)
+    told = _prefill_wave(programs, cfg, params, N, Tp, kv_dtype, told=True)
+    assert not plain[3] and told[3] == blocked
+    if not blocked:
+        for a, b in zip(plain[:3], told[:3]):
+            np.testing.assert_array_equal(a, b)
+        return
+    assert (plain[0].argmax(-1) == told[0].argmax(-1)).all()
+    limits = (1e-4, 1e-4) if dtype == "float32" else (0.03, 0.06)
+
+    def apart(a, b):  # as the benchmark's kv_rel_err, and the worst element
+        return (np.linalg.norm(a - b) / np.linalg.norm(a),
+                np.abs(a - b).max() / np.abs(a).max())
+
+    assert np.less(apart(plain[0], told[0]), limits).all()
+    for a, b in zip(plain[1:3], told[1:3]):
+        np.testing.assert_array_equal(a[0], b[0])
+        assert np.abs(a[1]).max() > 0.5  # the rows are there to compare
+        assert np.less(apart(a[1], b[1]), limits).all(), apart(a[1], b[1])
+
+
 # ------------------------------------------------------------------ the seam
 _KERNEL_READ = (r'(?<!\["moe"\])\[\s*"(wq|wk|wv|wo|w_gate|w_up|w_down)"\s*\]')
 

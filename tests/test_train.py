@@ -159,3 +159,45 @@ def test_trainer_failure_exhausts(rt, tmp_path):
     )
     result = trainer.fit()
     assert result.error is not None
+
+
+@pytest.mark.parametrize("dies, starts", [(1, 2), (99, 3)])
+def test_worker_group_that_does_not_start_is_started_again(
+        rt, tmp_path, monkeypatch, dies, starts):
+    """A worker that dies in set-up (on a TPU host: a chip its last holder
+    still has) has run no user code: the group is started again after each
+    pause of ``_START_BACKOFF_S``, apart from ``max_failures`` (0 here), and
+    a group that never starts is an error that says so."""
+    from ray_tpu.train import trainer as trainer_mod
+
+    counter = str(tmp_path / "setups")
+
+    class DiesInSetup(trainer_mod.TrainWorker):
+        def setup(self, checkpoint_path):
+            import os
+
+            with open(counter, "a") as f:
+                f.write("x")
+            if os.path.getsize(counter) <= dies:
+                os._exit(1)  # as a failed backend start ends the worker
+            return super().setup(checkpoint_path)
+
+    monkeypatch.setattr(trainer_mod, "TrainWorker", DiesInSetup)
+    monkeypatch.setattr(trainer_mod, "_START_BACKOFF_S", (0.05, 0.05))
+
+    def loop(config):
+        from ray_tpu import train
+
+        train.report({"answer": 2 * config["x"]})
+
+    result = JaxTrainer(
+        loop,
+        train_loop_config={"x": 21},
+        scaling_config=ScalingConfig(num_workers=1, collective_backend="cpu"),
+        run_config=RunConfig(storage_path=str(tmp_path / "c5")),
+    ).fit()
+    assert os.path.getsize(counter) == starts
+    if dies < starts:
+        assert result.error is None and result.metrics["answer"] == 42
+    else:
+        assert "did not start in 3 tries" in str(result.error)
