@@ -2,8 +2,11 @@
 full attention sparse-expert family, the learned-sparse-attention expert
 family, the state-space + attention + ungated-expert family, the windowed
 exact + pooled-pair attention family, the delta-rule + latent-attention
-group-routed expert family, ResNet, MLP."""
+group-routed expert family, the compressed-latent convolved attention +
+top-1 expert family, ResNet, MLP."""
 
+from ray_tpu.models.cca_moe import (  # noqa: F401
+    CcaMoeConfig, cca_moe_forward, cca_moe_init)
 from ray_tpu.models.cohere2_moe import (  # noqa: F401
     Cohere2MoeConfig, cohere2_moe_forward, cohere2_moe_init)
 from ray_tpu.models.eva import EvaConfig, eva_forward, eva_init  # noqa: F401

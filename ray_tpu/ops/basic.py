@@ -87,3 +87,12 @@ def experts_init(key, n, d_in, d_out, dtype):
     """``n`` experts' matrices ``[n, d_in, d_out]``, scaled as one."""
     scale = (2.0 / (d_in + d_out)) ** 0.5
     return (jax.random.normal(key, (n, d_in, d_out)) * scale).astype(dtype)
+
+
+def rope_lanes(x, cos, sin, positions, lanes: int):
+    """``rope`` over the FIRST ``lanes`` lanes of every head, the others
+    passing as they are (``partial_rotary_factor``). x: [B, T, H, D];
+    cos/sin: [T_max, lanes/2] (``rope_freqs(lanes, ...)``); positions:
+    [B, T]."""
+    return jnp.concatenate(
+        [rope(x[..., :lanes], cos, sin, positions), x[..., lanes:]], axis=-1)

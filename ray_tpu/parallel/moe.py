@@ -1,4 +1,4 @@
-"""Mixture-of-experts layers: three routers, two regimes, two expert forms.
+"""Mixture-of-experts layers: four routers, two regimes, two expert forms.
 
 Absent from the reference (ref: SURVEY §2.3 — "no MoE expert parallel
 in-tree"; vLLM handles EP internally).
@@ -8,14 +8,14 @@ in-tree"; vLLM handles EP internally).
   dispatch/combine formulation — a capacity-bounded one-hot ``[T, E, C]``
   dispatch tensor, expert weights sharded on the ``ep`` mesh axis, and
   sharding propagation turning the einsums into all_to_all over ICI.
-* **Serving (``sigmoid_topk_route`` / ``softmax_topk_route`` +
-  ``routed_experts`` + ``moe_layer``, reached from the five expert families'
-  program modules under ``llm/``):** no capacity, so no token is ever
+* **Serving (``sigmoid_topk_route`` / ``softmax_topk_route`` /
+  ``mlp_top1_route`` + ``routed_experts`` + ``moe_layer``, from the six expert
+  families' modules under ``llm/``):** no capacity, so no token is ever
   dropped. The router is the DeepSeek-V3 family's (sigmoid scores, the k
   largest ``score + bias`` chosen — among all experts, or inside the few
-  routing groups of largest score — and weighed by the score alone, scaled),
-  Cohere2's (that with no bias and no scale) or Qwen3-MoE's (a softmax over
-  all experts, the k most probable renormalised). An expert, routed or
+  groups of largest score — weighed by the score alone, scaled), Cohere2's
+  (no bias, no scale), Qwen3-MoE's (a softmax, the k most probable) or an MLP
+  with a carry across layers (``mlp_top1_route``). An expert, routed or
   shared, is a three-matrix SwiGLU or — where its tree has no ``w_gate`` —
   two matrices, ``W_down . relu(W_up . h)^2``. A one-hot dispatch does not
   scale to 128 experts x 12k prefill tokens, so the routed product is a
@@ -138,25 +138,25 @@ def sigmoid_topk_route(h, router_w, bias, k: int, scale: float,
 
 
 # rows HANDED to a routed product (T * k, whoever holds their experts) at or
-# under which it is a decode step's. The steps hand 192 (32 slots x 6, all
-# held), 256 (32 x 8, an eighth held), 384 (48 x 8, an eighth) and 768 (96 x
-# 8, an eighth): 192, 32, 48 and 96 rows expected on the held experts. The
-# smallest prefill program hands 3,072 (384 expected on the held), the
-# others 18,000 and more
+# under which it streams. At 6 or 8 experts a token those are the decode steps:
+# 192 to 768 rows handed, 32 to 192 on the held experts (their smallest prefill
+# program hands 3,072). At ONE a token the rows ARE the tokens: a step of 80
+# slots hands 80 (5 an expert of 16), and prefill waves of up to 1,024 tokens
+# (16-64 rows an expert) stream too; 2,048-8,192 tokens are ``ragged_dot``'s
 _FEW_ROWS = 1024
 
 
 def _streams_experts(rows: int) -> bool:
     """Which of the grouped products ``routed_experts`` runs, decided by
     what the code can see and by no option. On a TPU, ``rows = T * k`` at or
-    under ``_FEW_ROWS`` — a decode step: a row or a few on each held expert,
-    bound by the bytes of the touched experts' matrices — streams them through
-    ONE kernel. The bound is on the rows the kernel is HANDED, those of
-    experts held elsewhere too (they sort behind the last group and cost no
-    weight): its row block and float32 output stay in VMEM, twice each — 26
-    MB at 768 x 2560, 22 at 384 x 4096, of the 100 MB the kernel asks for,
-    beside 48 for the weights in flight. More rows — every prefill program:
-    hundreds to thousands of rows a group, bound by the MXU, and past 3,168
+    under ``_FEW_ROWS`` — a decode step, and at ONE expert a token a short
+    prefill wave: up to 64 rows a held expert, bound by the touched experts'
+    bytes (2048 x 2048: 25 MB, 31 us against 8 us of MXU at 64 rows) — streams
+    them through ONE kernel. The bound is on the rows HANDED (those of experts
+    held elsewhere sort last and cost no weight): row block and float32 output
+    stay in VMEM, twice each — 26 MB at 768 x 2560, 22 at 384 x 4096, 25 at
+    1,024 x 2048, of the kernel's 100 MB, beside 48 for the weights in flight.
+    More rows — hundreds to thousands a group, bound by the MXU, and past 3,168
     rows ``ragged_dot`` stops reading the weights alone — and every other
     backend, where the kernel would be interpreted, keep the three
     ``jax.lax.ragged_dot`` calls, which thereby stay the kernel's plain
@@ -260,8 +260,8 @@ def softmax_topk_route(h, router_w, k: int, norm: bool = True):
 def moe_route(h, moe, *, k: int, scale: float, norm: bool = True,
               softmax: bool = False, n_group: int = 1, topk_group: int = 1):
     """The layer's router on ``h`` [T, D] by its tree and the model's kind:
-    ``softmax_topk_route`` (it has no scale), else ``sigmoid_topk_route``,
-    a router without a ``bias`` choosing by its scores. Returns (idx, w)."""
+    ``softmax_topk_route``, else ``sigmoid_topk_route`` (no ``bias``: by its
+    scores): ONE matrix on ``h`` (not ``mlp_top1_route``). Returns (idx, w)."""
     if softmax:
         return softmax_topk_route(h, moe["router"]["kernel"], k, norm)
     return sigmoid_topk_route(h, moe["router"]["kernel"],
@@ -378,3 +378,38 @@ def _shared_expert(h, sh):
         return swiglu(h, sh["w_gate"]["kernel"], sh["w_up"]["kernel"],
                       sh["w_down"]["kernel"])
     return _relu2(h @ sh["w_up"]["kernel"]) @ sh["w_down"]["kernel"]
+
+
+@tracing.part("router")
+def mlp_top1_route(h, r_prev, router, eps: float = 1e-5):
+    """The ZAYA router: an MLP over a stream of its own that CARRIES from
+    layer to layer. ``r = h . W_down + gamma . r_prev`` (``r_prev`` None: the
+    first layer's, zeros), ``s = gelu(gelu(N(r) . W1 + c1) . W2 + c2) . W3``
+    with ``N`` an RMSNorm with a gain and ``gelu`` the tanh form, ``p =
+    softmax(s)``; the ONE expert of largest ``p + bias`` is chosen (equal
+    sums go to the lower index) and weighed by ``p`` itself, not
+    renormalised — the bias chooses and never weighs. All of it in float32
+    at the highest precision, for the reason ``sigmoid_topk_route`` gives:
+    with one expert a token a flipped choice replaces the whole sublayer's
+    output. ``r`` goes on to the next layer's router beside the residual; it
+    is a token's own and is cached nowhere.
+
+    h: [T, D]; r_prev: [T, R] float32 or None; router: {"down": [D, R],
+    "gamma": scalar, "norm": {"scale": [R]}, "w1", "w2": [R, R], "b1", "b2":
+    [R], "w3": [R, E], "bias": [E]}. Returns (idx [T, 1] int32, weights
+    [T, 1] float32, r [T, R] float32)."""
+    f32, hi = jnp.float32, jax.lax.Precision.HIGHEST
+
+    def mm(a, w):
+        return jnp.matmul(a, w.astype(f32), precision=hi)
+
+    r = mm(h.astype(f32), router["down"])
+    if r_prev is not None:
+        r = r + router["gamma"].astype(f32) * r_prev
+    a = r * jax.lax.rsqrt(jnp.mean(r * r, axis=-1, keepdims=True) + eps)
+    a = a * router["norm"]["scale"].astype(f32)
+    a = jax.nn.gelu(mm(a, router["w1"]) + router["b1"].astype(f32))
+    a = jax.nn.gelu(mm(a, router["w2"]) + router["b2"].astype(f32))
+    p = jax.nn.softmax(mm(a, router["w3"]), axis=-1)
+    idx = jnp.argmax(p + router["bias"].astype(f32), axis=-1)[:, None]
+    return idx.astype(jnp.int32), jnp.take_along_axis(p, idx, axis=-1), r

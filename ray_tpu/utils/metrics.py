@@ -343,6 +343,10 @@ LLM_MODEL_STATS = {
     "sparse_select_width": Counter(
         "rt_llm_sparse_select_columns_width_total",
         "slots x table width: what walked is a share of"),
+    "cca_row_updates": Counter(
+        "rt_llm_cca_row_updates_total",
+        "convolution rows a decode step read and wrote beside the K/V page "
+        "it wrote: live slots x layers (llm/cca_moe.py)"),
 }
 serve_lane_seconds = Histogram(
     "rt_serve_lane_seconds",

@@ -391,6 +391,7 @@ PARTS = (
     "ssm",             # dt, the recurrence in either form, the D skip, the gated norm
     "delta",           # a delta-rule layer's gate, q/k norms, recurrence in either form, head norm and gate
     "summary",         # a chunk's keys and values pooled into its pair, and its write
+    "mix",             # CCA between projections and attention: mean, both convolutions, norm, temperature, rotation, value shift, the row
     "weights_concat",  # a weight laid out again by a program: none may hold it
     "head",            # final norm and logits
     "sample",          # the sampling tail, rng

@@ -870,3 +870,106 @@ def test_kda_moe_prefill_batch_compiles(one_chip, monkeypatch):
     # (models/kda_moe.py kda_mixer's barrier): 3.31
     assert mem.temp_size_in_bytes < 3.6e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.2e9
+
+
+# ------- a K/V page AND a row a slot in every layer, one wide expert a token
+_CCA_POOL_COPY = (r"= bf16\[(?:20,9400,16,2,128|20,81,2688)\]\S* "
+                  r"(?:copy|transpose)\(")
+# the tied table, 1.07 GB: a copy of it, transposed or not, or one in float32
+_CCA_TABLE_COPY = (r"= (?:bf16|f32)\[(?:262272,2048|2048,262272)\]\S* "
+                   r"(?:copy|transpose)\(")
+
+
+def _cca_moe_args(one_chip):
+    """ZAYA1-8B at its published widths (8 query heads on 2 key heads of 128
+    inside a 2,048-wide model, a router of 256, all 16 experts of 2048 x
+    2048, the whole tied table of 262,272 rows), the cell's 20 layers, 9,400
+    K/V pages and 81 rows."""
+    from ray_tpu.llm.cca_moe import make_pools
+    from ray_tpu.models.cca_moe import CcaMoeConfig, cca_moe_init
+
+    cfg = CcaMoeConfig(n_layers=20, max_seq_len=3072)
+    params = one_chip(jax.eval_shape(
+        lambda: cca_moe_init(jax.random.PRNGKey(0), cfg)))
+    cache = one_chip(jax.eval_shape(
+        lambda: make_pools(cfg, 16, {"kv": 9400, "row": 81}, None)))
+    assert [c.shape for c in cache] == [
+        (20, 9400, 16, 2, 128), (20, 9400, 16, 2, 128), (20, 81, 2688)]
+    assert all(c.dtype == jnp.bfloat16 for c in cache)   # 5,376 B a row
+    return cfg, params, cache, one_chip(_shape((2,), jnp.uint32))
+
+
+def test_cca_moe_decode_multi_compiles(one_chip, monkeypatch):
+    """80 slots a step: every layer advances its rows where they lie and
+    attends its K/V pages in place, 80 rows stream their experts through the
+    grouped SwiGLU kernel (``parallel/moe.py`` ``_streams_experts`` at one
+    expert a token), the head reads the tied table as it lies — no whole
+    pool and no table is copied, transposed or not — and the whole is the
+    arguments and little more."""
+    from ray_tpu.llm.cca_moe import STATS, cca_moe_decode_multi
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cca_moe_decode_multi.clear_cache()
+    cfg, params, cache, key = _cca_moe_args(one_chip)
+    B = 80
+    i32 = one_chip(_shape((B,), jnp.int32))
+    tables = (one_chip(_shape((B, 192), jnp.int32)),
+              one_chip(_shape((B, 1), jnp.int32)))
+    try:
+        lowered = cca_moe_decode_multi.lower(
+            params, None, i32, i32, i32, tables, *cache,
+            one_chip(_shape((B,), jnp.bool_)),
+            one_chip(_shape((B,), jnp.float32)), key, cfg=cfg, n_steps=8)
+        compiled = lowered.compile()
+    finally:
+        cca_moe_decode_multi.clear_cache()
+    assert lowered.out_info[0].shape == (8, B + len(STATS))
+    mem = compiled.memory_analysis()
+    # weights 9.38 GB, K and V pools 3.08, rows 0.009
+    assert 12.4e9 < mem.argument_size_in_bytes < 12.55e9
+    assert mem.temp_size_in_bytes < 0.2e9
+    text = compiled.as_text()
+    assert len(re.findall(r"%_paged_decode_attention\S* = \S+ custom-call\(",
+                          text)) == cfg.n_layers == 20
+    assert len(re.findall(_SWIGLU, text)) == 20
+    assert not re.findall(_RAGGED_DOT, text)
+    assert not re.findall(_CCA_POOL_COPY, text)
+    assert not re.findall(_CCA_TABLE_COPY, text)
+    # the scan over the steps is the program's only loop: no row scattered
+    # slot by slot
+    assert len(re.findall(r" while\(", text)) == 1
+
+
+def test_cca_moe_prefill_batch_compiles(one_chip, monkeypatch):
+    """The cell's largest wave, 8 prompts of 1,024: blocked attention over
+    the fresh keys (``gqa_prefill_attention`` at 2 key heads, G = 4), 8,192
+    rows through three ``ragged_dot`` calls a layer with no chunking, the
+    rows and pages written in place — and the whole beside 12.5 GB of
+    arguments inside the chip's 16.9."""
+    from ray_tpu.llm.cca_moe import cca_moe_prefill_batch
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cca_moe_prefill_batch.clear_cache()
+    cfg, params, cache, key = _cca_moe_args(one_chip)
+    N, Tp = 8, 1024
+    pages = (one_chip(_shape((N, Tp // 16), jnp.int32)),
+             one_chip(_shape((N, 1), jnp.int32)))
+    try:
+        compiled = cca_moe_prefill_batch.lower(
+            params, None, one_chip(_shape((N,), jnp.int32)),
+            one_chip(_shape((N, Tp), jnp.int32)), pages, *cache,
+            one_chip(_shape((N,), jnp.int32)),
+            one_chip(_shape((N,), jnp.float32)), key, cfg=cfg).compile()
+    finally:
+        cca_moe_prefill_batch.clear_cache()
+    text = compiled.as_text()
+    assert len(re.findall(r"%gqa_prefill_attention\S* = \S+ custom-call\(",
+                          text)) == 20
+    assert len(re.findall(_RAGGED_DOT, text)) == 3 * 20
+    assert not re.findall(_SWIGLU, text)
+    assert not re.findall(r"f32\[8,(?:8,)?1024,1024\]", text)   # no scores written
+    assert not re.findall(_CCA_POOL_COPY, text)
+    assert not re.findall(_CCA_TABLE_COPY, text)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 0.6e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13.2e9
