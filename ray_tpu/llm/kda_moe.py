@@ -23,12 +23,12 @@ kinds of which ONE DOES NOT GROW, and a mixer by the layer's place.
   page (``paged_latent_attention``) or over the gathered window: one switch,
   the seam's rule bound here as ``_reads_in_place``.
 * **Prefill** is whole-prompt per pad bucket: the chunked scan from a zero
-  state (a reused row is overwritten, never read), expanded attention over
-  the wave's fresh latent rows, and the state written **at each prompt's
-  true length** — positions at or past it get ``beta`` 0 and decay 1, and
-  the convolution's saved inputs are the last ``K - 1`` true ones (zeros
-  where the prompt is shorter). A wave holds at most ``WAVE_LIMIT`` prompts
-  and tokens.
+  state (a reused row is overwritten; on a TPU ``ops/kda_chunk.py``'s kernel,
+  else ``ops/kda.py``'s plain form), expanded attention over the wave's fresh
+  latent rows, and the state written **at each prompt's true length** —
+  positions at or past it get ``beta`` 0 and decay 1, the convolution's saved
+  inputs are the last ``K - 1`` true ones (zeros where the prompt is
+  shorter). A wave holds at most ``WAVE_LIMIT`` prompts and tokens.
 * **The expert layer** routes over all experts inside the token's routing
   groups and computes the held ones' part plus the shared expert: a decode
   step's rows stream their touched experts through ``ops/grouped_swiglu.py``,
@@ -266,7 +266,8 @@ def kda_moe_prefill_batch(params, loras, aids, tokens, pages, pool, states,
         layer, kind = params[f"layers_{i}"], cfg.mixer(i)
         j, at[kind] = at[kind], at[kind] + 1
         if kind == KDA:
-            y, S, saved = kda_mixer(layer, x, cfg, valid, tails=true_lens)
+            y, S, saved = kda_mixer(layer, x, cfg, valid, tails=true_lens,
+                                    kernel=_reads_in_place())
             with tracing.part("delta"):
                 states = states.at[j, row].set(S)
             with tracing.part("conv"):

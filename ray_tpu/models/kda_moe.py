@@ -41,7 +41,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops import kda, ssm
+from ray_tpu.ops import kda, kda_chunk, ssm
 from ray_tpu.ops.basic import (
     dense_init, experts_init, rms_norm, rope_freqs, swiglu)
 from ray_tpu.ops.mla import mla_attend_expanded, mla_project
@@ -250,22 +250,28 @@ def conv_taps(layer, cfg: KdaMoeConfig):
     return layer["conv"]["kernel"], jnp.zeros((cfg.conv_width,), jnp.float32)
 
 
-def kda_qkv(xc, cfg: KdaMoeConfig):
-    """The convolution's output [..., 3 . d_inner] as q, k (L2-normalised a
-    head, q scaled by ``head_dim ** -0.5``; float32) and v, each [..., heads,
-    head_dim]."""
-    lead = xc.shape[:-1]
-    q, k, v = (a.reshape(*lead, cfg.n_heads, cfg.head_dim)
-               for a in jnp.split(xc, 3, axis=-1))
+def kda_qkv(xc, cfg: KdaMoeConfig, laid: bool = False):
+    """The convolution's output [..., 3 . d_inner] (``laid``: ``by_head`` of
+    it) as q, k (L2-normalised a head, q scaled by ``head_dim ** -0.5``;
+    float32) and v, each [..., heads, head_dim]."""
+    if laid:
+        q, k, v = jnp.split(xc, 3, axis=-2)
+    else:
+        lead = xc.shape[:-1]
+        q, k, v = (a.reshape(*lead, cfg.n_heads, cfg.head_dim)
+                   for a in jnp.split(xc, 3, axis=-1))
     return (kda.l2_normalise(q) * cfg.head_dim ** -0.5, kda.l2_normalise(k), v)
 
 
-def kda_decay(layer, a, beta, cfg: KdaMoeConfig):
+def kda_decay(layer, a, beta, cfg: KdaMoeConfig, laid: bool = False):
     """(g [..., heads, head_dim], beta [..., heads]) in float32: the log of
-    each key lane's decay and the head's write strength. a: [..., d_inner];
-    beta: [..., heads], raw."""
-    a = (a.astype(jnp.float32) + layer["a_bias"]).reshape(
-        *a.shape[:-1], cfg.n_heads, cfg.head_dim)
+    each key lane's decay and the head's write strength. a: [..., d_inner]
+    (``laid``: ``by_head`` of it); beta: [..., heads], raw."""
+    if laid:
+        a = a.astype(jnp.float32) + layer["a_bias"].reshape(a.shape[-2:])
+    else:
+        a = (a.astype(jnp.float32) + layer["a_bias"]).reshape(
+            *a.shape[:-1], cfg.n_heads, cfg.head_dim)
     return (kda.kda_gate(a, layer["A_log"], cfg.kda_lower_bound),
             jax.nn.sigmoid(beta.astype(jnp.float32)))
 
@@ -291,13 +297,26 @@ def mixer_out(layer, y):
     return y @ layer["wo"]["kernel"]
 
 
-def kda_mixer(layer, x, cfg: KdaMoeConfig, valid=None, tails=None):
+def by_head(x, cfg: KdaMoeConfig):
+    """``x`` [..., n . head_dim] as [..., n, head_dim], laid out so HERE,
+    while it is narrow: ``ops/kda_chunk.py``'s kernel reads a head's rows of
+    [N, T, heads, head_dim] float32, and left free the compiler turns q, k
+    and g into that layout after widening them, a float32 pass each."""
+    return jax.lax.optimization_barrier(
+        x.reshape(*x.shape[:-1], -1, cfg.head_dim))
+
+
+def kda_mixer(layer, x, cfg: KdaMoeConfig, valid=None, tails=None,
+              kernel: bool = False):
     """A whole KDA mixer over sequences from a zero state, the chunked scan.
     x: [N, T, D]; ``valid`` [N, T]: positions that move the state (None:
     all); ``tails`` [N] int32: where to read the convolution's saved inputs
-    (the K - 1 before that position). Returns (y [N, T, D], the state after
-    the last valid position [N, heads, head_dim, head_dim] float32, the
-    saved inputs [N, K - 1, 3 . d_inner] or None)."""
+    (the K - 1 before that position); ``kernel``: whether the scan may run
+    as ``ops/kda_chunk.py``'s kernel (the caller's platform rule; it does
+    where the shapes are the kernel's too, else ``ops/kda.py``'s plain
+    form). Returns (y [N, T, D], the state after the last valid position
+    [N, heads, head_dim, head_dim] float32, the saved inputs [N, K - 1, 3 .
+    d_inner] or None)."""
     u, a, beta, gate = kda_in(layer, x, cfg)
     with tracing.part("conv"):
         xc = ssm.causal_conv(u, *conv_taps(layer, cfg))
@@ -310,12 +329,17 @@ def kda_mixer(layer, x, cfg: KdaMoeConfig, valid=None, tails=None):
             xc, saved = jax.lax.optimization_barrier(
                 (xc, ssm.conv_tail(u, tails, cfg.conv_kernel)))
     with tracing.part("delta"):
-        q, k, v = kda_qkv(xc, cfg)
-        g, beta = kda_decay(layer, a, beta, cfg)
+        laid = kernel and kda_chunk.fits(cfg.head_dim, cfg.head_dim,
+                                         cfg.chunk_size, cfg.sub_chunk)
+        scan = kda_chunk.kda_chunk_scan if laid else kda.kda_chunked
+        if laid:
+            xc, a = by_head(xc, cfg), by_head(a, cfg)
+        q, k, v = kda_qkv(xc, cfg, laid)
+        g, beta = kda_decay(layer, a, beta, cfg, laid)
         if valid is not None:  # padding decays nothing and writes nothing
             g = jnp.where(valid[..., None, None], g, 0.0)
             beta = jnp.where(valid[..., None], beta, 0.0)
-        o, S = kda.kda_chunked(q, k, v, g, beta, cfg.chunk_size, cfg.sub_chunk)
+        o, S = scan(q, k, v, g, beta, cfg.chunk_size, cfg.sub_chunk)
         y = kda_out(layer, o, gate, cfg, x.dtype)
     return mixer_out(layer, y), S, saved
 

@@ -1,16 +1,19 @@
-"""The gated delta rule (``ops/kda.py``) and its pool kernel
-(``ops/kda_pool.py``): the chunked form against the one-step form scanned, at
-lengths under a sub-block, under a chunk, at a chunk and one either side and
-at several chunks + 5, with every lane's log-decay at the lower bound (-5 a
-position: the overflow case the sub-blocks exist for), at 0 and in between;
-the kernel in the interpreter against ``kda_step`` + ``.at[].set``. CPU,
-float32."""
+"""The gated delta rule (``ops/kda.py``) and its two kernels
+(``ops/kda_pool.py``, ``ops/kda_chunk.py``): the chunked form against the
+one-step form scanned, at lengths under a sub-block, under a chunk, at a
+chunk and one either side and at several chunks + 5, with every lane's
+log-decay at the lower bound (-5 a position: the overflow case the sub-blocks
+exist for), at 0 and in between; the pool kernel in the interpreter against
+``kda_step`` + ``.at[].set``; the chunk kernel in the interpreter against
+both forms, at one, two and three chunks and at three + 5 (padded), and under
+``models/kda_moe.py`` ``kda_mixer``. CPU, float32."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.ops import kda
+from ray_tpu.ops import kda, kda_chunk
+from ray_tpu.ops.kda_chunk import kda_chunk_scan
 from ray_tpu.ops.kda_pool import kda_pool_step
 
 LOWER = -5.0
@@ -165,3 +168,90 @@ def test_the_pool_kernel_takes_a_traced_layer_index():
     want, _ = kda.kda_step(pool[1], *step)
     assert rel(got[1], want) < 1e-6
     np.testing.assert_array_equal(got[0], pool[0])
+
+
+# ------------------------------------------------------------ the chunk kernel
+# chunks of 64 in sub-blocks of 16 at 128 key and value lanes, the kernel's
+# shapes: one chunk, two (one program of two), three (three programs: the
+# state carried in VMEM), and three + 5 (padded to four)
+@pytest.mark.parametrize("decay", ["random", "lower_bound", "zero"])
+@pytest.mark.parametrize("T", [64, 128, 192, 197])
+def test_the_chunk_kernel_is_the_one_step_rule_and_the_plain_form(T, decay):
+    args = _inputs(T, decay, seed=T, N=2, H=2, dk=128, dv=128)
+    got_o, got_S = kda_chunk_scan(*args, interpret=True)
+    want_o, want_S = _one_step_scan(*args)
+    plain_o, plain_S = kda.kda_chunked(*args)
+    assert got_o.shape == want_o.shape and got_o.dtype == jnp.float32
+    assert got_S.shape == want_S.shape and got_S.dtype == jnp.float32
+    assert bool(jnp.isfinite(got_o).all()) and bool(jnp.isfinite(got_S).all())
+    assert rel(got_o, want_o) < 1e-5 and rel(got_S, want_S) < 1e-5
+    assert rel(got_o, plain_o) < 1e-5 and rel(got_S, plain_S) < 1e-5
+
+
+@pytest.mark.parametrize("N,T,H", [(3, 70, 3), (1, 64, 16)])
+def test_the_chunk_kernel_at_other_heads_and_sequences(N, T, H):
+    """Three heads are one program's (a block takes all of them); sixteen
+    are two programs of eight, and each picks its own heads' beta."""
+    args = _inputs(T, "random", seed=11, N=N, H=H, dk=128, dv=128)
+    got_o, got_S = kda_chunk_scan(*args, interpret=True)
+    want_o, want_S = kda.kda_chunked(*args)
+    assert rel(got_o, want_o) < 1e-5 and rel(got_S, want_S) < 1e-5
+    for h in range(H):  # a head at a time: no head reads another's beta
+        assert rel(got_S[:, h], want_S[:, h]) < 1e-5
+
+
+def test_the_chunk_kernel_keeps_padding_out_of_the_state_and_takes_bf16_values():
+    """Positions 100.. of 197 with ``g = 0`` and ``beta = 0`` leave the state
+    of the first 100, as the plain form's test says of it; v comes as the
+    convolution leaves it, bf16."""
+    q, k, v, g, beta = _inputs(197, seed=5, N=2, H=2, dk=128, dv=128)
+    v = v.astype(jnp.bfloat16)
+    _, want = _one_step_scan(*(a[:, :100] for a in (q, k, v, g, beta)))
+    g, beta = g.at[:, 100:].set(0.0), beta.at[:, 100:].set(0.0)
+    got_o, got = kda_chunk_scan(q, k, v, g, beta, interpret=True)
+    assert rel(got, want) < 1e-5
+    plain_o, _ = kda.kda_chunked(q, k, v, g, beta)
+    assert rel(got_o, plain_o) < 1e-5
+
+
+@pytest.mark.parametrize("dk,dv,chunk,sub,fits", [
+    (128, 128, 64, 16, True), (256, 128, 64, 8, True), (128, 128, 32, 32, True),
+    (16, 8, 64, 16, False),      # lanes that are no whole tile
+    (128, 128, 48, 16, False),   # three sub-blocks: not two neighbours at a time
+    (128, 128, 64, 4, False),    # sub-blocks under a sublane tile
+    (128, 128, 64, 24, False)])  # a chunk that is not whole sub-blocks
+def test_which_shapes_are_the_chunk_kernels(dk, dv, chunk, sub, fits):
+    assert kda_chunk.fits(dk, dv, chunk, sub) is fits
+    if not fits:
+        args = _inputs(8, N=1, H=1, dk=dk, dv=dv)
+        with pytest.raises(ValueError, match="kernel's shapes"):
+            kda_chunk_scan(*args, chunk=chunk, sub=sub, interpret=True)
+
+
+def test_the_mixer_takes_the_form_it_is_told_and_the_two_agree(monkeypatch):
+    """``kda_mixer`` asks no platform: told ``kernel`` it runs the chunk
+    kernel where the shapes are its own, told nothing (or at other shapes)
+    the plain form — and the two give one result, padded prompts and all."""
+    from ray_tpu.models import kda_moe as M
+
+    cfg = M.KdaMoeConfig.tiny(n_heads=2, head_dim=128, chunk_size=64,
+                              sub_chunk=16, max_seq_len=256)
+    layer = M.kda_moe_layer_init(jax.random.PRNGKey(0), cfg, 0)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 150, cfg.d_model))
+    lens = jnp.asarray([150, 70], jnp.int32)
+    valid = jnp.arange(150)[None, :] < lens[:, None]
+    calls = []
+    real = kda_chunk.kda_chunk_scan
+    monkeypatch.setattr(kda_chunk, "kda_chunk_scan",
+                        lambda *a, **k: calls.append(1) or real(*a, **k))
+    want = M.kda_mixer(layer, x, cfg, valid, tails=lens)
+    assert not calls
+    got = M.kda_mixer(layer, x, cfg, valid, tails=lens, kernel=True)
+    assert calls == [1]
+    for a, b in zip(got, want):
+        assert a.shape == b.shape and rel(a, b) < 1e-5
+    # the tiny shape's 16 lanes are not the kernel's: the plain form, told or not
+    tiny = M.KdaMoeConfig.tiny()
+    M.kda_mixer(M.kda_moe_layer_init(jax.random.PRNGKey(0), tiny, 0),
+                x[:, :20], tiny, kernel=True)
+    assert calls == [1]
