@@ -139,9 +139,9 @@ class ContinuousBatchingEngine:
                 ("spec_enable", bool(spec_enable), P.decode_spec is not None)):
             if asked and not has:
                 raise UnsupportedByModel(feature, P)
-        self.params = params
-        # the model's cache, one tuple of pools (see ServePrograms)
-        self.cache = tuple(P.make_cache(cfg, page_size, n_pages, kv_dtype))
+        self.params = params  # laid out for serving: stage weights_prepare
+        with tracing.stage("pools"):  # the model's cache, one tuple of pools
+            self.cache = tuple(P.make_cache(cfg, page_size, n_pages, kv_dtype))
         self.kv_dtype = kv_dtype or "native"
         self._kv_in_place = bool(
             P.decode_in_place and P.decode_in_place(self.cache))
@@ -182,9 +182,9 @@ class ContinuousBatchingEngine:
         self._task = None
         self._rng = jax.random.PRNGKey(0)
         self.error: BaseException | None = None  # fatal loop failure
-        # (program, shapes) seen by _call -> future of its
-        # tracing.compiled_parts
-        self._compiled: dict = {}
+        # (program, shapes) seen by _call -> future of its compiled_parts;
+        # and of each, in that order, where it came from (program_builds)
+        self._compiled, self._builds = {}, collections.deque(maxlen=256)
         # speculative decoding (README § Speculative decoding): greedy
         # requests draft spec_k tokens per step (on-device n-gram
         # matcher over spec_ngram-grams, or the spec_drafter hook) and
@@ -571,11 +571,11 @@ class ContinuousBatchingEngine:
         nothing: the pools stay readable (``export_pages``) meanwhile.
         Steady state costs one dict lookup and never suspends; the compile
         does, so it closes ``ph`` (a phase belongs to its thread), waits
-        under ``engine.compile`` and opens ``ph`` again for the call. What
-        the compiled text says of its instructions' layer parts
-        (``program_parts``) is read by ``_PARTS_READER`` while the program's
-        first run holds the device (0.1-0.6 s of Python a program at real
-        widths, which the loop does not wait for)."""
+        under ``engine.compile`` and opens ``ph`` again for the call. The
+        compiled text's layer parts (``program_parts``) are read by
+        ``_PARTS_READER`` beside the program's first run (0.1-0.6 s a program,
+        which the loop does not wait for). The thunk's line AND columns are in
+        the compile-cache key of every program with a Mosaic call: keep them."""
         # what can differ between two calls under one engine: an array's
         # shape (pad and wave buckets) and the static step counts
         key = (fn, *(a if isinstance(a, int) else getattr(a, "shape", None)
@@ -583,13 +583,26 @@ class ContinuousBatchingEngine:
         if key not in self._compiled:
             ph.__exit__(None, None, None)
             with tracing.phase("engine.compile", program=fn.__name__,
-                               shape=str(key[1:])):
-                compiled = await asyncio.get_running_loop().run_in_executor(
+                               shape=str(key[1:])) as compiling:
+                compiled, built = await tracing.build_in_executor(
                     None, lambda: fn.lower(*args).compile())
+                compiling.set(source=built["source"])
             self._compiled[key] = _PARTS_READER.submit(
                 tracing.compiled_parts, compiled)
+            self._builds.append({"program": fn.__name__, "shape": str(key[1:]),
+                                 **built, "t": time.monotonic()})
             ph.__enter__()
         return fn(*args)
+
+    def program_builds(self) -> list[dict]:
+        """One record a program ``_call`` got ready, in that order:
+        ``{"program", "shape", "source": "cache" | "compiled", "trace_s",
+        "lower_s", "cache_read_s", "compile_s", "t"}`` — whether the
+        persistent compile cache had it (``"memory"``: jit's own caches did,
+        from an earlier engine of this process), the seconds jax reported of
+        each step in the building thread, and ``time.monotonic()`` at its
+        end."""
+        return list(self._builds)
 
     def program_parts(self) -> dict:
         """``{program: {"parts": {"<instruction>|<shape>": part}, "stale",
@@ -617,7 +630,8 @@ class ContinuousBatchingEngine:
     def params(self, tree):
         if tree is not None:
             if self.programs.prepare is not None:
-                tree = self.programs.prepare(tree, self.cfg)
+                with tracing.stage("weights_prepare"):
+                    tree = self.programs.prepare(tree, self.cfg)
             self.weights_prepared += 1
         self._params = tree
 
@@ -685,8 +699,6 @@ class ContinuousBatchingEngine:
             splits = len(waves) - len(groups)
             ph.set(prompts=len(adopted) + sum(map(len, groups.values())),
                    splits=splits)
-        if splits:
-            metrics.llm_prefill_wave_splits_total.inc(splits)
         out = []
         if adopted:
             from ray_tpu.llm.disagg.kv_plane import scatter_pages

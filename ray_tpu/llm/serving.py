@@ -94,18 +94,22 @@ class LLMEngineServer:
                  max_seq_len: int = 512, eos_id: int | None = None,
                  lora_adapters: dict | None = None, lora_rank: int = 8,
                  default_max_tokens: int = 32, kv_dtype: str | None = None):
+        # a replica runs jax; imported before configure_jax() so that a
+        # process pinned to the CPU without it hears of its programs too
+        import jax
+
+        from ray_tpu.utils import tracing
         from ray_tpu.utils.device import configure_jax
 
         configure_jax()
-        if params is None:
-            params = params_fn() if params_fn is not None else None
-        if params is None:
-            import jax
+        with tracing.stage("weights"):
+            if params is None:
+                params = params_fn() if params_fn is not None else None
+            if params is None:
+                from ray_tpu.llm.programs import serving_programs
 
-            from ray_tpu.llm.programs import serving_programs
-
-            params = serving_programs(model_config).init(
-                jax.random.PRNGKey(0), model_config)
+                params = serving_programs(model_config).init(
+                    jax.random.PRNGKey(0), model_config)
         from ray_tpu.llm.engine import ContinuousBatchingEngine
 
         self.engine = ContinuousBatchingEngine(
@@ -209,7 +213,11 @@ class LLMEngineServer:
         """Counters of this replica's engine. ``stages``: cumulative sum
         and count of every stage family of ``utils/metrics.py`` in this
         process — the engine loop's phases, a request's queue, prefill
-        and decode waits, the prefill counters, the lane's two legs.
+        and decode waits, the prefill counters, the lane's two legs, and
+        bring-up by stage (``rt_bringup_seconds``). ``program_builds``: of
+        every program the engine got ready, whether it was compiled or read
+        from the compile cache and the seconds of each step
+        (``ContinuousBatchingEngine.program_builds``).
         ``weights_prepared``: parameter trees the engine has taken and laid
         out for serving (1 unless somebody assigned ``engine.params``). While
         a ``jax.profiler`` trace is on, also ``program_parts``: whoever
@@ -221,6 +229,7 @@ class LLMEngineServer:
                "waiting": len(self.engine.waiting),
                "free_pages": len(self.engine.free[0]),
                "weights_prepared": self.engine.weights_prepared,
+               "program_builds": self.engine.program_builds(),
                "stages": metrics.stage_totals()}
         if tracing.profiling():
             out["program_parts"] = self.engine.program_parts()

@@ -26,6 +26,7 @@ from ray_tpu.core.ref import ActorError, GetTimeoutError, TaskError
 from ray_tpu.train.checkpoint import Checkpoint, CheckpointManager
 from ray_tpu.train.config import RunConfig, ScalingConfig
 from ray_tpu.train.session import TrainContext, init_session
+from ray_tpu.utils import tracing
 
 
 log = logging.getLogger(__name__)
@@ -70,33 +71,32 @@ class TrainWorker:
         self._done = False
         self._result: Any = None
         self._error: str | None = None
-        self._session = None
+        self._session, self._stage = None, ("created", time.monotonic())
 
-    def setup(self, checkpoint_path: str | None):
-        import ray_tpu.collective as collective
-        from ray_tpu.utils.device import configure_jax
+    # Keep ``run`` where it stands (its call of the user's loop is line 105).
+    # A program with a Pallas kernel carries, in the kernel's Mosaic payload,
+    # every user frame that reached its lowering — file path, line, columns,
+    # qualified name — and jax's persistent compile cache keys on the
+    # payload (PERF.md §7, ROADMAP D12). A training loop lowers its step
+    # under ``run``'s frame: a line more above it and every training program
+    # with a kernel compiles again, once, on every machine. So new methods
+    # go between ``__init__`` and ``run`` only line for line, or below
+    # (which is why ``setup`` stands below ``poll``, out of reading order).
+    def _stand_in(self, name: str):
+        """A stage of ``setup``, to be entered with ``with``: timed as
+        ``tracing.stage(name)``, and what ``setup_stage`` answers while it
+        lasts and after it raised."""
+        self._stage = (name, time.monotonic())
+        return tracing.stage(name)
 
-        configure_jax()
-        # a train worker runs jax (checkpoints alone need it): pay for the
-        # import here, while the group is still in step, not inside the
-        # loop's first report, where seconds of skew let one rank run ahead
-        import jax  # noqa: F401
-
-        ckpt = Checkpoint.from_directory(checkpoint_path) if checkpoint_path else None
-        context = TrainContext(
-            world_rank=self.rank,
-            world_size=self.world_size,
-            local_rank=0,
-            trial_name=self.trial_name,
-            collective_group=self.group_name,
-        )
-        self._session = init_session(context, ckpt)
-        if self.world_size > 1 or self.backend == "xla":
-            collective.init_collective_group(
-                self.world_size, self.rank, backend=self.backend,
-                group_name=self.group_name,
-            )
-        return True
+    def setup_stage(self) -> dict:
+        """The stage of bring-up this worker stands in and for how long:
+        ``created`` before ``setup``, ``set_up`` after it, else a name of
+        ``tracing.STAGES``. Served while ``setup`` blocks (the actor runs
+        two calls at a time): a group that does not start asks every worker
+        (``JaxTrainer._where_workers_stand``)."""
+        name, since = self._stage
+        return {"stage": name, "seconds": time.monotonic() - since}
 
     def run(self, train_loop, config: dict):
         """Blocking execution of the user loop (runs on the actor's executor
@@ -121,6 +121,44 @@ class TrainWorker:
                 metrics, ckpt = self._session.outbox.get_nowait()
                 out.append((metrics, ckpt.path if ckpt else None))
         return {"reports": out, "done": done, "error": self._error}
+
+    def setup(self, checkpoint_path: str | None):
+        import ray_tpu.collective as collective
+        from ray_tpu.utils.device import configure_jax
+
+        with self._stand_in("train_jax_import"):
+            # a train worker runs jax (checkpoints alone need it): pay for
+            # the import here, while the group is still in step, not inside
+            # the loop's first report, where seconds of skew let one rank run
+            # ahead. Imported before configure_jax() so that a worker pinned
+            # to the CPU without it hears of its programs too
+            import jax  # noqa: F401
+
+            configure_jax()
+        with self._stand_in("train_session"):
+            ckpt = (Checkpoint.from_directory(checkpoint_path)
+                    if checkpoint_path else None)
+            context = TrainContext(
+                world_rank=self.rank,
+                world_size=self.world_size,
+                local_rank=0,
+                trial_name=self.trial_name,
+                collective_group=self.group_name,
+            )
+            self._session = init_session(context, ckpt)
+        if self.world_size > 1 or self.backend == "xla":
+            with self._stand_in("train_collective"):
+                collective.init_collective_group(
+                    self.world_size, self.rank, backend=self.backend,
+                    group_name=self.group_name,
+                )
+        self._stage = ("set_up", time.monotonic())
+        return True
+
+
+#: what a group that did not start waits for its workers to say where they
+#: stand (``TrainWorker.setup_stage``), all of them together
+_ASK_STAGE_TIMEOUT_S = 5.0
 
 
 class JaxTrainer:
@@ -185,48 +223,80 @@ class JaxTrainer:
         for tries in range(len(_START_BACKOFF_S) + 1):
             # a group's name is its rendezvous key: never one a dead try left
             group_name = f"{name}_g{attempt}" + (f"r{tries}" if tries else "")
-            pg = ray_tpu.placement_group(
-                [scaling.worker_resources() for _ in range(n)],
-                strategy=scaling.placement_strategy,
-            )
-            if not pg.ready(timeout=60):
+            with tracing.stage("group_placement", workers=n):
+                pg = ray_tpu.placement_group(
+                    [scaling.worker_resources() for _ in range(n)],
+                    strategy=scaling.placement_strategy,
+                )
+                placed = pg.ready(timeout=60)
+            if not placed:
                 ray_tpu.remove_placement_group(pg)
                 raise TrainingFailedError(
                     f"no placement for {n} worker(s) of {scaling.worker_resources()} "
                     f"within 60s; the cluster has {ray_tpu.available_resources()} free")
             WorkerCls = ray_tpu.remote(TrainWorker)
-            workers = [
-                # per-worker bundle_index: options differ every iteration
-                WorkerCls.options(  # raylint: disable=RT009
-                    num_cpus=scaling.worker_resources().get("CPU", 1.0),
-                    resources={k: v for k, v in scaling.worker_resources().items()
-                               if k != "CPU"},
-                    placement_group=pg,
-                    placement_group_bundle_index=i,
-                    # poll() must be servable while run() blocks an executor thread
-                    max_concurrency=2,
-                ).remote(i, n, name, scaling.backend(), group_name)
-                for i in range(n)
-            ]
+            workers = []
             try:
-                resume = manager.latest() or self.resume_from_checkpoint
-                ray_tpu.get(
-                    [w.setup.remote(resume.path if resume else None) for w in workers],
-                    timeout=_SETUP_TIMEOUT_S,
-                )
+                with tracing.stage("group_setup", workers=n):
+                    workers += [
+                        # per-worker bundle_index: options differ every iteration
+                        WorkerCls.options(  # raylint: disable=RT009
+                            num_cpus=scaling.worker_resources().get("CPU", 1.0),
+                            resources={k: v for k, v in
+                                       scaling.worker_resources().items()
+                                       if k != "CPU"},
+                            placement_group=pg,
+                            placement_group_bundle_index=i,
+                            # poll() and setup_stage() must be servable while
+                            # run() or setup() blocks an executor thread
+                            max_concurrency=2,
+                        ).remote(i, n, name, scaling.backend(), group_name)
+                        for i in range(n)
+                    ]
+                    resume = manager.latest() or self.resume_from_checkpoint
+                    ray_tpu.get(
+                        [w.setup.remote(resume.path if resume else None)
+                         for w in workers],
+                        timeout=_SETUP_TIMEOUT_S,
+                    )
                 return pg, workers
             except (ActorError, GetTimeoutError) as e:
+                why = (f"{type(e).__name__}: {e}; "
+                       f"{self._where_workers_stand(workers)}")
                 self._stop_group(pg, workers)
                 if tries == len(_START_BACKOFF_S):
                     raise TrainingFailedError(
                         f"the worker group did not start in {tries + 1} tries: "
-                        f"{type(e).__name__}: {e}") from e
-                log.warning("worker group %s did not start (%s: %s): again in %g s",
-                            group_name, type(e).__name__, e, _START_BACKOFF_S[tries])
+                        f"{why}") from e
+                log.warning("worker group %s did not start (%s): again in %g s",
+                            group_name, why, _START_BACKOFF_S[tries])
                 time.sleep(_START_BACKOFF_S[tries])
             except BaseException:
                 self._stop_group(pg, workers)
                 raise
+
+    @staticmethod
+    def _where_workers_stand(workers) -> str:
+        """What each worker of a group that did not start says of itself
+        (``TrainWorker.setup_stage``), in words: "worker 0 stood in
+        train_collective for 118.2 s" — or, of one that gives no answer
+        within ``_ASK_STAGE_TIMEOUT_S`` for all, that it was never created.
+        Asking must not fail the start a second time."""
+        refs = [w.setup_stage.remote() for w in workers]
+        deadline = time.monotonic() + _ASK_STAGE_TIMEOUT_S
+        said = []
+        for rank, ref in enumerate(refs):
+            try:
+                # each answer alone: one worker's failure must not hide the
+                # others' stages
+                at = ray_tpu.get(  # raylint: disable=RT002
+                    ref, timeout=max(0.1, deadline - time.monotonic()))
+                said.append(f"worker {rank} stood in {at['stage']} for "
+                            f"{at['seconds']:.1f} s")
+            except Exception as e:  # raylint: disable=RT012 — a diagnosis, never a second failure
+                said.append(f"worker {rank} was never created "
+                            f"({type(e).__name__})")
+        return ", ".join(said)
 
     @staticmethod
     def _stop_group(pg, workers) -> None:

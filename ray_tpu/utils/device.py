@@ -43,6 +43,7 @@ _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 _CACHE_MIN_COMPILE_SECS = 0.1
 
 _configured = False
+_listening = False
 
 
 class AcceleratorMismatchError(RuntimeError):
@@ -95,11 +96,20 @@ def configure_jax() -> None:
     A process pinned to the CPU that has not imported jax is configured
     through the variables jax reads at import and imports nothing: most
     plain workers, the serve controller and the proxies never touch jax,
-    and importing it costs each of them seconds of start-up."""
+    and importing it costs each of them seconds of start-up.
+
+    A process that has jax also hears of every program jax builds from then
+    on (``_listen_to_builds``); one that was pinned without it does from its
+    next call here after its own ``import jax``."""
     global _configured
-    if _configured:
-        return
-    _configured = True
+    if not _configured:
+        _configured = True
+        _place_backend_and_cache()
+    if not _listening and "jax" in sys.modules:
+        _listen_to_builds()
+
+
+def _place_backend_and_cache() -> None:
     n = forced_cpu_devices()
     if n > 0:
         flags = os.environ.get("XLA_FLAGS", "")
@@ -128,6 +138,22 @@ def configure_jax() -> None:
                       _CACHE_MIN_COMPILE_SECS)
 
 
+def _listen_to_builds() -> None:
+    """Register, once a process, the listeners that sum what jax reports of
+    each program it traces, lowers, reads from the compile cache or compiles
+    into ``rt_bringup_seconds`` (``utils/tracing.py`` ``build_duration``).
+    They fire only when jax builds a program: never on a step's path."""
+    global _listening
+    _listening = True
+    from jax import monitoring
+
+    from ray_tpu.utils import tracing
+
+    monitoring.register_event_listener(tracing.build_event)
+    monitoring.register_scalar_listener(tracing.build_begin)
+    monitoring.register_event_duration_secs_listener(tracing.build_duration)
+
+
 def verify_leased_chips(chips: list[str]) -> None:
     """Hold a chip worker to its lease: it was born with exactly these
     chips in its environment, every device is a TPU and there are
@@ -141,10 +167,13 @@ def verify_leased_chips(chips: list[str]) -> None:
             f"with TPU_VISIBLE_CHIPS={os.environ.get('TPU_VISIBLE_CHIPS')!r}")
     if not chips or forced_cpu_devices() > 0:
         return
-    configure_jax()
-    import jax
+    from ray_tpu.utils import tracing
 
-    devs = jax.devices()
+    with tracing.stage("backend_start", chips=len(chips)):
+        configure_jax()
+        import jax
+
+        devs = jax.devices()
     if len(devs) != len(chips) or any(d.platform != "tpu" for d in devs):
         raise AcceleratorMismatchError(
             f"lease granted TPU chips {list(chips)} but this worker (pid "
@@ -167,9 +196,14 @@ def holds_tpu_backend() -> bool:
 
 
 def device_report() -> dict:
-    """What this process really runs on, for health checks and smokes."""
+    """What this process really runs on, for health checks and smokes, and
+    ``bringup``: the seconds and counts of every stage of bring-up this
+    process has been through (``rt_bringup_seconds`` by
+    ``tracing.STAGES``) — a train worker has no engine to ask."""
     configure_jax()
     import jax
+
+    from ray_tpu.utils import metrics
 
     devs = jax.devices()
     stats = [d.memory_stats() or {} for d in devs]
@@ -182,4 +216,5 @@ def device_report() -> dict:
         "peak_bytes_in_use": [s.get("peak_bytes_in_use") for s in stats],
         "bytes_in_use": [s.get("bytes_in_use") for s in stats],
         "bytes_limit": stats[0].get("bytes_limit"),
+        "bringup": metrics.family_totals(metrics.bringup_seconds),
     }

@@ -264,9 +264,6 @@ llm_window_pages_released_total = Counter(
     "rt_llm_window_pages_released_total",
     "pages a window slid past that its ring table reused, counted when the "
     "slot is freed")
-llm_prefill_wave_splits_total = Counter(
-    "rt_llm_prefill_wave_splits_total",
-    "prefill programs a family's wave limit added to their pad groups")
 # What a model family's decode programs count themselves, a step
 # (llm/programs.py ServePrograms.stats): the sums ride back with each block's
 # tokens and land here when the block is synced. The expert layers of
@@ -353,6 +350,18 @@ serve_lane_seconds = Histogram(
     "actor-lane call: ring (submit to pop) and loop (pop to the call's "
     "start on the event loop)",
     boundaries=_WAIT_BOUNDS, tag_keys=("leg",))
+# --- bring-up (PR 50) -------------------------------------------------------
+# Every stage between a process's start and its first step, by the one
+# vocabulary of utils/tracing.py STAGES: tracing.stage times the stages the
+# program walks through itself, tracing.build_duration what jax reports of
+# every program it builds (its trace, its lowering, and a read from the
+# persistent compile cache or a compile). Sums and counts, so a count is the
+# number of programs, tries or trees.
+bringup_seconds = Histogram(
+    "rt_bringup_seconds",
+    "stages of bring-up: backend start, weights, pools, a program's trace / "
+    "lower / cache read / compile, a train group's placement and set-up",
+    boundaries=(0.001, 0.01, 0.1, 1.0, 10.0, 100.0), tag_keys=("stage",))
 STAGE_FAMILIES = (
     llm_engine_phase_seconds, llm_queue_wait_seconds,
     llm_prefill_wait_seconds, llm_decode_seconds, llm_decode_tokens_total,
@@ -360,24 +369,23 @@ STAGE_FAMILIES = (
     llm_prefill_true_tokens_total, llm_prefill_padded_tokens_total,
     llm_decode_kv_tokens_live_total, llm_decode_kv_tokens_read_total,
     llm_pages_held, llm_pages_drawn_total, llm_window_pages_released_total,
-    llm_prefill_wave_splits_total,
-    *LLM_MODEL_STATS.values(), serve_lane_seconds)
+    *LLM_MODEL_STATS.values(), serve_lane_seconds, bringup_seconds)
+
+
+def family_totals(m) -> dict:
+    """``{tag value or "": {"sum", "count"}}`` of one family, cumulative
+    since process start (a counter has no ``count``)."""
+    if isinstance(m, Histogram):
+        return {(k[0][1] if k else ""):
+                {"sum": m._sums.get(k, 0.0), "count": sum(c)}
+                for k, c in list(m._counts.items())}
+    return {(k[0][1] if k else ""): {"sum": v}
+            for k, v in list(m._values.items())}
 
 
 def stage_totals() -> dict:
-    """``{family: {tag value or "": {"sum", "count"}}}`` of the stage
-    families above, cumulative since process start (a counter has no
-    ``count``)."""
-    out = {}
-    for m in STAGE_FAMILIES:
-        if isinstance(m, Histogram):
-            out[m.name] = {(k[0][1] if k else ""):
-                           {"sum": m._sums.get(k, 0.0), "count": sum(c)}
-                           for k, c in list(m._counts.items())}
-        else:
-            out[m.name] = {(k[0][1] if k else ""): {"sum": v}
-                           for k, v in list(m._values.items())}
-    return out
+    """``{family: family_totals}`` of the stage families above."""
+    return {m.name: family_totals(m) for m in STAGE_FAMILIES}
 
 
 # serve SLO cumulatives: serve_slo_breach_fraction = breaches/requests

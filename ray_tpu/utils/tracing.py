@@ -18,6 +18,12 @@ summed into one registry histogram and, while a ``jax.profiler`` trace
 is on, mirrored as a ``TraceAnnotation`` so that it lands in the same
 ``.xplane.pb`` — and on the same clock — as the device's own lines.
 
+Before a loop's first step stands :class:`stage`: a ``phase`` of bring-up
+(``STAGES``: the backend's start, weights, pools, a train group's placement
+and set-up), summed into ``rt_bringup_seconds``; :func:`build_duration`
+adds what jax reports of every program it builds, and says of each whether
+it was compiled or read from the persistent compile cache.
+
 Inside the jitted programs stands :func:`part`: the one vocabulary of named
 scopes (``PARTS``) by which a serve program says which part of a layer each
 of its instructions belongs to. The profiler drops a scope from the device's
@@ -47,6 +53,7 @@ PR 8 and PR 11 evicted from task and promise ids).
 
 from __future__ import annotations
 
+import asyncio
 import contextvars
 import itertools
 import os
@@ -339,11 +346,16 @@ class phase:
     this thread's line of the trace's ``/host:CPU`` plane — one file and
     one clock with the device's ``XLA Modules``. :meth:`set` adds what is
     only known inside the phase (a block's step count, tokens emitted).
+    ``seconds`` is the last interval, once it has closed.
 
     An annotation belongs to its thread: a phase must not span an
     ``await`` that suspends while another phase could open."""
 
-    __slots__ = ("name", "args", "_ann", "_t0")
+    __slots__ = ("name", "args", "seconds", "_ann", "_t0")
+    # where the seconds go: a subclass names another family and tag, and
+    # may know seconds of the interval that are counted under another name
+    family, tag = metrics.llm_engine_phase_seconds, "phase"
+    _elsewhere = 0.0
 
     def __init__(self, name: str, **args):
         self.name = name
@@ -367,9 +379,145 @@ class phase:
         if self._ann is not None:
             self._ann.__exit__(exc_type, exc, tb)
             self._ann = None
-        metrics.llm_engine_phase_seconds.observe(dt * 1e-9,
-                                                 {"phase": self.name})
+        self.seconds = max(0.0, dt * 1e-9 - self._elsewhere)
+        self.family.observe(self.seconds, {self.tag: self.name})
         return False
+
+
+# ----------------------------------------------------------------- bring-up
+# Every stage between a process's start and its first step, each named where
+# the work happens. The five ``program_*`` a jax.monitoring listener feeds
+# (``build_duration``); the others are ``stage`` blocks.
+STAGES = (
+    "backend_start",      # jax's import and jax.devices(): libtpu on the leased chip
+    "weights",            # a replica's parameter tree made (params_fn / the family's init)
+    "weights_prepare",    # ServePrograms.prepare: the tree laid out for serving
+    "pools",              # the engine's cache allocated (ServePrograms.make_cache)
+    "program_trace",      # a program traced to a jaxpr (Python)
+    "program_lower",      # the jaxpr lowered to its module (Python)
+    "program_cache_read",  # an executable read from the persistent compile cache
+    "program_compile",    # an executable compiled that the cache could hold
+    "program_compile_small",  # one compiled faster than the cache's minimum: never written
+    "parts_table",        # compiled_parts, in its own thread beside the program's first run
+    "group_placement",    # JaxTrainer: the placement group, a try
+    "group_setup",        # JaxTrainer: the workers created and set up, a try
+    "train_jax_import",   # TrainWorker.setup: jax imported and configured
+    "train_session",      # TrainWorker.setup: the checkpoint and the session
+    "train_collective",   # TrainWorker.setup: the collective group's rendezvous
+)
+
+
+class stage(phase):
+    """A :class:`phase` of bring-up: the same two clock reads, one observe
+    (into ``rt_bringup_seconds{stage=<name>}``) and annotation while a
+    profiler trace is on. A name outside ``STAGES`` raises where it is
+    written. Its seconds are its OWN: what jax built inside it in this
+    thread (making weights compiles a hundred small programs) is counted
+    under the ``program_*`` stages and taken off here, so that the stages of
+    one thread add up to its wall time."""
+
+    __slots__ = ("_built", "_elsewhere")
+    family, tag = metrics.bringup_seconds, "stage"
+
+    def __init__(self, name: str, **args):
+        if name not in STAGES:
+            raise ValueError(f"{name!r} is not a stage of bring-up: {STAGES}")
+        super().__init__(name, **args)
+
+    def __enter__(self):
+        self._built = _built_here()
+        return super().__enter__()
+
+    def __exit__(self, exc_type, exc, tb):
+        self._elsewhere = _built_here() - self._built
+        return super().__exit__(exc_type, exc, tb)
+
+
+# what jax reports of a program it builds, in the thread that builds it and
+# in this order (jax/_src/compiler.py compile_or_get_cached): trace, lower,
+# then on a cache hit the event, the retrieval time and a "backend compile"
+# duration that IS the retrieval; on a miss the backend compile alone
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_BUILD_EVENTS = {
+    _TRACE: "program_trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "program_lower",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "program_cache_read",
+    "/jax/core/compile/backend_compile_duration": "program_compile",
+}
+# per thread: .hit, a cache hit awaits its duration; .tracing, how deep in
+# traces of jitted functions inside jitted functions (jax times each, the
+# outer one's seconds hold the inner ones'); .built, the seconds counted
+_build_thread = _threading.local()
+# the record of ONE program that ``build_in_executor`` collects: a context
+# variable, because ``Context.run`` puts no Python frame between the thread
+# and the caller's thunk (a Mosaic payload carries those frames, and the
+# compile cache's key the payload)
+_building: contextvars.ContextVar[dict | None] = contextvars.ContextVar(
+    "rt_building", default=None)
+
+
+def _built_here() -> float:
+    """Seconds of program builds counted in this thread so far."""
+    return getattr(_build_thread, "built", 0.0)
+
+
+def build_event(event: str, **kw) -> None:
+    """jax.monitoring event listener (``utils/device.py`` registers the
+    three of them)."""
+    if event == _CACHE_HIT:
+        _build_thread.hit = True
+
+
+def build_begin(event: str, value: float, **kw) -> None:
+    """jax.monitoring scalar listener: jax says when a timed step starts."""
+    if event == _TRACE:
+        _build_thread.tracing = getattr(_build_thread, "tracing", 0) + 1
+
+
+def build_duration(event: str, seconds: float, **kw) -> None:
+    """jax.monitoring duration listener. A trace counts where it is the
+    outermost of its thread. Every executable obtained counts once — under
+    ``program_cache_read``, ``program_compile`` or, compiled faster than the
+    persistent cache's minimum as jax holds it now (jax never writes such a
+    program: eager operations make hundreds), ``program_compile_small``."""
+    name = _BUILD_EVENTS.get(event)
+    if name is None:
+        return
+    if name == "program_trace":
+        _build_thread.tracing = deep = getattr(_build_thread, "tracing", 1) - 1
+        if deep > 0:
+            return
+    elif name == "program_compile":
+        if getattr(_build_thread, "hit", False):
+            _build_thread.hit = False
+            return
+        config = sys.modules["jax"].config
+        if seconds < config.jax_persistent_cache_min_compile_time_secs:
+            name = "program_compile_small"
+    metrics.bringup_seconds.observe(seconds, {"stage": name})
+    _build_thread.built = _built_here() + seconds
+    built = _building.get()
+    if built is not None:
+        field = name.removeprefix("program_").removesuffix("_small") + "_s"
+        built[field] += seconds
+
+
+async def build_in_executor(executor, thunk):
+    """``loop.run_in_executor(executor, thunk)`` for a thunk that builds ONE
+    program -> ``(its result, {"source": "cache" | "compiled", "trace_s",
+    "lower_s", "cache_read_s", "compile_s"})``: what the listeners above saw
+    in the thread while it ran. ``"memory"`` where they saw no executable
+    obtained: jit's own caches had it (a second engine in one process)."""
+    built = dict.fromkeys(("trace_s", "lower_s", "cache_read_s", "compile_s"),
+                          0.0)
+    ctx = contextvars.copy_context()
+    ctx.run(_building.set, built)
+    result = await asyncio.get_running_loop().run_in_executor(
+        executor, ctx.run, thunk)
+    source = ("cache" if built["cache_read_s"] else
+              "compiled" if built["compile_s"] else "memory")
+    return result, {"source": source, **built}
 
 
 # ------------------------------------------------ parts of a jitted program
@@ -536,11 +684,11 @@ def compiled_parts(compiled) -> dict:
     ``instruction_parts`` and the seconds making them took. ``stale``: no
     instruction names a part — the executable came from a compile cache
     whose key leaves the scopes out, written by a tree without them."""
-    t0 = time.perf_counter()
-    module, parts = instruction_parts(compiled.as_text())
+    with stage("parts_table") as reading:
+        module, parts = instruction_parts(compiled.as_text())
     return {"module": module, "parts": parts,
             "stale": not any(p in PARTS for p in parts.values()),
-            "seconds": time.perf_counter() - t0}
+            "seconds": reading.seconds}
 
 
 def merged_parts(variants) -> dict:
