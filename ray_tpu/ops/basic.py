@@ -66,13 +66,26 @@ def layer_norm(x, scale, eps: float = 1e-5):
 def rope_pairs(x, cos, sin, positions):
     """Rotary embedding in the adjacent-pair form (``rope_gptj``): lanes
     (2i, 2i + 1) rotate together by ``positions * theta^(-2i / D)``. x:
-    [B, T, H, D]; cos/sin: [T_max, D/2] (``rope_freqs``); positions: [B, T]."""
-    c = cos[positions][:, :, None, :]
-    s = sin[positions][:, :, None, :]
-    pairs = x.reshape(*x.shape[:-1], x.shape[-1] // 2, 2)
-    x1, x2 = pairs[..., 0], pairs[..., 1]
-    out = jnp.stack([x1 * c - x2 * s, x1 * s + x2 * c], axis=-1)
-    return out.reshape(x.shape).astype(x.dtype)
+    [B, T, H, D]; cos/sin: [T_max, D/2] (``rope_freqs``); positions: [B, T].
+
+    Lane-preserving on purpose, ``x * C + swap(x) * S`` with ``swap`` two
+    one-lane shifts and a select on the lane's parity: every lane stays
+    where the product ``h @ wq`` left it. The plain form (``x[..., 0::2]``,
+    ``x[..., 1::2]``, stack) is a strided slice of a product, which XLA
+    moves onto the product's weight: each window layer then re-laid out its
+    whole ``wq`` (134 MB) on every decode step (ledger PR 50,
+    ``reshape:bf16_128_64_2_4096``; ``tests/test_chip_compile.py``
+    ``test_cohere2_moe_decode_lays_no_weight_out``). The shifts pad and
+    slice rather than ``jnp.roll``: a roll's wrap-around is a concatenate
+    that a 12,288-token prefill materialises (1.3 GB of temporaries)."""
+    odd = jnp.arange(x.shape[-1]) % 2 == 1
+    c = jnp.repeat(cos[positions], 2, axis=-1)[:, :, None, :]
+    s = jnp.repeat(sin[positions], 2, axis=-1)[:, :, None, :]
+    lead = ((0, 0),) * (x.ndim - 1)
+    after = jnp.pad(x, lead + ((0, 1),))[..., 1:]     # lane j holds x[j + 1]
+    before = jnp.pad(x, lead + ((1, 0),))[..., :-1]   # lane j holds x[j - 1]
+    out = x * c + jnp.where(odd, before, after) * jnp.where(odd, s, -s)
+    return out.astype(x.dtype)
 
 
 def dense_init(key, d_in, d_out, dtype):

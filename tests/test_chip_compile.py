@@ -10,6 +10,7 @@ described inside a fixture, after a test of this file has started — never
 while a module is imported."""
 
 import dataclasses
+import math
 import re
 
 import jax
@@ -342,30 +343,40 @@ def _cohere2_args(one_chip, layer_types):
     return cfg, params, cache, one_chip(_shape((2,), jnp.uint32))
 
 
-def test_cohere2_moe_decode_multi_compiles(one_chip, monkeypatch):
-    """One window and one full layer: each attends its own kind of pool in
-    place, the window layer through the ring table's 257 entries from its
-    first live page, 16 query heads a KV head in a 256-token block."""
+@pytest.fixture(scope="module")
+def cohere2_decode(one_chip):
+    """(cfg, lowered, compiled) decode program of one window and one full
+    layer for 48 slots and 8 steps, as a TPU's backend would choose its
+    forms: compiled once for the two tests that read it."""
     from ray_tpu.llm.cohere2_moe import cohere2_moe_decode_multi
-    from ray_tpu.llm.programs import MOE_STATS
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cohere2_moe_decode_multi.clear_cache()
     cfg, params, cache, key = _cohere2_args(
         one_chip, ("sliding_attention", "full_attention"))
     B = 48
     i32 = one_chip(_shape((B,), jnp.int32))
     tables = (one_chip(_shape((B, 832), jnp.int32)),
               one_chip(_shape((B, 257), jnp.int32)))
-    try:
-        lowered = cohere2_moe_decode_multi.lower(
-            params, None, i32, i32, i32, tables, *cache,
-            one_chip(_shape((B,), jnp.bool_)),
-            one_chip(_shape((B,), jnp.float32)), key, cfg=cfg, n_steps=8)
-        compiled = lowered.compile()
-    finally:
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
         cohere2_moe_decode_multi.clear_cache()
-    assert lowered.out_info[0].shape == (8, B + len(MOE_STATS))
+        try:
+            lowered = cohere2_moe_decode_multi.lower(
+                params, None, i32, i32, i32, tables, *cache,
+                one_chip(_shape((B,), jnp.bool_)),
+                one_chip(_shape((B,), jnp.float32)), key, cfg=cfg, n_steps=8)
+            return cfg, lowered, lowered.compile()
+        finally:
+            cohere2_moe_decode_multi.clear_cache()
+
+
+def test_cohere2_moe_decode_multi_compiles(cohere2_decode):
+    """One window and one full layer: each attends its own kind of pool in
+    place, the window layer through the ring table's 257 entries from its
+    first live page, 16 query heads a KV head in a 256-token block."""
+    from ray_tpu.llm.programs import MOE_STATS
+
+    cfg, lowered, compiled = cohere2_decode
+    assert lowered.out_info[0].shape == (8, 48 + len(MOE_STATS))
     text = compiled.as_text()
     assert len(re.findall(r"%_paged_window_attention\S* = \S+ custom-call\(",
                           text)) == 1
@@ -380,6 +391,43 @@ def test_cohere2_moe_decode_multi_compiles(one_chip, monkeypatch):
     assert not re.findall(gathered, text)
     # the temporaries are a step's activations, not a copy of a pool
     assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+
+
+def test_cohere2_moe_decode_lays_no_weight_out(cohere2_decode):
+    """Inside the step loop's body nothing re-lays out a projection's weight:
+    no ``reshape``, ``copy`` or ``transpose`` whose result has the extent of
+    ``wq`` (128 heads x 128 lanes x 4096) or of ``wk`` / ``wv`` (8 heads).
+    Until PR 51 ``rope_pairs`` took the even and the odd lanes of ``q`` and
+    ``k`` apart, the compiler moved that strided slice through the product
+    onto its weight, and the body held ``reshape bf16[128,64,2,4096]`` of
+    ``wq`` (134 MB a window layer a step) and ``bf16[8,64,2,4096]`` of
+    ``wk``. What the program does once — ``wq`` turned column-major in
+    ``main``, a layer a program — is outside the loop and stays."""
+    cfg, _, compiled = cohere2_decode
+    extents = {heads * cfg.head_dim * cfg.d_model
+               for heads in (cfg.n_heads, cfg.n_kv_heads)}
+
+    def lays_out(rows):
+        out = []
+        for _, key, opcode, *_ in rows:
+            if opcode not in ("reshape", "copy", "transpose"):
+                continue
+            dims = re.fullmatch(r"bf16\[([\d,]+)\]", key.split("|")[1])
+            if dims and math.prod(map(int, dims[1].split(","))) in extents:
+                out.append(f"{opcode} {key}")
+        return out
+
+    computations = tracing.program_instructions(compiled.as_text())[1]
+    main = [rows for rows in computations
+            if any(opcode == "while" for _, _, opcode, *_ in rows)]
+    assert len(main) == 1  # the scan over the steps is the only loop
+    # the walk sees a weight's layout change where there is one
+    assert any("copy" in found and "[4096,16384]" in found
+               for found in lays_out(main[0]))
+    # the body, and what it calls: the loop's condition, the sampling branches
+    body = [row for rows in computations if rows is not main[0] for row in rows]
+    assert len(body) > 300
+    assert lays_out(body) == []
 
 
 def test_cohere2_moe_prefill_batch_compiles(one_chip, monkeypatch):

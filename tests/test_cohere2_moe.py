@@ -20,6 +20,7 @@ from ray_tpu.llm.engine import (ContinuousBatchingEngine, UnsupportedByModel,
                                 serving_programs)
 from ray_tpu.models.cohere2_moe import (Cohere2MoeConfig, cohere2_moe_forward,
                                         cohere2_moe_init)
+from ray_tpu.ops.basic import rope_freqs, rope_pairs
 from ray_tpu.ops.paged_attention import paged_decode_attention
 from ray_tpu.ops.prefill_attention import gqa_prefill_attention
 from ray_tpu.parallel.moe import routed_experts, sigmoid_topk_route
@@ -383,6 +384,65 @@ def test_blocked_prefill_attention_matches_the_plain_form(window):
                                 v.reshape(N, T, -1), n_kv_heads=KV,
                                 window=window, interpret=True)
     assert float(jnp.abs(got - want).max()) < 1e-5
+
+
+# ------------------------------------------------------- the pair rotation
+def _rope_pairs_deinterleaved(x, cos, sin, positions):
+    """The form ``ops/basic.py`` ``rope_pairs`` had until PR 51, kept as its
+    plain reference: the lanes split into (even, odd) and stacked back."""
+    c = cos[positions][:, :, None, :]
+    s = sin[positions][:, :, None, :]
+    pairs = x.reshape(*x.shape[:-1], x.shape[-1] // 2, 2)
+    x1, x2 = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([x1 * c - x2 * s, x1 * s + x2 * c], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def _rotation_case(shape, dtype, max_seq_len=13312):
+    B, T, _, D = shape
+    rng = np.random.default_rng(B * 1000 + D)
+    x = jnp.asarray(rng.standard_normal(shape), dtype)
+    positions = rng.integers(0, max_seq_len, (B, T))
+    positions[0, 0], positions[-1, -1] = 0, max_seq_len - 1
+    return x, *rope_freqs(D, max_seq_len, 50000.0), jnp.asarray(positions)
+
+
+@pytest.mark.parametrize("jit", [False, True], ids=["eager", "jit"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("shape", [(2, 5, 8, 16), (48, 1, 128, 128),
+                                   (1, 7, 8, 128)])
+def test_rope_pairs_is_the_deinterleaved_rotation_lane_for_lane(shape, dtype,
+                                                                jit):
+    """Evaluated eagerly the lane-preserving form is the replaced one bit for
+    bit (``a - b * s`` and ``a + b * (-s)`` are one float operation); under
+    ``jit`` the compiler contracts the two forms' multiply-adds differently:
+    one unit in the last place of the output's dtype at most, and where the
+    two products cancel one float32 unit of the larger product."""
+    args = _rotation_case(shape, jnp.dtype(dtype))
+    if not jit:
+        with jax.disable_jit():
+            got, want = rope_pairs(*args), _rope_pairs_deinterleaved(*args)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        return
+    got = np.asarray(jax.jit(rope_pairs)(*args), np.float32)
+    want = np.asarray(jax.jit(_rope_pairs_deinterleaved)(*args), np.float32)
+    assert got.shape == shape
+    # a bfloat16's last place is 2**16 of the float32's that holds it
+    ulp = np.spacing(np.abs(want)) * (1 if dtype == "float32" else 2.0**16)
+    pair = np.abs(np.asarray(args[0], np.float32)).reshape(*shape[:-1], -1, 2)
+    product = np.repeat(pair.max(-1), 2, axis=-1)  # |cos|, |sin| <= 1
+    bound = np.maximum(ulp, np.spacing(product))
+    assert (np.abs(got - want) <= bound).all(), float(np.abs(got - want).max())
+
+
+def test_rope_pairs_is_the_references_rotation():
+    """Against ``benchmarks/reference/cohere2_moe.py`` ``_rotate_pairs``,
+    which makes its own angles from the position: float32, to 1e-6."""
+    T, H, D = 300, 8, 128
+    x = jax.random.normal(jax.random.PRNGKey(7), (T, H, D))
+    got = rope_pairs(x[None], *rope_freqs(D, 512, 50000.0),
+                     jnp.arange(T)[None])[0]
+    assert float(jnp.abs(got - R._rotate_pairs(x, 50000.0)).max()) < 1e-6
 
 
 # ---------------------------------------------------------------- refusals
