@@ -3,6 +3,7 @@
 that was leased the chip and reports once, at its end."""
 from __future__ import annotations
 
+import collections
 import os
 import tempfile
 import time
@@ -106,10 +107,20 @@ def train_loop(config: dict) -> None:
         losses.append(float(loss))  # the fence: a device -> host read
     marks["window"] = t0 = time.monotonic()
 
-    # one step in flight ahead of the one whose loss is read: the host never
-    # holds the device back, and each step's end is seen at its own fence
+    # ``steps_ahead`` steps (some seconds of them) in flight ahead of the one
+    # whose loss is read: the device works on while the host stands still, and
+    # each step's end is seen at its own fence. When the window's time is up
+    # nothing more is sent, all that was sent is waited for, and the last fence
+    # closes the span: every step sent counts, over all of that time
     seconds, trace_dir = config["seconds"], config["trace_dir"]
-    ends, pending, i, trace, tracing = [], None, 0, None, None
+    ahead = int(tr["steps_ahead"])
+    ends, sent, i, trace, tracing = [], collections.deque(), 0, None, None
+
+    def fence(keep: int) -> None:
+        while len(sent) > keep:
+            losses.append(float(sent.popleft()))
+            ends.append(time.monotonic())
+
     while True:
         now = time.monotonic()
         if config["trace"] and tracing is None and len(ends) >= 2:
@@ -117,10 +128,7 @@ def train_loop(config: dict) -> None:
             tracing = (time.monotonic(), len(ends))
         if (tracing and trace is None
                 and now - tracing[0] >= config["trace_seconds"]):
-            if pending is not None:
-                losses.append(float(pending))
-                ends.append(time.monotonic())
-                pending = None
+            fence(0)
             span = time.monotonic() - tracing[0]
             jax.profiler.stop_trace()
             trace = {**reduce_trace(trace_dir), "span_s": span,
@@ -130,19 +138,14 @@ def train_loop(config: dict) -> None:
         params, opt_state, loss = compiled(params, opt_state,
                                            batches[i % len(batches)])
         i += 1
-        if pending is not None:
-            losses.append(float(pending))
-            ends.append(time.monotonic())
-        pending = loss
-    if pending is not None:
-        losses.append(float(pending))
-        ends.append(time.monotonic())
-    whole = [e for e in ends if e <= t0 + seconds]
+        sent.append(loss)
+        fence(ahead)
+    fence(0)
     marks["measured"] = time.monotonic()
     train.report({
         "device": device_report(), "marks": marks, "reference": reference,
         "has_kernel": has_kernel, "losses": losses, "step_ends": [e - t0 for e in ends],
-        "steps": len(whole), "span_s": (whole[-1] - t0) if whole else 0.0,
+        "steps": len(ends), "span_s": (ends[-1] - t0) if ends else 0.0,
         "tokens_per_step": B * S, "trace": trace,
     })
 

@@ -16,8 +16,8 @@ With ``of``: as a share, in %, of the same reading of those stages.
 A program without the family (the parent of the PR that added it) reads as
 nothing. The first read of a traced run prints the stage table, each
 program's build record, what was built in the window and every
-``layer_metrics/setup.*.json`` as ``[bench]`` lines: a cell lists few of
-them, the run shows all."""
+``layer_metrics/setup.*.json`` as ``[bench]`` lines, whether the cell
+lists it or not."""
 import glob
 import os
 

@@ -15,6 +15,8 @@ either edge of the span can move; from it the same settling on the
 buckets. A trace without those annotations reads as nothing."""
 import statistics
 
+from benchmarks.readers.program_named import resolve
+
 HOST_PLANE, DISPATCH = "/host:CPU", "engine.decode_dispatch"
 
 
@@ -46,10 +48,10 @@ def _dispatched(run: dict) -> list[int]:
 
 
 def steps_and_seconds(run: dict, program: str):
-    trace = run.get("trace")
-    if not trace or program not in trace["programs"]:
+    program = resolve(run, program)
+    if program is None:
         return None
-    durations = trace["programs"][program]["durations"]
+    durations = run["trace"]["programs"][program]["durations"]
     dispatched = _dispatched(run)
     if not durations or not dispatched:
         return None

@@ -1,8 +1,10 @@
-"""Device time of one jitted program as a share of the device's busy time."""
+"""Device time of one jitted program (named, or found by its pattern:
+``readers/program_named.py``) as a share of the device's busy time."""
+from benchmarks.readers.program_named import resolve
 
 
 def read(run: dict, program: str):
-    trace = run.get("trace")
-    if not trace or not trace["busy_s"] or program not in trace["programs"]:
+    trace, program = run.get("trace"), resolve(run, program)
+    if program is None or not trace["busy_s"]:
         return None
     return 100.0 * trace["programs"][program]["seconds"] / trace["busy_s"]

@@ -1,5 +1,5 @@
-"""Tokens of the whole steps that ended inside the window over their span,
-which the last step's fence closes."""
+"""Tokens of every step sent inside the window over the time from its start to
+the last step's fence, which is waited for once the window's time is up."""
 
 
 def read(run: dict):

@@ -27,12 +27,12 @@ CELL = "zaya1_cot_closed"
 CONFIG = "zaya1-8b.json"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 PEAKS = {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12}
-MINE = {"engine.decode_step_ms.cot", "engine.prefill_share.cot",
+MINE = {"engine.decode_step_ms.batch", "engine.prefill_share.batch",
         "kernel.cca_moe_decode_roofline", "kernel.cca_moe_prefill_roofline",
         "kernel.paged_decode_attention_roofline.cot", "kernel.cca_mix_share.cot",
-        "kernel.head_share.cot", "kernel.grouped_matmul_share.cot",
-        "moe.experts_touched_share.cot", "moe.load_imbalance.cot",
-        "kernel.decode_kv_read_amplification.cot"}
+        "kernel.head_share.cot", "kernel.grouped_matmul_share",
+        "moe.experts_touched_share", "moe.load_imbalance",
+        "kernel.decode_kv_read_amplification.batch"}
 JOINED = {"moe.expert_passes_per_touched", "kernel.router_share",
           "kernel.unnamed_share.batch", "engine.compiles_in_window.batch",
           "engine.loop_blocked_share.batch",
@@ -229,13 +229,13 @@ def test_new_readers_on_a_hand_made_run():
     run = _run()
     got = {k: v["value"] for k, v in
            bench_run.read_metrics(cell, "per_layer", run).items()}
-    assert got["moe.experts_touched_share.cot"] == pytest.approx(100 * 310 / 320)
+    assert got["moe.experts_touched_share"] == pytest.approx(100 * 310 / 320)
     assert got["moe.expert_passes_per_touched"] == pytest.approx(1.0)
-    assert got["engine.decode_step_ms.cot"] == pytest.approx(25.0)
-    assert got["engine.prefill_share.cot"] == pytest.approx(5.0)
-    assert got["kernel.decode_kv_read_amplification.cot"] == pytest.approx(
+    assert got["engine.decode_step_ms.batch"] == pytest.approx(25.0)
+    assert got["engine.prefill_share.batch"] == pytest.approx(5.0)
+    assert got["kernel.decode_kv_read_amplification.batch"] == pytest.approx(
         93_000 / 92_800)
-    assert got["kernel.grouped_matmul_share.cot"] == pytest.approx(30.0)
+    assert got["kernel.grouped_matmul_share"] == pytest.approx(30.0)
     assert got["kernel.cca_mix_share.cot"] == pytest.approx(5.0)
     assert got["kernel.head_share.cot"] == pytest.approx(10.0)
     # 40 steps in the trace (three 8-step and four 4-step blocks); 20 layers:
@@ -263,7 +263,7 @@ def test_new_readers_on_a_hand_made_run():
     bare["admitted_lens"] = []
     bare["part_seconds"] = None
     left = bench_run.read_metrics(cell, "per_layer", bare)
-    assert not (set(left) & MINE) - {"engine.prefill_share.cot"}
+    assert not (set(left) & MINE) - {"engine.prefill_share.batch"}
 
 
 def test_the_new_cell_is_found_by_name_as_files_alone():
@@ -288,7 +288,7 @@ def test_the_new_cell_is_found_by_name_as_files_alone():
     e2e = {m["name"] for m in configs.cell_metrics(cell, "end_to_end")}
     assert e2e == {"serve_tokens_per_s", "setup_s"}
     layer = configs.cell_metrics(cell, "per_layer")
-    assert {m["moves"] for m in layer} == {"serve_tokens_per_s"}
+    assert {m["moves"] for m in layer} >= {"serve_tokens_per_s"}
     names = {m["name"] for m in layer}
     # this PR's, and those the cell joined by name: a later PR may add more
     assert MINE | JOINED <= names
@@ -363,4 +363,4 @@ def test_the_cell_rehearses_on_the_cpu_at_tiny_sizes(tmp_path):
     assert ref["repeats"]
     rehearsed = line["rehearsal"]
     assert rehearsed["cpu-rehearsal.engine.compiles_in_window.batch"] == 0
-    assert 0 < rehearsed["cpu-rehearsal.moe.experts_touched_share.cot"] <= 100
+    assert 0 < rehearsed["cpu-rehearsal.moe.experts_touched_share"] <= 100

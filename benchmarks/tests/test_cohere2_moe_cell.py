@@ -173,12 +173,12 @@ def test_new_readers_on_a_hand_made_run():
     run = _run()
     got = {k: v["value"] for k, v in
            bench_run.read_metrics(cell, "per_layer", run).items()}
-    assert got["moe.experts_touched_share.mixed"] == pytest.approx(93.75)
+    assert got["moe.experts_touched_share"] == pytest.approx(93.75)
     # largest 40 a layer (160 / 4) over the mean 3 (192 / 64)
-    assert got["moe.load_imbalance.mixed"] == pytest.approx(16 * 160 / 192)
-    assert got["engine.decode_step_ms.mixed"] == pytest.approx(25.0)
-    assert got["engine.prefill_share.mixed"] == pytest.approx(40.0)
-    assert got["kernel.decode_kv_read_amplification.mixed"] == pytest.approx(1.004)
+    assert got["moe.load_imbalance"] == pytest.approx(16 * 160 / 192)
+    assert got["engine.decode_step_ms.batch"] == pytest.approx(25.0)
+    assert got["engine.prefill_share.batch"] == pytest.approx(40.0)
+    assert got["kernel.decode_kv_read_amplification.batch"] == pytest.approx(1.004)
     assert got["cache.window_pages_held_share.mixed"] == pytest.approx(48.0)
     least = count.least_seconds(run["cfg"], run["peaks"], 48, 150_000.0, 15.0, 48.0)
     assert got["kernel.swa_moe_decode_roofline"] == pytest.approx(
@@ -195,7 +195,7 @@ def test_new_readers_on_a_hand_made_run():
         assert got[name] == pytest.approx(100 * 40 * paged_count.least_seconds(
             run["cfg"], run["peaks"], 48, kind, reach) / took)
         assert got[name] < 100
-    assert got["kernel.grouped_matmul_share.mixed"] == pytest.approx(25.0)
+    assert got["kernel.grouped_matmul_share"] == pytest.approx(25.0)
     # a program without the counters or the kernel (the parent) reads as
     # nothing, and nothing raises
     bare = _run()
@@ -205,14 +205,14 @@ def test_new_readers_on_a_hand_made_run():
     bare["dispatched_steps"] = []       # a trace with no annotation in it
     bare["admitted_lens"] = []
     left = bench_run.read_metrics(cell, "per_layer", bare)
-    assert not {"moe.experts_touched_share.mixed", "moe.load_imbalance.mixed",
-                "kernel.swa_moe_decode_roofline", "engine.decode_step_ms.mixed",
+    assert not {"moe.experts_touched_share", "moe.load_imbalance",
+                "kernel.swa_moe_decode_roofline", "engine.decode_step_ms.batch",
                 "kernel.gqa_prefill_attention_roofline",
                 "kernel.swa_moe_prefill_roofline",
                 "kernel.paged_window_attention_roofline",
                 "kernel.paged_decode_attention_roofline.mixed",
-                "kernel.grouped_matmul_share.mixed",
-                "kernel.decode_kv_read_amplification.mixed",
+                "kernel.grouped_matmul_share",
+                "kernel.decode_kv_read_amplification.batch",
                 "cache.window_pages_held_share.mixed"} & set(left)
 
 
@@ -261,11 +261,11 @@ def test_the_new_cell_is_found_by_name_as_files_alone():
     e2e = {m["name"] for m in configs.cell_metrics(cell, "end_to_end")}
     assert e2e == {"serve_tokens_per_s", "setup_s"}
     layer = configs.cell_metrics(cell, "per_layer")
-    assert {m["moves"] for m in layer} == {"serve_tokens_per_s"}
+    assert {m["moves"] for m in layer} >= {"serve_tokens_per_s"}
     names = {m["name"] for m in layer}
-    assert {"engine.decode_step_ms.mixed", "kernel.swa_moe_decode_roofline",
+    assert {"engine.decode_step_ms.batch", "kernel.swa_moe_decode_roofline",
             "kernel.gqa_prefill_attention_roofline",
-            "cache.window_pages_held_share.mixed", "moe.load_imbalance.mixed",
+            "cache.window_pages_held_share.mixed", "moe.load_imbalance",
             "device.idle_share.batch", "engine.compiles_in_window.batch"} <= names
     for m in layer:
         spec = configs.load_json("layer_metrics", m["name"] + ".json")

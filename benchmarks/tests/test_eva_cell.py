@@ -28,11 +28,11 @@ CONFIG = "evabyte-6.5b.json"
 PEAKS = {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12}
 # this PR's per-layer metrics; the cell reports these AND whatever later PRs
 # let it join (PERF.md section 7: a test that pins the whole set breaks then)
-MINE = {"engine.decode_step_ms.bytes", "engine.prefill_share.bytes",
+MINE = {"engine.decode_step_ms.batch", "engine.prefill_share.batch",
         "kernel.eva_decode_roofline", "kernel.eva_decode_attention_roofline",
         "kernel.eva_prefill_roofline", "kernel.eva_prefill_attention_roofline",
         "eva.pair_rows_share.bytes", "cache.eva_rows_held_share.bytes",
-        "kernel.summary_share.bytes", "kernel.decode_kv_read_amplification.bytes"}
+        "kernel.summary_share.bytes", "kernel.decode_kv_read_amplification.batch"}
 
 
 def published():
@@ -216,9 +216,9 @@ def test_new_readers_on_a_hand_made_run():
     got = {k: v["value"] for k, v in
            bench_run.read_metrics(cell, "per_layer", run).items()}
     assert MINE <= set(got)
-    assert got["engine.decode_step_ms.bytes"] == pytest.approx(25.0)
-    assert got["engine.prefill_share.bytes"] == pytest.approx(40.0)
-    assert got["kernel.decode_kv_read_amplification.bytes"] == pytest.approx(
+    assert got["engine.decode_step_ms.batch"] == pytest.approx(25.0)
+    assert got["engine.prefill_share.batch"] == pytest.approx(40.0)
+    assert got["kernel.decode_kv_read_amplification.batch"] == pytest.approx(
         18_900 / 18_720)
     assert got["eva.pair_rows_share.bytes"] == pytest.approx(
         100 * 13_440 / (24_000 + 13_440))
@@ -275,7 +275,7 @@ def test_the_new_cell_is_found_by_name_as_files_alone():
     e2e = {m["name"] for m in configs.cell_metrics(cell, "end_to_end")}
     assert e2e == {"serve_tokens_per_s", "setup_s"}
     layer = configs.cell_metrics(cell, "per_layer")
-    assert {m["moves"] for m in layer} == {"serve_tokens_per_s"}
+    assert {m["moves"] for m in layer} >= {"serve_tokens_per_s"}
     names = {m["name"] for m in layer}
     assert names >= MINE | {
         "kernel.unnamed_share.batch", "engine.compiles_in_window.batch",

@@ -26,7 +26,8 @@ def test_every_metric_has_its_file_and_reader():
                 configs.BENCH_DIR, "readers", spec["reader"] + ".py"))
     for w in manifest["workloads"]:
         cell = configs.load_cell(w["name"])
-        assert cell["traffic_file"]["driver"] in ("serve", "train")
+        assert os.path.exists(os.path.join(
+            configs.BENCH_DIR, "drivers", cell["traffic_file"]["driver"] + ".py"))
         e2e = {m["name"] for m in configs.cell_metrics(cell, "end_to_end")}
         assert "setup_s" in e2e and len(e2e) >= 2
         layer = configs.cell_metrics(cell, "per_layer")

@@ -25,13 +25,13 @@ from benchmarks.roofline import kda_moe_prefill_batch as prefill_count
 CELL = "ling3flashvl_think_closed"
 CONFIG = "ling-3.0-flash-vl.json"
 PEAKS = {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12}
-MINE = {"engine.decode_step_ms.think", "engine.prefill_share.think",
+MINE = {"engine.decode_step_ms.batch", "engine.prefill_share.batch",
         "kernel.kda_moe_decode_roofline", "kernel.delta_state_update_roofline",
         "kernel.kda_moe_prefill_roofline", "kernel.delta_prefill_roofline",
         "kernel.delta_share.think", "kernel.delta_pool_step_share.think",
-        "moe.tokens_here_share.think", "kernel.grouped_matmul_share.think",
-        "moe.experts_touched_share.think", "moe.load_imbalance.think",
-        "kernel.decode_kv_read_amplification.think"}
+        "moe.tokens_here_share.think", "kernel.grouped_matmul_share",
+        "moe.experts_touched_share", "moe.load_imbalance",
+        "kernel.decode_kv_read_amplification.batch"}
 
 
 def published():
@@ -235,14 +235,14 @@ def test_new_readers_on_a_hand_made_run():
     run = _run()
     got = {k: v["value"] for k, v in
            bench_run.read_metrics(cell, "per_layer", run).items()}
-    assert got["moe.experts_touched_share.think"] == pytest.approx(100 * 500 / 640)
+    assert got["moe.experts_touched_share"] == pytest.approx(100 * 500 / 640)
     assert got["moe.tokens_here_share.think"] == pytest.approx(50.0)
     assert got["moe.expert_passes_per_touched"] == pytest.approx(1.0)
-    assert got["engine.decode_step_ms.think"] == pytest.approx(25.0)
-    assert got["engine.prefill_share.think"] == pytest.approx(40.0)
-    assert got["kernel.decode_kv_read_amplification.think"] == pytest.approx(
+    assert got["engine.decode_step_ms.batch"] == pytest.approx(25.0)
+    assert got["engine.prefill_share.batch"] == pytest.approx(40.0)
+    assert got["kernel.decode_kv_read_amplification.batch"] == pytest.approx(
         260_000 / 259_200)
-    assert got["kernel.grouped_matmul_share.think"] == pytest.approx(5.0)
+    assert got["kernel.grouped_matmul_share"] == pytest.approx(5.0)
     assert got["kernel.delta_pool_step_share.think"] == pytest.approx(15.0)
     assert got["kernel.delta_share.think"] == pytest.approx(100 * 0.8 / 2.0)
     # 40 steps in the trace (three 8-step and four 4-step blocks); 10 expert
@@ -272,7 +272,7 @@ def test_new_readers_on_a_hand_made_run():
     bare["admitted_lens"] = []
     bare["part_seconds"] = None
     left = bench_run.read_metrics(cell, "per_layer", bare)
-    assert not (set(left) & MINE) - {"engine.prefill_share.think"}
+    assert not (set(left) & MINE) - {"engine.prefill_share.batch"}
 
 
 def test_the_new_cell_is_found_by_name_as_files_alone():
@@ -297,7 +297,7 @@ def test_the_new_cell_is_found_by_name_as_files_alone():
     e2e = {m["name"] for m in configs.cell_metrics(cell, "end_to_end")}
     assert e2e == {"serve_tokens_per_s", "setup_s"}
     layer = configs.cell_metrics(cell, "per_layer")
-    assert {m["moves"] for m in layer} == {"serve_tokens_per_s"}
+    assert {m["moves"] for m in layer} >= {"serve_tokens_per_s"}
     names = {m["name"] for m in layer}
     # this PR's, and those the cell joined by name: a later PR may add more
     assert MINE | {
@@ -379,5 +379,5 @@ def test_the_cell_rehearses_on_the_cpu_at_tiny_sizes(tmp_path):
     assert ref["repeats"]
     rehearsed = line["rehearsal"]
     assert rehearsed["cpu-rehearsal.engine.compiles_in_window.batch"] == 0
-    assert 0 < rehearsed["cpu-rehearsal.moe.experts_touched_share.think"] <= 100
+    assert 0 < rehearsed["cpu-rehearsal.moe.experts_touched_share"] <= 100
     assert 0 < rehearsed["cpu-rehearsal.moe.tokens_here_share.think"] <= 100

@@ -99,6 +99,8 @@ def _run(touched=700.0, slots=896.0, top=35.0, rows=1344.0, steps=12):
                 "jit_mla_moe_decode_multi": {"durations": [0.2, 0.1],
                                              "seconds": 0.3}}},
             "trace_window": (0.0, 1.0),
+            # the steps annotated on the trace's two decode dispatches
+            "dispatched_steps": [8, 4],
             "recs_all": [{"first": 0.0, "last": 1.0, "tokens": 11,
                           "prompt_len": 1000}] * 30}
 
@@ -110,10 +112,10 @@ def test_new_readers_on_a_hand_made_run():
     run = _run()
     got = bench_run.read_metrics(cell, "per_layer", run)
     # 700 of 896 expert slots a step: 78.125 %, 100 of 128 a layer
-    assert got["moe.experts_touched_share.gen"]["value"] == pytest.approx(78.125)
+    assert got["moe.experts_touched_share"]["value"] == pytest.approx(78.125)
     # largest 5 a layer (35 / 7) over the mean 1.5 (1344 / 896)
-    assert got["moe.load_imbalance.gen"]["value"] == pytest.approx(5 / 1.5)
-    assert got["engine.decode_step_ms.gen"]["value"] == pytest.approx(25.0)
+    assert got["moe.load_imbalance"]["value"] == pytest.approx(5 / 1.5)
+    assert got["engine.decode_step_ms.batch"]["value"] == pytest.approx(25.0)
     live = 30 * (1001 + 10 * 0.5)
     least = count.least_seconds(run["cfg"], run["peaks"], 32, live, 100.0)
     assert got["kernel.mla_moe_decode_roofline"]["value"] == pytest.approx(
@@ -124,7 +126,7 @@ def test_new_readers_on_a_hand_made_run():
     for snap in bare["counters"].values():
         snap["stages"] = {}
     got = bench_run.read_metrics(cell, "per_layer", bare)
-    assert not {"moe.experts_touched_share.gen", "moe.load_imbalance.gen",
+    assert not {"moe.experts_touched_share", "moe.load_imbalance",
                 "kernel.mla_moe_decode_roofline"} & set(got)
 
 
@@ -143,10 +145,10 @@ def test_the_new_cell_is_found_by_name_as_files_alone():
     e2e = {m["name"] for m in configs.cell_metrics(cell, "end_to_end")}
     assert e2e == {"serve_tokens_per_s", "setup_s"}
     layer = configs.cell_metrics(cell, "per_layer")
-    assert {m["moves"] for m in layer} == {"serve_tokens_per_s"}
+    assert {m["moves"] for m in layer} >= {"serve_tokens_per_s"}
     names = {m["name"] for m in layer}
-    assert {"engine.decode_step_ms.gen", "kernel.mla_moe_decode_roofline",
-            "moe.experts_touched_share.gen", "moe.load_imbalance.gen",
+    assert {"engine.decode_step_ms.batch", "kernel.mla_moe_decode_roofline",
+            "moe.experts_touched_share", "moe.load_imbalance",
             "device.idle_share.batch", "engine.compiles_in_window.batch"} <= names
     for m in layer:
         spec = configs.load_json("layer_metrics", m["name"] + ".json")

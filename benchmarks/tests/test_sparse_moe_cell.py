@@ -205,15 +205,15 @@ def test_new_readers_on_a_hand_made_run():
     run = _run()
     got = {k: v["value"] for k, v in
            bench_run.read_metrics(cell, "per_layer", run).items()}
-    assert got["moe.experts_touched_share.longctx"] == pytest.approx(87.5)
+    assert got["moe.experts_touched_share"] == pytest.approx(87.5)
     assert got["moe.expert_passes_per_touched"] == pytest.approx(1.0)
-    assert got["engine.decode_step_ms.longctx"] == pytest.approx(25.0)
-    assert got["engine.prefill_share.longctx"] == pytest.approx(40.0)
+    assert got["engine.decode_step_ms.batch"] == pytest.approx(25.0)
+    assert got["engine.prefill_share.batch"] == pytest.approx(40.0)
     assert got["sparse.attended_share.longctx"] == pytest.approx(
         100 * 786_432 / 3_600_000)
     assert got["kernel.decode_kv_read_amplification.longctx"] == pytest.approx(
         3_606_000 / 786_432)
-    assert got["kernel.grouped_matmul_share.longctx"] == pytest.approx(10.0)
+    assert got["kernel.grouped_matmul_share"] == pytest.approx(10.0)
     # 40 steps in the trace (three 8-step and four 4-step blocks)
     least = count.least_seconds(run["cfg"], PEAKS, 32, 3_600_000.0, 786_432.0,
                                 14.0, 16.0)
@@ -241,8 +241,9 @@ def test_new_readers_on_a_hand_made_run():
     bare["dispatched_steps"] = []
     bare["admitted_lens"] = []
     left = bench_run.read_metrics(cell, "per_layer", bare)
-    assert not {m for m in left if "sparse" in m or "longctx" in m
-                and m != "engine.prefill_share.longctx"}
+    joined = {"engine.decode_step_ms.batch", "moe.experts_touched_share",
+              "moe.load_imbalance", "kernel.grouped_matmul_share"}
+    assert not {m for m in left if "sparse" in m or "longctx" in m or m in joined}
 
 
 def test_the_new_cell_is_found_by_name_as_files_alone():
@@ -268,16 +269,16 @@ def test_the_new_cell_is_found_by_name_as_files_alone():
     e2e = {m["name"] for m in configs.cell_metrics(cell, "end_to_end")}
     assert e2e == {"serve_tokens_per_s", "setup_s"}
     layer = configs.cell_metrics(cell, "per_layer")
-    assert {m["moves"] for m in layer} == {"serve_tokens_per_s"}
+    assert {m["moves"] for m in layer} >= {"serve_tokens_per_s"}
     names = {m["name"] for m in layer}
-    assert {"engine.decode_step_ms.longctx", "engine.prefill_share.longctx",
+    assert {"engine.decode_step_ms.batch", "engine.prefill_share.batch",
             "kernel.sparse_moe_decode_roofline",
             "kernel.sparse_decode_attention_roofline",
             "kernel.sparse_prefill_attention_roofline",
             "kernel.sparse_moe_prefill_roofline", "sparse.attended_share.longctx",
             "kernel.decode_kv_read_amplification.longctx",
-            "kernel.grouped_matmul_share.longctx",
-            "moe.experts_touched_share.longctx", "moe.load_imbalance.longctx",
+            "kernel.grouped_matmul_share",
+            "moe.experts_touched_share", "moe.load_imbalance",
             "moe.expert_passes_per_touched", "device.idle_share.batch",
             "engine.compiles_in_window.batch"} <= names
     for m in layer:

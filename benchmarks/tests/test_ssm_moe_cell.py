@@ -213,14 +213,14 @@ def test_new_readers_on_a_hand_made_run():
     run = _run()
     got = {k: v["value"] for k, v in
            bench_run.read_metrics(cell, "per_layer", run).items()}
-    assert got["moe.experts_touched_share.reason"] == pytest.approx(87.5)
+    assert got["moe.experts_touched_share"] == pytest.approx(87.5)
     assert got["moe.expert_passes_per_touched"] == pytest.approx(1.0)
-    assert got["moe.load_imbalance.reason"] == pytest.approx(16 * 120 / 768)
-    assert got["engine.decode_step_ms.reason"] == pytest.approx(25.0)
-    assert got["engine.prefill_share.reason"] == pytest.approx(40.0)
-    assert got["kernel.decode_kv_read_amplification.reason"] == pytest.approx(
+    assert got["moe.load_imbalance"] == pytest.approx(16 * 120 / 768)
+    assert got["engine.decode_step_ms.batch"] == pytest.approx(25.0)
+    assert got["engine.prefill_share.batch"] == pytest.approx(40.0)
+    assert got["kernel.decode_kv_read_amplification.batch"] == pytest.approx(
         257_024 / 256_000)
-    assert got["kernel.grouped_matmul_share.reason"] == pytest.approx(5.0)
+    assert got["kernel.grouped_matmul_share"] == pytest.approx(5.0)
     assert got["kernel.ssm_share.reason"] == pytest.approx(100 * 0.8 / 2.0)
     # 40 steps in the trace (three 8-step and four 4-step blocks); 8 expert
     # blocks: 14 of 16 held experts touched, 96 rows routed to them a block
@@ -246,8 +246,10 @@ def test_new_readers_on_a_hand_made_run():
     bare["admitted_lens"] = []
     bare["part_seconds"] = None
     left = bench_run.read_metrics(cell, "per_layer", bare)
-    assert not {m for m in left if "ssm" in m or "reason" in m
-                and m != "engine.prefill_share.reason"}
+    joined = {"engine.decode_step_ms.batch", "moe.experts_touched_share",
+              "moe.load_imbalance", "kernel.grouped_matmul_share",
+              "kernel.decode_kv_read_amplification.batch"}
+    assert not {m for m in left if "ssm" in m or "reason" in m or m in joined}
 
 
 def test_the_new_cell_is_found_by_name_as_files_alone():
@@ -272,14 +274,14 @@ def test_the_new_cell_is_found_by_name_as_files_alone():
     e2e = {m["name"] for m in configs.cell_metrics(cell, "end_to_end")}
     assert e2e == {"serve_tokens_per_s", "setup_s"}
     layer = configs.cell_metrics(cell, "per_layer")
-    assert {m["moves"] for m in layer} == {"serve_tokens_per_s"}
+    assert {m["moves"] for m in layer} >= {"serve_tokens_per_s"}
     names = {m["name"] for m in layer}
-    assert {"engine.decode_step_ms.reason", "engine.prefill_share.reason",
+    assert {"engine.decode_step_ms.batch", "engine.prefill_share.batch",
             "kernel.ssm_moe_decode_roofline", "kernel.ssm_state_update_roofline",
             "kernel.ssm_moe_prefill_roofline", "kernel.ssm_share.reason",
-            "kernel.grouped_matmul_share.reason",
-            "moe.experts_touched_share.reason", "moe.load_imbalance.reason",
-            "kernel.decode_kv_read_amplification.reason",
+            "kernel.grouped_matmul_share",
+            "moe.experts_touched_share", "moe.load_imbalance",
+            "kernel.decode_kv_read_amplification.batch",
             "moe.expert_passes_per_touched", "kernel.router_share",
             "kernel.unnamed_share.batch", "engine.compiles_in_window.batch",
             "engine.loop_blocked_share.batch",
@@ -287,7 +289,7 @@ def test_the_new_cell_is_found_by_name_as_files_alone():
             "engine.prefill_pad_waste.batch", "device.idle_share.batch",
             "device.idle_in_sync_emit.batch", "device.idle_in_admit.batch",
             "device.idle_in_dispatch.batch", "device.idle_unattributed.batch"
-            } == names
+            } <= names
     for m in layer:
         spec = configs.load_json("layer_metrics", m["name"] + ".json")
         assert set(spec) == {"name", "reader", "args"}
@@ -361,4 +363,4 @@ def test_the_cell_rehearses_on_the_cpu_at_tiny_sizes(tmp_path):
     assert ref["repeats"]
     rehearsed = line["rehearsal"]
     assert rehearsed["cpu-rehearsal.engine.compiles_in_window.batch"] == 0
-    assert 0 < rehearsed["cpu-rehearsal.moe.experts_touched_share.reason"] <= 100
+    assert 0 < rehearsed["cpu-rehearsal.moe.experts_touched_share"] <= 100
