@@ -66,31 +66,42 @@ def _default_local_impl(q) -> str:
 
 
 @tracing.part("attention")
-def masked_attention(q, k, v, mask):
+def masked_attention(q, k, v, mask, sink=None):
     """Masked grouped-query attention with the scores written out: the plain
     form (a no-cache forward, and the serving programs off the TPU). q:
-    [B, Tq, H, hd]; k, v: [B, Tk, KV, hd]; mask: [B, Tq, Tk]. Returns
-    [B, Tq, H * hd]."""
+    [B, Tq, H, hd]; k: [B, Tk, KV, hd]; v: [B, Tk, KV, hv] (``hv`` need not
+    be ``hd``); mask: [B, Tq, Tk]. ``sink`` [H]: one learned score a query
+    head that stands in the softmax as one more column and is dropped — it
+    takes mass and gives no value. Returns [B, Tq, H * hv]."""
     B, Tq, H, d = q.shape
     KV = k.shape[2]
     qg = q.reshape(B, Tq, KV, H // KV, d)
     s = jnp.einsum("bqkgd,bskd->bkgqs", qg, k).astype(jnp.float32)
     s = jnp.where(mask[:, None, None], s / jnp.sqrt(jnp.float32(d)),
                   jnp.float32(-1e30))
+    if sink is not None:
+        col = jnp.broadcast_to(sink.astype(jnp.float32).reshape(
+            1, KV, H // KV, 1, 1), (B, KV, H // KV, Tq, 1))
+        s = jnp.concatenate([s, col], axis=-1)
     w = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-    return jnp.einsum("bkgqs,bskd->bqkgd", w, v).reshape(B, Tq, H * d)
+    if sink is not None:
+        w = w[..., :-1]
+    return jnp.einsum("bkgqs,bskd->bqkgd", w, v).reshape(
+        B, Tq, H * v.shape[-1])
 
 
 @tracing.part("attention")
-def gathered_attention(q, kpool, vpool, table, pos, window: int | None = None):
+def gathered_attention(q, kpool, vpool, table, pos, window: int | None = None,
+                       sink=None):
     """The plain form of a decode step's walk over K and V pages: every entry
     of the slot's table gathered, each row masked by the position it holds.
     ``window`` None: entry e holds page e and a query attends back to
     position 0. A number: the table is a RING — entry e holds the latest page
     ``p <= pos // PS`` with ``p % entries == e`` — and a query attends the
-    ``window`` positions up to its own. q: [B, 1, H, hd]; kpool, vpool: [P,
-    PS, KV, hd] (one layer); table: [B, entries]; pos: [B]. Returns [B, 1,
-    H * hd]."""
+    ``window`` positions up to its own. q: [B, 1, H, hd]; kpool: [P, PS, KV,
+    hk] with ``hk >= hd`` (a row's lanes past the query's width are not
+    read); vpool: [P, PS, KV, hv] (one layer each); table: [B, entries];
+    pos: [B]; ``sink``: ``masked_attention``'s. Returns [B, 1, H * hv]."""
     B, entries = table.shape
     PS = kpool.shape[1]
     e = jnp.arange(entries)[None, :]
@@ -109,4 +120,5 @@ def gathered_attention(q, kpool, vpool, table, pos, window: int | None = None):
         return pool[table].reshape(B, entries * PS, *pool.shape[2:]
                                    ).astype(q.dtype)
 
-    return masked_attention(q, rows(kpool), rows(vpool), mask[:, None])
+    return masked_attention(q, rows(kpool)[..., :q.shape[-1]], rows(vpool),
+                            mask[:, None], sink)

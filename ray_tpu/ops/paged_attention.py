@@ -6,9 +6,9 @@ table and fetches only the pages that hold tokens. Nothing is sliced out of a
 pool, no ``[B, MAXP * PS, ...]`` window is gathered, and no position past a
 slot's length is contracted. Two callers, one walk:
 
-* ``paged_decode_attention`` — a K pool and a V pool ``[L, P, PS, KV, hd]``
-  (``llm/llama.py``; what ``_kv_read`` + ``_gqa_attn`` do, and stay the plain
-  reference for). A page of a layer is one contiguous ``[PS, KV, hd]`` run.
+* ``paged_decode_attention`` — a K pool ``[L, P, PS, KV, hk]`` and a V pool
+  ``[.., hv]`` (``llm/llama.py``: what ``_kv_read`` + ``_gqa_attn`` do; V may be
+  narrower and a K row wider than q, below). A page of a layer is one run.
 * ``paged_latent_attention`` — ONE pool ``[L, P, PS, W]`` whose rows are the
   keys as they lie and whose first ``v_width`` lanes are the values (MLA's
   latent cache ``[c | k_rope]``, ``llm/mla_moe.py``; the window
@@ -98,13 +98,13 @@ def run_lengths(page_tables):
     return e - jax.lax.cummax(jnp.where(breaks, e, 0), axis=1)
 
 
-def kv_block(pool, MAXP: int):
-    """(pages a block, pages a sub-run) of a walk over a K and a V pool like
-    ``pool`` under a table of ``MAXP`` entries: ``_BLOCK_BYTES`` of K and V
-    rows as they lie (a head under 128 lanes padded to them)."""
-    PS, KV = pool.shape[2], pool.shape[3]
-    token = 2 * KV * -(-pool.shape[-1] // 128) * 128 * pool.dtype.itemsize
-    n_pages = max(1, min(_BLOCK_BYTES // token // PS, MAXP))
+def kv_block(pool, MAXP: int, vpool=None):
+    """(pages a block, pages a sub-run) of a walk over a K pool like ``pool``
+    and a V pool like ``vpool`` (None: like ``pool``) under a table of ``MAXP``
+    entries: ``_BLOCK_BYTES`` of K and V rows as they lie (``_block_pages``)."""
+    PS, KV, v = pool.shape[2], pool.shape[3], pool if vpool is None else vpool
+    token = KV * (_lanes(pool) + _lanes(v)) * pool.dtype.itemsize
+    n_pages = _block_pages(_BLOCK_BYTES // token // PS, MAXP)
     return n_pages, _RUN_PAGES if n_pages % _RUN_PAGES == 0 else n_pages
 
 
@@ -185,9 +185,9 @@ def _kernel(layer_ref, tables_ref, lengths_ref, *refs,
         j + n) of block ``buf`` of its buffer: as the rows they are on both
         sides, or (the latent pool's, with their padding) one page under a
         page axis."""
-        pool, dst = pools[kv], bufs[kv]
+        pool, dst, wide = pools[kv], bufs[kv], pools[kv].shape[-1]  # V's own
         if sub:
-            src = pool.reshape(pool.shape[0], pool.shape[1] * page_rows, width
+            src = pool.reshape(pool.shape[0], pool.shape[1] * page_rows, wide
                                ).at[layer, pl.ds(page * page_rows, n * page_rows)]
             to = dst.at[buf, pl.ds(j * page_rows, n * page_rows)]
         else:
@@ -359,13 +359,13 @@ def paged_decode_attention(q, kpool, vpool, layer, page_tables, lengths, *,
                            interpret: bool | None = None):
     """Attention of one query row a slot over the slot's pages, in place.
 
-    q: [B, H, hd]; kpool, vpool: [L, P, PS, KV, hd] (handed over whole; they
-    stay in HBM); layer: int32 scalar, the pool layer to read; page_tables:
+    q: [B, H, hd]; kpool: [L, P, PS, KV, hk >= hd], vpool: [L, P, PS, KV, hv]
+    (whole, in HBM); layer: int32 scalar, the pool layer to read; page_tables:
     [B, MAXP] int32 pool rows in position order (entries past a slot's live
     pages are never fetched); lengths: [B] int32 tokens to attend, the
     query's own position included — 0 for an inactive slot, which fetches
     nothing and gets zeros. A length past MAXP * PS attends the whole table.
-    Returns [B, H, hd] in q's dtype. H // KV query heads share a KV head,
+    Returns [B, H, hv] in q's dtype. H // KV query heads share a KV head,
     read from the shapes (KV == H is plain multi-head attention). The
     kernel compiles for the TPU and is interpreted anywhere else.
 
@@ -405,15 +405,15 @@ def _kv_call(q, kpool, vpool, layer, page_tables, lengths, runs, interpret):
         runs = run_lengths(page_tables)
     return ((q, kpool, vpool, jnp.asarray(layer, jnp.int32), page_tables,
              lengths, runs),
-            {"block": kv_block(kpool, page_tables.shape[1]),
+            {"block": kv_block(kpool, page_tables.shape[1], vpool),
              "interpret": bool(interpret)})
 
 
 def _kv_walk(q, kpool, vpool, layer, page_tables, lengths, runs, block,
              interpret, **form):
-    hd = q.shape[-1]
+    hd, hv = q.shape[-1], vpool.shape[-1]  # the scores' width, the output's
     return _walk_pools(q, (kpool, vpool), layer, page_tables, lengths,
-                       v_width=hd, sm_scale=1.0 / math.sqrt(hd), block=block,
+                       v_width=hv, sm_scale=1.0 / math.sqrt(hd), block=block,
                        interpret=interpret, runs=runs, **form)
 
 
@@ -491,9 +491,9 @@ def _walk_pools(q, pools, layer, page_tables, lengths, *, v_width: int,
     blocks a slot — 8 MB at 32 slots of 16,384 positions of 4 KV heads.
     ``parts``: the output in float32 and, beside it, the walk's running
     maximum and sum ``[B, H, 128]`` (a lane tile each, every lane the same)."""
-    B, H, width = q.shape
+    B, H, _ = q.shape
     PS, page = pools[0].shape[2], pools[0].shape[2:]
-    MAXP = page_tables.shape[1]
+    MAXP, width = page_tables.shape[1], page[-1]  # the rows': at least q's
     # Rows whose width is not whole lane tiles (MLA's 576 = 4.5 x 128) lie in
     # HBM padded to whole ones, and Mosaic takes no slice of a tiled axis that
     # is not whole tiles, the whole axis included ("Slice shape along
@@ -502,8 +502,8 @@ def _walk_pools(q, pools, layer, page_tables, lengths, *, v_width: int,
     # of HBM — and masks it; q gets zeros there. The interpreter has no
     # padding to fetch and none to mask.
     lanes = width if interpret else -(-width // 128) * 128
-    if lanes > width:
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, lanes - width)))
+    if lanes > q.shape[-1]:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, lanes - q.shape[-1])))
     n_pages, sub = block
     kernel = functools.partial(_kernel, sm_scale=sm_scale, n_pages=n_pages,
                                sub=sub, ring=starts is not None,
@@ -516,11 +516,11 @@ def _walk_pools(q, pools, layer, page_tables, lengths, *, v_width: int,
         picks = (jnp.repeat(jnp.pad(selected.astype(jnp.float32), (
             (0, 0), (0, n_blocks * n_pages * PS - selected.shape[1]))),
             KV, axis=1),)
-    # two blocks a pool: of rows, or of pages of rows with their padding
-    buf = pltpu.VMEM((2, n_pages * math.prod(page[:-1]), lanes) if sub
-                     else (2, n_pages, *page[:-1], lanes), pools[0].dtype)
-    # at most, a pool: every slot's whole table
-    window = B * MAXP * math.prod(page) * pools[0].dtype.itemsize
+    # two blocks a pool: of rows, or of pages of rows with their padding (a
+    # pool narrower than the first, values under wider keys, as its own rows
+    # are: ``_buffers``); and, at most, over the pools: every slot's table
+    bufs, window = _buffers(pools, n_pages, lanes, bool(sub))
+    window *= B * MAXP
     out = jax.ShapeDtypeStruct((B, H, v_width), q.dtype)
     outs = out
     if parts:
@@ -541,7 +541,7 @@ def _walk_pools(q, pools, layer, page_tables, lengths, *, v_width: int,
             out_specs=pl.BlockSpec(out.shape, lambda i, *_: (0, 0, 0))
             if not parts else
             [pl.BlockSpec(o.shape, lambda i, *_: (0, 0, 0)) for o in outs],
-            scratch_shapes=[buf] * len(pools)
+            scratch_shapes=bufs
             + [pltpu.SemaphoreType.DMA((len(pools), 2))],
         ),
         compiler_params=pltpu.CompilerParams(
@@ -551,7 +551,7 @@ def _walk_pools(q, pools, layer, page_tables, lengths, *, v_width: int,
         cost_estimate=pl.CostEstimate(
             flops=2 * B * H * MAXP * PS * (width + v_width),
             transcendentals=B * H * MAXP * PS,
-            bytes_accessed=len(pools) * window),
+            bytes_accessed=window),
         interpret=interpret,
         name=None if not parts else
         "paged_attention_part" if starts is None else "paged_window_part",
@@ -566,7 +566,7 @@ def paged_attention_part(q, kpool, vpool, layer, page_tables, lengths, *,
     """``paged_decode_attention`` as ONE PART of a softmax that runs over
     more than one table: the same walk (plain, or a ring's with ``starts``;
     ``runs`` as there), given out with what joins it to another — ``(o [B,
-    H, hd] float32, the walk's own normalised output; m [B, H] float32, its
+    H, hv] float32, the walk's own normalised output; m [B, H] float32, its
     largest score; l [B, H] float32, the sum of ``exp(score - m)`` over its
     rows)``. A slot with no rows gives ``l = 0``. ``merge_attention_parts``
     joins them exactly."""
@@ -604,3 +604,50 @@ def merge_attention_parts(*parts):
     w = [p[2] * jnp.exp(p[1] - m) for p in parts]
     total = jnp.maximum(sum(w), 1e-30)
     return sum(p[0] * (wi / total)[..., None] for p, wi in zip(parts, w))
+
+
+# ------------------------------------------- keys and values of unequal width
+# What the K and V walk needs where a model's keys are wider than its values
+# (192 | 128 lanes, ``llm/sink_moe.py``), kept BELOW everything above so that
+# not one line of the kernel or of its callers moves: a Mosaic kernel carries
+# the file's line numbers in its payload, and a moved line re-keys every
+# family's programs in the compile cache (PERF.md section 6, PR 54).
+#
+# * Each pool has a VMEM buffer as wide as its OWN rows; the output is as wide
+#   as a value (``v_width`` from the V pool); the scores are over ``sqrt`` of
+#   the QUERY's width.
+# * A K row may be wider than the query — a 192-lane head kept in the 256
+#   lanes the device's layout would pad it to anyway (``benchmarks/
+#   sizing_sink_moe.py --layout``), the lanes past the head's zeros — and the
+#   query is padded with zeros to meet it: a page is then whole lane tiles,
+#   one plain run, and a walk's copy of it one descriptor.
+# * A learned SINK, one score a query head that takes mass and gives no value,
+#   needs nothing of the kernel: it is the part ``(0, sink, 1)`` beside the
+#   walk's own ``paged_attention_part``, joined by ``merge_attention_parts``.
+def _lanes(pool) -> int:
+    """The lanes a row of ``pool`` lies in: whole tiles of 128."""
+    return -(-pool.shape[-1] // 128) * 128
+
+
+def _block_pages(n_pages: int, MAXP: int) -> int:
+    """``n_pages`` by the bytes, in whole sub-runs where that is more than
+    one (rows of unequal width: 21 pages at 4 KV heads of 256 | 128 lanes are
+    16; every power of two stays what it is), at least 1, at most the table."""
+    if n_pages > _RUN_PAGES:
+        n_pages -= n_pages % _RUN_PAGES
+    return max(1, min(n_pages, MAXP))
+
+
+def _buffers(pools, n_pages: int, lanes: int, sub: bool):
+    """(a VMEM buffer of two blocks a pool, the bytes of ONE table entry's
+    page over the pools): ``lanes`` wide for the first pool and every pool
+    like it, as wide as its own rows for a narrower one."""
+    width = pools[0].shape[-1]
+    bufs = []
+    for pool in pools:
+        page = pool.shape[2:]
+        w = lanes if page[-1] == width else page[-1]
+        bufs.append(pltpu.VMEM(
+            (2, n_pages * math.prod(page[:-1]), w) if sub
+            else (2, n_pages, *page[:-1], w), pool.dtype))
+    return bufs, sum(math.prod(p.shape[2:]) * p.dtype.itemsize for p in pools)

@@ -9,7 +9,7 @@ in-tree"; vLLM handles EP internally).
   dispatch tensor, expert weights sharded on the ``ep`` mesh axis, and
   sharding propagation turning the einsums into all_to_all over ICI.
 * **Serving (``sigmoid_topk_route`` / ``softmax_topk_route`` /
-  ``mlp_top1_route`` + ``routed_experts`` + ``moe_layer``, from the six expert
+  ``mlp_top1_route`` + ``routed_experts`` + ``moe_layer``, from the seven expert
   families' modules under ``llm/``):** no capacity, so no token is ever
   dropped. The router is the DeepSeek-V3 family's (sigmoid scores, the k
   largest ``score + bias`` chosen — among all experts, or inside the few

@@ -1029,3 +1029,106 @@ def test_cca_moe_prefill_batch_compiles(one_chip, monkeypatch):
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 0.6e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13.2e9
+
+
+# ------------------------- window layers with a sink, keys wider than values
+def _sink_moe_args(one_chip):
+    """MiMo-V2-Flash at its published widths (64 query heads of 192 | 128
+    lanes on 4 KV heads in a full layer and 8 in a window layer, 16 held
+    experts of 2048), the dense layer, a window and a full expert layer, the
+    cell's pools cut to 2,049 and 601 pages."""
+    from ray_tpu.llm.sink_moe import make_pools
+    from ray_tpu.models.sink_moe import SinkMoeConfig, sink_moe_init
+
+    cfg = SinkMoeConfig(vocab_size=19072, n_layers=3,
+                        layer_window=(False, True, False),
+                        layer_moe=(False, True, True), max_seq_len=18432,
+                        experts_held=(0, 16), vocab_held=(0, 19072))
+    params = one_chip(jax.eval_shape(
+        lambda: sink_moe_init(jax.random.PRNGKey(0), cfg)))
+    cache = one_chip(jax.eval_shape(lambda: make_pools(
+        cfg, 16, {"full": 2049, "window": 601}, None)))
+    assert [c.shape[3:] for c in cache] == [(4, 256), (4, 128), (8, 256), (8, 128)]
+    return cfg, params, cache, one_chip(_shape((2,), jnp.uint32))
+
+
+def test_sink_moe_decode_multi_compiles(one_chip):
+    """Each kind of layer attends its own pools in place — the full layers
+    the plain walk over keys of 256 lanes beside values of 128, the window
+    layer the ring's walk given out as a part (its sink joins outside the
+    kernel) — nothing copies a pool, and inside the step loop nothing
+    re-lays out ``wq`` (64 heads x 192 lanes x 4096: 100 MB a layer)."""
+    from ray_tpu.llm.programs import MOE_STATS
+    from ray_tpu.llm.sink_moe import sink_moe_decode_multi
+
+    cfg, params, cache, key = _sink_moe_args(one_chip)
+    B = 64
+    i32 = one_chip(_shape((B,), jnp.int32))
+    tables = (one_chip(_shape((B, 1152), jnp.int32)),
+              one_chip(_shape((B, 9), jnp.int32)))
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        sink_moe_decode_multi.clear_cache()
+        try:
+            lowered = sink_moe_decode_multi.lower(
+                params, None, i32, i32, i32, tables, *cache,
+                one_chip(_shape((B,), jnp.bool_)),
+                one_chip(_shape((B,), jnp.float32)), key, cfg=cfg, n_steps=8)
+            compiled = lowered.compile()
+        finally:
+            sink_moe_decode_multi.clear_cache()
+    assert lowered.out_info[0].shape == (8, 64 + len(MOE_STATS))
+    text = compiled.as_text()
+    # three results (output, running maximum and sum): a tuple's type
+    assert len(re.findall(r"%paged_window_part\S* = \([^=]*custom-call\(", text)) == 1
+    assert len(re.findall(r"%_paged_decode_attention\S* = \S+ custom-call\(",
+                          text)) == 2
+    # 512 rows over 16 held experts: one grouped SwiGLU kernel an expert layer
+    assert len(re.findall(_SWIGLU, text)) == 2
+    assert not re.findall(_RAGGED_DOT, text)
+    # no table gathered out of a pool, and the temporaries are a step's
+    # activations and the weights turned once a program, not a copy of a pool
+    assert not re.findall(r"bf16\[64,(?:9|1152|144|18432),(?:16,)?[48],(?:128|256)\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+    extents = {heads * lanes * cfg.d_model for heads, lanes in (
+        (64, 192), (64, 128), (4, 192), (8, 192), (4, 128), (8, 128))}
+    computations = tracing.program_instructions(text)[1]
+    main = [rows for rows in computations
+            if any(opcode == "while" for _, _, opcode, *_ in rows)]
+    assert len(main) == 1  # the scan over the steps is the only loop
+    body = [row for rows in computations if rows is not main[0] for row in rows]
+    assert len(body) > 300
+    for _, key_, opcode, *_ in body:
+        if opcode in ("reshape", "copy", "transpose"):
+            dims = re.fullmatch(r"bf16\[([\d,]+)\]", key_.split("|")[1])
+            assert not (dims and math.prod(map(int, dims[1].split(","))) in extents), key_
+
+
+def test_sink_moe_prefill_batch_compiles(one_chip, monkeypatch):
+    """Two 4,096-token prompts as one program: blocked attention a layer —
+    the window layer's from its sink, heads of 192 padded to 256 lanes
+    against values of 128 — and no [T, T] scores."""
+    from ray_tpu.llm.sink_moe import sink_moe_prefill_batch
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    sink_moe_prefill_batch.clear_cache()
+    cfg, params, cache, key = _sink_moe_args(one_chip)
+    N, Tp = 2, 4096
+    pages = (one_chip(_shape((N, Tp // 16), jnp.int32)),
+             one_chip(_shape((N, 9), jnp.int32)))
+    try:
+        compiled = sink_moe_prefill_batch.lower(
+            params, None, one_chip(_shape((N,), jnp.int32)),
+            one_chip(_shape((N, Tp), jnp.int32)), pages, *cache,
+            one_chip(_shape((N,), jnp.int32)),
+            one_chip(_shape((N,), jnp.float32)), key, cfg=cfg).compile()
+    finally:
+        sink_moe_prefill_batch.clear_cache()
+    text = compiled.as_text()
+    assert len(re.findall(r"%gqa_sink_prefill_attention\S* = \S+ custom-call\(",
+                          text)) == 1
+    assert len(re.findall(r"%gqa_prefill_attention\S* = \S+ custom-call\(",
+                          text)) == 2
+    assert not re.findall(r"\[2,(?:64|8,8|4,16),4096,4096\]", text)  # no scores
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.0e9
+    assert len(re.findall(_RAGGED_DOT, text)) == 3 * 2

@@ -286,6 +286,23 @@ def test_a_block_follows_the_bytes_of_a_token():
     assert block(4, hd=64, dtype=jnp.float32, ps=32) == (8, 8)  # padded lanes
 
 
+@pytest.mark.parametrize("kv,hk,hv,want", [
+    (8, 128, 128, (16, 8)),    # equal rows: what it always was
+    (8, 128, None, (16, 8)),   # no V pool given: like the K pool
+    (8, 256, 128, (8, 8)),     # 8 heads of 256 | 128 lanes: 10 pages -> 8
+    (4, 256, 128, (16, 8)),    # 4 heads: 21 pages by the bytes -> 16
+    (4, 192, 128, (16, 8)),    # a 192-lane row lies in 256
+    (2, 256, 128, (40, 8)),    # 42 -> 40: whole sub-runs of 8
+])
+def test_a_block_follows_both_pools_rows(kv, hk, hv, want):
+    """Keys wider than values: the block is ``_BLOCK_BYTES`` of BOTH rows as
+    they lie, in whole sub-runs where that is more than one."""
+    k = jax.ShapeDtypeStruct((2, 9, 16, kv, hk), jnp.bfloat16)
+    v = None if hv is None else jax.ShapeDtypeStruct((2, 9, 16, kv, hv), jnp.bfloat16)
+    assert kv_block(k, 4096, v) == want
+    assert kv_block(k, 9, v) == ((9, 9) if want[0] > 9 else want)   # a ring of 9
+
+
 # ------------------------------------------------------------ the latent pool
 # rows [c | k_rope] of r + 64 numbers at a small r: the values are not the
 # whole row, the row is not whole lane tiles, and the scores' scale is the
