@@ -5,11 +5,11 @@ python/ray/llm/_internal/serve/deployments/llm/vllm/vllm_engine.py:95 —
 there Ray wires vLLM; here the engine is owned). Design maps the vLLM
 ideas onto XLA's static-shape world:
 
-* **Fixed decode slots.** One jitted decode step advances ALL ``max_batch``
-  slots every iteration; inactive slots are masked. Admission = writing a
-  new request's prompt KV into a free slot's pages *between* decode steps
-  — a request never waits for the running batch to drain (continuous
-  batching at decode-step granularity).
+* **Fixed decode slots, one admission path.** A decode block advances ALL
+  ``max_batch`` slots (inactive ones masked). A request's end by ``max_tokens``
+  is SCHEDULED when its last block is dispatched: its slot is swept, refilled
+  by a prefill queued BEHIND that block and merged into the carry on the device;
+  no block is waited for. Only an EOS or a cancel, found at emission, drains.
 * **Paged cache.** The family's pools (``self.cache``: K and V ``[layers,
   n_pages, page_size, kv, hd]`` for the Llama family, other pools for
   others) and, for each KIND of page the family declares, a page table a
@@ -61,7 +61,7 @@ from ray_tpu.devtools import chaos
 from ray_tpu.llm.llama import (  # noqa: F401
     paged_decode_multi, paged_prefill_batch)
 from ray_tpu.llm.programs import (
-    PageKind, UnsupportedByModel, serving_programs)
+    PageKind, UnsupportedByModel, merge_carry, serving_programs)
 from ray_tpu.utils import metrics, tracing
 
 
@@ -75,7 +75,7 @@ class _Request:
     out: asyncio.Queue = field(default_factory=asyncio.Queue)
     slot: int = -1
     emitted: int = 0
-    planned: int = 0  # tokens scheduled on-device (planned mode)
+    planned: int = 0  # tokens scheduled: dispatched, emitted or not yet
     cancelled: bool = False
     finished: bool = False  # completed normally (max_tokens or eos)
     # disaggregated admission (llm/disagg): (k_stack, v_stack, first_tok)
@@ -518,12 +518,12 @@ class ContinuousBatchingEngine:
                     max(0, -(-reached // (self.PS * kind.stride)) - kind.table))
         self.seq_lens[slot] = 0
 
-    def _free_slot(self, slot: int):
-        req = self.slot_req[slot]
-        self.slot_req[slot] = None
-        self._release_pages(slot, int(self.seq_lens[slot]))
-        if req is not None:
-            self._finish_stream(req)
+    def _sync_oldest(self, pending: list) -> None:
+        """Wait for the oldest entry in flight, wave or block, and emit it."""
+        kind, *entry = pending.pop(0)
+        if kind == "prefill":
+            return self._emit_first(*entry)
+        self._emit_block(tuple(entry))
 
     def _finish_stream(self, req: _Request) -> None:
         """Unregister a request and close its token stream: live -> the
@@ -658,25 +658,35 @@ class ContinuousBatchingEngine:
         return tuple(jnp.asarray(t.copy()) for t in tables)
 
     async def _admit_wave(self) -> bool:
-        """Admit every waiting request that fits, prefilling each pad
-        bucket's group in ONE device dispatch (one host sync per group,
-        not per request). Returns True if anything was admitted."""
+        """Admit every waiting request that fits and WAIT for each wave's
+        first tokens (the speculative loop's admission, behind its drain).
+        Returns True if anything was admitted."""
         groups = await self._admit_dispatch()
         for reqs, first in groups:
-            with tracing.phase("engine.prefill_sync", prompts=len(reqs)):
-                first = np.asarray(first)  # ONE sync per group
-            for j, req in enumerate(reqs):
+            self._emit_first(reqs, first)
+        return bool(groups)
+
+    def _emit_first(self, reqs: list, first) -> None:
+        """Host-side emission of one synced prefill wave: one read for the
+        wave (``engine.prefill_sync``), each prompt's first token to its
+        stream and into the host's copy of the carry."""
+        with tracing.phase("engine.prefill_sync", prompts=len(reqs)):
+            first = np.asarray(first)  # ONE sync per group
+        for j, req in enumerate(reqs):
+            if req.slot >= 0:  # else its end was scheduled: the slot is gone
                 self.next_tok[req.slot] = int(first[j])
                 if self.spec_enable:
                     self.hist[req.slot, len(req.prompt)] = int(first[j])
-                if not req.cancelled:  # cancelled while its bucket compiled
-                    self._emit(req, int(first[j]))
-        return bool(groups)
+            if not req.cancelled:  # cancelled while its bucket compiled
+                self._emit(req, int(first[j]))
 
-    async def _admit_dispatch(self) -> list[tuple[list[_Request], object]]:
+    async def _admit_dispatch(self, behind: bool = False
+                              ) -> list[tuple[list[_Request], object]]:
         """Reserve slots and DISPATCH batched prefills for every waiting
         request that fits; no host sync — returns [(requests,
-        first-token device array)] per pad-bucket group."""
+        first-token device array)] per pad-bucket group. ``behind``: a
+        decode block is in flight and nothing has waited for it (what
+        ``rt_llm_admit_waves_undrained_total`` counts)."""
         groups: dict[int, list[_Request]] = {}
         adopted: list[_Request] = []
         with tracing.phase("engine.admit", pad=0, wave=0) as ph:
@@ -752,6 +762,7 @@ class ContinuousBatchingEngine:
             for req in reqs:
                 req.t_admit = now
             metrics.llm_prefill_waves_total.inc()
+            metrics.llm_admit_waves_undrained_total.inc(int(behind))
             metrics.llm_prefill_prompts_total.inc(len(reqs))
             metrics.llm_prefill_true_tokens_total.inc(true_tokens)
             metrics.llm_prefill_padded_tokens_total.inc(nb * Tp_pad)
@@ -777,7 +788,7 @@ class ContinuousBatchingEngine:
                         "exec")
             metrics.llm_decode_tokens_total.inc(req.emitted - 1)
             if req.slot < 0:
-                # planned mode already retired the slot; close the stream
+                # its end was scheduled and the slot handed on: close the stream
                 self._finish_stream(req)
 
     @staticmethod
@@ -827,47 +838,45 @@ class ContinuousBatchingEngine:
             return 16
         return 32
 
-    def _pick_block(self, planned: bool = False) -> int:
+    def _pick_block(self) -> int:
         """Fused-steps bucket for this dispatch: the smallest bucket
         covering every active request's ramp, each capped by its exact
-        remaining count (no over-decode on final blocks). A request about
-        to finish therefore caps the block so it completes — and frees
-        its slot for waiting admissions — without riding out a long
-        batch's block (continuous-batching latency semantics).
+        remaining count, so that a request's LAST block is the bucket that
+        covers its last token (the steps of that bucket past the token write
+        the junk page) and its slot can be handed on behind that block
+        instead of riding out a long batch's block (continuous-batching
+        latency semantics). Counted in the tokens DISPATCHED for a request
+        (``planned``: the loops run ahead of emission), so a request whose
+        tokens are all scheduled asks for nothing more; where that is every
+        slot (a lone request nobody waits behind) the loop runs on a step at
+        a time until the last token is on the host.
 
         At high occupancy the ramp is skipped: a full batch is the
         throughput regime, where small early blocks would multiply
         dispatch round trips for no latency benefit (newcomers can't be
-        admitted into a full batch anyway).
-
-        ``planned`` counts dispatch-scheduled tokens instead of emitted
-        ones (the planned loop runs ahead of emission)."""
-        live = [r for r in self.slot_req
-                if r is not None and not r.cancelled]
+        admitted into a full batch anyway)."""
+        live = [r for r in self.slot_req if r is not None
+                and not r.cancelled and r.planned < r.max_tokens]
         if not live:
             return 1
-
-        def done_count(r):
-            return r.planned if planned else r.emitted
-
         if 2 * len(live) >= self.B:
-            want = min(r.max_tokens - done_count(r) for r in live)
+            want = min(r.max_tokens - r.planned for r in live)
         else:
-            want = min(min(self._ramp(done_count(r)),
-                           r.max_tokens - done_count(r)) for r in live)
-        want = max(1, want)
+            want = min(min(self._ramp(r.planned), r.max_tokens - r.planned)
+                       for r in live)
         for b in self.block_buckets:
             if want <= b:
                 return b
         return self.block_buckets[-1]
 
-    async def _dispatch_block(self, carry, planned: bool = False):
+    async def _dispatch_block(self, carry):
         """Pick the block size and dispatch one fused decode block from
         the device-resident ``carry`` (token, length), or from host state
-        when it is None. No host sync. Returns (K, tokens [K, B] on the
-        device, the next carry)."""
+        when it is None, and count its steps as scheduled for every slot's
+        request. No host sync. Returns (K, tokens [K, B] on the device, the
+        next carry)."""
         with tracing.phase("engine.decode_dispatch") as ph:
-            K = self._pick_block(planned)
+            K = self._pick_block()
             active = np.array([r is not None for r in self.slot_req])
             ph.set(steps=K, live=int(active.sum()), **self._last_stats,
                    **self._last_kv)
@@ -878,10 +887,7 @@ class ContinuousBatchingEngine:
             # retire/emission mutation while the async dispatch is still
             # in flight would corrupt the program's view of them (race
             # observed as garbage decode tokens under load).
-            if carry is None:
-                carry = (jnp.asarray(self.next_tok.copy()),
-                         jnp.asarray(self.seq_lens.copy()))
-            tok_d, lens_d = carry
+            tok_d, lens_d = carry or self._host_carry()
             toks, tok_d, lens_d, *cache = await self._call(
                 ph, self.programs.decode_multi, self.params, self.loras,
                 jnp.asarray(self.aids.copy()), tok_d, lens_d,
@@ -889,6 +895,9 @@ class ContinuousBatchingEngine:
                 jnp.asarray(active), jnp.asarray(self.temps.copy()), sub,
                 self.cfg, K)
             self.cache = tuple(cache)
+        for r in self.slot_req:
+            if r is not None:
+                r.planned = min(r.max_tokens, r.planned + K)
         return K, toks, (tok_d, lens_d)
 
     def _emit_block(self, entry) -> None:
@@ -912,10 +921,14 @@ class ContinuousBatchingEngine:
                 if req is None:
                     continue
                 if self.slot_req[i] is req:
-                    # planned mode may have retired + re-admitted this
-                    # slot while the block was in flight; host per-slot
-                    # state then belongs to the newcomer
+                    # a scheduled end may have handed this slot on while
+                    # the block was in flight; host per-slot state then
+                    # belongs to the newcomer
                     self.seq_lens[i] += K
+                elif req.cancelled and req.req_id in self._reqs:
+                    # cancelled by its user after its slot was handed on:
+                    # no sweep will meet it, so its stream is closed here
+                    self._finish_stream(req)
                 for k in range(K):
                     if req.cancelled:
                         break  # finished/cancelled mid-block: discard rest
@@ -933,7 +946,7 @@ class ContinuousBatchingEngine:
         layer of the kind reaches back, counted in the kind's ROWS
         (``_rows_within``); over kinds, the mean over layers. A
         kind that holds no positions (a slot's state: ``PageKind.positions``)
-        is no part of either. A slot the planned loop
+        is no part of either. A slot that a scheduled end
         has already handed on has its start from the request itself."""
         starts = np.array(
             [self.seq_lens[i] if self.slot_req[i] is req
@@ -976,17 +989,64 @@ class ContinuousBatchingEngine:
             metrics.LLM_MODEL_STATS[name].inc(int(total))
             self._last_stats[name] = round(float(total) / rows.shape[0], 2)
 
-    def _sweep(self) -> None:
+    def _sweep(self, scheduled: bool = False) -> None:
         """Give back the slot and pages of every finished or cancelled
-        request (only with no block in flight: its writes would land on
-        pages handed to someone else)."""
+        request and, where ``scheduled``, of every request whose tokens are
+        all dispatched: its end is known, so its slot is handed on behind
+        the block that ends it. Blocks in flight are no obstacle: whatever
+        they still write to these pages (a finished slot's junk steps
+        included) is ordered on the device's stream BEFORE the prefill that
+        writes them again, and a block dispatched later reads the tables as
+        they are then (see paged_decode_multi; a state row likewise: a
+        reused row is overwritten, never read)."""
         with tracing.phase("engine.free") as ph:
             freed = 0
             for i, req in enumerate(self.slot_req):
-                if req is not None and req.cancelled:
-                    self._free_slot(i)
-                    freed += 1
+                over = req is not None and (req.cancelled or (
+                    scheduled and req.planned >= req.max_tokens))
+                if not over:
+                    continue
+                req.slot = -1  # emission closes the stream at its last token
+                self.slot_req[i] = None
+                # the positions it got to: what the host has seen of it, or
+                # what is scheduled where that runs ahead
+                self._release_pages(i, max(
+                    int(self.seq_lens[i]), len(req.prompt) + req.planned - 1))
+                freed += 1
+                if req.cancelled:
+                    # user-cancelled, or finished while it still held the
+                    # slot: no later emission closes this stream
+                    self._finish_stream(req)
             ph.set(freed=freed)
+
+    def _host_carry(self):
+        """The decode carry (token, length) from the host's copy: what a
+        drain leaves current (copies: see ``_dispatch_block``)."""
+        return (jnp.asarray(self.next_tok.copy()),
+                jnp.asarray(self.seq_lens.copy()))
+
+    async def _admit_behind(self, carry, pending: list):
+        """Admission without a drain: reserve slots for the waiting requests
+        that fit, dispatch their prefills BEHIND whatever is in flight and
+        merge their first tokens and lengths into the carry on the device
+        (``merge_carry``: one program a wave bucket, which every admission
+        takes, the first into an idle engine too — so none is first built
+        under load). Nothing is waited for; the first tokens are emitted
+        when ``pending`` reaches their entry. Returns the carry."""
+        behind = any(kind == "block" for kind, *_ in pending)
+        for reqs, first in await self._admit_dispatch(behind):
+            # a dummy row's slot lies out of range: the merge drops it
+            slots = np.full(first.shape[0], self.B, np.int32)
+            lens = np.zeros(first.shape[0], np.int32)
+            for j, req in enumerate(reqs):
+                slots[j], lens[j], req.planned = req.slot, len(req.prompt), 1
+            with tracing.phase("engine.admit", pad=0, wave=len(slots),
+                               merged=len(reqs)) as ph:
+                carry = await self._call(
+                    ph, merge_carry, *(carry or self._host_carry()),
+                    jnp.asarray(first), jnp.asarray(slots), jnp.asarray(lens))
+            pending.append(("prefill", reqs, first))
+        return carry
 
     async def _yield(self) -> None:
         """One turn of the event loop for everything else the replica
@@ -1027,60 +1087,14 @@ class ContinuousBatchingEngine:
         pending: list = []  # dispatch-ordered: ("prefill",...)|("block",...)
         carry = None
 
-        def sync_oldest():
-            kind, *rest = pending.pop(0)
-            if kind == "prefill":
-                reqs, first = rest
-                with tracing.phase("engine.prefill_sync", prompts=len(reqs)):
-                    first = np.asarray(first)
-                for j, req in enumerate(reqs):
-                    if not req.cancelled:  # user-cancelled: stream closed
-                        self._emit(req, int(first[j]))
-            else:
-                self._emit_block(rest)
-
         while self._running:
-            # retire slots whose scheduled tokens are all dispatched; their
-            # in-flight junk writes land on pages ordered BEFORE any new
-            # prefill, so immediate reuse is safe (see paged_decode_multi)
-            with tracing.phase("engine.free") as ph:
-                freed = 0
-                for i, req in enumerate(self.slot_req):
-                    if req is not None and (req.planned >= req.max_tokens
-                                            or req.cancelled):
-                        req.slot = -1  # emission closes the stream at finish
-                        self.slot_req[i] = None
-                        self._release_pages(
-                            i, len(req.prompt) + max(req.planned, 1) - 1)
-                        freed += 1
-                        if req.cancelled:
-                            # user-cancelled, or finished while it still held
-                            # the slot: no later emission closes this stream
-                            self._finish_stream(req)
-                ph.set(freed=freed)
+            # retire slots whose scheduled tokens are all dispatched
+            self._sweep(scheduled=True)
             if self.waiting and any(r is None for r in self.slot_req):
-                groups = await self._admit_dispatch()
-                if groups:
-                    if carry is None:
-                        carry = (jnp.asarray(self.next_tok.copy()),
-                                 jnp.asarray(self.seq_lens.copy()))
-                    tok_d, lens_d = carry
-                    for reqs, first in groups:
-                        slots = jnp.asarray([r.slot for r in reqs],
-                                            jnp.int32)
-                        lens = jnp.asarray(
-                            [len(r.prompt) for r in reqs], jnp.int32)
-                        # device-side carry merge: no host sync
-                        tok_d = tok_d.at[slots].set(first[:len(reqs)])
-                        lens_d = lens_d.at[slots].set(lens)
-                        for r in reqs:
-                            r.planned = 1
-                        pending.append(("prefill", reqs, first))
-                    carry = (tok_d, lens_d)
-            live = [r for r in self.slot_req if r is not None]
-            if not live:
+                carry = await self._admit_behind(carry, pending)
+            if all(r is None for r in self.slot_req):
                 while pending:
-                    sync_oldest()
+                    self._sync_oldest(pending)
                     # yield between blocks: consumers must observe tokens
                     # in emission order, not one burst after the drain
                     await self._yield()
@@ -1095,48 +1109,51 @@ class ContinuousBatchingEngine:
             # consumers see tokens before the next dispatch (whose first
             # use may compile) occupies the loop thread.
             while len(pending) >= 2:
-                sync_oldest()
+                self._sync_oldest(pending)
                 await self._yield()
-            K, toks, carry = await self._dispatch_block(carry, planned=True)
-            for r in live:
-                r.planned = min(r.max_tokens, r.planned + K)
+            K, toks, carry = await self._dispatch_block(carry)
             pending.append(("block", K, toks, list(self.slot_req)))
             await self._yield()
 
     async def _loop_reactive(self):
-        # pipeline of dispatched-but-unsynced decode blocks. Depth 2:
-        # block N+1 is enqueued before block N's tokens come back, so the
-        # host round trip rides under device compute. The (tok, pos)
-        # carry chains ON DEVICE between pipelined blocks; it is rebuilt
-        # from host state only after the pipeline drains at admission
-        # points (a new slot changes page_tables/active for the next
-        # dispatch).
-        # The loop holds the event loop's thread nearly all the time:
-        # every _emit_block sleeps in np.asarray (phase engine.block_sync)
-        # until the device has finished a block of up to 64 steps (about
-        # 0.6 s at 9 ms a step), and only the _yield() at the bottom and
-        # the one after a wave let the replica's other coroutines run.
-        pending: list = []
+        """The driver where a reply may end early (an ``eos_id``): what every
+        deployment runs. Entries in flight, dispatch-ordered as in the
+        planned loop: block N+1 is enqueued before block N's tokens come
+        back, so the host's round trip rides under device compute, and the
+        (tok, pos) carry chains ON THE DEVICE from block to block.
+
+        One admission path. What is known at dispatch is acted on at
+        dispatch: a request that reaches ``max_tokens`` inside a block
+        already dispatched has its end SCHEDULED, so when someone waits its
+        slot is swept and refilled behind that block (``_admit_behind``: the
+        prefill queued after it, the newcomers merged into the carry on the
+        device) and no block is waited for. What only emission can find — an
+        EOS, a user's cancel, the last token of a request nobody waited
+        behind — drains what is in flight, sweeps and rebuilds the carry
+        from the host's copy, which a drain leaves current. With nobody
+        waiting a scheduled slot keeps its place and the loop runs on, a
+        step a block, until its last token is on the host.
+
+        The loop holds the event loop's thread nearly all the time: every
+        _emit_block sleeps in np.asarray (phase engine.block_sync) until the
+        device has finished a block of up to 64 steps (about 0.6 s at 9 ms a
+        step), and only the _yield() at the bottom lets the replica's other
+        coroutines run."""
+        pending: list = []  # dispatch-ordered: ("prefill",...)|("block",...)
         carry = None  # (tok_dev, lens_dev) device-resident between blocks
 
         def drain():
             while pending:
-                self._emit_block(pending.pop(0))
+                self._sync_oldest(pending)
 
         while self._running:
-            if not pending:  # free only with no block in flight
-                self._sweep()
+            if self.waiting or not pending:
+                self._sweep(scheduled=bool(self.waiting))
             if self.waiting and any(r is None for r in self.slot_req):
-                drain()  # admission changes device-visible state
-                self._sweep()
-                if await self._admit_wave():
-                    carry = None
-                    # the wave just emitted each admitted request's
-                    # prefill token: let consumers flush it (TTFC) before
-                    # the next decode dispatch occupies the loop thread
-                    await self._yield()
+                carry = await self._admit_behind(carry, pending)
             if all(r is None for r in self.slot_req):
                 drain()
+                carry = None
                 # idle, OR the head-of-queue request can't be admitted yet
                 # (pages still held elsewhere): either way we must yield —
                 # a bare continue would spin the loop without ever
@@ -1144,11 +1161,12 @@ class ContinuousBatchingEngine:
                 await self._idle()
                 continue
             K, toks, carry = await self._dispatch_block(carry)
-            pending.append((K, toks, list(self.slot_req)))
-            if len(pending) >= 2:
-                self._emit_block(pending.pop(0))
-            # a finished request must stop the pipeline at the next
-            # admission point rather than over-decoding forever
+            pending.append(("block", K, toks, list(self.slot_req)))
+            # one block stays in flight while the host emits what is older
+            while len(pending) >= 2:
+                self._sync_oldest(pending)
+            # a request that emission found finished or cancelled in its
+            # slot must stop the pipeline rather than over-decode for ever
             if any(r is not None and r.cancelled for r in self.slot_req):
                 drain()
                 carry = None

@@ -220,3 +220,17 @@ def _sample_tail(logits, temps, key):
         return jnp.where(temps > 0, s, greedy)
 
     return jax.lax.cond(jnp.any(temps > 0), sampled, lambda: greedy)
+
+
+@jax.jit
+def merge_carry(tokens, seq_lens, first, slots, lens):
+    """A prefill wave's first tokens and prompt lengths into the decode
+    carry, ON THE DEVICE: what the engine's loops do at every admission, so
+    that no block in flight is waited for. tokens, seq_lens: [B], the carry
+    between blocks; first: [N], the prefill program's own output, dummy rows
+    included; slots, lens: [N], each prompt's slot and length, a dummy row's
+    slot out of range (dropped). One program a wave bucket ``N``. Down here
+    so that no line above moves (``decode_frame`` lies in the call stack of
+    every decode kernel, and so in its compile-cache key)."""
+    return (tokens.at[slots].set(first, mode="drop"),
+            seq_lens.at[slots].set(lens, mode="drop"))

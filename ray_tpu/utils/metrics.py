@@ -228,6 +228,14 @@ llm_prefill_waves_total = Counter(
     "rt_llm_prefill_waves_total", "batched prefill dispatches")
 llm_prefill_prompts_total = Counter(
     "rt_llm_prefill_prompts_total", "prompts those dispatches prefilled")
+# How often admission kept the pipeline full: of the prefill waves above,
+# those dispatched while a decode block was in flight that nothing waited for
+# (the engine's loops retire a slot whose end is scheduled and refill it behind
+# the block that ends it). The first wave into an idle engine has no block to
+# be behind.
+llm_admit_waves_undrained_total = Counter(
+    "rt_llm_admit_waves_undrained_total",
+    "prefill waves dispatched behind a decode block in flight, unwaited")
 llm_prefill_true_tokens_total = Counter(
     "rt_llm_prefill_true_tokens_total", "prompt tokens prefilled")
 llm_prefill_padded_tokens_total = Counter(
@@ -367,6 +375,7 @@ STAGE_FAMILIES = (
     llm_prefill_wait_seconds, llm_decode_seconds, llm_decode_tokens_total,
     llm_prefill_waves_total, llm_prefill_prompts_total,
     llm_prefill_true_tokens_total, llm_prefill_padded_tokens_total,
+    llm_admit_waves_undrained_total,
     llm_decode_kv_tokens_live_total, llm_decode_kv_tokens_read_total,
     llm_pages_held, llm_pages_drawn_total, llm_window_pages_released_total,
     *LLM_MODEL_STATS.values(), serve_lane_seconds, bringup_seconds)

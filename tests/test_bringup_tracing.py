@@ -183,7 +183,8 @@ def test_the_engine_says_where_each_program_came_from():
     builds = eng.program_builds()
     assert len(builds) == len(eng._compiled) >= 2
     for b in builds:
-        assert b["program"] in ("paged_prefill_batch", "paged_decode_multi")
+        assert b["program"] in ("paged_prefill_batch", "paged_decode_multi",
+                                "merge_carry")
         assert b["shape"].startswith("(") and b["t"] > 0
         assert b["source"] in ("cache", "compiled")
         assert b["trace_s"] > 0 and b["lower_s"] > 0

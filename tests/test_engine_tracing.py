@@ -89,6 +89,125 @@ def test_stage_counters_match_the_admissions(tiny, eos_id):
     assert 0 < sum(d["sum"] for d in phases.values()) <= wall
 
 
+def _logged_engine(tiny, **kw):
+    """An engine whose loop logs, in order, what it dispatches and what it
+    waits for: ("wave", prompts) a prefill wave dispatched, ("block", steps)
+    a decode block dispatched, ("sync", steps) a decode block read back."""
+    from ray_tpu.llm.engine import ContinuousBatchingEngine
+
+    cfg, params = tiny
+    eng = ContinuousBatchingEngine(params, cfg, **{**ENGINE_KW, **kw})
+    log = []
+    admit, dispatch, emit = (eng._admit_dispatch, eng._dispatch_block,
+                             eng._emit_block)
+
+    async def admit_logged(behind=False):
+        groups = await admit(behind)
+        log.extend(("wave", len(reqs)) for reqs, _ in groups)
+        return groups
+
+    async def dispatch_logged(carry):
+        out = await dispatch(carry)
+        log.append(("block", out[0]))
+        return out
+
+    def emit_logged(entry):
+        log.append(("sync", entry[0]))
+        emit(entry)
+
+    eng._admit_dispatch, eng._dispatch_block, eng._emit_block = (
+        admit_logged, dispatch_logged, emit_logged)
+    return eng, log
+
+
+def test_the_loop_waits_for_no_block_in_order_to_admit(tiny):
+    """Ten requests on four slots under an ``eos_id``: every wave after the
+    first admission's is dispatched while the block that ends its slot's
+    last holder is still in flight — that block is read back only AFTER the
+    wave's prefill is queued behind it — and counts as undrained."""
+    eng, log = _logged_engine(tiny, eos_id=1000, block_buckets=(4, 8))
+    prompts = [[1 + i, 2, 3, 4 + i] for i in range(10)]
+    lens = [9, 13, 6, 17, 8, 12, 5, 10, 7, 11]
+
+    async def go():
+        before = metrics.stage_totals()
+        await eng.start()
+        outs = await asyncio.gather(*(
+            eng.generate(p, max_tokens=m) for p, m in zip(prompts, lens)))
+        await eng.stop()
+        return before, metrics.stage_totals(), outs
+
+    before, after, outs = asyncio.run(go())
+    assert [len(o) for o in outs] == lens
+    waves = [i for i, e in enumerate(log) if e[0] == "wave"]
+    assert len(waves) >= 4 and log[0] == ("wave", 4), log
+    for i in waves[1:]:
+        # blocks dispatched before this wave, less those read back: the
+        # newest — the one that scheduled the end of the slot's holder — is
+        # in flight, and is waited for only after the wave
+        flying = (sum(e[0] == "block" for e in log[:i])
+                  - sum(e[0] == "sync" for e in log[:i]))
+        assert flying >= 1, (i, log)
+    n = _delta(after, before, "rt_llm_prefill_waves_total")["sum"]
+    assert n == len(waves)
+    assert _delta(after, before,
+                  "rt_llm_admit_waves_undrained_total")["sum"] == n - 1
+
+
+def test_the_merge_program_is_built_by_the_warm_waves(tiny):
+    """``merge_carry`` is one program a wave bucket, whatever the wave's
+    prompts or pad, and EVERY admission takes it, the first into an idle
+    engine too: after waves of 1, 2, 4 and 8 one-token requests (the
+    benchmark's warm-up) a loaded engine that hands slots on behind blocks
+    in flight builds none."""
+    from ray_tpu.llm.programs import merge_carry
+
+    cfg, params = tiny
+    eng, log = _logged_engine(tiny, eos_id=1000, max_batch=8,
+                              block_buckets=(4, 8))
+
+    def merges():
+        return sorted(key[1:] for key in eng._compiled if key[0] is merge_carry)
+
+    async def go():
+        await eng.start()
+        for pad in (8, 16):
+            for wave in (1, 2, 4, 8):
+                await asyncio.gather(*(
+                    eng.generate(list(range(1, pad - 1)), max_tokens=1)
+                    for _ in range(wave)))
+        warm = merges()
+        before = metrics.stage_totals()
+        await asyncio.gather(*(
+            eng.generate([1 + i, 2, 3], max_tokens=5 + i % 7)
+            for i in range(20)))
+        await eng.stop()
+        return warm, merges(), before, metrics.stage_totals()
+
+    warm, loaded, before, after = asyncio.run(go())
+    # keyed by the wave bucket alone: first, slots, lens — [N] each
+    assert warm == [((n,),) * 3 for n in (1, 2, 4, 8)]
+    assert loaded == warm
+    assert _delta(after, before, "rt_llm_admit_waves_undrained_total")["sum"] >= 3
+    assert {e[1] for e in log if e[0] == "wave"} <= {1, 2, 3, 4, 5, 6, 7, 8}
+
+
+def test_the_compile_thunk_keeps_its_line_and_columns():
+    """``llm/engine.py:588``, columns 34-49 — ``fn.lower(*args)`` in
+    ``_call``'s lambda — is the outermost user frame of every serve program
+    with a Mosaic call, and so in its compile-cache key (PERF.md section 7):
+    an edit that moves it makes every cell's set-up cold on the change's
+    side of a check. A change detector, kept until a PR that re-keys the
+    programs anyway moves the thunk to a module of its own (ROADMAP D12)."""
+    import inspect
+
+    from ray_tpu.llm import engine
+
+    line = inspect.getsource(engine).splitlines()[587]
+    assert line.strip() == "None, lambda: fn.lower(*args).compile())", line
+    assert line.index("fn.lower(*args)") == 34
+
+
 def _engine_events(trace_dir: str) -> dict:
     """{line name: [(start_ns, end_ns, name, stats)]} of the ``engine.*``
     events on the trace's ``/host:CPU`` plane."""

@@ -236,10 +236,12 @@ def test_compiled_still_iterates_as_keys_and_counts_them(served):
     keys = list(eng._compiled)
     assert len(eng._compiled) == len(keys) >= 3
     for key in keys:
-        assert callable(key[0]) and key[0].__name__.startswith("paged_")
+        assert callable(key[0]) and key[0].__name__ in (
+            "paged_decode_multi", "paged_prefill_batch", "merge_carry")
         assert (fn := key[0]) and (fn, *key[1:]) in eng._compiled
     names = {k[0].__name__ for k in keys}
-    assert names == {"paged_decode_multi", "paged_prefill_batch"}
+    # the family's two, and the seam's merge that every admission takes
+    assert names == {"paged_decode_multi", "paged_prefill_batch", "merge_carry"}
     by_program = eng.program_parts()
     assert sum(p["variants"] for p in by_program.values()) == len(keys)
 
