@@ -413,7 +413,7 @@ class CoreClient:
         # dispatch; ref: dependency resolver holding arg refs)
         self._inflight_pins: dict[TaskID, list] = {}
         self._ship_collect: list | None = None  # set during arg serialization
-        self._rc_lock = _threading.Lock()  # counts are bumped off-loop too
+        self._rc_lock = _threading.RLock()  # off-loop too; a GC inside re-enters
         self._xq: list = []  # thread->loop submission queue (see _call_on_loop)
         self._xq_armed = False
         self._xq_linger = False

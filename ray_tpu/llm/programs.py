@@ -11,9 +11,9 @@ cache, a ``_decode_body``, two jitted programs and ``PROGRAMS``), with its
 stats' counters in ``utils/metrics.py`` ``LLM_MODEL_STATS`` and its parts in
 ``tracing.PARTS`` — and no line of this file, the engine or another family:
 a family's module imports this file, its own ``models/`` module and ``ops/``,
-never the engine and never another family. What a slot caches is the
-family's: pages that grow with the sequence (K and V, of one geometry or of a
-kind's own; a latent; a window's ring; an indexer's keys), rows that each stand
+never the engine and never another family. What a slot caches is the family's:
+pages that grow with the sequence (K and V, of one geometry, a kind's own or a
+pass's own; a latent; a window's ring; an indexer's keys), rows that each stand
 for a stride of positions, or a row of fixed size: beside such pages, or in them.
 
 What the families share is here, once: the platform rule (``reads_in_place``),

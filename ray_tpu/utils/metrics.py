@@ -352,6 +352,15 @@ LLM_MODEL_STATS = {
         "rt_llm_cca_row_updates_total",
         "convolution rows a decode step read and wrote beside the K/V page "
         "it wrote: live slots x layers (llm/cca_moe.py)"),
+    # the looped family (llm/looped.py), each summed over decode steps
+    "looped_live_slots": Counter(
+        "rt_llm_looped_live_slots_total",
+        "live slots of a decode step: a share of steps x max_batch — where "
+        "the pages run dry, slots stand empty beside a queue"),
+    "looped_exit_depth": Counter(
+        "rt_llm_looped_exit_depth_total",
+        "the pass the exit rule chose, summed over live slots: over live "
+        "slots, the mean depth (n_passes at a threshold of 1)"),
 }
 serve_lane_seconds = Histogram(
     "rt_serve_lane_seconds",

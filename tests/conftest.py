@@ -19,6 +19,7 @@ os.environ["RT_FORCE_CPU_DEVICES"] = "8"
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import faulthandler  # noqa: E402
+import gc  # noqa: E402
 import signal  # noqa: E402
 import threading  # noqa: E402
 
@@ -115,6 +116,45 @@ def pytest_runtest_call(item):
 @pytest.hookimpl(hookwrapper=True)
 def pytest_runtest_teardown(item):
     yield from _limited(item, "tear-down")
+
+
+def _mappings() -> int:
+    """Memory mappings this process holds (0 where /proc does not say)."""
+    try:
+        with open("/proc/self/maps", "rb") as f:
+            return sum(1 for _ in f)
+    except OSError:
+        return 0
+
+
+def _mappings_allowed() -> int:
+    try:
+        with open("/proc/sys/vm/max_map_count") as f:
+            return int(f.read())
+    except (OSError, ValueError):
+        return 65530  # the kernel's default
+
+
+@pytest.fixture(autouse=True)
+def _compiled_programs_released():
+    """XLA's CPU compiler maps several regions of memory for every program
+    it compiles or reads from the compile cache, jax keeps every program of
+    every jitted function for the life of the process, and a process may
+    hold ``vm.max_map_count`` mappings (65,530). An xdist worker that ran
+    enough serving-engine modules in a row reached it (one such module
+    leaves 10,000-16,000 behind, the largest 52,000): LLVM's next mapping
+    failed ("LLVM compilation error: Cannot allocate memory") and the
+    worker died of a segmentation fault inside ``backend_compile_and_load``
+    or the compile cache's read or write, in whatever test stood next. So
+    after a test that leaves the process with more than half its allowance,
+    jax's caches are cleared: the programs go with them (16,000 mappings
+    fall to under 1,000), and what the next test needs again it reads from
+    the compile cache's directory. An engine a fixture still holds keeps
+    its own programs."""
+    yield
+    if _mappings() > _mappings_allowed() // 2:
+        gc.collect()  # engines in cycles hold their compiled programs
+        jax.clear_caches()
 
 
 @pytest.fixture(scope="session")

@@ -1132,3 +1132,133 @@ def test_sink_moe_prefill_batch_compiles(one_chip, monkeypatch):
     assert not re.findall(r"\[2,(?:64|8,8|4,16),4096,4096\]", text)  # no scores
     assert compiled.memory_analysis().temp_size_in_bytes < 2.0e9
     assert len(re.findall(_RAGGED_DOT, text)) == 3 * 2
+
+
+# ---- a stack run four times a token: a plane a pass a layer, the passes a loop
+_LOOPED_POOL = r"bf16\[192,352,16,16,128\]"
+_LOOPED_POOL_COPY = rf"= {_LOOPED_POOL}\S* (?:copy|transpose)\("
+# the embedding or the head, 201 MB each: a copy, transposed or not
+_LOOPED_TABLE_COPY = (r"= (?:bf16|f32)\[(?:49152,2048|2048,49152)\]\S* "
+                      r"(?:copy|transpose)\(")
+
+
+def _looped_args(one_chip):
+    """Ouro-2.6B as the cell serves it: every published width and count (48
+    layers, 4 passes, 16 heads on 16 of 128, the SwiGLU at 5,632, the whole
+    vocabulary), 352 pages of 16 positions in 192 planes."""
+    from ray_tpu.llm.looped import make_pools
+    from ray_tpu.models.looped import LoopedConfig, looped_init
+
+    cfg = LoopedConfig(max_seq_len=640)
+    params = one_chip(jax.eval_shape(
+        lambda: looped_init(jax.random.PRNGKey(0), cfg)))
+    cache = one_chip(jax.eval_shape(lambda: make_pools(cfg, 16, 352, None)))
+    assert [c.shape for c in cache] == [(192, 352, 16, 16, 128)] * 2
+    assert all(c.dtype == jnp.bfloat16 for c in cache)   # 1.5 MiB a position
+    return cfg, params, cache, one_chip(_shape((2,), jnp.uint32))
+
+
+@pytest.fixture(scope="module")
+def looped_decode(one_chip):
+    """(cfg, lowered, compiled) decode program for 24 slots and 8 steps, as a
+    TPU's backend would choose its forms: compiled once for the tests that
+    read it."""
+    from ray_tpu.llm.looped import looped_decode_multi
+
+    was = jax.default_backend
+    jax.default_backend = lambda: "tpu"
+    looped_decode_multi.clear_cache()
+    cfg, params, cache, key = _looped_args(one_chip)
+    B = 24
+    i32 = one_chip(_shape((B,), jnp.int32))
+    try:
+        lowered = looped_decode_multi.lower(
+            params, None, i32, i32, i32, one_chip(_shape((B, 40), jnp.int32)),
+            *cache, one_chip(_shape((B,), jnp.bool_)),
+            one_chip(_shape((B,), jnp.float32)), key, cfg=cfg, n_steps=8)
+        compiled = lowered.compile()
+    finally:
+        jax.default_backend = was
+        looped_decode_multi.clear_cache()
+    return cfg, lowered, compiled
+
+
+def test_looped_decode_multi_compiles(looped_decode):
+    """24 slots a step at the published widths: the four passes are ONE
+    traced body — each layer's walk stands in the compiled text once, not
+    four times —, the pools are updated in place through both loops (no copy
+    of a pool on entry, on exit or between passes), neither table is copied,
+    and the whole is the arguments and little more."""
+    from ray_tpu.llm.looped import LOOPED_STATS
+
+    cfg, lowered, compiled = looped_decode
+    assert lowered.out_info[0].shape == (8, 24 + len(LOOPED_STATS))
+    mem = compiled.memory_analysis()
+    # weights 5.34 GB, K and V pools 8.86
+    assert 14.1e9 < mem.argument_size_in_bytes < 14.3e9
+    assert mem.temp_size_in_bytes < 0.2e9
+    text = compiled.as_text()
+    assert len(re.findall(r"%_paged_decode_attention\S* = \S+ custom-call\(",
+                          text)) == cfg.n_layers == 48
+    assert not re.findall(_LOOPED_POOL_COPY, text)
+    assert not re.findall(_LOOPED_TABLE_COPY, text)
+    # the steps' scan and, inside it, the passes' loop: no third
+    assert len(re.findall(r" while\(", text)) == 2
+
+
+def test_looped_decode_lays_no_weight_out(looped_decode):
+    """The same 51 M-parameter layer is read four times a step: inside the
+    loops nothing re-lays out one of its kernels — no ``reshape``, ``copy``
+    or ``transpose`` whose result has the extent of ``wqkv``, ``wo``,
+    ``w_gate_up`` or ``w_down`` — and no pool is converted or sliced whole."""
+    cfg, _, compiled = looped_decode
+    D, hd = cfg.d_model, cfg.head_dim
+    extents = {D * (cfg.n_heads + 2 * cfg.n_kv_heads) * hd,
+               cfg.n_heads * hd * D, D * 2 * cfg.d_ff, cfg.d_ff * D}
+
+    def lays_out(rows):
+        out = []
+        for _, key, opcode, *_ in rows:
+            if opcode not in ("reshape", "copy", "transpose"):
+                continue
+            dims = re.fullmatch(r"bf16\[([\d,]+)\]", key.split("|")[1])
+            if dims and math.prod(map(int, dims[1].split(","))) in extents:
+                out.append(f"{opcode} {key}")
+        return out
+
+    computations = tracing.program_instructions(compiled.as_text())[1]
+    assert sum(opcode == "while" for rows in computations
+               for _, _, opcode, *_ in rows) == 2
+    everything = [row for rows in computations for row in rows]
+    assert len(everything) > 1000
+    assert lays_out(everything) == []
+
+
+def test_looped_prefill_batch_compiles(one_chip, monkeypatch):
+    """The cell's largest wave, 4 prompts of 384: blocked attention over the
+    fresh keys (``gqa_prefill_attention`` at 16 KV heads, G = 1) once a layer
+    in the passes' one body, the planes written in place, and the whole
+    beside 14.2 GB of arguments inside the chip's 16.9."""
+    from ray_tpu.llm.looped import looped_prefill_batch
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    looped_prefill_batch.clear_cache()
+    cfg, params, cache, key = _looped_args(one_chip)
+    N, Tp = 4, 384
+    try:
+        compiled = looped_prefill_batch.lower(
+            params, None, one_chip(_shape((N,), jnp.int32)),
+            one_chip(_shape((N, Tp), jnp.int32)),
+            one_chip(_shape((N, Tp // 16), jnp.int32)), *cache,
+            one_chip(_shape((N,), jnp.int32)),
+            one_chip(_shape((N,), jnp.float32)), key, cfg=cfg).compile()
+    finally:
+        looped_prefill_batch.clear_cache()
+    text = compiled.as_text()
+    assert len(re.findall(r"%gqa_prefill_attention\S* = \S+ custom-call\(",
+                          text)) == 48
+    assert not re.findall(r"f32\[4,(?:16,)?384,384\]", text)   # no scores written
+    assert not re.findall(_LOOPED_POOL_COPY, text)
+    assert not re.findall(_LOOPED_TABLE_COPY, text)
+    assert len(re.findall(r" while\(", text)) == 1   # the passes' loop
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9

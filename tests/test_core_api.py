@@ -1,6 +1,7 @@
 """End-to-end task/actor API tests (modeled on the reference's
 python/ray/tests/test_basic.py coverage)."""
 
+import gc
 import time
 
 import numpy as np
@@ -166,6 +167,29 @@ def test_actor_ordering(rt):
     for i in range(20):
         a.add.remote(i)
     assert rt.get(a.items_list.remote()) == list(range(20))
+
+
+def test_a_handle_collected_inside_the_count_lock_does_not_wait_for_itself(rt):
+    """The keys of the core client's counts hash in Python, so a garbage
+    collection can start inside any section of their lock and run an
+    ``ActorHandle.__del__`` there, which takes the same lock on the same
+    thread: with a plain lock the thread waited for itself for ever
+    ("Garbage-collecting" above ``note_actor_handle_dropped`` in the stack
+    of a test at its time limit)."""
+    from ray_tpu.utils.ids import ActorID
+
+    @rt.remote
+    class Held:
+        def ping(self):
+            return 1
+
+    h = Held.remote()
+    assert rt.get(h.ping.remote()) == 1
+    core = h._core
+    with core._rc_lock:
+        core.note_actor_handle_dropped(ActorID.generate())  # an unknown id
+        del h  # the last handle of an enrolled actor, collected HERE
+        gc.collect()
 
 
 def test_async_actor(rt):

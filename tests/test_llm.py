@@ -708,7 +708,7 @@ def _arrows_point_one_way():
 
     families = _families()
     assert {"llama", "mla_moe", "cohere2_moe", "sparse_moe", "ssm_moe",
-            "eva", "kda_moe", "cca_moe", "sink_moe"} <= set(families)
+            "eva", "kda_moe", "cca_moe", "sink_moe", "looped"} <= set(families)
     subprocess.run(
         [sys.executable, "-c",
          "import sys, ray_tpu.llm.engine; "
@@ -770,7 +770,7 @@ def _a_family_is_declared_once():
                "cohere2_moe": "Cohere2MoeConfig", "sparse_moe": "SparseMoeConfig",
                "ssm_moe": "SsmMoeConfig", "eva": "EvaConfig",
                "kda_moe": "KdaMoeConfig", "cca_moe": "CcaMoeConfig",
-               "sink_moe": "SinkMoeConfig"}
+               "sink_moe": "SinkMoeConfig", "looped": "LoopedConfig"}
     assert set(configs) == set(families)
     procs = [subprocess.Popen([sys.executable, "-c", script, name, cls])
              for name, cls in configs.items()]
