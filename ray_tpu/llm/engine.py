@@ -38,14 +38,14 @@ ideas onto XLA's static-shape world:
   sequence, or a ring of one window's pages); a row a STRIDE of positions (a
   chunk's pooled pair: a page of ``PS`` rows stands for ``PS * stride``
   positions); or a row with NO positions (a recurrent state: one entry,
-  whatever the length). A slot of ``n`` positions draws what each kind says
-  it holds, and admission waits for whichever kind runs out. No device program
-  is defined here, nothing reads a weight: engine -> seam -> programs -> ``ops/``.
+  whatever the length). A slot HOLDS what its ``n`` positions reach, drawn as
+  it grows, and admission waits for the kind whose TIMELINE passes its pool
+  (``_timeline``). No device program here: engine -> seam -> programs -> ``ops/``.
 """
 from __future__ import annotations
 
 import asyncio
-import collections
+import collections.abc
 import concurrent.futures
 import itertools
 import time
@@ -154,7 +154,7 @@ class ContinuousBatchingEngine:
                       if P.page_kinds else (PageKind("kv", 1, self.MAXP),))
         counts = [n_pages[k.name] if isinstance(n_pages, dict) else n_pages
                   for k in self.kinds]
-        self.free = [list(range(1, n)) for n in counts]
+        self.free = [_FreePages(n) for n in counts]
         self.capacity = [n - 1 for n in counts]
         self.loras = None
         self.lora_index = {"__base__": 0}
@@ -382,18 +382,21 @@ class ContinuousBatchingEngine:
                 "blocks": blocks}
 
     @property
-    def free_pages(self) -> list:
-        """The first kind's free list, under the name that callers who know
-        one kind of page read it by (the benchmark's replicas, the tests)."""
+    def free_pages(self) -> "_FreePages":
+        """The first kind's free pages, under the name that callers who know
+        one kind of page read them by (the benchmark's replicas, the tests)."""
         return self.free[0]
 
     def headroom(self) -> dict:
-        """Admission-control snapshot for the disagg scheduler: free KV
-        pages and decode slots, queue depth, and the decode
-        tokens-in-flight signal."""
-        return {"free_pages": len(self.free[0]),
-                "free_pages_by_kind": {k.name: len(f) for k, f in
-                                       zip(self.kinds, self.free)},
+        """Admission-control snapshot for the disagg scheduler: the KV pages
+        a newcomer could still be PROMISED (the pool less the peak of what
+        the residents will hold, ``_timeline``: a page not yet drawn is not a
+        page to spare; ``free_pages_now`` is the count not drawn), decode
+        slots, queue depth, and the decode tokens-in-flight signal."""
+        spare = [int(c - t) for c, t in zip(self.capacity, self._timeline())]
+        return {"free_pages": spare[0], "free_pages_now": len(self.free[0]),
+                "free_pages_by_kind": {k.name: n for k, n in
+                                       zip(self.kinds, spare)},
                 "free_slots": sum(r is None for r in self.slot_req),
                 "waiting": len(self.waiting),
                 "tokens_in_flight": self.tokens_in_flight(),
@@ -476,41 +479,36 @@ class ContinuousBatchingEngine:
             self._wake.set()
 
     # ------------------------------------------------------------ internals
-    def _pages_of(self, n: int) -> list[int]:
-        """Pages of each kind that a slot of ``n`` positions holds."""
-        return [min(-(-n // (self.PS * k.stride)), k.table) for k in self.kinds]
+    def _pages_of(self, n) -> list:
+        """Pages of each kind a slot of ``n`` positions holds (or an array)."""
+        return [np.minimum(-(-n // (self.PS * k.stride)), k.table)
+                for k in self.kinds]
 
-    def _alloc_pages(self, n: int) -> list[list[int]] | None:
-        """Draw a slot's pages for ``n`` positions, a list a kind, or
-        nothing if ANY kind has too few left."""
-        need = self._pages_of(n)
+    def _draw(self, slot: int, n: int, whole: int, grown: bool) -> bool:
+        """Fill ``slot``'s tables up to what ``n`` positions hold, every kind,
+        or draw nothing if ANY kind has too few left. ``whole``: the positions
+        of the request's end, for the place (``_place``). ``grown``: after
+        admission (``rt_llm_pages_grown_total``, of every family)."""
+        have = [int(np.count_nonzero(t[slot])) for t in self.tables]
+        need = [int(m) - h for m, h in zip(self._pages_of(n), have)]
         if any(len(f) < m for f, m in zip(self.free, need)):
-            return None
-        out = []
-        for i, (free, m) in enumerate(zip(self.free, need)):
-            out.append(free[:m])
-            del free[:m]
-            self._count_pages(i, drawn=m)
-        return out
-
-    def _count_pages(self, i: int, drawn: int = 0) -> None:
-        """Kind ``i``'s pages drawn and held, where a family has kinds."""
-        if len(self.kinds) == 1:
-            return
-        tags = {"kind": self.kinds[i].name}
-        if drawn:
-            metrics.llm_pages_drawn_total.inc(drawn, tags)
-        metrics.llm_pages_held.set(
-            self.capacity[i] - len(self.free[i]), tags)
+            return False
+        for i, (h, m, end) in enumerate(zip(have, need, self._pages_of(whole))):
+            if m > 0:
+                self.tables[i][slot, h:h + m] = self._place(
+                    i, slot, h, m, int(end) - h)
+                self._count_pages(i, drawn=m)
+                if grown:
+                    metrics.llm_pages_grown_total.inc(
+                        m, {"kind": self.kinds[i].name})
+        return True
 
     def _release_pages(self, slot: int, reached: int) -> None:
-        """Give back every page of ``slot``'s tables, all kinds. A table
-        holds ALL pages drawn at admission (prompt + max_tokens worth), not
-        just the ones reached — free every entry. ``reached``: the
-        positions the slot got to, for the count of pages a ring reused."""
+        """Give back every page that ``slot``'s tables hold, all kinds.
+        ``reached``: its positions, for the count of pages a ring reused."""
         for i, (kind, free, table) in enumerate(
                 zip(self.kinds, self.free, self.tables)):
-            free.extend(int(p) for p in table[slot] if p != 0)
+            free.give(table[slot][table[slot] != 0])
             table[slot, :] = 0
             self._count_pages(i)
             if kind.reach is not None:  # a ring: the pages it wrote over
@@ -537,20 +535,22 @@ class ContinuousBatchingEngine:
         req.out.put_nowait(None)
 
     def _reserve_slot(self, req: _Request) -> int | None:
-        """Claim a slot + pages for one waiting request (host bookkeeping
-        only; the prefill itself is dispatched per wave)."""
+        """Claim a slot for one waiting request and draw its prompt's pages,
+        if the residents' timeline has room for it (host bookkeeping only;
+        the prefill itself is dispatched per wave). The speculative loop
+        advances a slot by what its drafts are worth, which no timeline
+        knows: there every resident counts at its end and draws whole."""
         slot = next((i for i, r in enumerate(self.slot_req) if r is None), -1)
         if slot < 0:
             return None
         Tp = len(req.prompt)
-        pages = self._alloc_pages(Tp + req.max_tokens)
-        if pages is None:
+        whole = Tp + req.max_tokens
+        if (self._timeline(req) > self.capacity).any() or not self._draw(
+                slot, whole if self.spec_enable else Tp, whole, grown=False):
+            metrics.llm_admit_deferred_total.inc(1, {"for": "pages"})
             return None
         req.slot = slot
         self.slot_req[slot] = req
-        for table, drawn in zip(self.tables, pages):
-            table[slot, :] = 0
-            table[slot, :len(drawn)] = drawn
         self.seq_lens[slot] = Tp
         self.temps[slot] = req.temperature
         self.aids[slot] = req.adapter
@@ -854,7 +854,9 @@ class ContinuousBatchingEngine:
         At high occupancy the ramp is skipped: a full batch is the
         throughput regime, where small early blocks would multiply
         dispatch round trips for no latency benefit (newcomers can't be
-        admitted into a full batch anyway)."""
+        admitted into a full batch anyway). A slot draws the pages a block
+        steps into before the block is dispatched (``_grow``), so the bucket
+        is no larger than the free pages cover."""
         live = [r for r in self.slot_req if r is not None
                 and not r.cancelled and r.planned < r.max_tokens]
         if not live:
@@ -864,10 +866,13 @@ class ContinuousBatchingEngine:
         else:
             want = min(min(self._ramp(r.planned), r.max_tokens - r.planned)
                        for r in live)
-        for b in self.block_buckets:
-            if want <= b:
-                return b
-        return self.block_buckets[-1]
+        cover = next((b for b in self.block_buckets if want <= b),
+                     self.block_buckets[-1])
+        # the slots that end INSIDE a block hold their pages until its
+        # sweep: the largest bucket whose growth the free pages cover this
+        # instant. One step always is, by the timeline's bound
+        return next((b for b in reversed(self.block_buckets)
+                     if b <= cover and not self._lacking(b)), 1)
 
     async def _dispatch_block(self, carry):
         """Pick the block size and dispatch one fused decode block from
@@ -877,8 +882,11 @@ class ContinuousBatchingEngine:
         next carry)."""
         with tracing.phase("engine.decode_dispatch") as ph:
             K = self._pick_block()
+            self._grow(K)
             active = np.array([r is not None for r in self.slot_req])
-            ph.set(steps=K, live=int(active.sum()), **self._last_stats,
+            ph.set(steps=K, live=int(active.sum()),
+                   held=self.capacity[0] - len(self.free[0]),
+                   promised=int(self._timeline()[0]), **self._last_stats,
                    **self._last_kv)
             self._rng, sub = jax.random.split(self._rng)
             # .copy() on every host array that the loops later mutate
@@ -1090,7 +1098,7 @@ class ContinuousBatchingEngine:
         while self._running:
             # retire slots whose scheduled tokens are all dispatched
             self._sweep(scheduled=True)
-            if self.waiting and any(r is None for r in self.slot_req):
+            if self._slot_for_the_head():
                 carry = await self._admit_behind(carry, pending)
             if all(r is None for r in self.slot_req):
                 while pending:
@@ -1131,8 +1139,9 @@ class ContinuousBatchingEngine:
         EOS, a user's cancel, the last token of a request nobody waited
         behind — drains what is in flight, sweeps and rebuilds the carry
         from the host's copy, which a drain leaves current. With nobody
-        waiting a scheduled slot keeps its place and the loop runs on, a
-        step a block, until its last token is on the host.
+        waiting, for its slot or for its pages, a scheduled slot keeps its
+        place and the loop runs on, a step a block, until its last token is
+        on the host.
 
         The loop holds the event loop's thread nearly all the time: every
         _emit_block sleeps in np.asarray (phase engine.block_sync) until the
@@ -1147,9 +1156,13 @@ class ContinuousBatchingEngine:
                 self._sync_oldest(pending)
 
         while self._running:
-            if self.waiting or not pending:
-                self._sweep(scheduled=bool(self.waiting))
-            if self.waiting and any(r is None for r in self.slot_req):
+            # a scheduled end is swept when someone waits for its slot, or
+            # a live slot's next step for its pages: the timeline that let
+            # that slot in counted them free from the end on
+            scheduled = bool(self.waiting) or self._lacking(1)
+            if scheduled or not pending:
+                self._sweep(scheduled)
+            if self._slot_for_the_head():
                 carry = await self._admit_behind(carry, pending)
             if all(r is None for r in self.slot_req):
                 drain()
@@ -1293,7 +1306,7 @@ class ContinuousBatchingEngine:
         while self._running:
             if not pending:  # free only with no block in flight
                 self._sweep()
-            if self.waiting and any(r is None for r in self.slot_req):
+            if self._slot_for_the_head():
                 drain()  # admission changes device-visible state
                 self._sweep()
                 if await self._admit_wave():
@@ -1417,3 +1430,165 @@ class ContinuousBatchingEngine:
             return np.minimum(lens, kind.reach)
         whole = (lens - 1) // kind.reach * kind.reach
         return lens - whole if kind.stride == 1 else whole // kind.stride
+
+    def _count_pages(self, i: int, drawn: int = 0) -> None:
+        """Kind ``i``'s pages drawn and held, where a family has kinds."""
+        if len(self.kinds) == 1:
+            return
+        tags = {"kind": self.kinds[i].name}
+        if drawn:
+            metrics.llm_pages_drawn_total.inc(drawn, tags)
+        metrics.llm_pages_held.set(
+            self.capacity[i] - len(self.free[i]), tags)
+
+    def _slot_for_the_head(self) -> bool:
+        """Whether someone waits and a slot stands empty: the turns on which
+        the loop tries an admission. A turn on which the head of the queue
+        waits for a SLOT is counted here, one on which it waits for pages
+        where the timeline refuses it (``_reserve_slot``)."""
+        if not self.waiting:
+            return False
+        if any(r is None for r in self.slot_req):
+            return True
+        metrics.llm_admit_deferred_total.inc(1, {"for": "slots"})
+        return False
+
+    def _timeline(self, req: _Request | None = None):
+        """The most pages of each kind ([kinds]) that the residents, and
+        ``req`` admitted beside them, hold at any step still to be dispatched
+        — what admission checks against the pool. Every live slot advances one
+        position a dispatched step, and a request's end is known (``planned``,
+        ``max_tokens``): with ``p = len(prompt) + planned`` positions drawn for
+        and ``r = max_tokens - planned`` steps to go, the step ``t`` from now
+        finds the pages of ``p + min(t + a, r)`` positions in every slot with
+        ``r >= t`` and the others swept. That sum rises only between ends, so
+        it is taken at the ends alone (and at 0: what is held now). ``a``, how
+        far ahead of its step a slot draws, is nothing — a block draws what it
+        steps into, and ``_pick_block`` sizes it to the free pages — but in the
+        speculative loop, whose slots draw whole: there ``a`` is past every
+        end. An EOS or a cancel only frees pages sooner."""
+        # a newcomer's first token is planned before its first block is
+        # dispatched (``_admit_behind``)
+        pr = [(len(q.prompt) + max(q.planned, 1),
+               0 if q.cancelled else q.max_tokens - max(q.planned, 1))
+              for q in (*self.slot_req, req) if q is not None]
+        if not pr:
+            return np.zeros(len(self.kinds), np.int64)
+        p, r = np.array(pr, np.int64).T
+        ends = np.unique(np.append(r, 0))[:, None]  # [ends, 1] against [slots]
+        a = self.MAXP * self.PS if self.spec_enable else 0
+        held = [(pages * (r >= ends)).sum(axis=1).max() for pages in
+                self._pages_of(p + np.minimum(ends + a, r))]
+        return np.array(held, np.int64)
+
+    def _growth(self, K: int) -> list:
+        """Pages of each kind that every slot draws before a block of ``K``
+        steps ([B] a kind): what the positions the block reaches hold — none
+        past the request's end — less what the slot's table holds."""
+        n = np.array([0 if r is None else self._reach(r, K)
+                      for r in self.slot_req], np.int64)
+        return [np.maximum(pages - np.count_nonzero(t, axis=1), 0)
+                for pages, t in zip(self._pages_of(n), self.tables)]
+
+    @staticmethod
+    def _reach(r: _Request, K: int) -> int:
+        """The positions a block of ``K`` steps draws pages for in ``r``'s
+        slot: none past its end — where a request all dispatched stays, the
+        steps the loop runs on included (a request of ONE token so draws the
+        page of that token's position, which a step run on writes) — and none
+        at all (0) once it is cancelled or over."""
+        if r.cancelled:
+            return 0
+        return len(r.prompt) + min(r.planned + K, r.max_tokens)
+
+    def _lacking(self, K: int) -> bool:
+        """Whether a block of ``K`` steps would step into a page that the
+        free pages cannot give this instant."""
+        return any(g.sum() > len(f)
+                   for g, f in zip(self._growth(K), self.free))
+
+    def _grow(self, K: int) -> None:
+        """Draw, for every slot that steps in a block of ``K``, the pages the
+        block steps into. A table entry still 0 there would send the step's K
+        and V to the junk page, lost without a sound: hence the assertion."""
+        for slot in np.flatnonzero(np.any(self._growth(K), axis=0)):
+            r = self.slot_req[slot]
+            drawn = self._draw(slot, self._reach(r, K),
+                               len(r.prompt) + r.max_tokens, grown=True)
+            assert drawn, f"slot {slot} steps into a page nobody can give it"
+
+    def _place(self, i: int, slot: int, have: int, m: int, rest: int):
+        """Take ``m`` free pages of kind ``i`` for ``slot``'s table entries
+        from ``have`` on, so that the walks' runs survive
+        (``ops/paged_attention.py`` ``run_lengths``: consecutive pool pages
+        are one copy): the page after the slot's last one while that is free,
+        else from the start of a hole (``_hole``) for the ``rest`` of the
+        request — these ``m`` and what it draws until its end. The count is
+        the timeline's; the place is best effort."""
+        free, out = self.free[i].is_free, np.empty(m, np.int32)
+        at = int(self.tables[i][slot, have - 1]) + 1 if have else 0
+        got = 0
+        while got < m:
+            if not (at and at < len(free) and free[at]):
+                at = self._hole(i, slot, rest - got)
+            run = free[at:at + m - got]
+            n = len(run) if run.all() else int(run.argmin())
+            out[got:got + n] = np.arange(at, at + n)
+            free[at:at + n] = False
+            at, got = at + n, got + n
+        self.free[i].count -= m
+        return out
+
+    def _hole(self, i: int, slot: int, rest: int) -> int:
+        """Where ``slot`` starts a new run of kind ``i``: the first free run
+        that holds ``rest`` pages, else the longest — of the pages no OTHER
+        slot is about to grow into (every resident's hole: as many pages
+        after its last one as it still draws), and only where none of those
+        is left, of all the free pages. Where pages do not bind, each slot so
+        grows inside a hole of its own and its table is one run; where they
+        do, the pool is shared in time and not in place, and a table is what
+        it gets (the looped family's walk, the one cell where they do, reads
+        pages of 64 KB at the same speed scattered: PERF.md section 6, PR 58)."""
+        free = self.free[i].is_free
+        spare = free.copy()
+        for other, r in enumerate(self.slot_req):
+            if r is None or other == slot:
+                continue
+            row = self.tables[i][other]
+            have = int(np.count_nonzero(row))
+            more = int(self._pages_of(len(r.prompt) + r.max_tokens)[i]) - have
+            if have and more > 0:
+                spare[row[have - 1] + 1:row[have - 1] + 1 + more] = False
+        for mask in (spare, free):
+            edges = np.flatnonzero(np.diff(mask, prepend=False, append=False))
+            starts, lengths = edges[::2], edges[1::2] - edges[::2]
+            if len(starts):
+                return int(starts[np.argmax(lengths >= rest) if
+                                  (lengths >= rest).any() else lengths.argmax()])
+        raise AssertionError("no free page: _draw counts before it places")
+
+
+class _FreePages(collections.abc.Sequence):
+    """A kind's free pages: a map (``is_free[p]`` — a list cannot say whether
+    the page after a slot's last one is free) that reads as the ascending list
+    of them, which is what an idle engine's next request draws from the head
+    of (the tests and the benchmark's replicas slice it to know where a
+    request's K and V will lie). Page 0 is the junk page, never free."""
+
+    def __init__(self, n_pages: int):
+        self.is_free = np.ones(n_pages, bool)
+        self.is_free[0] = False
+        self.count = n_pages - 1
+
+    def __len__(self) -> int:
+        return self.count
+
+    def __getitem__(self, i):
+        return np.flatnonzero(self.is_free)[i]
+
+    def __iter__(self):
+        return iter(np.flatnonzero(self.is_free).tolist())
+
+    def give(self, pages) -> None:
+        self.is_free[pages] = True
+        self.count += len(pages)

@@ -210,7 +210,10 @@ class LLMEngineServer:
             self.engine.cancel(rid)  # no-op once finished
 
     def engine_stats(self) -> dict:
-        """Counters of this replica's engine. ``stages``: cumulative sum
+        """Counters of this replica's engine. ``free_pages``: the pages a
+        newcomer could still be promised, as the disagg scheduler reads it
+        (``ContinuousBatchingEngine.headroom``; ``free_pages_now``: those not
+        drawn this instant). ``stages``: cumulative sum
         and count of every stage family of ``utils/metrics.py`` in this
         process — the engine loop's phases, a request's queue, prefill
         and decode waits, the prefill counters, the lane's two legs, and
@@ -227,7 +230,8 @@ class LLMEngineServer:
 
         out = {"steps": self.engine.steps, "tokens_out": self.engine.tokens_out,
                "waiting": len(self.engine.waiting),
-               "free_pages": len(self.engine.free[0]),
+               **{k: v for k, v in self.engine.headroom().items()
+                  if k in ("free_pages", "free_pages_now")},
                "weights_prepared": self.engine.weights_prepared,
                "program_builds": self.engine.program_builds(),
                "stages": metrics.stage_totals()}

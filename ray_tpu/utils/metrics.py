@@ -260,14 +260,26 @@ llm_decode_kv_tokens_read_total = Counter(
     "positions the decode programs fetched from the page pool for them",
     tag_keys=("kind",))
 # The allocator of such a cache, a kind: pages its slots hold now, pages
-# drawn at admissions, and the pages of window layers that a ring table
-# wrote over as the window slid past them (never drawn a second time).
+# drawn (whenever: at admission for the prompt, then as the slot grows), and
+# the pages of window layers that a ring table wrote over as the window slid
+# past them (never drawn a second time). Of EVERY family, one kind or more:
+# the pages drawn after admission, and the loop turns on which the head of
+# the queue was refused, by what it waited for — how often admission by the
+# timeline of demand (llm/engine.py _timeline) engages.
 llm_pages_held = Gauge(
     "rt_llm_pages_held", "pages of a kind that slots hold now",
     tag_keys=("kind",))
 llm_pages_drawn_total = Counter(
-    "rt_llm_pages_drawn_total", "pages of a kind drawn at admissions",
+    "rt_llm_pages_drawn_total", "pages of a kind drawn, whenever",
     tag_keys=("kind",))
+llm_pages_grown_total = Counter(
+    "rt_llm_pages_grown_total",
+    "pages of a kind drawn after admission, as a slot's sequence grew",
+    tag_keys=("kind",))
+llm_admit_deferred_total = Counter(
+    "rt_llm_admit_deferred_total",
+    "loop turns on which the head of the queue was not admitted",
+    tag_keys=("for",))
 llm_window_pages_released_total = Counter(
     "rt_llm_window_pages_released_total",
     "pages a window slid past that its ring table reused, counted when the "
@@ -387,6 +399,7 @@ STAGE_FAMILIES = (
     llm_admit_waves_undrained_total,
     llm_decode_kv_tokens_live_total, llm_decode_kv_tokens_read_total,
     llm_pages_held, llm_pages_drawn_total, llm_window_pages_released_total,
+    llm_pages_grown_total, llm_admit_deferred_total,
     *LLM_MODEL_STATS.values(), serve_lane_seconds, bringup_seconds)
 
 

@@ -89,6 +89,53 @@ def test_stage_counters_match_the_admissions(tiny, eos_id):
     assert 0 < sum(d["sum"] for d in phases.values()) <= wall
 
 
+@pytest.mark.parametrize("n_pages", [21, 64], ids=["binding", "roomy"])
+def test_growth_and_deferral_counters_add_up(n_pages):
+    """How often admission by the timeline engages, counted by the program:
+    ten requests of 16 + 12..48 on four slots of a family with two kinds of
+    pages. Every page drawn is drawn at admission (the prompt's) or grown
+    (``rt_llm_pages_grown_total``), of each kind; the head of the queue waits
+    for a slot (``rt_llm_admit_deferred_total{for="slots"}``) and, only where
+    the pool binds, for pages."""
+    import importlib
+
+    from ray_tpu.llm.engine import ContinuousBatchingEngine
+
+    W = importlib.import_module("benchmarks.lib.weights_cohere2_moe")
+    cfg = importlib.import_module("ray_tpu.models.cohere2_moe").Cohere2MoeConfig.tiny(
+        experts_held=(4, 12), vocab_held=(256, 512))
+    eng = ContinuousBatchingEngine(
+        W.make_params(W.seed_key(5), cfg), cfg, max_batch=4, page_size=8,
+        max_seq_len=96, eos_id=1000, block_buckets=(4, 8),
+        n_pages={"full": n_pages, "window": 25})
+    lens = [48, 20, 36, 12, 48, 28, 40, 16, 44, 24]
+
+    async def go():
+        before = metrics.stage_totals()
+        await eng.start()
+        outs = await asyncio.gather(*(
+            eng.generate([3 + i] * 16, max_tokens=m) for i, m in enumerate(lens)))
+        await eng.stop()
+        return before, metrics.stage_totals(), outs
+
+    before, after, outs = asyncio.run(go())
+    assert [len(o) for o in outs] == lens
+
+    def grown(family, tag):
+        return _delta(after, before, family, tag)["sum"]
+
+    # a prompt of 16 draws 2 pages of either kind at admission; a reply of m
+    # tokens reaches 16 + m positions: the ring stops at its 4 entries + 1
+    for kind, at_end in (("full", lambda n: -(-n // 8)),
+                         ("window", lambda n: min(-(-n // 8), 5))):
+        assert grown("rt_llm_pages_grown_total", kind) == sum(
+            at_end(16 + m) - 2 for m in lens) > 0
+        assert grown("rt_llm_pages_drawn_total", kind) == (
+            grown("rt_llm_pages_grown_total", kind) + 2 * len(lens))
+    assert grown("rt_llm_admit_deferred_total", "slots") > 0
+    assert (grown("rt_llm_admit_deferred_total", "pages") > 0) == (n_pages == 21)
+
+
 def _logged_engine(tiny, **kw):
     """An engine whose loop logs, in order, what it dispatches and what it
     waits for: ("wave", prompts) a prefill wave dispatched, ("block", steps)
@@ -258,6 +305,10 @@ def test_phases_land_in_the_profilers_trace(tiny, tmp_path):
     dispatches = [e for e in events if e[2] == "engine.decode_dispatch"]
     assert dispatches and all(e[3]["steps"] in (1, 4, 8, 16, 32, 64)
                               and e[3]["live"] >= 1 for e in dispatches)
+    # pages held now, and the most the residents will hold (the timeline's
+    # peak): two requests of 3 + 30 on pages of 8 reach 5 pages each
+    assert all(0 < e[3]["held"] <= e[3]["promised"] <= 10 for e in dispatches)
+    assert {e[3]["promised"] for e in dispatches} == {10}
     assert sum(e[3]["tokens"] for e in events
                if e[2] == "engine.emit") == 2 * 29  # all but the first tokens
     # a prefill wave's admit says how many prompts and true tokens it holds

@@ -838,41 +838,227 @@ def _all_given_back(eng) -> bool:
 # (prompt length, max_tokens): ends inside blocks and at their edges, a
 # one-token request, a prompt past cohere2's window of 32, pages of 8 crossed
 _HANDED_ON = [(10, 13), (40, 9), (2, 5), (5, 1), (20, 17), (7, 8), (33, 12)]
+# a pool that BINDS: four slots and 20 pages of 8 positions, five eighths of
+# four whole reservations of 16 + 48 — the sum of the ends does not fit, the
+# peak of what staggered requests hold does. Reserving whole, as the engine
+# did, two of these live at once: a third asks for 4 to 8 of the 4 pages left
+_BINDING = [(16, 48), (16, 20), (16, 36), (16, 12), (16, 48), (16, 28),
+            (16, 40), (16, 16), (16, 44), (16, 24)]
+_BINDING_POOLS = {"llama": 21, "cohere2_moe": {"full": 21, "window": 25}}
 
 
-@pytest.mark.parametrize("family", ["llama", "ssm_moe", "cohere2_moe"])
-def test_replies_equal_those_served_alone_when_slots_are_handed_on(family):
+def _watch_growth(eng) -> dict:
+    """Taps ``eng._grow``: the most slots that stepped in one block, and that
+    after it no table entry a stepping slot's block reaches is the junk
+    page's, of any kind."""
+    seen, grow = {"live": 0}, eng._grow
+
+    def tap(K):
+        grow(K)
+        stepping = [(i, r) for i, r in enumerate(eng.slot_req) if r is not None
+                    and not r.cancelled and r.planned < r.max_tokens]
+        seen["live"] = max(seen["live"], len(stepping))
+        for i, r in stepping:
+            reach = len(r.prompt) + min(r.planned + K, r.max_tokens)
+            for table, n in zip(eng.tables, eng._pages_of(reach)):
+                assert table[i, :n].all(), (i, K, reach, table[i])
+
+    eng._grow = tap
+    return seen
+
+
+@pytest.mark.parametrize("family,binds,eos_id", [
+    ("llama", False, 1000), ("ssm_moe", False, 1000),
+    ("cohere2_moe", False, 1000), ("llama", True, 1000), ("llama", True, None),
+    ("cohere2_moe", True, 1000), ("cohere2_moe", True, None)])
+def test_replies_equal_those_served_alone_when_slots_are_handed_on(
+        family, binds, eos_id):
     """The page- and row-reuse guard: seven greedy requests on two slots, so
     five of them take a slot (its pages, its ring, its state row) that was
     swept while blocks of its last holder were in flight. Each reply equals
-    the same request served alone, and everything is given back."""
+    the same request served alone, and everything is given back. Where the
+    pool ``binds`` (ten requests on four slots over ``_BINDING_POOLS``, both
+    loops): a slot holds the pages its positions have reached, so more than
+    two live at once, each block steps into pages drawn before it, and a page
+    given back mid-flight is drawn again by a slot that grows."""
     import asyncio
 
-    eng, vocab = _family_engine(family)
-    prompts = _prompts(vocab, [n for n, _ in _HANDED_ON])
+    cases = _BINDING if binds else _HANDED_ON
+    eng, vocab = _family_engine(family, eos_id=eos_id, **(
+        {"max_batch": 4, "n_pages": _BINDING_POOLS[family]} if binds else {}))
+    prompts = _prompts(vocab, [n for n, _ in cases])
+    seen = _watch_growth(eng)
 
     async def go():
         await eng.start()
         before = _admit_counters()
         together = await asyncio.wait_for(asyncio.gather(*(
             eng.generate(p, max_tokens=m)
-            for p, (_, m) in zip(prompts, _HANDED_ON))), timeout=600)
+            for p, (_, m) in zip(prompts, cases))), timeout=600)
         waves, undrained = (a - b for a, b in zip(_admit_counters(), before))
+        for _ in range(200):  # the planned loop's sweep behind the last block
+            if _all_given_back(eng):
+                break
+            await asyncio.sleep(0.01)
         given_back = _all_given_back(eng)
         alone = [await eng.generate(p, max_tokens=m)
-                 for p, (_, m) in zip(prompts, _HANDED_ON)]
+                 for p, (_, m) in zip(prompts, cases)]
         await eng.stop()
         return together, alone, waves, undrained, given_back
 
     together, alone, waves, undrained, given_back = _run(go())
-    assert [len(o) for o in together] == [m for _, m in _HANDED_ON]
+    assert [len(o) for o in together] == [m for _, m in cases]
     assert together == alone
     assert given_back
+    if binds:
+        assert 2 < seen["live"] <= 4, seen
+        return
     # every wave finds a block in flight and waits for none, but: the first
     # admission's (two pads, two waves into an idle engine) and the one
     # behind the one-token request, whose only token is on the host before a
     # sweep meets it — the drained path, like any end that emission finds
     assert waves >= 5 and undrained >= waves - 3, (waves, undrained)
+
+
+# --------------------------------------------------- the timeline's bound
+def _bookkeeper(kinds, counts, B, **kw):
+    """An engine that is never started, for its host bookkeeping alone: ``B``
+    slots over pages of 8 positions of the given ``kinds``, ``counts`` pages
+    a kind (the junk page included)."""
+    import jax
+
+    from ray_tpu.llm import ContinuousBatchingEngine
+    from ray_tpu.llm.engine import _FreePages
+
+    cfg = LlamaConfig.tiny()
+    eng = ContinuousBatchingEngine(
+        llama_init(jax.random.PRNGKey(0), cfg), cfg, max_batch=B, page_size=8,
+        n_pages=8, max_seq_len=128, **kw)
+    eng.kinds = kinds
+    eng.free = [_FreePages(n) for n in counts]
+    eng.capacity = [n - 1 for n in counts]
+    eng.tables = [np.zeros((B, k.table), np.int32) for k in kinds]
+    return eng
+
+
+def _admit(eng):
+    """The top of a turn of the loops, bookkeeping alone: scheduled ends
+    swept and the head of the queue admitted while it fits. Returns the head
+    that was refused though a slot stood empty — refused for pages."""
+    eng._sweep(scheduled=True)
+    while eng.waiting and any(r is None for r in eng.slot_req):
+        if eng._reserve_slot(eng.waiting[0]) is None:
+            return eng.waiting[0]
+        eng.waiting.pop(0).planned = 1
+
+
+def _block(eng, K=None) -> None:
+    """The rest of the turn: one block's pages drawn (``_grow`` asserts that
+    every page the block steps into was there) and its steps planned."""
+    K = K or eng._pick_block()
+    eng._grow(K)
+    for r in eng.slot_req:
+        if r is not None:
+            r.planned = min(r.max_tokens, r.planned + K)
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_the_timeline_admits_what_fits_and_refuses_what_would_not(seed):
+    """Seeded random requests over four kinds of pages — a row a position, a
+    ring, a strided kind, a state row — in pools that bind: stepping what
+    admission let in to its ends, in the blocks ``_pick_block`` sizes, never
+    asks the free pages for a page they lack (``_grow`` asserts it), a cancel
+    only lowers what is held and promised, and a request the check refused
+    WOULD have: let in all the same and stepped a step a block, some block
+    lacks a page. Everything is given back at the end."""
+    import copy
+
+    from ray_tpu.llm.engine import _Request
+    from ray_tpu.llm.programs import PageKind
+
+    rng = np.random.default_rng(seed)
+    kinds = (PageKind("full", 1, 16), PageKind("window", 1, 4, reach=24),
+             PageKind("pairs", 1, 4, stride=4), PageKind("state", 1, 1,
+                                                         positions=False))
+    counts = [int(rng.integers(25, 45)), int(rng.integers(9, 17)),
+              int(rng.integers(7, 12)), 7]
+    eng = _bookkeeper(kinds, counts, B=6)
+    for i in range(40):  # two tokens at the least: see ``_timeline`` on one
+        n = int(rng.integers(1, 64))
+        eng.waiting.append(_Request(i, [1] * n, int(rng.integers(2, 128 - n)),
+                                    0.0, 0))
+    refused = lacked = 0
+    while eng.waiting or any(r is not None for r in eng.slot_req):
+        head = _admit(eng)
+        if head is not None:  # what if it had been let in all the same
+            refused += 1
+            twin = _bookkeeper(kinds, counts, B=6)
+            twin.slot_req, twin.tables, twin.free = copy.deepcopy(
+                (eng.slot_req, eng.tables, eng.free))
+            late, slot = copy.deepcopy(head), twin.slot_req.index(None)
+            twin.slot_req[slot], late.slot, late.planned = late, slot, 1
+            short = not twin._draw(slot, len(late.prompt), 128, grown=False)
+            while not short and any(r is not None for r in twin.slot_req):
+                short = twin._lacking(1)
+                if not short:
+                    _block(twin, K=1)
+                    _admit(twin)
+            lacked += short
+        _block(eng)
+        live = [r for r in eng.slot_req if r is not None]
+        if rng.random() < 0.1 and live:  # an EOS or a cancel, mid-block
+            before = eng._timeline()
+            live[int(rng.integers(len(live)))].cancelled = True
+            assert (eng._timeline() <= before).all()
+    assert refused and lacked == refused, (refused, lacked)
+    assert _all_given_back(eng)
+
+
+def test_the_speculative_engine_still_draws_whole():
+    """A speculative step advances a slot by what its drafts are worth, so no
+    timeline knows its positions: there admission counts every resident at its
+    end — the sum of the whole reservations, as it always did — and a slot
+    draws whole at once, through the same drawing path."""
+    from ray_tpu.llm.engine import _Request
+    from ray_tpu.llm.programs import PageKind
+
+    kinds = (PageKind("kv", 1, 16),)
+    spec, plain = (_bookkeeper(kinds, [21], B=4, spec_enable=on)
+                   for on in (True, False))
+    for eng in (spec, plain):
+        eng.waiting = [_Request(i, [1] * 16, 48, 0.0, 0) for i in range(4)]
+        while eng.waiting and eng._reserve_slot(eng.waiting[0]) is not None:
+            eng.waiting.pop(0)
+    # 20 pages: two whole requests of 8, or the prompts of four that the
+    # timeline refuses all the same (their ends coincide: 4 x 8 at the peak)
+    assert [len(e.waiting) for e in (spec, plain)] == [2, 2]
+    assert [int(np.count_nonzero(e.tables[0])) for e in (spec, plain)] == [16, 4]
+    assert list(spec._timeline()) == list(plain._timeline()) == [16]
+    assert len(spec.free[0]) == 4 and not spec._growth(4)[0].any()
+    # what the scheduler is told: pages not yet drawn are not pages to spare
+    room = plain.headroom()
+    assert (room["free_pages"], room["free_pages_now"]) == (4, 16)
+    assert room["free_pages_by_kind"] == {"kv": 4}
+
+
+def test_a_request_of_one_token_draws_the_page_a_step_run_on_writes():
+    """A request of ONE token is all dispatched at admission, and the loop
+    runs on a step a block until that token is on the host: such a step
+    writes the token's position, which a prompt of whole pages leaves on a
+    page the prompt did not draw. The benchmark's replicas read the state
+    that those steps leave (``served(1)``): the page must be the slot's own,
+    not the junk page every dead slot writes."""
+    from ray_tpu.llm.engine import _Request
+    from ray_tpu.llm.programs import PageKind
+
+    eng = _bookkeeper((PageKind("kv", 1, 16),), [9], B=2)
+    eng.waiting = [_Request(1, [1] * 8, 1, 0.0, 0)]
+    assert _admit(eng) is None and np.count_nonzero(eng.tables[0]) == 1
+    assert list(eng._timeline()) == [2] == eng._pages_of(8 + 1)
+    _block(eng, K=1)  # the step run on: position 8, the second page
+    assert np.count_nonzero(eng.tables[0][0]) == 2
+    _block(eng, K=1)  # and no page past the request's end
+    assert np.count_nonzero(eng.tables[0][0]) == 2
 
 
 def _eos_case(first, rest):

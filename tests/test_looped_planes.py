@@ -107,14 +107,16 @@ _REQS = [(10, 14), (17, 7), (20, 12), (5, 9), (9, 10), (14, 10)]
 
 def _serve_logged(eng, reqs):
     """Serve ``reqs`` submitted at once; every try of ``_reserve_slot`` is
-    logged as (request id, admitted, pages free before, slots free before)."""
+    logged as (request id, admitted, pages free before, slots free before,
+    whether the timeline of demand with the request in it fits the pool)."""
     log, reserve = [], eng._reserve_slot
 
     def logged(req):
         free, empty = len(eng.free[0]), sum(r is None for r in eng.slot_req)
+        fits = bool((eng._timeline(req) <= eng.capacity).all())
         slot = reserve(req)
         assert len(eng.free[0]) >= 0
-        log.append((req.req_id, slot is not None, free, empty))
+        log.append((req.req_id, slot is not None, free, empty, fits))
         return slot
 
     eng._reserve_slot = logged
@@ -139,27 +141,37 @@ def _undrained():
 
 @pytest.mark.parametrize("eos", [None, 1000], ids=["planned", "reactive"])
 def test_admission_when_the_pages_run_dry(eos):
-    """Four slots and pages for two requests: six requests are served in the
-    order they came, a slot never holds a page that is not free, slots stand
-    empty while the head of the queue waits for PAGES (the request behind it
-    that would fit does not jump it), a refill happens behind a block as soon
-    as an end frees pages enough and not before, and every reply is the
-    unbounded engine's."""
+    """Four slots and pages for two whole requests: six requests are served in
+    the order they came, a slot never holds a page that is not free, slots
+    stand empty while the head of the queue waits for PAGES (the request
+    behind it that would fit does not jump it), a refill happens behind a
+    block as soon as the TIMELINE of demand has room and not before — which
+    is not when the free pages cover the request whole: a slot holds the
+    pages its positions have reached — and every reply is the unbounded
+    engine's."""
     want, _ = _serve_logged(_engine(eos_id=eos, n_pages=49), _REQS)
     eng = _engine(eos_id=eos, n_pages=7)   # 6 pages to draw: two requests
     before = _undrained()
     got, log = _serve_logged(eng, _REQS)
     assert got == want
     assert len(eng.free[0]) == 6 and not any(t.any() for t in eng.tables)
-    need = {i + 1: -(-(n + m) // PS) for i, (n, m) in enumerate(_REQS)}
-    admitted = [rid for rid, ok, _, _ in log if ok]
+    need = {i + 1: (-(-n // PS), -(-(n + m) // PS))      # at admission, at its end
+            for i, (n, m) in enumerate(_REQS)}
+    admitted = [rid for rid, ok, *_ in log if ok]
     assert admitted == sorted(need)                      # in order, each once
-    for rid, ok, free, empty in log:
-        assert ok == (free >= need[rid] and empty > 0)   # never over-drawn
+    for rid, ok, free, empty, fits in log:
+        assert ok == (fits and empty > 0)
+        assert not ok or free >= need[rid][0]            # never over-drawn
     # the head waited for pages beside empty slots, and nobody jumped it
-    waited = [(rid, free, empty) for rid, ok, free, empty in log if not ok]
+    waited = [(rid, free, empty) for rid, ok, free, empty, _ in log if not ok]
     assert any(empty >= 2 for _, _, empty in waited)
-    assert any(rid == 3 and free == 3 for rid, free, _ in waited)  # 4 > 3 >= 2
+    # request 3 (3 pages of prompt, 4 at its end) is refused beside 3 free
+    # pages: request 1 holds the other 3 to an end that lies after the step
+    # at which 3 would draw its fourth. Request 5 (2, then 3) enters on 2
+    # free pages: request 3 ends before 5 reaches its third
+    assert any(rid == 3 and free == 3 for rid, free, _ in waited)
+    assert any(ok and rid == 5 and free == 2 < need[5][1]
+               for rid, ok, free, *_ in log)
     assert _undrained() > before   # refilled behind a block in flight
 
 

@@ -266,8 +266,8 @@ def test_engine_stats_carries_the_table_only_under_a_profiler_trace(tmp_path):
     out, plain, traced, after = asyncio.run(go())
     assert len(out["completion_tokens"]) == 4
     assert set(plain) == set(after) == {
-        "steps", "tokens_out", "waiting", "free_pages", "weights_prepared",
-        "program_builds", "stages"}
+        "steps", "tokens_out", "waiting", "free_pages", "free_pages_now",
+        "weights_prepared", "program_builds", "stages"}
     assert len(plain["program_builds"]) == len(server.engine._compiled)
     assert plain["weights_prepared"] == 1
     assert set(traced) == set(plain) | {"program_parts"}
