@@ -6,10 +6,12 @@ there Ray wires vLLM; here the engine is owned). Design maps the vLLM
 ideas onto XLA's static-shape world:
 
 * **Fixed decode slots, one admission path.** A decode block advances ALL
-  ``max_batch`` slots (inactive ones masked). A request's end by ``max_tokens``
-  is SCHEDULED when its last block is dispatched: its slot is swept, refilled
-  by a prefill queued BEHIND that block and merged into the carry on the device;
-  no block is waited for. Only an EOS or a cancel, found at emission, drains.
+  ``max_batch`` slots (inactive ones masked) by its ``K`` steps in one
+  program. A request's end by ``max_tokens`` is SCHEDULED when its last block
+  is dispatched: its slot is swept then, refilled by a prefill queued BEHIND
+  that block, and the newcomer's first token and length are merged into the
+  carry on the device; no block is waited for. Only an EOS or a cancel, which
+  emission alone finds, drains.
 * **Paged cache.** The family's pools (``self.cache``: K and V ``[layers,
   n_pages, page_size, kv, hd]`` for the Llama family, other pools for
   others) and, for each KIND of page the family declares, a page table a
@@ -503,6 +505,102 @@ class ContinuousBatchingEngine:
                         m, {"kind": self.kinds[i].name})
         return True
 
+    def _place(self, i: int, slot: int, have: int, m: int, rest: int):
+        """Take ``m`` free pages of kind ``i`` for ``slot``'s table entries
+        from ``have`` on, so that the walks' runs survive
+        (``ops/paged_attention.py`` ``run_lengths``: consecutive pool pages
+        are one copy): the page after the slot's last one while that is free,
+        else from the start of a hole (``_hole``) for the ``rest`` of the
+        request — these ``m`` and what it draws until its end. The count is
+        the timeline's; the place is best effort."""
+        free, out = self.free[i].is_free, np.empty(m, np.int32)
+        at = int(self.tables[i][slot, have - 1]) + 1 if have else 0
+        got = 0
+        while got < m:
+            if not (at and at < len(free) and free[at]):
+                at = self._hole(i, slot, rest - got)
+            run = free[at:at + m - got]
+            n = len(run) if run.all() else int(run.argmin())
+            out[got:got + n] = np.arange(at, at + n)
+            free[at:at + n] = False
+            at, got = at + n, got + n
+        self.free[i].count -= m
+        return out
+
+    def _hole(self, i: int, slot: int, rest: int) -> int:
+        """Where ``slot`` starts a new run of kind ``i``: the first free run
+        that holds ``rest`` pages, else the longest — of the pages no OTHER
+        slot is about to grow into (every resident's hole: as many pages
+        after its last one as it still draws), and only where none of those
+        is left, of all the free pages. Where pages do not bind, each slot so
+        grows inside a hole of its own and its table is one run; where they
+        do, the pool is shared in time and not in place, and a table is what
+        it gets (the looped family's walk, the one cell where they do, reads
+        pages of 64 KB at the same speed scattered: PERF.md section 6, PR 58)."""
+        free = self.free[i].is_free
+        spare = free.copy()
+        for other, r in enumerate(self.slot_req):
+            if r is None or other == slot:
+                continue
+            row = self.tables[i][other]
+            have = int(np.count_nonzero(row))
+            more = int(self._pages_of(len(r.prompt) + r.max_tokens)[i]) - have
+            if have and more > 0:
+                spare[row[have - 1] + 1:row[have - 1] + 1 + more] = False
+        for mask in (spare, free):
+            edges = np.flatnonzero(np.diff(mask, prepend=False, append=False))
+            starts, lengths = edges[::2], edges[1::2] - edges[::2]
+            if len(starts):
+                return int(starts[np.argmax(lengths >= rest) if
+                                  (lengths >= rest).any() else lengths.argmax()])
+        raise AssertionError("no free page: _draw counts before it places")
+
+    def _count_pages(self, i: int, drawn: int = 0) -> None:
+        """Kind ``i``'s pages drawn and held, where a family has kinds."""
+        if len(self.kinds) == 1:
+            return
+        tags = {"kind": self.kinds[i].name}
+        if drawn:
+            metrics.llm_pages_drawn_total.inc(drawn, tags)
+        metrics.llm_pages_held.set(
+            self.capacity[i] - len(self.free[i]), tags)
+
+    @staticmethod
+    def _reach(r: _Request, K: int) -> int:
+        """The positions a block of ``K`` steps draws pages for in ``r``'s
+        slot: none past its end — where a request all dispatched stays, the
+        steps the loop runs on included (a request of ONE token so draws the
+        page of that token's position, which a step run on writes) — and none
+        at all (0) once it is cancelled or over."""
+        if r.cancelled:
+            return 0
+        return len(r.prompt) + min(r.planned + K, r.max_tokens)
+
+    def _growth(self, K: int) -> list:
+        """Pages of each kind that every slot draws before a block of ``K``
+        steps ([B] a kind): what the positions the block reaches hold — none
+        past the request's end — less what the slot's table holds."""
+        n = np.array([0 if r is None else self._reach(r, K)
+                      for r in self.slot_req], np.int64)
+        return [np.maximum(pages - np.count_nonzero(t, axis=1), 0)
+                for pages, t in zip(self._pages_of(n), self.tables)]
+
+    def _lacking(self, K: int) -> bool:
+        """Whether a block of ``K`` steps would step into a page that the
+        free pages cannot give this instant."""
+        return any(g.sum() > len(f)
+                   for g, f in zip(self._growth(K), self.free))
+
+    def _grow(self, K: int) -> None:
+        """Draw, for every slot that steps in a block of ``K``, the pages the
+        block steps into. A table entry still 0 there would send the step's K
+        and V to the junk page, lost without a sound: hence the assertion."""
+        for slot in np.flatnonzero(np.any(self._growth(K), axis=0)):
+            r = self.slot_req[slot]
+            drawn = self._draw(slot, self._reach(r, K),
+                               len(r.prompt) + r.max_tokens, grown=True)
+            assert drawn, f"slot {slot} steps into a page nobody can give it"
+
     def _release_pages(self, slot: int, reached: int) -> None:
         """Give back every page that ``slot``'s tables hold, all kinds.
         ``reached``: its positions, for the count of pages a ring reused."""
@@ -516,13 +614,6 @@ class ContinuousBatchingEngine:
                     max(0, -(-reached // (self.PS * kind.stride)) - kind.table))
         self.seq_lens[slot] = 0
 
-    def _sync_oldest(self, pending: list) -> None:
-        """Wait for the oldest entry in flight, wave or block, and emit it."""
-        kind, *entry = pending.pop(0)
-        if kind == "prefill":
-            return self._emit_first(*entry)
-        self._emit_block(tuple(entry))
-
     def _finish_stream(self, req: _Request) -> None:
         """Unregister a request and close its token stream: live -> the
         bounded finished-awaiting-drain map (stream() can still reach the
@@ -533,6 +624,46 @@ class ContinuousBatchingEngine:
         while len(self._done) > self._done_cap:
             self._done.popitem(last=False)
         req.out.put_nowait(None)
+
+    def _slot_for_the_head(self) -> bool:
+        """Whether someone waits and a slot stands empty: the turns on which
+        the loop tries an admission. A turn on which the head of the queue
+        waits for a SLOT is counted here, one on which it waits for pages
+        where the timeline refuses it (``_reserve_slot``)."""
+        if not self.waiting:
+            return False
+        if any(r is None for r in self.slot_req):
+            return True
+        metrics.llm_admit_deferred_total.inc(1, {"for": "slots"})
+        return False
+
+    def _timeline(self, req: _Request | None = None):
+        """The most pages of each kind ([kinds]) that the residents, and
+        ``req`` admitted beside them, hold at any step still to be dispatched
+        — what admission checks against the pool. Every live slot advances one
+        position a dispatched step, and a request's end is known (``planned``,
+        ``max_tokens``): with ``p = len(prompt) + planned`` positions drawn for
+        and ``r = max_tokens - planned`` steps to go, the step ``t`` from now
+        finds the pages of ``p + min(t + a, r)`` positions in every slot with
+        ``r >= t`` and the others swept. That sum rises only between ends, so
+        it is taken at the ends alone (and at 0: what is held now). ``a``, how
+        far ahead of its step a slot draws, is nothing — a block draws what it
+        steps into, and ``_pick_block`` sizes it to the free pages — but in the
+        speculative loop, whose slots draw whole: there ``a`` is past every
+        end. An EOS or a cancel only frees pages sooner."""
+        # a newcomer's first token is planned before its first block is
+        # dispatched (``_admit_behind``)
+        pr = [(len(q.prompt) + max(q.planned, 1),
+               0 if q.cancelled else q.max_tokens - max(q.planned, 1))
+              for q in (*self.slot_req, req) if q is not None]
+        if not pr:
+            return np.zeros(len(self.kinds), np.int64)
+        p, r = np.array(pr, np.int64).T
+        ends = np.unique(np.append(r, 0))[:, None]  # [ends, 1] against [slots]
+        a = self.MAXP * self.PS if self.spec_enable else 0
+        held = [(pages * (r >= ends)).sum(axis=1).max() for pages in
+                self._pages_of(p + np.minimum(ends + a, r))]
+        return np.array(held, np.int64)
 
     def _reserve_slot(self, req: _Request) -> int | None:
         """Claim a slot for one waiting request and draw its prompt's pages,
@@ -574,8 +705,7 @@ class ContinuousBatchingEngine:
         under ``engine.compile`` and opens ``ph`` again for the call. The
         compiled text's layer parts (``program_parts``) are read by
         ``_PARTS_READER`` beside the program's first run (0.1-0.6 s a program,
-        which the loop does not wait for). The thunk's line AND columns are in
-        the compile-cache key of every program with a Mosaic call: keep them."""
+        which the loop does not wait for)."""
         # what can differ between two calls under one engine: an array's
         # shape (pad and wave buckets) and the static step counts
         key = (fn, *(a if isinstance(a, int) else getattr(a, "shape", None)
@@ -988,6 +1118,28 @@ class ContinuousBatchingEngine:
         self._last_kv = {"kv_live": round(live / K, 2),
                          "kv_read": round(read / K, 2)}
 
+    @staticmethod
+    def _rows_within(kind: PageKind, lens):
+        """Of ``lens`` positions ([slots, steps], the query's own included),
+        how many ROWS of ``kind`` a step attends: every position; as many as
+        the kind reaches back from the query (a sliding window); or, where
+        the reach is ``aligned``, those since its last multiple (a kind of a
+        row a position) and one row a ``stride`` of the whole reaches before
+        it (a strided kind)."""
+        if kind.reach is None:
+            return lens
+        if not kind.aligned:
+            return np.minimum(lens, kind.reach)
+        whole = (lens - 1) // kind.reach * kind.reach
+        return lens - whole if kind.stride == 1 else whole // kind.stride
+
+    def _attended(self, reach):
+        """Of the positions within reach ([slots, steps]), how many a decode
+        step's attention attends: all of them, or at most what a family that
+        picks its keys says (``ServePrograms.attends_most``)."""
+        most = self.programs.attends_most
+        return reach if most is None else np.minimum(reach, most(self.cfg))
+
     def _observe_stats(self, rows) -> None:
         """A synced block's per-step sums of the model's programs
         (``ServePrograms.stats``, [K, n] int32) into the ``rt_llm_*_total``
@@ -1055,6 +1207,15 @@ class ContinuousBatchingEngine:
                     jnp.asarray(first), jnp.asarray(slots), jnp.asarray(lens))
             pending.append(("prefill", reqs, first))
         return carry
+
+    def _sync_oldest(self, pending: list) -> None:
+        """Wait for the oldest entry in flight and emit it: ``("prefill",
+        requests, first tokens)`` a wave admitted behind the blocks,
+        ``("block", K, tokens, the slots' requests)`` a decode block."""
+        kind, *entry = pending.pop(0)
+        if kind == "prefill":
+            return self._emit_first(*entry)
+        self._emit_block(tuple(entry))
 
     async def _yield(self) -> None:
         """One turn of the event loop for everything else the replica
@@ -1405,167 +1566,6 @@ class ContinuousBatchingEngine:
                 drain()
                 carry = None
             await self._yield()
-
-    def _attended(self, reach):
-        """Of the positions within reach ([slots, steps]), how many a decode
-        step's attention attends: all of them, or at most what a family that
-        picks its keys says (``ServePrograms.attends_most``). Read by
-        ``_observe_kv_reads``; down here so that no line above moves (the
-        decode programs' compile-cache keys hold the loops' line numbers:
-        PERF.md section 7)."""
-        most = self.programs.attends_most
-        return reach if most is None else np.minimum(reach, most(self.cfg))
-
-    @staticmethod
-    def _rows_within(kind: PageKind, lens):
-        """Of ``lens`` positions ([slots, steps], the query's own included),
-        how many ROWS of ``kind`` a step attends: every position; as many as
-        the kind reaches back from the query (a sliding window); or, where
-        the reach is ``aligned``, those since its last multiple (a kind of a
-        row a position) and one row a ``stride`` of the whole reaches before
-        it (a strided kind). Down here for ``_attended``'s reason."""
-        if kind.reach is None:
-            return lens
-        if not kind.aligned:
-            return np.minimum(lens, kind.reach)
-        whole = (lens - 1) // kind.reach * kind.reach
-        return lens - whole if kind.stride == 1 else whole // kind.stride
-
-    def _count_pages(self, i: int, drawn: int = 0) -> None:
-        """Kind ``i``'s pages drawn and held, where a family has kinds."""
-        if len(self.kinds) == 1:
-            return
-        tags = {"kind": self.kinds[i].name}
-        if drawn:
-            metrics.llm_pages_drawn_total.inc(drawn, tags)
-        metrics.llm_pages_held.set(
-            self.capacity[i] - len(self.free[i]), tags)
-
-    def _slot_for_the_head(self) -> bool:
-        """Whether someone waits and a slot stands empty: the turns on which
-        the loop tries an admission. A turn on which the head of the queue
-        waits for a SLOT is counted here, one on which it waits for pages
-        where the timeline refuses it (``_reserve_slot``)."""
-        if not self.waiting:
-            return False
-        if any(r is None for r in self.slot_req):
-            return True
-        metrics.llm_admit_deferred_total.inc(1, {"for": "slots"})
-        return False
-
-    def _timeline(self, req: _Request | None = None):
-        """The most pages of each kind ([kinds]) that the residents, and
-        ``req`` admitted beside them, hold at any step still to be dispatched
-        — what admission checks against the pool. Every live slot advances one
-        position a dispatched step, and a request's end is known (``planned``,
-        ``max_tokens``): with ``p = len(prompt) + planned`` positions drawn for
-        and ``r = max_tokens - planned`` steps to go, the step ``t`` from now
-        finds the pages of ``p + min(t + a, r)`` positions in every slot with
-        ``r >= t`` and the others swept. That sum rises only between ends, so
-        it is taken at the ends alone (and at 0: what is held now). ``a``, how
-        far ahead of its step a slot draws, is nothing — a block draws what it
-        steps into, and ``_pick_block`` sizes it to the free pages — but in the
-        speculative loop, whose slots draw whole: there ``a`` is past every
-        end. An EOS or a cancel only frees pages sooner."""
-        # a newcomer's first token is planned before its first block is
-        # dispatched (``_admit_behind``)
-        pr = [(len(q.prompt) + max(q.planned, 1),
-               0 if q.cancelled else q.max_tokens - max(q.planned, 1))
-              for q in (*self.slot_req, req) if q is not None]
-        if not pr:
-            return np.zeros(len(self.kinds), np.int64)
-        p, r = np.array(pr, np.int64).T
-        ends = np.unique(np.append(r, 0))[:, None]  # [ends, 1] against [slots]
-        a = self.MAXP * self.PS if self.spec_enable else 0
-        held = [(pages * (r >= ends)).sum(axis=1).max() for pages in
-                self._pages_of(p + np.minimum(ends + a, r))]
-        return np.array(held, np.int64)
-
-    def _growth(self, K: int) -> list:
-        """Pages of each kind that every slot draws before a block of ``K``
-        steps ([B] a kind): what the positions the block reaches hold — none
-        past the request's end — less what the slot's table holds."""
-        n = np.array([0 if r is None else self._reach(r, K)
-                      for r in self.slot_req], np.int64)
-        return [np.maximum(pages - np.count_nonzero(t, axis=1), 0)
-                for pages, t in zip(self._pages_of(n), self.tables)]
-
-    @staticmethod
-    def _reach(r: _Request, K: int) -> int:
-        """The positions a block of ``K`` steps draws pages for in ``r``'s
-        slot: none past its end — where a request all dispatched stays, the
-        steps the loop runs on included (a request of ONE token so draws the
-        page of that token's position, which a step run on writes) — and none
-        at all (0) once it is cancelled or over."""
-        if r.cancelled:
-            return 0
-        return len(r.prompt) + min(r.planned + K, r.max_tokens)
-
-    def _lacking(self, K: int) -> bool:
-        """Whether a block of ``K`` steps would step into a page that the
-        free pages cannot give this instant."""
-        return any(g.sum() > len(f)
-                   for g, f in zip(self._growth(K), self.free))
-
-    def _grow(self, K: int) -> None:
-        """Draw, for every slot that steps in a block of ``K``, the pages the
-        block steps into. A table entry still 0 there would send the step's K
-        and V to the junk page, lost without a sound: hence the assertion."""
-        for slot in np.flatnonzero(np.any(self._growth(K), axis=0)):
-            r = self.slot_req[slot]
-            drawn = self._draw(slot, self._reach(r, K),
-                               len(r.prompt) + r.max_tokens, grown=True)
-            assert drawn, f"slot {slot} steps into a page nobody can give it"
-
-    def _place(self, i: int, slot: int, have: int, m: int, rest: int):
-        """Take ``m`` free pages of kind ``i`` for ``slot``'s table entries
-        from ``have`` on, so that the walks' runs survive
-        (``ops/paged_attention.py`` ``run_lengths``: consecutive pool pages
-        are one copy): the page after the slot's last one while that is free,
-        else from the start of a hole (``_hole``) for the ``rest`` of the
-        request — these ``m`` and what it draws until its end. The count is
-        the timeline's; the place is best effort."""
-        free, out = self.free[i].is_free, np.empty(m, np.int32)
-        at = int(self.tables[i][slot, have - 1]) + 1 if have else 0
-        got = 0
-        while got < m:
-            if not (at and at < len(free) and free[at]):
-                at = self._hole(i, slot, rest - got)
-            run = free[at:at + m - got]
-            n = len(run) if run.all() else int(run.argmin())
-            out[got:got + n] = np.arange(at, at + n)
-            free[at:at + n] = False
-            at, got = at + n, got + n
-        self.free[i].count -= m
-        return out
-
-    def _hole(self, i: int, slot: int, rest: int) -> int:
-        """Where ``slot`` starts a new run of kind ``i``: the first free run
-        that holds ``rest`` pages, else the longest — of the pages no OTHER
-        slot is about to grow into (every resident's hole: as many pages
-        after its last one as it still draws), and only where none of those
-        is left, of all the free pages. Where pages do not bind, each slot so
-        grows inside a hole of its own and its table is one run; where they
-        do, the pool is shared in time and not in place, and a table is what
-        it gets (the looped family's walk, the one cell where they do, reads
-        pages of 64 KB at the same speed scattered: PERF.md section 6, PR 58)."""
-        free = self.free[i].is_free
-        spare = free.copy()
-        for other, r in enumerate(self.slot_req):
-            if r is None or other == slot:
-                continue
-            row = self.tables[i][other]
-            have = int(np.count_nonzero(row))
-            more = int(self._pages_of(len(r.prompt) + r.max_tokens)[i]) - have
-            if have and more > 0:
-                spare[row[have - 1] + 1:row[have - 1] + 1 + more] = False
-        for mask in (spare, free):
-            edges = np.flatnonzero(np.diff(mask, prepend=False, append=False))
-            starts, lengths = edges[::2], edges[1::2] - edges[::2]
-            if len(starts):
-                return int(starts[np.argmax(lengths >= rest) if
-                                  (lengths >= rest).any() else lengths.argmax()])
-        raise AssertionError("no free page: _draw counts before it places")
 
 
 class _FreePages(collections.abc.Sequence):

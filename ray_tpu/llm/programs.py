@@ -121,8 +121,9 @@ class ServePrograms:
     hands one (``page_tables`` in decode, ``pages`` in prefill, in the
     kinds' order); None is one kind for every layer.
     ``prefill_wave_limit = (prompts, tokens)``: the most one prefill program
-    may hold, so a pad group is split; None splits nothing. ``attends_most(cfg) -> int``: the most positions a decode
-    step's attention ATTENDS of a slot however many it fetches (a model that
+    may hold, so a pad group is split; None splits nothing.
+    ``attends_most(cfg) -> int``: the most positions a decode step's
+    attention ATTENDS of a slot however many it fetches (a model that
     picks its keys: the read counters then say what was attended and what
     was fetched for it); None attends everything within reach.
     ``lora(cfg, adapters, rank) -> (stack, name -> index)`` stacks named
@@ -199,6 +200,18 @@ def decode_frame(body, params, tokens, seq_lens, tables, cache, active, temps,
     return (rows, tok, pos, *cache)
 
 
+@jax.jit
+def merge_carry(tokens, seq_lens, first, slots, lens):
+    """A prefill wave's first tokens and prompt lengths into the decode
+    carry, ON THE DEVICE: what the engine's loops do at every admission, so
+    that no block in flight is waited for. tokens, seq_lens: [B], the carry
+    between blocks; first: [N], the prefill program's own output, dummy rows
+    included; slots, lens: [N], each prompt's slot and length, a dummy row's
+    slot out of range (dropped). One program a wave bucket ``N``."""
+    return (tokens.at[slots].set(first, mode="drop"),
+            seq_lens.at[slots].set(lens, mode="drop"))
+
+
 def last_rows(x, true_lens):
     """Each prompt's row at its last true position. x: [N, T, D] -> [N, D]."""
     return jnp.take_along_axis(
@@ -220,17 +233,3 @@ def _sample_tail(logits, temps, key):
         return jnp.where(temps > 0, s, greedy)
 
     return jax.lax.cond(jnp.any(temps > 0), sampled, lambda: greedy)
-
-
-@jax.jit
-def merge_carry(tokens, seq_lens, first, slots, lens):
-    """A prefill wave's first tokens and prompt lengths into the decode
-    carry, ON THE DEVICE: what the engine's loops do at every admission, so
-    that no block in flight is waited for. tokens, seq_lens: [B], the carry
-    between blocks; first: [N], the prefill program's own output, dummy rows
-    included; slots, lens: [N], each prompt's slot and length, a dummy row's
-    slot out of range (dropped). One program a wave bucket ``N``. Down here
-    so that no line above moves (``decode_frame`` lies in the call stack of
-    every decode kernel, and so in its compile-cache key)."""
-    return (tokens.at[slots].set(first, mode="drop"),
-            seq_lens.at[slots].set(lens, mode="drop"))

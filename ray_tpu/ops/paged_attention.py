@@ -8,7 +8,7 @@ slot's length is contracted. Two callers, one walk:
 
 * ``paged_decode_attention`` — a K pool ``[L, P, PS, KV, hk]`` and a V pool
   ``[.., hv]`` (``llm/llama.py``: what ``_kv_read`` + ``_gqa_attn`` do; V may be
-  narrower and a K row wider than q, below). A page of a layer is one run.
+  narrower and a K row wider than q: below). A page of a layer is one run.
 * ``paged_latent_attention`` — ONE pool ``[L, P, PS, W]`` whose rows are the
   keys as they lie and whose first ``v_width`` lanes are the values (MLA's
   latent cache ``[c | k_rope]``, ``llm/mla_moe.py``; the window
@@ -27,6 +27,20 @@ PART of a softmax that runs over more than one table
 (``paged_attention_part``: the plain walk or the ring's, with its running
 maximum and sum beside its output; ``merge_attention_parts`` joins such parts
 exactly).
+
+Keys may be wider than values (192 | 128 lanes, ``llm/sink_moe.py``):
+
+* Each pool has a VMEM buffer as wide as its OWN rows (``_buffers``); the
+  output is as wide as a value (``v_width`` from the V pool); the scores are
+  over ``sqrt`` of the QUERY's width.
+* A K row may be wider than the query — a 192-lane head kept in the 256 lanes
+  the device's layout would pad it to anyway (``benchmarks/
+  sizing_sink_moe.py --layout``), the lanes past the head's zeros — and the
+  query is padded with zeros to meet it: a page is then whole lane tiles, one
+  plain run, and a walk's copy of it one descriptor.
+* A learned SINK, one score a query head that takes mass and gives no value,
+  needs nothing of the kernel: it is the part ``(0, sink, 1)`` beside the
+  walk's own ``paged_attention_part``, joined by ``merge_attention_parts``.
 
 One kernel invocation serves every slot: a work list of (slot, block) items
 runs through two VMEM buffers — the next item's page copies are in flight
@@ -106,6 +120,20 @@ def kv_block(pool, MAXP: int, vpool=None):
     token = KV * (_lanes(pool) + _lanes(v)) * pool.dtype.itemsize
     n_pages = _block_pages(_BLOCK_BYTES // token // PS, MAXP)
     return n_pages, _RUN_PAGES if n_pages % _RUN_PAGES == 0 else n_pages
+
+
+def _lanes(pool) -> int:
+    """The lanes a row of ``pool`` lies in: whole tiles of 128."""
+    return -(-pool.shape[-1] // 128) * 128
+
+
+def _block_pages(n_pages: int, MAXP: int) -> int:
+    """``n_pages`` by the bytes, in whole sub-runs where that is more than
+    one (rows of unequal width: 21 pages at 4 KV heads of 256 | 128 lanes are
+    16; every power of two stays what it is), at least 1, at most the table."""
+    if n_pages > _RUN_PAGES:
+        n_pages -= n_pages % _RUN_PAGES
+    return max(1, min(n_pages, MAXP))
 
 
 def walk_copies(runs, unit: int, pages_live):
@@ -483,8 +511,9 @@ def _walk_pools(q, pools, layer, page_tables, lengths, *, v_width: int,
     """The one ``pallas_call`` every entry makes: the pools stay where they
     are (``pl.ANY``), a VMEM buffer of two blocks a pool. ``block`` is
     (pages a block, pages a sub-run: ``kv_block``; none for the latent
-    pool, whose rows come with padding). ``starts`` is one more scalar-prefetched array, and a
-    ring table (``_kernel``); so is ``runs``, the table's ``run_lengths``.
+    pool, whose rows come with padding). ``starts`` is one more
+    scalar-prefetched array, and a ring table (``_kernel``); so is ``runs``,
+    the table's ``run_lengths``.
     ``selected`` is one more input in VMEM: float 0 / 1 a ROW of the blocks
     (a position's pick repeated over its KV heads here, in XLA: a repeat
     that interleaves lanes is no vector operation of the kernel's), whole
@@ -560,6 +589,21 @@ def _walk_pools(q, pools, layer, page_tables, lengths, *, v_width: int,
       *picks, q, *pools)
 
 
+def _buffers(pools, n_pages: int, lanes: int, sub: bool):
+    """(a VMEM buffer of two blocks a pool, the bytes of ONE table entry's
+    page over the pools): ``lanes`` wide for the first pool and every pool
+    like it, as wide as its own rows for a narrower one."""
+    width = pools[0].shape[-1]
+    bufs = []
+    for pool in pools:
+        page = pool.shape[2:]
+        w = lanes if page[-1] == width else page[-1]
+        bufs.append(pltpu.VMEM(
+            (2, n_pages * math.prod(page[:-1]), w) if sub
+            else (2, n_pages, *page[:-1], w), pool.dtype))
+    return bufs, sum(math.prod(p.shape[2:]) * p.dtype.itemsize for p in pools)
+
+
 def paged_attention_part(q, kpool, vpool, layer, page_tables, lengths, *,
                          starts=None, runs=None,
                          interpret: bool | None = None):
@@ -604,50 +648,3 @@ def merge_attention_parts(*parts):
     w = [p[2] * jnp.exp(p[1] - m) for p in parts]
     total = jnp.maximum(sum(w), 1e-30)
     return sum(p[0] * (wi / total)[..., None] for p, wi in zip(parts, w))
-
-
-# ------------------------------------------- keys and values of unequal width
-# What the K and V walk needs where a model's keys are wider than its values
-# (192 | 128 lanes, ``llm/sink_moe.py``), kept BELOW everything above so that
-# not one line of the kernel or of its callers moves: a Mosaic kernel carries
-# the file's line numbers in its payload, and a moved line re-keys every
-# family's programs in the compile cache (PERF.md section 6, PR 54).
-#
-# * Each pool has a VMEM buffer as wide as its OWN rows; the output is as wide
-#   as a value (``v_width`` from the V pool); the scores are over ``sqrt`` of
-#   the QUERY's width.
-# * A K row may be wider than the query — a 192-lane head kept in the 256
-#   lanes the device's layout would pad it to anyway (``benchmarks/
-#   sizing_sink_moe.py --layout``), the lanes past the head's zeros — and the
-#   query is padded with zeros to meet it: a page is then whole lane tiles,
-#   one plain run, and a walk's copy of it one descriptor.
-# * A learned SINK, one score a query head that takes mass and gives no value,
-#   needs nothing of the kernel: it is the part ``(0, sink, 1)`` beside the
-#   walk's own ``paged_attention_part``, joined by ``merge_attention_parts``.
-def _lanes(pool) -> int:
-    """The lanes a row of ``pool`` lies in: whole tiles of 128."""
-    return -(-pool.shape[-1] // 128) * 128
-
-
-def _block_pages(n_pages: int, MAXP: int) -> int:
-    """``n_pages`` by the bytes, in whole sub-runs where that is more than
-    one (rows of unequal width: 21 pages at 4 KV heads of 256 | 128 lanes are
-    16; every power of two stays what it is), at least 1, at most the table."""
-    if n_pages > _RUN_PAGES:
-        n_pages -= n_pages % _RUN_PAGES
-    return max(1, min(n_pages, MAXP))
-
-
-def _buffers(pools, n_pages: int, lanes: int, sub: bool):
-    """(a VMEM buffer of two blocks a pool, the bytes of ONE table entry's
-    page over the pools): ``lanes`` wide for the first pool and every pool
-    like it, as wide as its own rows for a narrower one."""
-    width = pools[0].shape[-1]
-    bufs = []
-    for pool in pools:
-        page = pool.shape[2:]
-        w = lanes if page[-1] == width else page[-1]
-        bufs.append(pltpu.VMEM(
-            (2, n_pages * math.prod(page[:-1]), w) if sub
-            else (2, n_pages, *page[:-1], w), pool.dtype))
-    return bufs, sum(math.prod(p.shape[2:]) * p.dtype.itemsize for p in pools)

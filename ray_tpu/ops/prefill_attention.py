@@ -23,6 +23,16 @@ two things that kernel has not.
   ``models/sparse_moe.py``): the mask comes a block beside the keys, one byte
   a pair, and no float ``[T, T]`` array exists. Every causal block is still
   visited: the picks of a scattered selection touch nearly all of them.
+* **Keys wider than values.** k may be ``KV`` heads of ``hd`` lanes beside
+  v's ``KV`` heads of ``hv`` (192 | 128, ``models/sink_moe.py``): the output
+  is as wide as the values. A head that is not whole lane tiles is padded
+  with zeros to them before the kernel (a 192-lane head costs the MXU its
+  256 either way, and a block of one head must be whole tiles), the scores
+  still over ``sqrt(hd)`` (``gqa_sink_attention``).
+* **A sink.** ``sink`` [H] float is one learned score a query head that
+  joins every softmax as a column with no value: the running maximum and
+  sum of a block of queries START at ``(sink, 1)`` where they start at
+  ``(-inf, 0)``, and nothing else of the kernel knows it.
 """
 from __future__ import annotations
 
@@ -46,10 +56,13 @@ def _key_blocks(i, block_q: int, block_k: int, window: int | None):
     return first, (i * block_q + block_q - 1) // block_k
 
 
-def _kernel(q_ref, k_ref, v_ref, *refs, sm_scale: float, window: int | None, block_q: int,
-            block_k: int, G: int, hd: int, picks: bool = False, hv: int = 0, sink: bool = False):
-    picked_ref, hv = refs[0] if picks else None, hv or hd  # hv: a value head's lanes, the output's
-    sink_ref = refs[int(picks)] if sink else None  # [1, G, 128]: a lane tile a query head's sink
+def _kernel(q_ref, k_ref, v_ref, *refs, sm_scale: float, window: int | None,
+            block_q: int, block_k: int, G: int, hd: int, picks: bool = False,
+            hv: int = 0, sink: bool = False):
+    # hv: a value head's lanes, the output's; the sink: [1, G, 128], a lane
+    # tile a query head
+    picked_ref, hv = refs[0] if picks else None, hv or hd
+    sink_ref = refs[int(picks)] if sink else None
     o_ref, m_scr, l_scr, acc_scr = refs[int(picks) + int(sink):]
     i, j = pl.program_id(2), pl.program_id(3)
     first, last = _key_blocks(i, block_q, block_k, window)
@@ -57,7 +70,8 @@ def _kernel(q_ref, k_ref, v_ref, *refs, sm_scale: float, window: int | None, blo
 
     @pl.when(j == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG_BIG) if not sink else _sink_rows(sink_ref, m_scr)
+        m_scr[...] = (_sink_rows(sink_ref, m_scr) if sink
+                      else jnp.full_like(m_scr, _NEG_BIG))
         l_scr[...] = jnp.zeros_like(l_scr) if not sink else jnp.ones_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
@@ -98,6 +112,13 @@ def _kernel(q_ref, k_ref, v_ref, *refs, sm_scale: float, window: int | None, blo
                 acc_scr[g] / denom[:, None]).astype(o_ref.dtype)
 
 
+def _sink_rows(sink_ref, m_scr):
+    """Where a block of queries' running maximum starts under a sink: each
+    query head's own score, on every row."""
+    return jnp.stack([jnp.broadcast_to(sink_ref[0, g:g + 1, :], m_scr.shape[1:])
+                      for g in range(m_scr.shape[0])])
+
+
 def blocks_for(T: int) -> tuple[int, int] | None:
     """The (queries, keys) block sizes for ``T`` positions, or None where
     ``T`` is not whole blocks of at least 128 (the caller's plain form
@@ -112,7 +133,7 @@ def gqa_prefill_attention(q, k, v, *, n_kv_heads: int, window: int | None = None
     """Causal attention of every position of a prompt over the prompt, the heads
     grouped, optionally within a window. q: [N, T, H * hd]; k: [N, T, KV * hd], v:
     [N, T, KV * hv], ``KV = n_kv_heads`` (hv a multiple of 128; hd too unless ``hv !=
-    hd`` or a ``sink`` [H] is given: ``gqa_sink_attention``, below); query head h reads
+    hd`` or a ``sink`` [H] is given: ``gqa_sink_attention``); query head h reads
     KV head ``h // (H // KV)``. ``window``: position i attends j where ``0 <= i - j <
     window`` (None: every ``j <= i``). ``picked``: [N, T, T] int8 (or bool), i attends
     ``j <= i`` only where set. T whole blocks (``blocks_for``). Returns [N, T, H * hv]
@@ -146,9 +167,39 @@ def _gqa_picked_attention(q, k, v, picked, *, n_kv_heads: int,
     return _blocked(q, k, v, picked, n_kv_heads, None, interpret)
 
 
+def gqa_sink_attention(q, k, v, sink, n_kv_heads: int, window, interpret: bool,
+                       picked=None):
+    """``gqa_prefill_attention`` where k's heads are wider than v's, or a
+    ``sink`` [H] stands in every softmax (or both): q: [N, T, H * hd]; k: [N,
+    T, KV * hd]; v: [N, T, KV * hv], any ``hd``. Returns [N, T, H * hv]."""
+    if picked is not None:
+        raise ValueError("picks beside a sink or unequal widths: no such form")
+    return _gqa_sink_attention(
+        q, k, v, sink, n_kv_heads=int(n_kv_heads),
+        window=None if window is None else int(window),
+        interpret=bool(interpret))
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("n_kv_heads", "window", "interpret"))
+def _gqa_sink_attention(q, k, v, sink, *, n_kv_heads: int,
+                        window: int | None, interpret: bool):
+    """A jit of its own for the reason ``_gqa_prefill_attention`` is one.
+    Heads of q and k that are not whole lane tiles are padded to them here."""
+    N, T, _ = q.shape
+    hd = k.shape[-1] // n_kv_heads
+    short = -hd % _LANES
+    if short:
+        q, k = (jnp.pad(a.reshape(N, T, -1, hd), ((0, 0),) * 3 + ((0, short),)
+                        ).reshape(N, T, -1) for a in (q, k))
+    return _blocked(q, k, v, None, n_kv_heads, window, interpret, sink=sink,
+                    sm_scale=1.0 / math.sqrt(hd))
+
+
 def _blocked(q, k, v, picked, n_kv_heads: int, window: int | None,
              interpret: bool, sink=None, sm_scale: float | None = None):
-    """The one ``pallas_call`` every entry makes (``sink``, ``sm_scale``: below)."""
+    """The one ``pallas_call`` every entry makes (``sink``, ``sm_scale``:
+    ``_gqa_sink_attention``'s)."""
     N, T, HD = q.shape
     KV = n_kv_heads
     hd, hv = k.shape[-1] // KV, v.shape[-1] // KV
@@ -179,7 +230,8 @@ def _blocked(q, k, v, picked, n_kv_heads: int, window: int | None,
             pl.BlockSpec((1, bk, hd), key_block),
             pl.BlockSpec((1, bk, hv), key_block),
         ] + [pl.BlockSpec((1, bq, bk), lambda n, kv, i, j: (
-            n, i, key_block(n, kv, i, j)[1])) for _ in masks[:picked is not None]] + _sink_specs(sink, G),
+            n, i, key_block(n, kv, i, j)[1]))
+             for _ in masks[:picked is not None]] + _sink_specs(sink, G),
         out_specs=pl.BlockSpec((1, bq, G * hv), lambda n, kv, i, j: (n, i, kv)),
         scratch_shapes=[
             pltpu.VMEM((G, bq, _LANES), jnp.float32),
@@ -199,6 +251,21 @@ def _blocked(q, k, v, picked, n_kv_heads: int, window: int | None,
         name="gqa_sink_prefill_attention" if sink is not None else
         "gqa_prefill_attention" if picked is None else "gqa_picked_attention",
     )(q, k, v, *masks)
+
+
+def _sink_tiles(sink, KV: int, G: int) -> tuple:
+    """The sinks as the kernel takes them, [KV, G, 128] float32 — a lane tile
+    a query head, every lane the same — or nothing."""
+    if sink is None:
+        return ()
+    return (jnp.broadcast_to(sink.astype(jnp.float32).reshape(KV, G, 1),
+                             (KV, G, _LANES)),)
+
+
+def _sink_specs(sink, G: int) -> list:
+    """The block of ``_sink_tiles`` a grid cell sees: its KV head's."""
+    return [] if sink is None else [
+        pl.BlockSpec((1, G, _LANES), lambda n, kv, i, j: (kv, 0, 0))]
 
 
 # ------------------------------------------------- exact window + pooled pairs
@@ -347,70 +414,3 @@ def _eva_prefill_attention(q, k, v, kh, vh, *, n_heads: int, window: int,
         interpret=interpret,
         name="eva_prefill_attention",
     )(q, k, v, kh, vh)
-
-
-# --------------------------------------------- keys wider than values, a sink
-# Kept BELOW everything above so that not one line of the kernels or of their
-# callers moves: a Mosaic kernel carries the file's line numbers in its
-# payload, and a moved line re-keys every family's prefill programs in the
-# compile cache (PERF.md section 6, PR 54).
-#
-# * **Keys wider than values.** k may be ``KV`` heads of ``hd`` lanes beside
-#   v's ``KV`` heads of ``hv`` (192 | 128, ``models/sink_moe.py``): the output
-#   is as wide as the values. A head that is not whole lane tiles is padded
-#   with zeros to them before the kernel (a 192-lane head costs the MXU its
-#   256 either way, and a block of one head must be whole tiles), the scores
-#   still over ``sqrt(hd)``.
-# * **A sink.** ``sink`` [H] float is one learned score a query head that
-#   joins every softmax as a column with no value: the running maximum and
-#   sum of a block of queries START at ``(sink, 1)`` where they start at
-#   ``(-inf, 0)``, and nothing else of the kernel knows it.
-def gqa_sink_attention(q, k, v, sink, n_kv_heads: int, window, interpret: bool,
-                       picked=None):
-    """``gqa_prefill_attention`` where k's heads are wider than v's, or a
-    ``sink`` [H] stands in every softmax (or both): q: [N, T, H * hd]; k: [N,
-    T, KV * hd]; v: [N, T, KV * hv], any ``hd``. Returns [N, T, H * hv]."""
-    if picked is not None:
-        raise ValueError("picks beside a sink or unequal widths: no such form")
-    return _gqa_sink_attention(
-        q, k, v, sink, n_kv_heads=int(n_kv_heads),
-        window=None if window is None else int(window),
-        interpret=bool(interpret))
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("n_kv_heads", "window", "interpret"))
-def _gqa_sink_attention(q, k, v, sink, *, n_kv_heads: int,
-                        window: int | None, interpret: bool):
-    """A jit of its own for the reason ``_gqa_prefill_attention`` is one.
-    Heads of q and k that are not whole lane tiles are padded to them here."""
-    N, T, _ = q.shape
-    hd = k.shape[-1] // n_kv_heads
-    short = -hd % _LANES
-    if short:
-        q, k = (jnp.pad(a.reshape(N, T, -1, hd), ((0, 0),) * 3 + ((0, short),)
-                        ).reshape(N, T, -1) for a in (q, k))
-    return _blocked(q, k, v, None, n_kv_heads, window, interpret, sink=sink,
-                    sm_scale=1.0 / math.sqrt(hd))
-
-
-def _sink_tiles(sink, KV: int, G: int) -> tuple:
-    """The sinks as the kernel takes them, [KV, G, 128] float32 — a lane tile
-    a query head, every lane the same — or nothing."""
-    if sink is None:
-        return ()
-    return (jnp.broadcast_to(sink.astype(jnp.float32).reshape(KV, G, 1),
-                             (KV, G, _LANES)),)
-
-
-def _sink_specs(sink, G: int) -> list:
-    """The block of ``_sink_tiles`` a grid cell sees: its KV head's."""
-    return [] if sink is None else [
-        pl.BlockSpec((1, G, _LANES), lambda n, kv, i, j: (kv, 0, 0))]
-
-
-def _sink_rows(sink_ref, m_scr):
-    """Where a block of queries' running maximum starts under a sink: each
-    query head's own score, on every row."""
-    return jnp.stack([jnp.broadcast_to(sink_ref[0, g:g + 1, :], m_scr.shape[1:])
-                      for g in range(m_scr.shape[0])])

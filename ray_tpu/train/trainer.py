@@ -73,15 +73,6 @@ class TrainWorker:
         self._error: str | None = None
         self._session, self._stage = None, ("created", time.monotonic())
 
-    # Keep ``run`` where it stands (its call of the user's loop is line 105).
-    # A program with a Pallas kernel carries, in the kernel's Mosaic payload,
-    # every user frame that reached its lowering — file path, line, columns,
-    # qualified name — and jax's persistent compile cache keys on the
-    # payload (PERF.md §7, ROADMAP D12). A training loop lowers its step
-    # under ``run``'s frame: a line more above it and every training program
-    # with a kernel compiles again, once, on every machine. So new methods
-    # go between ``__init__`` and ``run`` only line for line, or below
-    # (which is why ``setup`` stands below ``poll``, out of reading order).
     def _stand_in(self, name: str):
         """A stage of ``setup``, to be entered with ``with``: timed as
         ``tracing.stage(name)``, and what ``setup_stage`` answers while it
@@ -97,30 +88,6 @@ class TrainWorker:
         (``JaxTrainer._where_workers_stand``)."""
         name, since = self._stage
         return {"stage": name, "seconds": time.monotonic() - since}
-
-    def run(self, train_loop, config: dict):
-        """Blocking execution of the user loop (runs on the actor's executor
-        thread; poll() is served concurrently by the async loop)."""
-        try:
-            self._result = train_loop(config) if config is not None else train_loop()
-            return {"ok": True}
-        except Exception as e:  # noqa: BLE001
-            self._error = f"{type(e).__name__}: {e}\n{traceback.format_exc()}"
-            return {"ok": False, "error": self._error}
-        finally:
-            self._done = True
-
-    def poll(self):
-        """Drain report() outbox (ref: controller _poll_workers :249).
-        _done is read BEFORE draining: a report enqueued between the drain
-        and the done-check would otherwise be lost on the final poll."""
-        done = self._done
-        out = []
-        if self._session is not None:
-            while not self._session.outbox.empty():
-                metrics, ckpt = self._session.outbox.get_nowait()
-                out.append((metrics, ckpt.path if ckpt else None))
-        return {"reports": out, "done": done, "error": self._error}
 
     def setup(self, checkpoint_path: str | None):
         import ray_tpu.collective as collective
@@ -154,6 +121,30 @@ class TrainWorker:
                 )
         self._stage = ("set_up", time.monotonic())
         return True
+
+    def run(self, train_loop, config: dict):
+        """Blocking execution of the user loop (runs on the actor's executor
+        thread; poll() is served concurrently by the async loop)."""
+        try:
+            self._result = train_loop(config) if config is not None else train_loop()
+            return {"ok": True}
+        except Exception as e:  # noqa: BLE001
+            self._error = f"{type(e).__name__}: {e}\n{traceback.format_exc()}"
+            return {"ok": False, "error": self._error}
+        finally:
+            self._done = True
+
+    def poll(self):
+        """Drain report() outbox (ref: controller _poll_workers :249).
+        _done is read BEFORE draining: a report enqueued between the drain
+        and the done-check would otherwise be lost on the final poll."""
+        done = self._done
+        out = []
+        if self._session is not None:
+            while not self._session.outbox.empty():
+                metrics, ckpt = self._session.outbox.get_nowait()
+                out.append((metrics, ckpt.path if ckpt else None))
+        return {"reports": out, "done": done, "error": self._error}
 
 
 #: what a group that did not start waits for its workers to say where they

@@ -26,6 +26,16 @@ The persistent compilation cache is placed here and nowhere else: where
 is set in code; otherwise it is ``<checkout>/.jax_cache``. The path is part
 of nothing but the checkout, so replicas, trainer workers and
 ``chip_smoke.py`` (which inherit the environment) share one cache.
+
+What the cache keys a program on is fixed here too: its operations, shapes
+and ``jax.named_scope`` names, and nothing of where its source lies. jax would
+write the Python frames that reached a lowering (path, line, columns) into
+every location, a Pallas kernel's Mosaic payload carries them and the key
+reads the payload: a line moved above a kernel, or the same tree in another
+directory, would re-key programs whose operations did not change. So no frame
+is written (an operator loses the Python frame in an XLA runtime error's
+location) and the scopes are in the key (``_CACHE_SETTINGS``): a cached
+executable carries its tree's (``tests/test_compile_key.py``).
 """
 
 from __future__ import annotations
@@ -41,6 +51,15 @@ _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 #: in well under jax's 1 s default and would otherwise be recompiled by
 #: every replica
 _CACHE_MIN_COMPILE_SECS = 0.1
+#: the cache's settings, a pinned process's variables (names in upper case)
+_CACHE_SETTINGS = {
+    "jax_persistent_cache_min_compile_time_secs": _CACHE_MIN_COMPILE_SECS,
+    # Python frames in an operation's location (jax's default: 10): with
+    # none, a moved line or another checkout directory changes no key
+    "jax_traceback_in_locations_limit": 0,
+    # the key reads the named scopes: a cached executable has its tree's
+    "jax_compilation_cache_include_metadata_in_key": True,
+}
 
 _configured = False
 _listening = False
@@ -123,8 +142,8 @@ def _place_backend_and_cache() -> None:
         pin_cpu()
         if cache_dir is not None:
             os.environ["JAX_COMPILATION_CACHE_DIR"] = cache_dir
-        os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = str(
-            _CACHE_MIN_COMPILE_SECS)
+        os.environ.update(
+            {name.upper(): str(v) for name, v in _CACHE_SETTINGS.items()})
         return
     import jax
 
@@ -134,8 +153,8 @@ def _place_backend_and_cache() -> None:
         jax.config.update("jax_platforms", platform)
     if cache_dir is not None:
         jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                      _CACHE_MIN_COMPILE_SECS)
+    for name, v in _CACHE_SETTINGS.items():
+        jax.config.update(name, v)
 
 
 def _listen_to_builds() -> None:

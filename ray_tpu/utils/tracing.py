@@ -447,14 +447,9 @@ _BUILD_EVENTS = {
 }
 # per thread: .hit, a cache hit awaits its duration; .tracing, how deep in
 # traces of jitted functions inside jitted functions (jax times each, the
-# outer one's seconds hold the inner ones'); .built, the seconds counted
+# outer one's seconds hold the inner ones'); .built, the seconds counted;
+# .record, the record of the ONE program ``build_in_executor`` builds here
 _build_thread = _threading.local()
-# the record of ONE program that ``build_in_executor`` collects: a context
-# variable, because ``Context.run`` puts no Python frame between the thread
-# and the caller's thunk (a Mosaic payload carries those frames, and the
-# compile cache's key the payload)
-_building: contextvars.ContextVar[dict | None] = contextvars.ContextVar(
-    "rt_building", default=None)
 
 
 def _built_here() -> float:
@@ -497,7 +492,7 @@ def build_duration(event: str, seconds: float, **kw) -> None:
             name = "program_compile_small"
     metrics.bringup_seconds.observe(seconds, {"stage": name})
     _build_thread.built = _built_here() + seconds
-    built = _building.get()
+    built = getattr(_build_thread, "record", None)
     if built is not None:
         field = name.removeprefix("program_").removesuffix("_small") + "_s"
         built[field] += seconds
@@ -511,10 +506,15 @@ async def build_in_executor(executor, thunk):
     obtained: jit's own caches had it (a second engine in one process)."""
     built = dict.fromkeys(("trace_s", "lower_s", "cache_read_s", "compile_s"),
                           0.0)
-    ctx = contextvars.copy_context()
-    ctx.run(_building.set, built)
-    result = await asyncio.get_running_loop().run_in_executor(
-        executor, ctx.run, thunk)
+
+    def build():
+        _build_thread.record = built
+        try:
+            return thunk()
+        finally:
+            _build_thread.record = None
+
+    result = await asyncio.get_running_loop().run_in_executor(executor, build)
     source = ("cache" if built["cache_read_s"] else
               "compiled" if built["compile_s"] else "memory")
     return result, {"source": source, **built}
@@ -681,13 +681,13 @@ def instruction_parts(hlo_text: str) -> tuple[str, dict[str, str]]:
 def compiled_parts(compiled) -> dict:
     """What a reader needs of one compiled program (a ``jax.stages.
     Compiled``): its module's name as a trace prints it, its
-    ``instruction_parts`` and the seconds making them took. ``stale``: no
-    instruction names a part — the executable came from a compile cache
-    whose key leaves the scopes out, written by a tree without them."""
+    ``instruction_parts`` and the seconds making them took. ``stale``: what
+    the benchmark's readers ask of a table, and never true — the compile
+    cache keys on the scopes (``utils/device.py``). A program with no part
+    of its own (``merge_carry``) has an empty table."""
     with stage("parts_table") as reading:
         module, parts = instruction_parts(compiled.as_text())
-    return {"module": module, "parts": parts,
-            "stale": not any(p in PARTS for p in parts.values()),
+    return {"module": module, "parts": parts, "stale": False,
             "seconds": reading.seconds}
 
 
@@ -695,22 +695,16 @@ def merged_parts(variants) -> dict:
     """``compiled_parts`` of a process's programs -> ``{module: {"parts",
     "stale", "variants", "seconds"}}``: the shape variants of one program
     (pads, waves, step counts) as one table, in which a key that two of them
-    give different parts names neither. A program with a stale variant is
-    stale as a whole and has no table: its events must read as unnamed,
-    never as another variant's parts."""
+    give different parts names neither."""
     out: dict[str, dict] = {}
     for v in variants:
         p = out.setdefault(v["module"], {"parts": {}, "stale": False,
                                          "variants": 0, "seconds": 0.0})
         p["variants"] += 1
         p["seconds"] += v["seconds"]
-        p["stale"] |= v["stale"]
         for key, name in v["parts"].items():
             if p["parts"].setdefault(key, name) != name:
                 p["parts"][key] = AMBIGUOUS
-    for p in out.values():
-        if p["stale"]:
-            p["parts"] = {}
     return out
 
 

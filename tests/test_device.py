@@ -138,10 +138,13 @@ sys.path.insert(0, {repo!r})
 from ray_tpu.utils.device import configure_jax
 configure_jax()
 print("jax" in sys.modules)
+print(os.environ.get("JAX_TRACEBACK_IN_LOCATIONS_LIMIT"))
 import jax
 print(jax.config.jax_compilation_cache_dir)
 print(jax.config.jax_persistent_cache_min_compile_time_secs)
 print(jax.config.jax_platforms)
+print(jax.config.jax_traceback_in_locations_limit)
+print(jax.config.jax_compilation_cache_include_metadata_in_key)
 """
 
 
@@ -152,7 +155,10 @@ def test_configure_jax_places_the_cache(var, chips, tmp_path):
     the directory jax reports is the variable's; where it is not, the
     checkout's. A chip-less worker is pinned to the CPU on the way, whatever
     platform its parent exported, and does not pay for importing jax to be
-    so; a chip worker needs jax anyway and lists the TPU first."""
+    so; a chip worker needs jax anyway and lists the TPU first. Either way
+    the process writes no Python frame into a program's locations and keys
+    the cache on its scopes (``tests/test_compile_key.py``): the pinned one
+    through the variable jax reads at import."""
     env = {k: v for k, v in os.environ.items()
            if k not in ("JAX_COMPILATION_CACHE_DIR", "RT_FORCE_CPU_DEVICES",
                         "TPU_VISIBLE_CHIPS")}
@@ -167,8 +173,11 @@ def test_configure_jax_places_the_cache(var, chips, tmp_path):
         [sys.executable, "-c", _CACHE_CHILD.format(repo=REPO)], env=env,
         cwd=tmp_path, capture_output=True, text=True, timeout=120)
     assert out.returncode == 0, out.stderr
-    imported, cache_dir, min_secs, platforms = out.stdout.split()
+    (imported, frames_var, cache_dir, min_secs, platforms, frames,
+     scopes_in_key) = out.stdout.split()
     assert imported == str(bool(chips))
+    assert frames_var == ("None" if chips else "0")
+    assert (frames, scopes_in_key) == ("0", "True")
     assert cache_dir == (var or os.path.join(REPO, ".jax_cache"))
     assert float(min_secs) < 1.0
     assert platforms == ("tpu,cpu" if chips else "cpu")

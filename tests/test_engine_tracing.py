@@ -239,22 +239,6 @@ def test_the_merge_program_is_built_by_the_warm_waves(tiny):
     assert {e[1] for e in log if e[0] == "wave"} <= {1, 2, 3, 4, 5, 6, 7, 8}
 
 
-def test_the_compile_thunk_keeps_its_line_and_columns():
-    """``llm/engine.py:588``, columns 34-49 — ``fn.lower(*args)`` in
-    ``_call``'s lambda — is the outermost user frame of every serve program
-    with a Mosaic call, and so in its compile-cache key (PERF.md section 7):
-    an edit that moves it makes every cell's set-up cold on the change's
-    side of a check. A change detector, kept until a PR that re-keys the
-    programs anyway moves the thunk to a module of its own (ROADMAP D12)."""
-    import inspect
-
-    from ray_tpu.llm import engine
-
-    line = inspect.getsource(engine).splitlines()[587]
-    assert line.strip() == "None, lambda: fn.lower(*args).compile())", line
-    assert line.index("fn.lower(*args)") == 34
-
-
 def _engine_events(trace_dir: str) -> dict:
     """{line name: [(start_ns, end_ns, name, stats)]} of the ``engine.*``
     events on the trace's ``/host:CPU`` plane."""

@@ -172,9 +172,9 @@ def test_the_innermost_part_of_an_op_name(op_name, part):
     assert tracing.op_part(op_name) == part
 
 
-def test_variants_that_disagree_name_neither_and_a_stale_one_empties_the_table():
-    def variant(parts, stale=False):
-        return {"module": "jit_p", "parts": parts, "stale": stale,
+def test_variants_that_disagree_name_neither():
+    def variant(parts):
+        return {"module": "jit_p", "parts": parts, "stale": False,
                 "seconds": 0.25}
 
     a = variant({"fusion.1|f32[8]": "ffn", "fusion.2|f32[8]": "project"})
@@ -184,7 +184,7 @@ def test_variants_that_disagree_name_neither_and_a_stale_one_empties_the_table()
                                "project", "fusion.3|f32[8]": "head"}
     assert merged["variants"] == 2 and merged["seconds"] == 0.5
     assert not merged["stale"]
-    # a program from a compile cache written without the scopes
+    # a scan's own instruction, under no scope of PARTS
     text = ("HloModule jit_p, is_scheduled=true\n\nENTRY %main (a: f32[8]) -> "
             "f32[8] {\n  %a = f32[8]{0} parameter(0)\n  ROOT %fusion.1 = f32[8]"
             "{0} fusion(%a), kind=kLoop, calls=%fc, metadata={op_name="
@@ -195,9 +195,24 @@ def test_variants_that_disagree_name_neither_and_a_stale_one_empties_the_table()
         ("a", "a|f32[8]", "parameter", None, []),
         ("fusion.1", "fusion.1|f32[8]", "fusion", "jit(p)/while/body/mul",
          ["a", "fc"])]]
-    stale = variant(parts, stale=True)
-    assert tracing.merged_parts([a, stale])["jit_p"] == {
-        "parts": {}, "stale": True, "variants": 2, "seconds": 0.5}
+
+
+def test_a_program_with_no_part_of_its_own_is_not_stale():
+    """``merge_carry`` is two scatters under no scope: its table is empty and
+    NOT stale, so the benchmark's ``part_share`` readers, which answer
+    nothing for a run with a stale program, answer for every cell that
+    admits through it (every serve cell since PR 56). Nothing can be stale:
+    the compile cache keys on the scopes (``tests/test_compile_key.py``)."""
+    from ray_tpu.llm.programs import merge_carry
+
+    i32 = jnp.zeros((4,), jnp.int32)
+    table = tracing.compiled_parts(
+        merge_carry.lower(i32, i32, i32[:2], i32[:2], i32[:2]).compile())
+    assert table["module"] == "jit_merge_carry"
+    assert not set(table["parts"].values()) & set(tracing.PARTS)
+    assert table["stale"] is False
+    merged = tracing.merged_parts([table, table])["jit_merge_carry"]
+    assert merged["stale"] is False and merged["variants"] == 2
 
 
 def test_the_compilers_own_instructions_take_their_neighbours_part():

@@ -107,24 +107,34 @@ def test_jax_trainer_single_worker(rt, tmp_path):
 
 def test_jax_trainer_worker_failure_restarts(rt, tmp_path):
     """FailureConfig path: worker 1 dies once, group restarts and resumes
-    from the last checkpoint (ref: Train v2 FailurePolicy semantics)."""
+    from the last checkpoint (ref: Train v2 FailurePolicy semantics).
+
+    The ranks keep in step through the group's barrier, as a data-parallel
+    loop does through its all-reduce: ``train.report`` holds nobody back, so
+    without it rank 0 runs its six steps alone, and where rank 1 came to its
+    crash a poll later (a busy host; here it always starts half a second
+    late) the restart resumed from rank 0's LAST checkpoint, had nothing
+    left to do and reported nothing."""
     marker = str(tmp_path / "crashed_once")
 
     def flaky_loop(config):
         import os
+        import time
 
-        import numpy as np
-
+        import ray_tpu.collective as collective
         from ray_tpu import train
         from ray_tpu.train import Checkpoint
 
         ctx = train.get_context()
         start = train.get_checkpoint()
         step0 = start.to_dict()["step"] if start else 0
+        if ctx.get_world_rank() == 1 and not os.path.exists(config["marker"]):
+            time.sleep(0.5)
         for step in range(step0, 6):
             if step == 3 and ctx.get_world_rank() == 1 and not os.path.exists(config["marker"]):
                 open(config["marker"], "w").close()
                 os._exit(1)  # hard crash, not an exception
+            collective.barrier(group_name=ctx.collective_group)
             ckpt = Checkpoint.from_dict({"step": step + 1})
             train.report({"step": step}, checkpoint=ckpt)
         return "done"
