@@ -12,16 +12,25 @@ kinds of which ONE DOES NOT GROW, and a block a pattern character.
   ``prefill_batch`` and ``decode_multi`` find a slot's row through the tables
   the engine already hands over, the free list and the ``rt_llm_pages_*``
   gauges count rows, and the engine learns nothing about a state. Row 0 is
-  the junk row, as page 0 is the junk page: dead decode slots and a wave's
-  dummy prompts write there. The conv rows lie flat, ``[R, (K - 1) . C]``: a
-  second-minor axis of 3 would pad to a whole sublane tile on the device.
+  the junk row, as page 0 is the junk page: a wave's dummy prompts write
+  there, and a dead decode slot points there and leaves it as it is. The
+  conv rows lie flat, ``[R, (K - 1) . C]``: a second-minor axis of 3 would
+  pad to a whole sublane tile on the device.
 * **Decode** advances each live slot's row one position a step, exactly.
-  The state is updated where it lies: the step's small inputs are laid out
+  Which slot owns each row, and whether a live one does, is found once a
+  step (``_decode_body``: ``owner``, ``owned``); both pools are then
+  updated where they lie. The state: the step's small inputs are laid out
   by row, every row takes the update (``dt`` 0 where no live slot owns it:
-  unchanged), and only the outputs come back by slot (``_mamba_step``). On
-  a TPU that is ONE pass over a block's pool — ``ops/ssm_pool.py``'s kernel
-  reads a row, advances it, reads it out and writes it; anywhere else
-  ``ops/ssm.py``'s one-step form, plain XLA operations and the kernel's
+  unchanged), and only the outputs come back by slot (``_mamba_step``). The
+  conv rows: every owned row of ONE block's slab drops its oldest input and
+  takes its owner's new one, a row of no live slot stays bit for bit. (The
+  device keeps ``[n_mamba, R, ...]`` with the BLOCK axis in the sublanes
+  where ``R`` would pad a tile, so a scatter of the slots' rows rewrote the
+  conv rows of all blocks, every block of every step: 0.716 ms of a 14.4 ms
+  step at 8 blocks of 129 rows.) On a TPU the state's update is ONE pass
+  over a block's pool — ``ops/ssm_pool.py``'s kernel reads a row, advances
+  it, reads it out and writes it; anywhere else ``ops/ssm.py``'s one-step
+  form, plain XLA operations and the kernel's
   reference. Attention likewise reads its pages where they lie
   (``ops/paged_attention.py``) or, off the TPU, the gathered table with a
   position mask: one switch, the seam's rule bound here as ``_reads_in_place``.
@@ -110,33 +119,48 @@ def make_pools(cfg: SsmMoeConfig, page_size: int, n_pages, kv_dtype):
                       jnp.dtype(cfg.dtype)))
 
 
-def _mamba_step(layer, x, j, row, live, states, convs, cfg: SsmMoeConfig):
+def _mamba_step(layer, x, j, row, owner, owned, states, convs,
+                cfg: SsmMoeConfig):
     """One position of Mamba-2 block ``j`` (its place among the Mamba-2
     blocks) for every slot, through the slots' rows. x: [B, 1, D]; row: [B]
-    int32 (0, the junk row, for a slot that is not ``live``). The state pool
-    is updated WHERE IT LIES: the step's small inputs (x, dt, B, C of a
-    slot) are laid out by row, every row of the block's pool takes the
-    update — a row of no live slot has ``dt`` 0, which decays nothing and
-    adds nothing, so it stays bit for bit — and only the outputs are
-    gathered back by slot. A gather of the rows, the update and a scatter
-    back moved the state six times a step and took 3.4 ms a block at 128
-    slots; the plain form in place moves it twice and reads it a third time
-    for ``y`` (1.19 ms); ``ssm_pool_step`` reads it once and writes it once
-    (0.83 ms: PERF.md section 6, PRs 38 and 39). Returns (y [B, 1, D],
-    states, convs)."""
+    int32 (0, the junk row, for a slot that is not live); owner: [R] int32,
+    the slot that holds each row, and owned: [R] bool, whether a LIVE slot
+    does (``_decode_body`` finds both once a step). The state pool is updated
+    WHERE IT LIES: the step's small inputs (x, dt, B, C of a slot) are laid
+    out by row, every row of the block's pool takes the update — a row of no
+    live slot has ``dt`` 0, which decays nothing and adds nothing, so it
+    stays bit for bit — and only the outputs are gathered back by slot. A
+    gather of the rows, the update and a scatter back moved the state six
+    times a step and took 3.4 ms a block at 128 slots; the plain form in
+    place moves it twice and reads it a third time for ``y`` (1.19 ms);
+    ``ssm_pool_step`` reads it once and writes it once (0.83 ms: PERF.md
+    section 6, PRs 38 and 39). The conv pool likewise: block ``j``'s slab is
+    read, every owned row of it shifted by one input, and the slab written
+    back where it lay — no row is scattered into the pool (part ``conv``
+    below says why). Returns (y [B, 1, D], states, convs)."""
     B, R = x.shape[0], states.shape[1]
+    C = cfg.conv_width
     z, u, dt = mamba_in(layer, x, cfg)
+
+    def by_row(a):  # [B, ...] of the slots -> [R, ...] of the rows
+        return jnp.where(owned.reshape((R,) + (1,) * (a.ndim - 1)),
+                         a[owner], 0)
+
     with tracing.part("conv"):
+        # the conv rows shift where they lie too: every row of the block
+        # drops its oldest input and takes its slot's new one, a row of no
+        # live slot (the junk row too) stays. (The device keeps the pool
+        # with the BLOCK axis in the sublanes, so a scatter of the slots'
+        # rows into it rewrote the conv rows of ALL blocks, 76 MB a block,
+        # 0.716 ms a step at 8 blocks of 129 rows: PERF.md section 6, PR 60.)
+        old = convs[j]
         window = jnp.concatenate(
-            [convs[j, row].reshape(B, cfg.conv_kernel - 1, -1), u], axis=1)
+            [old[row].reshape(B, cfg.conv_kernel - 1, C), u], axis=1)
         xbc = ssm.conv_step(window, layer["conv"]["kernel"],
                             layer["conv"]["bias"])
-        convs = convs.at[j, row].set(window[:, 1:].reshape(B, -1))
+        shifted = jnp.concatenate([old[:, C:], by_row(u[:, 0])], axis=1)
+        convs = convs.at[j].set(jnp.where(owned[:, None], shifted, old))
     with tracing.part("ssm"):
-        def by_row(a):  # [B, ...] of the slots -> [R, ...] of the rows
-            a = jnp.where(live.reshape((B,) + (1,) * (a.ndim - 1)), a, 0)
-            return jnp.zeros((R,) + a.shape[1:], a.dtype).at[row].set(a)
-
         xs, Bm, Cm = split_conv(xbc, cfg)
         step = (by_row(xs), by_row(mamba_dt(layer, dt[:, 0])),
                 mamba_decay(layer), by_row(Bm), by_row(Cm), layer["D"])
@@ -160,6 +184,12 @@ def _decode_body(params, tokens, pos, tables, cache, active, temps, key,
     off = pos % PS
     page = jnp.take_along_axis(t_kv, (pos // PS)[:, None], axis=1)[:, 0]
     row = jnp.where(active, t_state[:, 0], 0)  # a dead slot: the junk row
+    # each row's slot, and whether a live one holds it: the junk row's is
+    # whichever dead slot wrote last, and never live
+    R = states.shape[1]
+    owner = jnp.zeros((R,), jnp.int32).at[row].set(
+        jnp.arange(B, dtype=jnp.int32))
+    owned = jnp.zeros((R,), bool).at[row].set(active)
     lengths = jnp.where(active, pos + 1, 0)
     in_place = _reads_in_place()
     at = {MAMBA: 0, ATTENTION: 0}  # the block's place in its kind's pools
@@ -171,7 +201,7 @@ def _decode_body(params, tokens, pos, tables, cache, active, temps, key,
         if kind == MAMBA:
             j, at[kind] = at[kind], at[kind] + 1
             y, states, convs = _mamba_step(
-                layer, x, j, row, active, states, convs, cfg)
+                layer, x, j, row, owner, owned, states, convs, cfg)
         elif kind == ATTENTION:
             j, at[kind] = at[kind], at[kind] + 1
             q, k, v = attn_project(layer, x, cfg)

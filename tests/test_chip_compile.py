@@ -59,6 +59,18 @@ def _shape(shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
+def _main_and_body(text):
+    """(instructions of the computation that holds the loop over the steps,
+    the program's only loop; instructions of every other computation: the
+    loop's body and what it calls — its condition, the sampling branches) of
+    a compiled decode program, rows of ``tracing.program_instructions``."""
+    computations = tracing.program_instructions(text)[1]
+    main, = [rows for rows in computations
+             if any(opcode == "while" for _, _, opcode, *_ in rows)]
+    return main, [row for rows in computations if rows is not main
+                  for row in rows]
+
+
 # chip_smoke's train phase: Llama-2-7B heads, sequence 2048
 _QKV = _shape((1, 2048, 32, 128), jnp.bfloat16)
 
@@ -417,15 +429,10 @@ def test_cohere2_moe_decode_lays_no_weight_out(cohere2_decode):
                 out.append(f"{opcode} {key}")
         return out
 
-    computations = tracing.program_instructions(compiled.as_text())[1]
-    main = [rows for rows in computations
-            if any(opcode == "while" for _, _, opcode, *_ in rows)]
-    assert len(main) == 1  # the scan over the steps is the only loop
+    main, body = _main_and_body(compiled.as_text())
     # the walk sees a weight's layout change where there is one
     assert any("copy" in found and "[4096,16384]" in found
-               for found in lays_out(main[0]))
-    # the body, and what it calls: the loop's condition, the sampling branches
-    body = [row for rows in computations if rows is not main[0] for row in rows]
+               for found in lays_out(main))
     assert len(body) > 300
     assert lays_out(body) == []
 
@@ -565,8 +572,10 @@ def test_sparse_moe_prefill_batch_compiles(one_chip, monkeypatch):
 
 
 # ------------------------------ state-space blocks beside attention and experts
-_POOL_COPY = (r"= (?:f32\[8,129,64,64,128\]|bf16\[8,129,18432\]|"
+_POOL_COPY = (r"= (?:f32\[8,129,64,64,128\]|"
               r"bf16\[2,20000,16,2,128\])\S* copy\(")
+_CONV_POOL = "bf16[8,129,18432]"
+_CONV_POOL_COPY = rf"= {re.escape(_CONV_POOL)}\S* copy\("
 
 
 def _ssm_moe_args(one_chip, pattern="MEMEM*EMEMEM*EMEME", kv=20000, state=129):
@@ -629,7 +638,16 @@ def _ssm_moe_decode(one_chip, monkeypatch, args, B, n_steps):
         ssm_moe_decode_multi.clear_cache()
 
 
-def test_ssm_moe_decode_multi_compiles(one_chip, monkeypatch):
+@pytest.fixture(scope="module")
+def ssm_moe_decode(one_chip):
+    """(lowered, compiled) decode program of the cell — 128 slots, 129 state
+    rows, 8 steps, published widths: compiled once for the two tests that
+    read it."""
+    with pytest.MonkeyPatch.context() as patch:
+        return _ssm_moe_decode(one_chip, patch, _ssm_moe_args(one_chip), 128, 8)
+
+
+def test_ssm_moe_decode_multi_compiles(ssm_moe_decode):
     """128 slots a step: the two attention blocks read their pages where
     they lie (2 KV heads, 16 query heads each), every expert block applies
     its 16 held two-matrix experts to all 128 tokens in one batched product
@@ -637,12 +655,15 @@ def test_ssm_moe_decode_multi_compiles(one_chip, monkeypatch):
     block's state pool is updated where it lies — one ``ssm_pool_step`` call
     a block (the test below), no gathered ``[128, 64, 64, 128]`` rows, no
     loop over slots — with no copy of a whole state or K/V pool on entry or
-    exit."""
+    exit. The conv pool alone is copied, once on entry and once on exit: the
+    device keeps ``[8, 129, 18432]`` with the 8 blocks in the sublanes (129
+    rows would pad a tile), the step updates one block's slab in place with
+    the rows there, and the compiler turns the pool once a PROGRAM, outside
+    the loop over the steps. What matters is that no step pays for it."""
     from ray_tpu.llm.programs import MOE_STATS
 
     B = 128
-    lowered, compiled = _ssm_moe_decode(
-        one_chip, monkeypatch, _ssm_moe_args(one_chip), B, 8)
+    lowered, compiled = ssm_moe_decode
     # tokens | MOE_STATS | ssm_updates, walk_blocks, walk_run_blocks
     assert lowered.out_info[0].shape == (8, B + len(MOE_STATS) + 3)
     text = compiled.as_text()
@@ -662,6 +683,9 @@ def test_ssm_moe_decode_multi_compiles(one_chip, monkeypatch):
     assert not re.findall(_RAGGED_DOT, text)
     assert not re.findall(_SWIGLU, text)
     assert not re.findall(_POOL_COPY, text)
+    assert not [key for _, key, opcode, *_ in _main_and_body(text)[1]
+                if opcode == "copy" and key.endswith("|" + _CONV_POOL)]
+    assert len(re.findall(_CONV_POOL_COPY, text)) <= 2
     # no table of K/V rows gathered out of a pool, no state rows gathered
     # by slot, and the scan over the steps is the program's only loop
     assert not re.findall(r"bf16\[128,(?:256|4096),(?:16,)?2,128\]", text)
@@ -669,6 +693,41 @@ def test_ssm_moe_decode_multi_compiles(one_chip, monkeypatch):
     assert len(re.findall(r" while\(", text)) == 1
     # 75 MB: the step's activations; no state row leaves its pool
     assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
+
+
+def test_ssm_moe_decode_shifts_the_conv_rows_where_they_lie(ssm_moe_decode):
+    """The cell's decode program: inside the loop over the steps what writes
+    the conv pool is, a Mamba-2 block, ONE update in place of that block's
+    ``[1, 129, 18432]`` slab (a fusion rooted in a ``dynamic-update-slice``,
+    part ``conv``), and no ``scatter`` takes the pool anywhere. Until PR 60
+    the step scattered the slots' 128 rows into the pool, which the device
+    keeps with the BLOCK axis in the sublanes: eight ``conv/scatter``
+    fusions a step, each with the whole pool of all blocks as its result,
+    76 MB moved to change 4.7. What else hands the pool on there is the
+    compiler's own staging through fast memory, which has no ``op_name``."""
+    text = ssm_moe_decode[1].as_text()
+    pool = re.escape(_CONV_POOL)
+    assert not re.findall(rf"= {pool}\S* scatter\(", text)
+    roots = dict(re.findall(
+        r"^%?([\w.\-]+) \(.*\{\n(?:  (?!ROOT ).*\n)*  ROOT (.*)$", text, re.M))
+    writes = []
+    for name, key, opcode, op, _ in _main_and_body(text)[1]:
+        if not key.endswith("|" + _CONV_POOL) or opcode in (
+                "parameter", "get-tuple-element", "bitcast"):
+            continue
+        if op is None:
+            assert opcode in ("copy-start", "copy-done", "slice-start",
+                              "custom-call"), (name, opcode)
+            continue
+        assert opcode == "fusion" and tracing.op_part(op) == "conv", (name, op)
+        line, = re.findall(rf"^\s*%{re.escape(name)} = .*$", text, re.M)
+        root = roots[re.search(r"calls=%?([\w.\-]+)", line)[1]]
+        slab, = re.findall(
+            rf"= {pool}\S* dynamic-update-slice\(%\S+, %([\w.\-]+),", root)
+        assert re.search(
+            rf"%{re.escape(slab)} = bf16\[1,129,18432\]\S* ", text), root
+        writes.append(name)
+    assert len(writes) == 8, writes
 
 
 def test_ssm_moe_decode_moves_the_state_in_one_pass(one_chip, monkeypatch):
@@ -723,6 +782,7 @@ def test_ssm_moe_prefill_batch_compiles(one_chip, monkeypatch):
     assert len(re.findall(_RAGGED_DOT, text)) == 2 * 8
     assert not re.findall(_SWIGLU, text)
     assert not re.findall(_POOL_COPY, text)
+    assert not re.findall(_CONV_POOL_COPY, text)
     # the in-projection of 16,384 tokens, the chunks' decay blocks and
     # states in float32: 2.94 GB
     assert compiled.memory_analysis().temp_size_in_bytes < 3.4e9

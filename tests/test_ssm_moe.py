@@ -20,7 +20,8 @@ from ray_tpu.llm import ssm_moe as programs
 from ray_tpu.llm.engine import (ContinuousBatchingEngine, UnsupportedByModel,
                                 serving_programs)
 from ray_tpu.models.ssm_moe import (ATTENTION, EXPERTS, MAMBA, SsmMoeConfig,
-                                    ssm_moe_forward, ssm_moe_init)
+                                    mamba_decay, mamba_dt, mamba_in,
+                                    split_conv, ssm_moe_forward, ssm_moe_init)
 from ray_tpu.ops import ssm
 from ray_tpu.ops.paged_attention import paged_decode_attention
 from ray_tpu.ops.ssm_pool import ssm_pool_step
@@ -270,10 +271,10 @@ def test_a_padded_prompt_leaves_the_state_of_its_true_length(lens):
 
 def test_a_dead_slot_and_the_junk_row_never_reach_a_live_slot():
     """Decode with one live slot of three: the dead slots' steps go to the
-    junk row (planted non-zero, as every other row) — its conv row is
-    written, its state takes ``dt`` 0 and stays bit for bit — the live
-    slot's row is the reference's, and rows nobody holds keep what was
-    planted."""
+    junk row (planted non-zero, as every other row), which no live slot
+    owns — its conv row stays bit for bit, and so does its state, which
+    takes ``dt`` 0 — the live slot's row is the reference's, and rows
+    nobody holds keep what was planted."""
     eng = _engine()
     kp, vp, states, convs = eng.cache
     eng.cache = (kp, vp, states + 3.0, convs + 3.0)
@@ -287,7 +288,7 @@ def test_a_dead_slot_and_the_junk_row_never_reach_a_live_slot():
     assert np.all(np.asarray(eng.cache[2][:, others]) == 3.0)
     assert np.all(np.asarray(eng.cache[2][:, 0]) == 3.0)       # dt 0: unchanged
     assert np.all(np.asarray(eng.cache[3][:, others]) == 3.0)
-    assert not np.all(np.asarray(eng.cache[3][:, 0]) == 3.0)   # junk, written
+    assert np.all(np.asarray(eng.cache[3][:, 0]) == 3.0)       # junk: unowned
 
 
 def test_a_slot_reused_after_a_release_starts_from_a_zero_state():
@@ -662,6 +663,53 @@ def test_the_pool_step_kernel_leaves_unowned_rows_and_other_blocks_bit_for_bit()
         assert np.array_equal(got[others], pool[others])
         assert not np.array_equal(got[j, 2], pool[j, 2])
         assert rel(got[j, owned], ssm.ssm_step(pool[j], *step)[0][owned]) < 1e-6
+
+
+def test_a_decode_step_shifts_live_conv_rows_by_one_input_and_leaves_the_rest():
+    """One decode step on planted pools of five rows, slots 0 and 2 live
+    (rows 2 and 3), slot 1 dead with its old row 1 still in its table: in
+    every Mamba-2 block the junk row, the dead slot's old row and a row
+    nobody drew stay bit for bit, and a live slot's row drops its oldest
+    input and keeps the other two where they were, bit for bit. In the first
+    block, whose input is the embedding, the input taken and the state are
+    ``ops/ssm.py``'s one-step forms on that slot ALONE."""
+    eng = _engine(n_pages={"kv": 41, "state": 5})
+    C, K = CFG.conv_width, CFG.conv_kernel
+    rng = np.random.default_rng(6)
+    kp, vp, states, convs = eng.cache
+    start = (kp, vp, jnp.asarray(rng.normal(size=states.shape), states.dtype),
+             jnp.asarray(rng.normal(size=convs.shape), convs.dtype))
+    tables = (jnp.asarray(rng.permutation(np.arange(1, 37)).reshape(3, 12),
+                          jnp.int32), jnp.asarray([[2], [1], [3]], jnp.int32))
+    tok = jnp.asarray([7, 9, 11], jnp.int32)
+    _, _, _, _, _, got_S, got = programs.ssm_moe_decode_multi(
+        eng.params, None, jnp.zeros(3, jnp.int32), tok,
+        jnp.asarray([5, 8, 17], jnp.int32), tables,
+        *(jnp.copy(a) for a in start), jnp.asarray([True, False, True]),
+        jnp.zeros(3), jax.random.PRNGKey(0), cfg=CFG, n_steps=1)
+    old = np.asarray(start[3])
+    got = np.asarray(got)
+    assert got.shape == (N_M, 5, (K - 1) * C)
+    for unowned in (0, 1, 4):                 # junk, a dead slot's, nobody's
+        assert np.array_equal(got[:, unowned], old[:, unowned]), unowned
+        assert np.array_equal(got_S[:, unowned], start[2][:, unowned])
+    layer = eng.params["layers_0"]
+    assert CFG.pattern[0] == MAMBA
+    for slot, row in ((0, 2), (2, 3)):
+        assert np.array_equal(got[:, row, :-C], old[:, row, C:])   # every block
+        assert not np.array_equal(got[:, row, -C:], old[:, row, -C:])
+        x = eng.params["tok"]["embedding"][tok[slot]][None, None]
+        _, u, dt = mamba_in(layer, x, CFG)
+        window = jnp.concatenate(
+            [start[3][0, row].reshape(1, K - 1, C), u], axis=1)
+        assert rel(got[0, row], window[:, 1:].reshape(-1)) < 1e-6
+        xbc = ssm.conv_step(window, layer["conv"]["kernel"],
+                            layer["conv"]["bias"])
+        xs, Bm, Cm = split_conv(xbc, CFG)
+        want_S, _ = ssm.ssm_step(
+            start[2][0, row][None], xs, mamba_dt(layer, dt[:, 0]),
+            mamba_decay(layer), Bm, Cm, layer["D"])
+        assert rel(got_S[0, row], want_S[0]) < 1e-6
 
 
 def test_decode_through_the_pool_step_kernel_is_the_plain_decode(monkeypatch):
